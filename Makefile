@@ -1,9 +1,9 @@
 # Test entry points. JAX_PLATFORMS=cpu matches tests/conftest.py's virtual
-# 8-device CPU setup (and keeps a TPU plugin from grabbing the chip).
+# 8-device CPU setup. chip-smoke is the one target that wants the chip.
 
 PY ?= python
 
-.PHONY: test smoke serve-smoke serve-restart-smoke observatory-smoke \
+.PHONY: test chip-smoke smoke serve-smoke serve-restart-smoke observatory-smoke \
 	scenarios-smoke fleet-smoke perf-diff bench-byzantine bench-churn \
 	bench-robust-scale bench-sweep bench-compute bench-telemetry \
 	bench-fused bench-serving bench-serving-load bench-fleet \
@@ -15,6 +15,12 @@ PY ?= python
 # local runs should fail loudly on broken collection).
 test:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
+
+# The main path once on the TPU (headline GLM to eps, full-width softmax,
+# reference check, four-chip segment where four are visible): exits
+# non-zero without a chip. No platform pin — it takes the machine's TPU.
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # Fast robustness smoke: fault-injection + churn + Byzantine + gather-
 # aggregation + replica-batched-parity + telemetry + serving +
@@ -111,8 +117,7 @@ bench-sweep:
 	JAX_PLATFORMS=cpu $(PY) examples/bench_sweep.py
 
 # Regenerate the compute-bound tier evidence with its published MFU-floor
-# gate (docs/perf/compute_bound.json; meaningful numbers need the real
-# chip — on CPU containers set BENCH_NO_RANGE_CHECK=1).
+# gate (docs/perf/compute_bound.json; exits without a TPU).
 bench-compute:
 	$(PY) examples/bench_compute_bound.py
 

@@ -1,0 +1,214 @@
+"""Chip smoke: the main path, once, on the TPU, through the normal entry points.
+
+    python chip_smoke.py
+
+The quickest proof that the system still starts on the chip. It is not a
+benchmark: every rate it prints is a smoke observation from one run. One
+process, no children; any failed check or exception is a non-zero exit, and
+without a TPU it exits before doing any work. Segments:
+
+1. Headline GLM (bench.py's configuration at T=30,000): logistic D-SGD on a
+   ring, N=256, d=81, b=16, f32, ``eval_every=1`` — ``Simulator`` runs data
+   generation, the sklearn oracle, the scan and the report. Must cross
+   ε ≤ 0.08 inside the horizon with finite, bounded consensus.
+2. Full-width softmax (examples/bench_compute_bound.py's shape, the only
+   supported model that loads the MXU): N=8, d=4096(+1), K=512, b=2048, in
+   bf16/default and f32/highest, a few tens of steps on a seeded random
+   dataset. Loss finite, below the zero model's ln K, and not rising.
+3. Reference agreement on a small input: full-batch logistic and softmax
+   runs against the numpy reference-semantics backend.
+4. Where four chips are visible: the ``worker_mesh=4`` ring at N=100,000
+   against the same config unsharded, with the state spread over the four.
+
+Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
+the choices ``auto`` makes on the chip are the ones exercised. The last
+line of stdout is one JSON object naming the device as JAX reports it.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import sys
+
+import numpy as np
+
+# f32 (device) against f64 (numpy reference) over tens of full-batch steps
+# on models of order 0.1–1: accumulated rounding stays near 1e-6; a bf16
+# data path or a wrong update is orders of magnitude above this.
+REFERENCE_ATOL = 1e-4
+# worker_mesh=4 and worker_mesh=0 are different programs (halo ppermute vs
+# in-place gather): same arithmetic, summation order free to differ by an
+# f32 ulp per step. 100 steps on models of order 0.1 stay well inside this.
+MESH_ATOL = 1e-5
+
+
+def _check(ok: bool, what: str) -> None:
+    if not ok:
+        raise RuntimeError(f"chip_smoke: FAILED — {what}")
+
+
+def _say(segment: str, device: dict, hist, **extra) -> None:
+    fields = {
+        "platform": device["platform"],
+        "device_kind": device["kind"],
+        "devices_visible": device["count"],
+        "mesh_devices": hist.mesh_devices,
+        "compile_s": round(hist.compile_seconds, 2),
+        "steps_per_s_smoke_observation": round(hist.iters_per_second, 1),
+        **extra,
+    }
+    print(f"[chip_smoke] {segment}: " + " ".join(f"{k}={v}" for k, v in fields.items()),
+          flush=True)
+
+
+def glm_segment(device: dict, *, n_workers: int = 256,
+                n_iterations: int = 30_000) -> None:
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.simulator import Simulator
+
+    cfg = ExperimentConfig(
+        problem_type="logistic", algorithm="dsgd", topology="ring",
+        n_workers=n_workers, n_iterations=n_iterations,
+    )
+    sim = Simulator(cfg)
+    rec = sim.run_one(verbose=False)
+    sim.report_numerical_results()
+    hist = rec.result.history
+    crossed = rec.summary.iterations_to_threshold
+    _say("glm", device, hist, crossed_eps_at=crossed,
+         final_gap=f"{hist.objective[-1]:.4f}",
+         consensus=f"{hist.consensus_error[-1]:.3e}")
+    _check(hist.objective.shape == (n_iterations,), "one gap row per iteration")
+    _check(bool(np.all(np.isfinite(hist.objective))), "suboptimality gap finite")
+    _check(0 < crossed <= n_iterations,
+           f"gap crosses eps={cfg.suboptimality_threshold} within T={n_iterations}")
+    cons = hist.consensus_error
+    _check(bool(np.all(np.isfinite(cons))) and cons[-1] < 1.0,
+           "consensus error finite and bounded")
+    _check(rec.result.final_models.shape == (n_workers, sim.dataset.n_features),
+           "final model stack is [N, d]")
+
+
+def softmax_segment(device: dict, *, n_workers: int = 8, d_feat: int = 4096,
+                    n_classes: int = 512, batch: int = 2048,
+                    n_iterations: int = 40, eval_every: int = 10) -> None:
+    from distributed_optimization_tpu.backends import jax_backend
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.utils.data import random_softmax_dataset
+
+    ds = random_softmax_dataset(n_workers, batch, d_feat, n_classes)
+    for dtype, precision in (("bfloat16", "default"), ("float32", "highest")):
+        cfg = ExperimentConfig(
+            problem_type="softmax", n_classes=n_classes, algorithm="dsgd",
+            topology="ring", n_workers=n_workers, local_batch_size=batch,
+            n_samples=n_workers * batch, n_features=d_feat,
+            n_informative_features=min(64, d_feat),
+            n_iterations=n_iterations, eval_every=eval_every,
+            dtype=dtype, matmul_precision=precision,
+        )
+        # f* = 0, so the recorded "gap" is the full-dataset loss itself.
+        result = jax_backend.run(cfg, ds, 0.0)
+        loss = result.history.objective
+        _say(f"softmax {dtype}/{precision}", device, result.history,
+             loss=">".join(f"{v:.4f}" for v in loss))
+        _check(loss.shape == (n_iterations // eval_every,), "one loss row per eval")
+        _check(bool(np.all(np.isfinite(loss))), f"{dtype} loss finite")
+        _check(loss[0] < math.log(n_classes),
+               f"{dtype} loss below the zero model's ln K after {eval_every} steps")
+        _check(bool(np.all(np.diff(loss) <= 0.0)), f"{dtype} loss not rising")
+        _check(
+            result.final_models.shape == (n_workers, (d_feat + 1) * n_classes)
+            and bool(np.all(np.isfinite(result.final_models))),
+            f"{dtype} final models finite, [N, (d+1)K]",
+        )
+
+
+def reference_segment(device: dict) -> None:
+    """Small full-batch runs (no sampling, so deterministic on every
+    backend) against the numpy reference-semantics backend."""
+    from distributed_optimization_tpu.backends import jax_backend, numpy_backend
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.utils.data import generate_synthetic_dataset
+    from distributed_optimization_tpu.utils.oracle import compute_reference_optimum
+
+    for problem, extra in (("logistic", {}), ("softmax", {"n_classes": 4})):
+        cfg = ExperimentConfig(
+            problem_type=problem, algorithm="dsgd", topology="ring",
+            n_workers=8, n_samples=256, n_features=12,
+            n_informative_features=8, local_batch_size=32,
+            n_iterations=40, eval_every=10, **extra,
+        )
+        ds = generate_synthetic_dataset(cfg)
+        _, f_opt = compute_reference_optimum(
+            ds, cfg.reg_param, n_classes=cfg.n_classes
+        )
+        got = jax_backend.run(cfg, ds, f_opt)
+        want = numpy_backend.run(cfg, ds, f_opt)
+        err = float(np.max(np.abs(got.final_models - want.final_models)))
+        gap_err = float(np.max(np.abs(got.history.objective - want.history.objective)))
+        _say(f"reference {problem}", device, got.history,
+             max_model_err=f"{err:.2e}", max_gap_err=f"{gap_err:.2e}")
+        _check(err <= REFERENCE_ATOL and gap_err <= REFERENCE_ATOL,
+               f"{problem} agrees with the numpy reference at {REFERENCE_ATOL}")
+
+
+def four_chip_segment(device: dict, *, n_workers: int = 100_000,
+                      n_samples: int = 200_000, n_iterations: int = 100) -> None:
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.simulator import Simulator
+
+    cfg = ExperimentConfig(
+        problem_type="logistic", algorithm="dsgd", topology="ring",
+        topology_impl="neighbor", mixing_impl="gather",
+        n_workers=n_workers, n_samples=n_samples, local_batch_size=4,
+        n_iterations=n_iterations, eval_every=n_iterations // 2,
+    )
+    sim = Simulator(cfg)
+    sharded = sim.run_one("ring worker_mesh=4", verbose=False, worker_mesh=4)
+    single = sim.run_one("ring worker_mesh=0", verbose=False)
+    sim.report_numerical_results()
+    for rec in (sharded, single):
+        _say(rec.label, device, rec.result.history,
+             final_gap=f"{rec.result.history.objective[-1]:.5f}")
+    err = float(np.max(np.abs(
+        sharded.result.final_models - single.result.final_models
+    )))
+    print(f"[chip_smoke] four-chip: max |x_mesh4 - x_mesh0| = {err:.2e} "
+          f"(tolerance {MESH_ATOL})", flush=True)
+    _check(sharded.result.history.mesh_devices == 4,
+           "worker_mesh=4 state held as four row blocks, one per device")
+    _check(single.result.history.mesh_devices == 1,
+           "worker_mesh=0 matrix-free run stays on one device")
+    _check(bool(np.all(np.isfinite(sharded.result.final_models))),
+           "sharded final models finite")
+    _check(err <= MESH_ATOL, "worker_mesh=4 agrees with worker_mesh=0")
+
+
+def main() -> int:
+    from distributed_optimization_tpu.runtime import (
+        configure_compile_cache,
+        require_tpu,
+    )
+
+    cache_dir = configure_compile_cache()
+    device = require_tpu("chip_smoke.py")
+    import jax
+
+    print(f"[chip_smoke] jax {jax.__version__} platform={device['platform']} "
+          f"device_kind={device['kind']} devices_visible={device['count']} "
+          f"compile_cache={cache_dir}", flush=True)
+    glm_segment(device)
+    softmax_segment(device)
+    reference_segment(device)
+    if device["count"] >= 4:
+        four_chip_segment(device)
+    else:
+        print(f"[chip_smoke] four-chip segment: NOT RUN "
+              f"({device['count']} device(s) visible)", flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

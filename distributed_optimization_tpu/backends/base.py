@@ -26,10 +26,8 @@ def x64_scope(config):
     """
     import jax
 
-    from distributed_optimization_tpu.parallel._compat import enable_x64
-
     return (
-        enable_x64()
+        jax.enable_x64()
         if config.dtype == "float64" and not jax.config.jax_enable_x64
         else contextlib.nullcontext()
     )

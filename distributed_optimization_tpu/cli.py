@@ -121,8 +121,10 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--topology", choices=TOPOLOGIES, default=_DEFAULTS.topology)
     run.add_argument("--backend", choices=BACKENDS, default=_DEFAULTS.backend)
     run.add_argument("--platform", choices=("tpu", "cpu", "auto"), default="auto",
-                     help="force the JAX platform (cpu is useful for quick "
-                          "checks and virtual multi-device runs)")
+                     help="force the JAX platform: 'tpu' fails when no chip "
+                          "is found; 'cpu' is for quick checks and virtual "
+                          "multi-device runs; 'auto' takes what JAX finds — "
+                          "the report header names the device it ran on")
     run.add_argument("--multihost", action="store_true",
                      help="call jax.distributed.initialize() so the worker "
                           "mesh spans all hosts of a multi-host TPU slice "
@@ -268,10 +270,10 @@ def build_parser() -> argparse.ArgumentParser:
                           "'fused' runs the gather math as one pallas "
                           "kernel (gather+screen+mix+SGD for dsgd), the "
                           "[N,k_max,d] stack never hitting HBM; 'auto' = "
-                          "measured rule: gather unless fully connected, "
-                          "promoted to fused when eligible (static "
-                          "topology, supported rule, telemetry off — "
-                          "docs/perf/fused_robust.json)")
+                          "measured rule: gather unless fully connected; "
+                          "never fused — Mosaic refuses that kernel, so "
+                          "on a TPU an explicit 'fused' stops at the "
+                          "compiler's error")
     opt.add_argument("--partition", choices=("sorted", "shuffled"),
                      default=_DEFAULTS.partition,
                      help="worker data split: 'sorted' = the study's "
@@ -408,9 +410,6 @@ def build_parser() -> argparse.ArgumentParser:
     execg.add_argument("--scan-unroll", type=int, default=_DEFAULTS.scan_unroll,
                        help="XLA unroll factor for the training scan "
                             "(0 = auto: 8 on accelerators, 1 on CPU)")
-    execg.add_argument("--compile-cache", metavar="DIR", default=None,
-                       help="enable jax's persistent compilation cache in "
-                            "DIR (repeat runs skip the 5-30s XLA compile)")
     execg.add_argument("--dtype", choices=("float32", "float64", "bfloat16"),
                        default=_DEFAULTS.dtype)
     execg.add_argument("--matmul-precision",
@@ -608,18 +607,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 setattr(args, field, value)
 
     if args.platform != "auto":
-        # Must run before any jax operation; overrides the TPU plugin's pin
-        # (and for 'tpu' fails fast if no TPU platform can initialize,
-        # instead of silently benchmarking on a CPU fallback).
+        # Must run before any jax operation ('tpu' fails fast if no TPU
+        # platform can initialize, instead of falling back to the CPU).
         import jax
 
         jax.config.update("jax_platforms", args.platform)
 
-    if args.compile_cache:
-        import jax
+    from distributed_optimization_tpu.runtime import configure_compile_cache
 
-        jax.config.update("jax_compilation_cache_dir", args.compile_cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    configure_compile_cache()
 
     if args.multihost:
         # Multi-host slice: every host runs this same process; jax wires the

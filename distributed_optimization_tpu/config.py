@@ -347,10 +347,10 @@ class ExperimentConfig:
     # plus the SGD update for dsgd) so the [N, k_max, d] neighbor stack
     # never materializes in HBM (ops/pallas_kernels.py; count rules need
     # the closed neighborhood to fit the in-kernel sort network,
-    # k_max+1 <= FUSED_MAX_SORT_WIDTH). 'auto' picks from the measured
-    # crossover and promotes to 'fused' when the backend reports it
-    # eligible — static topology, fused-supported rule, no telemetry
-    # activity probe (see resolved_robust_impl).
+    # k_max+1 <= FUSED_MAX_SORT_WIDTH). 'auto' picks dense or gather from
+    # the measured crossover (resolved_robust_impl) and never selects
+    # 'fused': Mosaic refuses the kernel's in-kernel gather, so it is an
+    # explicit opt-in that runs only in interpreter mode on CPU.
     robust_impl: str = "auto"
     # Gossip schedule: 'synchronous' averages with all (surviving) neighbors
     # per iteration; 'one_peer' is Boyd-style randomized gossip — each node
@@ -1407,9 +1407,7 @@ class ExperimentConfig:
             return "dense"
         return "gather"
 
-    def resolved_robust_impl(
-        self, k_max: int, *, fused_eligible: bool = False
-    ) -> str:
+    def resolved_robust_impl(self, k_max: int) -> str:
         """Resolve robust_impl='auto' from the topology's maximum degree.
 
         The gather form does (k_max+1)/N of the dense sort work but adds
@@ -1422,19 +1420,14 @@ class ExperimentConfig:
         (dense keeps the fully-connected case: nothing to gain, and the
         [N, k_max+1, d] gather buffer matches dense's memory anyway).
 
-        ``fused_eligible``: the BACKEND's report that the single-kernel
-        pallas form can take this configuration (static topology, a
-        fused-supported rule at this k_max, no telemetry activity probe
-        — jax_backend._bind_byzantine computes it); when set, the gather
-        branch promotes to 'fused' — same math, one VMEM-resident kernel
-        instead of gather→sort→mix ops bouncing through HBM. An explicit
-        robust_impl is never overridden.
+        'auto' never resolves to 'fused': the pallas kernel does not
+        lower for the TPU (Mosaic's gather rule rejects its in-kernel
+        ``jnp.take``), and the default path must be the same program on
+        the chip and on CPU. An explicit robust_impl is never overridden.
         """
         if self.robust_impl != "auto":
             return self.robust_impl
-        if k_max + 1 >= self.n_workers:
-            return "dense"
-        return "fused" if fused_eligible else "gather"
+        return "dense" if k_max + 1 >= self.n_workers else "gather"
 
     def resolved_scan_unroll(self, platform: str) -> int:
         if self.scan_unroll > 0:

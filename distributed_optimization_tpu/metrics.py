@@ -55,6 +55,11 @@ class RunHistory:
     # flops, bytes_accessed, ...); None off the jax path or when telemetry
     # is off.
     cost: Optional[dict] = None
+    # How many devices each held their own block of the model stack's
+    # worker rows, read off the final state's sharding (1 = unsharded or
+    # replicated): a one-chip number and a four-chip number must never be
+    # confused, and nothing in the config says what the auto-mesh chose.
+    mesh_devices: int = 1
 
     def as_dict(self) -> dict:
         out = {

@@ -23,10 +23,9 @@ ancestor by eyeball. This module is the query side:
   must match exactly (the drift-guard contract), flagged booleans must
   not regress, and the named numeric series must agree within each
   entry's relative tolerance. Wall-clock-dependent numbers are NOT
-  checked by default — on a co-tenant machine they vary 2-3× between
-  sessions (docs/ROUND5_NOTES.md); the specs name the quantities that
-  are supposed to be stable (ratios, convergence envelopes, gate
-  booleans). Exit code 1 on any regression — ``make perf-diff`` wires it
+  checked by default — they depend on the machine and the session; the
+  specs name the quantities that are supposed to be stable (ratios,
+  convergence envelopes, gate booleans). Exit code 1 on any regression — ``make perf-diff`` wires it
   into CI, turning the bench corpus into a guarded time series.
 """
 
@@ -505,9 +504,8 @@ class Check:
 
 
 # Per-artifact checks. Deliberately NOT exhaustive: bench JSON is full of
-# session-dependent wall-clock numbers that vary 2-3× between runs on this
-# shared machine (docs/ROUND5_NOTES.md) — checking those would make the
-# guard cry wolf. What IS checked: the gate booleans every bench asserts
+# wall-clock numbers that depend on the machine and the session —
+# checking those would make the guard cry wolf. What IS checked: the gate booleans every bench asserts
 # (a regen that flips one has regressed — including platform-conditional
 # flags like ``floor_applied``, which correctly fail when "fresh" came
 # from different hardware: such a regen is not comparable evidence),

@@ -25,7 +25,11 @@ All kernels run in interpreter mode on CPU (tests / virtual-device CI) and
 compile via Mosaic on real TPU. Interpreter-mode selection respects the
 INPUT's committed platform — not the global ``jax.devices()[0]`` — so
 routing stays correct under ``jax.default_device`` / mixed-platform setups;
-pass ``interpret=`` to force either mode (tests).
+pass ``interpret=`` to force either mode (tests). A run that selects a
+kernel logs which of the two it took (``log_kernel_mode``). No ``auto``
+selector picks a kernel: they are reached by ``mixing_impl='pallas'`` and
+``robust_impl='fused'`` only, and Mosaic does not lower the fused robust
+kernels at all (tests/test_tpu_lowering.py pins which lower).
 """
 
 from __future__ import annotations
@@ -39,6 +43,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_optimization_tpu.config import AGGREGATIONS
+from distributed_optimization_tpu.log import get_logger
+
+_log = get_logger("pallas")
 
 # Width bound for the in-kernel odd-even transposition sort network the
 # count-based rules (trimmed mean / median) screen with: the network is
@@ -83,6 +90,17 @@ def resolve_interpret(x=None, interpret: Optional[bool] = None) -> bool:
         else:
             platform = default.platform
     return platform == "cpu"
+
+
+def log_kernel_mode(what: str) -> None:
+    """Say which mode a run's pallas kernels take (tracers carry no
+    platform, so under ``jit`` this is what ``resolve_interpret`` returns
+    inside the kernels too)."""
+    mode = (
+        "the pallas INTERPRETER (CPU)" if resolve_interpret()
+        else "Mosaic-compiled kernels"
+    )
+    _log.info("%s runs through %s", what, mode)
 
 
 def _roll(x, shift: int, interp: bool):

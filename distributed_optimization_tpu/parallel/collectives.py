@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import dataclasses
@@ -34,7 +35,6 @@ import dataclasses
 import numpy as np
 
 from distributed_optimization_tpu.ops.mixing import MixingOp
-from distributed_optimization_tpu.parallel._compat import shard_map
 from distributed_optimization_tpu.parallel.mesh import WORKER_AXIS
 from distributed_optimization_tpu.parallel.topology import (
     Topology,

@@ -43,16 +43,14 @@ def make_sized_worker_mesh(n_devices: int) -> Mesh:
     count as a contract — the halo plan, the per-shard timeline slices
     and the bytes-over-ICI accounting are all built for that exact P —
     so unlike ``make_worker_mesh`` there is no best-effort shrink: too
-    few visible devices is an error naming the CPU-host simulation
-    escape hatch.
+    few visible devices is an error that says what is visible.
     """
     devices = jax.devices()
     if len(devices) < n_devices:
         raise ValueError(
-            f"worker_mesh={n_devices} needs that many devices; only "
-            f"{len(devices)} visible — on CPU hosts set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=P before "
-            "importing jax"
+            f"worker_mesh={n_devices} needs that many devices; JAX sees "
+            f"{len(devices)} (platform {devices[0].platform}, "
+            f"{devices[0].device_kind})"
         )
     return Mesh(devices[:n_devices], (WORKER_AXIS,))
 

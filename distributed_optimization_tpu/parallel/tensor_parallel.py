@@ -36,11 +36,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_optimization_tpu.backends.base import x64_scope
-from distributed_optimization_tpu.parallel._compat import shard_map
 from distributed_optimization_tpu.parallel.mesh import WORKER_AXIS
 
 MODEL_AXIS = "model"

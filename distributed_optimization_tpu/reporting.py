@@ -23,8 +23,14 @@ def _fmt_sci(v: float) -> str:
 
 
 def format_report(records, config, f_opt: float, phases=None,
-                  serving=None) -> str:
+                  serving=None, device=None) -> str:
     """Render the numerical-results table for a list of ExperimentRecords.
+
+    ``device``: ``runtime.device_summary()`` of the process when a jax run
+    is in the table — the header then names platform, device kind and
+    visible count, so a report from a CPU fallback cannot pass for a chip's.
+    Runs whose state was sharded over several devices get a line saying
+    over how many.
 
     ``phases``: optional {name: seconds} wall-clock phase accounting
     (Simulator's PhaseTimer) appended as its own section. Records carrying
@@ -42,7 +48,8 @@ def format_report(records, config, f_opt: float, phases=None,
         f"Numerical results — problem={config.problem_type}, N={config.n_workers}, "
         f"T={config.n_iterations}, b={config.local_batch_size}, "
         f"eta0={config.learning_rate_eta0}, lambda={config.l2_regularization_lambda}",
-        f"backend={config.backend}; f(x*) = {f_opt:.6f}; "
+        f"backend={config.backend}{_device_tag(device)}; "
+        f"f(x*) = {f_opt:.6f}; "
         f"suboptimality threshold = {config.suboptimality_threshold}",
         "=" * 78,
     ]
@@ -128,6 +135,14 @@ def format_report(records, config, f_opt: float, phases=None,
             "~ sec→ε interpolated from total run wall-clock "
             "(use --measure-time for per-eval timestamps)"
         )
+    sharded = [
+        f"{rec.label} over {rec.result.history.mesh_devices}"
+        for rec in records
+        if getattr(rec, "result", None) is not None
+        and rec.result.history.mesh_devices > 1
+    ]
+    if sharded:
+        lines.append("worker rows sharded over devices: " + "; ".join(sharded))
     health_lines = _health_section(records)
     if health_lines:
         lines.append("run health (telemetry):")
@@ -142,6 +157,15 @@ def format_report(records, config, f_opt: float, phases=None,
             share = secs / total if total > 0 else 0.0
             lines.append(f"  {name:<12}{secs:>10.3f}s{share:>8.1%}")
     return "\n".join(lines)
+
+
+def _device_tag(device) -> str:
+    if device is None:
+        return ""
+    return (
+        f" on {device['platform']} ({device['kind']}, "
+        f"{device['count']} visible)"
+    )
 
 
 def _serving_line(serving) -> Optional[str]:

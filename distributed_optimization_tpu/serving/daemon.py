@@ -532,6 +532,9 @@ def main(argv=None) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    from distributed_optimization_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
     if args.store:
         # The env var is the single wiring point for the persistent
         # store: the parent's process cache attaches it on first use,

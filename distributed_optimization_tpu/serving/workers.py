@@ -195,6 +195,9 @@ def _worker_main(worker_id: int, task_q, result_q, env: dict) -> None:
     import (platform pins and the store path must precede backend init),
     then serves tasks until the ``None`` sentinel."""
     os.environ.update(env)
+    from distributed_optimization_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
     result_q.put(("ready", worker_id, os.getpid()))
     datasets: dict = {}
     while True:

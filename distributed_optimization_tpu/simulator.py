@@ -348,9 +348,22 @@ class Simulator:
             self.records, self.config, self.f_opt,
             phases=dict(self.phase_timer.phases),
             serving=serving,
+            device=self._jax_device(),
         )
         print(text)
         return text
+
+    def _jax_device(self) -> Optional[dict]:
+        """The process's jax device if any recorded run used the jax
+        backend (numpy/cpp-only tables never touch jax), else None."""
+        if not any(
+            rec.config is not None and rec.config.backend == "jax"
+            for rec in self.records
+        ):
+            return None
+        from distributed_optimization_tpu.runtime import device_summary
+
+        return device_summary()
 
     # ------------------------------------------------------------- telemetry
     def run_traces(self) -> list:
@@ -437,6 +450,7 @@ class Simulator:
             "phases": {
                 k: float(v) for k, v in self.phase_timer.phases.items()
             },
+            "device": self._jax_device(),
             "runs": [],
         }
         for rec in self.records:
@@ -456,6 +470,7 @@ class Simulator:
                     ),
                     spectral_gap=rec.summary.spectral_gap,
                     iters_per_second=rec.summary.iters_per_second,
+                    mesh_devices=rec.result.history.mesh_devices,
                     final_objective_gap=float(rec.result.history.objective[-1]),
                     history=rec.result.history.as_dict(),
                 )

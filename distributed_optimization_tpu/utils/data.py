@@ -196,6 +196,30 @@ def generate_digits_dataset(config) -> HostDataset:
     )
 
 
+def random_softmax_dataset(
+    n_workers: int, batch: int, d_feat: int, n_classes: int, seed: int = 0
+) -> HostDataset:
+    """Seeded random standardized features + uniform labels for the
+    compute-bound softmax tier; each worker's shard is exactly its batch
+    (full-batch local gradients).
+
+    Generated directly rather than through sklearn: throughput does not
+    depend on learnability, and ``make_classification`` at d=4096 costs
+    minutes a measurement does not need. Convergence of the family is
+    pinned at small shapes in tests/test_softmax.py.
+    """
+    rng = np.random.default_rng(seed)
+    n = n_workers * batch
+    X = rng.standard_normal((n, d_feat)).astype(np.float64)
+    X = np.hstack([X, np.ones((n, 1))])
+    y = rng.integers(0, n_classes, size=n).astype(np.float64)
+    shard_indices = [
+        np.arange(i * batch, (i + 1) * batch) for i in range(n_workers)
+    ]
+    return HostDataset(X_full=X, y_full=y, shard_indices=shard_indices,
+                       problem_type="softmax")
+
+
 def partition_summary(dataset: HostDataset, max_workers: int = 32) -> str:
     """Per-worker shard report, parity with the reference's generation-time
     printout (reference ``utils.py:43-48``): shard size, target range, and

@@ -88,12 +88,10 @@ def check_ppermute_roundtrip(mesh=None) -> None:
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from distributed_optimization_tpu.parallel._compat import shard_map
-
     mesh, k, axis, x = _mesh_and_probe(mesh)
 
     @partial(
-        shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)
+        jax.shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)
     )
     def roundtrip(block):
         fwd = [(i, (i + 1) % k) for i in range(k)]
@@ -113,12 +111,10 @@ def check_psum_identity(mesh=None) -> None:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from distributed_optimization_tpu.parallel._compat import shard_map
-
     mesh, k, axis, x = _mesh_and_probe(mesh)
 
     @partial(
-        shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)
+        jax.shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)
     )
     def total(block):
         return jnp.broadcast_to(

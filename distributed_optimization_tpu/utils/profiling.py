@@ -14,7 +14,7 @@ The reference's only observability is coarse per-iteration wall-clock deltas
   surface is unchanged, and every timed phase is also recorded as a span
   with nesting and timestamps, exportable as a Chrome trace;
 - ``trace`` — context manager around ``jax.profiler`` trace collection for
-  TensorBoard/XProf on real TPU runs, a no-op when profiling is unavailable.
+  TensorBoard/XProf; raises if the profiler cannot start.
 """
 
 from __future__ import annotations
@@ -22,10 +22,7 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator, Optional
 
-from distributed_optimization_tpu.log import get_logger
 from distributed_optimization_tpu.observability.spans import Tracer
-
-_log = get_logger("profiling")
 
 # The flat phase accounting grew into hierarchical span tracing
 # (ISSUE-10); PhaseTimer remains the name the rest of the repo
@@ -37,23 +34,19 @@ PhaseTimer = Tracer
 
 @contextlib.contextmanager
 def trace(log_dir: Optional[str]) -> Iterator[None]:
-    """Collect a jax.profiler trace into ``log_dir`` (no-op if None/fails).
+    """Collect a jax.profiler trace into ``log_dir`` (no-op if None).
 
-    View with TensorBoard's profile plugin / XProf. Failure to start the
-    profiler (e.g. unsupported platform) degrades to a no-op rather than
-    killing the run.
+    View with TensorBoard's profile plugin / XProf, or read the
+    ``.xplane.pb`` with ``jax.profiler.ProfileData``. A profiler that will
+    not start raises: a requested trace that silently is not there would
+    leave every metric read from it unmeasured.
     """
     if log_dir is None:
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:  # pragma: no cover - platform dependent
-        _log.warning("trace unavailable: %s", e)
-        yield
-        return
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:

@@ -71,10 +71,9 @@ def main() -> None:
         eval_every=500, partition="shuffled",
     )
     # This artifact documents the GATHER robust path's breakdown table
-    # (PR 3); pin it explicitly — since PR 6 a bare 'auto' on these
-    # static-ring cells promotes to the fused pallas kernel, which would
-    # silently change what a regen measures vs what the config string
-    # claims (the fused path's own evidence is docs/perf/fused_robust.json).
+    # (PR 3); pin it explicitly so the config string says what a regen
+    # measures whatever 'auto' resolves to (between PR 6 and PR 21 it
+    # resolved to the fused pallas kernel on these static-ring cells).
     ROBUST_IMPL = "gather"
     # Attackers, per-neighborhood budget (ring min degree 2 => b <= 1),
     # sign-flip scale. f=6 under seed 203 places <= 1 attacker in every
@@ -204,9 +203,7 @@ def main() -> None:
         "device": str(jax.devices()[0]),
         "config": (
             "logistic N=64 ring T=4k shuffled partition (gather robust "
-            f"path, robust_impl={ROBUST_IMPL!r} pinned — since PR 6 "
-            "'auto' promotes these static cells to the fused kernel, "
-            f"whose evidence is fused_robust.json); f={F} Byzantine of "
+            f"path, robust_impl={ROBUST_IMPL!r} pinned); f={F} Byzantine of "
             f"64, per-neighborhood budget b={B}, sign-flip scale {S}"
         ),
         "note": (

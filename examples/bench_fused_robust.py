@@ -7,11 +7,15 @@ Two measurements, one artifact (``docs/perf/fused_robust.json``):
    VMEM-resident pass, the [N, k_max, d] neighbor stack never
    materialized in HBM) against the multi-op gather path, per rule, on
    the N=256 ring headline shape of robust_scale.json. The fusion claim
-   is a COMPILED-path claim: on real TPU the kernel lowers through
-   Mosaic and the asserted floor applies (fused ≥ 1.1× gather for the
-   count rules); on CPU hosts pallas runs in INTERPRETER mode — not the
-   claimed artifact — so cells carry honest per-cell ``fused_loses``
-   flags instead of a gate (same convention as robust_scale.json's
+   is a COMPILED-path claim, and its "compiled-path floor" (fused ≥ 1.1×
+   gather for the count rules on accelerators) HAS NEVER BEEN EVALUATED:
+   Mosaic refuses the kernel (``_gather_lowering_rule``: "Shape mismatch
+   in input, indices and output" — on the v5e, PR 21, and in
+   tests/test_tpu_lowering.py), so on a TPU this script stops at the
+   compiler's error before it measures anything, and ``robust_impl``
+   'auto' no longer selects the kernel. On CPU hosts pallas runs in
+   INTERPRETER mode — not the claimed artifact — so cells carry honest
+   per-cell ``fused_loses`` flags instead of a gate (same convention as robust_scale.json's
    crossover cells and sweep.json's CPU floor; as it happens the fused
    form measured a ~2.4× WIN here even interpreted — see the committed
    note). ``BENCH_NO_RANGE_CHECK`` escapes the accelerator gate for

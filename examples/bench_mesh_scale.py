@@ -1,7 +1,8 @@
 """Million-worker mesh evidence (ISSUE 18) -> docs/perf/mesh_scale.json.
 
-Runs under a FORCED 16-device host platform (XLA_FLAGS, set below before
-jax initializes). Four measured claims, each gated:
+A CPU COUNT BENCH: it runs under a FORCED 16-device host platform
+(JAX_PLATFORMS=cpu and XLA_FLAGS, both pinned below before jax
+initializes). Four measured claims, each gated:
 
 1. **1M completion** — N = 1,000,000 ring AND torus runs COMPLETE
    sharded over 16 devices (10× the N=100k headroom worker_mesh.json
@@ -41,8 +42,11 @@ import sys
 from pathlib import Path
 
 # Must precede any jax import, including in spawn-context subprocesses
-# (they re-import this module's top level).
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# (they re-import this module's top level). Pinned, not defaulted: this
+# is a CPU count bench by construction — the parent touches jax and then
+# spawns one child per cell, which a chip (one process at a time) would
+# refuse — so it never takes a TPU even where one is the default.
+os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

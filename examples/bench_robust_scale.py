@@ -113,23 +113,9 @@ def main() -> None:
         )
         k_max = int(topo.degrees.max())
         setups[topo_name] = (cfg, generate_synthetic_dataset(cfg), k_max)
-        from distributed_optimization_tpu.ops.pallas_kernels import (
-            fused_robust_supported,
-        )
-
-        # What production 'auto' actually runs on this cell: these are
-        # static, telemetry-off, meshless configs, so since PR 6 the
-        # gather branch promotes to the fused kernel wherever the rule
-        # fits the sort network (the backend's fused_eligible gate).
-        # This artifact's measurement stays gather-vs-dense — that is
-        # the degree-bounded-crossover claim — and the fused twin's own
-        # evidence is docs/perf/fused_robust.json.
-        fused_ok = fused_robust_supported(cfg.aggregation, k_max)
         cross[topo_name] = {
             "k_max": k_max,
-            "auto_resolves_to": cfg.resolved_robust_impl(
-                k_max, fused_eligible=fused_ok
-            ),
+            "auto_resolves_to": cfg.resolved_robust_impl(k_max),
             "dense_ips": [], "gather_ips": [],
         }
     for c in range(args.cycles):
@@ -164,15 +150,11 @@ def main() -> None:
             f"{rule}: gather must be >= 5x dense at N=256 ring, got {ratio}x"
         )
     # Routing honesty: wherever a form measured >= 25% slower, 'auto' must
-    # not have picked it (a tie within 25% may route either way). Since
-    # PR 6 'auto' may promote the winning gather branch to its fused
-    # single-kernel twin — same degree-bounded math, so the crossover
-    # claim covers both spellings (fused's own floor lives in
-    # fused_robust.json).
+    # not have picked it (a tie within 25% may route either way).
     for topo_name, row in cross.items():
         ratio = row["gather_over_dense"]
         if ratio >= 1.25:
-            assert row["auto_resolves_to"] in ("gather", "fused"), (
+            assert row["auto_resolves_to"] == "gather", (
                 f"{topo_name}: gather wins {ratio}x but auto routes dense"
             )
         elif ratio <= 0.8:

@@ -28,8 +28,9 @@ Asserted floors (same convention as bench.py's published-range gate,
 BENCH_NO_RANGE_CHECK escape hatch included):
 
 - **accelerator platforms** (the canonical latency/dispatch-bound regime
-  this tentpole targets — BENCH_r05 measured the [256, 81] hot loop at
-  ~103k iters/sec with the vector lanes mostly idle): aggregate at R=32
+  this tentpole targets — the 2026-07 headline capture measured the
+  [256, 81] hot loop at ~103k iters/sec with the vector lanes mostly
+  idle): aggregate at R=32
   must be ≥ 8× the sequential single-run baseline.
 - **CPU hosts** (this container: single core, every config compute-bound
   — SIMD lane-filling is the only headroom, measured ~3.5–4.6×):
@@ -225,7 +226,7 @@ def main() -> None:
             "'floors': >= 8x at R=32 on accelerator platforms (the "
             "latency/dispatch-bound regime the tentpole targets — the "
             "chip idles its vector lanes at the [256, 81] hot-loop "
-            "shape, BENCH_r05), >= 2.5x steady on CPU hosts, where this "
+            "shape), >= 2.5x steady on CPU hosts, where this "
             "container's single core makes every config compute-bound "
             "and SIMD lane-filling is the only headroom (measured "
             "3.5-4.6x at R=32; the northstar_n256 cell shows the "

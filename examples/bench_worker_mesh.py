@@ -1,9 +1,13 @@
 """Sharded worker-mesh evidence (ISSUE 11) -> docs/perf/worker_mesh.json.
 
-Runs under a FORCED 4-device host platform (XLA_FLAGS, set below before
-jax initializes) — the same mechanism tests/conftest.py uses — so the
-halo-exchange collectives execute as real multi-device ppermutes on this
-CPU container. Three measured claims, each gated by an assertion:
+A CPU COUNT BENCH: it runs under a FORCED 4-device host platform
+(JAX_PLATFORMS=cpu and XLA_FLAGS, both pinned below before jax
+initializes) — the same mechanism tests/conftest.py uses — so the
+halo-exchange collectives execute as multi-device ppermutes between host
+devices. Its bytes, rows and parity results are counts and stand; its
+iters/sec are CPU rates, not device numbers. The four-chip evidence is
+chip_smoke.py's worker_mesh=4 segment (one process driving four chips).
+Three measured claims, each gated by an assertion:
 
 1. **Parity** — sharded (worker_mesh=4) and unsharded trajectories at
    matched N are BITWISE identical on the final models (ring and ER via
@@ -40,8 +44,11 @@ import sys
 from pathlib import Path
 
 # Must precede any jax import, including in spawn-context subprocesses
-# (they re-import this module's top level).
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# (they re-import this module's top level). Pinned, not defaulted: this
+# is a CPU count bench by construction — the parent touches jax and then
+# spawns one child per cell, which a chip (one process at a time) would
+# refuse — so it never takes a TPU even where one is the default.
+os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

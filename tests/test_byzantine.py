@@ -11,6 +11,7 @@ the independent per-node numpy oracles through real backend runs.
 """
 
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
@@ -26,7 +27,6 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     validate_budget,
 )
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.adversary import (
     byzantine_mask,
     make_adversary,

@@ -2,11 +2,11 @@
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
 
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
-from distributed_optimization_tpu.parallel._compat import shard_map
 from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     make_worker_mesh,

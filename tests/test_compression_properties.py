@@ -22,10 +22,10 @@ module meaningful without it.
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import pytest
 
 from distributed_optimization_tpu.ops.compression import make_compressor
-from distributed_optimization_tpu.parallel._compat import enable_x64
 
 try:
     from hypothesis import given, settings, strategies as st

@@ -9,13 +9,13 @@ closed form.
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
 from distributed_optimization_tpu.backends import jax_backend, numpy_backend
 from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.faults import (
     make_faulty_mixing,
     metropolis_hastings_weights,

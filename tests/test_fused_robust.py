@@ -7,9 +7,9 @@ outputs for the count rules (trimmed mean / median — the in-kernel sort
 network reproduces jnp.sort's values exactly for finite inputs), ≤ 1e-12
 f64 for clipping, through unit calls AND real backend runs composed with
 bursty links + crash-recovery churn + Byzantine injection, plus
-checkpoint/resume exactness. Routing contract: 'auto' promotes to fused
-exactly when eligible (static topology, supported rule, telemetry off,
-no worker mesh), explicit 'fused' is honored beyond the auto gate but
+checkpoint/resume exactness. Routing contract: 'auto' NEVER selects fused
+(Mosaic refuses the kernel — tests/test_tpu_lowering.py — so the default
+path is the gather form on every platform), explicit 'fused' is honored but
 rejected where the kernel cannot run (replica batches, over-wide sort
 networks), and interpret-mode selection respects the input's committed
 platform (the ``_on_cpu`` satellite fix).
@@ -17,6 +17,7 @@ platform (the ``_on_cpu`` satellite fix).
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
@@ -29,7 +30,6 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     robust_aggregate_np,
 )
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.topology import neighbor_table
 
 RULES = ("trimmed_mean", "median", "clipped_gossip")
@@ -287,21 +287,27 @@ def test_fused_resume_exactness(e2e_data, tmp_path):
 
 # ------------------------------------------------------- routing contract
 
-def test_auto_promotes_to_fused_when_eligible(e2e_data):
-    """Static topology + supported rule + telemetry off + no mesh: 'auto'
-    runs the fused kernel — same compiled trajectory as forcing it (and
-    the count-rule path is bitwise, so equality is exact)."""
+def test_auto_never_builds_the_fused_kernel(e2e_data, monkeypatch):
+    """Static topology + supported rule + telemetry off + no mesh — the
+    configuration 'auto' used to promote: it now IS the gather program
+    (bitwise the explicit 'gather' run; the fused builders never called)."""
     ds, f_opt = e2e_data
+
+    def boom(*a, **kw):
+        raise AssertionError("robust_impl='auto' built the fused kernel")
+
+    monkeypatch.setattr(pk, "make_fused_robust_aggregator", boom)
+    monkeypatch.setattr(pk, "make_fused_robust_dsgd_step", boom)
     ra = jax_backend.run(E2E_CFG, ds, f_opt, use_mesh=False)
-    rf = jax_backend.run(
-        E2E_CFG.replace(robust_impl="fused"), ds, f_opt, use_mesh=False
+    rg = jax_backend.run(
+        E2E_CFG.replace(robust_impl="gather"), ds, f_opt, use_mesh=False
     )
-    np.testing.assert_array_equal(ra.final_models, rf.final_models)
+    np.testing.assert_array_equal(ra.final_models, rg.final_models)
 
 
 def test_auto_stays_gather_under_faults_and_telemetry(e2e_data):
-    """The auto gate is conservative: time-varying graphs or an active
-    telemetry activity probe keep the measured gather routing."""
+    """Time-varying graphs and an active telemetry activity probe run the
+    measured gather routing, like every other 'auto' configuration."""
     ds, f_opt = e2e_data
     faulty = E2E_CFG.replace(edge_drop_prob=0.2)
     ra = jax_backend.run(faulty, ds, f_opt, use_mesh=False)
@@ -317,22 +323,20 @@ def test_auto_stays_gather_under_faults_and_telemetry(e2e_data):
     np.testing.assert_array_equal(rt.final_models, rtg.final_models)
 
 
-def test_resolved_robust_impl_fused_gate():
+def test_resolved_robust_impl_never_fused():
     cfg = E2E_CFG
-    assert cfg.resolved_robust_impl(4, fused_eligible=True) == "fused"
-    assert cfg.resolved_robust_impl(4, fused_eligible=False) == "gather"
-    # Fully connected keeps dense regardless of eligibility.
-    assert cfg.resolved_robust_impl(11, fused_eligible=True) == "dense"
-    # Explicit forms are never overridden.
-    assert cfg.replace(robust_impl="gather").resolved_robust_impl(
-        4, fused_eligible=True
-    ) == "gather"
+    assert cfg.resolved_robust_impl(4) == "gather"
+    # Fully connected keeps dense.
+    assert cfg.resolved_robust_impl(11) == "dense"
+    # Explicit forms are never overridden — fused stays an opt-in.
+    assert cfg.replace(robust_impl="fused").resolved_robust_impl(4) == "fused"
+    assert cfg.replace(robust_impl="gather").resolved_robust_impl(4) == "gather"
 
 
 def test_fused_rejects_over_wide_sort_network():
     """Rules whose in-kernel sort would exceed the network width bound
-    are not fused-eligible: explicit 'fused' raises, and
-    fused_robust_supported gates auto. Clipping sorts nothing at a FIXED
+    are not fused-eligible: explicit 'fused' raises. Clipping sorts
+    nothing at a FIXED
     radius (any degree), but the ADAPTIVE radius ranks the [N, k_max]
     norms through the same quadratic network, so it carries the bound
     too."""

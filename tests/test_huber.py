@@ -8,13 +8,13 @@ convergence on all three backends.
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
 from conftest import batch_schedule as _schedule, small_backend_config
 from distributed_optimization_tpu.backends import run_algorithm
 from distributed_optimization_tpu.ops import losses, losses_np
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.utils import (
     compute_reference_optimum,
     generate_synthetic_dataset,

@@ -19,11 +19,11 @@ replicas must reproduce sequential runs of the same stream bitwise.
 
 import numpy as np
 import pytest
+from jax import enable_x64
 
 from distributed_optimization_tpu.backends import jax_backend
 from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.faults import (
     build_fault_timeline,
     make_faulty_mixing,

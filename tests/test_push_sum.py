@@ -21,6 +21,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
@@ -29,7 +30,6 @@ from distributed_optimization_tpu.backends import run_algorithm
 from distributed_optimization_tpu.backends import jax_backend, numpy_backend
 from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.collectives import (
     make_shard_map_mixing_op,
 )

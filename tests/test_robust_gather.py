@@ -13,6 +13,7 @@ rejected where it would be silently ignored.
 """
 
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
@@ -24,7 +25,6 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     robust_aggregate_np,
 )
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.faults import make_faulty_mixing
 from distributed_optimization_tpu.parallel.topology import (
     incident_edge_slots,

@@ -19,6 +19,7 @@ whose gradients are real [b,d]x[d,K] matmuls that tile onto the MXU
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64
 import numpy as np
 import pytest
 
@@ -26,7 +27,6 @@ from conftest import batch_schedule as _schedule, small_backend_config
 from distributed_optimization_tpu.backends import jax_backend, numpy_backend
 from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.models import get_problem
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.ops import losses, losses_np
 from distributed_optimization_tpu.utils.data import (
     generate_digits_dataset,

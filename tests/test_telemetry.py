@@ -368,10 +368,6 @@ PERF_ARTIFACT_KEYS = {
     "fused_robust.json": {
         "bytes_vs_gap", "device", "fused_vs_gather", "gates", "note",
         "platform", "protocol"},
-    "headline_sessions.json": {
-        "metric", "protocol", "published_floor_ratio_vs_numpy",
-        "published_range_ips", "range_derivation", "sessions_t300k",
-        "sessions_t30k_superseded_protocol"},
     "monitors.json": {
         "device", "platform", "protocol", "note", "overhead", "async",
         "divergence", "halt", "gates"},

@@ -17,12 +17,12 @@ tensor parallelism together (parallel/tensor_parallel.py). Pinned here:
 import re
 
 import jax
+from jax import enable_x64
 import numpy as np
 import pytest
 
 from conftest import small_backend_config
 from distributed_optimization_tpu.backends import jax_backend, numpy_backend
-from distributed_optimization_tpu.parallel._compat import enable_x64
 from distributed_optimization_tpu.parallel.tensor_parallel import (
     build_tp_softmax_dsgd,
     make_dp_tp_mesh,

@@ -1,0 +1,101 @@
+"""What tier-1 (CPU) can pin about the program the chip compiles.
+
+The sandbox has no accelerator, but two things about the on-chip program
+are checkable here: (1) whether each Pallas kernel LOWERS for the TPU
+(``jax.export`` runs the Mosaic lowering rules without a device; whether
+Mosaic then compiles the result is for a chip run — CHANGES.md PR 21 lists
+it kernel by kernel), and (2) that the program form ``auto`` picks on an
+accelerator — dense sampling, scan unroll 8 — computes the same run as the
+CPU default (gather sampling, unroll 1).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import export
+
+from distributed_optimization_tpu.backends import jax_backend
+from distributed_optimization_tpu.config import ExperimentConfig
+from distributed_optimization_tpu.ops import pallas_kernels as pk
+from distributed_optimization_tpu.parallel import build_topology
+from distributed_optimization_tpu.parallel.topology import neighbor_table
+
+# The headline model stack: whole-array VMEM blocks, 81 is not a multiple
+# of the 128-lane tile.
+N, D = 256, 81
+X = jax.ShapeDtypeStruct((N, D), jnp.float32)
+
+
+def _lower_for_tpu(fn, *args):
+    return export.export(jax.jit(fn), platforms=["tpu"])(*args)
+
+
+@pytest.mark.parametrize("kernel", [
+    pk.ring_mix, pk.fc_mix, pk.ring_neighbor_sum, pk.fc_neighbor_sum,
+])
+def test_mixing_kernels_lower_for_tpu(kernel):
+    """mixing_impl='pallas' (explicit opt-in) reaches these four."""
+    exported = _lower_for_tpu(lambda x: kernel(x, interpret=False), X)
+    assert exported.platforms == ("tpu",)
+
+
+def test_fused_ring_dsgd_step_lowers_for_tpu():
+    exported = _lower_for_tpu(
+        lambda x, g: pk.fused_ring_dsgd_step(x, g, 0.05, interpret=False), X, X
+    )
+    assert exported.platforms == ("tpu",)
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="Mosaic's _gather_lowering_rule rejects the in-kernel "
+           "jnp.take(xa, nbr, axis=0): 'Shape mismatch in input, indices "
+           "and output'. robust_impl='auto' therefore never selects "
+           "'fused'; when this starts passing, ROADMAP A4/C3 can re-open.",
+)
+@pytest.mark.parametrize("with_sgd", [False, True])
+@pytest.mark.parametrize("rule", ["trimmed_mean", "median", "clipped_gossip"])
+def test_fused_robust_kernel_lowers_for_tpu(rule, with_sgd):
+    nbr_idx, nbr_mask = neighbor_table(build_topology("ring", N).adjacency)
+    live = jax.ShapeDtypeStruct(nbr_mask.shape, jnp.float32)
+    if with_sgd:
+        step = pk.make_fused_robust_dsgd_step(rule, 1, nbr_idx, interpret=False)
+        _lower_for_tpu(lambda lv, x, g: step(lv, x, g, 0.05), live, X, X)
+    else:
+        agg = pk.make_fused_robust_aggregator(rule, 1, nbr_idx, interpret=False)
+        _lower_for_tpu(agg, live, X)
+
+
+def test_accelerator_program_form_matches_cpu_default():
+    """Off CPU, ``auto`` resolves sampling to 'dense' (shards <= 64 rows)
+    and the scan unroll to 8; on CPU to 'gather' and 1. Forced together at
+    a tiny headline shape (logistic D-SGD ring, L=49, eval_every=1) the two
+    forms draw the same batches and differ only in f32 summation order:
+    measured 9e-8 on models of order 0.2 over 400 steps, bound at 1e-5 — a
+    different batch subset or a skipped step is orders of magnitude above."""
+    from distributed_optimization_tpu.utils.data import generate_synthetic_dataset
+    from distributed_optimization_tpu.utils.oracle import compute_reference_optimum
+
+    cfg = ExperimentConfig(
+        problem_type="logistic", algorithm="dsgd", topology="ring",
+        n_workers=16, n_samples=16 * 49, n_features=20,
+        n_informative_features=12, n_iterations=400, eval_every=1,
+    )
+    assert cfg.resolved_sampling_impl("cpu", 49) == "gather"
+    assert cfg.resolved_sampling_impl("tpu", 49) == "dense"
+    assert (cfg.resolved_scan_unroll("cpu"), cfg.resolved_scan_unroll("tpu")) == (1, 8)
+    ds = generate_synthetic_dataset(cfg)
+    _, f_opt = compute_reference_optimum(ds, cfg.reg_param)
+    cpu_form = jax_backend.run(cfg, ds, f_opt, use_mesh=False)
+    chip_form = jax_backend.run(
+        cfg.replace(sampling_impl="dense", scan_unroll=8), ds, f_opt,
+        use_mesh=False,
+    )
+    np.testing.assert_allclose(
+        chip_form.final_models, cpu_form.final_models, rtol=0, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        chip_form.history.objective, cpu_form.history.objective,
+        rtol=0, atol=1e-5,
+    )
