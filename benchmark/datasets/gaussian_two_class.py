@@ -1,0 +1,27 @@
+"""Binary labels in {-1, +1}; one unit-variance Gaussian cluster per class,
+``class_sep`` apart along a seeded direction over the informative
+features; ``flip_y`` of the rows carry the other class's label. Rows are
+sorted by label before they are cut into shards (the reference study's
+non-IID partition), so each shard holds one label."""
+
+import numpy as np
+
+from benchmark.datasets import features_with_bias
+
+
+def generate(spec, exp, seed_seq):
+    L = int(spec["rows_per_worker"])
+    n_features = int(exp["n_features"])
+    n = int(exp["n_workers"]) * L
+    feat_seq, dir_seq, flip_seq = seed_seq.spawn(3)
+    y = np.where(np.arange(n) < n // 2, -1.0, 1.0).astype(np.float32)
+    rng = np.random.default_rng(dir_seq)
+    direction = np.zeros(n_features, dtype=np.float32)
+    k = int(exp["n_informative_features"])
+    direction[:k] = rng.standard_normal(k)
+    direction *= float(spec["class_sep"]) / np.linalg.norm(direction)
+    cluster = y.copy()
+    flips = np.random.default_rng(flip_seq).random(n) < float(spec["flip_y"])
+    cluster[flips] *= -1.0
+    X = features_with_bias(n, n_features, feat_seq, cluster, direction)
+    return X, y, L
