@@ -20,6 +20,7 @@ loops end to end.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -38,6 +39,7 @@ from distributed_optimization_tpu.metrics import (
     decentralized_floats_per_iteration,
 )
 from distributed_optimization_tpu.models import get_problem
+from distributed_optimization_tpu.observability.spans import current_tracer
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
 from distributed_optimization_tpu.ops.sampling import (
     sample_worker_batch_weights,
@@ -818,7 +820,8 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
 
 def _run_chunked(
     chunk, state0, data_args, checkpoint, mesh, config, n_evals,
-    measure_compile, progress_hook=None, progress_every=1, halt_check=None,
+    measure_compile, spans, progress_hook=None, progress_every=1,
+    halt_check=None,
 ):
     """Host-driven chunk loop: measured per-eval timestamps, optional orbax
     checkpointing (``checkpoint=None`` runs the loop purely for timing).
@@ -856,12 +859,14 @@ def _run_chunked(
             ckptr.reset(config)
     ts_row0 = _replicate(mesh, jnp.arange(eval_every, dtype=jnp.int32))
 
+    spans.enter("compile")
     t0 = time.perf_counter()
     with jax.default_matmul_precision(config.matmul_precision):
         lowered = jax.jit(chunk).lower(state0, ts_row0, data_args)
         cost = cost_from_lowered(lowered) if config.telemetry else None
         compiled = lowered.compile()
     compile_seconds = time.perf_counter() - t0 if measure_compile else 0.0
+    spans.enter("prepare")
 
     state = state0
     gap_list: list[float] = []
@@ -888,6 +893,9 @@ def _run_chunked(
 
     # Cumulative-time offset from previous installments of a resumed run.
     time_offset = time_list[-1] if time_list else 0.0
+    spans.enter("upload_wait")
+    jax.block_until_ready((state, data_args))
+    spans.enter("scan")
     t1 = time.perf_counter()
     save_seconds = 0.0  # cumulative orbax-save time, excluded from stamps
     done = start_chunk
@@ -938,6 +946,7 @@ def _run_chunked(
             # remaining chunks just never execute.
             break
     run_seconds = time.perf_counter() - t1 - save_seconds
+    spans.enter("harvest")
 
     gap_hist = np.asarray(gap_list, dtype=np.float64)
     cons_hist = np.asarray(cons_list, dtype=np.float64) if cons_list else None
@@ -954,7 +963,7 @@ def _run_chunked(
 
 def _run_segmented_fused(
     make_seg_scan, harvest, state0, data_args, checkpoint, mesh, config,
-    n_evals, measure_compile, *, progress_hook=None, progress_every=1,
+    n_evals, measure_compile, spans, *, progress_hook=None, progress_every=1,
     exec_cache=None, cache_key_fn=None, halt_check=None,
 ):
     """Segmented execution of the flat fused scan (round 4 — VERDICT r3
@@ -1055,6 +1064,7 @@ def _run_segmented_fused(
     cold_compile = 0.0
     with jax.default_matmul_precision(config.matmul_precision):
         for size in sorted(sizes):
+            spans.enter("cache_lookup")
             key = cache_key_fn(size) if (
                 exec_cache is not None and cache_key_fn is not None
             ) else None
@@ -1064,6 +1074,9 @@ def _run_segmented_fused(
                 if config.telemetry and cost is None:
                     cost = cached.cost
                 continue
+            spans.enter("compile")
+            if key is not None:
+                spans.note_root(cache="miss")
             t_cold = time.perf_counter()
             lowered = jax.jit(make_seg_scan(size)).lower(
                 state, t0_probe, data_args
@@ -1084,6 +1097,10 @@ def _run_segmented_fused(
     compile_seconds = cold_compile if measure_compile else 0.0
 
     time_offset = time_list[-1] if time_list else 0.0
+    spans.enter("upload_wait")
+    jax.block_until_ready((state, data_args))
+    # The segments' own harvests and saves fall inside this span.
+    spans.enter("scan")
     t1 = time.perf_counter()
     save_seconds = 0.0  # cumulative orbax-save time, excluded from stamps
     done = start_chunk
@@ -1135,6 +1152,7 @@ def _run_segmented_fused(
             # contract); the remaining segments never execute.
             break
     run_seconds = time.perf_counter() - t1 - save_seconds
+    spans.enter("harvest")
 
     gap_hist = np.asarray(gap_list, dtype=np.float64) if gap_list else None
     cons_hist = np.asarray(cons_list, dtype=np.float64) if cons_list else None
@@ -1147,6 +1165,60 @@ def _run_segmented_fused(
     )
     return (state, gap_hist, cons_hist, time_hist, realized_floats,
             executed_iters, compile_seconds, run_seconds, trace, cost)
+
+
+class _RunSpans:
+    """The spans of one ``_run`` call (docs/OBSERVABILITY.md, "Span
+    tracing"): the ``dopt.run`` root and, under it, one open child at a
+    time — ``enter(name)`` closes the open child and opens
+    ``dopt.run.<name>``, so the children are disjoint, in order, and leave
+    the root next to no time of its own. None aggregates into the tracer's
+    flat ``phases`` table."""
+
+    def __init__(self, tracer, root: dict):
+        self._tracer = tracer
+        self._root = root
+        self._open = None
+        self._event = None
+
+    def enter(self, name: str, **args) -> dict:
+        """Open ``dopt.run.<name>`` (closing the open child); returns its
+        event, whose ``duration`` is set once the next ``enter`` or
+        ``close`` has closed it."""
+        self.close()
+        self._open = self._tracer.span(
+            "dopt.run." + name, aggregate=False, **args
+        )
+        self._event = self._open.__enter__()
+        return self._event
+
+    def close(self) -> None:
+        if self._open is not None:
+            span, self._open = self._open, None
+            span.__exit__(None, None, None)
+
+    def note(self, **args) -> None:
+        """Add arguments to the open child: counts known only after the
+        work it names."""
+        self._event.setdefault("args", {}).update(args)
+
+    def note_root(self, **args) -> None:
+        self._root.setdefault("args", {}).update(args)
+
+
+@contextlib.contextmanager
+def _run_spans():
+    """The root span of one ``_run`` call on ``current_tracer()``: under the
+    caller's open span if the caller activated a tracer of its own
+    (``Simulator.run_one``, a serving plan), else a root of the process
+    tracer."""
+    tracer = current_tracer()
+    with tracer.span("dopt.run", aggregate=False) as root:
+        spans = _RunSpans(tracer, root)
+        try:
+            yield spans
+        finally:
+            spans.close()
 
 
 def run(
@@ -1256,9 +1328,9 @@ def run(
             progress_cb=progress_cb, progress_every=progress_every,
             monitors=monitors, checkpoint=checkpoint,
         )
-    with x64_scope(config):
+    with x64_scope(config), _run_spans() as spans:
         return _run(
-            config, dataset, f_opt, mesh=mesh, use_mesh=use_mesh,
+            config, dataset, f_opt, spans, mesh=mesh, use_mesh=use_mesh,
             batch_schedule=batch_schedule, collect_metrics=collect_metrics,
             measure_compile=measure_compile, checkpoint=checkpoint,
             measure_timestamps=measure_timestamps,
@@ -1321,6 +1393,7 @@ def _run(
     config,
     dataset: HostDataset,
     f_opt: float,
+    spans: _RunSpans,
     *,
     mesh=None,
     use_mesh: bool = True,
@@ -1348,7 +1421,10 @@ def _run(
     save (and resume) between segments — add ``measure_timestamps=True`` to
     instead use the host-driven chunk loop with real per-eval timestamps,
     at its measured 2.2× coarse-cadence cost (docs/PERF.md §root-cause).
+    ``spans``: the call's ``dopt.run`` root (``_run_spans``); each stretch
+    of this function runs under the child span that names it.
     """
+    spans.enter("prepare")
     if config.telemetry and checkpoint is not None:
         raise ValueError(
             "telemetry trace buffers are not checkpointed: a resumed run "
@@ -1378,7 +1454,10 @@ def _run(
     T = config.n_iterations
     n = config.n_workers
 
+    spans.enter("stack_shards")
     device_data = stack_shards(dataset, dtype=np.dtype(config.dtype))
+    spans.note(bytes=device_data.X.nbytes + device_data.y.nbytes)
+    spans.enter("prepare")
     # The trained parameter dimension: n_features for the scalar GLMs,
     # n_features·K for softmax (flattened [d, K] matrix). Everything the
     # model vector touches — state init, gossip payload accounting, the
@@ -1596,9 +1675,17 @@ def _run(
             mesh = make_worker_mesh(n)
 
     # --- device placement (sharded over the worker axis where it matters) ---
+    # ``upload`` is the enqueue; the wait for the copy is ``upload_wait``,
+    # directly before the scan's clock starts.
+    spans.enter(
+        "upload",
+        bytes=device_data.X.nbytes + device_data.y.nbytes
+        + device_data.n_valid.nbytes,
+    )
     X = shard_over_workers(mesh, jnp.asarray(device_data.X))
     y = shard_over_workers(mesh, jnp.asarray(device_data.y))
     n_valid = shard_over_workers(mesh, jnp.asarray(device_data.n_valid))
+    spans.enter("prepare")
     x0 = shard_over_workers(
         mesh, jnp.zeros((n, d_model), dtype=device_data.X.dtype)
     )
@@ -1625,7 +1712,9 @@ def _run(
 
     schedule = None
     if batch_schedule is not None:
+        spans.enter("upload", bytes=4 * int(np.size(batch_schedule)))
         schedule = replicate(mesh, jnp.asarray(batch_schedule, dtype=jnp.int32))
+        spans.enter("prepare")
 
     full_objective = make_full_objective_fn(problem, reg)
     eta_fn = _make_eta_fn(config)
@@ -1938,7 +2027,11 @@ def _run(
             # re-executing its compiled program is bitwise the same.
             exec_cache = resolve_cache(executable_cache)
             cache_key = cached = None
+            spans.note_root(
+                path="fused", cache="off" if exec_cache is None else "miss"
+            )
             if exec_cache is not None:
+                spans.enter("cache_lookup")
                 cache_key = sequential_cache_key(
                     config, f_opt, device_data,
                     schedule_signature=(
@@ -1955,6 +2048,7 @@ def _run(
                 )
                 cached = exec_cache.get(cache_key)
             if cached is not None:
+                spans.note_root(cache="hit")
                 compiled = cached.executable
                 cost = cached.cost if config.telemetry else None
                 compile_seconds = 0.0
@@ -1962,6 +2056,7 @@ def _run(
                 # AOT compile so compile time and steady-state execution
                 # are separable (jax.profiler-style phase split, SURVEY.md
                 # §5.1).
+                spans.enter("compile")
                 t0 = time.perf_counter()
                 with jax.default_matmul_precision(config.matmul_precision):
                     lowered = jax.jit(run_scan).lower(state0, data_args)
@@ -1978,10 +2073,18 @@ def _run(
                         compile_seconds=cold_seconds,
                     )
 
-            t1 = time.perf_counter()
+            # The shards' copy drains here, after all host preparation and
+            # outside the scan's clock: the program could not start before
+            # its inputs were resident anyway, so the call is no longer for
+            # it, but ``run_seconds`` (and so ``iters_per_second``) no
+            # longer counts the copy.
+            spans.enter("upload_wait")
+            jax.block_until_ready((state0, data_args))
+            scan = spans.enter("scan")
             final_state, ys = compiled(state0, data_args)
             final_state = jax.block_until_ready(final_state)
-            run_seconds = time.perf_counter() - t1
+            spans.enter("harvest")
+            run_seconds = scan["duration"]
             executed_iters = T
 
             gap_hist, cons_hist, floats_per_eval, trace = _harvest(
@@ -2011,6 +2114,9 @@ def _run(
                 resolve_cache(executable_cache) if checkpoint is None
                 else None
             )
+            spans.note_root(
+                path="segmented", cache="off" if seg_cache is None else "hit"
+            )
             cache_key_fn = None
             if seg_cache is not None:
                 mesh_sig = (
@@ -2037,7 +2143,7 @@ def _run(
              executed_iters, compile_seconds, run_seconds, trace, cost) = (
                 _run_segmented_fused(
                     make_seg_scan, _harvest, state0, data_args, checkpoint,
-                    mesh, config, n_evals, measure_compile,
+                    mesh, config, n_evals, measure_compile, spans,
                     progress_hook=progress_emit,
                     progress_every=progress_every,
                     exec_cache=seg_cache, cache_key_fn=cache_key_fn,
@@ -2053,11 +2159,13 @@ def _run(
         def chunk_fn(state, ts, data):
             return make_chunk(data)(state, ts)
 
+        spans.note_root(path="chunked", cache="off")
+
         (final_state, gap_hist, cons_hist, time_hist, realized_floats,
          executed_iters, compile_seconds, run_seconds, trace, cost) = (
             _run_chunked(
                 chunk_fn, state0, data_args, checkpoint, mesh, config,
-                n_evals, measure_compile, progress_hook=progress_emit,
+                n_evals, measure_compile, spans, progress_hook=progress_emit,
                 progress_every=progress_every, halt_check=halt_check,
             )
         )
@@ -2092,6 +2200,7 @@ def _run(
     )
     x_final = final_state["x"]
     mesh_devices = n // x_final.sharding.shard_shape(x_final.shape)[0]
+    spans.note(bytes=x_final.nbytes)
     final_models = _fetch_to_host(x_final).astype(np.float64)
     # The reported model under attack is the HONEST average — Byzantine
     # rows are adversary-controlled state, not part of the solution.

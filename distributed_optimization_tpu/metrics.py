@@ -61,6 +61,13 @@ class RunHistory:
     # confused, and nothing in the config says what the auto-mesh chose.
     mesh_devices: int = 1
 
+    @property
+    def run_seconds(self) -> float:
+        """The backend's own clock around the run, the last entry of
+        ``time`` (on the sequential jax path the ``dopt.run.scan`` span's
+        interval): compile, stacking, upload and harvest are not in it."""
+        return float(self.time[-1]) if len(self.time) else 0.0
+
     def as_dict(self) -> dict:
         out = {
             "objective": self.objective.tolist(),

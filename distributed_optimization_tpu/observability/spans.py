@@ -27,18 +27,44 @@ trace-event JSON export viewable in chrome://tracing or Perfetto.
 ``utils/profiling.PhaseTimer`` is now an alias of ``Tracer``, so every
 bench script's existing ``PhaseTimer()`` transparently records spans and
 its manifest sidecar gains the span tree for free.
+
+Code that is handed no tracer (the run builder, ``jax_backend._run``)
+records into ``current_tracer()``: the tracer a caller made current with
+``with tracer.activate():`` and otherwise ``process_tracer()``, the
+process-wide default, which keeps the spans of its last
+``PROCESS_TRACER_ROOTS`` root spans only. While a ``jax.profiler`` trace is
+being collected every live span is also a ``TraceAnnotation``: an event of
+the same name on a host plane of the ``.xplane.pb``, on the clock the
+device planes use.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
 from typing import Iterator, Optional
 
-import contextlib
+# Root spans whose events the process-wide tracer keeps (older ones are
+# dropped whole, children included).
+PROCESS_TRACER_ROOTS = 64
+
+
+def _trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, or a no-op where jax has not
+    been imported (then no profiler session can be live either, and this
+    module stays importable without jax). With no session the annotation
+    is a flag test."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name)
 
 
 class Tracer:
@@ -46,9 +72,16 @@ class Tracer:
 
     Thread-safe: span completion appends under a lock; the per-thread
     open-span stack lives in a ``threading.local``.
+
+    ``max_roots`` bounds the event buffer: once more than that many root
+    spans (spans with no parent) have completed, the oldest root's events
+    are dropped, its children with it — what a long-lived process-wide
+    tracer needs. ``None`` keeps everything.
     """
 
-    def __init__(self, phases: Optional[dict] = None):
+    def __init__(
+        self, phases: Optional[dict] = None, max_roots: Optional[int] = None
+    ):
         # Aggregate seconds by span name — the PhaseTimer-compatible
         # surface. A plain dict on purpose: callers assign into it.
         self.phases: dict[str, float] = dict(phases or {})
@@ -56,6 +89,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._next_id = 0
+        self._max_roots = max_roots
+        self._roots: collections.deque = collections.deque()
         # Epoch anchor so timestamps from perf_counter are absolute-ish
         # and comparable across tracers in one process.
         self._t0_wall = time.time() - time.perf_counter()
@@ -68,56 +103,61 @@ class Tracer:
             self._tls.stack = st
         return st
 
-    def _record(self, name, start, duration, parent_id, args, aggregate):
-        with self._lock:
-            self._next_id += 1
-            ev = {
-                "id": self._next_id,
-                "name": name,
-                "start": start,  # perf_counter seconds
-                "duration": duration,
-                "parent": parent_id,
-                "thread": threading.current_thread().name,
-            }
-            if args:
-                ev["args"] = dict(args)
-            self._events.append(ev)
-            if aggregate:
-                self.phases[name] = self.phases.get(name, 0.0) + duration
-            return ev
-
-    @contextlib.contextmanager
-    def span(self, name: str, aggregate: bool = True, **args) -> Iterator[None]:
-        """Time a live interval; nests under the thread's open span.
-        ``aggregate=False`` records the span without folding its duration
-        into ``phases`` — for grouping spans (a request, a labeled run)
-        whose children already account the same seconds."""
+    def _new_event(self, name, args) -> dict:
+        """An open event under the thread's open span (ids are handed out
+        at entry, so a parent's id is below its children's)."""
         stack = self._stack()
-        parent_id = stack[-1] if stack else None
         with self._lock:
             self._next_id += 1
             span_id = self._next_id
-        stack.append(span_id)
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            duration = time.perf_counter() - start
-            stack.pop()
-            with self._lock:
-                ev = {
-                    "id": span_id,
-                    "name": name,
-                    "start": start,
-                    "duration": duration,
-                    "parent": parent_id,
-                    "thread": threading.current_thread().name,
-                }
-                if args:
-                    ev["args"] = dict(args)
-                self._events.append(ev)
-                if aggregate:
-                    self.phases[name] = self.phases.get(name, 0.0) + duration
+        ev = {
+            "id": span_id,
+            "name": name,
+            "parent": stack[-1]["id"] if stack else None,
+            "root": stack[0]["id"] if stack else span_id,
+            "thread": threading.current_thread().name,
+        }
+        if args:
+            ev["args"] = dict(args)
+        return ev
+
+    def _record(self, ev, aggregate):
+        with self._lock:
+            self._events.append(ev)
+            if aggregate:
+                self.phases[ev["name"]] = (
+                    self.phases.get(ev["name"], 0.0) + ev["duration"]
+                )
+            if self._max_roots is not None and ev["parent"] is None:
+                self._roots.append(ev["id"])
+                if len(self._roots) > self._max_roots:
+                    old = self._roots.popleft()
+                    self._events = [
+                        e for e in self._events if e["root"] != old
+                    ]
+
+    @contextlib.contextmanager
+    def span(self, name: str, aggregate: bool = True, **args) -> Iterator[dict]:
+        """Time a live interval; nests under the thread's open span.
+        ``aggregate=False`` records the span without folding its duration
+        into ``phases`` — for grouping spans (a request, a labeled run)
+        whose children already account the same seconds.
+
+        Yields the span's event: ``start`` is set on entry and ``duration``
+        on exit (so a caller that needs the interval reads the one clock
+        the span read), and ``args`` may be added to until the span
+        closes."""
+        ev = self._new_event(name, args)
+        stack = self._stack()
+        stack.append(ev)
+        with _trace_annotation(name):
+            ev["start"] = time.perf_counter()  # perf_counter seconds
+            try:
+                yield ev
+            finally:
+                ev["duration"] = time.perf_counter() - ev["start"]
+                stack.pop()
+                self._record(ev, aggregate)
 
     # PhaseTimer compatibility: same name, same semantics, now a span.
     phase = span
@@ -134,11 +174,23 @@ class Tracer:
         """Record an interval measured elsewhere (e.g. the backend's AOT
         compile seconds) as a child of the thread's current open span.
         ``start`` defaults to "it just ended" (now − seconds)."""
-        stack = self._stack()
-        parent_id = stack[-1] if stack else None
-        if start is None:
-            start = time.perf_counter() - seconds
-        self._record(name, start, float(seconds), parent_id, args, aggregate)
+        ev = self._new_event(name, args)
+        ev["duration"] = float(seconds)
+        ev["start"] = (
+            time.perf_counter() - ev["duration"] if start is None else start
+        )
+        self._record(ev, aggregate)
+
+    @contextlib.contextmanager
+    def activate(self) -> Iterator["Tracer"]:
+        """Make this the tracer ``current_tracer()`` returns in the calling
+        context, so code that is handed no tracer (the run builder) nests
+        its spans under the caller's open span."""
+        token = _CURRENT.set(self)
+        try:
+            yield self
+        finally:
+            _CURRENT.reset(token)
 
     # ------------------------------------------------------------ reading
     def spans(self) -> list[dict]:
@@ -204,3 +256,23 @@ class Tracer:
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(json.dumps(self.to_chrome_trace()) + "\n")
         return p
+
+
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "dopt_current_tracer", default=None
+)
+_PROCESS_TRACER = Tracer(max_roots=PROCESS_TRACER_ROOTS)
+
+
+def process_tracer() -> Tracer:
+    """The process-wide default tracer (as ``metrics_registry()`` is the
+    process-wide registry). Bounded: it holds the spans of the last
+    ``PROCESS_TRACER_ROOTS`` root spans, so a serving daemon or a sweep of
+    a million runs keeps a fixed few hundred events."""
+    return _PROCESS_TRACER
+
+
+def current_tracer() -> Tracer:
+    """The tracer made current by ``Tracer.activate`` in this context, else
+    the process default."""
+    return _CURRENT.get() or _PROCESS_TRACER

@@ -798,12 +798,13 @@ class SimulationService:
                        "coalesced": plan.coalesced},
             ))
         progress_factory, cohort_cb, banks = self._plan_progress(plan)
-        # Per-plan span tree (request → cohort → compile/run → the
-        # backend's chunks): embedded in each member's manifest and
-        # aggregated into the service tracer's flat phases.
+        # Per-plan span tree (request → cohort → the backend's
+        # ``dopt.run.*`` spans, which an in-process plan records here
+        # because the tracer is activated): embedded in each member's
+        # manifest; its flat phases are folded into the service tracer's.
         plan_tracer = Tracer()
         try:
-            with plan_tracer.span(
+            with plan_tracer.activate(), plan_tracer.span(
                 "cohort", aggregate=False, size=plan.size,
                 coalesced=plan.coalesced,
                 structural_hash=plan.base.structural_hash(),
@@ -844,13 +845,17 @@ class SimulationService:
                         progress_every=self.options.progress_every,
                     )
                 wall = time.perf_counter() - t_start
-                compile_s = min(
-                    results[0].history.compile_seconds, wall
-                ) if results else 0.0
-                plan_tracer.add_span("compile", compile_s, start=t_start)
-                plan_tracer.add_span(
-                    "run", wall - compile_s, start=t_start + compile_s
-                )
+            # The flat ``compile`` and ``run`` rows are the backend's own
+            # clocks of the programs this plan ran (a coalesced plan runs
+            # one, a sequential plan one a request), not the wall.
+            ran = (
+                results if plan.sequential_reason is not None
+                else results[:1]
+            )
+            plan_tracer.phases.update(
+                compile=sum(r.history.compile_seconds for r in ran),
+                run=sum(r.history.run_seconds for r in ran),
+            )
         except Exception as e:  # isolate the poison plan, keep serving
             msg = f"{type(e).__name__}: {e}"
             _log.warning("plan of %d request(s) failed: %s", plan.size, msg)

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from pathlib import Path
 from typing import Optional
 
@@ -174,11 +173,12 @@ class Simulator:
             )
         batch = None
         stats = None
-        t_run = time.perf_counter()
-        # The labeled span groups this run's compile/run children in the
-        # Chrome trace (aggregate=False: the children already account the
-        # same seconds in the flat phase table).
-        with self.phase_timer.span(f"run_one:{label}", aggregate=False):
+        # The labeled span groups this run in the Chrome trace. Activated,
+        # the tracer is where the backend records its own ``dopt.run.*``
+        # spans, under this span and outside the flat table.
+        with self.phase_timer.activate(), self.phase_timer.span(
+            f"run_one:{label}", aggregate=False
+        ):
             if replicated:
                 # One vmapped program runs every replica (ISSUE-4): the
                 # record keeps replica 0 as the representative trajectory
@@ -197,27 +197,23 @@ class Simulator:
                 )
             else:
                 result = run_algorithm(cfg, self.dataset, self.f_opt, **kwargs)
-            total_seconds = time.perf_counter() - t_run
-            # Phase split: compile is measured inside the backend (AOT
-            # lowering); the remainder of the wall-clock around the call is
-            # the run phase. add_span records both as children of the
-            # labeled span AND folds them into the flat phase table.
-            compile_seconds = min(result.history.compile_seconds, total_seconds)
-            self.phase_timer.add_span(
-                "compile", compile_seconds, start=t_run
-            )
-            self.phase_timer.add_span(
-                "run", total_seconds - compile_seconds,
-                start=t_run + compile_seconds,
+        # The flat table's ``compile`` and ``run`` rows are the backend's
+        # own two clocks (on the sequential jax path the ``dopt.run.compile``
+        # and ``dopt.run.scan`` spans' intervals), not the wall around the
+        # call: stacking, upload and harvest are spans of the tree only.
+        phases = {
+            "compile": result.history.compile_seconds,
+            "run": result.history.run_seconds,
+        }
+        for name, seconds in phases.items():
+            self.phase_timer.phases[name] = (
+                self.phase_timer.phases.get(name, 0.0) + seconds
             )
         from distributed_optimization_tpu.observability.metrics_registry import (
             observe_phases,
         )
 
-        observe_phases({
-            "compile": compile_seconds,
-            "run": total_seconds - compile_seconds,
-        })
+        observe_phases(phases)
         summary = summarize_run(
             label,
             result.history,

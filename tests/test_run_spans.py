@@ -1,0 +1,270 @@
+"""Spans inside the run builder (ISSUE 24): one ``dopt.run`` root a call of
+``jax_backend._run`` with a child around each stretch it names, recorded in
+the tracer the caller made current (else the bounded process tracer), none
+of them a row of the flat phase table; ``--profile-dir`` traces with the
+Python tracer off.
+CPU, small N and T: what is checked is structure and counts, never a time.
+"""
+
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+from conftest import small_backend_config
+
+from distributed_optimization_tpu.backends import jax_backend
+from distributed_optimization_tpu.observability import spans as spans_mod
+from distributed_optimization_tpu.observability.spans import (
+    PROCESS_TRACER_ROOTS,
+    Tracer,
+    current_tracer,
+    process_tracer,
+)
+from distributed_optimization_tpu.serving.cache import ExecutableCache
+from distributed_optimization_tpu.simulator import Simulator
+from distributed_optimization_tpu.utils.data import (
+    generate_synthetic_dataset,
+    stack_shards,
+)
+
+CHILDREN_COLD = [
+    "prepare", "stack_shards", "prepare", "upload", "prepare",
+    "cache_lookup", "compile", "upload_wait", "scan", "harvest",
+]
+CHILDREN_WARM = [c for c in CHILDREN_COLD if c != "compile"]
+
+
+@pytest.fixture(scope="module")
+def setup():
+    cfg = small_backend_config(n_iterations=40, eval_every=10)
+    return cfg, generate_synthetic_dataset(cfg)
+
+
+def run_under(tracer, cfg, ds, **kw):
+    """One call recorded in ``tracer``; returns (result, root, children in
+    order of their start)."""
+    with tracer.activate():
+        result = jax_backend.run(cfg, ds, 0.0, **kw)
+    events = tracer.spans()
+    roots = [e for e in events if e["name"] == "dopt.run"]
+    children = sorted(
+        (e for e in events if e["parent"] == roots[-1]["id"]),
+        key=lambda e: e["start"],
+    )
+    return result, roots, children
+
+
+def names(children):
+    return [e["name"].removeprefix("dopt.run.") for e in children]
+
+
+def test_one_root_with_the_named_children_in_order(setup):
+    cfg, ds = setup
+    tracer = Tracer()
+    _, roots, children = run_under(
+        tracer, cfg, ds, executable_cache=ExecutableCache()
+    )
+    assert len(roots) == 1
+    root = roots[0]
+    assert names(children) == CHILDREN_COLD
+    assert all(e["name"].startswith("dopt.run.") for e in children)
+    # Disjoint, in order, inside the root; their sum is at most the root.
+    end = root["start"]
+    for e in children:
+        assert e["start"] >= end
+        end = e["start"] + e["duration"]
+    assert end <= root["start"] + root["duration"]
+    assert sum(e["duration"] for e in children) <= root["duration"]
+    assert root["args"] == {"path": "fused", "cache": "miss"}
+    by_name = {e["name"]: e for e in children}
+    stacked = stack_shards(ds, dtype=np.float32)
+    assert by_name["dopt.run.stack_shards"]["args"]["bytes"] == (
+        stacked.X.nbytes + stacked.y.nbytes
+    )
+    assert by_name["dopt.run.upload"]["args"]["bytes"] == (
+        stacked.X.nbytes + stacked.y.nbytes + stacked.n_valid.nbytes
+    )
+    assert "args" not in by_name["dopt.run.scan"]
+    assert by_name["dopt.run.harvest"]["args"]["bytes"] == (
+        cfg.n_workers * stacked.X.shape[2] * 4
+    )
+    # None of them is a row of the flat phase table.
+    assert tracer.phases == {}
+
+
+def test_second_identical_call_hits_the_cache_and_does_not_compile(setup):
+    cfg, ds = setup
+    tracer, cache = Tracer(), ExecutableCache()
+    first, roots, _ = run_under(tracer, cfg, ds, executable_cache=cache)
+    second, roots, children = run_under(tracer, cfg, ds, executable_cache=cache)
+    assert [r["args"]["cache"] for r in roots] == ["miss", "hit"]
+    assert roots[1]["id"] > roots[0]["id"]
+    assert names(children) == CHILDREN_WARM
+    np.testing.assert_array_equal(first.final_models, second.final_models)
+    off, roots, children = run_under(tracer, cfg, ds, executable_cache=False)
+    assert roots[-1]["args"]["cache"] == "off"
+    assert names(children) == [c for c in CHILDREN_COLD if c != "cache_lookup"]
+
+
+@pytest.mark.parametrize("path", ["segmented", "chunked"])
+def test_outputs_bitwise_under_another_tracer_and_path(setup, path):
+    cfg, ds = setup
+    base = jax_backend.run(cfg, ds, 0.0)  # the process tracer
+    kw = (
+        {"progress_cb": lambda ev: None} if path == "segmented"
+        else {"measure_timestamps": True}
+    )
+    other, roots, children = run_under(Tracer(), cfg, ds, **kw)
+    np.testing.assert_array_equal(base.final_models, other.final_models)
+    np.testing.assert_array_equal(
+        base.history.objective, other.history.objective
+    )
+    np.testing.assert_array_equal(
+        base.history.consensus_error, other.history.consensus_error
+    )
+    assert roots[-1]["args"]["path"] == path
+    got = names(children)
+    assert got[:5] == CHILDREN_COLD[:5]
+    assert got[-3:] == ["upload_wait", "scan", "harvest"]
+    assert set(got) <= set(CHILDREN_COLD)
+
+
+def test_iters_per_second_is_the_scan_spans_clock(setup):
+    cfg, ds = setup
+    result, _, children = run_under(Tracer(), cfg, ds)
+    scan = next(e for e in children if e["name"] == "dopt.run.scan")
+    assert result.history.iters_per_second == cfg.n_iterations / scan["duration"]
+    assert result.history.time[-1] == scan["duration"]
+
+
+def test_process_tracer_keeps_the_last_roots_only(setup):
+    cfg, ds = setup
+    assert current_tracer() is process_tracer()
+    for _ in range(PROCESS_TRACER_ROOTS + 6):
+        jax_backend.run(cfg, ds, 0.0)
+    events = process_tracer().spans()
+    roots = [e for e in events if e["parent"] is None]
+    assert len(roots) == PROCESS_TRACER_ROOTS == 64
+    assert [e["id"] for e in roots] == sorted(e["id"] for e in roots)
+    # Every event that is left belongs to a root that is left.
+    assert {e["root"] for e in events} == {e["id"] for e in roots}
+    assert len(events) <= PROCESS_TRACER_ROOTS * (len(CHILDREN_COLD) + 1)
+
+
+def test_bounded_tracer_drops_a_root_with_its_children():
+    tracer = Tracer(max_roots=2)
+    for k in range(5):
+        with tracer.span(f"root{k}"):
+            with tracer.span("child"):
+                tracer.add_span("leaf", 0.25)
+    events = tracer.spans()
+    assert sorted(e["name"] for e in events if e["parent"] is None) == [
+        "root3", "root4"
+    ]
+    assert len(events) == 6
+    assert tracer.phases["leaf"] == 1.25  # the flat table is not cut
+
+
+def test_activate_nests_and_restores():
+    outer, inner = Tracer(), Tracer()
+    with outer.activate():
+        assert current_tracer() is outer
+        with inner.activate():
+            assert current_tracer() is inner
+        assert current_tracer() is outer
+    assert current_tracer() is process_tracer()
+
+
+def test_span_yields_its_event_and_takes_args_until_it_closes():
+    tracer = Tracer()
+    with tracer.span("work", aggregate=False, n=1) as ev:
+        assert ev["start"] > 0 and "duration" not in ev
+        ev["args"]["late"] = "yes"
+    (recorded,) = tracer.spans()
+    assert recorded["duration"] == ev["duration"] >= 0
+    assert recorded["args"] == {"n": 1, "late": "yes"}
+
+
+def test_simulator_run_one_owns_the_backends_spans():
+    # A seed no other test uses: the process's executable cache has not
+    # seen this program, so the run compiles.
+    cfg = small_backend_config(n_iterations=40, eval_every=10, seed=240024)
+    sim = Simulator(cfg)
+    before = len(process_tracer().spans())
+    sim.run_one("ring", topology="ring")
+    events = {e["id"]: e for e in sim.phase_timer.spans()}
+    group = next(e for e in events.values() if e["name"] == "run_one:ring")
+    backend = [e for e in events.values() if e["name"].startswith("dopt.run")]
+    assert {e["name"] for e in backend} >= {
+        "dopt.run", "dopt.run.stack_shards", "dopt.run.scan",
+        "dopt.run.harvest",
+    }
+    for e in backend:
+        assert e["root"] == group["id"]
+    root = next(e for e in backend if e["name"] == "dopt.run")
+    assert root["parent"] == group["id"]
+    # No event is synthesised beside the real ones...
+    assert [e["name"] for e in events.values() if e["parent"] == group["id"]] == [
+        "dopt.run"
+    ]
+    # ...and the flat table, and so the report and --json, keep their rows:
+    # ``compile`` and ``run`` are the backend's own clocks, the two spans'.
+    phases = sim.phase_timer.phases
+    assert set(phases) == {"data_gen", "oracle", "compile", "run"}
+    by_name = {e["name"]: e for e in backend}
+    assert phases["run"] == by_name["dopt.run.scan"]["duration"]
+    assert 0 < phases["compile"] <= by_name["dopt.run.compile"]["duration"]
+    table = sim.phase_timer.report()
+    assert "dopt.run" not in table and "run_one" not in table
+    assert len(process_tracer().spans()) == before
+
+
+def test_profile_dir_trace_holds_the_spans_and_no_python_events(setup, tmp_path):
+    from distributed_optimization_tpu.utils import profiling
+
+    cfg, ds = setup
+    jax_backend.run(cfg, ds, 0.0)  # compiled before the trace starts
+    tracer = Tracer()
+    with profiling.trace(str(tmp_path)), tracer.activate():
+        jax_backend.run(cfg, ds, 0.0)
+    (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(xplane))
+    host = {}
+    python_events = 0
+    for plane in data.planes:
+        for line in plane.lines:
+            for event in line.events:
+                python_events += event.name.startswith("$")
+                if event.name.startswith("dopt.run"):
+                    host.setdefault(event.name, []).append(
+                        (plane.name, event.start_ns, event.duration_ns)
+                    )
+    recorded = {e["name"]: e for e in tracer.spans()}
+    assert set(host) == set(recorded)
+    assert all(p.startswith("/host:") for v in host.values() for p, _, _ in v)
+    # One clock: an annotation starts before its span's clock is read and
+    # ends after it, so it is the longer of the two, by microseconds.
+    _, _, scan_ns = host["dopt.run.scan"][0]
+    scan_s = recorded["dopt.run.scan"]["duration"]
+    assert scan_s <= scan_ns * 1e-9 <= scan_s + 0.01
+    # The Python tracer's events are called "$<file>:<line> <function>".
+    assert python_events == 0
+
+
+def test_spans_module_imports_without_jax():
+    code = (
+        "import sys, importlib.util\n"
+        "sys.modules['jax'] = None\n"
+        f"spec = importlib.util.spec_from_file_location('spans', {spans_mod.__file__!r})\n"
+        "m = importlib.util.module_from_spec(spec); spec.loader.exec_module(m)\n"
+        "t = m.Tracer()\n"
+        "with t.span('a'):\n    pass\n"
+        "assert [e['name'] for e in t.spans()] == ['a']\n"
+        "assert 'jax.profiler' not in sys.modules\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=60
+    )
+    assert proc.returncode == 0, proc.stderr
