@@ -11,6 +11,10 @@ pytree state whose leaves are ``[N, d]``-stacked arrays, so the same rule
   carries ``mix``/``neighbor_sum`` closures that may be a dense matmul, a
   GSPMD stencil, or explicit shard_map ppermute/psum collectives).
 
+``[N, d]`` stands for ``[N, *param_shape]``: the jax scan carries the
+problem's own parameter shape (``[N, d, K]`` for softmax — models/base.py),
+so a rule touches the worker axis only and is elementwise over the rest.
+
 Every state pytree has an ``x: [N, d]`` leaf (per-worker models). The
 centralized algorithm keeps all rows identical — its "mixing" is the exact
 all-reduce mean a parameter server performs, which on the mesh compiles to a
@@ -39,7 +43,7 @@ class StepContext:
     ``mix``: x -> W x (gossip averaging).
     ``neighbor_sum``: x -> A x (sum over graph neighbors, for ADMM).
     ``eta``: learning rate for this iteration (scalar).
-    ``degrees``: [N, 1] node degrees.
+    ``degrees``: [N, 1] node degrees (a unit axis per parameter axis).
     ``config``: the ExperimentConfig (static hyperparameters only).
     ``fused_mix_step``: optional backend-provided fusion of the canonical
     gossip-SGD update, (x, g, eta) -> W x − eta g in one kernel (the pallas

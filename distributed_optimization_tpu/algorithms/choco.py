@@ -40,12 +40,13 @@ from distributed_optimization_tpu.ops.compression import (
     compression_key,
     make_compressor,
     make_error_feedback,
+    row_dim,
 )
 
 
 def _init(x0, config, *, neighbor_sum=None) -> State:
     ef = make_error_feedback(
-        config.compression, x0.shape[-1], config.compression_k,
+        config.compression, row_dim(x0), config.compression_k,
         config.choco_gamma,
     )
     return {"x": x0, "xhat": ef.init(x0)}
@@ -62,7 +63,7 @@ def _step(state: State, ctx: StepContext) -> State:
     cfg = ctx.config
     x, xhat = state["x"], state["xhat"]
     ef = make_error_feedback(
-        cfg.compression, x.shape[-1], cfg.compression_k, cfg.choco_gamma
+        cfg.compression, row_dim(x), cfg.compression_k, cfg.choco_gamma
     )
     g = ctx.grad(x, 0)
     x_half = x - ctx.eta * g

@@ -44,10 +44,11 @@ def _init(x0, config, *, neighbor_sum=None) -> State:
     if config.compression != "none":
         from distributed_optimization_tpu.ops.compression import (
             make_error_feedback,
+            row_dim,
         )
 
         ef = make_error_feedback(
-            config.compression, x0.shape[-1], config.compression_k,
+            config.compression, row_dim(x0), config.compression_k,
             config.choco_gamma,
         )
         return {"x": x0, "xhat": ef.init(x0)}
@@ -61,11 +62,12 @@ def _step(state: State, ctx: StepContext) -> State:
         from distributed_optimization_tpu.ops.compression import (
             compression_key,
             make_error_feedback,
+            row_dim,
         )
 
         cfg = ctx.config
         ef = make_error_feedback(
-            cfg.compression, x.shape[-1], cfg.compression_k,
+            cfg.compression, row_dim(x), cfg.compression_k,
             cfg.choco_gamma,
         )
         g = ctx.grad(x, 0)

@@ -61,7 +61,9 @@ from distributed_optimization_tpu.algorithms.base import (
 def _init(x0, config, *, neighbor_sum=None) -> State:
     # ones_like of a column slice inherits x0's worker-axis sharding, so the
     # mass vector lives where its worker's rows live on a mesh.
-    w0 = jnp.ones_like(x0[:, :1])
+    # One scalar mass per worker, with a unit axis for every parameter axis
+    # ([N, 1] for an [N, d] stack) so num / w broadcasts at any rank.
+    w0 = jnp.ones_like(x0[(slice(None),) + (slice(1),) * (x0.ndim - 1)])
     return {"x": x0, "num": x0, "w": w0}
 
 

@@ -40,6 +40,7 @@ from distributed_optimization_tpu.metrics import (
 )
 from distributed_optimization_tpu.models import get_problem
 from distributed_optimization_tpu.observability.spans import current_tracer
+from distributed_optimization_tpu.ops.losses import sq_norm
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
 from distributed_optimization_tpu.ops.sampling import (
     sample_worker_batch_weights,
@@ -104,7 +105,7 @@ def make_full_objective_fn(problem, reg):
         per_worker = jax.vmap(
             lambda Xi, yi, wi: problem.objective_weighted(w, Xi, yi, wi, 0.0)
         )(X, y, weights)
-        return jnp.sum(per_worker) + 0.5 * reg * jnp.dot(w, w)
+        return jnp.sum(per_worker) + 0.5 * reg * sq_norm(w)
 
     return full_objective
 
@@ -121,6 +122,34 @@ def _fetch_to_host(tree):
 
         tree = multihost_utils.process_allgather(tree, tiled=True)
     return jax.tree.map(np.asarray, tree)
+
+
+def _flat_rows(tree):
+    """Host leaves as ``[rows, D]``: the flat contract of every boundary
+    (``final_models``, ``final_state``, checkpoints), whatever parameter
+    shape the scan carried (``Problem.param_shape``). A view where the leaf
+    is C-contiguous (every rank-2 leaf), else a copy (``_host_f64``)."""
+    return jax.tree.map(lambda a: a.reshape(a.shape[0], -1), tree)
+
+
+def _host_f64(x):
+    """A device leaf as the float64 ``[rows, D]`` array the results hold.
+
+    The cast writes C order BEFORE the flatten: the TPU runtime hands a
+    rank-3 array to the host in the device's own dimension order (the
+    softmax models ``[96, 4097, 512]`` arrive with the strides of a
+    ``[4097, 96, 512]`` buffer — my chip run, PR 25), so flattening the
+    fetched array first would copy the whole leaf once more, where the
+    cast, which touches every element anyway, reorders for nothing."""
+    return _flat_rows(_fetch_to_host(x).astype(np.float64, order="C"))
+
+
+def _restored_state(state_np, like):
+    """A checkpoint's flat leaves in the shapes of the run's own state
+    ``like`` (the inverse of ``_flat_rows``)."""
+    return jax.tree.map(
+        lambda a, ref: np.asarray(a).reshape(ref.shape), state_np, like
+    )
 
 
 def _make_eta_fn(config, eta0=None):
@@ -422,6 +451,7 @@ def _make_step_eval(p: _StepPieces, data):
         or off (tests/test_telemetry.py pins it). The gradient uses the
         same (key, t) batch realization the iteration-t step consumed."""
         x = state["x"]
+        param_axes = tuple(range(1, x.ndim))
         acc = jnp.promote_types(jnp.float32, x.dtype)
         g = grad_fn_factory(t)(x, 0).astype(acc)
         nonfinite = jnp.zeros((), dtype=jnp.float32)
@@ -442,11 +472,11 @@ def _make_step_eval(p: _StepPieces, data):
             else jnp.zeros((), dtype=jnp.float32)
         )
         return {
-            "grad_norm": jnp.sqrt(jnp.sum(g * g, axis=-1)).astype(
+            "grad_norm": jnp.sqrt(jnp.sum(g * g, axis=param_axes)).astype(
                 jnp.float32
             ),
             "param_norm": jnp.sqrt(
-                jnp.sum(x.astype(acc) ** 2, axis=-1)
+                jnp.sum(x.astype(acc) ** 2, axis=param_axes)
             ).astype(jnp.float32),
             "nodes_up": nodes_up,
             "nonfinite": nonfinite,
@@ -488,6 +518,9 @@ def _make_step_eval(p: _StepPieces, data):
                 )
         if p.collect_metrics:
             x = state["x"]
+            # Every parameter axis: [N, d] for the GLMs, [N, d, K] for
+            # softmax on the sequential path (Problem.param_shape).
+            param_axes = tuple(range(1, x.ndim))
             if adversary is not None:
                 # Honest-only metrics (docs/BYZANTINE.md): the gap is
                 # f(x̄_honest) − f* on the unchanged global objective,
@@ -495,12 +528,14 @@ def _make_step_eval(p: _StepPieces, data):
                 # adversary-controlled and would poison both.
                 hw = p.honest_w.astype(x.dtype)
                 nh = jnp.sum(hw)
-                xbar = jnp.sum(x * hw[:, None], axis=0) / nh
+                xbar = jnp.sum(
+                    x * jnp.expand_dims(hw, param_axes), axis=0
+                ) / nh
                 out["gap"] = p.full_objective(xbar, X, y, n_valid) - p.f_opt
                 if p.track_consensus:
                     out["cons"] = (
                         jnp.sum(
-                            hw * jnp.sum((x - xbar[None, :]) ** 2, axis=1)
+                            hw * jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
                         )
                         / nh
                     )
@@ -509,7 +544,7 @@ def _make_step_eval(p: _StepPieces, data):
                 out["gap"] = p.full_objective(xbar, X, y, n_valid) - p.f_opt
                 if p.track_consensus:
                     out["cons"] = jnp.mean(
-                        jnp.sum((x - xbar[None, :]) ** 2, axis=1)
+                        jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
                     )
         return out
 
@@ -885,7 +920,7 @@ def _run_chunked(
                     f"horizon of {n_evals} chunks (n_iterations shrank below "
                     "the checkpointed progress)"
                 )
-            state = _shard(mesh, jax.tree.map(np.asarray, state_np))
+            state = _shard(mesh, _restored_state(state_np, state0))
             gap_list = [float(v) for v in gaps]
             cons_list = [float(v) for v in conss]
             floats_list = [float(v) for v in floats]
@@ -935,7 +970,7 @@ def _run_chunked(
         ):
             t_save = time.perf_counter()
             ckptr.save(
-                done, _fetch_to_host(state),
+                done, _flat_rows(_fetch_to_host(state)),
                 gap_list, cons_list, floats_list, time_list,
             )
             save_seconds += time.perf_counter() - t_save
@@ -1035,7 +1070,7 @@ def _run_segmented_fused(
                     f"horizon of {n_evals} chunks (n_iterations shrank below "
                     "the checkpointed progress)"
                 )
-            state = _shard(mesh, jax.tree.map(np.asarray, state_np))
+            state = _shard(mesh, _restored_state(state_np, state0))
             gap_list = [float(v) for v in gaps]
             cons_list = [float(v) for v in conss]
             floats_list = [float(v) for v in floats]
@@ -1141,7 +1176,7 @@ def _run_segmented_fused(
         if ckptr is not None:
             t_save = time.perf_counter()
             ckptr.save(
-                done, _fetch_to_host(state),
+                done, _flat_rows(_fetch_to_host(state)),
                 gap_list, cons_list, floats_list, time_list,
             )
             save_seconds += time.perf_counter() - t_save
@@ -1386,7 +1421,7 @@ HOISTED_MIN_RATIO = float("inf")
 # (``mixing_impl='pallas'``, f32 whole-array envelope only — Mosaic
 # refuses the ring kernels' rotate in bf16 ("Rotate with non-32-bit
 # data", libtpu 0.0.34), and operands live unblocked in VMEM, so the
-# softmax tier's flat d·K models are out of range).
+# softmax tier's d·K-wide models are out of range).
 
 
 def _run(
@@ -1458,11 +1493,16 @@ def _run(
     device_data = stack_shards(dataset, dtype=np.dtype(config.dtype))
     spans.note(bytes=device_data.X.nbytes + device_data.y.nbytes)
     spans.enter("prepare")
-    # The trained parameter dimension: n_features for the scalar GLMs,
-    # n_features·K for softmax (flattened [d, K] matrix). Everything the
-    # model vector touches — state init, gossip payload accounting, the
-    # mixing-impl gate — sizes off this, not off the feature count.
+    # One worker's parameter: (n_features,) for the scalar GLMs,
+    # (n_features, K) for softmax. The scan carries every model-shaped leaf
+    # as [n, *param_shape] — the shape the gradient kernels read and write —
+    # and the models are flattened once, on the host, at harvest; d_model,
+    # the flat length, is what the boundaries speak (gossip payload
+    # accounting, the compressed halo's leaves, final_models).
+    param_shape = tuple(problem.param_shape(device_data.n_features))
     d_model = problem.param_dim(device_data.n_features)
+    carry_shape = (n,) + param_shape
+    spans.note_root(carry="x".join(map(str, carry_shape)))
 
     # --- topology & collectives (centralized needs none) ---
     halo_mesh = None
@@ -1553,7 +1593,9 @@ def _run(
             mix_op = make_mixing_op(
                 topo, impl=mixing_impl, dtype=device_data.X.dtype
             )
-        degrees = jnp.asarray(topo.degrees, dtype=device_data.X.dtype)[:, None]
+        degrees = jnp.asarray(
+            topo.degrees, dtype=device_data.X.dtype
+        ).reshape((n,) + (1,) * len(param_shape))
         # Per-edge payload: d · gossip_rounds for full-vector exchange, or the
         # algorithm's override (compressed gossip transmits less).
         if algo.comm_payload is not None:
@@ -1668,7 +1710,9 @@ def _run(
         mix_op = None
         faulty = None
         edge_payload = None
-        degrees = jnp.zeros((n, 1), dtype=device_data.X.dtype)
+        degrees = jnp.zeros(
+            (n,) + (1,) * len(param_shape), dtype=device_data.X.dtype
+        )
         floats_per_iter = centralized_floats_per_iteration(n, d_model)
         spectral_gap = None
         if mesh is None and use_mesh and len(jax.devices()) > 1:
@@ -1687,7 +1731,7 @@ def _run(
     n_valid = shard_over_workers(mesh, jnp.asarray(device_data.n_valid))
     spans.enter("prepare")
     x0 = shard_over_workers(
-        mesh, jnp.zeros((n, d_model), dtype=device_data.X.dtype)
+        mesh, jnp.zeros(carry_shape, dtype=device_data.X.dtype)
     )
     state0 = algo.init(
         x0, config,
@@ -2201,7 +2245,7 @@ def _run(
     x_final = final_state["x"]
     mesh_devices = n // x_final.sharding.shard_shape(x_final.shape)[0]
     spans.note(bytes=x_final.nbytes)
-    final_models = _fetch_to_host(x_final).astype(np.float64)
+    final_models = _host_f64(x_final)
     # The reported model under attack is the HONEST average — Byzantine
     # rows are adversary-controlled state, not part of the solution.
     final_avg = (
@@ -2237,10 +2281,7 @@ def _run(
         final_models=final_models,
         final_avg_model=final_avg,
         final_state=(
-            {
-                k: _fetch_to_host(v).astype(np.float64)
-                for k, v in final_state.items()
-            }
+            {k: _host_f64(v) for k, v in final_state.items()}
             if return_state
             else None
         ),

@@ -11,6 +11,7 @@ problem.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable
 
 import jax
@@ -29,11 +30,19 @@ class Problem:
     - ``objective_weighted(w, X, y, weights, reg)`` / ``gradient_weighted`` —
       per-sample-weight forms used on the TPU path (static shapes; weights
       encode masking / effective batch size).
-    - ``param_dim(n_features)`` — the flattened parameter dimension for a
-      d-feature dataset. Identity for the scalar-output GLMs; d·K for the
-      softmax family, whose [d, K] weight matrix travels through the
-      mixing/algorithm layers as a flat vector (gossip is elementwise over
-      the parameter axis, so flattening is exact).
+    - ``param_shape(n_features)`` — the shape of ONE worker's parameter
+      for a d-feature dataset: ``(d,)`` for the scalar-output GLMs,
+      ``(d, K)`` for the softmax family. The jax scan carries every
+      model-shaped leaf as ``[N, *param_shape]`` (gossip, update and
+      consensus act on the worker axis and are elementwise over the rest,
+      so no layer needs the matrix flattened) and flattens once, on the
+      host, at harvest. The kernels take a parameter of either form: the
+      problem's own shape in, the same shape out; a flat vector in, a flat
+      vector out (what the numpy/C++ tiers, ``run_batch`` and the event
+      scan pass).
+    - ``param_dim(n_features)`` — the product of ``param_shape``: the
+      flat length every boundary speaks (``final_models``, checkpoints,
+      gossip payload accounting, the other backends).
     """
 
     name: str
@@ -41,7 +50,10 @@ class Problem:
     gradient: Callable[..., jax.Array]
     objective_weighted: Callable[..., jax.Array]
     gradient_weighted: Callable[..., jax.Array]
-    param_dim: Callable[[int], int] = lambda d: d
+    param_shape: Callable[[int], tuple] = lambda d: (d,)
+
+    def param_dim(self, n_features: int) -> int:
+        return math.prod(self.param_shape(n_features))
 
 
 _REGISTRY: dict[str, Problem] = {}

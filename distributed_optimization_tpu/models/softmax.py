@@ -8,11 +8,15 @@ backward, 2·b·d·K FLOPs each) instead of the scalar GLMs' matvecs, so wide
 (d, K) configurations load the systolic array instead of the memory bus
 (measured: docs/perf/compute_bound.json, docs/PERF.md §compute-bound).
 
-Parameters travel flattened ([d·K]) through mixing/algorithms — gossip is
-elementwise over the parameter axis, so flattening is exact; ``param_dim``
-tells the backends how long the flat vector is. The kernels themselves
-infer K from static shapes (``ops/losses.py`` softmax section), so the
-bound class count only sizes the parameter vector.
+Parameters keep their own shape inside the jax scan and are flat at every
+boundary: ``param_shape`` is ``(d, K)``, the scan carries ``[N, d, K]`` — the
+layout the two matmuls read and write, so no step copies the models between
+a flat and a matrix form (on the TPU that copy was a third of an iteration:
+PERF.md §6, PR 25) — and the run builder flattens once, on the host, at
+harvest (``final_models`` is ``[N, d·K]``). ``param_dim`` is the flat
+length. The kernels take either form and infer K from static shapes
+(``ops/losses.py`` softmax section), so the bound class count only sizes
+the parameter.
 """
 
 import functools
@@ -39,7 +43,7 @@ def make_softmax_problem(n_classes: int) -> Problem:
         gradient=losses.softmax_gradient,
         objective_weighted=losses.softmax_objective_weighted,
         gradient_weighted=losses.softmax_gradient_weighted,
-        param_dim=lambda d: d * n_classes,
+        param_shape=lambda d: (d, n_classes),
     )
 
 

@@ -160,6 +160,12 @@ class ErrorFeedbackGossip:
     the plain W-mix (v⁺ = W v), which is why uncompressed trajectories
     are unaffected. ``floats_per_edge`` (the compressor's payload) is the
     comms-accounting hook the backends consume.
+
+    The compressors contract over ONE parameter axis (top-k, the row norm),
+    so a model-shaped stack ([N, d, K]) is flattened to [N, d·K] at this
+    boundary and the results restored; for an [N, d] stack the reshapes are
+    the identity and trace no op. Build the exchange for the flat row
+    length (``row_dim``).
     """
 
     compressor: Compressor
@@ -183,7 +189,9 @@ class ErrorFeedbackGossip:
         term-for-term the pre-refactor CHOCO step — trajectories are
         bitwise-unchanged (pinned in tests/test_choco.py).
         """
-        q = self.compressor.apply(key, v - memory)
+        q = self.compressor.apply(
+            key, (v - memory).reshape(v.shape[0], -1)
+        ).reshape(v.shape)
         memory_new = memory + q
         v_new = v + self.gamma * (mix(memory_new) - memory_new)
         return v_new, memory_new
@@ -204,11 +212,19 @@ class ErrorFeedbackGossip:
         row-sharded stack (row-wise + shape-based draws, so sharding
         cannot change its output), keeping the historical per-row draws.
         """
-        q = self.compressor.apply(key, v - memory)
+        q = self.compressor.apply(
+            key, (v - memory).reshape(v.shape[0], -1)
+        ).reshape(v.shape)
         memory_new = memory + q
         mixed, halo_new = compressed_mix(q, memory_new, halo)
         v_new = v + self.gamma * (mixed - memory_new)
         return v_new, memory_new, halo_new
+
+
+def row_dim(x) -> int:
+    """Flat length of one row of an [N, ...] stack: the ``d`` a compressor
+    over that stack is built for."""
+    return x.size // x.shape[0]
 
 
 def make_error_feedback(

@@ -183,11 +183,20 @@ def huber_gradient_weighted(
 # onto the MXU. docs/PERF.md §compute-bound measures the MFU this family
 # reaches where the toy tier cannot.
 #
-# Parameters travel FLATTENED ([d·K] vectors) through the mixing/algorithm
-# layers — gossip is elementwise over the parameter axis, so flattening is
-# exact — and are reshaped here; K is inferred from the static shapes
-# (w.size / X.shape[-1]), so the kernels need no bound class count.
+# Rank-polymorphic in the parameter: a [d, K] matrix in gives a [d, K]
+# gradient out (what the jax scan carries — parameter shape inside the scan,
+# flat at the boundary, models/base.Problem.param_shape), a flat [d·K] vector
+# in gives a flat vector out (the numpy/C++ tiers' layout, ``run_batch``, the
+# event scan, the benchmark's reference). ``w.reshape(d, -1)`` is the
+# identity on a matrix, so the matrix path has no relayout in it. K is
+# inferred from the static shapes (w.size / X.shape[-1]), so the kernels need
+# no bound class count.
 # ---------------------------------------------------------------------------
+
+
+def sq_norm(w: jax.Array) -> jax.Array:
+    """‖w‖² of a parameter of either form (Frobenius for a matrix)."""
+    return jnp.dot(w, w) if w.ndim == 1 else jnp.sum(w * w)
 
 
 def _softmax_ce(logits: jax.Array, y: jax.Array) -> jax.Array:
@@ -201,7 +210,7 @@ def _softmax_ce(logits: jax.Array, y: jax.Array) -> jax.Array:
 
 def softmax_objective(w: jax.Array, X: jax.Array, y: jax.Array, lam: float) -> jax.Array:
     logits = X @ w.reshape(X.shape[-1], -1)
-    return jnp.mean(_softmax_ce(logits, y)) + 0.5 * lam * jnp.dot(w, w)
+    return jnp.mean(_softmax_ce(logits, y)) + 0.5 * lam * sq_norm(w)
 
 
 def softmax_gradient(w: jax.Array, X: jax.Array, y: jax.Array, lam: float) -> jax.Array:
@@ -210,14 +219,14 @@ def softmax_gradient(w: jax.Array, X: jax.Array, y: jax.Array, lam: float) -> ja
     P = jax.nn.softmax(logits, axis=-1)
     Y = jax.nn.one_hot(y.astype(jnp.int32), W.shape[1], dtype=X.dtype)
     G = X.T @ (P - Y) / X.shape[0] + lam * W
-    return G.reshape(-1)
+    return G.reshape(w.shape)
 
 
 def softmax_objective_weighted(
     w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, lam: float
 ) -> jax.Array:
     logits = X @ w.reshape(X.shape[-1], -1)
-    return jnp.sum(weights * _softmax_ce(logits, y)) + 0.5 * lam * jnp.dot(w, w)
+    return jnp.sum(weights * _softmax_ce(logits, y)) + 0.5 * lam * sq_norm(w)
 
 
 def softmax_gradient_weighted(
@@ -228,7 +237,7 @@ def softmax_gradient_weighted(
     P = jax.nn.softmax(logits, axis=-1)
     Y = jax.nn.one_hot(y.astype(jnp.int32), W.shape[1], dtype=X.dtype)
     G = X.T @ (weights[:, None] * (P - Y)) + lam * W
-    return G.reshape(-1)
+    return G.reshape(w.shape)
 
 
 def batch_weights(mask: jax.Array) -> jax.Array:

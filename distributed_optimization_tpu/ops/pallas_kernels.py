@@ -22,11 +22,14 @@ several XLA ops bouncing through HBM. Kernel families:
   round-trip it through HBM.
 
 All kernels run in interpreter mode on CPU (tests / virtual-device CI) and
-compile via Mosaic on real TPU. Interpreter-mode selection respects the
-INPUT's committed platform — not the global ``jax.devices()[0]`` — so
-routing stays correct under ``jax.default_device`` / mixed-platform setups;
-pass ``interpret=`` to force either mode (tests). A run that selects a
-kernel logs which of the two it took (``log_kernel_mode``). No ``auto``
+compile via Mosaic on real TPU. Every kernel holds ONE ``[N, D]`` block: a
+model-shaped stack (``[N, d, K]``, what the softmax scan carries) is
+flattened at the call and the result restored — the identity, and no traced
+op, for the ``[N, d]`` stacks the kernels were sized for. Interpreter-mode
+selection respects the INPUT's committed platform — not the global
+``jax.devices()[0]`` — so routing stays correct under
+``jax.default_device`` / mixed-platform setups; pass ``interpret=`` to force
+either mode (tests). A run that selects a kernel logs which of the two it took (``log_kernel_mode``). No ``auto``
 selector picks a kernel: they are reached by ``mixing_impl='pallas'`` and
 ``robust_impl='fused'`` only, and Mosaic does not lower the fused robust
 kernels at all (tests/test_tpu_lowering.py pins which lower).
@@ -164,6 +167,8 @@ def fused_ring_dsgd_step(
     """One fused D-SGD iteration on a ring: W x − eta g, single kernel."""
     interp = resolve_interpret(x, interpret)
     eta_arr = jnp.asarray(eta, dtype=x.dtype).reshape(1)
+    shape = x.shape
+    x, g = x.reshape(shape[0], -1), g.reshape(shape[0], -1)
     return pl.pallas_call(
         _make_fused_ring_step_kernel(interp),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -174,17 +179,19 @@ def fused_ring_dsgd_step(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interp,
-    )(eta_arr, x, g)
+    )(eta_arr, x, g).reshape(shape)
 
 
 def _unary_call(kernel, x: jax.Array, interp: bool) -> jax.Array:
+    shape = x.shape
+    x = x.reshape(shape[0], -1)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interp,
-    )(x)
+    )(x).reshape(shape)
 
 
 def fc_mix(x: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
@@ -395,6 +402,8 @@ def _make_fused_robust(
     def call(live, x, g=None, eta=None):
         interp = resolve_interpret(x, interpret)
         kernel, acc = make_kernel(x.dtype)
+        shape = x.shape
+        x = x.reshape(shape[0], -1)
         # Fixed-radius clipping threads tau as a [1] SMEM scalar (possibly
         # traced — the replica-swept axis); the count rules and adaptive
         # clipping ignore it (adaptive recomputes per node in-kernel).
@@ -413,14 +422,14 @@ def _make_fused_robust(
         args += [nbr_dev, live, x]
         if with_sgd:
             specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
-            args.append(g)
+            args.append(g.reshape(x.shape))
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             in_specs=specs,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             interpret=interp,
-        )(*args)
+        )(*args).reshape(shape)
 
     return call
 

@@ -82,6 +82,20 @@ from distributed_optimization_tpu.parallel.faults import (
 RobustAggregator = Callable[[jax.Array, jax.Array], jax.Array]
 
 
+def _on_flat_rows(rule):
+    """``rule(graph, x)`` is written over ONE parameter axis (it sorts per
+    coordinate and takes row norms): a model-shaped stack ([N, d, K]) is
+    flattened to [N, d·K] at this boundary and a stack-shaped result
+    restored. For the [N, d] stack the reshapes are the identity and trace
+    no op."""
+
+    def on_any_rank(graph, x):
+        out = rule(graph, x.reshape(x.shape[0], -1))
+        return out.reshape(x.shape) if out.ndim else out
+
+    return on_any_rank
+
+
 def validate_budget(min_degree: int, budget: int, aggregation: str) -> None:
     """Reject trimming budgets the topology cannot support.
 
@@ -218,7 +232,7 @@ def make_robust_aggregator(
             moved = jnp.sum(W[:, :, None] * diffs * factor[:, :, None], axis=1)
             return (xa + moved).astype(x.dtype)
 
-    return aggregate
+    return _on_flat_rows(aggregate)
 
 
 def make_gather_robust_aggregator(
@@ -323,7 +337,7 @@ def make_gather_robust_aggregator(
             moved = jnp.sum(w[:, :, None] * diffs * factor[:, :, None], axis=1)
             return (xa + moved).astype(x.dtype)
 
-    return aggregate
+    return _on_flat_rows(aggregate)
 
 
 def _screening_fraction(name: str, budget: int, counts):
@@ -385,7 +399,7 @@ def make_robust_activity(
                 jnp.float32
             )
 
-    return activity
+    return _on_flat_rows(activity)
 
 
 def make_gather_robust_activity(
@@ -430,7 +444,7 @@ def make_gather_robust_activity(
                 jnp.float32
             )
 
-    return activity
+    return _on_flat_rows(activity)
 
 
 def robust_activity_np(

@@ -131,9 +131,10 @@ def make_adversary(
         else:  # alie: colluders share honest_mean − scale·honest_std
             h = (1.0 - byz_dev).astype(acc)
             n_honest = jnp.sum(h)
-            mu = jnp.sum(xa * h[:, None], axis=0) / n_honest
+            rows = tuple(range(1, x.ndim))  # a unit axis per parameter axis
+            mu = jnp.sum(xa * jnp.expand_dims(h, rows), axis=0) / n_honest
             var = (
-                jnp.sum(h[:, None] * (xa - mu[None, :]) ** 2, axis=0)
+                jnp.sum(jnp.expand_dims(h, rows) * (xa - mu[None]) ** 2, axis=0)
                 / n_honest
             )
             payload = jnp.broadcast_to(

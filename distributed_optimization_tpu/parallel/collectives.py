@@ -147,15 +147,12 @@ def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
         if n < 3:
             raise ValueError("shard_map ring mixing needs n >= 3")
         mix_block, nbr_block = _ring_block_mix(axis, n_devices, 1.0 / 3.0)
-        spec_in = P(axis, None)
     elif topo.name == "directed_ring":
         if n < 3:
             raise ValueError("shard_map directed_ring mixing needs n >= 3")
         mix_block, nbr_block = _directed_ring_block_mix(axis, n_devices)
-        spec_in = P(axis, None)
     elif topo.name == "fully_connected":
         mix_block, nbr_block = _fc_block_ops(axis, n)
-        spec_in = P(axis, None)
     elif topo.name == "grid":
         rows, cols = topo.grid_shape  # type: ignore[misc]
         if min(rows, cols) < 3:
@@ -165,25 +162,27 @@ def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
                 f"grid rows={rows} not divisible by mesh size {n_devices}"
             )
         mix_block, nbr_block = _grid_block_ops(axis, n_devices, rows, cols, 1.0 / 5.0)
-        spec_in = P(axis, None, None)
     else:
         raise ValueError(
             f"No shard_map stencil for topology {topo.name!r}; use dense mixing"
         )
 
     def _wrap(block_fn):
-        if topo.name == "grid":
-            rows, cols = topo.grid_shape  # type: ignore[misc]
+        # The worker axis (the grid's row axis) is blocked over devices and
+        # every parameter axis replicated: the spec follows the stack's
+        # rank ([N, d] or a model-shaped [N, d, K]).
+        def fn(x):
+            g = x
+            if topo.name == "grid":  # grid layout -> stencil -> back
+                rows, cols = topo.grid_shape  # type: ignore[misc]
+                g = x.reshape(rows, cols, *x.shape[1:])
+            spec = P(axis, *([None] * (g.ndim - 1)))
+            out = shard_map(
+                block_fn, mesh=mesh, in_specs=spec, out_specs=spec
+            )(g)
+            return out.reshape(x.shape)
 
-            def fn(x):  # x: [N, d] -> grid layout -> stencil -> back
-                g = x.reshape(rows, cols, x.shape[-1])
-                out = shard_map(
-                    block_fn, mesh=mesh, in_specs=spec_in, out_specs=spec_in
-                )(g)
-                return out.reshape(x.shape)
-
-            return fn
-        return shard_map(block_fn, mesh=mesh, in_specs=spec_in, out_specs=spec_in)
+        return fn
 
     return MixingOp(topo.name, "shard_map", _wrap(mix_block), _wrap(nbr_block))
 
@@ -644,6 +643,7 @@ def make_halo_robust_aggregator_t(
             active_fn(t) if active_fn is not None
             else jnp.ones(n, dtype=jnp.float32)
         )
-        return hx.run(body, x, m)
+        # The body screens over one parameter axis: flatten at the boundary.
+        return hx.run(body, x.reshape(n, -1), m).reshape(x.shape)
 
     return aggregate_t

@@ -1061,12 +1061,13 @@ def make_faulty_mixing(
             acc = jnp.promote_types(jnp.float32, x.dtype)
             A_t = realized_adjacency(t).astype(acc)
             deg = jnp.sum(A_t, axis=1)
-            nbr_avg = jnp.tensordot(A_t, x.astype(acc), axes=1) / jnp.maximum(
-                deg, 1.0
-            )[:, None]
+            rows = tuple(range(1, x.ndim))  # a unit axis per parameter axis
+            nbr_avg = jnp.tensordot(
+                A_t, x.astype(acc), axes=1
+            ) / jnp.expand_dims(jnp.maximum(deg, 1.0), rows)
             take = rejoin_dev[t] & (deg > 0)
             return jnp.where(
-                take[:, None], nbr_avg, x.astype(acc)
+                jnp.expand_dims(take, rows), nbr_avg, x.astype(acc)
             ).astype(x.dtype)
 
     match_key = (
@@ -1238,12 +1239,14 @@ def _make_gather_faulty_mixing(
             acc = jnp.promote_types(jnp.float32, x.dtype)
             lv = live(t).astype(acc)
             deg = jnp.sum(lv, axis=1)
+            rows = tuple(range(1, x.ndim))  # a unit axis per parameter axis
             nbr_avg = jnp.sum(
-                lv[:, :, None] * x.astype(acc)[nbr_dev], axis=1
-            ) / jnp.maximum(deg, 1.0)[:, None]
+                jnp.expand_dims(lv, tuple(range(2, x.ndim + 1)))
+                * x.astype(acc)[nbr_dev], axis=1
+            ) / jnp.expand_dims(jnp.maximum(deg, 1.0), rows)
             take = rejoin_dev[t] & (deg > 0)
             return jnp.where(
-                take[:, None], nbr_avg, x.astype(acc)
+                jnp.expand_dims(take, rows), nbr_avg, x.astype(acc)
             ).astype(x.dtype)
 
     def make_neighbor_liveness(nbr_idx: np.ndarray, nbr_mask: np.ndarray):

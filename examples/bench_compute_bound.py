@@ -159,6 +159,13 @@ def main() -> None:
         # Minimum HBM traffic: X re-read twice (fwd+bwd) + W read twice /
         # written once per worker per iteration. Logits/softmax intermediates
         # assumed fused (XLA does); this is a LOWER bound on real traffic.
+        # Checked against the compiled scan, which carries the models as
+        # [N, d, K] (PERF.md §5, PR 25, v5e): the logits matmul reads X and
+        # W, the weight-gradient matmul reads X again and writes the models
+        # once with the update fused in, and the ring stencil is an op of its
+        # own that reads W and writes two shifted partial sums the gradient
+        # fusion reads back. The real count is 2 reads of X, about 4 reads
+        # and 3 writes of W: the bound holds.
         bytes_per_iter = (2 * N * b * d + 3 * N * d * K) * bytes_el
         achieved_tf = flops_per_iter * ips / 1e12
         results[label] = {

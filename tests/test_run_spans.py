@@ -77,7 +77,11 @@ def test_one_root_with_the_named_children_in_order(setup):
         end = e["start"] + e["duration"]
     assert end <= root["start"] + root["duration"]
     assert sum(e["duration"] for e in children) <= root["duration"]
-    assert root["args"] == {"path": "fused", "cache": "miss"}
+    # ``carry``: the shape of the scan's model leaf, [N, *param_shape].
+    assert root["args"] == {
+        "path": "fused", "cache": "miss",
+        "carry": f"{cfg.n_workers}x{ds.n_features}",
+    }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)
     assert by_name["dopt.run.stack_shards"]["args"]["bytes"] == (
