@@ -1718,6 +1718,17 @@ def _run(
         if mesh is None and use_mesh and len(jax.devices()) > 1:
             mesh = make_worker_mesh(n)
 
+    # What ran and what one edge carries an iteration: the compressor with
+    # its count (kept coordinates, or qsgd bits) over the row it works on.
+    spans.note_root(
+        algorithm=config.algorithm,
+        compress=(
+            "none" if config.compression == "none"
+            else f"{config.compression}:{config.compression_k}/{d_model}"
+        ),
+        wire_floats_per_edge=float(edge_payload or 0.0),
+    )
+
     # --- device placement (sharded over the worker axis where it matters) ---
     # ``upload`` is the enqueue; the wait for the copy is ``upload_wait``,
     # directly before the scan's clock starts.
@@ -2244,7 +2255,12 @@ def _run(
     )
     x_final = final_state["x"]
     mesh_devices = n // x_final.sharding.shard_shape(x_final.shape)[0]
-    spans.note(bytes=x_final.nbytes)
+    # Every leaf that comes down: the models, and under ``return_state`` the
+    # whole state (CHOCO's xhat, a tracker), the models among them again.
+    spans.note(bytes=x_final.nbytes + (
+        sum(v.nbytes for v in jax.tree.leaves(final_state))
+        if return_state else 0
+    ))
     final_models = _host_f64(x_final)
     # The reported model under attack is the HONEST average — Byzantine
     # rows are adversary-controlled state, not part of the solution.

@@ -97,7 +97,21 @@ def make_compressor(name: str, d: int, k: int = 0) -> Compressor:
         raise ValueError(f"compression_k must be in (0, {d}], got {k}")
 
     def keep_top_scored(v, scores):
-        # Row-wise mask keeping the k top-scored coordinates of each row.
+        """``v`` with all but the k top-scored coordinates of each row set
+        to 0: exactly k survive, and of equal scores the one at the lower
+        index does (``lax.top_k``'s order; the benchmark's plain reference,
+        ``benchmark/reference/choco_ring.py``, restates the rule).
+
+        Cost at large rows. The TPU compiler lowers ``top_k`` at this k to
+        a stable sort of the whole row with an iota beside it, and the
+        scatter to a flat scatter with a sort of its indices in front and a
+        row-by-row copy back. On ``[96, 2097664]`` rows at k = 20,977 (one
+        v5e, builder's chip runs, PR 26, ``softmax4096_choco_ring96``): the
+        sort 542 ms an iteration, scatter and its way back 41 ms, mask
+        arithmetic 8 ms, against 36 ms for the whole D-SGD step beside it;
+        at d = 81 the same lines are a small partial reduce. A selection by
+        threshold (no sort, no scatter) is the open ``perf_opt`` (PERF.md
+        section 7); it has to keep this tie rule."""
         _, idx = jax.lax.top_k(scores, k)
         mask = jnp.zeros_like(v).at[
             jnp.arange(v.shape[0])[:, None], idx

@@ -162,3 +162,22 @@ def test_compressor_rejects_bad_params():
         make_compressor("qsgd", 8, 17)
     with pytest.raises(ValueError, match="Unknown compression"):
         make_compressor("signsgd", 8, 1)
+
+
+# ------------------------------------------------------ ties at the threshold
+
+@pytest.mark.parametrize("k,kept", [
+    (1, [1]),            # three entries tie for the largest: the first
+    (2, [1, 3]),
+    (3, [1, 3, 4]),
+    (4, [0, 1, 3, 4]),   # two tie for the fourth place: the lower index
+    (5, [0, 1, 3, 4, 6]),
+])
+def test_top_k_ties_go_to_the_lower_index(k, kept):
+    """Equal magnitudes at the threshold (signs do not matter): exactly k are
+    kept, the ones at the lower indices, so a row's payload is 2k whatever
+    ties (the benchmark's plain reference restates this rule)."""
+    v = jnp.asarray([[1.0, -3.0, 0.25, 3.0, -3.0, 0.5, -1.0, 0.0]], jnp.float32)
+    out = np.asarray(make_compressor("top_k", 8, k).apply(None, v))
+    assert np.flatnonzero(out[0]).tolist() == kept
+    np.testing.assert_array_equal(out[0, kept], np.asarray(v)[0, kept])

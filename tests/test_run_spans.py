@@ -78,9 +78,13 @@ def test_one_root_with_the_named_children_in_order(setup):
     assert end <= root["start"] + root["duration"]
     assert sum(e["duration"] for e in children) <= root["duration"]
     # ``carry``: the shape of the scan's model leaf, [N, *param_shape].
+    # ``algorithm``, ``compress``, ``wire_floats_per_edge``: what ran and
+    # what one edge carries an iteration (plain D-SGD: the whole model).
     assert root["args"] == {
         "path": "fused", "cache": "miss",
         "carry": f"{cfg.n_workers}x{ds.n_features}",
+        "algorithm": "dsgd", "compress": "none",
+        "wire_floats_per_edge": float(ds.n_features),
     }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)
@@ -96,6 +100,32 @@ def test_one_root_with_the_named_children_in_order(setup):
     )
     # None of them is a row of the flat phase table.
     assert tracer.phases == {}
+
+
+@pytest.mark.parametrize("problem,classes", [("quadratic", 2), ("softmax", 3)])
+def test_root_names_the_compressor_and_harvest_counts_every_leaf(problem, classes):
+    """CHOCO with top-k (ISSUE 26): the root says which compressor ran over
+    which row and what an edge carries (k values + k indices); the harvest's
+    ``bytes`` are the leaves fetched: the models alone, and with
+    ``return_state`` the state's leaves (x again, and xhat) beside them."""
+    cfg = small_backend_config(
+        n_iterations=20, eval_every=10, algorithm="choco", compression="top_k",
+        compression_k=4, choco_gamma=0.2, problem_type=problem,
+        n_classes=classes,
+    )
+    ds = generate_synthetic_dataset(cfg)
+    row = ds.n_features * (classes if problem == "softmax" else 1)
+    leaf = cfg.n_workers * row * 4
+    for return_state, fetched in ((False, leaf), (True, 3 * leaf)):
+        result, roots, children = run_under(
+            Tracer(), cfg, ds, return_state=return_state)
+        args = roots[-1]["args"]
+        assert args["algorithm"] == "choco"
+        assert args["compress"] == f"top_k:4/{row}"
+        assert args["wire_floats_per_edge"] == 8.0
+        assert children[-1]["name"] == "dopt.run.harvest"
+        assert children[-1]["args"]["bytes"] == fetched
+    assert sorted(result.final_state) == ["x", "xhat"]
 
 
 def test_second_identical_call_hits_the_cache_and_does_not_compile(setup):
