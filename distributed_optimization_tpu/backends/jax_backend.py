@@ -40,6 +40,7 @@ from distributed_optimization_tpu.metrics import (
 )
 from distributed_optimization_tpu.models import get_problem
 from distributed_optimization_tpu.observability.spans import current_tracer
+from distributed_optimization_tpu.ops.compression import selection_label
 from distributed_optimization_tpu.ops.losses import sq_norm
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
 from distributed_optimization_tpu.ops.sampling import (
@@ -1719,13 +1720,15 @@ def _run(
             mesh = make_worker_mesh(n)
 
     # What ran and what one edge carries an iteration: the compressor with
-    # its count (kept coordinates, or qsgd bits) over the row it works on.
+    # its count (kept coordinates, or qsgd bits) over the row it works on,
+    # and how it picks what it keeps.
     spans.note_root(
         algorithm=config.algorithm,
         compress=(
             "none" if config.compression == "none"
             else f"{config.compression}:{config.compression_k}/{d_model}"
         ),
+        select=selection_label(config.compression, device_data.X.dtype),
         wire_floats_per_edge=float(edge_payload or 0.0),
     )
 

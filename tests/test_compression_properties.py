@@ -25,7 +25,11 @@ import jax.numpy as jnp
 from jax import enable_x64
 import pytest
 
-from distributed_optimization_tpu.ops.compression import make_compressor
+from distributed_optimization_tpu.ops.compression import (
+    make_compressor,
+    select_top_scored,
+    selection_label,
+)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -92,18 +96,24 @@ def _row(d, seed, heavy_tail=False):
     return v
 
 
-@pytest.mark.parametrize("dtype_x64", [
-    ("float32", False), ("float32", True), ("float64", True),
-], ids=["f32", "f32-x64on", "f64-x64on"])
+_DTYPES_X64 = [("float32", False), ("float32", True), ("float64", True)]
+_DTYPE_IDS = ["f32", "f32-x64on", "f64-x64on"]
+
+
+def _under(x64, fn):
+    """``fn()``, under ``enable_x64`` where asked."""
+    if x64:
+        with enable_x64():
+            return fn()
+    return fn()
+
+
+@pytest.mark.parametrize("dtype_x64", _DTYPES_X64, ids=_DTYPE_IDS)
 @pytest.mark.parametrize("name,d,k,seed", _SEEDED_CASES)
 def test_contraction_seeded(name, d, k, seed, dtype_x64):
     dtype, x64 = dtype_x64
     v = _row(d, seed, heavy_tail=seed % 2 == 0)
-    if x64:
-        with enable_x64():
-            _check_contraction(name, d, k, v, jnp.dtype(dtype))
-    else:
-        _check_contraction(name, d, k, v, jnp.dtype(dtype))
+    _under(x64, lambda: _check_contraction(name, d, k, v, jnp.dtype(dtype)))
 
 
 if HAVE_HYPOTHESIS:
@@ -181,3 +191,132 @@ def test_top_k_ties_go_to_the_lower_index(k, kept):
     out = np.asarray(make_compressor("top_k", 8, k).apply(None, v))
     assert np.flatnonzero(out[0]).tolist() == kept
     np.testing.assert_array_equal(out[0, kept], np.asarray(v)[0, kept])
+
+
+# ------------------------------------------- selection by a counted threshold
+
+def _oracle_mask(scores, k):
+    """The k largest of each row by a plain stable argsort: of equal scores
+    the lower index first. Rows are everything behind the first axis."""
+    flat = np.asarray(scores).reshape(scores.shape[0], -1)
+    mask = np.zeros(flat.shape, bool)
+    for row, keep in zip(flat, mask):
+        keep[np.argsort(-row, kind="stable")[:k]] = True
+    return mask.reshape(scores.shape)
+
+
+def _tie_rows(kind, d, rng):
+    """Three rows of d numbers whose magnitudes tie at the threshold."""
+    if kind == "all_equal":
+        return np.full((3, d), -2.5)
+    if kind == "all_zero":
+        return np.zeros((3, d))
+    if kind == "signed_zeros":  # +0.0 and -0.0 tie; a few non-zeros
+        v = np.where(rng.random((3, d)) < 0.5, 0.0, -0.0)
+        v[:, ::5] = rng.standard_normal((3, len(range(0, d, 5))))
+        return v
+    if kind == "subnormals":  # ordered among themselves, above 0
+        return rng.integers(-4, 5, (3, d)) * 1e-40
+    if kind == "grid":  # few distinct magnitudes: ties wherever k falls
+        return rng.integers(-3, 4, (3, d)) * 0.5
+    assert kind == "continuous"
+    return rng.standard_normal((3, d))
+
+
+_TIE_KINDS = [
+    "all_equal", "all_zero", "signed_zeros", "subnormals", "grid", "continuous",
+]
+@pytest.mark.parametrize("dtype_x64", _DTYPES_X64, ids=_DTYPE_IDS)
+@pytest.mark.parametrize("k", [1, 5, 12, 24])
+@pytest.mark.parametrize("kind", _TIE_KINDS)
+def test_top_k_is_the_argsort_oracle(kind, k, dtype_x64):
+    """``top_k`` keeps exactly the entries a stable argsort of the magnitudes
+    keeps (k = 1, k inside a run of ties, k = d), and the kept values are the
+    input's: on rows full of ties, zeros of both signs and subnormals."""
+    dtype, x64 = dtype_x64
+    d = 24
+
+    def check():
+        v = jnp.asarray(_tie_rows(kind, d, np.random.default_rng(k)), dtype)
+        out = np.asarray(make_compressor("top_k", d, k).apply(None, v))
+        keep = _oracle_mask(np.abs(np.asarray(v)), k)
+        assert out.dtype == np.dtype(dtype)
+        # v * mask, as the operator always computed it (a product flushes a
+        # kept subnormal on some backends, with either mask).
+        np.testing.assert_array_equal(
+            out, np.asarray(v * jnp.asarray(keep, v.dtype))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(select_top_scored(jnp.abs(v), k)), keep
+        )
+
+    _under(x64, check)
+
+
+@pytest.mark.parametrize("dtype_x64", _DTYPES_X64, ids=_DTYPE_IDS)
+@pytest.mark.parametrize("k", [1, 7, 24])
+def test_random_k_is_the_argsort_oracle_on_its_draws(k, dtype_x64):
+    """``random_k`` keeps the k entries with the largest uniform draws, the
+    draws being ``jax.random.uniform(key, v.shape)`` as they always were:
+    exactly k non-zeros a row where the input has no zero."""
+    dtype, x64 = dtype_x64
+    d = 24
+
+    def check():
+        v = jnp.asarray(np.random.default_rng(k).standard_normal((3, d)), dtype)
+        key = jax.random.key(k)
+        out = np.asarray(make_compressor("random_k", d, k).apply(key, v))
+        keep = _oracle_mask(jax.random.uniform(key, v.shape), k)
+        np.testing.assert_array_equal(out, np.where(keep, np.asarray(v), 0))
+        assert (np.count_nonzero(out, axis=1) == k).all()
+
+    _under(x64, check)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16"])
+@pytest.mark.parametrize("k", [1, 9, 40, 63, 64])
+def test_select_top_scored_counts_exactly_k_whatever_ties(k, dtype):
+    """The selection itself, on scores with long runs of ties (a grid of
+    eight values over 64 entries, a run of ties at every k): the oracle's
+    mask, exactly k a row, in every score width."""
+    scores = jnp.asarray(
+        np.random.default_rng(k).integers(0, 8, (4, 64)) / 8.0, dtype
+    )
+    mask = np.asarray(select_top_scored(scores, k))
+    np.testing.assert_array_equal(
+        mask, _oracle_mask(np.asarray(scores, np.float32), k)
+    )
+    assert (mask.sum(axis=1) == k).all()
+
+
+@pytest.mark.parametrize("name", ["top_k", "random_k", "qsgd"])
+@pytest.mark.parametrize("kind", ["grid", "continuous"])
+def test_model_shaped_stack_is_the_flattened_stack(name, kind):
+    """A compressor over [N, d, K] is the compressor over the stack
+    flattened to [N, d·K]: "the lower index" is row-major over (d, K), and
+    the randomized operators draw the same numbers for either shape."""
+    n, d, k_classes, k = 3, 7, 5, 4 if name == "qsgd" else 11
+    v = jnp.asarray(
+        _tie_rows(kind, d * k_classes, np.random.default_rng(3)), jnp.float32
+    )
+    comp = make_compressor(name, d * k_classes, k)
+    key = jax.random.key(17)
+    shaped = comp.apply(key, v.reshape(n, d, k_classes))
+    assert shaped.shape == (n, d, k_classes)
+    np.testing.assert_array_equal(
+        np.asarray(shaped).reshape(n, -1), np.asarray(comp.apply(key, v))
+    )
+
+
+@pytest.mark.parametrize("name,dtype,label", [
+    ("top_k", "float32", "threshold:16"),
+    ("top_k", "bfloat16", "threshold:8"),
+    ("top_k", "float64", "threshold:32"),
+    ("random_k", "bfloat16", "threshold:16"),  # its scores: float32 draws
+    ("qsgd", "float32", "none"),
+    ("none", "float32", "none"),
+])
+def test_selection_label(name, dtype, label):
+    """The root span's ``select``: the method and its counting passes over a
+    row, one per two bits of a score."""
+    assert selection_label(name, dtype) == label

@@ -78,12 +78,13 @@ def test_one_root_with_the_named_children_in_order(setup):
     assert end <= root["start"] + root["duration"]
     assert sum(e["duration"] for e in children) <= root["duration"]
     # ``carry``: the shape of the scan's model leaf, [N, *param_shape].
-    # ``algorithm``, ``compress``, ``wire_floats_per_edge``: what ran and
-    # what one edge carries an iteration (plain D-SGD: the whole model).
+    # ``algorithm``, ``compress``, ``select``, ``wire_floats_per_edge``:
+    # what ran and what one edge carries an iteration (plain D-SGD: the
+    # whole model, nothing selected).
     assert root["args"] == {
         "path": "fused", "cache": "miss",
         "carry": f"{cfg.n_workers}x{ds.n_features}",
-        "algorithm": "dsgd", "compress": "none",
+        "algorithm": "dsgd", "compress": "none", "select": "none",
         "wire_floats_per_edge": float(ds.n_features),
     }
     by_name = {e["name"]: e for e in children}
@@ -105,7 +106,8 @@ def test_one_root_with_the_named_children_in_order(setup):
 @pytest.mark.parametrize("problem,classes", [("quadratic", 2), ("softmax", 3)])
 def test_root_names_the_compressor_and_harvest_counts_every_leaf(problem, classes):
     """CHOCO with top-k (ISSUE 26): the root says which compressor ran over
-    which row and what an edge carries (k values + k indices); the harvest's
+    which row, how it selects (ISSUE 27) and what an edge carries (k values +
+    k indices); the harvest's
     ``bytes`` are the leaves fetched: the models alone, and with
     ``return_state`` the state's leaves (x again, and xhat) beside them."""
     cfg = small_backend_config(
@@ -122,10 +124,27 @@ def test_root_names_the_compressor_and_harvest_counts_every_leaf(problem, classe
         args = roots[-1]["args"]
         assert args["algorithm"] == "choco"
         assert args["compress"] == f"top_k:4/{row}"
+        # By a counted threshold, two bits of a float32 magnitude a pass.
+        assert args["select"] == "threshold:16"
         assert args["wire_floats_per_edge"] == 8.0
         assert children[-1]["name"] == "dopt.run.harvest"
         assert children[-1]["args"]["bytes"] == fetched
     assert sorted(result.final_state) == ["x", "xhat"]
+
+
+@pytest.mark.parametrize("compression,k,select", [
+    ("random_k", 4, "threshold:16"), ("qsgd", 4, "none"),
+])
+def test_root_select_for_the_other_compressors(compression, k, select):
+    """``select`` on compressed D-SGD: the sparsifiers select by a counted
+    threshold, a quantizer selects nothing."""
+    cfg = small_backend_config(
+        n_iterations=10, eval_every=10, algorithm="dsgd",
+        compression=compression, compression_k=k, choco_gamma=0.2,
+    )
+    _, roots, _ = run_under(Tracer(), cfg, generate_synthetic_dataset(cfg))
+    assert roots[-1]["args"]["compress"].startswith(f"{compression}:{k}/")
+    assert roots[-1]["args"]["select"] == select
 
 
 def test_second_identical_call_hits_the_cache_and_does_not_compile(setup):

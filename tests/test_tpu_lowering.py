@@ -9,6 +9,8 @@ accelerator — dense sampling, scan unroll 8 — computes the same run as the
 CPU default (gather sampling, unroll 1).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,3 +101,33 @@ def test_accelerator_program_form_matches_cpu_default():
         chip_form.history.objective, cpu_form.history.objective,
         rtol=0, atol=1e-5,
     )
+
+
+@pytest.mark.parametrize("shape", [(96, 81), (8, 33, 16)], ids=["Nd", "NdK"])
+def test_top_k_exchange_lowers_without_sort_or_scatter(shape):
+    """The top-k of a compressed exchange is a counted threshold
+    (``ops.compression.select_top_scored``): the program the chip is given
+    holds no sort, no top-k call and no scatter. On [96, 2097664] rows the
+    sort ``lax.top_k`` became was 542 ms of every 639 ms CHOCO iteration and
+    the scatter 41 (PERF.md section 6, PR 26), so neither may come back
+    unnoticed; nor may a flatten of the model-shaped stack (13 ms there)."""
+    from distributed_optimization_tpu.ops.compression import (
+        make_error_feedback,
+        row_dim,
+    )
+
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    ef = make_error_feedback("top_k", row_dim(x), 7, 0.2)
+
+    def exchange(v, memory):
+        return ef.exchange(None, v, memory, lambda m: jnp.roll(m, 1, axis=0))
+
+    text = _lower_for_tpu(exchange, x, x).mlir_module()
+    ops = set(re.findall(r"\b(?:stablehlo|chlo)\.\w+", text))
+    assert {"stablehlo.while", "stablehlo.reduce", "stablehlo.compare"} <= ops
+    assert not ops & {
+        "stablehlo.sort", "chlo.top_k", "stablehlo.scatter",
+        "stablehlo.custom_call",
+    }, ops
+    flat = f"tensor<{shape[0]}x{row_dim(x)}x"
+    assert (flat in text) == (len(shape) == 2)
