@@ -497,9 +497,9 @@ def _make_step_eval(p: _StepPieces, data):
     def eval_metrics(state, t_last, cadence_known=False):
         """Per-eval metrics + flight-recorder row at iteration ``t_last``.
 
-        ``cadence_known=True`` promises t_last IS an eval boundary (the
-        chunked/hoisted forms); the inline fused scan computes its eval
-        every trip and discards off-cadence rows, so there the trace row —
+        ``cadence_known=True`` promises t_last IS an eval boundary (a scan
+        of one trip per eval); otherwise the scan computes its eval every
+        trip and discards off-cadence rows, so there the trace row —
         whose gradient probe is NOT latency-hidden the way the stacked-
         output eval is — hides behind a ``lax.cond`` on the boundary
         predicate instead of running every trip (measured 36% → <10%
@@ -854,194 +854,72 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
     return adversary, byz_mix, activity_t, fused_step_t
 
 
-def _run_chunked(
-    chunk, state0, data_args, checkpoint, mesh, config, n_evals,
-    measure_compile, spans, progress_hook=None, progress_every=1,
-    halt_check=None,
-):
-    """Host-driven chunk loop: measured per-eval timestamps, optional orbax
-    checkpointing (``checkpoint=None`` runs the loop purely for timing).
-    ``chunk(state, ts, data_args)`` takes the sharded data pytree as an
-    argument (multi-process safe; see ``make_chunk``).
-
-    One 'chunk' = ``eval_every`` fused iterations (the same compiled body the
-    single-scan path uses); the host only intervenes at eval boundaries, so
-    steady-state throughput matches the fused path up to one host sync per
-    ``eval_every`` iterations. Each chunk records a real ``perf_counter``
-    timestamp — the measured wall-clock the reference samples per iteration
-    (trainer.py:63,181), at eval granularity. Returns (final_state, gap_hist,
-    cons_hist, time_hist, realized_floats, executed_iters, compile_seconds,
-    run_seconds, trace, cost) — ``executed_iters`` counts only iterations
-    run in THIS process, so resumed runs report honest throughput;
-    ``time_hist`` is cumulative across installments (restored timestamps
-    carry an offset); ``trace``/``cost`` are the flight-recorder buffers
-    and XLA cost analysis (None when ``config.telemetry`` is off).
-    """
-    from distributed_optimization_tpu.parallel.mesh import (
-        replicate as _replicate,
-        shard_over_workers as _shard,
-    )
-    from distributed_optimization_tpu.utils.checkpoint import RunCheckpointer
-
-    eval_every = config.eval_every
-    ckptr = None
-    if checkpoint is not None:
-        ckptr = RunCheckpointer(checkpoint)
-        if checkpoint.resume:
-            ckptr.validate_or_record_config(config)
-        else:
-            # Explicit fresh start: clear stale chunks (they would poison a
-            # later resume) and rewrite the sidecar instead of validating.
-            ckptr.reset(config)
-    ts_row0 = _replicate(mesh, jnp.arange(eval_every, dtype=jnp.int32))
-
-    spans.enter("compile")
-    t0 = time.perf_counter()
-    with jax.default_matmul_precision(config.matmul_precision):
-        lowered = jax.jit(chunk).lower(state0, ts_row0, data_args)
-        cost = cost_from_lowered(lowered) if config.telemetry else None
-        compiled = lowered.compile()
-    compile_seconds = time.perf_counter() - t0 if measure_compile else 0.0
-    spans.enter("prepare")
-
-    state = state0
-    gap_list: list[float] = []
-    cons_list: list[float] = []
-    floats_list: list[float] = []
-    time_list: list[float] = []
-    trace_lists: dict[str, list] = {}
-    start_chunk = 0
-    if ckptr is not None and checkpoint.resume:
-        restored = ckptr.restore()
-        if restored is not None:
-            state_np, gaps, conss, floats, times, start_chunk = restored
-            if start_chunk > n_evals:
-                raise ValueError(
-                    f"checkpoint at chunk {start_chunk} exceeds this run's "
-                    f"horizon of {n_evals} chunks (n_iterations shrank below "
-                    "the checkpointed progress)"
-                )
-            state = _shard(mesh, _restored_state(state_np, state0))
-            gap_list = [float(v) for v in gaps]
-            cons_list = [float(v) for v in conss]
-            floats_list = [float(v) for v in floats]
-            time_list = [float(v) for v in times]
-
-    # Cumulative-time offset from previous installments of a resumed run.
-    time_offset = time_list[-1] if time_list else 0.0
-    spans.enter("upload_wait")
-    jax.block_until_ready((state, data_args))
-    spans.enter("scan")
-    t1 = time.perf_counter()
-    save_seconds = 0.0  # cumulative orbax-save time, excluded from stamps
-    done = start_chunk
-    for c in range(start_chunk, n_evals):
-        ts = _replicate(
-            mesh,
-            jnp.arange(c * eval_every, (c + 1) * eval_every, dtype=jnp.int32),
+def _on_cadence_rows(ys, n_seg_evals, trips_per_eval):
+    """On-cadence rows of one segment's stacked outputs, on the host:
+    ``({"gap", "cons", "floats"} as recorded, trace buffers)``. The scan
+    evaluates every trip, so only every ``trips_per_eval``-th row sits on
+    an eval boundary: the others hold real evals the requested cadence
+    discards, and the trace selects the same rows. Faults' realized floats
+    are summed per eval."""
+    sel = slice(trips_per_eval - 1, None, trips_per_eval)
+    rows = {
+        k: np.asarray(ys[k][sel], dtype=np.float64)
+        for k in ("gap", "cons") if k in ys
+    }
+    if "floats" in ys:
+        rows["floats"] = (
+            np.asarray(ys["floats"], dtype=np.float64)
+            .reshape(n_seg_evals, trips_per_eval).sum(axis=1)
         )
-        state, out = compiled(state, ts, data_args)
-        if "gap" in out:
-            gap_list.append(float(out["gap"]))
-        if "cons" in out:
-            cons_list.append(float(out["cons"]))
-        if "floats" in out:
-            floats_list.append(float(out["floats"]))
-        if "trace" in out:
-            for k, v in out["trace"].items():
-                trace_lists.setdefault(k, []).append(np.asarray(v))
-        # The metric fetches above already forced the chunk to completion;
-        # sync explicitly anyway so the timestamp is honest when metrics
-        # collection is off. Earlier saves' durations are subtracted — they
-        # are checkpoint I/O, not optimization time (round-5 advisor fix,
-        # matching the segmented path's accounting).
-        jax.block_until_ready(state)
-        time_list.append(time_offset + time.perf_counter() - t1 - save_seconds)
-        done = c + 1
-        if progress_hook is not None and (
-            done % progress_every == 0 or done == n_evals
-        ):
-            # The chunk loop is already host-synced per eval, so the
-            # heartbeat costs only the callback itself — but the cadence
-            # contract (one heartbeat per progress_every eval-chunks) is
-            # the same as the segmented/batched/async paths'.
-            progress_hook(done, gap_list, cons_list, time_list[-1])
-        if ckptr is not None and (
-            done % checkpoint.every_evals == 0 or done == n_evals
-        ):
-            t_save = time.perf_counter()
-            ckptr.save(
-                done, _flat_rows(_fetch_to_host(state)),
-                gap_list, cons_list, floats_list, time_list,
-            )
-            save_seconds += time.perf_counter() - t_save
-        if halt_check is not None and halt_check():
-            # Early-halt policy (ISSUE-13): a fatal anomaly stops the run
-            # at this eval-chunk boundary — the executed prefix is the
-            # full run's prefix (same compiled chunk, same carries), the
-            # remaining chunks just never execute.
-            break
-    run_seconds = time.perf_counter() - t1 - save_seconds
-    spans.enter("harvest")
-
-    gap_hist = np.asarray(gap_list, dtype=np.float64)
-    cons_hist = np.asarray(cons_list, dtype=np.float64) if cons_list else None
-    time_hist = np.asarray(time_list, dtype=np.float64)
-    realized_floats = float(np.sum(floats_list)) if floats_list else None
-    executed_iters = (done - start_chunk) * eval_every
-    trace = (
-        {k: np.stack(v) for k, v in trace_lists.items()}
-        if trace_lists else None
-    )
-    return (state, gap_hist, cons_hist, time_hist, realized_floats,
-            executed_iters, compile_seconds, run_seconds, trace, cost)
+    return rows, {k: np.asarray(v)[sel] for k, v in ys.get("trace", {}).items()}
 
 
-def _run_segmented_fused(
-    make_seg_scan, harvest, state0, data_args, checkpoint, mesh, config,
-    n_evals, measure_compile, spans, *, progress_hook=None, progress_every=1,
-    exec_cache=None, cache_key_fn=None, halt_check=None,
+def _drive_segments(
+    make_seg_scan, trips_per_eval, state0, data_args, mesh, config, n_evals,
+    spans, *, checkpoint, measure_timestamps, progress_hook, progress_every,
+    halt_check, exec_cache, cache_key_fn, measure_compile,
 ):
-    """Segmented execution of the flat fused scan (round 4 — VERDICT r3
-    item 5; generalized for ISSUE-10 progress streaming).
+    """The driver of the sequential scan: the run as segments of whole
+    eval-chunks of ONE program, ``make_seg_scan(size)(state, t0, data)``,
+    whose iteration offset ``t0`` is an argument, so one executable serves
+    every segment of its size and a run split at eval boundaries is bitwise
+    the unsplit run (tests/test_segments.py).
 
-    The round-2/3 design forced every checkpointed run through the
-    host-driven chunk loop — one compiled call + host sync per eval chunk —
-    which the round-3 root-cause measurements put at 2.2× slower than the
-    flat fused scan at coarse cadence (docs/PERF.md §root-cause). Here a
-    checkpointed run executes ``checkpoint.every_evals`` eval-chunks per
-    compiled call through the SAME flat microchunk scan the fused path
-    uses (iteration indices offset by a traced ``t0``, so one executable
-    serves every segment), with the orbax save between segments. The host
-    intervenes once per SAVE, not once per eval; per-eval wall-clock inside
-    a segment is interpolated (``time_measured=False``) — opt into
-    ``measure_timestamps=True`` for real per-eval samples via the chunk
-    loop, accepting its measured cost.
+    A segment is as long as what the caller asked for lets it be: one eval
+    under ``measure_timestamps`` (a real ``perf_counter`` stamp per eval),
+    else ``checkpoint.every_evals``, else ``progress_every`` when a
+    heartbeat is wanted, else the whole run. Only a segment's end is a
+    real stamp; the evals inside it are spread evenly. The host looks at a
+    boundary only where someone reads it: the rows come down for a
+    heartbeat (``progress_hook(done_evals, gap_list, cons_list, elapsed)``
+    every ``progress_every`` evals of this call and at the end) or a save
+    (every ``checkpoint.every_evals`` and at the end), and otherwise stay
+    on the device until the run is over. ``halt_check()`` is asked at
+    every boundary; on a halt the executed prefix is the full run's.
 
-    Progress streaming (ISSUE-10) runs THIS path with ``checkpoint=None``:
-    segments of ``progress_every`` eval-chunks, a heartbeat
-    (``progress_hook(done_evals, gap_list, cons_list, elapsed)``) after
-    each — the identical compiled program split at eval boundaries, so
-    trajectories are bitwise the one-shot run's (the continuation
-    contract, asserted in tests/test_observatory.py). With progress on
-    the segmented executables are cacheable (``exec_cache`` +
-    ``cache_key_fn(size)``): the serving daemon heartbeats every request,
-    so the progress path must amortize compiles like the one-shot path.
+    Executables are looked up (``exec_cache``, ``cache_key_fn(segment=size)``)
+    or compiled, one per distinct size, before the clock starts. The
+    ``scan`` span opens once the inputs are resident and closes on the
+    last segment's final state: its duration less the saves inside it is
+    ``run_seconds`` AND the last stamp, one clock read once; the last
+    boundary's rows, heartbeat and save fall in ``harvest``. Saves are
+    checkpoint I/O, not optimization time, and are subtracted from every
+    later stamp.
 
     Returns (final_state, gap_hist, cons_hist, time_hist, realized_floats,
-    executed_iters, compile_seconds, run_seconds, trace, cost);
-    ``executed_iters`` counts only iterations run in THIS process (resumed
-    runs report honest throughput); ``trace``/``cost`` are the flight-
-    recorder buffers and XLA cost analysis (None when ``config.telemetry``
-    is off — and always None for checkpointed runs, which reject
-    telemetry upstream).
+    executed_iters, compile_seconds, run_seconds, trace, cost).
+    ``executed_iters`` counts only iterations run in THIS process, so a
+    resumed run reports honest throughput; ``time_hist`` is cumulative
+    across installments (the restored stamps are its offset);
+    ``trace``/``cost`` are the flight-recorder buffers and XLA cost
+    analysis (None when ``config.telemetry`` is off).
     """
-    from distributed_optimization_tpu.parallel.mesh import (
-        replicate as _replicate,
-        shard_over_workers as _shard,
-    )
-
     eval_every = config.eval_every
+    state = state0
+    # Per-eval rows as Python floats, in the order a checkpoint holds them.
+    hist = {"gap": [], "cons": [], "floats": [], "time": []}
+    trace_lists: dict[str, list] = {}
+    start_chunk = 0
     ckptr = None
     if checkpoint is not None:
         from distributed_optimization_tpu.utils.checkpoint import (
@@ -1049,158 +927,166 @@ def _run_segmented_fused(
         )
 
         ckptr = RunCheckpointer(checkpoint)
+        restored = None
         if checkpoint.resume:
             ckptr.validate_or_record_config(config)
+            restored = ckptr.restore()
         else:
+            # Explicit fresh start: clear stale chunks (they would poison a
+            # later resume) and rewrite the sidecar instead of validating.
             ckptr.reset(config)
-
-    state = state0
-    gap_list: list[float] = []
-    cons_list: list[float] = []
-    floats_list: list[float] = []
-    time_list: list[float] = []
-    trace_lists: dict[str, list] = {}
-    start_chunk = 0
-    if ckptr is not None and checkpoint.resume:
-        restored = ckptr.restore()
         if restored is not None:
-            state_np, gaps, conss, floats, times, start_chunk = restored
+            state_np, *rows, start_chunk = restored
             if start_chunk > n_evals:
                 raise ValueError(
                     f"checkpoint at chunk {start_chunk} exceeds this run's "
                     f"horizon of {n_evals} chunks (n_iterations shrank below "
                     "the checkpointed progress)"
                 )
-            state = _shard(mesh, _restored_state(state_np, state0))
-            gap_list = [float(v) for v in gaps]
-            cons_list = [float(v) for v in conss]
-            floats_list = [float(v) for v in floats]
-            time_list = [float(v) for v in times]
+            state = shard_over_workers(
+                mesh, _restored_state(state_np, state0)
+            )
+            hist = {k: [float(v) for v in r] for k, r in zip(hist, rows)}
+    # Cumulative-time offset from previous installments of a resumed run.
+    time_offset = hist["time"][-1] if hist["time"] else 0.0
 
     remaining = n_evals - start_chunk
-    seg_evals = (
-        min(checkpoint.every_evals, max(remaining, 1))
-        if checkpoint is not None
-        else min(max(int(progress_every), 1), max(remaining, 1))
-    )
+    if measure_timestamps:
+        seg_evals = 1
+    elif checkpoint is not None:
+        seg_evals = checkpoint.every_evals
+    elif progress_hook is not None:
+        seg_evals = int(progress_every)
+    else:
+        seg_evals = remaining
+    seg_evals = max(min(seg_evals, remaining), 1)
+    # (size, t0) of every segment; the offsets go up before the clock.
+    segments = [
+        (
+            min(seg_evals, n_evals - first),
+            replicate(mesh, jnp.asarray(first * eval_every, dtype=jnp.int32)),
+        )
+        for first in range(start_chunk, n_evals, seg_evals)
+    ]
 
-    # AOT-compile every segment size this run needs (the full segment plus
-    # a possible trailing remainder) before the timer starts, so compile and
-    # steady-state stay separable. One executable serves all same-size
-    # segments because the iteration offset is a traced argument.
-    sizes = set()
-    if remaining > 0:
-        sizes.add(min(seg_evals, remaining))
-        if remaining % seg_evals:
-            sizes.add(remaining % seg_evals)
-    t0c = time.perf_counter()
-    t0_probe = _replicate(mesh, jnp.asarray(0, dtype=jnp.int32))
+    # AOT, so that compile time and steady state are separable: at most two
+    # sizes, the full segment and a trailing remainder.
     compiled_by_size = {}
     cost = None
     cold_compile = 0.0
+    spans.note_root(cache="off" if exec_cache is None else "hit")
     with jax.default_matmul_precision(config.matmul_precision):
-        for size in sorted(sizes):
-            spans.enter("cache_lookup")
-            key = cache_key_fn(size) if (
-                exec_cache is not None and cache_key_fn is not None
-            ) else None
-            cached = exec_cache.get(key) if key is not None else None
+        for size in sorted({size for size, _ in segments}):
+            key = cached = None
+            if exec_cache is not None:
+                spans.enter("cache_lookup")
+                key = cache_key_fn(segment=size)
+                cached = exec_cache.get(key)
             if cached is not None:
                 compiled_by_size[size] = cached.executable
                 if config.telemetry and cost is None:
                     cost = cached.cost
                 continue
             spans.enter("compile")
-            if key is not None:
+            if exec_cache is not None:
                 spans.note_root(cache="miss")
             t_cold = time.perf_counter()
             lowered = jax.jit(make_seg_scan(size)).lower(
-                state, t0_probe, data_args
+                state, segments[0][1], data_args
             )
-            size_cost = (
-                cost_from_lowered(lowered) if config.telemetry else None
-            )
+            size_cost = cost_from_lowered(lowered) if config.telemetry else None
             if cost is None:
                 cost = size_cost
             compiled_by_size[size] = lowered.compile()
             this_cold = time.perf_counter() - t_cold
             cold_compile += this_cold
-            if key is not None:
+            if exec_cache is not None:
                 exec_cache.put(
                     key, compiled_by_size[size], cost=size_cost,
                     compile_seconds=this_cold,
                 )
     compile_seconds = cold_compile if measure_compile else 0.0
 
-    time_offset = time_list[-1] if time_list else 0.0
+    pending = []  # (ys, size) of the segments whose rows are on the device
+
+    def fetch_rows():
+        for ys, size in pending:
+            rows, trace_seg = _on_cadence_rows(ys, size, trips_per_eval)
+            for k, v in rows.items():
+                hist[k].extend(v.tolist())
+            for k, v in trace_seg.items():
+                trace_lists.setdefault(k, []).append(v)
+        pending.clear()
+
+    # The shards' copy drains here, after all host preparation and outside
+    # the scan's clock: the program could not start before its inputs were
+    # resident anyway, but ``run_seconds`` (and so ``iters_per_second``)
+    # does not count the copy.
     spans.enter("upload_wait")
     jax.block_until_ready((state, data_args))
-    # The segments' own harvests and saves fall inside this span.
-    spans.enter("scan")
-    t1 = time.perf_counter()
-    save_seconds = 0.0  # cumulative orbax-save time, excluded from stamps
+    scan = spans.enter("scan")
+    save_seconds = 0.0  # orbax saves inside the scan span
+    run_seconds = 0.0
     done = start_chunk
-    while done < n_evals:
-        this_evals = min(seg_evals, n_evals - done)
-        t0_iter = _replicate(
-            mesh, jnp.asarray(done * eval_every, dtype=jnp.int32)
+    last = False
+    for size, t0 in segments:
+        state, ys = compiled_by_size[size](state, t0, data_args)
+        state = jax.block_until_ready(state)
+        pending.append((ys, size))
+        done += size
+        last = done == n_evals
+        prev = time_offset + run_seconds
+        if last:
+            spans.enter("harvest")
+            run_seconds = scan["duration"] - save_seconds
+        else:
+            run_seconds = (
+                time.perf_counter() - scan["start"] - save_seconds
+            )
+        stamp = time_offset + run_seconds
+        stamps = np.linspace(prev + (stamp - prev) / size, stamp, size)
+        stamps[-1] = stamp
+        hist["time"].extend(stamps.tolist())
+        evals_here = done - start_chunk
+        beat = progress_hook is not None and (
+            last or evals_here % progress_every == 0
         )
-        state, ys = compiled_by_size[this_evals](state, t0_iter, data_args)
-        gap, cons, floats, trace_seg = harvest(ys, this_evals)
-        if gap is not None:
-            gap_list.extend(gap.tolist())
-        if cons is not None:
-            cons_list.extend(cons.tolist())
-        if floats is not None:
-            floats_list.extend(floats.tolist())
-        if trace_seg is not None:
-            for k, v in trace_seg.items():
-                trace_lists.setdefault(k, []).append(np.asarray(v))
-        jax.block_until_ready(state)
-        done += this_evals
-        # Per-eval timestamps are interpolated within the segment (the scan
-        # runs without host syncs); only the segment boundary is a real
-        # sample. The restored cumulative offset carries across installments
-        # like the chunk loop's. Earlier segments' orbax-save durations are
-        # subtracted (round-5 advisor fix: they are checkpoint I/O, not
-        # optimization time — without this every segment after the first
-        # folded prior saves into its stamps and into run_seconds, so
-        # checkpointed iters/sec silently included checkpoint I/O).
-        seg_end = time_offset + time.perf_counter() - t1 - save_seconds
-        prev = time_list[-1] if time_list else time_offset
-        time_list.extend(
-            np.linspace(prev + (seg_end - prev) / this_evals, seg_end,
-                        this_evals).tolist()
+        save = ckptr is not None and (
+            last or evals_here % checkpoint.every_evals == 0
         )
-        if progress_hook is not None:
-            progress_hook(done, gap_list, cons_list, seg_end)
-        if ckptr is not None:
+        if beat or save:
+            fetch_rows()
+        if beat:
+            progress_hook(done, hist["gap"], hist["cons"], stamp)
+        if save:
             t_save = time.perf_counter()
             ckptr.save(
-                done, _flat_rows(_fetch_to_host(state)),
-                gap_list, cons_list, floats_list, time_list,
+                done, _flat_rows(_fetch_to_host(state)), hist["gap"],
+                hist["cons"], hist["floats"], hist["time"],
             )
             save_seconds += time.perf_counter() - t_save
         if halt_check is not None and halt_check():
             # Early-halt policy (ISSUE-13): a fatal anomaly fired on this
-            # segment's heartbeat — stop at the boundary. The executed
-            # prefix is the one-shot program's prefix (the continuation
-            # contract); the remaining segments never execute.
+            # boundary's heartbeat. The executed prefix is the full run's
+            # prefix; the remaining segments never execute.
             break
-    run_seconds = time.perf_counter() - t1 - save_seconds
-    spans.enter("harvest")
+    if not last:
+        spans.enter("harvest")
+    fetch_rows()
 
-    gap_hist = np.asarray(gap_list, dtype=np.float64) if gap_list else None
-    cons_hist = np.asarray(cons_list, dtype=np.float64) if cons_list else None
-    time_hist = np.asarray(time_list, dtype=np.float64)
-    realized_floats = float(np.sum(floats_list)) if floats_list else None
-    executed_iters = (done - start_chunk) * eval_every
-    trace = (
+    return (
+        state,
+        np.asarray(hist["gap"], dtype=np.float64) if hist["gap"] else None,
+        np.asarray(hist["cons"], dtype=np.float64) if hist["cons"] else None,
+        np.asarray(hist["time"], dtype=np.float64),
+        float(np.sum(hist["floats"])) if hist["floats"] else None,
+        (done - start_chunk) * eval_every,
+        compile_seconds,
+        run_seconds,
         {k: np.concatenate(v, axis=0) for k, v in trace_lists.items()}
-        if trace_lists else None
+        or None,
+        cost,
     )
-    return (state, gap_hist, cons_hist, time_hist, realized_floats,
-            executed_iters, compile_seconds, run_seconds, trace, cost)
 
 
 class _RunSpans:
@@ -1270,8 +1156,6 @@ def run(
     checkpoint=None,
     measure_timestamps: Optional[bool] = None,
     return_state: bool = False,
-    hoisted_min_ratio: Optional[float] = None,
-    eval_hoist_limit: Optional[int] = None,
     executable_cache=None,
     progress_cb=None,
     progress_every: int = 1,
@@ -1279,58 +1163,53 @@ def run(
 ) -> BackendRunResult:
     """Run one experiment on the JAX backend; returns histories + final models.
 
-    ``monitors`` (ISSUE-13 anomaly sentinel): an
-    ``observability.monitors.MonitorBank`` observing the run's heartbeats
-    online. With a bank installed the run executes through the SAME
-    segmented progress machinery as ``progress_cb`` (off, and on with
-    nothing firing, are bitwise the one-shot program — the progress
-    contract), detectors fire structured anomalies into the bank, and
-    under ``halt_on='fatal'`` a fatal anomaly stops the run at the next
-    chunk boundary with the executed prefix returned as a partial
-    result (``monitors.halted_at`` records where). Trace-derived
-    detectors are fed the flight-recorder buffers after the run when
-    ``config.telemetry`` is on.
+    A synchronous run is ONE device program, the flat scan with the eval
+    inline (``_run``), driven as segments of whole eval-chunks
+    (``_drive_segments``). The keywords below choose where the segments
+    end, never another program: however a run is split, its trajectory and
+    final models are bitwise the unsplit run's.
 
-    ``progress_cb`` (ISSUE-10 live observatory): a host callback receiving
-    one ``observability.progress.ProgressEvent`` every ``progress_every``
-    eval-chunks on ALL paths — the fused paths then execute as segments
-    of the SAME compiled scan split at eval boundaries (trajectories stay
-    bitwise-identical to the one-shot program, asserted); the measured
-    chunked loop and the async event loop are host-synced per eval
-    already and just invoke the callback at the same cadence. ``None``
-    (default) changes nothing: same code path, same compiled program —
-    the ``config.telemetry`` discipline.
+    ``measure_timestamps=True`` makes every segment one eval, so the
+    history carries a real ``perf_counter`` stamp per eval
+    (``time_measured=True``) at the price of one host round-trip per
+    ``eval_every`` iterations. The default (``None`` == ``False``) spreads
+    each segment's time evenly over its evals (``time_measured=False``).
+
+    ``checkpoint`` (``utils.checkpoint.CheckpointOptions``): segments of
+    ``every_evals`` eval-chunks with an orbax save after each and after
+    the last; with ``resume`` the run continues from the latest intact
+    chunk. Under ``measure_timestamps`` the segments stay one eval and the
+    saves keep their cadence.
+
+    ``progress_cb`` (the live observatory): a host callback receiving one
+    ``observability.progress.ProgressEvent`` every ``progress_every``
+    eval-chunks and at the end; without a checkpoint that is the segment
+    size. ``None`` (default) is the whole run in one segment. The async
+    event loop honours the same cadence.
+
+    ``monitors``: an ``observability.monitors.MonitorBank`` observing the
+    run's heartbeats online (it rides ``progress_cb``'s segments).
+    Detectors fire structured anomalies into the bank, and under
+    ``halt_on='fatal'`` a fatal anomaly stops the run at the next segment
+    boundary with the executed prefix returned as a partial result
+    (``monitors.halted_at`` records where). Trace-derived detectors are
+    fed the flight-recorder buffers after the run when ``config.telemetry``
+    is on.
 
     ``executable_cache`` controls AOT compile reuse (docs/SERVING.md): the
     default ``None`` consults the process-wide
     ``serving.cache.process_executable_cache()`` — a repeated identical run
     in one process re-executes the cached compiled program instead of
     re-tracing and re-compiling it (bitwise-identical results; the cache
-    key pins the full config, f*, data/mesh signatures and the jax
-    environment, so anything that could change the program misses).
-    ``False`` forces a cold compile (benches that MEASURE compile cost use
-    this); an ``ExecutableCache`` instance scopes reuse explicitly (the
-    serving layer passes its own). Only the fused no-checkpoint path
-    caches; the chunked/segmented forms always compile. On a cache hit
+    key pins the full config, f*, data/mesh signatures, the jax
+    environment and the segment's size, so anything that could change the
+    program misses). ``False`` forces a cold compile (benches that MEASURE
+    compile cost use this); an ``ExecutableCache`` instance scopes reuse
+    explicitly (the serving layer passes its own). One-shot and heartbeat
+    runs consult the cache — a heartbeat run whose one segment is the whole
+    run hits the one-shot run's executable; checkpointed and
+    ``measure_timestamps`` runs always compile. On a cache hit
     ``history.compile_seconds`` is 0.0.
-
-    ``hoisted_min_ratio`` / ``eval_hoist_limit`` override the module-level
-    eval-cadence-form defaults (HOISTED_MIN_RATIO / EVAL_HOIST_LIMIT) for
-    THIS run only — e.g. ``hoisted_min_ratio=0.0`` forces the hoisted
-    exact-cadence form, ``eval_hoist_limit=0`` forces inline; ``None``
-    keeps the measured defaults.
-
-    ``measure_timestamps=True`` executes eval-chunks under a host-driven loop
-    recording a real ``perf_counter`` timestamp per eval (one host sync per
-    ``eval_every`` iterations) instead of the fully fused scan; the returned
-    history then carries measured wall-clock (``time_measured=True``) rather
-    than a linspace interpolation of the total run time. The default
-    (``None`` == ``False``) is the fused scan at every cadence: since the
-    round-3 flat restructuring fixed the nested-loop pipelining defect, the
-    fused path is the fastest at EVERY eval cadence (measured 2.2× the
-    chunked loop at eval_every=50k — docs/PERF.md "root cause" section), so
-    the former coarse-cadence auto-routing is gone; measured timestamps are
-    purely opt-in.
 
     A float64 config runs under a scoped ``enable_x64`` — without it jax
     silently truncates every array to float32, defeating the fidelity dtype.
@@ -1371,58 +1250,22 @@ def run(
             measure_compile=measure_compile, checkpoint=checkpoint,
             measure_timestamps=measure_timestamps,
             return_state=return_state,
-            hoisted_min_ratio=hoisted_min_ratio,
-            eval_hoist_limit=eval_hoist_limit,
             executable_cache=executable_cache,
             progress_cb=progress_cb, progress_every=progress_every,
             monitors=monitors,
         )
 
 
-# Eval-cadence forms for the fused scan (round 5 — VERDICT r4 item 6).
-# The flat microchunk computes the full-dataset eval INLINE every `micro`
-# iterations regardless of cadence. The inline eval feeds only the scan's
-# stacked outputs (never the carry), so XLA can overlap it with subsequent
-# steps. Two exact-cadence alternatives exist:
-# - HOISTED (a Python-unrolled SEQUENCE of eval-free flat scans with the
-#   eval between them — one XLA program, no nested/conditional control
-#   flow in any hot loop body, eval exactly on cadence);
-# - chunk loop (measure_timestamps=True): one host round-trip per eval.
-#   Never a routing target; it exists for real per-eval timestamps.
-#
-# The only comparison on record (docs/perf/eval_cadence.json, 2026-07,
-# under an earlier runtime that charged every scan region and host
-# round-trip a fixed dispatch cost) had inline win every cell; the forms
-# are NOT MEASURED on the current machine. HOISTED_MIN_RATIO therefore
-# stays at infinity — nothing selects the hoisted form by default — and
-# the machinery stays (exact-cadence semantics, resume-exact, tested)
-# until a chip measurement keeps or deletes it (ROADMAP C2). These module
-# constants are IMMUTABLE defaults: override per run via the
-# ``hoisted_min_ratio`` / ``eval_hoist_limit`` kwargs of ``run()`` (tests
-# and examples/bench_eval_cadence.py force forms that way — nothing
-# mutates the globals, so concurrent runs cannot race on them).
-# EVAL_HOIST_LIMIT bounds program size (64 unrolled scan+eval segments).
-EVAL_HOIST_LIMIT = 64
-HOISTED_MIN_RATIO = float("inf")
-
-
-# Mixing-impl history (why there is no TPU-specific resolver here): round 1
-# (gather era) the fused pallas ring kernel won decisively at the headline
-# shape; round 2 (dense sampling) pallas and stencil tied within chip
-# noise; round 3 (flat fused scan) stencil measured ~10% ahead at d=81 and
-# pallas ~13% ahead at d=1024 — one session each, which became a "d >= 512"
-# auto-gate. Round 5 settled it with the interleaved 7-dim sweep the
-# round-3 bracket asked for (d ∈ {81..1024},
-# ``docs/perf/pallas_regimes.json``): the e2e pallas/stencil ratio bounces
-# 0.78–1.29 with NO trend across adjacent dims — run-to-run noise — and
-# the round-3 d=1024 win does not replicate (0.78 in the sweep). There is
-# no crossover to gate on, so ``mixing_impl`` passes straight through to
-# ``make_mixing_op`` ('auto' → stencil where the graph embeds as mesh
-# shifts, else dense) and the VMEM kernels are explicit opt-in
-# (``mixing_impl='pallas'``, f32 whole-array envelope only — Mosaic
-# refuses the ring kernels' rotate in bf16 ("Rotate with non-32-bit
+# Why there is no TPU-specific mixing resolver here: ``mixing_impl`` passes
+# straight through to ``make_mixing_op`` ('auto' -> stencil where the graph
+# embeds as mesh shifts, else dense). The interleaved sweep over d in
+# {81..1024} (``docs/perf/pallas_regimes.json``) has the end-to-end
+# pallas/stencil ratio bounce 0.78-1.29 with no trend across adjacent
+# dims, so there is no crossover to gate on, and the VMEM kernels are an
+# explicit opt-in (``mixing_impl='pallas'``, f32 whole-array envelope only:
+# Mosaic refuses the ring kernels' rotate in bf16 ("Rotate with non-32-bit
 # data", libtpu 0.0.34), and operands live unblocked in VMEM, so the
-# softmax tier's d·K-wide models are out of range).
+# softmax tier's d*K-wide models are out of range).
 
 
 def _run(
@@ -1439,8 +1282,6 @@ def _run(
     checkpoint=None,
     measure_timestamps: Optional[bool] = None,
     return_state: bool = False,
-    hoisted_min_ratio: Optional[float] = None,
-    eval_hoist_limit: Optional[int] = None,
     executable_cache=None,
     progress_cb=None,
     progress_every: int = 1,
@@ -1451,14 +1292,9 @@ def _run(
     ``mesh``: an explicit ``jax.sharding.Mesh`` (1-D, axis 'workers');
     ``use_mesh=True`` builds one over all visible devices that evenly divide
     N. ``batch_schedule [T, N, b]`` injects fixed batch indices (equivalence
-    testing vs the numpy oracle — SURVEY.md §4c). ``checkpoint``: a
-    ``utils.checkpoint.CheckpointOptions``; when given, the run executes the
-    flat fused scan in SEGMENTS of ``every_evals`` eval-chunks with an orbax
-    save (and resume) between segments — add ``measure_timestamps=True`` to
-    instead use the host-driven chunk loop with real per-eval timestamps,
-    at its measured 2.2× coarse-cadence cost (docs/PERF.md §root-cause).
-    ``spans``: the call's ``dopt.run`` root (``_run_spans``); each stretch
-    of this function runs under the child span that names it.
+    testing vs the numpy oracle — SURVEY.md §4c). ``spans``: the call's
+    ``dopt.run`` root (``_run_spans``); each stretch of this function runs
+    under the child span that names it.
     """
     spans.enter("prepare")
     if config.telemetry and checkpoint is not None:
@@ -1809,12 +1645,7 @@ def _run(
         collect_metrics and algo.is_decentralized and config.record_consensus
     )
     eval_every = config.eval_every
-    # The chunked (host-driven) path nests a scan per chunk; split the unroll
-    # budget so the total unrolled step bodies stay ~scan_unroll (not
-    # scan_unroll²). The fused path below does NOT nest — see _flat_micro.
     scan_unroll = config.resolved_scan_unroll(jax.devices()[0].platform)
-    inner_unroll = min(scan_unroll, eval_every)
-    outer_unroll = max(1, scan_unroll // eval_every)
 
     honest_w = None
     if adversary is not None:
@@ -1859,112 +1690,34 @@ def _run(
         compressed_mix=compressed_mix,
     )
 
-    def make_step_eval(data):
-        return _make_step_eval(pieces, data)
-
-    def make_chunk(data):
-        """One eval-chunk for the host-driven loop: ``eval_every`` iterations
-        of pure optimization under a nested scan, then one on-device metric
-        evaluation — the eval-cadence knob SURVEY.md §7 hard part (b) calls
-        for (the reference evaluates every iteration; k=1 reproduces that
-        exactly)."""
-        step, eval_metrics, floats_for = make_step_eval(data)
-
-        def chunk(state, ts):
-            state, _ = jax.lax.scan(step, state, ts, unroll=inner_unroll)
-            out = eval_metrics(state, ts[-1], cadence_known=True)
-            if faulty is not None:
-                out["floats"] = floats_for(ts)
-            return state, out
-
-        return chunk
-
     n_evals = T // eval_every
+    measure_timestamps = bool(measure_timestamps)
 
-    # The default is the fused scan at every cadence (see ``run``'s
-    # docstring: the flat restructuring removed the coarse-cadence defect
-    # that round 2's auto-routing worked around); measured timestamps are
-    # opt-in because the host-driven loop pays one host round-trip per
-    # eval chunk — never a routing target (see the eval-cadence note above
-    # the run() helpers).
-    if measure_timestamps is None:
-        measure_timestamps = False
-
-    # Quantities for the eval-cadence form choice (round 5 — see
-    # EVAL_HOIST_LIMIT / HOISTED_MIN_RATIO above). Checkpointed runs hoist
-    # per SEGMENT (each compiled scan covers every_evals eval-chunks), so
-    # the hoist-availability gate uses the per-scan eval count, not the
-    # run total.
-    _micro_probe, _trips_per_eval, _flat_unroll = _flat_scan_cadence(
+    # The device program, the only one the sequential path has: ONE flat
+    # scan over micro-chunks of ``micro`` Python-unrolled steps with the
+    # metric eval computed INLINE every trip — never a scan nested inside a
+    # scan, and no lax.cond round the eval. Non-flat control flow in the hot
+    # loop body defeats XLA:TPU's pipelining across iterations: a scan of
+    # steps nested under a loop of chunks ran identical fusions ~6.4x
+    # slower per execution, and a cond-guarded eval re-serialized the loop
+    # harder still (docs/PERF.md "root cause"). The inline eval feeds only
+    # the scan's stacked outputs, never the carry, so it overlaps the next
+    # steps; the off-cadence rows are discarded on the host
+    # (``_on_cadence_rows``). ``micro`` is the largest divisor of
+    # eval_every within the unroll budget, so some trip lands exactly on
+    # every eval boundary; at eval_every=1 this is the plain step scan.
+    micro, trips_per_eval, flat_unroll = _flat_scan_cadence(
         scan_unroll, eval_every
     )
-    per_scan_evals = (
-        n_evals if checkpoint is None
-        else min(checkpoint.every_evals, max(n_evals, 1))
-    )
-    total_samples = float(np.sum(device_data.n_valid))
-    eval_dominance_ratio = total_samples / max(
-        2.0 * _micro_probe * n
-        * min(batch_size, device_data.X.shape[1]), 1.0
-    )
 
-    if not measure_timestamps:
-        # FLAT fused scan (round-3 anomaly fix — mechanism and measurements
-        # in docs/PERF.md §"root cause"): the run is ONE scan over
-        # micro-chunks of ``micro`` Python-unrolled steps with the metric
-        # eval computed INLINE every trip — never a scan nested inside a
-        # scan, and no lax.cond in the body. Both alternatives measured
-        # badly on the chip, for the same reason: non-flat control flow in
-        # the hot loop body defeats XLA:TPU's inter-iteration pipelining.
-        # The round-2 nested form (outer chunks × inner step scan) ran
-        # identical fusions ~6.4× slower per execution inside the nested
-        # while (device-trace evidence; 2.1× total device
-        # time), and a cond-guarded eval re-serialized the loop harder
-        # still (~23k vs ~47k iters/sec, same session). Computing the eval
-        # every trip is measured-free at this scale (the full-data pass is
-        # a few µs against a latency-bound step) and the off-cadence rows
-        # are discarded host-side; ``micro`` is the largest divisor of
-        # eval_every within the unroll budget so some trip lands exactly on
-        # every eval boundary. At k=1 this degenerates to exactly the old
-        # (always-fast) flat structure.
-        #
-        # Checkpointed runs (round 4 — VERDICT r3 item 5) run the SAME flat
-        # scan in segments of ``checkpoint.every_evals`` eval-chunks with an
-        # orbax save between segments, instead of paying the host-driven
-        # chunk loop's 2.2× coarse-cadence tax for the whole run; the host
-        # intervenes once per SAVE, not once per eval.
-        micro = _micro_probe
-        trips_per_eval = _trips_per_eval
-        flat_unroll = _flat_unroll
+    def make_seg_scan(n_seg_evals):
+        """``n_seg_evals`` eval-chunks of the flat scan, starting at the
+        iteration ``t0``: an argument, so one executable serves every
+        segment of this size, the whole run (``n_evals`` at 0) included."""
+        n_trips_seg = n_seg_evals * trips_per_eval
 
-        # Exact-cadence "hoisted" form (round 5 — VERDICT r4 item 6): a
-        # Python-unrolled SEQUENCE of eval-free flat scans with the metric
-        # eval computed between them. Applies only when the run is
-        # measured eval-DOMINATED (the per-region dispatch tax otherwise
-        # loses to inline's latency-hidden extra evals — see the
-        # eval-cadence note above the run() helpers), the inline form
-        # would compute more evals than the cadence asks for
-        # (trips_per_eval > 1), and the program stays small (evals per
-        # compiled scan <= the hoist limit). Checkpointed runs hoist per
-        # SEGMENT, so coarse-cadence checkpointed runs on huge datasets
-        # get exact-cadence evals even when the run's total eval count is
-        # large.
-        hoist_limit = (
-            EVAL_HOIST_LIMIT if eval_hoist_limit is None else eval_hoist_limit
-        )
-        min_ratio = (
-            HOISTED_MIN_RATIO if hoisted_min_ratio is None
-            else hoisted_min_ratio
-        )
-        use_hoisted = (
-            collect_metrics
-            and trips_per_eval > 1
-            and per_scan_evals <= hoist_limit
-            and eval_dominance_ratio >= min_ratio
-        )
-
-        def make_microchunk(data):
-            step, eval_metrics, floats_for = make_step_eval(data)
+        def seg_scan(state_init, t0, data):
+            step, eval_metrics, floats_for = _make_step_eval(pieces, data)
 
             def microchunk(state, ts_row):
                 for j in range(micro):
@@ -1976,262 +1729,59 @@ def _run(
                     out["floats"] = floats_for(ts_row)
                 return state, out
 
-            return microchunk
-
-        def make_hoisted_scan(n_evals_in):
-            """``n_evals_in`` eval-chunks as sequential flat scans inside
-            one traced program; iteration indices offset by a (possibly
-            traced) ``t0`` so one executable serves every same-size
-            segment. No scan nests inside a scan and no cond guards the
-            eval — the round-3 pipelining constraints hold; the eval just
-            moves from the scan body to between scans, running EXACTLY
-            once per cadence point."""
-
-            def hoisted(state_init, t0, data):
-                step, eval_metrics, floats_for = make_step_eval(data)
-
-                def micro_only(state, ts_row):
-                    for j in range(micro):
-                        state, _ = step(state, ts_row[j])
-                    return state, None
-
-                state, outs = state_init, []
-                for e in range(n_evals_in):
-                    ts = (
-                        t0 + e * eval_every
-                        + jnp.arange(eval_every, dtype=jnp.int32)
-                    ).reshape(trips_per_eval, micro)
-                    state, _ = jax.lax.scan(
-                        micro_only, state, ts, unroll=flat_unroll
-                    )
-                    out = eval_metrics(
-                        state, ts.reshape(-1)[-1], cadence_known=True
-                    )
-                    if faulty is not None:
-                        out["floats"] = floats_for(ts.reshape(-1))
-                    outs.append(out)
-                ys = jax.tree.map(lambda *vs: jnp.stack(vs), *outs)
-                return state, ys
-
-            return hoisted
-
-        def make_inline_seg_scan(n_seg_evals):
-            n_trips_seg = n_seg_evals * trips_per_eval
-
-            def seg_scan(state_init, t0, data):
-                microchunk = make_microchunk(data)
-                ts = (
-                    t0 + jnp.arange(n_trips_seg * micro, dtype=jnp.int32)
-                ).reshape(n_trips_seg, micro)
-                return jax.lax.scan(
-                    microchunk, state_init, ts, unroll=flat_unroll
-                )
-
-            return seg_scan
-
-        def _harvest_inline(ys, n_rows_evals):
-            """On-cadence metric rows from a scan's stacked outputs (the
-            off-cadence rows hold real inline-computed evals the requested
-            cadence discards); faults' realized floats summed per eval.
-            Trace-buffer rows select like the gap: the eval-boundary trip's
-            row is the recorded one."""
-            sel = slice(trips_per_eval - 1, None, trips_per_eval)
-            gap = (
-                np.asarray(ys["gap"][sel], dtype=np.float64)
-                if "gap" in ys else None
-            )
-            cons = (
-                np.asarray(ys["cons"][sel], dtype=np.float64)
-                if "cons" in ys else None
-            )
-            floats = (
-                np.asarray(ys["floats"], dtype=np.float64)
-                .reshape(n_rows_evals, trips_per_eval).sum(axis=1)
-                if "floats" in ys else None
-            )
-            trace = (
-                {k: np.asarray(v)[sel] for k, v in ys["trace"].items()}
-                if "trace" in ys else None
-            )
-            return gap, cons, floats, trace
-
-        def _harvest_hoisted(ys, n_rows_evals):
-            """Hoisted rows are already exactly per-eval."""
-            return (
-                np.asarray(ys["gap"], dtype=np.float64)
-                if "gap" in ys else None,
-                np.asarray(ys["cons"], dtype=np.float64)
-                if "cons" in ys else None,
-                np.asarray(ys["floats"], dtype=np.float64)
-                if "floats" in ys else None,
-                {k: np.asarray(v) for k, v in ys["trace"].items()}
-                if "trace" in ys else None,
+            ts = (
+                t0 + jnp.arange(n_trips_seg * micro, dtype=jnp.int32)
+            ).reshape(n_trips_seg, micro)
+            return jax.lax.scan(
+                microchunk, state_init, ts, unroll=flat_unroll
             )
 
-        make_seg_scan = (
-            make_hoisted_scan if use_hoisted else make_inline_seg_scan
+        return seg_scan
+
+    # ``path`` names what the caller asked for, not another program: real
+    # per-eval stamps (chunked), segments for saves or heartbeats
+    # (segmented), or neither (fused).
+    spans.note_root(path=(
+        "chunked" if measure_timestamps
+        else "fused" if checkpoint is None and progress_emit is None
+        else "segmented"
+    ))
+    # AOT executable reuse (docs/SERVING.md): the program bakes its PRNG
+    # key, scalars and f*, so the key is the FULL config hash, the
+    # call-level trace facts and the segment's size — a hit means the
+    # identical experiment ran a segment of this size before in this
+    # process, and re-executing its program is bitwise the same. One-shot
+    # and heartbeat runs consult the cache (the serving daemon heartbeats
+    # every request); checkpointed and measured runs always compile.
+    exec_cache = (
+        resolve_cache(executable_cache)
+        if checkpoint is None and not measure_timestamps else None
+    )
+    cache_key_fn = functools.partial(
+        sequential_cache_key, config, f_opt, device_data,
+        schedule_signature=(
+            tuple(batch_schedule.shape) if batch_schedule is not None
+            else None
+        ),
+        collect_metrics=collect_metrics,
+        mesh_signature=(
+            tuple(str(d) for d in mesh.devices.flat)
+            if mesh is not None else None
+        ),
+    )
+    (final_state, gap_hist, cons_hist, time_hist, realized_floats,
+     executed_iters, compile_seconds, run_seconds, trace, cost) = (
+        _drive_segments(
+            make_seg_scan, trips_per_eval, state0, data_args, mesh, config,
+            n_evals, spans, checkpoint=checkpoint,
+            measure_timestamps=measure_timestamps,
+            progress_hook=progress_emit, progress_every=progress_every,
+            halt_check=halt_check, exec_cache=exec_cache,
+            cache_key_fn=cache_key_fn, measure_compile=measure_compile,
         )
-        _harvest = _harvest_hoisted if use_hoisted else _harvest_inline
-
-        if checkpoint is None and progress_emit is None:
-            def run_scan(state_init, data):
-                t0_const = jnp.asarray(0, dtype=jnp.int32)
-                return make_seg_scan(n_evals)(state_init, t0_const, data)
-
-            # AOT executable reuse (docs/SERVING.md): the sequential
-            # program bakes its PRNG key, scalars and f*, so the key is
-            # the FULL config hash + call-level trace facts — a hit means
-            # the identical experiment ran before in this process, and
-            # re-executing its compiled program is bitwise the same.
-            exec_cache = resolve_cache(executable_cache)
-            cache_key = cached = None
-            spans.note_root(
-                path="fused", cache="off" if exec_cache is None else "miss"
-            )
-            if exec_cache is not None:
-                spans.enter("cache_lookup")
-                cache_key = sequential_cache_key(
-                    config, f_opt, device_data,
-                    schedule_signature=(
-                        tuple(batch_schedule.shape)
-                        if batch_schedule is not None else None
-                    ),
-                    collect_metrics=collect_metrics,
-                    mesh_signature=(
-                        tuple(str(d) for d in mesh.devices.flat)
-                        if mesh is not None else None
-                    ),
-                    hoisted_min_ratio=hoisted_min_ratio,
-                    eval_hoist_limit=eval_hoist_limit,
-                )
-                cached = exec_cache.get(cache_key)
-            if cached is not None:
-                spans.note_root(cache="hit")
-                compiled = cached.executable
-                cost = cached.cost if config.telemetry else None
-                compile_seconds = 0.0
-            else:
-                # AOT compile so compile time and steady-state execution
-                # are separable (jax.profiler-style phase split, SURVEY.md
-                # §5.1).
-                spans.enter("compile")
-                t0 = time.perf_counter()
-                with jax.default_matmul_precision(config.matmul_precision):
-                    lowered = jax.jit(run_scan).lower(state0, data_args)
-                    cost = (
-                        cost_from_lowered(lowered)
-                        if config.telemetry else None
-                    )
-                    compiled = lowered.compile()
-                cold_seconds = time.perf_counter() - t0
-                compile_seconds = cold_seconds if measure_compile else 0.0
-                if exec_cache is not None:
-                    exec_cache.put(
-                        cache_key, compiled, cost=cost,
-                        compile_seconds=cold_seconds,
-                    )
-
-            # The shards' copy drains here, after all host preparation and
-            # outside the scan's clock: the program could not start before
-            # its inputs were resident anyway, so the call is no longer for
-            # it, but ``run_seconds`` (and so ``iters_per_second``) no
-            # longer counts the copy.
-            spans.enter("upload_wait")
-            jax.block_until_ready((state0, data_args))
-            scan = spans.enter("scan")
-            final_state, ys = compiled(state0, data_args)
-            final_state = jax.block_until_ready(final_state)
-            spans.enter("harvest")
-            run_seconds = scan["duration"]
-            executed_iters = T
-
-            gap_hist, cons_hist, floats_per_eval, trace = _harvest(
-                ys, n_evals
-            )
-            if gap_hist is None:
-                gap_hist = np.full(n_evals, np.nan)
-            realized_floats = (
-                float(floats_per_eval.sum())
-                if floats_per_eval is not None else None
-            )
-            # The fused scan runs on-device without per-eval host
-            # timestamps; spread the measured total uniformly (interpolated
-            # — the report labels it as such; pass measure_timestamps=True
-            # for real samples).
-            time_hist = np.linspace(
-                run_seconds / max(n_evals, 1), run_seconds, n_evals
-            )
-        else:
-            # Segmented execution: checkpointed runs (orbax save between
-            # segments) and/or progress streaming (heartbeat between
-            # segments) — the same flat scan split at eval boundaries.
-            # Progress-only segments reuse cached executables (the
-            # serving daemon heartbeats every request); checkpointed
-            # runs keep the always-compile behavior.
-            seg_cache = (
-                resolve_cache(executable_cache) if checkpoint is None
-                else None
-            )
-            spans.note_root(
-                path="segmented", cache="off" if seg_cache is None else "hit"
-            )
-            cache_key_fn = None
-            if seg_cache is not None:
-                mesh_sig = (
-                    tuple(str(d) for d in mesh.devices.flat)
-                    if mesh is not None else None
-                )
-                sched_sig = (
-                    tuple(batch_schedule.shape)
-                    if batch_schedule is not None else None
-                )
-
-                def cache_key_fn(size):
-                    return sequential_cache_key(
-                        config, f_opt, device_data,
-                        schedule_signature=sched_sig,
-                        collect_metrics=collect_metrics,
-                        mesh_signature=mesh_sig,
-                        hoisted_min_ratio=hoisted_min_ratio,
-                        eval_hoist_limit=eval_hoist_limit,
-                        segment=("seg", int(size)),
-                    )
-
-            (final_state, gap_hist, cons_hist, time_hist, realized_floats,
-             executed_iters, compile_seconds, run_seconds, trace, cost) = (
-                _run_segmented_fused(
-                    make_seg_scan, _harvest, state0, data_args, checkpoint,
-                    mesh, config, n_evals, measure_compile, spans,
-                    progress_hook=progress_emit,
-                    progress_every=progress_every,
-                    exec_cache=seg_cache, cache_key_fn=cache_key_fn,
-                    halt_check=halt_check,
-                )
-            )
-            if gap_hist is None:
-                gap_hist = np.full(len(time_hist), np.nan)
-        # Per-eval wall-clock is interpolated on both fused paths (within
-        # segments, for the checkpointed one) — time_measured stays False.
-        time_measured = False
-    else:
-        def chunk_fn(state, ts, data):
-            return make_chunk(data)(state, ts)
-
-        spans.note_root(path="chunked", cache="off")
-
-        (final_state, gap_hist, cons_hist, time_hist, realized_floats,
-         executed_iters, compile_seconds, run_seconds, trace, cost) = (
-            _run_chunked(
-                chunk_fn, state0, data_args, checkpoint, mesh, config,
-                n_evals, measure_compile, spans, progress_hook=progress_emit,
-                progress_every=progress_every, halt_check=halt_check,
-            )
-        )
-        time_measured = True
-        if not collect_metrics:
-            gap_hist = np.full(len(time_hist), np.nan)
-        if not track_consensus:
-            cons_hist = None
+    )
+    if gap_hist is None:
+        gap_hist = np.full(len(time_hist), np.nan)
 
     # Early-halt bookkeeping (ISSUE-13): a loop that stopped before the
     # horizon left fewer per-eval rows than n_evals. The histories stay
@@ -2277,7 +1827,9 @@ def _run(
         objective=gap_hist,
         consensus_error=cons_hist,
         time=time_hist,
-        time_measured=time_measured,
+        # Only a segment's end is a real sample: measured where every
+        # segment is one eval, interpolated otherwise.
+        time_measured=measure_timestamps,
         mesh_devices=mesh_devices,
         # Truncated to the executed prefix when the run halted early.
         eval_iterations=np.arange(eval_every, T + 1, eval_every)[

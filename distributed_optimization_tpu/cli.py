@@ -429,10 +429,11 @@ def build_parser() -> argparse.ArgumentParser:
     diag.add_argument("--measure-time", action=argparse.BooleanOptionalAction,
                       default=None,
                       help="record real per-eval wall-clock timestamps "
-                           "(host-driven chunk loop; one sync per eval) "
-                           "instead of interpolating the fused scan's total "
-                           "(jax backend). Default: off — the fused flat "
-                           "scan is the fastest path at every eval cadence "
+                           "(the scan in segments of one eval; one sync per "
+                           "eval) instead of interpolating a longer "
+                           "segment's total (jax backend). Default: off — "
+                           "the whole run in one segment is fastest at "
+                           "every eval cadence "
                            "(docs/PERF.md root-cause section); opt in when "
                            "measured per-eval wall-clock matters more than "
                            "throughput")

@@ -4,8 +4,8 @@ A fused run is one opaque XLA call: between submit and result there is
 nothing to look at, which is exactly wrong for a serving daemon under
 load and for the long async/federated runs this repo now executes. This
 module defines the heartbeat contract the backends emit at CHUNK
-boundaries (``jax_backend.run(..., progress_cb=...)``: segmented fused
-scan, chunked loop, batched segments, async eval-chunk loop) and the
+boundaries (``jax_backend.run(..., progress_cb=...)``: the sequential
+scan's segments, batched segments, async eval-chunk loop) and the
 bounded pub/sub stream the daemon's ``/v1/progress/<request_id>`` channel
 reads.
 

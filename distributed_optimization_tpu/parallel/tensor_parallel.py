@@ -46,8 +46,8 @@ from distributed_optimization_tpu.parallel.mesh import WORKER_AXIS
 MODEL_AXIS = "model"
 
 # Metric evals run BETWEEN per-cadence scans (a Python-unrolled segment
-# sequence — the backend's "hoisted" structure), so a run computes exactly
-# n_evals full-dataset evaluations; the limit bounds traced program size.
+# sequence), so a run computes exactly n_evals full-dataset evaluations;
+# the limit bounds traced program size.
 EVAL_SEGMENT_LIMIT = 64
 
 
@@ -218,11 +218,10 @@ def build_tp_softmax_dsgd(
             # the DP-only payload (ring gossip on the LOCAL class slice).
             return ring_mix(Wcur) - eta * g, None
 
-        # Exact-cadence metrics (the backend's "hoisted" structure): a
-        # Python-unrolled sequence of eval-free scans with the
-        # full-dataset eval computed BETWEEN them, so a run pays exactly
-        # n_evals evaluations instead of one per step. Metrics off: one
-        # flat scan, no segments.
+        # Exact-cadence metrics: a Python-unrolled sequence of eval-free
+        # scans with the full-dataset eval computed BETWEEN them, so a run
+        # pays exactly n_evals evaluations instead of one per step.
+        # Metrics off: one flat scan, no segments.
         if not collect_metrics:
             Wcur, _ = jax.lax.scan(
                 step, Wb, jnp.arange(T, dtype=jnp.int32)

@@ -382,7 +382,7 @@ def dataset_signature(device_data) -> tuple:
     """What a compiled program pins about its data INPUTS: shapes and
     dtypes — the values themselves are traced arguments — plus the
     per-worker valid counts, which feed host-side branch decisions
-    (full-batch fast path, eval-cadence form selection)."""
+    (the full-batch fast path)."""
     return (
         tuple(device_data.X.shape),
         str(device_data.X.dtype),
@@ -399,18 +399,16 @@ def sequential_cache_key(
     schedule_signature=None,
     collect_metrics: bool = True,
     mesh_signature=None,
-    hoisted_min_ratio=None,
-    eval_hoist_limit=None,
     segment=None,
 ) -> tuple:
-    """Cache key for the sequential fused-scan program (``_run``'s
-    no-checkpoint path). Everything per-run is baked there — the PRNG key,
-    the hyperparameter scalars, f* — so the key is the FULL config hash
-    plus the call-level knobs that alter the trace. ``segment`` carries
-    the progress-streaming segmentation facts (segment size in evals):
-    the segmented program takes its iteration offset as a TRACED argument
-    where the one-shot program bakes t0=0, so the two must never share an
-    executable."""
+    """Cache key for the sequential scan's program (``_run``). Everything
+    per-run is baked there — the PRNG key, the hyperparameter scalars, f*
+    — so the key is the FULL config hash plus the call-level knobs that
+    alter the trace. ``segment`` is the size, in evals, of the segment the
+    executable runs: its iteration offset is an argument, so every segment
+    of that size shares it, the whole run in one segment included. (The
+    async event path keys its programs through ``schedule_signature`` and
+    leaves ``segment`` unset.)"""
     return (
         "seq",
         _full_config_hash(config),
@@ -419,8 +417,6 @@ def sequential_cache_key(
         schedule_signature,
         bool(collect_metrics),
         mesh_signature,
-        hoisted_min_ratio,
-        eval_hoist_limit,
         segment,
         _jax_env_signature(),
     )

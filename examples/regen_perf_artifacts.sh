@@ -22,7 +22,6 @@ python examples/bench_byzantine.py         # -> docs/perf/byzantine.json
 python examples/bench_robust_scale.py      # -> docs/perf/robust_scale.json
 python examples/bench_sparse_mixing.py     # -> docs/perf/sparse_mixing.json
 python examples/bench_compute_bound.py     # -> docs/perf/compute_bound.json (MFU-floor gated)
-python examples/bench_eval_cadence.py      # -> docs/perf/eval_cadence.json
 python examples/bench_sweep.py             # -> docs/perf/sweep.json (replica-batch floor gated)
 python examples/bench_telemetry.py         # -> docs/perf/telemetry.json (overhead-ceiling gated)
 python examples/bench_fused_robust.py      # -> docs/perf/fused_robust.json (CPU only: Mosaic refuses the fused kernel)
