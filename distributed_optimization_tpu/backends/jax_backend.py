@@ -72,6 +72,7 @@ from distributed_optimization_tpu.parallel import build_topology
 from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     make_worker_mesh,
+    place_shards,
     replicate,
     shard_over_workers,
 )
@@ -1576,7 +1577,8 @@ def _run(
         bytes=device_data.X.nbytes + device_data.y.nbytes
         + device_data.n_valid.nbytes,
     )
-    X = shard_over_workers(mesh, jnp.asarray(device_data.X))
+    X, placement = place_shards(mesh, device_data.X)
+    spans.note_root(stack=device_data.stacked_by, placement=placement)
     y = shard_over_workers(mesh, jnp.asarray(device_data.y))
     n_valid = shard_over_workers(mesh, jnp.asarray(device_data.n_valid))
     spans.enter("prepare")

@@ -11,9 +11,14 @@ step 8).
 
 from __future__ import annotations
 
+import collections
+import functools
+import math
 from typing import Optional, Sequence
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 WORKER_AXIS = "workers"
@@ -76,3 +81,122 @@ def replicate(mesh: Optional[Mesh], tree):
     return jax.tree.map(
         lambda a: jax.device_put(a, NamedSharding(mesh, P())), tree
     )
+
+
+# --- the stacked shards' way to the device (ISSUE 29) -----------------------
+#
+# One host-to-device copy whose device buffer is 2**32 bytes or more takes
+# a slow path in the TPU runtime, whatever its shape: on one v5e a
+# [245760, 53, 81] f32 stack (4.22 GB, 4.46 GB in the device's tiles) goes
+# up at 0.18 GB/s where [131072, 53, 81] goes at 7.6, and the same bytes as
+# [1099008, 1024] at 0.40 where [1030320, 1024] goes at 7.4 (PERF.md
+# section 6, PR 29). So a stack that large goes up in blocks of whole
+# workers, each a 2-D [rows, FLAT_COLUMNS] view of its bytes, which the
+# runtime copies at 9.5 GB/s whatever [L, d] is (blocks in their own
+# [nb, 53, 81] shape: 7.4), and a jitted reshape writes each block into
+# the [N, L, d] array on the device. Under the cliff a stack goes up as it
+# is (6-9 GB/s), with no second program, transient or device time.
+
+FLAT_COLUMNS = 1024
+# One block's bytes. The transient on the device is two blocks in flight
+# and one being reshaped, not a second copy of the stack (which, for the
+# GLM cell's 4.5 GB, does not fit: the one-block program needs 19.8 GB).
+FLAT_BLOCK_BYTES = 128 << 20
+# The cliff, held against ``tiled_bytes``.
+FLAT_MIN_TILED_BYTES = 1 << 32
+
+
+def tiled_bytes(shape: Sequence[int], itemsize: int) -> int:
+    """Bytes of an array of ``shape`` with its two minor dimensions padded
+    to the TPU's (8, 128) tiles (sublanes pack two 16-bit or four 8-bit
+    numbers). The runtime orders the dimensions as it likes ([N, 53, 81]
+    f32: N minor, 1.06 of the host's bytes where this says 1.67), so this
+    is what one copy's device buffer may be at most, not what it is."""
+    *major, rows, cols = (1, 1, *shape)
+    sublanes = 8 * max(1, 4 // itemsize)
+    return (
+        math.prod(major) * math.ceil(rows / sublanes) * sublanes
+        * math.ceil(cols / 128) * 128 * itemsize
+    )
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_block(out, main, tail, first_worker):
+    """``out`` with the workers from ``first_worker`` on replaced by the
+    block whose numbers are ``main`` ([rows, C]) then ``tail`` (1-D, fewer
+    than C); the second result is ready when the block has been written."""
+    flat = jnp.concatenate([main.reshape(-1), tail])
+    block = flat.reshape((-1,) + out.shape[1:])
+    return (
+        jax.lax.dynamic_update_slice(out, block, (first_worker, 0, 0)),
+        block[0, 0, 0],
+    )
+
+
+def _place_flat(X: np.ndarray, block_workers: int, columns: int):
+    """``X`` on the device, sent as blocks of ``block_workers`` workers, each
+    a 2-D ``[rows, columns]`` view of its bytes (and a tail); and the rows
+    sent in all."""
+    n = X.shape[0]
+    per_worker = X.shape[1] * X.shape[2]
+    flat = X.reshape(-1)  # a view: X is C-contiguous
+    out = jnp.zeros(X.shape, X.dtype)
+    written = collections.deque()
+    rows = 0
+    for w0 in range(0, n, block_workers):
+        chunk = flat[w0 * per_worker: (w0 + block_workers) * per_worker]
+        split = chunk.size - chunk.size % columns
+        rows += split // columns
+        out, done = _write_block(
+            out,
+            jax.device_put(chunk[:split].reshape(-1, columns)),
+            jax.device_put(chunk[split:]),
+            w0,
+        )
+        # At most two blocks on the device beside ``out``: the copy of this
+        # one runs under the write of the one before.
+        written.append(done)
+        if len(written) > 1:
+            written.popleft().block_until_ready()
+    return out, rows
+
+
+def place_shards(
+    mesh: Optional[Mesh],
+    X: np.ndarray,
+    *,
+    min_tiled_bytes: int = FLAT_MIN_TILED_BYTES,
+    block_bytes: int = FLAT_BLOCK_BYTES,
+    columns: int = FLAT_COLUMNS,
+) -> tuple[jax.Array, str]:
+    """The stacked shards ``X [N, L, d]`` (host) on the device, with the
+    shape, dtype and default layout ``jnp.asarray`` gives, and how they got
+    there: ``direct``, or ``flat:<rows>x<C>/<blocks>``.
+
+    The choice is made from what can be seen here: under a mesh the stack
+    is sharded over the workers as before; a stack that one copy can take
+    without reaching the runtime's cliff (``tiled_bytes`` under
+    ``min_tiled_bytes``), or one that is not contiguous, goes up as it is;
+    any other goes up as 2-D ``[rows, columns]`` blocks of whole workers
+    (``_place_flat``). The keywords are for tests and measurements.
+    """
+    if (
+        mesh is not None
+        or X.ndim != 3
+        or tiled_bytes(X.shape, X.dtype.itemsize) < min_tiled_bytes
+        or not X.flags.c_contiguous
+    ):
+        return shard_over_workers(mesh, jnp.asarray(X)), "direct"
+    n = X.shape[0]
+    per_worker = X.shape[1] * X.shape[2]
+    # Blocks of about ``block_bytes``, all of one size but the last; whole
+    # rows of ``columns`` a block (no tail) where that is a few workers more.
+    block_workers = min(
+        n, max(1, block_bytes // (per_worker * X.dtype.itemsize))
+    )
+    block_workers = math.ceil(n / math.ceil(n / block_workers))
+    whole = columns // math.gcd(per_worker, columns)
+    if whole <= block_workers:
+        block_workers = math.ceil(block_workers / whole) * whole
+    out, rows = _place_flat(X, block_workers, columns)
+    return out, f"flat:{rows}x{columns}/{math.ceil(n / block_workers)}"

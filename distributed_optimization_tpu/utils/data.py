@@ -54,6 +54,9 @@ class DeviceDataset:
     X: np.ndarray
     y: np.ndarray
     n_valid: np.ndarray
+    # How ``stack_shards`` formed ``X``: "view" (it IS the host dataset's
+    # memory: read-only by contract), "cast" or "gather".
+    stacked_by: str = "gather"
 
     @property
     def n_workers(self) -> int:
@@ -265,6 +268,15 @@ def partition_summary(dataset: HostDataset, max_workers: int = 32) -> str:
 def stack_shards(dataset: HostDataset, dtype=np.float32) -> DeviceDataset:
     """Stack ragged shards into padded [N, L, d] arrays for the device path.
 
+    No loop over workers. Equal shards that are consecutive rows of
+    ``X_full`` ARE ``X_full.reshape(N, L, d)``: in the run dtype that view
+    is returned as it is (``stacked_by`` ``view``; nothing downstream writes
+    into a ``DeviceDataset``, and nothing may), in another dtype it is one
+    ``astype`` pass (``cast``). Any other partition (a permutation from
+    ``argsort``, ragged sizes from ``array_split``, empty trailing shards) is
+    one [N, L] index matrix and one ``np.take`` (``gather``), padding rows
+    zero.
+
     Softmax labels are CLASS INDICES and stay int32 regardless of the run
     dtype: under bfloat16 (8-bit significand) every odd index above 256
     would silently round to its even neighbor — at the compute-bound
@@ -276,11 +288,42 @@ def stack_shards(dataset: HostDataset, dtype=np.float32) -> DeviceDataset:
     d = dataset.n_features
     sizes = np.array([len(idx) for idx in dataset.shard_indices], dtype=np.int32)
     L = int(sizes.max()) if n else 0
-    y_dtype = np.int32 if dataset.problem_type == "softmax" else dtype
-    X = np.zeros((n, L, d), dtype=dtype)
-    y = np.zeros((n, L), dtype=y_dtype)
-    for i in range(n):
-        Xi, yi = dataset.shard(i)
-        X[i, : sizes[i]] = Xi
-        y[i, : sizes[i]] = yi
-    return DeviceDataset(X=X, y=y, n_valid=sizes)
+    dtype = np.dtype(dtype)
+    y_dtype = np.dtype(np.int32) if dataset.problem_type == "softmax" else dtype
+    if L == 0:  # no worker holds a row
+        return DeviceDataset(
+            X=np.zeros((n, 0, d), dtype), y=np.zeros((n, 0), y_dtype),
+            n_valid=sizes,
+        )
+    X_full, y_full = dataset.X_full, dataset.y_full
+    rows = np.concatenate(dataset.shard_indices)
+    if rows.size == n * L and np.array_equal(rows, np.arange(n * L)):
+        view = X_full.dtype == dtype and X_full.flags.c_contiguous
+        X = X_full[: n * L].astype(dtype, copy=not view).reshape(n, L, d)
+        y = y_full[: n * L].astype(y_dtype, copy=False).reshape(n, L)
+        for a, full in ((X, X_full), (y, y_full)):
+            if np.may_share_memory(a, full):
+                a.flags.writeable = False
+        return DeviceDataset(
+            X=X, y=y, n_valid=sizes, stacked_by="view" if view else "cast"
+        )
+    n_rows = X_full.shape[0]
+    if rows.min() < -n_rows or rows.max() >= n_rows:
+        raise IndexError(
+            f"shard_indices reach outside the dataset's {n_rows} rows"
+        )
+    # Padding slots read row 0 (any valid row) and are zeroed after.
+    index = np.zeros((n, L), dtype=np.intp)
+    valid = np.arange(L) < sizes[:, None]
+    index[valid] = rows % n_rows
+    X = np.empty((n, L, d), dtype=dtype)
+    y = np.empty((n, L), dtype=y_dtype)
+    # Cast first: the gather then writes the output once, in its dtype.
+    # ``mode="clip"`` (the indices are in range, checked above) because
+    # under the default mode ``np.take`` fills ``out`` through a buffer.
+    np.take(X_full.astype(dtype, copy=False), index, axis=0, out=X, mode="clip")
+    np.take(y_full.astype(y_dtype, copy=False), index, out=y, mode="clip")
+    padding = ~valid
+    X[padding] = 0
+    y[padding] = 0
+    return DeviceDataset(X=X, y=y, n_valid=sizes, stacked_by="gather")

@@ -6,6 +6,7 @@ import pytest
 from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.ops import losses_np
 from distributed_optimization_tpu.utils import (
+    HostDataset,
     compute_reference_optimum,
     generate_synthetic_dataset,
     stack_shards,
@@ -73,6 +74,129 @@ def test_uneven_split_padding():
     # 100 = 7*14 + 2 → first two shards hold 15 (array_split semantics).
     assert sorted(dev.n_valid.tolist(), reverse=True) == [15, 15] + [14] * 5
     assert dev.X.shape[1] == 15
+
+
+def _stack_shards_by_loop(dataset, dtype=np.float32):
+    """``stack_shards`` as it was until ISSUE 29, a Python loop over the
+    workers: the plain oracle the loop-free form is held to, bit for bit."""
+    n = dataset.n_workers
+    d = dataset.n_features
+    sizes = np.array([len(idx) for idx in dataset.shard_indices], dtype=np.int32)
+    L = int(sizes.max()) if n else 0
+    y_dtype = np.int32 if dataset.problem_type == "softmax" else dtype
+    X = np.zeros((n, L, d), dtype=dtype)
+    y = np.zeros((n, L), dtype=y_dtype)
+    for i in range(n):
+        Xi, yi = dataset.shard(i)
+        X[i, : sizes[i]] = Xi
+        y[i, : sizes[i]] = yi
+    return X, y, sizes
+
+
+def _consecutive(
+    n_workers, rows, dtype=np.float64, problem="logistic", *,
+    shard_indices=None, fortran=False,
+):
+    """Equal shards laid worker after worker, as the benchmark's are (or
+    ``shard_indices`` over the same rows)."""
+    rng = np.random.default_rng(7)
+    n = n_workers * rows
+    X = rng.standard_normal((n, 6)).astype(dtype)
+    y = (
+        rng.integers(0, 512, size=n) if problem == "softmax"
+        else rng.standard_normal(n)
+    )
+    if shard_indices is None:
+        shard_indices = list(np.arange(n).reshape(n_workers, rows))
+    return HostDataset(
+        X_full=np.asfortranarray(X) if fortran else X, y_full=y.astype(dtype),
+        shard_indices=shard_indices, problem_type=problem,
+    )
+
+
+def _generated(problem, **kw):
+    return generate_synthetic_dataset(small_config(problem, **kw))
+
+
+STACK_CASES = {
+    # name: (dataset factory, run dtype, expected ``stacked_by``)
+    "consecutive-run-dtype": (
+        lambda: _consecutive(8, 5, np.float32), "float32", "view"),
+    "consecutive-f64-to-f32": (lambda: _consecutive(8, 5), "float32", "cast"),
+    "consecutive-prefix": (
+        lambda: _consecutive(
+            8, 5, np.float32,
+            shard_indices=list(np.arange(30).reshape(6, 5))),
+        "float32", "view"),
+    "consecutive-fortran-order": (
+        lambda: _consecutive(8, 5, np.float32, fortran=True),
+        "float32", "cast"),
+    "argsort-equal-sizes": (
+        lambda: _generated("quadratic", n_workers=5, n_samples=250),
+        "float32", "gather"),
+    "argsort-equal-sizes-f64": (
+        lambda: _generated("logistic", n_workers=5, n_samples=250),
+        "float64", "gather"),
+    "ragged": (
+        lambda: _generated("quadratic", n_workers=7, n_samples=100),
+        "float32", "gather"),
+    "ragged-consecutive": (
+        lambda: _consecutive(
+            8, 5, np.float32, shard_indices=np.array_split(np.arange(38), 8)),
+        "float32", "gather"),
+    "more-workers-than-samples": (
+        lambda: _generated(
+            "quadratic", n_workers=12, n_samples=9, local_batch_size=1),
+        "float32", "gather"),
+    "no-rows-at-all": (
+        lambda: _consecutive(
+            3, 5, np.float32, shard_indices=[np.arange(0)] * 3),
+        "float32", "gather"),
+    "shuffled": (
+        lambda: _generated(
+            "logistic", n_workers=4, n_samples=100, partition="shuffled"),
+        "float32", "gather"),
+    "negative-indices": (
+        lambda: _consecutive(
+            4, 5, np.float32,
+            shard_indices=list(np.arange(-20, 0).reshape(4, 5))),
+        "float32", "gather"),
+    "softmax-bfloat16": (
+        lambda: _consecutive(4, 128, problem="softmax"), "bfloat16", "cast"),
+    "softmax-bfloat16-argsort": (
+        lambda: _generated(
+            "softmax", n_workers=5, n_samples=203, n_classes=3),
+        "bfloat16", "gather"),
+}
+
+
+@pytest.mark.parametrize("case", list(STACK_CASES))
+def test_stack_shards_is_bitwise_the_loop(case):
+    """ISSUE 29: no loop over workers, and the same bytes — X, y, n_valid
+    and their dtypes — as the loop gave, whatever the partition; where the
+    shards are consecutive rows in the run dtype X is the dataset's own
+    memory, read-only."""
+    make, dtype, stacked_by = STACK_CASES[case]
+    ds = make()
+    dtype = np.dtype(dtype)
+    X, y, sizes = _stack_shards_by_loop(ds, dtype=dtype)
+    dev = stack_shards(ds, dtype=dtype)
+    assert dev.stacked_by == stacked_by
+    for got, want in ((dev.X, X), (dev.y, y), (dev.n_valid, sizes)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+    assert np.shares_memory(dev.X, ds.X_full) == (stacked_by == "view")
+    if stacked_by == "view":
+        assert not dev.X.flags.writeable and ds.X_full.flags.writeable
+        with pytest.raises(ValueError, match="read-only"):
+            dev.X[0, 0, 0] = 1.0
+
+
+def test_stack_shards_refuses_rows_outside_the_dataset():
+    ds = _consecutive(
+        4, 5, shard_indices=list(np.arange(1, 21).reshape(4, 5)))
+    with pytest.raises(IndexError, match="outside"):
+        stack_shards(ds)
 
 
 @pytest.mark.parametrize("problem", ["logistic", "quadratic"])

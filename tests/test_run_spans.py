@@ -6,6 +6,7 @@ Python tracer off.
 CPU, small N and T: what is checked is structure and counts, never a time.
 """
 
+import functools
 import subprocess
 import sys
 
@@ -24,7 +25,9 @@ from distributed_optimization_tpu.observability.spans import (
 )
 from distributed_optimization_tpu.serving.cache import ExecutableCache
 from distributed_optimization_tpu.simulator import Simulator
+from distributed_optimization_tpu.parallel.mesh import place_shards
 from distributed_optimization_tpu.utils.data import (
+    HostDataset,
     generate_synthetic_dataset,
     stack_shards,
 )
@@ -86,6 +89,9 @@ def test_one_root_with_the_named_children_in_order(setup):
         "carry": f"{cfg.n_workers}x{ds.n_features}",
         "algorithm": "dsgd", "compress": "none", "select": "none",
         "wire_floats_per_edge": float(ds.n_features),
+        # How the shards were stacked and how they went up (ISSUE 29): an
+        # ``argsort`` partition is gathered; so small a stack goes up as it is.
+        "stack": "gather", "placement": "direct",
     }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)
@@ -101,6 +107,48 @@ def test_one_root_with_the_named_children_in_order(setup):
     )
     # None of them is a row of the flat phase table.
     assert tracer.phases == {}
+
+
+@pytest.mark.parametrize("layout,dtype,stack", [
+    ("consecutive", "float32", "view"), ("consecutive", "float64", "cast"),
+    ("argsort", "float64", "gather"),
+])
+def test_root_says_how_the_shards_were_stacked_and_placed(
+    setup, monkeypatch, layout, dtype, stack
+):
+    """ISSUE 29: ``stack`` is ``view`` for shards laid worker after worker
+    in the run dtype, ``cast`` for those in another, ``gather`` for an
+    ``argsort`` partition; ``placement`` is ``direct``, or names the 2-D
+    blocks a stack went up as. No new child, and ``stack_shards``' ``bytes``
+    are still the stacked arrays'."""
+    cfg, ds = setup
+    stacked = stack_shards(ds, dtype=np.float32)
+    n, L, d = stacked.X.shape
+    if layout == "consecutive":
+        ds = HostDataset(
+            X_full=stacked.X.reshape(n * L, d).astype(dtype),
+            y_full=stacked.y.reshape(n * L).astype(dtype),
+            shard_indices=list(np.arange(n * L).reshape(n, L)),
+            problem_type=ds.problem_type,
+        )
+    for flat, placement in (
+        (False, "direct"), (True, f"flat:{n * L * d // 128}x128/2"),
+    ):
+        if flat:
+            monkeypatch.setattr(
+                jax_backend, "place_shards",
+                functools.partial(
+                    place_shards, min_tiled_bytes=0, columns=128,
+                    block_bytes=n // 2 * L * d * 4),
+            )
+        _, roots, children = run_under(Tracer(), cfg, ds, use_mesh=False)
+        assert roots[-1]["args"]["stack"] == stack
+        assert roots[-1]["args"]["placement"] == placement
+        assert names(children) in (CHILDREN_COLD, CHILDREN_WARM)
+        by_name = {e["name"]: e for e in children}
+        assert by_name["dopt.run.stack_shards"]["args"]["bytes"] == (
+            stacked.X.nbytes + stacked.y.nbytes
+        )
 
 
 @pytest.mark.parametrize("problem,classes", [("quadratic", 2), ("softmax", 3)])
