@@ -18,7 +18,12 @@ without a TPU it exits before doing any work. Segments:
 3. Reference agreement on a small input: full-batch logistic and softmax
    runs against the numpy reference-semantics backend.
 4. Where four chips are visible: the ``worker_mesh=4`` ring at N=100,000
-   against the same config unsharded, with the state spread over the four.
+   against the same config unsharded, with the state spread over the four
+   and the shards sent block by block, each to its own chip; and, first of
+   all, while the process has put nothing else on a device, a 1.1 GB stack
+   placed over the four with no chip ever holding more than its quarter
+   (``parallel.mesh.place_shards``: what a deployment past one chip's memory
+   rests on).
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -153,6 +158,53 @@ def reference_segment(device: dict) -> None:
                f"{problem} agrees with the numpy reference at {REFERENCE_ATOL}")
 
 
+def placement_segment(device: dict, *, n_workers: int = 65_536,
+                      rows: int = 53, d: int = 81) -> None:
+    """``place_shards`` under a mesh of four, before anything else has been
+    on a device, so that ``peak_bytes_in_use`` is this segment's own: each
+    chip's block goes to that chip, as it is (``direct``) and in flat pieces,
+    and the first chip never holds the whole stack, which is what
+    ``shard_over_workers(mesh, jnp.asarray(X))`` did."""
+    import jax
+
+    from distributed_optimization_tpu.parallel.mesh import (
+        make_sized_worker_mesh,
+        place_shards,
+    )
+
+    mesh = make_sized_worker_mesh(4)
+    X = np.arange(n_workers * rows * d, dtype=np.float32).reshape(
+        n_workers, rows, d)
+    # One chip's quarter as the host holds it; the runtime lays [n, 53, 81]
+    # out with n minor, 1.06 of that (297,298,432 bytes here: PR 30).
+    quarter = X.nbytes // 4
+    for kw, label, room in (
+        ({}, "mesh4:direct", 1.25),
+        # pieces of 32 MiB, two in flight beside the block: read 1.19
+        (dict(min_tiled_bytes=0, block_bytes=32 << 20),
+         "mesh4:flat:68688x1024/8", 1.5),
+    ):
+        got, how = place_shards(mesh, X, **kw)
+        jax.block_until_ready(got)
+        stats = [dev.memory_stats() for dev in mesh.devices.flat]
+        peaks = [int(st["peak_bytes_in_use"]) for st in stats]
+        print(f"[chip_smoke] placement: {how} stack_bytes={X.nbytes} "
+              f"peak_bytes_by_device={peaks}", flush=True)
+        _check(how == label, f"a {X.shape} stack over four chips goes up {label}")
+        _check(all(s.device == dev and s.index[0].start == p * (n_workers // 4)
+                   for p, (s, dev) in enumerate(zip(
+                       sorted(got.addressable_shards,
+                              key=lambda s: s.index[0].start),
+                       mesh.devices.flat))),
+               "each chip holds its own block of workers")
+        _check(max(peaks) <= room * quarter,
+               f"no chip ever held more than {room} of its quarter of the "
+               f"stack ({quarter} bytes): the first chip is no staging post")
+        _check(np.asarray(got).tobytes() == X.tobytes(),
+               "the placed stack is the host array, bit for bit")
+        del got
+
+
 def four_chip_segment(device: dict, *, n_workers: int = 100_000,
                       n_samples: int = 200_000, n_iterations: int = 100) -> None:
     from distributed_optimization_tpu.config import ExperimentConfig
@@ -178,6 +230,17 @@ def four_chip_segment(device: dict, *, n_workers: int = 100_000,
           f"(tolerance {MESH_ATOL})", flush=True)
     _check(sharded.result.history.mesh_devices == 4,
            "worker_mesh=4 state held as four row blocks, one per device")
+    roots = [e["args"] for e in sim.phase_timer.spans()
+             if e["name"] == "dopt.run"]
+    print(f"[chip_smoke] four-chip: root spans {roots}", flush=True)
+    _check(len(roots) == 2
+           and roots[0]["placement"] == "mesh4:direct"
+           and roots[0]["mesh"] == f"4x{n_workers // 4}"
+           and roots[0]["mixing"] == "halo_gather"
+           and roots[0]["halo_rows"] == 2,
+           "worker_mesh=4 shards sent per chip, mixing by the halo gather")
+    _check(roots[1]["placement"] == "direct" and "mesh" not in roots[1],
+           "worker_mesh=0 shards placed as before")
     _check(single.result.history.mesh_devices == 1,
            "worker_mesh=0 matrix-free run stays on one device")
     _check(bool(np.all(np.isfinite(sharded.result.final_models))),
@@ -198,6 +261,8 @@ def main() -> int:
     print(f"[chip_smoke] jax {jax.__version__} platform={device['platform']} "
           f"device_kind={device['kind']} devices_visible={device['count']} "
           f"compile_cache={cache_dir}", flush=True)
+    if device["count"] >= 4:
+        placement_segment(device)  # first: the peaks it reads are its own
     glm_segment(device)
     softmax_segment(device)
     reference_segment(device)

@@ -75,6 +75,7 @@ from distributed_optimization_tpu.parallel.mesh import (
     place_shards,
     replicate,
     shard_over_workers,
+    zeros_over_workers,
 )
 from distributed_optimization_tpu.utils.data import HostDataset, stack_shards
 
@@ -1523,6 +1524,16 @@ def _run(
             _halo_g.reset()
             for _p, _rows in enumerate(_ici["halo_rows_per_device"]):
                 _halo_g.set(float(_rows), device=str(_p))
+            # The same numbers on the call's root span, the fullest
+            # device's: what the benchmark's readers see (ISSUE 30).
+            spans.note_root(
+                mesh=f"{config.worker_mesh}x{n // config.worker_mesh}",
+                mixing=mix_op.impl,
+                halo_rows=int(max(_ici["halo_rows_per_device"])),
+                ici_bytes_per_round=float(
+                    max(_ici["bytes_per_device_per_round"])
+                ),
+            )
     else:
         if (
             config.edge_drop_prob > 0.0
@@ -1579,12 +1590,12 @@ def _run(
     )
     X, placement = place_shards(mesh, device_data.X)
     spans.note_root(stack=device_data.stacked_by, placement=placement)
-    y = shard_over_workers(mesh, jnp.asarray(device_data.y))
-    n_valid = shard_over_workers(mesh, jnp.asarray(device_data.n_valid))
+    # Host arrays, so under a mesh each device's rows go to that device
+    # and nothing is staged whole on the first (ISSUE 30).
+    y = shard_over_workers(mesh, device_data.y)
+    n_valid = shard_over_workers(mesh, device_data.n_valid)
     spans.enter("prepare")
-    x0 = shard_over_workers(
-        mesh, jnp.zeros(carry_shape, dtype=device_data.X.dtype)
-    )
+    x0 = zeros_over_workers(mesh, carry_shape, device_data.X.dtype)
     state0 = algo.init(
         x0, config,
         neighbor_sum=mix_op.neighbor_sum if mix_op is not None else None,
@@ -1597,12 +1608,9 @@ def _run(
         # carries the leaves passes through untouched.
         for _leaf in ("xhat", "yhat"):
             if _leaf in state0 and f"{_leaf}_halo" not in state0:
-                state0[f"{_leaf}_halo"] = shard_over_workers(
-                    mesh,
-                    jnp.zeros(
-                        (compressed_mix.halo_rows, d_model),
-                        dtype=device_data.X.dtype,
-                    ),
+                state0[f"{_leaf}_halo"] = zeros_over_workers(
+                    mesh, (compressed_mix.halo_rows, d_model),
+                    device_data.X.dtype,
                 )
     key = jax.random.key(config.seed)
 

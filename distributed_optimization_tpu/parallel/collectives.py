@@ -196,8 +196,11 @@ def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
 # table references (the halo), then runs the ordinary gather math locally.
 # Per-row arithmetic is the EXACT op sequence of the single-device gather
 # operators (same slot order, same accumulation dtype), so sharded and
-# unsharded trajectories are bitwise identical at matched N
-# (tests/test_worker_mesh.py pins it); the only cross-device traffic is
+# unsharded trajectories agree at matched N to the few units in the last
+# place by which two executables contract the same products and sums
+# differently (bitwise under the jax this was written on; one f32 ulp a
+# round under jax 0.9: tests/test_worker_mesh.py states the tolerance,
+# ISSUE 30); the only cross-device traffic is
 # the halo rows — O(boundary · d) per device per round, independent of N
 # for ring/torus/chain and O(E/P² · d) per rotation for Erdős–Rényi.
 # Single-process multi-device (the closures capture sharded tables, which
@@ -320,10 +323,14 @@ def make_halo_mixing_op(
     MH weights are the identical per-slot values ``gather_mixing_weights``
     derives (sharded per block); the apply/neighbor_sum bodies run the
     identical per-row op sequence as the single-device gather operator on
-    the halo-extended buffer, so the two forms are BITWISE equal — with
+    the halo-extended buffer, so the two forms are equal to the last
+    place or two (two executables: see the section comment above) — with
     boundary rows arriving over ICI as ppermute traffic instead of being
     addressed in one device's HBM (the compiled-HLO payload test in
     tests/test_worker_mesh.py pins ring rounds to 2·d floats per device).
+    On the chip the gather is the cost: 7.0 of 31.6 ms an iteration at
+    262,144 rows a device, against the stencil's 1.4 on one chip
+    (PERF.md sections 5 and 6, PR 30).
 
     ``overlap='double_buffer'`` (config.halo_overlap; docs/PERF.md §17)
     restructures ``apply`` into the stencil latency-hiding form: the

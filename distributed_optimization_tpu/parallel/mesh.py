@@ -66,11 +66,31 @@ def worker_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
 
 
 def shard_over_workers(mesh: Optional[Mesh], tree):
-    """device_put every array leaf with axis 0 split over the worker axis."""
+    """device_put every array leaf with axis 0 split over the worker axis.
+
+    A host (numpy) leaf goes shard by shard, each slice from the host to
+    its own device; a leaf that is already a device array is resharded
+    from where it lies. So what is large is handed over as numpy."""
     if mesh is None:
         return jax.tree.map(jax.numpy.asarray, tree)
     return jax.tree.map(
         lambda a: jax.device_put(a, worker_sharding(mesh, a.ndim)), tree
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _sharded_zeros(shape, dtype, sharding):
+    return jax.lax.with_sharding_constraint(jnp.zeros(shape, dtype), sharding)
+
+
+def zeros_over_workers(mesh: Optional[Mesh], shape, dtype) -> jax.Array:
+    """Zeros of ``shape``, axis 0 split over the worker axis: each device
+    fills its own rows. (``jnp.zeros(..., device=)`` fills the whole array
+    on the first device and sends it on: my chip run, PR 30.)"""
+    if mesh is None:
+        return jnp.zeros(shape, dtype=dtype)
+    return _sharded_zeros(
+        tuple(shape), np.dtype(dtype), worker_sharding(mesh, len(shape))
     )
 
 
@@ -95,7 +115,10 @@ def replicate(mesh: Optional[Mesh], tree):
 # runtime copies at 9.5 GB/s whatever [L, d] is (blocks in their own
 # [nb, 53, 81] shape: 7.4), and a jitted reshape writes each block into
 # the [N, L, d] array on the device. Under the cliff a stack goes up as it
-# is (6-9 GB/s), with no second program, transient or device time.
+# is (6-9 GB/s), with no second program, transient or device time. Under a
+# mesh the same rule is applied to each device's block of workers, which
+# goes to that device alone (ISSUE 30): four chips' shards, 18 GB, are
+# never on one.
 
 FLAT_COLUMNS = 1024
 # One block's bytes. The transient on the device is two blocks in flight
@@ -120,6 +143,12 @@ def tiled_bytes(shape: Sequence[int], itemsize: int) -> int:
     )
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _filled(value, shape):
+    """``shape`` filled with ``value``, on the device ``value`` lies on."""
+    return jnp.broadcast_to(value, shape)
+
+
 @functools.partial(jax.jit, donate_argnums=0)
 def _write_block(out, main, tail, first_worker):
     """``out`` with the workers from ``first_worker`` on replaced by the
@@ -133,32 +162,44 @@ def _write_block(out, main, tail, first_worker):
     )
 
 
-def _place_flat(X: np.ndarray, block_workers: int, columns: int):
-    """``X`` on the device, sent as blocks of ``block_workers`` workers, each
-    a 2-D ``[rows, columns]`` view of its bytes (and a tail); and the rows
-    sent in all."""
-    n = X.shape[0]
-    per_worker = X.shape[1] * X.shape[2]
-    flat = X.reshape(-1)  # a view: X is C-contiguous
-    out = jnp.zeros(X.shape, X.dtype)
-    written = collections.deque()
+def _place_flat(blocks, devices, block_workers: int, columns: int):
+    """Each of ``blocks`` (equal ``[n, L, d]`` host arrays) on its device
+    (``None``: the default device, uncommitted), sent as pieces of
+    ``block_workers`` workers, each a 2-D ``[rows, columns]`` view of its
+    bytes (and a tail); and the rows sent to one device in all. The pieces
+    go round the devices in turn, so the copies to different devices are in
+    flight together."""
+    n = blocks[0].shape[0]
+    per_worker = blocks[0].shape[1] * blocks[0].shape[2]
+    flats = [X.reshape(-1) for X in blocks]  # views: each is C-contiguous
+    # Each buffer is filled where it will lie: ``jnp.zeros(..., device=)``
+    # fills on the first device and sends the zeros on, which put three
+    # other chips' 4.76 GB on chip 0 at once (14.4 of its 15.75 GB: my chip
+    # run, PR 30).
+    outs = [
+        _filled(jax.device_put(np.zeros((), X.dtype), dev), X.shape)
+        for X, dev in zip(blocks, devices)
+    ]
+    written = [collections.deque() for _ in blocks]
     rows = 0
     for w0 in range(0, n, block_workers):
-        chunk = flat[w0 * per_worker: (w0 + block_workers) * per_worker]
-        split = chunk.size - chunk.size % columns
-        rows += split // columns
-        out, done = _write_block(
-            out,
-            jax.device_put(chunk[:split].reshape(-1, columns)),
-            jax.device_put(chunk[split:]),
-            w0,
-        )
-        # At most two blocks on the device beside ``out``: the copy of this
-        # one runs under the write of the one before.
-        written.append(done)
-        if len(written) > 1:
-            written.popleft().block_until_ready()
-    return out, rows
+        for p, (flat, dev) in enumerate(zip(flats, devices)):
+            chunk = flat[w0 * per_worker: (w0 + block_workers) * per_worker]
+            split = chunk.size - chunk.size % columns
+            if p == 0:
+                rows += split // columns
+            outs[p], done = _write_block(
+                outs[p],
+                jax.device_put(chunk[:split].reshape(-1, columns), dev),
+                jax.device_put(chunk[split:], dev),
+                w0,
+            )
+            # At most two pieces on a device beside its ``out``: the copy
+            # of this one runs under the write of the one before.
+            written[p].append(done)
+            if len(written[p]) > 1:
+                written[p].popleft().block_until_ready()
+    return outs, rows
 
 
 def place_shards(
@@ -171,32 +212,56 @@ def place_shards(
 ) -> tuple[jax.Array, str]:
     """The stacked shards ``X [N, L, d]`` (host) on the device, with the
     shape, dtype and default layout ``jnp.asarray`` gives, and how they got
-    there: ``direct``, or ``flat:<rows>x<C>/<blocks>``.
+    there: ``direct``, or ``flat:<rows>x<C>/<blocks>``; under a mesh of P
+    devices ``mesh<P>:`` and then how each device's block got to it.
 
-    The choice is made from what can be seen here: under a mesh the stack
-    is sharded over the workers as before; a stack that one copy can take
-    without reaching the runtime's cliff (``tiled_bytes`` under
+    Under a mesh every device's block of workers (a view of ``X``) goes
+    from the host to that device and to no other, and the blocks are joined
+    (``jax.make_array_from_single_device_arrays``) into the array
+    ``shard_over_workers`` would give: the whole stack is never on one
+    device (ISSUE 30: four chips' shards do not fit one). The choice is
+    made per block, from what can be seen here: a block that one copy can
+    take without reaching the runtime's cliff (``tiled_bytes`` under
     ``min_tiled_bytes``), or one that is not contiguous, goes up as it is;
-    any other goes up as 2-D ``[rows, columns]`` blocks of whole workers
+    any other goes up as 2-D ``[rows, columns]`` pieces of whole workers
     (``_place_flat``). The keywords are for tests and measurements.
     """
+    if mesh is None:
+        sharding, devices, blocks, label = None, [None], [X], ""
+    else:
+        sharding = worker_sharding(mesh, X.ndim)
+        where = sharding.addressable_devices_indices_map(X.shape)
+        devices = list(where)
+        blocks = [X[where[dev]] for dev in devices]  # views: rows p*S..(p+1)*S
+        label = f"mesh{mesh.size}:"
+    block = blocks[0]
     if (
-        mesh is not None
-        or X.ndim != 3
-        or tiled_bytes(X.shape, X.dtype.itemsize) < min_tiled_bytes
+        X.ndim != 3
+        or tiled_bytes(block.shape, X.dtype.itemsize) < min_tiled_bytes
         or not X.flags.c_contiguous
     ):
-        return shard_over_workers(mesh, jnp.asarray(X)), "direct"
-    n = X.shape[0]
-    per_worker = X.shape[1] * X.shape[2]
-    # Blocks of about ``block_bytes``, all of one size but the last; whole
-    # rows of ``columns`` a block (no tail) where that is a few workers more.
-    block_workers = min(
-        n, max(1, block_bytes // (per_worker * X.dtype.itemsize))
+        if mesh is None:
+            return jnp.asarray(X), "direct"
+        outs = [jax.device_put(b, dev) for b, dev in zip(blocks, devices)]
+        label += "direct"
+    else:
+        n = block.shape[0]
+        per_worker = X.shape[1] * X.shape[2]
+        # Pieces of about ``block_bytes``, all of one size but the last;
+        # whole rows of ``columns`` a piece (no tail) where that is a few
+        # workers more.
+        block_workers = min(
+            n, max(1, block_bytes // (per_worker * X.dtype.itemsize))
+        )
+        block_workers = math.ceil(n / math.ceil(n / block_workers))
+        whole = columns // math.gcd(per_worker, columns)
+        if whole <= block_workers:
+            block_workers = math.ceil(block_workers / whole) * whole
+        outs, rows = _place_flat(blocks, devices, block_workers, columns)
+        label += f"flat:{rows}x{columns}/{math.ceil(n / block_workers)}"
+    if mesh is None:
+        return outs[0], label
+    return (
+        jax.make_array_from_single_device_arrays(X.shape, sharding, outs),
+        label,
     )
-    block_workers = math.ceil(n / math.ceil(n / block_workers))
-    whole = columns // math.gcd(per_worker, columns)
-    if whole <= block_workers:
-        block_workers = math.ceil(block_workers / whole) * whole
-    out, rows = _place_flat(X, block_workers, columns)
-    return out, f"flat:{rows}x{columns}/{math.ceil(n / block_workers)}"

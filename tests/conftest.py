@@ -33,6 +33,23 @@ def rng():
     return np.random.default_rng(0)
 
 
+def assert_ulps_of_scale(got, want, ulps):
+    """``got`` is ``want`` to ``ulps`` units in the last place of the largest
+    number in ``want``, in ``want``'s own float type.
+
+    The yardstick for TWO DIFFERENT executables of the same per-row
+    arithmetic (the sharded and the unsharded program): XLA contracts
+    ``w_self * x + sum(w_nbr * gathered)`` into fused multiply-adds as each
+    program's fusions fall, so a product rounds once more in one than in the
+    other — one unit of the row's scale a round (jax 0.9 on the CPU; the
+    same executable replayed stays bitwise and is held to that). The next
+    precision down misses it by orders of magnitude: float32 arithmetic is
+    2**29 float64 units off, bfloat16 2**16 float32 units."""
+    want = np.asarray(want)
+    atol = ulps * float(np.finfo(want.dtype).eps) * float(np.max(np.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=atol)
+
+
 def small_backend_config(**kw):
     """The canonical small experiment config shared by the backend-level test
     modules (test_backends, test_oracle_extensions): 8 ring workers, tiny

@@ -85,17 +85,100 @@ def test_flat_placement_is_the_host_array(case):
     ("not-contiguous", stack((40, 9, 7)).transpose(0, 2, 1), 0,
      dict(min_tiled_bytes=0)),
     ("labels-rank-2", stack((40, 7)), 0, dict(min_tiled_bytes=0)),
-    ("under-a-mesh", stack((40, 7, 9)), 4, dict(min_tiled_bytes=0)),
+    ("under-a-mesh-and-the-cliff", stack((40, 7, 9)), 4, {}),
+    ("labels-under-a-mesh", stack((40, 7)), 4, dict(min_tiled_bytes=0)),
 ])
 def test_direct_placement_where_flat_gains_nothing(why, X, mesh_size, kw):
     mesh = make_worker_mesh(X.shape[0], jax.devices()[:mesh_size]) if (
         mesh_size) else None
     got, how = place_shards(mesh, X, **kw)
-    assert how == "direct"
+    assert how == ("direct" if mesh is None else f"mesh{mesh_size}:direct")
     assert got.shape == X.shape and got.dtype == X.dtype
     np.testing.assert_array_equal(np.asarray(got), X)
     if mesh is not None:
         assert len(got.sharding.device_set) == mesh_size
+
+
+MESH_CASES = {
+    # name: (shape, dtype, devices, keywords, label)
+    "direct-f32": ((40, 7, 9), "float32", 4, {}, "mesh4:direct"),
+    "direct-f64": ((16, 5, 3), "float64", 2, {}, "mesh2:direct"),
+    "direct-rank-2": ((40, 7), "float32", 4, dict(min_tiled_bytes=0),
+                      "mesh4:direct"),
+    # 10 workers a device in pieces of 3: four pieces, the last of one
+    # worker, every piece with a tail (63 numbers a worker, 128 columns).
+    "flat-tails": (
+        (40, 7, 9), "float32", 4,
+        dict(min_tiled_bytes=0, block_bytes=3 * 63 * 4, columns=128),
+        "mesh4:flat:3x128/4"),
+    "flat-one-piece": (
+        (16, 7, 9), "bfloat16", 8,
+        dict(min_tiled_bytes=0, block_bytes=1 << 30, columns=128),
+        "mesh8:flat:0x128/1"),
+    "flat-f64": (
+        (16, 7, 9), "float64", 2,
+        dict(min_tiled_bytes=0, block_bytes=4 * 63 * 8, columns=128),
+        "mesh2:flat:2x128/2"),
+    # The cliff is held against ONE DEVICE's block: this stack is over the
+    # threshold whole, each of its four blocks under it.
+    "threshold-is-per-block": (
+        (8, 8, 128), "float32", 4, dict(min_tiled_bytes=3 * 8 * 128 * 4),
+        "mesh4:direct"),
+    "threshold-reached-by-a-block": (
+        (8, 8, 128), "float32", 4,
+        dict(min_tiled_bytes=2 * 8 * 128 * 4, block_bytes=8 * 128 * 4),
+        "mesh4:flat:2x1024/2"),
+}
+
+
+@pytest.mark.parametrize("case", list(MESH_CASES))
+def test_under_a_mesh_each_block_goes_to_its_own_device(case, monkeypatch):
+    """ISSUE 30: bit for bit the array ``shard_over_workers(mesh,
+    jnp.asarray(X))`` gives, every shard on its own device, and nothing of
+    the whole stack's size is ever put on, or made on, one device."""
+    shape, dtype, n_dev, kw, label = MESH_CASES[case]
+    X = stack(shape, np.dtype(dtype))
+    mesh = make_worker_mesh(shape[0], jax.devices()[:n_dev])
+    assert mesh.size == n_dev
+    largest = {"put": 0, "made": 0}
+    real_put, real_zeros, real_asarray = (
+        jax.device_put, jnp.zeros, jnp.asarray)
+
+    def device_put(x, device=None, **k):
+        assert isinstance(device, jax.Device), "each copy names its device"
+        largest["put"] = max(largest["put"], np.size(x))
+        return real_put(x, device, **k)
+
+    def zeros(shape_, dtype=None, **k):
+        # ``jnp.zeros(..., device=)`` fills on the FIRST device and sends
+        # the zeros on (chip_smoke.py's placement segment reads the peak
+        # that leaves): a buffer is filled from a scalar put on its device.
+        largest["made"] = max(largest["made"], int(np.prod(shape_)))
+        return real_zeros(shape_, dtype, **k)
+
+    def asarray(a, *args, **k):
+        assert np.size(a) < X.size, "the whole stack as one device array"
+        return real_asarray(a, *args, **k)
+
+    with jax.enable_x64(dtype == "float64"):
+        want = mesh_mod.shard_over_workers(mesh, jnp.asarray(X))
+        with monkeypatch.context() as patch:
+            patch.setattr(jax, "device_put", device_put)
+            patch.setattr(jnp, "zeros", zeros)
+            patch.setattr(jnp, "asarray", asarray)
+            got, how = place_shards(mesh, X, **kw)
+    assert how == label
+    assert got.shape == X.shape and got.dtype == want.dtype == X.dtype
+    assert got.sharding == want.sharding and got.committed
+    assert np.asarray(got).tobytes() == X.tobytes()
+    rows = shape[0] // n_dev
+    for p, shard in enumerate(sorted(
+            got.addressable_shards, key=lambda s: s.index[0].start)):
+        assert shard.device == mesh.devices.flat[p]
+        assert shard.index[0] == slice(p * rows, (p + 1) * rows)
+        assert shard.data.shape == (rows,) + shape[1:]
+    assert 0 < largest["put"] <= X.size // n_dev
+    assert largest["made"] <= 1
 
 
 def test_the_cliff_is_held_against_the_tiled_bytes():

@@ -90,8 +90,10 @@ def test_one_root_with_the_named_children_in_order(setup):
         "algorithm": "dsgd", "compress": "none", "select": "none",
         "wire_floats_per_edge": float(ds.n_features),
         # How the shards were stacked and how they went up (ISSUE 29): an
-        # ``argsort`` partition is gathered; so small a stack goes up as it is.
-        "stack": "gather", "placement": "direct",
+        # ``argsort`` partition is gathered; so small a stack goes up as it
+        # is, each device's block to that device (the 8 forced host devices
+        # are a mesh: ISSUE 30).
+        "stack": "gather", "placement": f"mesh{jax.device_count()}:direct",
     }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)
@@ -149,6 +151,39 @@ def test_root_says_how_the_shards_were_stacked_and_placed(
         assert by_name["dopt.run.stack_shards"]["args"]["bytes"] == (
             stacked.X.nbytes + stacked.y.nbytes
         )
+
+
+def test_root_names_the_worker_mesh_and_its_halo(setup):
+    """ISSUE 30: under ``worker_mesh`` the root says over how many devices
+    the workers lie and how many each holds, that mixing is the halo
+    gather, and the fullest device's boundary rows and bytes over ICI a
+    round, the numbers of ``telemetry.ici_summary`` (and so of the
+    ``dopt_worker_mesh_*`` gauges); the upload's ``bytes`` stay the total,
+    and no child span is added."""
+    from distributed_optimization_tpu.telemetry import ici_summary
+
+    cfg, ds = setup
+    cfg = cfg.replace(worker_mesh=4)
+    _, roots, children = run_under(Tracer(), cfg, ds)
+    args = roots[-1]["args"]
+    ici = ici_summary(cfg, d_features=ds.n_features)
+    assert args["mesh"] == f"4x{cfg.n_workers // 4}"
+    assert args["placement"] == "mesh4:direct"
+    assert args["mixing"] == "halo_gather"
+    # A ring block has two boundary rows whatever its length.
+    assert args["halo_rows"] == max(ici["halo_rows_per_device"]) == 2
+    assert args["ici_bytes_per_round"] == max(
+        ici["bytes_per_device_per_round"]) == 2 * ds.n_features * 4
+    assert names(children) in (CHILDREN_COLD, CHILDREN_WARM)
+    stacked = stack_shards(ds, dtype=np.float32)
+    by_name = {e["name"]: e for e in children}
+    assert by_name["dopt.run.upload"]["args"]["bytes"] == (
+        stacked.X.nbytes + stacked.y.nbytes + stacked.n_valid.nbytes
+    )
+    # An unsharded run says none of it.
+    _, roots, _ = run_under(Tracer(), cfg.replace(worker_mesh=0), ds)
+    assert not {"mesh", "mixing", "halo_rows", "ici_bytes_per_round"} & set(
+        roots[-1]["args"])
 
 
 @pytest.mark.parametrize("problem,classes", [("quadratic", 2), ("softmax", 3)])
