@@ -25,6 +25,15 @@ without a TPU it exits before doing any work. Segments:
    (``parallel.mesh.place_shards``: what a deployment past one chip's memory
    rests on).
 
+5. The carried forward product at a size the chip notices (N = 65,536 workers
+   of 53 rows, d = 81: a 1.1 GB stack): one run with the margins X·x carried
+   from the eval to the next step against the same run recomputing them, the
+   objective and consensus rows within the benchmark's GLM limits, the peak
+   of device memory within 0.1 GB, and the carried run split at an eval
+   boundary bitwise the unsplit run. What the CPU cannot see: where the
+   carry's buffers live, and whether the chip's two compilations of the
+   paired pass (in the loop, in front of it) round alike.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -205,6 +214,81 @@ def placement_segment(device: dict, *, n_workers: int = 65_536,
         del got
 
 
+# The benchmark's GLM limits (benchmark/configs/glm81_ring262k.json): worst
+# relative gap of the objective and of the consensus error over the rows.
+CARRY_LIMITS = {"objective": 1e-6, "consensus_error": 1e-5}
+CARRY_PEAK_ROOM = 100_000_000  # bytes the carried program may hold more
+
+
+def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
+                          rows: int = 53, d: int = 80,
+                          n_iterations: int = 200) -> None:
+    """Runs first on its chip, recomputed before carried, so that the peak
+    counter (which only rises) prices what the carry adds."""
+    import jax
+
+    from distributed_optimization_tpu.backends import jax_backend
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.observability.spans import process_tracer
+    from distributed_optimization_tpu.utils.data import HostDataset
+
+    rng = np.random.default_rng(31)
+    n = n_workers * rows
+    y = np.where(rng.random(n) < 0.5, -1.0, 1.0).astype(np.float32)
+    X = rng.standard_normal((n, d + 1), dtype=np.float32)
+    X[:, :8] += 0.5 * y[:, None]
+    X[:, -1] = 1.0
+    ds = HostDataset(
+        X_full=X, y_full=y, problem_type="logistic",
+        shard_indices=[np.arange(i * rows, (i + 1) * rows) for i in range(n_workers)],
+    )
+    cfg = ExperimentConfig(
+        problem_type="logistic", algorithm="dsgd", topology="ring",
+        n_workers=n_workers, n_samples=n, n_features=d,
+        n_informative_features=8, n_iterations=n_iterations,
+    )
+    chip = jax.devices()[0]
+
+    def run(label, **kw):
+        result = jax_backend.run(cfg, ds, 0.0, use_mesh=False,
+                                 executable_cache=False, **kw)
+        root = [e["args"] for e in process_tracer().spans()
+                if e["name"] == "dopt.run"][-1]
+        peak = int(chip.memory_stats()["peak_bytes_in_use"])
+        _say(f"forward {label}", device, result.history, forward=root["forward"],
+             peak_bytes=peak, final_loss=f"{result.history.objective[-1]:.6f}")
+        return result, root["forward"], peak
+
+    decide = jax_backend._forward_is_carried
+    jax_backend._forward_is_carried = lambda *a, **k: False
+    try:
+        want, how, peak_recomputed = run("recomputed")
+    finally:
+        jax_backend._forward_is_carried = decide
+    _check(how == "recomputed", "the private switch gives the recomputed program")
+    got, how, peak_carried = run("carried")
+    _check(how == "carried",
+           "a GLM's D-SGD over 53-row shards carries its forward product")
+    for key, limit in CARRY_LIMITS.items():
+        a, b = getattr(got.history, key), getattr(want.history, key)
+        rel = float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)))
+        print(f"[chip_smoke] forward: {key} carried against recomputed, worst "
+              f"relative gap {rel:.3e} (limit {limit})", flush=True)
+        _check(a.shape == (n_iterations,) and rel <= limit,
+               f"{key} rows of the carried run within {limit} of the recomputed")
+    print(f"[chip_smoke] forward: peak_bytes recomputed={peak_recomputed} "
+          f"carried={peak_carried}", flush=True)
+    _check(peak_carried - peak_recomputed <= CARRY_PEAK_ROOM,
+           f"the carry costs no more than {CARRY_PEAK_ROOM} bytes of device memory")
+    split, _, _ = run("carried, in segments of 80 evals",
+                      progress_cb=lambda ev: None, progress_every=80)
+    _check(np.array_equal(split.final_models, got.final_models)
+           and np.array_equal(split.history.objective, got.history.objective)
+           and np.array_equal(split.history.consensus_error,
+                              got.history.consensus_error),
+           "the carried run split at eval boundaries is bitwise the unsplit run")
+
+
 def four_chip_segment(device: dict, *, n_workers: int = 100_000,
                       n_samples: int = 200_000, n_iterations: int = 100) -> None:
     from distributed_optimization_tpu.config import ExperimentConfig
@@ -263,6 +347,7 @@ def main() -> int:
           f"compile_cache={cache_dir}", flush=True)
     if device["count"] >= 4:
         placement_segment(device)  # first: the peaks it reads are its own
+    forward_carry_segment(device)  # next: its chip's peak is still its own
     glm_segment(device)
     softmax_segment(device)
     reference_segment(device)

@@ -127,6 +127,13 @@ class Algorithm:
     # local steps). config.LOCAL_STEP_ALGORITHMS mirrors this flag so
     # validation stays jax-free.
     supports_local_steps: bool = False
+    # Whether ``step`` takes its first gradient at the carried models
+    # themselves: ``ctx.grad(state["x"], 0)``, that very array. The jax scan
+    # may then hand that call the margins X·x it carried from the last eval
+    # instead of reading the shards for them (jax_backend.
+    # _forward_is_carried); a gradient anywhere else (x/w, a mixed or
+    # half-stepped model, a later slot) is computed as ever.
+    first_grad_at_x: bool = False
     # Optional override of the per-edge float payload for comms accounting:
     # (config, d) -> floats per edge per iteration. None = d · gossip_rounds
     # (full-vector exchange). Compressed-gossip algorithms set this.

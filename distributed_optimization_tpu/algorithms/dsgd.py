@@ -116,5 +116,6 @@ def _comm_payload(config, d: int) -> float:
 DSGD = register_algorithm(
     Algorithm(name="dsgd", init=_init, step=_step, gossip_rounds=1,
               supports_byzantine=True, supports_churn=True,
-              supports_local_steps=True, comm_payload=_comm_payload)
+              supports_local_steps=True, first_grad_at_x=True,
+              comm_payload=_comm_payload)
 )

@@ -41,7 +41,7 @@ from distributed_optimization_tpu.metrics import (
 from distributed_optimization_tpu.models import get_problem
 from distributed_optimization_tpu.observability.spans import current_tracer
 from distributed_optimization_tpu.ops.compression import selection_label
-from distributed_optimization_tpu.ops.losses import sq_norm
+from distributed_optimization_tpu.ops.losses import paired_margins, sq_norm
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
 from distributed_optimization_tpu.ops.sampling import (
     sample_worker_batch_weights,
@@ -87,6 +87,13 @@ from distributed_optimization_tpu.utils.data import HostDataset, stack_shards
 DENSE_SAMPLING_WARN_ROWS = 256
 
 
+def _full_data_weights(X, n_valid):
+    """``[N, L]`` weights of the global mean over the stacked shards:
+    padding rows 0, every real row 1/total."""
+    mask = (jnp.arange(X.shape[1])[None, :] < n_valid[:, None]).astype(X.dtype)
+    return mask / jnp.maximum(jnp.sum(n_valid).astype(X.dtype), 1.0)
+
+
 def make_full_objective_fn(problem, reg):
     """Full-dataset objective of a single model w, computed from the stacked
     per-worker shards (so it shards over the mesh and reduces with one psum).
@@ -101,13 +108,9 @@ def make_full_objective_fn(problem, reg):
     """
 
     def full_objective(w, X, y, n_valid):
-        L = X.shape[1]
-        mask = (jnp.arange(L)[None, :] < n_valid[:, None]).astype(X.dtype)
-        total = jnp.maximum(jnp.sum(n_valid).astype(X.dtype), 1.0)
-        weights = mask / total  # [N, L]
         per_worker = jax.vmap(
             lambda Xi, yi, wi: problem.objective_weighted(w, Xi, yi, wi, 0.0)
-        )(X, y, weights)
+        )(X, y, _full_data_weights(X, n_valid))
         return jnp.sum(per_worker) + 0.5 * reg * sq_norm(w)
 
     return full_objective
@@ -314,12 +317,40 @@ class _StepPieces:
     # (collectives.make_halo_compressed_mixing_op); only set on the
     # worker-mesh path with compression != 'none'.
     compressed_mix: object = None
+    # ``_forward_is_carried``: the eval's pass over X also yields the next
+    # trip's margins X·x. Never on the replica-batched path.
+    carry_forward: bool = False
+
+
+def _forward_is_carried(algo, problem, config, faulty, *, rows, batch_size,
+                        sampling_impl, scheduled, collect_metrics):
+    """Whether the scan carries the margins ``z = X·x`` from a trip's eval to
+    the next trip's first gradient (two reads of the shard stack an
+    iteration, not three): decided by what the run is, never by an option.
+    All of: a GLM with a vector parameter (``[N, L]`` margins, a
+    bandwidth-bound pass; softmax's logits are a matmul); the gradient over
+    the whole padded shard (full batch or the dense sampler, no gathered or
+    injected batches); metrics collected (the eval makes the paired pass); a
+    rule whose first gradient is at the carried models
+    (``Algorithm.first_grad_at_x``; its compressed branch is left as it
+    was); no restart of x at a rejoin. Anything else recomputes, its program
+    unchanged."""
+    return bool(
+        problem.link is not None
+        and algo.first_grad_at_x and config.compression == "none"
+        and collect_metrics and not scheduled
+        and (batch_size >= rows or sampling_impl == "dense")
+        and (faulty is None or faulty.rejoin_restart is None)
+    )
 
 
 def _make_step_eval(p: _StepPieces, data):
     """Bind the step/eval/floats closures to the data pytree passed through
     jit (shared by the sequential and replica-batched paths — see
-    ``_StepPieces``)."""
+    ``_StepPieces``). Under ``p.carry_forward`` ``step(state, t, z)`` takes
+    the margins of ``state["x"]``, ``eval_metrics`` returns them for the
+    state it was shown (``(rows, z)``) and ``init_forward(state)`` makes the
+    ones a scan starts from; otherwise z is None throughout."""
     X, y, n_valid = data["X"], data["y"], data["n_valid"]
     schedule = data.get("schedule")
     batch_size = p.batch_size
@@ -343,7 +374,22 @@ def _make_step_eval(p: _StepPieces, data):
             n_valid[:, None].astype(X.dtype), 1.0
         )
 
-    def grad_fn_factory(t):
+    if p.carry_forward:
+        link = p.problem.link
+        eval_wts = _full_data_weights(X, n_valid)
+
+    def full_objective(x, xbar):
+        """``(f(x̄) over the full data, z)``; carried, both from ONE pass over
+        X: ``z = X·x`` is the next trip's forward product. Else z is None."""
+        if not p.carry_forward:
+            return p.full_objective(xbar, X, y, n_valid), None
+        z, zbar = paired_margins(X, x, xbar)
+        data_loss = jnp.sum(jnp.sum(eval_wts * link.loss(zbar, y), axis=1))
+        return data_loss + 0.5 * p.reg * sq_norm(xbar), z
+
+    def grad_fn_factory(t, z=None, z_of=None):
+        """The iteration's ``ctx.grad``; ``z`` = X·``z_of`` as the scan
+        carried it, used where the rule asks at that very array, slot 0."""
         def grad(params, slot):
             if schedule is not None:
                 idx = schedule[t]  # [N, b] injected batch indices
@@ -368,13 +414,18 @@ def _make_step_eval(p: _StepPieces, data):
                     slot_key, t, X, y, n_valid, batch_size
                 )
                 wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
+            if z is not None and params is z_of and slot == 0:
+                return jax.vmap(
+                    link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
+                )(z, params, Xb, yb, wts, p.reg)
             return jax.vmap(
                 p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
             )(params, Xb, yb, wts, p.reg)
 
         return grad
 
-    def step(state, t):
+    def step(state, t, z=None):
+        z_of = state["x"]  # the array the carried margins are of
         if faulty is not None and faulty.rejoin_restart is not None:
             # neighbor_restart rejoin policy: BEFORE the step at the
             # rejoin round, a node coming back from an outage replaces
@@ -413,7 +464,7 @@ def _make_step_eval(p: _StepPieces, data):
                 )
             )
         ctx = StepContext(
-            grad=grad_fn_factory(t),
+            grad=grad_fn_factory(t, z, z_of),
             mix=mix_fn,
             neighbor_sum=nbr_fn,
             # Cast to the run dtype so low-precision carries (bfloat16)
@@ -508,6 +559,7 @@ def _make_step_eval(p: _StepPieces, data):
         steady overhead on the CPU container; docs/perf/telemetry.json).
         """
         out = {}
+        z_next = None
         if p.telemetry:
             if cadence_known:
                 out["trace"] = trace_row(state, t_last)
@@ -534,7 +586,8 @@ def _make_step_eval(p: _StepPieces, data):
                 xbar = jnp.sum(
                     x * jnp.expand_dims(hw, param_axes), axis=0
                 ) / nh
-                out["gap"] = p.full_objective(xbar, X, y, n_valid) - p.f_opt
+                f_bar, z_next = full_objective(x, xbar)
+                out["gap"] = f_bar - p.f_opt
                 if p.track_consensus:
                     out["cons"] = (
                         jnp.sum(
@@ -544,12 +597,24 @@ def _make_step_eval(p: _StepPieces, data):
                     )
             else:
                 xbar = jnp.mean(x, axis=0)
-                out["gap"] = p.full_objective(xbar, X, y, n_valid) - p.f_opt
+                f_bar, z_next = full_objective(x, xbar)
+                out["gap"] = f_bar - p.f_opt
                 if p.track_consensus:
                     out["cons"] = jnp.mean(
                         jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
                     )
-        return out
+        return out, z_next
+
+    def init_forward(state):
+        """The margins a scan starts from, by the paired pass itself. The
+        barrier keeps x̄'s half alive: with it thrown away the compiler
+        makes another reduction of the half that is left, and a segment's
+        first trip is no longer bitwise the unsplit run's (at d = 81, CPU)."""
+        if not p.carry_forward:
+            return None
+        x = state["x"]
+        pair = paired_margins(X, x, jnp.mean(x, axis=0))
+        return jax.lax.optimization_barrier(pair)[0]
 
     def floats_for(ts):
         # Honest comms accounting under faults: floats actually
@@ -561,7 +626,7 @@ def _make_step_eval(p: _StepPieces, data):
             * p.edge_payload
         )
 
-    return step, eval_metrics, floats_for
+    return step, eval_metrics, floats_for, init_forward
 
 
 def _flat_scan_cadence(scan_unroll: int, eval_every: int):
@@ -1698,6 +1763,14 @@ def _run(
         telemetry=config.telemetry, robust_activity=robust_activity,
         static_degree_sum=static_degree_sum,
         compressed_mix=compressed_mix,
+        carry_forward=_forward_is_carried(
+            algo, problem, config, faulty, rows=device_data.X.shape[1],
+            batch_size=batch_size, sampling_impl=sampling_impl,
+            scheduled=schedule is not None, collect_metrics=collect_metrics,
+        ),
+    )
+    spans.note_root(
+        forward="carried" if pieces.carry_forward else "recomputed"
     )
 
     n_evals = T // eval_every
@@ -1727,24 +1800,33 @@ def _run(
         n_trips_seg = n_seg_evals * trips_per_eval
 
         def seg_scan(state_init, t0, data):
-            step, eval_metrics, floats_for = _make_step_eval(pieces, data)
+            step, eval_metrics, floats_for, init_forward = _make_step_eval(
+                pieces, data
+            )
 
-            def microchunk(state, ts_row):
+            # The carry is (state, z): the margins X·x of the carried models
+            # where the forward product is carried, else None (no leaf: the
+            # program is the state's alone). z is the program's, not the
+            # state's contract: made here, dropped at the end.
+            def microchunk(carry, ts_row):
+                state, z = carry
                 for j in range(micro):
-                    state, _ = step(state, ts_row[j])
-                out = eval_metrics(
+                    state, _ = step(state, ts_row[j], z if j == 0 else None)
+                out, z = eval_metrics(
                     state, ts_row[-1], cadence_known=trips_per_eval == 1
                 )
                 if faulty is not None:
                     out["floats"] = floats_for(ts_row)
-                return state, out
+                return (state, z), out
 
             ts = (
                 t0 + jnp.arange(n_trips_seg * micro, dtype=jnp.int32)
             ).reshape(n_trips_seg, micro)
-            return jax.lax.scan(
-                microchunk, state_init, ts, unroll=flat_unroll
+            (state, _), ys = jax.lax.scan(
+                microchunk, (state_init, init_forward(state_init)), ts,
+                unroll=flat_unroll,
             )
+            return state, ys
 
         return seg_scan
 
@@ -2546,12 +2628,12 @@ def _run_batch(
             telemetry=config.telemetry, robust_activity=robust_activity,
             static_degree_sum=static_degree_sum,
         )
-        step, eval_metrics, floats_for = _make_step_eval(pieces, data)
+        step, eval_metrics, floats_for, _ = _make_step_eval(pieces, data)
 
         def microchunk(state, ts_row):
             for j in range(micro):
                 state, _ = step(state, ts_row[j])
-            out = eval_metrics(
+            out, _ = eval_metrics(
                 state, ts_row[-1], cadence_known=trips_per_eval == 1
             )
             if faulty is not None:

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable
+from typing import Any, Callable, Optional
 
 import jax
 
@@ -30,6 +30,11 @@ class Problem:
     - ``objective_weighted(w, X, y, weights, reg)`` / ``gradient_weighted`` —
       per-sample-weight forms used on the TPU path (static shapes; weights
       encode masking / effective batch size).
+    - ``link`` — the scalar-output GLMs' per-row pair on the margin
+      ``z = X @ w`` (``ops.losses.MarginLink``: ``loss(z, y)``,
+      ``coeff(z, y)``, and ``gradient_at(z, ...)`` for a caller that
+      already holds the margins); the four kernels above are that pair's
+      (``glm_problem``). None for a family with no scalar margin (softmax).
     - ``param_shape(n_features)`` — the shape of ONE worker's parameter
       for a d-feature dataset: ``(d,)`` for the scalar-output GLMs,
       ``(d, K)`` for the softmax family. The jax scan carries every
@@ -51,9 +56,22 @@ class Problem:
     objective_weighted: Callable[..., jax.Array]
     gradient_weighted: Callable[..., jax.Array]
     param_shape: Callable[[int], tuple] = lambda d: (d,)
+    link: Optional[Any] = None
 
     def param_dim(self, n_features: int) -> int:
         return math.prod(self.param_shape(n_features))
+
+
+def glm_problem(name: str, link) -> Problem:
+    """The Problem of a scalar-output GLM family stated as its margin pair."""
+    return Problem(
+        name=name,
+        objective=link.objective,
+        gradient=link.gradient,
+        objective_weighted=link.objective_weighted,
+        gradient_weighted=link.gradient_weighted,
+        link=link,
+    )
 
 
 _REGISTRY: dict[str, Problem] = {}

@@ -11,7 +11,11 @@ parameter and does not minimize this objective).
 
 import functools
 
-from distributed_optimization_tpu.models.base import Problem, register_problem
+from distributed_optimization_tpu.models.base import (
+    Problem,
+    glm_problem,
+    register_problem,
+)
 from distributed_optimization_tpu.ops import losses
 
 
@@ -20,20 +24,10 @@ def make_huber_problem(delta: float) -> Problem:
     """Huber Problem with the transition point bound to ``delta``.
 
     Cached per δ so a given δ always yields the SAME callable objects —
-    the backends pass these as jit static arguments, and a fresh partial
+    the backends pass these as jit static arguments, and a fresh instance
     per call would defeat XLA's compilation cache.
     """
-    return Problem(
-        name="huber",
-        objective=functools.partial(losses.huber_objective, delta=delta),
-        gradient=functools.partial(losses.huber_gradient, delta=delta),
-        objective_weighted=functools.partial(
-            losses.huber_objective_weighted, delta=delta
-        ),
-        gradient_weighted=functools.partial(
-            losses.huber_gradient_weighted, delta=delta
-        ),
-    )
+    return glm_problem("huber", losses.huber_link(delta))
 
 
 HUBER = register_problem(make_huber_problem(losses.HUBER_DELTA))

@@ -4,15 +4,7 @@ Capability parity with reference ``obj_problems.py:3-36`` (the convex test
 problem of the study, PDF §II-B).
 """
 
-from distributed_optimization_tpu.models.base import Problem, register_problem
+from distributed_optimization_tpu.models.base import glm_problem, register_problem
 from distributed_optimization_tpu.ops import losses
 
-LOGISTIC = register_problem(
-    Problem(
-        name="logistic",
-        objective=losses.logistic_objective,
-        gradient=losses.logistic_gradient,
-        objective_weighted=losses.logistic_objective_weighted,
-        gradient_weighted=losses.logistic_gradient_weighted,
-    )
-)
+LOGISTIC = register_problem(glm_problem("logistic", losses.LOGISTIC))

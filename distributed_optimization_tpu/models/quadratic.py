@@ -4,15 +4,7 @@ Capability parity with reference ``obj_problems.py:39-69`` (the strongly
 convex test problem of the study, PDF §II-B).
 """
 
-from distributed_optimization_tpu.models.base import Problem, register_problem
+from distributed_optimization_tpu.models.base import glm_problem, register_problem
 from distributed_optimization_tpu.ops import losses
 
-QUADRATIC = register_problem(
-    Problem(
-        name="quadratic",
-        objective=losses.quadratic_objective,
-        gradient=losses.quadratic_gradient,
-        objective_weighted=losses.quadratic_objective_weighted,
-        gradient_weighted=losses.quadratic_gradient_weighted,
-    )
-)
+QUADRATIC = register_problem(glm_problem("quadratic", losses.QUADRATIC))

@@ -19,8 +19,13 @@ them against ``jax.grad`` of the objectives and finite differences.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distributed_optimization_tpu.config import DEFAULT_HUBER_DELTA
 
@@ -31,87 +36,94 @@ def _softplus_neg(z: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Logistic regression (convex):  f(w) = mean_i log(1+exp(-y_i x_i^T w)) + (λ/2)‖w‖²
+# The scalar-output GLMs, each stated ONCE as its per-row pair on the margin
+# z = xᵀw:  loss φ(z, y)  and  coeff ψ(z, y) = ∂φ/∂z.  Every kernel of a
+# family is the same four lines over its pair (``MarginLink``):
+#
+#   f(w)  = mean_i φ(z_i, y_i) + (λ/2)‖w‖²        ∇f(w) = Xᵀψ(z, y)/n + λw
+#   weighted:  Σ_i weights_i·φ(z_i, y_i) + (λ/2)‖w‖²,  Xᵀ(weights·ψ(z, y)) + λw
+#
+# so a caller that already holds the margins (the jax scan carries X·x from
+# the eval's pass over X to the next step, ``paired_margins`` below) asks for
+# the gradient at them (``gradient_at``) and reads the shard once less.
 # ---------------------------------------------------------------------------
 
 
-def logistic_objective(w: jax.Array, X: jax.Array, y: jax.Array, lam: float) -> jax.Array:
-    """Full-batch logistic objective. Parity: reference obj_problems.py:3-11."""
-    margins = y * (X @ w)
-    data_loss = jnp.mean(_softplus_neg(margins))
-    return data_loss + 0.5 * lam * jnp.dot(w, w)
+@dataclasses.dataclass(frozen=True)
+class MarginLink:
+    """One GLM family: ``loss(z, y)`` and ``coeff(z, y)`` per row of the
+    margin ``z = X @ w``, and the kernels every family shares over them."""
+
+    loss: Callable[[jax.Array, jax.Array], jax.Array]
+    coeff: Callable[[jax.Array, jax.Array], jax.Array]
+
+    def objective(self, w, X, y, lam):
+        """Full-batch mean objective (reference obj_problems.py:3-11, 39-44)."""
+        return jnp.mean(self.loss(X @ w, y)) + 0.5 * lam * jnp.dot(w, w)
+
+    def gradient(self, w, X, y, lam):
+        """Mean gradient over the given rows (reference obj_problems.py:13-20,
+        46-53; applied to a full shard, its dead full-gradient code)."""
+        return X.T @ self.coeff(X @ w, y) / X.shape[0] + lam * w
+
+    def objective_weighted(self, w, X, y, weights, lam):
+        """Σ_i weights_i·loss_i + (λ/2)‖w‖². With ``weights = mask / count``
+        the reference's mean over the valid rows; with all-zero weights the
+        pure regularizer (the reference returns 0.0 for an empty batch,
+        obj_problems.py:4-5; the sampling layer guarantees nonempty batches
+        whenever a worker has data)."""
+        return jnp.sum(weights * self.loss(X @ w, y)) + 0.5 * lam * jnp.dot(w, w)
+
+    def gradient_at(self, z, w, X, y, weights, lam):
+        """The weighted gradient at ``w`` given its margins ``z = X @ w``."""
+        return X.T @ (weights * self.coeff(z, y)) + lam * w
+
+    def gradient_weighted(self, w, X, y, weights, lam):
+        return self.gradient_at(X @ w, w, X, y, weights, lam)
 
 
-def logistic_gradient(w: jax.Array, X: jax.Array, y: jax.Array, lam: float) -> jax.Array:
-    """Mini-batch (or full-batch) logistic gradient.
-
-    Parity: reference obj_problems.py:13-20 (stochastic) and, applied to a full
-    shard, obj_problems.py:22-36 (the reference's dead full-gradient code).
-    """
-    margins = y * (X @ w)
-    coeff = -y * jax.nn.sigmoid(-margins)  # d/dlogit of the loss, per sample
-    return X.T @ coeff / X.shape[0] + lam * w
-
-
-def logistic_objective_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, lam: float
-) -> jax.Array:
-    """Weighted logistic objective: sum_i weights_i * loss_i + (λ/2)‖w‖².
-
-    With ``weights = mask / count`` this equals the reference's mean over the
-    valid rows; with all-zero weights it degrades to the pure regularizer
-    (reference returns 0.0 for an empty batch, obj_problems.py:4-5 — the
-    regularizer-only value is used here instead so the function stays smooth;
-    the sampling layer guarantees nonempty batches whenever a worker has data).
-    """
-    margins = y * (X @ w)
-    data_loss = jnp.sum(weights * _softplus_neg(margins))
-    return data_loss + 0.5 * lam * jnp.dot(w, w)
+def paired_margins(X: jax.Array, x: jax.Array, xbar: jax.Array):
+    """``(X·x_i, X·x̄)`` for every worker's shard in ONE read of the stack:
+    X ``[N, L, d]``, x ``[N, d]`` (each worker's own model), x̄ ``[d]`` (one
+    model for all) -> two ``[N, L]`` margin arrays. One reduction over d with
+    two accumulators (a single variadic ``reduce``), so the bandwidth-bound
+    pass over X is paid once for both; two dots side by side are two reads.
+    Elementwise products in at least f32: no matmul precision applies."""
+    acc = jnp.promote_types(X.dtype, jnp.float32)
+    Xa = X.astype(acc)
+    zero = np.zeros((), acc)
+    z, zbar = jax.lax.reduce(
+        (Xa * x.astype(acc)[:, None, :], Xa * xbar.astype(acc)[None, None, :]),
+        (zero, zero),
+        lambda a, b: (a[0] + b[0], a[1] + b[1]),
+        dimensions=(2,),
+    )
+    return z.astype(X.dtype), zbar.astype(X.dtype)
 
 
-def logistic_gradient_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, lam: float
-) -> jax.Array:
-    margins = y * (X @ w)
-    coeff = weights * (-y) * jax.nn.sigmoid(-margins)
-    return X.T @ coeff + lam * w
+# Logistic regression (convex), labels in {-1, +1}:
+#   φ = log(1 + exp(−y z)),  ψ = −y σ(−y z)
+LOGISTIC = MarginLink(
+    loss=lambda z, y: _softplus_neg(y * z),
+    coeff=lambda z, y: -y * jax.nn.sigmoid(-(y * z)),
+)
+logistic_objective = LOGISTIC.objective
+logistic_gradient = LOGISTIC.gradient
+logistic_objective_weighted = LOGISTIC.objective_weighted
+logistic_gradient_weighted = LOGISTIC.gradient_weighted
 
+# Quadratic / least squares (strongly convex):  φ = ½(z − y)²,  ψ = z − y
+QUADRATIC = MarginLink(
+    loss=lambda z, y: 0.5 * (z - y) ** 2,
+    coeff=lambda z, y: z - y,
+)
+quadratic_objective = QUADRATIC.objective
+quadratic_gradient = QUADRATIC.gradient
+quadratic_objective_weighted = QUADRATIC.objective_weighted
+quadratic_gradient_weighted = QUADRATIC.gradient_weighted
 
 # ---------------------------------------------------------------------------
-# Quadratic / least squares (strongly convex):
-#   f(w) = ½ mean_i (x_i^T w − y_i)² + (μ/2)‖w‖²
-# ---------------------------------------------------------------------------
-
-
-def quadratic_objective(w: jax.Array, X: jax.Array, y: jax.Array, mu: float) -> jax.Array:
-    """Parity: reference obj_problems.py:39-44."""
-    residuals = X @ w - y
-    return 0.5 * jnp.mean(residuals**2) + 0.5 * mu * jnp.dot(w, w)
-
-
-def quadratic_gradient(w: jax.Array, X: jax.Array, y: jax.Array, mu: float) -> jax.Array:
-    """Parity: reference obj_problems.py:46-53 (and dead code 55-69)."""
-    residuals = X @ w - y
-    return X.T @ residuals / X.shape[0] + mu * w
-
-
-def quadratic_objective_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, mu: float
-) -> jax.Array:
-    residuals = X @ w - y
-    return 0.5 * jnp.sum(weights * residuals**2) + 0.5 * mu * jnp.dot(w, w)
-
-
-def quadratic_gradient_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, mu: float
-) -> jax.Array:
-    residuals = X @ w - y
-    return X.T @ (weights * residuals) + mu * w
-
-
-# ---------------------------------------------------------------------------
-# Huber regression (convex, robust):
-#   f(w) = mean_i H_δ(x_i^T w − y_i) + (λ/2)‖w‖²,
+# Huber regression (convex, robust):  φ = H_δ(z − y),  ψ = clip(z − y, −δ, δ),
 #   H_δ(r) = ½r² for |r| ≤ δ, else δ(|r| − ½δ)
 #
 # Not in the reference — the framework's third objective family: a robust
@@ -122,8 +134,7 @@ def quadratic_gradient_weighted(
 # optimum — the classical choice — and is configurable
 # (``ExperimentConfig.huber_delta``) because it is data-scale-dependent; the
 # single source of the default is config.DEFAULT_HUBER_DELTA. Closed forms
-# only: the gradient coefficient is clip(r, −δ, δ), smooth everywhere
-# (H_δ is C¹).
+# only: the coefficient is smooth everywhere (H_δ is C¹).
 # ---------------------------------------------------------------------------
 
 # Backward-compatible alias; the definition lives in config (jax-free) so the
@@ -136,38 +147,30 @@ def _huber(r: jax.Array, delta: float) -> jax.Array:
     return jnp.where(a <= delta, 0.5 * r * r, delta * (a - 0.5 * delta))
 
 
-def huber_objective(
-    w: jax.Array, X: jax.Array, y: jax.Array, lam: float,
-    delta: float = DEFAULT_HUBER_DELTA,
-) -> jax.Array:
-    r = X @ w - y
-    return jnp.mean(_huber(r, delta)) + 0.5 * lam * jnp.dot(w, w)
+@functools.lru_cache(maxsize=None)
+def huber_link(delta: float) -> MarginLink:
+    """The Huber pair with the transition bound to ``delta``; cached, so a
+    given δ is always the SAME object (jit static arguments stay identical)."""
+    return MarginLink(
+        loss=lambda z, y: _huber(z - y, delta),
+        coeff=lambda z, y: jnp.clip(z - y, -delta, delta),
+    )
 
 
-def huber_gradient(
-    w: jax.Array, X: jax.Array, y: jax.Array, lam: float,
-    delta: float = DEFAULT_HUBER_DELTA,
-) -> jax.Array:
-    r = X @ w - y
-    coeff = jnp.clip(r, -delta, delta)
-    return X.T @ coeff / X.shape[0] + lam * w
+def huber_objective(w, X, y, lam, delta=DEFAULT_HUBER_DELTA):
+    return huber_link(delta).objective(w, X, y, lam)
 
 
-def huber_objective_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, lam: float,
-    delta: float = DEFAULT_HUBER_DELTA,
-) -> jax.Array:
-    r = X @ w - y
-    return jnp.sum(weights * _huber(r, delta)) + 0.5 * lam * jnp.dot(w, w)
+def huber_gradient(w, X, y, lam, delta=DEFAULT_HUBER_DELTA):
+    return huber_link(delta).gradient(w, X, y, lam)
 
 
-def huber_gradient_weighted(
-    w: jax.Array, X: jax.Array, y: jax.Array, weights: jax.Array, lam: float,
-    delta: float = DEFAULT_HUBER_DELTA,
-) -> jax.Array:
-    r = X @ w - y
-    coeff = weights * jnp.clip(r, -delta, delta)
-    return X.T @ coeff + lam * w
+def huber_objective_weighted(w, X, y, weights, lam, delta=DEFAULT_HUBER_DELTA):
+    return huber_link(delta).objective_weighted(w, X, y, weights, lam)
+
+
+def huber_gradient_weighted(w, X, y, weights, lam, delta=DEFAULT_HUBER_DELTA):
+    return huber_link(delta).gradient_weighted(w, X, y, weights, lam)
 
 
 # ---------------------------------------------------------------------------

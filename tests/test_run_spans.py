@@ -94,6 +94,9 @@ def test_one_root_with_the_named_children_in_order(setup):
         # is, each device's block to that device (the 8 forced host devices
         # are a mesh: ISSUE 30).
         "stack": "gather", "placement": f"mesh{jax.device_count()}:direct",
+        # Whether the eval's pass over the shards also made the next step's
+        # margins (ISSUE 31): gathered batches on the CPU, so no.
+        "forward": "recomputed",
     }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)

@@ -234,9 +234,9 @@ def test_scan_step_carries_the_matrix_and_never_flattens_it():
             "n_valid": jnp.asarray(dev.n_valid)}
 
     def one_trip(state):
-        step, eval_metrics, _ = jax_backend._make_step_eval(pieces, data)
+        step, eval_metrics, *_ = jax_backend._make_step_eval(pieces, data)
         state, _ = step(state, jnp.int32(0))
-        return state, eval_metrics(state, jnp.int32(0), cadence_known=True)
+        return state, eval_metrics(state, jnp.int32(0), cadence_known=True)[0]
 
     x0 = jnp.zeros((n, *problem.param_shape(d)), jnp.float32)
     closed = jax.make_jaxpr(one_trip)({"x": x0})

@@ -131,3 +131,28 @@ def test_top_k_exchange_lowers_without_sort_or_scatter(shape):
     }, ops
     flat = f"tensor<{shape[0]}x{row_dim(x)}x"
     assert (flat in text) == (len(shape) == 2)
+
+
+def test_paired_margins_reach_the_chip_as_one_reduce_with_two_results():
+    """The carried forward product (``ops.losses.paired_margins``): what the
+    TPU's compiler is handed for X·x and X·x̄ is ONE ``stablehlo.reduce``
+    over d with two results and no ``dot_general``, at the GLM cells' shard
+    shape. XLA:TPU runs that as one ``multiply_reduce_fusion`` reading the
+    4.76 GB stack once (7.31 ms an iteration where two dots were 13.19:
+    PERF.md section 6, PR 31); two dots side by side it runs apart."""
+    from distributed_optimization_tpu.ops.losses import paired_margins
+
+    n, L, d = 1024, 53, 81
+    exported = _lower_for_tpu(
+        paired_margins,
+        jax.ShapeDtypeStruct((n, L, d), jnp.float32),
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((d,), jnp.float32),
+    )
+    text = exported.mlir_module()
+    assert "dot_general" not in text
+    reduces = re.findall(r"stablehlo\.reduce[^\n]*", text)
+    assert len(reduces) == 1
+    assert len(re.findall(
+        rf"-> \(tensor<{n}x{L}xf32>, tensor<{n}x{L}xf32>\)", text
+    )) == 1
