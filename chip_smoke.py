@@ -34,6 +34,15 @@ without a TPU it exits before doing any work. Segments:
    carry's buffers live, and whether the chip's two compilations of the
    paired pass (in the loop, in front of it) round alike.
 
+6. The same stack under the README's faults (30% of the links down, 10% of
+   the workers out, every round; ISSUE 32), right after segment 5 so that the
+   peak counter prices what the fault layer adds: the bits drawn inside the
+   step (``fault_form`` ``drawn``), the run split at an eval boundary bitwise
+   the unsplit run, ``live_edge_share`` within 0.567 ± 0.005, and the
+   device's peak no more than the fault-free run's plus the ``fault_bytes``
+   the root states. What the CPU cannot see: what an argument takes in the
+   device's tiles, and whether the executable holds a table of its own.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -222,14 +231,12 @@ CARRY_PEAK_ROOM = 100_000_000  # bytes the carried program may hold more
 
 def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
                           rows: int = 53, d: int = 80,
-                          n_iterations: int = 200) -> None:
+                          n_iterations: int = 200):
     """Runs first on its chip, recomputed before carried, so that the peak
-    counter (which only rises) prices what the carry adds."""
-    import jax
-
+    counter (which only rises) prices what the carry adds. Returns its
+    experiment and the peak it leaves, for ``faults_segment``."""
     from distributed_optimization_tpu.backends import jax_backend
     from distributed_optimization_tpu.config import ExperimentConfig
-    from distributed_optimization_tpu.observability.spans import process_tracer
     from distributed_optimization_tpu.utils.data import HostDataset
 
     rng = np.random.default_rng(31)
@@ -247,16 +254,10 @@ def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
         n_workers=n_workers, n_samples=n, n_features=d,
         n_informative_features=8, n_iterations=n_iterations,
     )
-    chip = jax.devices()[0]
 
     def run(label, **kw):
-        result = jax_backend.run(cfg, ds, 0.0, use_mesh=False,
-                                 executable_cache=False, **kw)
-        root = [e["args"] for e in process_tracer().spans()
-                if e["name"] == "dopt.run"][-1]
-        peak = int(chip.memory_stats()["peak_bytes_in_use"])
-        _say(f"forward {label}", device, result.history, forward=root["forward"],
-             peak_bytes=peak, final_loss=f"{result.history.objective[-1]:.6f}")
+        result, root, peak = _rooted_run(
+            f"forward {label}", device, cfg, ds, ("forward",), **kw)
         return result, root["forward"], peak
 
     decide = jax_backend._forward_is_carried
@@ -282,11 +283,67 @@ def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
            f"the carry costs no more than {CARRY_PEAK_ROOM} bytes of device memory")
     split, _, _ = run("carried, in segments of 80 evals",
                       progress_cb=lambda ev: None, progress_every=80)
-    _check(np.array_equal(split.final_models, got.final_models)
-           and np.array_equal(split.history.objective, got.history.objective)
-           and np.array_equal(split.history.consensus_error,
-                              got.history.consensus_error),
+    _check(_same_run(split, got),
            "the carried run split at eval boundaries is bitwise the unsplit run")
+    return cfg, ds, _peak_bytes()
+
+
+def _peak_bytes() -> int:
+    import jax
+
+    return int(jax.devices()[0].memory_stats()["peak_bytes_in_use"])
+
+
+def _rooted_run(label: str, device: dict, cfg, ds, say: tuple, **kw):
+    """One unsharded, uncached ``jax_backend.run``: (result, the arguments of
+    its ``dopt.run`` root, the device's peak after it); prints the ``say``
+    arguments with the segment's line."""
+    from distributed_optimization_tpu.backends import jax_backend
+    from distributed_optimization_tpu.observability.spans import process_tracer
+
+    result = jax_backend.run(cfg, ds, 0.0, use_mesh=False,
+                             executable_cache=False, **kw)
+    root = [e["args"] for e in process_tracer().spans()
+            if e["name"] == "dopt.run"][-1]
+    peak = _peak_bytes()
+    _say(label, device, result.history, **{k: root[k] for k in say},
+         peak_bytes=peak, final_loss=f"{result.history.objective[-1]:.6f}")
+    return result, root, peak
+
+
+def _same_run(a, b) -> bool:
+    return (np.array_equal(a.final_models, b.final_models)
+            and np.array_equal(a.history.objective, b.history.objective)
+            and np.array_equal(a.history.consensus_error,
+                               b.history.consensus_error))
+
+
+LIVE_EDGE_SHARE = 0.7 * 0.9 ** 2  # a link is up, and both its ends are
+
+
+def faults_segment(device: dict, cfg, ds, peak_fault_free: int) -> None:
+    """``forward_carry_segment``'s experiment under p = 0.3, q = 0.1, directly
+    after it: the peak counter only rises, so what it reads above
+    ``peak_fault_free`` is the fault layer's."""
+    cfg = cfg.replace(edge_drop_prob=0.3, straggler_prob=0.1)
+    say = ("faults", "fault_form", "fault_bytes", "live_edge_share", "forward")
+    got, root, peak = _rooted_run("faults p=0.3 q=0.1", device, cfg, ds, say)
+    _check(root["fault_form"] == "drawn" and root["forward"] == "carried",
+           "memoryless faults on the neighbor table are drawn in the step, "
+           "and the forward product stays carried")
+    _check(abs(root["live_edge_share"] - LIVE_EDGE_SHARE) <= 0.005,
+           f"live_edge_share {root['live_edge_share']:.5f} within 0.005 of "
+           f"{LIVE_EDGE_SHARE:.4f}")
+    print(f"[chip_smoke] faults: peak_bytes fault-free={peak_fault_free} "
+          f"faulty={peak} stated fault_bytes={root['fault_bytes']:.0f}", flush=True)
+    _check(peak - peak_fault_free <= root["fault_bytes"],
+           "the device's peak is no more than the fault-free run's plus the "
+           "stated fault_bytes")
+    split, _, _ = _rooted_run(
+        "faults p=0.3 q=0.1, in segments of 80 evals", device, cfg, ds, say,
+        progress_cb=lambda ev: None, progress_every=80)
+    _check(_same_run(split, got),
+           "the faulty run split at eval boundaries is bitwise the unsplit run")
 
 
 def four_chip_segment(device: dict, *, n_workers: int = 100_000,
@@ -347,7 +404,8 @@ def main() -> int:
           f"compile_cache={cache_dir}", flush=True)
     if device["count"] >= 4:
         placement_segment(device)  # first: the peaks it reads are its own
-    forward_carry_segment(device)  # next: its chip's peak is still its own
+    # next: its chip's peak is still its own, then the fault layer's on top
+    faults_segment(device, *forward_carry_segment(device))
     glm_segment(device)
     softmax_segment(device)
     reference_segment(device)

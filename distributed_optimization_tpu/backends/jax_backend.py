@@ -357,6 +357,10 @@ def _make_step_eval(p: _StepPieces, data):
     faulty, mix_op, byz_mix, adversary = (
         p.faulty, p.mix_op, p.byz_mix, p.adversary
     )
+    if "faults" in data:
+        # The gather fault layer over the tables this program was handed,
+        # not over constants of its own.
+        faulty = faulty.bind(data["faults"])
 
     # Full-batch fast path: sampling b >= L rows without replacement IS
     # the whole shard with 1/n_i weights (the reference's b=min(b, n_i)
@@ -642,6 +646,38 @@ def _flat_scan_cadence(scan_unroll: int, eval_every: int):
         if eval_every % d == 0
     )
     return micro, eval_every // micro, max(1, scan_unroll // micro)
+
+
+def _fault_root_args(config, faulty, tables) -> dict:
+    """What the ``dopt.run`` root says of a call that ran faults: ``faults``
+    (every active process with its rate), ``fault_form`` (``drawn``: each
+    round's bits made inside the step from (seed, t); ``timeline``: read
+    from a precomputed ``[horizon, ·]`` table) and, where the fault layer's
+    arrays are arguments of the program (``FaultyMixing.tables``),
+    ``fault_bytes``: what they take on the device, timeline leaves
+    included."""
+    parts = [
+        f"{name}:{value:g}" for name, value, on in (
+            ("edge_drop", config.edge_drop_prob, config.edge_drop_prob > 0.0),
+            ("burst", config.burst_len, config.burst_len >= 1.0),
+            ("straggler", config.straggler_prob, config.straggler_prob > 0.0),
+            ("mttf", config.mttf, config.mttf > 0.0),
+            ("mttr", config.mttr, config.mttf > 0.0),
+            ("participation", config.participation_rate,
+             config.participation_rate < 1.0),
+        ) if on
+    ]
+    if config.gossip_schedule != "synchronous":
+        parts.append(f"schedule:{config.gossip_schedule}")
+    args = {
+        "faults": ",".join(parts),
+        "fault_form": "drawn" if faulty.timeline is None else "timeline",
+    }
+    if tables is not None:
+        args["fault_bytes"] = float(sum(
+            leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
+        ))
+    return args
 
 
 def _build_faulty(config, algo, topo, T, *, drop_prob=None, keys=None,
@@ -1541,7 +1577,19 @@ def _run(
         # robust rule with a positive budget to defend with; robust_b == 0
         # keeps the plain gossip path bitwise (a robust rule degrades to
         # MH gossip at zero budget by definition).
-        faulty = _build_faulty(config, algo, topo, T, halo_mesh=halo_mesh)
+        faulty, fault_tables = None, None
+        if time_varying:
+            # ``faults``: whatever this call spends making, fetching or
+            # placing fault realizations and their tables
+            # (docs/OBSERVABILITY.md).
+            spans.enter("faults")
+            faulty = _build_faulty(
+                config, algo, topo, T, halo_mesh=halo_mesh
+            )
+            if faulty.tables is not None:
+                fault_tables = replicate(mesh, faulty.tables)
+            spans.note_root(**_fault_root_args(config, faulty, fault_tables))
+            spans.enter("prepare")
         adversary, byz_mix, robust_activity, fused_robust_step = (
             _bind_byzantine(
                 config, algo, topo, faulty, mix_op, halo_mesh=halo_mesh,
@@ -1623,6 +1671,7 @@ def _run(
         topo = None
         mix_op = None
         faulty = None
+        fault_tables = None
         edge_payload = None
         degrees = jnp.zeros(
             (n,) + (1,) * len(param_shape), dtype=device_data.X.dtype
@@ -1715,6 +1764,11 @@ def _run(
     data_args = {"X": X, "y": y, "n_valid": n_valid}
     if schedule is not None:
         data_args["schedule"] = schedule
+    if fault_tables is not None:
+        # The gather fault layer's tables (and a persistent process's
+        # timeline) too: a closed-over [horizon, N] leaf is a constant of
+        # the executable, in the device's tiles (ROADMAP A9).
+        data_args["faults"] = fault_tables
 
     track_consensus = (
         collect_metrics and algo.is_decentralized and config.record_consensus
@@ -1874,6 +1928,13 @@ def _run(
     )
     if gap_hist is None:
         gap_hist = np.full(len(time_hist), np.nan)
+    if realized_floats is not None and executed_iters and static_degree_sum:
+        # Of the static graph's links, the share that carried a model, over
+        # the iterations this call ran: the counter floats_transmitted keeps.
+        spans.note_root(live_edge_share=float(
+            realized_floats
+            / (static_degree_sum * edge_payload * executed_iters)
+        ))
 
     # Early-halt bookkeeping (ISSUE-13): a loop that stopped before the
     # horizon left fewer per-eval rows than n_evals. The histories stay

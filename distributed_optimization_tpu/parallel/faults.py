@@ -103,6 +103,24 @@ sums pick up off-by-ulp mass, corrupting both the mixing invariants and the
 "honest" comms metric. Only the mixed MODEL values are cast to the run
 dtype.
 
+**Matrix-free draws.** On a neighbor-table (matrix-free) topology no
+[N, N] uniform matrix exists, so the stream is its own, equally seed-pure:
+round t (counted from 0) draws ONE float32 uniform per edge,
+``uniform(fold_in(fold_in(key(seed), 0x0FA17), t), (E,))``, and one per
+node, ``uniform(fold_in(fold_in(key(seed), 0x57A66), t), (N,))``; edge e is
+up iff ``u_e >= p`` and node i takes part iff ``u_i >= q``. Edge e is row e
+of ``_edge_list(topo)``: the ``i < j`` entries of the ASCENDING neighbor
+table read row by row — on a ring of N nodes (0, 1), (0, N−1), (1, 2),
+(2, 3), …, (N−2, N−1), which is NOT {i, i+1} in turn (node 0's row holds
+both its neighbors before node 1's adds (1, 2)). A link carries a model iff
+it is up and both its ends take part: ``live_ij = up_ij · m_i · m_j``,
+``w_ij = live_ij / (1 + max(deg_i, deg_j))`` on realized degrees, the row
+remainder on the diagonal. Memoryless faults are drawn inside the step by
+exactly this rule; persistent processes unroll the same draws into a
+timeline (``build_fault_timeline``), so at burst_len = 1 or plain
+``straggler_prob`` the two are one realization, bit for bit
+(tests/test_fault_draws.py).
+
 Masks are derived purely from (fault key, iteration) — like batch sampling,
 fault realizations are reproducible and checkpoint/resume-safe with no
 carried RNG state.  The underlying uniform draws are EXPLICIT float32
@@ -188,6 +206,18 @@ class FaultyMixing:
     # memoryless on-the-fly path) — exposed for diagnostics
     # (``node_downtime``, ``windowed_connectivity``) and tests.
     timeline: Optional["FaultTimeline"] = None
+    # Matrix-free (gather) form only, else None: ``tables`` is the pytree
+    # of device arrays the operators above read, placed once when the
+    # mixing is built — the neighbor table, its mask, the (node, slot) →
+    # edge-id map and, for a persistent process, the ``[horizon, ·]``
+    # timeline leaves — and ``bind(tb)`` the same operators over another
+    # copy of it, such as the tracers a jitted program receives
+    # ``tables`` as. ``jax_backend._run`` hands the tables to the scan as
+    # ARGUMENTS; the unbound operators make them constants of whatever
+    # program traces them, which at 2^18 workers is hundreds of megabytes
+    # of executable (ROADMAP A9).
+    tables: Optional[dict] = None
+    bind: Optional[Callable[[dict], "FaultyMixing"]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -365,8 +395,10 @@ def _edge_list(topo: Topology) -> np.ndarray:
     sampler), or per one-way link (i, j) for directed graphs.
 
     Matrix-free topologies enumerate the same i < j rows from the
-    neighbor table without touching a dense [N, N] array (used by the
-    connectivity diagnostics; per-edge fault PROCESSES stay dense-only).
+    ascending neighbor table, read row by row, without touching a dense
+    [N, N] array. That order IS the numbering of the matrix-free per-edge
+    draws (module docstring, "Matrix-free draws"): on a ring of N nodes
+    (0, 1), (0, N−1), (1, 2), (2, 3), …, (N−2, N−1) — not {i, i+1} in turn.
     """
     if topo.is_matrix_free:
         rows, slots = np.nonzero(topo.nbr_mask)
@@ -784,8 +816,11 @@ def make_faulty_mixing(
     are cast back to the input's dtype.
 
     Memoryless faults (``drop_prob``/``straggler_prob`` alone) sample masks
-    on the fly from (seed, t).  Persistent processes — bursty links
-    (``burst_len >= 1``) and crash-recovery churn (``mttf``/``mttr``) —
+    on the fly from (seed, t) — dense topologies from the symmetric [N, N]
+    draw, unsharded matrix-free ones from one uniform an edge and one a
+    node (module docstring, "Matrix-free draws").  Persistent processes —
+    bursty links (``burst_len >= 1``) and crash-recovery churn
+    (``mttf``/``mttr``) —
     require ``horizon`` and route through a precomputed
     ``build_fault_timeline`` (gathered per iteration; bitwise-identical to
     the on-the-fly path at burst_len=1 / the iid-equivalent churn point).
@@ -847,12 +882,18 @@ def make_faulty_mixing(
     use_timeline = (
         burst_len >= 1.0 or churn_active or participation_active
         or timeline is not None
-        # Matrix-free faults always route through the precomputed
-        # timeline (iid stragglers' chains are bitwise the on-the-fly
-        # draws, and iid edge drops are the burst_len=1 point of the
-        # per-edge chains, so nothing changes semantically — one code
-        # path with no dense [N, N] draw anywhere).
-        or (topo.is_matrix_free and (strag_active or drop_active))
+        # The sharded matrix-free route keeps its per-shard [horizon, N/P]
+        # timeline slices for memoryless stragglers too. Unsharded,
+        # MEMORYLESS matrix-free faults are DRAWN inside the step from
+        # (seed, t) — the same keys, shapes and float32 comparison
+        # ``build_fault_timeline`` uses, one uniform an edge and one a
+        # node, so the realization is bitwise the timeline's and nothing
+        # [horizon, ·] is built, fetched or placed (no dense [N, N] draw
+        # either way). Decided by what the faults are, never by an option.
+        or (
+            topo.is_matrix_free and mesh is not None
+            and (strag_active or drop_active)
+        )
     )
     if use_timeline and timeline is None:
         if horizon is None:
@@ -869,6 +910,13 @@ def make_faulty_mixing(
             mttf=mttf, mttr=mttr,
             participation_rate=participation_rate,
         )
+    # Distinct streams from batch sampling: fold tags into the seed key
+    # (or take the caller's pre-derived per-replica keys verbatim).
+    if keys is None:
+        fault_key = jax.random.fold_in(jax.random.key(seed), 0x0FA17)
+        node_key = jax.random.fold_in(jax.random.key(seed), 0x57A66)
+    else:
+        fault_key, node_key, _ = keys
     if mesh is not None and not topo.is_matrix_free:
         raise ValueError(
             "sharded (worker_mesh) fault mixing is neighbor-table-native: "
@@ -908,15 +956,9 @@ def make_faulty_mixing(
             topo, timeline, drop_prob=drop_prob,
             straggler_prob=straggler_prob, churn_active=churn_active,
             participation_active=participation_active, rejoin=rejoin,
+            fault_key=fault_key, node_key=node_key,
         )
     base_A = jnp.asarray(topo.adjacency, dtype=jnp.float32)
-    # Distinct streams from batch sampling: fold tags into the seed key
-    # (or take the caller's pre-derived per-replica keys verbatim).
-    if keys is None:
-        fault_key = jax.random.fold_in(jax.random.key(seed), 0x0FA17)
-        node_key = jax.random.fold_in(jax.random.key(seed), 0x57A66)
-    else:
-        fault_key, node_key, _ = keys
 
     if use_timeline:
         node_up_dev = (
@@ -1133,19 +1175,22 @@ def make_faulty_mixing(
 
 def _make_gather_faulty_mixing(
     topo: Topology,
-    timeline: FaultTimeline,
+    timeline: Optional[FaultTimeline],
     *,
     drop_prob: float,
     straggler_prob: float,
     churn_active: bool,
     participation_active: bool,
     rejoin: str,
+    fault_key,
+    node_key,
 ) -> FaultyMixing:
-    """Node-process faults over a matrix-free (neighbor-table) topology.
+    """Faults over a matrix-free (neighbor-table) topology, in gather form.
 
-    The realized graph at round t is the static table masked by the
-    composed node-availability row m_t (churn/straggler-up AND
-    sampled-in): ``live_t[i, s] = mask[i, s] · m_t[i] · m_t[nbr[i, s]]``.
+    The realized graph at round t is the static table masked by the edge
+    liveness bits and the composed node-availability row m_t
+    (churn/straggler-up AND sampled-in):
+    ``live_t[i, s] = mask[i, s] · up_t[slot[i, s]] · m_t[i] · m_t[nbr[i, s]]``.
     Realized MH weights come straight from the live slots —
     ``w = live / (1 + max(deg_i, deg_{nbr}))`` with the row remainder on
     the diagonal, the identical per-entry formula the dense
@@ -1154,153 +1199,201 @@ def _make_gather_faulty_mixing(
     whole time-varying gossip round stays O(N·k_max·d) with no [N, N]
     object anywhere. Same float32 mask/weight convention as the dense
     path; only the mixed model values are cast back to the input dtype.
+
+    Where the bits come from is decided by ``timeline``: given one
+    (persistent processes, the replica-batched stacker, an injected
+    realization) round t reads its rows; given none (memoryless faults)
+    round t DRAWS them from ``fold_in(fault_key, t)`` / ``fold_in(node_key,
+    t)`` — ``build_fault_timeline``'s own keys, shapes and float32
+    comparison, so the two forms realize one graph bit for bit and the
+    drawn form holds nothing ``[horizon, ·]``.
+
+    Every array the operators read lives in ONE pytree, ``tables``, placed
+    on the device here, and the operators are built over a copy of it by
+    ``bind`` (see the ``FaultyMixing`` fields): the caller decides whether
+    the tables are arguments of its program or constants in it.
     """
     n = topo.n
-    nbr_dev = jnp.asarray(topo.nbr_idx, dtype=jnp.int32)
-    mask_dev = jnp.asarray(topo.nbr_mask, dtype=jnp.float32)
-    node_up_dev = (
-        jnp.asarray(timeline.node_up)
-        if timeline is not None and timeline.node_up is not None else None
+    k_max = topo.nbr_idx.shape[1]
+    drawn = timeline is None
+    # The drawn form's thresholds, None where that process is off (a traced
+    # drop probability always draws: see ``make_faulty_mixing``).
+    drop_off = isinstance(drop_prob, (int, float)) and drop_prob == 0.0
+    drop_p = (
+        jnp.asarray(drop_prob, dtype=jnp.float32)
+        if drawn and not drop_off else None
     )
-    part_up_dev = (
-        jnp.asarray(timeline.part_up)
-        if timeline is not None and timeline.part_up is not None else None
+    strag_q = (
+        np.float32(straggler_prob)
+        if drawn and straggler_prob > 0.0 else None
     )
-    # Per-edge chains in gather form (ISSUE-9 satellite): the [horizon, E]
-    # liveness bits land on both endpoints' rows through the static
-    # (node, slot) → edge-id table — the same symmetric composition the
-    # dense path realizes by scattering A[ei, ej] = A[ej, ei] = up[e],
-    # with no [N, N] object anywhere.
-    edge_up_dev = None
-    slot_dev = None
-    if timeline is not None and timeline.edge_up is not None:
+    # The [N, k_max] tables are kept SLOT-MAJOR, [k_max, N]: as an argument
+    # in the TPU's (8, 128) tiles an s32[262144, 2] is 134 MB where its
+    # transpose is 2; the operators read them through ``.T``, which costs a
+    # program nothing (a layout).
+    tables = {
+        "nbr": np.asarray(topo.nbr_idx, dtype=np.int32).T,
+        "mask": np.asarray(topo.nbr_mask, dtype=np.float32).T,
+    }
+    # Per-edge liveness in gather form (ISSUE-9 satellite): the bits land
+    # on both endpoints' rows through the static (node, slot) → edge-id
+    # table — the same symmetric composition the dense path realizes by
+    # scattering A[ei, ej] = A[ej, ei] = up[e], with no [N, N] object.
+    edge_index = None
+    if not drawn and timeline.edge_up is not None:
+        edge_index = timeline.edge_index
+        tables["edge_up"] = timeline.edge_up
+    elif drop_p is not None:
+        edge_index = _edge_list(topo)
+    if edge_index is not None:
         from distributed_optimization_tpu.parallel.topology import (
             incident_edge_slots,
         )
 
-        edge_up_dev = jnp.asarray(timeline.edge_up)
-        slot_dev = jnp.asarray(
-            incident_edge_slots(
-                topo.nbr_idx, topo.nbr_mask, timeline.edge_index
-            ),
-            dtype=jnp.int32,
-        )
+        tables["slot"] = incident_edge_slots(
+            topo.nbr_idx, topo.nbr_mask, edge_index
+        ).T
+        n_edges = edge_index.shape[0]
+    if not drawn:
+        for name in ("node_up", "part_up"):
+            if getattr(timeline, name) is not None:
+                tables[name] = getattr(timeline, name)
+        if churn_active and rejoin == "neighbor_restart":
+            tables["rejoin"] = timeline.rejoin
 
-    def active(t) -> jax.Array:
-        if node_up_dev is None and part_up_dev is None:
-            return jnp.ones(n, dtype=jnp.float32)
-        if node_up_dev is None:
-            return part_up_dev[t].astype(jnp.float32)
-        m = node_up_dev[t].astype(jnp.float32)
-        if part_up_dev is not None:
-            m = m * part_up_dev[t].astype(jnp.float32)
-        return m
+    # Placed here, once (a traced timeline leaf of the replica-batched
+    # path passes through as it is).
+    tables = {k: jnp.asarray(v) for k, v in tables.items()}
 
-    def live(t) -> jax.Array:
-        out = mask_dev
-        if edge_up_dev is not None:
-            out = out * edge_up_dev[t].astype(jnp.float32)[slot_dev]
-        m = active(t)
-        return out * m[:, None] * m[nbr_dev]
+    def bind(tb) -> FaultyMixing:
+        nbr_dev, mask_dev = tb["nbr"].T, tb["mask"].T
+        slot_dev = tb["slot"].T if "slot" in tb else None
 
-    def _wshape(x: jax.Array):
-        return (n, nbr_dev.shape[1]) + (1,) * (x.ndim - 1)
+        def edge_up(t):
+            """[E] float32 link liveness at t, or None (no edge process)."""
+            if "edge_up" in tb:
+                return tb["edge_up"][t].astype(jnp.float32)
+            if drop_p is None:
+                return None
+            u = jax.random.uniform(
+                jax.random.fold_in(fault_key, t), (n_edges,),
+                dtype=jnp.float32,
+            )
+            return (u >= drop_p).astype(jnp.float32)
 
-    def mix(t, x):
-        acc = jnp.promote_types(jnp.float32, x.dtype)
-        lv = live(t).astype(acc)
-        deg = jnp.sum(lv, axis=1)
-        w = lv / (1.0 + jnp.maximum(deg[:, None], deg[nbr_dev]))
-        w_self = 1.0 - jnp.sum(w, axis=1)
-        xa = x.astype(acc)
-        out = w_self.reshape((-1,) + (1,) * (x.ndim - 1)) * xa + jnp.sum(
-            w.reshape(_wshape(x)) * xa[nbr_dev], axis=1
-        )
-        return out.astype(x.dtype)
+        def active(t) -> jax.Array:
+            if strag_q is not None:
+                u = jax.random.uniform(
+                    jax.random.fold_in(node_key, t), (n,), dtype=jnp.float32
+                )
+                return (u >= strag_q).astype(jnp.float32)
+            if "node_up" not in tb and "part_up" not in tb:
+                return jnp.ones(n, dtype=jnp.float32)
+            if "node_up" not in tb:
+                return tb["part_up"][t].astype(jnp.float32)
+            m = tb["node_up"][t].astype(jnp.float32)
+            if "part_up" in tb:
+                m = m * tb["part_up"][t].astype(jnp.float32)
+            return m
 
-    def neighbor_sum(t, x):
-        acc = jnp.promote_types(jnp.float32, x.dtype)
-        lv = live(t).astype(acc)
-        return jnp.sum(
-            lv.reshape(_wshape(x)) * x.astype(acc)[nbr_dev], axis=1
-        ).astype(x.dtype)
+        def live_over(t, nbr, mask, slots) -> jax.Array:
+            out = mask
+            up = edge_up(t)
+            if up is not None:
+                out = out * up[slots]
+            m = active(t)
+            return out * m[:, None] * m[nbr]
 
-    def realized_degree_sum(t):
-        return jnp.sum(live(t))
+        def live(t) -> jax.Array:
+            return live_over(t, nbr_dev, mask_dev, slot_dev)
 
-    rejoin_restart = None
-    if churn_active and rejoin == "neighbor_restart":
-        rejoin_dev = jnp.asarray(timeline.rejoin)
+        def _wshape(x: jax.Array):
+            return (n, k_max) + (1,) * (x.ndim - 1)
 
-        def rejoin_restart(t, x) -> jax.Array:
-            # Gather twin of the dense warm restart: a rejoining node's
-            # model row becomes its realized-neighborhood average;
-            # isolated rejoiners keep their stale state.
+        def mix(t, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)
             lv = live(t).astype(acc)
             deg = jnp.sum(lv, axis=1)
-            rows = tuple(range(1, x.ndim))  # a unit axis per parameter axis
-            nbr_avg = jnp.sum(
-                jnp.expand_dims(lv, tuple(range(2, x.ndim + 1)))
-                * x.astype(acc)[nbr_dev], axis=1
-            ) / jnp.expand_dims(jnp.maximum(deg, 1.0), rows)
-            take = rejoin_dev[t] & (deg > 0)
-            return jnp.where(
-                jnp.expand_dims(take, rows), nbr_avg, x.astype(acc)
+            w = lv / (1.0 + jnp.maximum(deg[:, None], deg[nbr_dev]))
+            w_self = 1.0 - jnp.sum(w, axis=1)
+            xa = x.astype(acc)
+            out = w_self.reshape((-1,) + (1,) * (x.ndim - 1)) * xa + jnp.sum(
+                w.reshape(_wshape(x)) * xa[nbr_dev], axis=1
+            )
+            return out.astype(x.dtype)
+
+        def neighbor_sum(t, x):
+            acc = jnp.promote_types(jnp.float32, x.dtype)
+            lv = live(t).astype(acc)
+            return jnp.sum(
+                lv.reshape(_wshape(x)) * x.astype(acc)[nbr_dev], axis=1
             ).astype(x.dtype)
 
-    def make_neighbor_liveness(nbr_idx: np.ndarray, nbr_mask: np.ndarray):
-        # Same contract as the dense path's: live(t) over the CALLER's
-        # tables (which, for a matrix-free topology, are the topology's
-        # own — there is exactly one table), composing the edge chains
-        # through the caller-table slot map plus the node availability.
-        caller_nbr = jnp.asarray(nbr_idx, dtype=jnp.int32)
-        caller_mask = jnp.asarray(nbr_mask, dtype=jnp.float32)
-        caller_slots = None
-        if timeline is not None and timeline.edge_up is not None:
+        def realized_degree_sum(t):
+            return jnp.sum(live(t))
+
+        rejoin_restart = None
+        if "rejoin" in tb:
+
+            def rejoin_restart(t, x) -> jax.Array:
+                # Gather twin of the dense warm restart: a rejoining
+                # node's model row becomes its realized-neighborhood
+                # average; isolated rejoiners keep their stale state.
+                acc = jnp.promote_types(jnp.float32, x.dtype)
+                lv = live(t).astype(acc)
+                deg = jnp.sum(lv, axis=1)
+                rows = tuple(range(1, x.ndim))  # a unit axis per param axis
+                nbr_avg = jnp.sum(
+                    jnp.expand_dims(lv, tuple(range(2, x.ndim + 1)))
+                    * x.astype(acc)[nbr_dev], axis=1
+                ) / jnp.expand_dims(jnp.maximum(deg, 1.0), rows)
+                take = tb["rejoin"][t] & (deg > 0)
+                return jnp.where(
+                    jnp.expand_dims(take, rows), nbr_avg, x.astype(acc)
+                ).astype(x.dtype)
+
+        def make_neighbor_liveness(nbr_idx: np.ndarray, nbr_mask: np.ndarray):
+            # Same contract as the dense path's: live(t) over the CALLER's
+            # tables, composing the edge bits through the caller-table
+            # slot map plus the node availability. For a matrix-free
+            # topology the caller's tables are the topology's own
+            # (neighbor_tables_for returns them verbatim): the bound ones.
             if nbr_idx is topo.nbr_idx and nbr_mask is topo.nbr_mask:
-                # The usual case: the caller's tables ARE the topology's
-                # own (neighbor_tables_for on a matrix-free topology
-                # returns them verbatim) — reuse the slot map computed
-                # above instead of redoing the O(N·k_max) Python walk.
-                caller_slots = slot_dev
-            else:
+                return live
+            caller_nbr = jnp.asarray(nbr_idx, dtype=jnp.int32)
+            caller_mask = jnp.asarray(nbr_mask, dtype=jnp.float32)
+            caller_slots = None
+            if edge_index is not None:
                 from distributed_optimization_tpu.parallel.topology import (
                     incident_edge_slots,
                 )
 
-                caller_slots = jnp.asarray(
-                    incident_edge_slots(
-                        np.asarray(nbr_idx), np.asarray(nbr_mask),
-                        timeline.edge_index,
-                    ),
-                    dtype=jnp.int32,
-                )
+                caller_slots = jnp.asarray(incident_edge_slots(
+                    np.asarray(nbr_idx), np.asarray(nbr_mask), edge_index
+                ))
+            return lambda t: live_over(t, caller_nbr, caller_mask, caller_slots)
 
-        def live_fn(t) -> jax.Array:
-            out = caller_mask
-            if caller_slots is not None:
-                out = out * edge_up_dev[t].astype(jnp.float32)[caller_slots]
-            m = active(t)
-            return out * m[:, None] * m[caller_nbr]
+        return FaultyMixing(
+            mix=mix,
+            neighbor_sum=neighbor_sum,
+            realized_degree_sum=realized_degree_sum,
+            active=active,
+            drop_prob=(
+                drop_prob if isinstance(drop_prob, (int, float)) else 0.0
+            ),
+            straggler_prob=straggler_prob,
+            realized_adjacency=None,
+            make_neighbor_liveness=make_neighbor_liveness,
+            churn_active=churn_active,
+            rejoin=rejoin,
+            rejoin_restart=rejoin_restart,
+            participation_active=participation_active,
+            timeline=timeline,
+            tables=tables,
+            bind=bind,
+        )
 
-        return live_fn
-
-    return FaultyMixing(
-        mix=mix,
-        neighbor_sum=neighbor_sum,
-        realized_degree_sum=realized_degree_sum,
-        active=active,
-        drop_prob=drop_prob if isinstance(drop_prob, (int, float)) else 0.0,
-        straggler_prob=straggler_prob,
-        realized_adjacency=None,
-        make_neighbor_liveness=make_neighbor_liveness,
-        churn_active=churn_active,
-        rejoin=rejoin,
-        rejoin_restart=rejoin_restart,
-        participation_active=participation_active,
-        timeline=timeline,
-    )
+    return bind(tables)
 
 
 def make_halo_faulty_mixing(
