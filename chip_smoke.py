@@ -37,11 +37,15 @@ without a TPU it exits before doing any work. Segments:
 6. The same stack under the README's faults (30% of the links down, 10% of
    the workers out, every round; ISSUE 32), right after segment 5 so that the
    peak counter prices what the fault layer adds: the bits drawn inside the
-   step (``fault_form`` ``drawn``), the run split at an eval boundary bitwise
-   the unsplit run, ``live_edge_share`` within 0.567 ± 0.005, and the
-   device's peak no more than the fault-free run's plus the ``fault_bytes``
-   the root states. What the CPU cannot see: what an argument takes in the
-   device's tiles, and whether the executable holds a table of its own.
+   step (``fault_form`` ``drawn``), the ring's neighbours read by shifts
+   (``fault_mixing`` ``shift``, ISSUE 33: no table, ``fault_bytes`` 0), the
+   run split at an eval boundary bitwise the unsplit run, ``live_edge_share``
+   within 0.567 ± 0.005, and the device's peak no more than the fault-free
+   run's plus the ``fault_bytes`` the root states. Then one round of the
+   shift and of the gather form on the same (seed, t): the same ``live`` bits
+   and the mixed rows within 1e-6. What the CPU cannot see: what an argument
+   takes in the device's tiles, whether the executable holds a table of its
+   own, and whether the chip's two programs of one round agree.
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -326,11 +330,15 @@ def faults_segment(device: dict, cfg, ds, peak_fault_free: int) -> None:
     after it: the peak counter only rises, so what it reads above
     ``peak_fault_free`` is the fault layer's."""
     cfg = cfg.replace(edge_drop_prob=0.3, straggler_prob=0.1)
-    say = ("faults", "fault_form", "fault_bytes", "live_edge_share", "forward")
+    say = ("faults", "fault_form", "fault_mixing", "fault_bytes",
+           "live_edge_share", "forward")
     got, root, peak = _rooted_run("faults p=0.3 q=0.1", device, cfg, ds, say)
     _check(root["fault_form"] == "drawn" and root["forward"] == "carried",
            "memoryless faults on the neighbor table are drawn in the step, "
            "and the forward product stays carried")
+    _check(root["fault_mixing"] == "shift" and root["fault_bytes"] == 0.0,
+           "a ring's neighbours are read by shifts, with no table handed "
+           "to the scan")
     _check(abs(root["live_edge_share"] - LIVE_EDGE_SHARE) <= 0.005,
            f"live_edge_share {root['live_edge_share']:.5f} within 0.005 of "
            f"{LIVE_EDGE_SHARE:.4f}")
@@ -344,6 +352,42 @@ def faults_segment(device: dict, cfg, ds, peak_fault_free: int) -> None:
         progress_cb=lambda ev: None, progress_every=80)
     _check(_same_run(split, got),
            "the faulty run split at eval boundaries is bitwise the unsplit run")
+    _one_round_both_ways(cfg)
+
+
+def _one_round_both_ways(cfg, rounds=(0, 7)) -> None:
+    """The shift and the gather form of one round of ``cfg``'s faults on the
+    chip, from the same keys: two programs of the same arithmetic."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_optimization_tpu.parallel import build_topology, faults
+
+    topo = build_topology("ring", cfg.n_workers, impl="neighbor")
+    key = jax.random.key(cfg.seed)
+    gather, shift = (
+        build(topo, None, drop_prob=cfg.edge_drop_prob,
+              straggler_prob=cfg.straggler_prob, churn_active=False,
+              participation_active=False, rejoin="frozen",
+              fault_key=jax.random.fold_in(key, 0x0FA17),
+              node_key=jax.random.fold_in(key, 0x57A66))
+        for build in (faults._make_gather_faulty_mixing,
+                      faults._make_shift_faulty_mixing))
+    x = jax.random.normal(
+        jax.random.key(1), (cfg.n_workers, cfg.n_features + 1), jnp.float32)
+    for t in rounds:
+        live, mixed = zip(*(
+            (np.asarray(jax.jit(
+                f.make_neighbor_liveness(topo.nbr_idx, topo.nbr_mask))(t)),
+             np.asarray(jax.jit(f.mix)(t, x)))
+            for f in (gather, shift)))
+        gap = float(np.max(np.abs(mixed[0] - mixed[1])))
+        print(f"[chip_smoke] faults: round {t} shift against gather, live "
+              f"slots {int(live[0].sum())} of {live[0].size}, worst gap of "
+              f"the mixed rows {gap:.3e}", flush=True)
+        _check(np.array_equal(live[0], live[1]) and 0 < live[0].sum() < live[0].size,
+               "the shift and the gather form realize the same live bits")
+        _check(gap <= 1e-6, "the two forms' mixed rows agree within 1e-6")
 
 
 def four_chip_segment(device: dict, *, n_workers: int = 100_000,

@@ -655,7 +655,8 @@ def _fault_root_args(config, faulty, tables) -> dict:
     from a precomputed ``[horizon, ·]`` table) and, where the fault layer's
     arrays are arguments of the program (``FaultyMixing.tables``),
     ``fault_bytes``: what they take on the device, timeline leaves
-    included."""
+    included (0 where the form needs none), and ``fault_mixing``
+    (``shift`` / ``gather``: how that layer addresses a neighbour)."""
     parts = [
         f"{name}:{value:g}" for name, value, on in (
             ("edge_drop", config.edge_drop_prob, config.edge_drop_prob > 0.0),
@@ -677,6 +678,8 @@ def _fault_root_args(config, faulty, tables) -> dict:
         args["fault_bytes"] = float(sum(
             leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
         ))
+    if faulty.addressing is not None:
+        args["fault_mixing"] = faulty.addressing
     return args
 
 

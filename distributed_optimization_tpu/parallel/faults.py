@@ -119,7 +119,13 @@ remainder on the diagonal. Memoryless faults are drawn inside the step by
 exactly this rule; persistent processes unroll the same draws into a
 timeline (``build_fault_timeline``), so at burst_len = 1 or plain
 ``straggler_prob`` the two are one realization, bit for bit
-(tests/test_fault_draws.py).
+(tests/test_fault_draws.py). Where the neighbor table is a ring's
+(``_table_is_a_ring``) the fault layer reads that draw, and a timeline's
+row, by three static slices of ``_edge_list``'s order — the bit of edge
+{i, i+1 mod N} is ``concat(up[0:1], up[2:N], up[1:2])[i]`` — and its
+neighbours by rolls (``_make_shift_faulty_mixing``); on every other graph
+through the (node, slot) → edge-id table and the neighbor table
+(``_make_gather_faulty_mixing``). One realization either way.
 
 Masks are derived purely from (fault key, iteration) — like batch sampling,
 fault realizations are reproducible and checkpoint/resume-safe with no
@@ -206,18 +212,27 @@ class FaultyMixing:
     # memoryless on-the-fly path) — exposed for diagnostics
     # (``node_downtime``, ``windowed_connectivity``) and tests.
     timeline: Optional["FaultTimeline"] = None
-    # Matrix-free (gather) form only, else None: ``tables`` is the pytree
+    # Unsharded matrix-free forms only, else None: ``tables`` is the pytree
     # of device arrays the operators above read, placed once when the
-    # mixing is built — the neighbor table, its mask, the (node, slot) →
-    # edge-id map and, for a persistent process, the ``[horizon, ·]``
-    # timeline leaves — and ``bind(tb)`` the same operators over another
-    # copy of it, such as the tracers a jitted program receives
-    # ``tables`` as. ``jax_backend._run`` hands the tables to the scan as
-    # ARGUMENTS; the unbound operators make them constants of whatever
-    # program traces them, which at 2^18 workers is hundreds of megabytes
-    # of executable (ROADMAP A9).
+    # mixing is built — in the gather form the neighbor table, its mask
+    # and the (node, slot) → edge-id map; in either form, for a persistent
+    # process, the ``[horizon, ·]`` timeline leaves; so the shift form
+    # under memoryless faults holds an EMPTY dict — and ``bind(tb)`` the
+    # same operators over another copy of it, such as the tracers a jitted
+    # program receives ``tables`` as. ``jax_backend._run`` hands the tables
+    # to the scan as ARGUMENTS; the unbound operators make them constants
+    # of whatever program traces them, which at 2^18 workers is hundreds
+    # of megabytes of executable (ROADMAP A9).
     tables: Optional[dict] = None
     bind: Optional[Callable[[dict], "FaultyMixing"]] = None
+    # How those operators reach a neighbour's value and an incident edge's
+    # bit: ``'shift'`` where the neighbor table is a ring's (rolls and
+    # three slices of the edge draw, no table) and ``'gather'`` on every
+    # other matrix-free graph (index tables); decided from the table when
+    # the mixing is built, by no option (``_table_is_a_ring``). The
+    # ``dopt.run`` root's ``fault_mixing``. None off the unsharded
+    # neighbor table.
+    addressing: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -927,9 +942,9 @@ def make_faulty_mixing(
         # Matrix-free (neighbor-table-native) route: node-process faults
         # (participation sampling, iid stragglers, crash-recovery churn)
         # AND per-edge drop processes (iid / bursty Gilbert-Elliott
-        # chains, ISSUE-9 satellite) — all realized in gather form over
-        # the static [N, k_max] table, the [horizon, E] edge chains
-        # indexed through the (node, slot) → edge-id map. Matching
+        # chains, ISSUE-9 satellite) — all realized over the static
+        # [N, k_max] table, the [horizon, E] edge chains indexed through
+        # the (node, slot) → edge-id map (or, on a ring, sliced). Matching
         # schedules still need the dense adjacency (partner sampling is
         # an [N, N] argmax) and are rejected upstream and here.
         if one_peer or topo.directed:
@@ -952,7 +967,19 @@ def make_faulty_mixing(
                 churn_active=churn_active,
                 participation_active=participation_active, rejoin=rejoin,
             )
-        return _make_gather_faulty_mixing(
+        # One round, two ways to address a neighbour, chosen by the shape
+        # of the input: a ring's table is shifts (an injected timeline's
+        # edge bits must be numbered as the ring's draw is), anything else
+        # index tables.
+        shifts = _table_is_a_ring(topo) and (
+            timeline is None or timeline.edge_up is None
+            or np.array_equal(timeline.edge_index, _edge_list(topo))
+        )
+        build = (
+            _make_shift_faulty_mixing if shifts
+            else _make_gather_faulty_mixing
+        )
+        return build(
             topo, timeline, drop_prob=drop_prob,
             straggler_prob=straggler_prob, churn_active=churn_active,
             participation_active=participation_active, rejoin=rejoin,
@@ -1173,48 +1200,54 @@ def make_faulty_mixing(
     )
 
 
-def _make_gather_faulty_mixing(
+def _table_is_a_ring(topo: Topology) -> bool:
+    """Whether the neighbor table IS a ring's: n >= 3, every row the two
+    neighbours (i ± 1) mod n in ascending order, every slot live. Read off
+    the table, not the topology's name: whatever graph has this table is
+    mixed by shifts (``_make_shift_faulty_mixing``), every other one by
+    gathers. Host arrays, a few ms at 2^18 workers."""
+    from distributed_optimization_tpu.parallel.topology import (
+        _ring_neighbor_tables,
+    )
+
+    n = topo.n
+    if n < 3 or topo.nbr_idx.shape != (n, 2):
+        return False
+    nbr, mask = _ring_neighbor_tables(n)
+    return bool(
+        np.array_equal(topo.nbr_mask, mask)
+        and np.array_equal(topo.nbr_idx, nbr)
+    )
+
+
+def _matrix_free_bits(
     topo: Topology,
     timeline: Optional[FaultTimeline],
     *,
-    drop_prob: float,
+    drop_prob,
     straggler_prob: float,
     churn_active: bool,
-    participation_active: bool,
     rejoin: str,
     fault_key,
     node_key,
-) -> FaultyMixing:
-    """Faults over a matrix-free (neighbor-table) topology, in gather form.
+):
+    """Where round t's bits come from on a neighbor table, whichever way
+    the neighbours are then addressed: ``(leaves, edge_index, bits)``.
 
-    The realized graph at round t is the static table masked by the edge
-    liveness bits and the composed node-availability row m_t
-    (churn/straggler-up AND sampled-in):
-    ``live_t[i, s] = mask[i, s] · up_t[slot[i, s]] · m_t[i] · m_t[nbr[i, s]]``.
-    Realized MH weights come straight from the live slots —
-    ``w = live / (1 + max(deg_i, deg_{nbr}))`` with the row remainder on
-    the diagonal, the identical per-entry formula the dense
-    ``metropolis_hastings_weights`` computes on the realized adjacency
-    (a fully-masked row degenerates to identity the same way) — so the
-    whole time-varying gossip round stays O(N·k_max·d) with no [N, N]
-    object anywhere. Same float32 mask/weight convention as the dense
-    path; only the mixed model values are cast back to the input dtype.
-
-    Where the bits come from is decided by ``timeline``: given one
-    (persistent processes, the replica-batched stacker, an injected
-    realization) round t reads its rows; given none (memoryless faults)
-    round t DRAWS them from ``fold_in(fault_key, t)`` / ``fold_in(node_key,
-    t)`` — ``build_fault_timeline``'s own keys, shapes and float32
-    comparison, so the two forms realize one graph bit for bit and the
-    drawn form holds nothing ``[horizon, ·]``.
-
-    Every array the operators read lives in ONE pytree, ``tables``, placed
-    on the device here, and the operators are built over a copy of it by
-    ``bind`` (see the ``FaultyMixing`` fields): the caller decides whether
-    the tables are arguments of its program or constants in it.
+    Decided by ``timeline``: given one (persistent processes, the
+    replica-batched stacker, an injected realization) round t reads its
+    rows, and ``leaves`` holds them (``edge_up``, ``node_up``, ``part_up``,
+    ``rejoin``: host arrays or traced slices, to be handed to the program
+    with the form's own tables); given none (memoryless faults) round t
+    DRAWS them from ``fold_in(fault_key, t)`` / ``fold_in(node_key, t)`` —
+    ``build_fault_timeline``'s own keys, shapes and float32 comparison, so
+    the two realize one graph bit for bit and ``leaves`` is empty.
+    ``edge_index`` is the ``[E, 2]`` list the edge bits are numbered by
+    (None without an edge process); ``bits(tb)`` gives ``edge_up(t)``
+    (``[E]`` float32, or None) and ``active(t)`` (``[N]`` float32) over a
+    copy ``tb`` of the leaves.
     """
     n = topo.n
-    k_max = topo.nbr_idx.shape[1]
     drawn = timeline is None
     # The drawn form's thresholds, None where that process is off (a traced
     # drop probability always draws: see ``make_faulty_mixing``).
@@ -1227,48 +1260,22 @@ def _make_gather_faulty_mixing(
         np.float32(straggler_prob)
         if drawn and straggler_prob > 0.0 else None
     )
-    # The [N, k_max] tables are kept SLOT-MAJOR, [k_max, N]: as an argument
-    # in the TPU's (8, 128) tiles an s32[262144, 2] is 134 MB where its
-    # transpose is 2; the operators read them through ``.T``, which costs a
-    # program nothing (a layout).
-    tables = {
-        "nbr": np.asarray(topo.nbr_idx, dtype=np.int32).T,
-        "mask": np.asarray(topo.nbr_mask, dtype=np.float32).T,
-    }
-    # Per-edge liveness in gather form (ISSUE-9 satellite): the bits land
-    # on both endpoints' rows through the static (node, slot) → edge-id
-    # table — the same symmetric composition the dense path realizes by
-    # scattering A[ei, ej] = A[ej, ei] = up[e], with no [N, N] object.
+    leaves = {}
     edge_index = None
     if not drawn and timeline.edge_up is not None:
         edge_index = timeline.edge_index
-        tables["edge_up"] = timeline.edge_up
+        leaves["edge_up"] = timeline.edge_up
     elif drop_p is not None:
         edge_index = _edge_list(topo)
-    if edge_index is not None:
-        from distributed_optimization_tpu.parallel.topology import (
-            incident_edge_slots,
-        )
-
-        tables["slot"] = incident_edge_slots(
-            topo.nbr_idx, topo.nbr_mask, edge_index
-        ).T
-        n_edges = edge_index.shape[0]
     if not drawn:
         for name in ("node_up", "part_up"):
             if getattr(timeline, name) is not None:
-                tables[name] = getattr(timeline, name)
+                leaves[name] = getattr(timeline, name)
         if churn_active and rejoin == "neighbor_restart":
-            tables["rejoin"] = timeline.rejoin
+            leaves["rejoin"] = timeline.rejoin
+    n_edges = None if edge_index is None else edge_index.shape[0]
 
-    # Placed here, once (a traced timeline leaf of the replica-batched
-    # path passes through as it is).
-    tables = {k: jnp.asarray(v) for k, v in tables.items()}
-
-    def bind(tb) -> FaultyMixing:
-        nbr_dev, mask_dev = tb["nbr"].T, tb["mask"].T
-        slot_dev = tb["slot"].T if "slot" in tb else None
-
+    def bits(tb):
         def edge_up(t):
             """[E] float32 link liveness at t, or None (no edge process)."""
             if "edge_up" in tb:
@@ -1295,6 +1302,82 @@ def _make_gather_faulty_mixing(
             if "part_up" in tb:
                 m = m * tb["part_up"][t].astype(jnp.float32)
             return m
+
+        return edge_up, active
+
+    return leaves, edge_index, bits
+
+
+def _make_gather_faulty_mixing(
+    topo: Topology,
+    timeline: Optional[FaultTimeline],
+    *,
+    drop_prob: float,
+    straggler_prob: float,
+    churn_active: bool,
+    participation_active: bool,
+    rejoin: str,
+    fault_key,
+    node_key,
+) -> FaultyMixing:
+    """Faults over a matrix-free (neighbor-table) topology, in gather form:
+    any graph whose table is not a ring's (``_table_is_a_ring``).
+
+    The realized graph at round t is the static table masked by the edge
+    liveness bits and the composed node-availability row m_t
+    (churn/straggler-up AND sampled-in):
+    ``live_t[i, s] = mask[i, s] · up_t[slot[i, s]] · m_t[i] · m_t[nbr[i, s]]``.
+    Realized MH weights come straight from the live slots —
+    ``w = live / (1 + max(deg_i, deg_{nbr}))`` with the row remainder on
+    the diagonal, the identical per-entry formula the dense
+    ``metropolis_hastings_weights`` computes on the realized adjacency
+    (a fully-masked row degenerates to identity the same way) — so the
+    whole time-varying gossip round stays O(N·k_max·d) with no [N, N]
+    object anywhere. Same float32 mask/weight convention as the dense
+    path; only the mixed model values are cast back to the input dtype.
+
+    Where the bits come from: ``_matrix_free_bits``.
+
+    Every array the operators read lives in ONE pytree, ``tables``, placed
+    on the device here, and the operators are built over a copy of it by
+    ``bind`` (see the ``FaultyMixing`` fields): the caller decides whether
+    the tables are arguments of its program or constants in it.
+    """
+    n = topo.n
+    k_max = topo.nbr_idx.shape[1]
+    leaves, edge_index, bits = _matrix_free_bits(
+        topo, timeline, drop_prob=drop_prob, straggler_prob=straggler_prob,
+        churn_active=churn_active, rejoin=rejoin,
+        fault_key=fault_key, node_key=node_key,
+    )
+    # The [N, k_max] tables are kept SLOT-MAJOR, [k_max, N]: as an argument
+    # in the TPU's (8, 128) tiles an s32[262144, 2] is 134 MB where its
+    # transpose is 2; the operators read them through ``.T``, which costs a
+    # program nothing (a layout).
+    tables = {
+        "nbr": np.asarray(topo.nbr_idx, dtype=np.int32).T,
+        "mask": np.asarray(topo.nbr_mask, dtype=np.float32).T,
+    }
+    # Per-edge liveness in gather form (ISSUE-9 satellite): the bits land
+    # on both endpoints' rows through the static (node, slot) → edge-id
+    # table — the same symmetric composition the dense path realizes by
+    # scattering A[ei, ej] = A[ej, ei] = up[e], with no [N, N] object.
+    if edge_index is not None:
+        from distributed_optimization_tpu.parallel.topology import (
+            incident_edge_slots,
+        )
+
+        tables["slot"] = incident_edge_slots(
+            topo.nbr_idx, topo.nbr_mask, edge_index
+        ).T
+    # Placed here, once (a traced timeline leaf of the replica-batched
+    # path passes through as it is).
+    tables = {k: jnp.asarray(v) for k, v in {**tables, **leaves}.items()}
+
+    def bind(tb) -> FaultyMixing:
+        nbr_dev, mask_dev = tb["nbr"].T, tb["mask"].T
+        slot_dev = tb["slot"].T if "slot" in tb else None
+        edge_up, active = bits(tb)
 
         def live_over(t, nbr, mask, slots) -> jax.Array:
             out = mask
@@ -1391,6 +1474,163 @@ def _make_gather_faulty_mixing(
             timeline=timeline,
             tables=tables,
             bind=bind,
+            addressing="gather",
+        )
+
+    return bind(tables)
+
+
+def _make_shift_faulty_mixing(
+    topo: Topology,
+    timeline: Optional[FaultTimeline],
+    *,
+    drop_prob: float,
+    straggler_prob: float,
+    churn_active: bool,
+    participation_active: bool,
+    rejoin: str,
+    fault_key,
+    node_key,
+) -> FaultyMixing:
+    """``_make_gather_faulty_mixing``'s round where the neighbor table is a
+    ring's (``_table_is_a_ring``): node i's neighbours are rows i − 1 and
+    i + 1 mod N, so a neighbour's value is a ``jnp.roll`` and no table is
+    read — an index gather costs the chip about 9 ns an index whatever it
+    fetches (PERF.md §6, PR 32), a shift its bytes.
+
+    Edge-major, the two directions kept as two ``[N]`` arrays (never one
+    ``[N, 2]``): ``r[i]`` is the live bit of edge {i, i+1 mod N} =
+    ``up_t[{i, i+1}] · m_t[i] · m_t[i+1]``, ``l = roll(r, 1)`` that of
+    {i−1, i}, ``deg = l + r``, ``w_r = r / (1 + max(deg, roll(deg, −1)))``,
+    ``w_l = roll(w_r, 1)`` (an edge's weight is one number, read from either
+    end), ``w_self = 1 − (w_l + w_r)`` and
+    ``out = w_self·x + (w_l·roll(x, 1) + w_r·roll(x, −1))``: the gather
+    form's arithmetic term for term, its two-term sums in either order.
+
+    The draw is the gather form's, untouched (``_matrix_free_bits``): one
+    float32 uniform an edge in ``_edge_list``'s order — on a ring (0, 1),
+    (0, N−1), (1, 2), …, (N−2, N−1) — so the bit of edge {i, i+1 mod N} is
+    ``concat(up[0:1], up[2:N], up[1:2])[i]``: three static slices, no
+    (node, slot) → edge map. A timeline's ``edge_up[t]`` row is numbered by
+    the same list (``make_faulty_mixing`` checks an injected one's
+    ``edge_index`` before it takes this form). ``tables`` holds the
+    timeline's leaves and nothing else: nothing at all for memoryless
+    faults.
+    """
+    n = topo.n
+    leaves, _, bits = _matrix_free_bits(
+        topo, timeline, drop_prob=drop_prob, straggler_prob=straggler_prob,
+        churn_active=churn_active, rejoin=rejoin,
+        fault_key=fault_key, node_key=node_key,
+    )
+    tables = {k: jnp.asarray(v) for k, v in leaves.items()}
+
+    def bind(tb) -> FaultyMixing:
+        edge_up, active = bits(tb)
+
+        def right(t) -> jax.Array:
+            """[N] float32: the live bit of edge {i, i+1 mod N}."""
+            m = active(t)
+            r = m * jnp.roll(m, -1)
+            up = edge_up(t)
+            if up is not None:
+                r = jnp.concatenate([up[0:1], up[2:], up[1:2]]) * r
+            return r
+
+        def _col(v, x):
+            return v.reshape((-1,) + (1,) * (x.ndim - 1))
+
+        def neighbor_terms(x, c_l, c_r):
+            """Σ over the two neighbours of coefficient × row."""
+            return _col(c_l, x) * jnp.roll(x, 1, axis=0) + _col(
+                c_r, x
+            ) * jnp.roll(x, -1, axis=0)
+
+        def mix(t, x):
+            acc = jnp.promote_types(jnp.float32, x.dtype)
+            r = right(t).astype(acc)
+            deg = jnp.roll(r, 1) + r
+            w_r = r / (1.0 + jnp.maximum(deg, jnp.roll(deg, -1)))
+            w_l = jnp.roll(w_r, 1)
+            w_self = 1.0 - (w_l + w_r)
+            xa = x.astype(acc)
+            out = _col(w_self, x) * xa + neighbor_terms(xa, w_l, w_r)
+            return out.astype(x.dtype)
+
+        def neighbor_sum(t, x):
+            acc = jnp.promote_types(jnp.float32, x.dtype)
+            r = right(t).astype(acc)
+            return neighbor_terms(
+                x.astype(acc), jnp.roll(r, 1), r
+            ).astype(x.dtype)
+
+        def realized_degree_sum(t):
+            # Σ_i deg_i: every live edge once from each end.
+            return 2.0 * jnp.sum(right(t))
+
+        rejoin_restart = None
+        if "rejoin" in tb:
+
+            def rejoin_restart(t, x) -> jax.Array:
+                # The gather form's warm restart: a rejoining node's row
+                # becomes its realized-neighborhood average; isolated
+                # rejoiners keep their stale state.
+                acc = jnp.promote_types(jnp.float32, x.dtype)
+                r = right(t).astype(acc)
+                l = jnp.roll(r, 1)
+                deg = l + r
+                xa = x.astype(acc)
+                nbr_avg = neighbor_terms(xa, l, r) / _col(
+                    jnp.maximum(deg, 1.0), x
+                )
+                take = tb["rejoin"][t] & (deg > 0)
+                return jnp.where(_col(take, x), nbr_avg, xa).astype(x.dtype)
+
+        def make_neighbor_liveness(nbr_idx: np.ndarray, nbr_mask: np.ndarray):
+            # The contract is the gather form's: [N, k_max] in the CALLER's
+            # table order, which on a ring is ascending, not left/right
+            # (row 0 is [1, N−1], row N−1 [0, N−2]). Each slot is told on
+            # the host which of the two edges it names.
+            nbr_idx = np.asarray(nbr_idx)
+            mask = np.asarray(nbr_mask, dtype=bool)
+            ids = np.arange(n)
+            is_right = nbr_idx == ((ids + 1) % n)[:, None]
+            is_left = nbr_idx == ((ids - 1) % n)[:, None]
+            if (mask & ~(is_right | is_left)).any():
+                raise ValueError(
+                    "neighbor liveness was asked over a table that is not "
+                    "this ring's"
+                )
+            pick_right = jnp.asarray(is_right)
+            caller_mask = jnp.asarray(mask, dtype=jnp.float32)
+
+            def live(t) -> jax.Array:
+                r = right(t)
+                return caller_mask * jnp.where(
+                    pick_right, r[:, None], jnp.roll(r, 1)[:, None]
+                )
+
+            return live
+
+        return FaultyMixing(
+            mix=mix,
+            neighbor_sum=neighbor_sum,
+            realized_degree_sum=realized_degree_sum,
+            active=active,
+            drop_prob=(
+                drop_prob if isinstance(drop_prob, (int, float)) else 0.0
+            ),
+            straggler_prob=straggler_prob,
+            realized_adjacency=None,
+            make_neighbor_liveness=make_neighbor_liveness,
+            churn_active=churn_active,
+            rejoin=rejoin,
+            rejoin_restart=rejoin_restart,
+            participation_active=participation_active,
+            timeline=timeline,
+            tables=tables,
+            bind=bind,
+            addressing="shift",
         )
 
     return bind(tables)

@@ -60,12 +60,25 @@ def judged(produced, ref, config):
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-def test_the_program_is_within_the_cells_limits(cell, seed):
+@pytest.mark.parametrize("mixing", ["shift", "gather"])
+def test_the_program_is_within_the_cells_limits(cell, seed, mixing, monkeypatch):
+    """The cell's ring is mixed by shifts (ISSUE 33: read off its neighbor
+    table, no option), with no table to hand the scan; the index-table form
+    that every other graph keeps is held to the same limits on the same
+    ring, by telling the rule that the table is no ring's."""
+    from distributed_optimization_tpu.parallel import faults
+
+    if mixing == "gather":
+        monkeypatch.setattr(faults, "_table_is_a_ring", lambda topo: False)
+        # the executable cache keys a program by its configuration, which
+        # decides the form everywhere but under this patch
+        monkeypatch.setenv("DOPT_EXEC_CACHE", "0")
     config, traffic = cell
     result, args, (X, y, pseed) = run_program(config, traffic, seed)
     assert args["faults"] == "edge_drop:0.3,straggler:0.1"
-    assert args["fault_form"] == "drawn"
-    assert args["fault_bytes"] == 3 * 64 * 2 * 4
+    assert args["fault_form"] == "drawn" and args["fault_mixing"] == mixing
+    # gather: nbr s32, mask f32, slot s32, each [64, 2]
+    assert args["fault_bytes"] == (0 if mixing == "shift" else 3 * 64 * 2 * 4)
     assert 0.45 < args["live_edge_share"] < 0.68  # 0.567 over 40 rounds of 64 links
     ref = dsgd_ring_faulty.run(config, traffic, X, y, pseed)
     ok, said = judged(harness.produced_of(result), ref, config)

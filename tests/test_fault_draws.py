@@ -10,6 +10,8 @@ process's timeline reaches its program as arguments, not constants; the
 CPU, N = 64: values, structure and counts, never a time.
 """
 
+import dataclasses
+import hashlib
 import re
 
 import jax
@@ -29,7 +31,11 @@ GRAPHS = {
     "ring": dict(topology="ring"),
     "torus": dict(topology="grid"),
     "sparse_er": dict(topology="erdos_renyi", erdos_renyi_p=0.1),
+    "chain": dict(topology="chain"),
 }
+# How the fault layer addresses a neighbour (ISSUE 33), read off the
+# neighbor table: a ring's is shifts, every other graph's index tables.
+FORM = {"ring": "shift", "torus": "gather", "sparse_er": "gather", "chain": "gather"}
 
 
 def topo_of(graph):
@@ -83,9 +89,15 @@ def test_the_drawn_bits_are_the_timelines(graph):
     timed = faults.make_faulty_mixing(
         topo, P_DROP, 11, straggler_prob=Q_STRAG, timeline=tl)
     assert drawn.timeline is None and timed.timeline is tl
-    assert sorted(drawn.tables) == ["mask", "nbr", "slot"]
-    assert sorted(timed.tables) == ["edge_up", "mask", "nbr", "node_up", "slot"]
-    slot = np.asarray(drawn.tables["slot"]).T  # kept slot-major, [k_max, N]
+    # a ring's table is read by shifts and needs no table; the others keep
+    # theirs, slot-major [k_max, N]
+    held = [] if graph == "ring" else ["mask", "nbr", "slot"]
+    assert drawn.addressing == timed.addressing == FORM[graph]
+    assert sorted(drawn.tables) == held
+    assert sorted(timed.tables) == sorted(held + ["edge_up", "node_up"])
+    slot = incident_edge_slots(topo.nbr_idx, topo.nbr_mask, tl.edge_index)
+    if held:
+        np.testing.assert_array_equal(np.asarray(drawn.tables["slot"]).T, slot)
     live = drawn.make_neighbor_liveness(topo.nbr_idx, topo.nbr_mask)
     x = jnp.asarray(np.random.default_rng(0).standard_normal((N, 7)), jnp.float32)
     for t in range(T):
@@ -106,9 +118,10 @@ def test_a_drawn_run_is_bitwise_the_run_handed_the_timeline(graph, monkeypatch):
     got, root, children = run_rooted(cfg, ds)
     assert root["fault_form"] == "drawn" and "dopt.run.faults" in children
     assert root["faults"] == "edge_drop:0.3,straggler:0.1"
+    assert root["fault_mixing"] == FORM[graph]
     handed_the_timeline(monkeypatch)
     want, root, _ = run_rooted(cfg, ds)
-    assert root["fault_form"] == "timeline"
+    assert root["fault_form"] == "timeline" and root["fault_mixing"] == FORM[graph]
     np.testing.assert_array_equal(got.history.objective, want.history.objective)
     np.testing.assert_array_equal(
         got.history.consensus_error, want.history.consensus_error)
@@ -181,22 +194,30 @@ def horizon_shapes(text, horizon):
     return sorted(found - {f"tensor<{horizon}x1xi32>"})
 
 
-def test_a_memoryless_matrix_free_program_holds_nothing_by_the_horizon(monkeypatch):
+@pytest.mark.parametrize("graph", ["ring", "torus"])
+def test_a_memoryless_matrix_free_program_holds_nothing_by_the_horizon(graph, monkeypatch):
     # a horizon that is no other dimension of the program
-    cfg = cfg_of("ring", n_iterations=37)
+    cfg = cfg_of(graph, n_iterations=37)
     ds = generate_synthetic_dataset(cfg)
+    assert run_rooted(cfg, ds)[1]["fault_mixing"] == FORM[graph]
+    held = [] if graph == "ring" else ["mask", "nbr", "slot"]
     text, data = lowered_scan(cfg, ds, monkeypatch)
-    assert sorted(data["faults"]) == ["mask", "nbr", "slot"]
+    assert sorted(data["faults"]) == held
     assert horizon_shapes(text, 37) == []
-    # ... and the tables are arguments: no [N, k] integer constant either
-    assert not re.search(rf"stablehlo\.constant dense<.*tensor<{N}x2xi32>", text)
+    # ... and the tables are arguments (the ring has none to hand over): no
+    # [N, k] integer constant either, and no gather of a ring's neighbours
+    assert not re.search(rf"stablehlo\.constant dense<.*tensor<{N}x\dxi32>", text)
+    k = 2 if graph == "ring" else 4
+    assert (f"tensor<{N}x{k}xi32>" in text) == (graph != "ring")
     # the form it replaced held the bits as constants of the program
     handed_the_timeline(monkeypatch)
     text, data = lowered_scan(cfg, ds, monkeypatch)
-    assert sorted(data["faults"]) == ["edge_up", "mask", "nbr", "node_up", "slot"]
-    assert horizon_shapes(text, 37) == ["tensor<37x64xi1>"]
+    assert sorted(data["faults"]) == sorted(held + ["edge_up", "node_up"])
+    n_edges = N * k // 2
+    assert horizon_shapes(text, 37) == sorted(
+        {"tensor<37x64xi1>", f"tensor<37x{n_edges}xi1>"})
     assert "stablehlo.constant" not in "".join(
-        line for line in text.splitlines() if "tensor<37x64xi1>" in line)
+        line for line in text.splitlines() if "tensor<37x" in line and "xi1>" in line)
 
 
 @pytest.mark.parametrize("process", [
@@ -204,36 +225,44 @@ def test_a_memoryless_matrix_free_program_holds_nothing_by_the_horizon(monkeypat
     dict(edge_drop_prob=0.0, straggler_prob=0.0, mttf=12.0, mttr=4.0),
     dict(edge_drop_prob=0.0, straggler_prob=0.0, participation_rate=0.7),
 ], ids=["bursts", "churn", "participation"])
-def test_a_persistent_process_keeps_its_timeline_as_arguments(process, monkeypatch):
-    cfg = cfg_of("ring", n_iterations=37, **process)
+@pytest.mark.parametrize("graph", ["ring", "torus"])
+def test_a_persistent_process_keeps_its_timeline_as_arguments(graph, process, monkeypatch):
+    cfg = cfg_of(graph, n_iterations=37, **process)
     ds = generate_synthetic_dataset(cfg)
     _, root, _ = run_rooted(cfg, ds)
-    assert root["fault_form"] == "timeline"
+    assert root["fault_form"] == "timeline" and root["fault_mixing"] == FORM[graph]
     text, data = lowered_scan(cfg, ds, monkeypatch)
     leaves = {k: v for k, v in data["faults"].items() if v.shape[0] == 37}
     assert leaves and all(v.dtype == bool for v in leaves.values())
+    # the shift form is handed the timeline's leaves and nothing else
+    assert (set(data["faults"]) == set(leaves)) == (graph == "ring")
     # each [horizon, ·] leaf is a parameter of the program and no constant
-    assert horizon_shapes(text, 37) == ["tensor<37x64xi1>"]
+    shapes = sorted({f"tensor<37x{v.shape[1]}xi1>" for v in leaves.values()})
+    assert horizon_shapes(text, 37) == shapes
     assert root["fault_bytes"] >= sum(v.nbytes for v in data["faults"].values())
     for line in text.splitlines():
-        assert not ("stablehlo.constant" in line and "tensor<37x64xi1>" in line)
+        assert not ("stablehlo.constant" in line and any(s in line for s in shapes))
 
 
-def test_the_root_counts_what_the_fault_layer_holds_and_what_got_through():
-    cfg = cfg_of("ring", n_iterations=400, eval_every=100)
+@pytest.mark.parametrize("graph, degree", [("ring", 2), ("chain", 2), ("torus", 4)])
+def test_the_root_counts_what_the_fault_layer_holds_and_what_got_through(graph, degree):
+    cfg = cfg_of(graph, n_iterations=400, eval_every=100)
     ds = generate_synthetic_dataset(cfg)
     result, root, _ = run_rooted(cfg, ds)
-    # nbr s32, mask f32, slot s32, each [64, 2]
-    assert root["fault_bytes"] == 3 * N * 2 * 4
+    assert root["fault_mixing"] == FORM[graph]
+    # gather: nbr s32, mask f32, slot s32, each [64, k_max]; shift: no table
+    assert root["fault_bytes"] == (0 if graph == "ring" else 3 * N * degree * 4)
     # a link carries a model iff it is up and both its ends are
     want = (1 - P_DROP) * (1 - Q_STRAG) ** 2
     assert root["live_edge_share"] == pytest.approx(want, abs=0.01)
+    links = 2 * (N - 1) if graph == "chain" else degree * N  # Σ deg_i
     assert root["live_edge_share"] == pytest.approx(
-        result.history.total_floats_transmitted / (400 * 2 * N * 21))
+        result.history.total_floats_transmitted / (400 * links * 21))
     # a fault-free call says nothing of faults
     _, root, children = run_rooted(
-        cfg_of("ring", edge_drop_prob=0.0, straggler_prob=0.0), ds)
-    assert not {"faults", "fault_form", "fault_bytes", "live_edge_share"} & set(root)
+        cfg_of(graph, edge_drop_prob=0.0, straggler_prob=0.0), ds)
+    assert not {"faults", "fault_form", "fault_bytes", "fault_mixing",
+                "live_edge_share"} & set(root)
 
 
 def test_forward_is_carried_under_the_cells_faults(monkeypatch):
@@ -245,6 +274,7 @@ def test_forward_is_carried_under_the_cells_faults(monkeypatch):
     ds = generate_synthetic_dataset(cfg)
     got, root, _ = run_rooted(cfg, ds)
     assert root["forward"] == "carried" and root["fault_form"] == "drawn"
+    assert root["fault_mixing"] == "shift"
     monkeypatch.setattr(jax_backend, "_forward_is_carried", lambda *a, **k: False)
     want, root, _ = run_rooted(cfg, ds)
     assert root["forward"] == "recomputed"
@@ -253,3 +283,185 @@ def test_forward_is_carried_under_the_cells_faults(monkeypatch):
     assert_ulps_of_scale(
         got.history.consensus_error, want32(want.history.consensus_error), 16)
     assert_ulps_of_scale(got.final_models, want32(want.final_models), 16)
+
+
+# --- ISSUE 33: on a ring the fault layer reads its neighbours by shifts ------
+#
+# One round, two ways to address a neighbour. No option asks for either, so
+# the two builders are called directly, on the same ring, keys and t.
+
+# jax 0.9's lowering of the two graphs below on the parent commit (fd89778),
+# taken there by ``lowered_scan`` on ``cfg_of(graph, n_iterations=37)``: what
+# "a graph that is not a shift runs the parent's program" is held to. Another
+# jax spells the text its own way and is held to the builder's name alone.
+PARENT_SCANS = {"jax": "0.9", "chain": "63277440065029ae", "sparse_er": "18283ae3dc7839e0"}
+
+
+def ring_of(n):
+    """A ring's neighbor-table topology, N = 3 included (``build_topology``
+    refuses a table as wide as the dense adjacency: 3 workers of degree 2)."""
+    from distributed_optimization_tpu.parallel.topology import (
+        Topology, _ring_neighbor_tables)
+
+    nbr, mask = _ring_neighbor_tables(n)
+    return Topology(name="ring", n=n, adjacency=None, mixing_matrix=None,
+                    degrees=mask.sum(axis=1).astype(np.float64),
+                    nbr_idx=nbr, nbr_mask=mask)
+
+
+def keys_of(seed):
+    key = jax.random.key(seed)
+    return dict(fault_key=jax.random.fold_in(key, 0x0FA17),
+                node_key=jax.random.fold_in(key, 0x57A66))
+
+
+def both_forms(topo, timeline=None, *, seed=11, **kw):
+    kw = dict(dict(drop_prob=0.0, straggler_prob=0.0, churn_active=False,
+                   participation_active=False, rejoin="frozen"), **kw)
+    return tuple(
+        build(topo, timeline, **kw, **keys_of(seed))
+        for build in (faults._make_gather_faulty_mixing,
+                      faults._make_shift_faulty_mixing))
+
+
+FAULTS = {
+    "drop_and_stragglers": dict(drop_prob=P_DROP, straggler_prob=Q_STRAG),
+    "drop": dict(drop_prob=P_DROP),
+    "stragglers": dict(straggler_prob=Q_STRAG),
+    "bursts": dict(drop_prob=P_DROP, timeline=dict(
+        edge_drop_prob=P_DROP, burst_len=3.0)),
+    "churn_restart": dict(churn_active=True, rejoin="neighbor_restart",
+                          timeline=dict(mttf=6.0, mttr=3.0)),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+@pytest.mark.parametrize("n", [3, 4, 64])
+def test_shift_and_gather_realize_one_round(n, fault):
+    """The same ``live`` bits, participation and degree sums exactly; ``mix``,
+    ``neighbor_sum`` and ``rejoin_restart`` bit for bit operation by operation
+    (every sum of the round has two terms, and two terms commute), and to 2
+    units in the last place as two compiled programs, where XLA:CPU contracts
+    ``w_self·x + (w_l·x_l + w_r·x_r)`` into fused multiply-adds as each
+    program's fusions fall (``conftest.assert_ulps_of_scale``)."""
+    topo, rounds = ring_of(n), 12
+    assert faults._table_is_a_ring(topo)
+    kw = dict(FAULTS[fault])
+    tl = kw.pop("timeline", None)
+    if tl is not None:
+        tl = faults.build_fault_timeline(topo, rounds, 11, **tl)
+    gather, shift = both_forms(topo, tl, **kw)
+    assert (gather.addressing, shift.addressing) == ("gather", "shift")
+    assert sorted(shift.tables) == sorted(
+        k for k in ("edge_up", "node_up", "rejoin")
+        if tl is not None and getattr(tl, k) is not None
+        and (k != "rejoin" or fault == "churn_restart"))
+    assert {"nbr", "mask"} <= set(gather.tables)
+    tables = (topo.nbr_idx, topo.nbr_mask)
+    live_g, live_s = (f.make_neighbor_liveness(*tables) for f in (gather, shift))
+    x = jnp.asarray(
+        np.random.default_rng(n).standard_normal((n, 7)) + 3.0, jnp.float32)
+    ops = ["mix", "neighbor_sum"] + ["rejoin_restart"] * (fault == "churn_restart")
+    compiled = {(f.addressing, op): jax.jit(getattr(f, op))
+                for f in (gather, shift) for op in ops}
+    links = 0.0
+    for t in range(rounds):
+        m = np.asarray(gather.active(t))
+        np.testing.assert_array_equal(np.asarray(shift.active(t)), m)
+        lv = np.asarray(live_g(t))
+        np.testing.assert_array_equal(np.asarray(live_s(t)), lv)
+        assert float(shift.realized_degree_sum(t)) == float(
+            gather.realized_degree_sum(t)) == lv.sum()
+        links += lv.sum()
+        for op in ops:
+            want = np.asarray(getattr(gather, op)(t, x))
+            np.testing.assert_array_equal(np.asarray(getattr(shift, op)(t, x)), want)
+            assert_ulps_of_scale(
+                compiled["shift", op](t, x), compiled["gather", op](t, x), 2)
+        mixed = np.asarray(shift.mix(t, x))
+        # W_t is doubly stochastic: the mean stays; a node that sat out has
+        # lost every link, so its row of W_t is the identity's
+        np.testing.assert_allclose(mixed.mean(axis=0), np.asarray(x).mean(axis=0), rtol=2e-6)
+        np.testing.assert_array_equal(mixed[m == 0], np.asarray(x)[m == 0])
+    assert 0 < links < rounds * 2 * n  # some links were down, some up
+
+
+def test_shift_liveness_is_in_the_callers_order():
+    """``make_neighbor_liveness`` answers in the order of the table it is
+    handed: the topology's own is ascending (row 0 is [1, N-1], not
+    left/right), and a caller may list a row's neighbours the other way."""
+    topo = ring_of(8)
+    gather, shift = both_forms(topo, drop_prob=P_DROP, straggler_prob=Q_STRAG)
+    assert topo.nbr_idx[0].tolist() == [1, 7] and topo.nbr_idx[7].tolist() == [0, 6]
+    flipped = topo.nbr_idx[:, ::-1].copy()
+    for nbr in (topo.nbr_idx, flipped):
+        got, want = (f.make_neighbor_liveness(nbr, topo.nbr_mask) for f in (shift, gather))
+        for t in range(8):
+            np.testing.assert_array_equal(np.asarray(got(t)), np.asarray(want(t)))
+    with pytest.raises(ValueError, match="not this ring's"):
+        shift.make_neighbor_liveness((topo.nbr_idx + 2) % 8, topo.nbr_mask)
+
+
+def test_shift_under_vmap_with_traced_keys():
+    """As ``run_batch`` builds it: inside ``vmap``, the keys and the drop
+    probability tracers. Each replica is its own sequential round."""
+    topo, seeds = ring_of(16), [3, 5, 2147483999]
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((16, 5)), jnp.float32)
+    stacked = {k: jnp.stack([keys_of(s)[k] for s in seeds]) for k in keys_of(0)}
+    drops = jnp.asarray([0.3, 0.3, 0.5], jnp.float32)
+
+    def replica(keys, p, t):
+        fm = faults._make_shift_faulty_mixing(
+            topo, None, drop_prob=p, straggler_prob=Q_STRAG, churn_active=False,
+            participation_active=False, rejoin="frozen", **keys)
+        return fm.mix(t, x), fm.realized_degree_sum(t), fm.active(t)
+
+    for t in (0, 7):
+        mixed, links, up = jax.vmap(replica, in_axes=(0, 0, None))(stacked, drops, t)
+        for r, seed in enumerate(seeds):
+            (one,) = both_forms(topo, seed=seed, drop_prob=float(drops[r]),
+                                straggler_prob=Q_STRAG)[1:]
+            assert_ulps_of_scale(mixed[r], one.mix(t, x), 2)
+            assert float(links[r]) == float(one.realized_degree_sum(t))
+            np.testing.assert_array_equal(np.asarray(up[r]), np.asarray(one.active(t)))
+
+
+@pytest.mark.parametrize("graph, is_ring", [
+    ("ring", True), ("chain", False), ("torus", False), ("sparse_er", False)])
+def test_the_form_is_read_off_the_table(graph, is_ring):
+    topo = topo_of(graph)
+    assert faults._table_is_a_ring(topo) is is_ring
+    fm = faults.make_faulty_mixing(topo, P_DROP, 11, straggler_prob=Q_STRAG)
+    assert fm.addressing == FORM[graph]
+    if is_ring:
+        # the table, not the name: the same ring listed descending is a gather
+        other = dataclasses.replace(topo, nbr_idx=topo.nbr_idx[:, ::-1].copy())
+        assert not faults._table_is_a_ring(other)
+        # ... and an injected timeline whose edges are numbered another way
+        tl = faults.build_fault_timeline(topo, 5, 11, edge_drop_prob=P_DROP)
+        order = np.random.default_rng(0).permutation(N)
+        moved = dataclasses.replace(
+            tl, edge_index=tl.edge_index[order], edge_up=tl.edge_up[:, order])
+        same = [faults.make_faulty_mixing(topo, P_DROP, 11, timeline=t) for t in (tl, moved)]
+        assert [f.addressing for f in same] == ["shift", "gather"]
+        live = [f.make_neighbor_liveness(topo.nbr_idx, topo.nbr_mask) for f in same]
+        for t in range(5):
+            np.testing.assert_array_equal(np.asarray(live[0](t)), np.asarray(live[1](t)))
+
+
+@pytest.mark.parametrize("graph", ["chain", "sparse_er"])
+def test_a_graph_that_is_not_a_shift_runs_the_parents_program(graph, monkeypatch):
+    cfg = cfg_of(graph, n_iterations=37)
+    ds = generate_synthetic_dataset(cfg)
+    assert run_rooted(cfg, ds)[1]["fault_mixing"] == "gather"
+    text, data = lowered_scan(cfg, ds, monkeypatch)
+    assert sorted(data["faults"]) == ["mask", "nbr", "slot"]
+    # the builder it goes through is the gather's, called directly ...
+    direct = faults._make_gather_faulty_mixing
+    monkeypatch.setattr(faults, "_make_shift_faulty_mixing", None)
+    monkeypatch.setattr(faults, "_table_is_a_ring", lambda topo: False)
+    assert lowered_scan(cfg, ds, monkeypatch)[0] == text
+    assert faults._make_gather_faulty_mixing is direct
+    # ... and the program is the parent's, byte for byte
+    if jax.__version__.startswith(PARENT_SCANS["jax"]):
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_SCANS[graph]

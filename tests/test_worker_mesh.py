@@ -294,21 +294,35 @@ def test_e2e_gradient_tracking_bitwise(problem):
     assert_parity(r_u, r_s, models_ulps=MODEL_ULPS)
 
 
-def test_e2e_churn_bitwise(problem):
+# Node faults through the halo against the unsharded fault layer. On an
+# Erdős–Rényi graph both address their neighbours through index tables and
+# the models are bitwise; on the ring the unsharded layer reads its
+# neighbours by shifts (ISSUE 33), another program of the same arithmetic,
+# as the fault-free ring's stencil already is: ``MODEL_ULPS``.
+FAULT_GRAPHS = {"ring": ({}, MODEL_ULPS), "erdos_renyi": (ER, None)}
+
+
+@pytest.mark.parametrize("graph", sorted(FAULT_GRAPHS))
+def test_e2e_churn_bitwise(problem, graph):
     """Crash-recovery churn composes through the halo: per-shard timeline
-    slices realize the same masks as the unsharded gather path."""
-    r_u, r_s = run_pair(problem, mttf=20.0, mttr=3.0, rejoin="frozen")
-    assert_parity(r_u, r_s)
+    slices realize the same masks as the unsharded path."""
+    kw, ulps = FAULT_GRAPHS[graph]
+    r_u, r_s = run_pair(problem, mttf=20.0, mttr=3.0, rejoin="frozen", **kw)
+    assert_parity(r_u, r_s, models_ulps=ulps)
 
 
-def test_e2e_participation_bitwise(problem):
-    r_u, r_s = run_pair(problem, participation_rate=0.75)
-    assert_parity(r_u, r_s)
+@pytest.mark.parametrize("graph", sorted(FAULT_GRAPHS))
+def test_e2e_participation_bitwise(problem, graph):
+    kw, ulps = FAULT_GRAPHS[graph]
+    r_u, r_s = run_pair(problem, participation_rate=0.75, **kw)
+    assert_parity(r_u, r_s, models_ulps=ulps)
 
 
-def test_e2e_stragglers_bitwise(problem):
-    r_u, r_s = run_pair(problem, straggler_prob=0.2)
-    assert_parity(r_u, r_s)
+@pytest.mark.parametrize("graph", sorted(FAULT_GRAPHS))
+def test_e2e_stragglers_bitwise(problem, graph):
+    kw, ulps = FAULT_GRAPHS[graph]
+    r_u, r_s = run_pair(problem, straggler_prob=0.2, **kw)
+    assert_parity(r_u, r_s, models_ulps=ulps)
 
 
 @pytest.mark.parametrize("rule", ["trimmed_mean", "median", "clipped_gossip"])
@@ -336,13 +350,15 @@ def test_e2e_byzantine_trimmed_mean_er_within_convention(problem):
     assert_parity(r_u, r_s, models_bitwise=False)
 
 
-def test_e2e_byzantine_churn_composed_bitwise(problem):
+@pytest.mark.parametrize("graph", sorted(FAULT_GRAPHS))
+def test_e2e_byzantine_churn_composed_bitwise(problem, graph):
+    kw, ulps = FAULT_GRAPHS[graph]
     r_u, r_s = run_pair(
         problem, attack="sign_flip", n_byzantine=1,
         aggregation="median", robust_b=1, robust_impl="gather",
-        mttf=20.0, mttr=3.0, rejoin="frozen",
+        mttf=20.0, mttr=3.0, rejoin="frozen", **kw,
     )
-    assert_parity(r_u, r_s)
+    assert_parity(r_u, r_s, models_ulps=ulps)
 
 
 def test_checkpoint_resume_bitwise_with_mesh(problem, tmp_path):
