@@ -47,6 +47,17 @@ without a TPU it exits before doing any work. Segments:
    takes in the device's tiles, whether the executable holds a table of its
    own, and whether the chip's two programs of one round agree.
 
+7. The same stack, fault-free, once more under the profiler (ISSUE 34): the
+   device's time by phase, joined two ways through the compiled program's
+   own table from instruction to scope (``observability/device_scopes.py``).
+   The exact join (every op event by instruction name) sums with its
+   ``None`` to the op line's summed leaf durations and finds at least 99% of
+   the busy time among the table's instructions; the benchmark's ten-row
+   join (``benchmark/scope_reduce.py``) is at or under it scope by scope and
+   within 3% of it in total; the root's ``temp_bytes`` is
+   ``memory_analysis().temp_size_in_bytes``. What the CPU cannot see: whether
+   a trace event's name is the compiled text's instruction.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -355,6 +366,71 @@ def faults_segment(device: dict, cfg, ds, peak_fault_free: int) -> None:
     _one_round_both_ways(cfg)
 
 
+def scopes_segment(device: dict, cfg, ds) -> None:
+    """``forward_carry_segment``'s experiment under ``utils.profiling.trace``,
+    then both joins of that trace with the program's scope table."""
+    import tempfile
+    import time
+
+    from benchmark import scope_reduce, trace_reduce
+    from distributed_optimization_tpu.observability import device_scopes
+    from distributed_optimization_tpu.utils.profiling import trace
+
+    trace_dir = tempfile.mkdtemp(prefix="chip_smoke_trace_")
+    t0 = time.perf_counter()
+    with trace(trace_dir):
+        result, root, _ = _rooted_run(
+            "scopes (traced)", device, cfg, ds, ("program", "temp_bytes"))
+    wall = time.perf_counter() - t0
+    compiled = device_scopes._held[-1]  # cache off: the holder kept it
+    _check(root["temp_bytes"]
+           == compiled.memory_analysis().temp_size_in_bytes > 0,
+           "the root's temp_bytes is memory_analysis().temp_size_in_bytes")
+    t0 = time.perf_counter()
+    table = device_scopes.table_for(root["program"])
+    print(f"[chip_smoke] scopes: table of {table['module']} "
+          f"{len(table['rows'])} instructions, text {table['text_s']:.3f} s, "
+          f"parse {table['parse_s']:.3f} s, asked and built in "
+          f"{time.perf_counter() - t0:.3f} s; traced call {wall:.2f} s",
+          flush=True)
+    path = trace_reduce.find_xplane(trace_dir)
+    planes = trace_reduce.load_events(path)
+    summary = trace_reduce.reduce_planes(planes)
+    leaves = sum(sec for _, sec in
+                 trace_reduce.reduce_planes(planes, top=10 ** 9)["device_ops"])
+    exact = device_scopes.device_time_by_scope(path, table)
+    known = device_scopes.device_time_by_scope(path, {
+        **table, "rows": [{**r, "scope": "update"} for r in table["rows"]]})
+    T = cfg.n_iterations
+    facts = {"iterations": T, "calls": [{
+        "wall_s": wall, "iterations": T,
+        "scan_s": T / result.history.iters_per_second}]}
+    ten = scope_reduce.by_scope(summary, facts)
+    busy = summary["busy_s"]
+    for name, by in (("exact", exact), ("ten-row", ten)):
+        print(f"[chip_smoke] scopes: {name} join, us an iteration: "
+              + " ".join(f"{k}={v * 1e6 / T:.1f}" for k, v in by.items())
+              + f" | busy {busy * 1e6 / T:.1f}", flush=True)
+    _check(math.isclose(sum(exact.values()), leaves, rel_tol=1e-9),
+           "the exact join's scopes and None sum to the op line's leaves")
+    _check(known.get("update", 0.0) >= 0.99 * busy,
+           f"the table knows the instructions of 99% of the busy time "
+           f"({known.get('update', 0.0):.4f} of {busy:.4f} s)")
+    scoped = sum(v for k, v in exact.items() if k is not None)
+    scoped_ten = sum(v for k, v in ten.items() if k is not None)
+    print(f"[chip_smoke] scopes: scoped share of busy, exact "
+          f"{scoped / busy:.4f}, ten-row {scoped_ten / busy:.4f}", flush=True)
+    _check(scoped >= 0.95 * busy, "the exact join bills 95% of the busy time "
+           "to a scope")
+    _check(all(v <= exact.get(k, 0.0) * (1 + 1e-9)
+               for k, v in ten.items() if k is not None),
+           "the ten-row join is at or under the exact one, scope by scope")
+    _check(scoped_ten >= 0.97 * scoped,
+           "the ten-row join is within 3% of the exact one in total")
+    _check(math.isclose(sum(ten.values()), busy, rel_tol=1e-9),
+           "the ten-row join's scopes and None sum to the busy time")
+
+
 def _one_round_both_ways(cfg, rounds=(0, 7)) -> None:
     """The shift and the gather form of one round of ``cfg``'s faults on the
     chip, from the same keys: two programs of the same arithmetic."""
@@ -449,7 +525,9 @@ def main() -> int:
     if device["count"] >= 4:
         placement_segment(device)  # first: the peaks it reads are its own
     # next: its chip's peak is still its own, then the fault layer's on top
-    faults_segment(device, *forward_carry_segment(device))
+    cfg, ds, peak = forward_carry_segment(device)
+    faults_segment(device, cfg, ds, peak)
+    scopes_segment(device, cfg, ds)
     glm_segment(device)
     softmax_segment(device)
     reference_segment(device)

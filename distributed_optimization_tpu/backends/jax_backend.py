@@ -39,6 +39,7 @@ from distributed_optimization_tpu.metrics import (
     decentralized_floats_per_iteration,
 )
 from distributed_optimization_tpu.models import get_problem
+from distributed_optimization_tpu.observability import device_scopes
 from distributed_optimization_tpu.observability.spans import current_tracer
 from distributed_optimization_tpu.ops.compression import selection_label
 from distributed_optimization_tpu.ops.losses import paired_margins, sq_norm
@@ -395,36 +396,41 @@ def _make_step_eval(p: _StepPieces, data):
         """The iteration's ``ctx.grad``; ``z`` = X·``z_of`` as the scan
         carried it, used where the rule asks at that very array, slot 0."""
         def grad(params, slot):
-            if schedule is not None:
-                idx = schedule[t]  # [N, b] injected batch indices
-                Xb = jnp.take_along_axis(X, idx[:, :, None], axis=1)
-                yb = jnp.take_along_axis(y, idx, axis=1)
-                wts = jnp.full(idx.shape, 1.0 / idx.shape[1], dtype=X.dtype)
-            elif full_batch:
-                Xb, yb, wts = X, y, full_wts
-            elif p.sampling_impl == "dense":
-                # Dense-weights sampling: no top_k, no gather — the
-                # weighted gradient runs over the full padded shard with
-                # 1/b weights on the sampled rows (same subsets as the
-                # gather path for the same key; see ops/sampling.py).
-                slot_key = jax.random.fold_in(p.key, slot)
-                Xb, yb = X, y
-                wts = sample_worker_batch_weights(
-                    slot_key, t, n_valid, X.shape[1], batch_size
-                ).astype(X.dtype)
-            else:
-                slot_key = jax.random.fold_in(p.key, slot)
-                Xb, yb, wts = sample_worker_batches(
-                    slot_key, t, X, y, n_valid, batch_size
-                )
-                wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
-            if z is not None and params is z_of and slot == 0:
+            with device_scopes.scope("sampling"):
+                if schedule is not None:
+                    idx = schedule[t]  # [N, b] injected batch indices
+                    Xb = jnp.take_along_axis(X, idx[:, :, None], axis=1)
+                    yb = jnp.take_along_axis(y, idx, axis=1)
+                    wts = jnp.full(
+                        idx.shape, 1.0 / idx.shape[1], dtype=X.dtype
+                    )
+                elif full_batch:
+                    Xb, yb, wts = X, y, full_wts
+                elif p.sampling_impl == "dense":
+                    # Dense-weights sampling: no top_k, no gather — the
+                    # weighted gradient runs over the full padded shard
+                    # with 1/b weights on the sampled rows (same subsets as
+                    # the gather path for the same key; see
+                    # ops/sampling.py).
+                    slot_key = jax.random.fold_in(p.key, slot)
+                    Xb, yb = X, y
+                    wts = sample_worker_batch_weights(
+                        slot_key, t, n_valid, X.shape[1], batch_size
+                    ).astype(X.dtype)
+                else:
+                    slot_key = jax.random.fold_in(p.key, slot)
+                    Xb, yb, wts = sample_worker_batches(
+                        slot_key, t, X, y, n_valid, batch_size
+                    )
+                    wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
+            with device_scopes.scope("gradient"):
+                if z is not None and params is z_of and slot == 0:
+                    return jax.vmap(
+                        link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
+                    )(z, params, Xb, yb, wts, p.reg)
                 return jax.vmap(
-                    link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
-                )(z, params, Xb, yb, wts, p.reg)
-            return jax.vmap(
-                p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
-            )(params, Xb, yb, wts, p.reg)
+                    p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
+                )(params, Xb, yb, wts, p.reg)
 
         return grad
 
@@ -437,9 +443,10 @@ def _make_step_eval(p: _StepPieces, data):
             # average (auxiliary leaves stay frozen-stale — only the
             # model is warm-restarted). The restarted value is what it
             # gossips this round.
-            state = {
-                **state, "x": faulty.rejoin_restart(t, state["x"])
-            }
+            with device_scopes.scope("faults"):
+                state = {
+                    **state, "x": faulty.rejoin_restart(t, state["x"])
+                }
         if faulty is not None:
             mix_fn = lambda v: faulty.mix(t, v)  # noqa: E731
             nbr_fn = lambda v: faulty.neighbor_sum(t, v)  # noqa: E731
@@ -458,6 +465,11 @@ def _make_step_eval(p: _StepPieces, data):
                 nbr_fn = lambda v: base_nbr(  # noqa: E731
                     adversary.corrupt(t, v)
                 )
+        # Whatever realizes them (stencil, gather, halo exchange, the fault
+        # layer's weighted sum, a robust aggregate): the gossip of the step.
+        mix_fn, nbr_fn = (
+            device_scopes.scope("gossip")(f) for f in (mix_fn, nbr_fn)
+        )
         fused_mix_step = p.fused_mix_step
         if p.fused_robust_step is not None:
             # robust_impl='fused' + dsgd: the whole corrupt → screen →
@@ -480,7 +492,8 @@ def _make_step_eval(p: _StepPieces, data):
             fused_mix_step=fused_mix_step,
             compressed_mix=p.compressed_mix,
         )
-        new_state = p.algo.step(state, ctx)
+        with device_scopes.scope("update"):
+            new_state = p.algo.step(state, ctx)
         if faulty is not None and (
             faulty.straggler_prob > 0.0 or faulty.churn_active
             or faulty.participation_active
@@ -491,16 +504,19 @@ def _make_step_eval(p: _StepPieces, data):
             # outage, so a 'frozen' rejoin resumes the stale pre-crash
             # state for free. Its mixing row already degenerated to
             # identity via the dropped edges.
-            m = faulty.active(t)
-            new_state = jax.tree.map(
-                lambda new, old: jnp.where(
-                    m.reshape((-1,) + (1,) * (new.ndim - 1)) > 0, new, old
-                ),
-                new_state,
-                state,
-            )
+            with device_scopes.scope("faults"):
+                m = faulty.active(t)
+                new_state = jax.tree.map(
+                    lambda new, old: jnp.where(
+                        m.reshape((-1,) + (1,) * (new.ndim - 1)) > 0,
+                        new, old,
+                    ),
+                    new_state,
+                    state,
+                )
         return new_state, None
 
+    @device_scopes.scope("recorder")
     def trace_row(state, t):
         """One flight-recorder row (telemetry.TRACE_FIELDS) at iteration t:
         pure observability computed from the post-step state, feeding the
@@ -575,40 +591,41 @@ def _make_step_eval(p: _StepPieces, data):
                     _zero_trace,
                     state,
                 )
-        if p.collect_metrics:
-            x = state["x"]
-            # Every parameter axis: [N, d] for the GLMs, [N, d, K] for
-            # softmax on the sequential path (Problem.param_shape).
-            param_axes = tuple(range(1, x.ndim))
-            if adversary is not None:
-                # Honest-only metrics (docs/BYZANTINE.md): the gap is
-                # f(x̄_honest) − f* on the unchanged global objective,
-                # consensus is the honest spread — Byzantine rows are
-                # adversary-controlled and would poison both.
-                hw = p.honest_w.astype(x.dtype)
-                nh = jnp.sum(hw)
-                xbar = jnp.sum(
-                    x * jnp.expand_dims(hw, param_axes), axis=0
-                ) / nh
-                f_bar, z_next = full_objective(x, xbar)
-                out["gap"] = f_bar - p.f_opt
-                if p.track_consensus:
-                    out["cons"] = (
-                        jnp.sum(
-                            hw * jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
+        with device_scopes.scope("eval"):
+            if p.collect_metrics:
+                x = state["x"]
+                # Every parameter axis: [N, d] for the GLMs, [N, d, K] for
+                # softmax on the sequential path (Problem.param_shape).
+                param_axes = tuple(range(1, x.ndim))
+                if adversary is not None:
+                    # Honest-only metrics (docs/BYZANTINE.md): the gap is
+                    # f(x̄_honest) − f* on the unchanged global objective,
+                    # consensus is the honest spread — Byzantine rows are
+                    # adversary-controlled and would poison both.
+                    hw = p.honest_w.astype(x.dtype)
+                    nh = jnp.sum(hw)
+                    xbar = jnp.sum(
+                        x * jnp.expand_dims(hw, param_axes), axis=0
+                    ) / nh
+                    f_bar, z_next = full_objective(x, xbar)
+                    out["gap"] = f_bar - p.f_opt
+                    if p.track_consensus:
+                        out["cons"] = jnp.sum(
+                            hw * jnp.sum(
+                                (x - xbar[None]) ** 2, axis=param_axes
+                            )
+                        ) / nh
+                else:
+                    xbar = jnp.mean(x, axis=0)
+                    f_bar, z_next = full_objective(x, xbar)
+                    out["gap"] = f_bar - p.f_opt
+                    if p.track_consensus:
+                        out["cons"] = jnp.mean(
+                            jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
                         )
-                        / nh
-                    )
-            else:
-                xbar = jnp.mean(x, axis=0)
-                f_bar, z_next = full_objective(x, xbar)
-                out["gap"] = f_bar - p.f_opt
-                if p.track_consensus:
-                    out["cons"] = jnp.mean(
-                        jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
-                    )
         return out, z_next
 
+    @device_scopes.scope("eval")
     def init_forward(state):
         """The margins a scan starts from, by the paired pass itself. The
         barrier keeps x̄'s half alive: with it thrown away the compiler
@@ -620,6 +637,7 @@ def _make_step_eval(p: _StepPieces, data):
         pair = paired_margins(X, x, jnp.mean(x, axis=0))
         return jax.lax.optimization_barrier(pair)[0]
 
+    @device_scopes.scope("faults")
     def floats_for(ts):
         # Honest comms accounting under faults: floats actually
         # exchanged over realized edges for these iterations (recomputed
@@ -1103,7 +1121,9 @@ def _drive_segments(
             size_cost = cost_from_lowered(lowered) if config.telemetry else None
             if cost is None:
                 cost = size_cost
-            compiled_by_size[size] = lowered.compile()
+            compiled_by_size[size] = device_scopes.compile_keeping_scopes(
+                lowered
+            )
             this_cold = time.perf_counter() - t_cold
             cold_compile += this_cold
             if exec_cache is not None:
@@ -1112,6 +1132,13 @@ def _drive_segments(
                     compile_seconds=this_cold,
                 )
     compile_seconds = cold_compile if measure_compile else 0.0
+    if segments:
+        # Which executable this call ran, for whoever asks later what its
+        # instructions belong to (observability/device_scopes.py).
+        spans.note_root(**device_scopes.note_program(
+            compiled_by_size[segments[0][0]],
+            held_elsewhere=exec_cache is not None,
+        ))
 
     pending = []  # (ys, size) of the segments whose rows are on the device
 

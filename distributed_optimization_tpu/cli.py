@@ -439,7 +439,8 @@ def build_parser() -> argparse.ArgumentParser:
                            "throughput")
     diag.add_argument("--profile-dir", metavar="DIR", default=None,
                       help="collect a jax.profiler (XProf/TensorBoard) trace "
-                           "of the run into DIR")
+                           "of the run into DIR; the report then ends with "
+                           "the device's time by scope")
     diag.add_argument("--check-nans", action="store_true",
                       help="enable jax_debug_nans: raise at the first "
                            "NaN-producing op instead of finishing with NaNs")
@@ -801,6 +802,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             sim.run_one(verbose=not args.quiet, run_kwargs=run_kwargs)
 
     sim.report_numerical_results()
+    if args.profile_dir and args.backend == "jax":
+        # Beside the phase table: the trace just written, joined with the
+        # compiled programs' own account of their instructions.
+        from distributed_optimization_tpu.observability import device_scopes
+
+        print(device_scopes.profile_report(
+            args.profile_dir, sim.phase_timer.spans()
+        ))
     if args.plot:
         sim.plot_results(path=args.plot)
         _log.info("figure saved to %s", args.plot)

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_optimization_tpu.config import COMPRESSIONS
+from distributed_optimization_tpu.observability import device_scopes
 
 # Counter-based stream tag for the (possibly randomized) compressor draws,
 # folded into the run seed: jax.random.fold_in(fold_in(key(seed), TAG), t).
@@ -301,7 +302,10 @@ class ErrorFeedbackGossip:
     & Jaggi '19). Identity compression at γ = 1 makes one exchange exactly
     the plain W-mix (v⁺ = W v), which is why uncompressed trajectories
     are unaffected. ``floats_per_edge`` (the compressor's payload) is the
-    comms-accounting hook the backends consume.
+    comms-accounting hook the backends consume. An exchange is traced under
+    ``dopt.compress`` (``observability/device_scopes.py``): the compressor,
+    the estimate's update and the γ step, the sharded wire form with them;
+    the ``mix`` inside it stays the backend's ``dopt.gossip``.
 
     The stacks go to the compressor in the shape the scan carries them,
     [N, d] or model-shaped [N, d, K]: a selection by threshold compares and
@@ -333,9 +337,10 @@ class ErrorFeedbackGossip:
         term-for-term the pre-refactor CHOCO step — trajectories are
         bitwise-unchanged (pinned in tests/test_choco.py).
         """
-        q = self.compressor.apply(key, v - memory)
-        memory_new = memory + q
-        v_new = v + self.gamma * (mix(memory_new) - memory_new)
+        with device_scopes.scope("compress"):
+            q = self.compressor.apply(key, v - memory)
+            memory_new = memory + q
+            v_new = v + self.gamma * (mix(memory_new) - memory_new)
         return v_new, memory_new
 
     def exchange_sharded(
@@ -354,10 +359,11 @@ class ErrorFeedbackGossip:
         row-sharded stack (row-wise + shape-based draws, so sharding
         cannot change its output), keeping the historical per-row draws.
         """
-        q = self.compressor.apply(key, v - memory)
-        memory_new = memory + q
-        mixed, halo_new = compressed_mix(q, memory_new, halo)
-        v_new = v + self.gamma * (mixed - memory_new)
+        with device_scopes.scope("compress"):
+            q = self.compressor.apply(key, v - memory)
+            memory_new = memory + q
+            mixed, halo_new = compressed_mix(q, memory_new, halo)
+            v_new = v + self.gamma * (mixed - memory_new)
         return v_new, memory_new, halo_new
 
 

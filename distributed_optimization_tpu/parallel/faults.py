@@ -127,6 +127,11 @@ neighbours by rolls (``_make_shift_faulty_mixing``); on every other graph
 through the (node, slot) → edge-id table and the neighbor table
 (``_make_gather_faulty_mixing``). One realization either way.
 
+**Device scope.** What a faulty round costs beyond a fault-free one is
+traced under ``dopt.faults`` (``observability/device_scopes.py``): the draws
+or timeline reads, liveness, realized degrees and weights, a warm restart.
+The weighted sum over the neighbours stays the caller's ``dopt.gossip``.
+
 Masks are derived purely from (fault key, iteration) — like batch sampling,
 fault realizations are reproducible and checkpoint/resume-safe with no
 carried RNG state.  The underlying uniform draws are EXPLICIT float32
@@ -144,6 +149,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_optimization_tpu.observability import device_scopes
 from distributed_optimization_tpu.parallel.topology import Topology
 
 # Allowed rejoin policies after a crash-recovery outage (config and CLI
@@ -1005,6 +1011,7 @@ def make_faulty_mixing(
             ej = jnp.asarray(timeline.edge_index[:, 1], dtype=jnp.int32)
         node_masked = node_up_dev is not None or part_up_dev is not None
 
+        @device_scopes.scope("faults")
         def active(t) -> jax.Array:
             # Realized availability: churn/straggler-up AND sampled-in
             # (participation). Either alone is the mask verbatim.
@@ -1017,6 +1024,7 @@ def make_faulty_mixing(
                 m = m * part_up_dev[t].astype(jnp.float32)
             return m
 
+        @device_scopes.scope("faults")
         def realized_adjacency(t) -> jax.Array:
             if edge_up_dev is not None:
                 e = edge_up_dev[t].astype(jnp.float32)
@@ -1030,6 +1038,7 @@ def make_faulty_mixing(
             return A_t
     else:
 
+        @device_scopes.scope("faults")
         def active(t) -> jax.Array:
             if not strag_active:
                 return jnp.ones(base_A.shape[0], dtype=jnp.float32)
@@ -1039,6 +1048,7 @@ def make_faulty_mixing(
             )
             return (u >= straggler_prob).astype(jnp.float32)
 
+        @device_scopes.scope("faults")
         def realized_adjacency(t) -> jax.Array:
             if not drop_active and not strag_active:
                 return base_A  # no fault sampling on the fault-free fast path
@@ -1081,6 +1091,7 @@ def make_faulty_mixing(
                 )
                 edge_up_gather = jnp.asarray(timeline.edge_up)
 
+            @device_scopes.scope("faults")
             def live(t) -> jax.Array:
                 out = mask_dev
                 if slot_dev is not None:
@@ -1093,6 +1104,7 @@ def make_faulty_mixing(
                 return out
         else:
 
+            @device_scopes.scope("faults")
             def live(t) -> jax.Array:
                 if not drop_active and not strag_active:
                     return mask_dev  # fault-free fast path: static table
@@ -1144,6 +1156,7 @@ def make_faulty_mixing(
         if keys is None else keys[2]
     )
 
+    @device_scopes.scope("faults")
     def partner(t) -> jax.Array:
         key = jax.random.fold_in(match_key, t)
         return sample_one_peer_matching(key, realized_adjacency(t))
@@ -1167,7 +1180,8 @@ def make_faulty_mixing(
 
         def mix(t, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)
-            W = realized_weights(realized_adjacency(t).astype(acc))
+            with device_scopes.scope("faults"):
+                W = realized_weights(realized_adjacency(t).astype(acc))
             return jnp.tensordot(W, x.astype(acc), axes=1).astype(x.dtype)
 
         def neighbor_sum(t, x):
@@ -1276,6 +1290,7 @@ def _matrix_free_bits(
     n_edges = None if edge_index is None else edge_index.shape[0]
 
     def bits(tb):
+        @device_scopes.scope("faults")
         def edge_up(t):
             """[E] float32 link liveness at t, or None (no edge process)."""
             if "edge_up" in tb:
@@ -1288,6 +1303,7 @@ def _matrix_free_bits(
             )
             return (u >= drop_p).astype(jnp.float32)
 
+        @device_scopes.scope("faults")
         def active(t) -> jax.Array:
             if strag_q is not None:
                 u = jax.random.uniform(
@@ -1379,6 +1395,7 @@ def _make_gather_faulty_mixing(
         slot_dev = tb["slot"].T if "slot" in tb else None
         edge_up, active = bits(tb)
 
+        @device_scopes.scope("faults")
         def live_over(t, nbr, mask, slots) -> jax.Array:
             out = mask
             up = edge_up(t)
@@ -1395,10 +1412,11 @@ def _make_gather_faulty_mixing(
 
         def mix(t, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)
-            lv = live(t).astype(acc)
-            deg = jnp.sum(lv, axis=1)
-            w = lv / (1.0 + jnp.maximum(deg[:, None], deg[nbr_dev]))
-            w_self = 1.0 - jnp.sum(w, axis=1)
+            with device_scopes.scope("faults"):
+                lv = live(t).astype(acc)
+                deg = jnp.sum(lv, axis=1)
+                w = lv / (1.0 + jnp.maximum(deg[:, None], deg[nbr_dev]))
+                w_self = 1.0 - jnp.sum(w, axis=1)
             xa = x.astype(acc)
             out = w_self.reshape((-1,) + (1,) * (x.ndim - 1)) * xa + jnp.sum(
                 w.reshape(_wshape(x)) * xa[nbr_dev], axis=1
@@ -1528,6 +1546,7 @@ def _make_shift_faulty_mixing(
     def bind(tb) -> FaultyMixing:
         edge_up, active = bits(tb)
 
+        @device_scopes.scope("faults")
         def right(t) -> jax.Array:
             """[N] float32: the live bit of edge {i, i+1 mod N}."""
             m = active(t)
@@ -1548,11 +1567,12 @@ def _make_shift_faulty_mixing(
 
         def mix(t, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)
-            r = right(t).astype(acc)
-            deg = jnp.roll(r, 1) + r
-            w_r = r / (1.0 + jnp.maximum(deg, jnp.roll(deg, -1)))
-            w_l = jnp.roll(w_r, 1)
-            w_self = 1.0 - (w_l + w_r)
+            with device_scopes.scope("faults"):
+                r = right(t).astype(acc)
+                deg = jnp.roll(r, 1) + r
+                w_r = r / (1.0 + jnp.maximum(deg, jnp.roll(deg, -1)))
+                w_l = jnp.roll(w_r, 1)
+                w_self = 1.0 - (w_l + w_r)
             xa = x.astype(acc)
             out = _col(w_self, x) * xa + neighbor_terms(xa, w_l, w_r)
             return out.astype(x.dtype)
@@ -1604,6 +1624,7 @@ def _make_shift_faulty_mixing(
             pick_right = jnp.asarray(is_right)
             caller_mask = jnp.asarray(mask, dtype=jnp.float32)
 
+            @device_scopes.scope("faults")
             def live(t) -> jax.Array:
                 r = right(t)
                 return caller_mask * jnp.where(
@@ -1706,6 +1727,7 @@ def make_halo_faulty_mixing(
         if timeline is not None and timeline.part_up is not None else None
     )
 
+    @device_scopes.scope("faults")
     def active(t) -> jax.Array:
         if node_up_dev is None and part_up_dev is None:
             return jnp.ones(n, dtype=jnp.float32)
@@ -1721,15 +1743,17 @@ def make_halo_faulty_mixing(
         # models in the accumulation dtype, neighbor degrees fetched
         # through the second exchange's extra column.
         acc = jnp.promote_types(jnp.float32, xb.dtype)
-        m_ext = exchange(mb[:, None])[:, 0]               # [S + h + 1] f32
-        lv = (mask_f32 * mb[:, None] * m_ext[nbr_l]).astype(acc)
-        deg = jnp.sum(lv, axis=1)                          # [S] acc
+        with device_scopes.scope("faults"):
+            m_ext = exchange(mb[:, None])[:, 0]           # [S + h + 1] f32
+            lv = (mask_f32 * mb[:, None] * m_ext[nbr_l]).astype(acc)
+            deg = jnp.sum(lv, axis=1)                      # [S] acc
         xa = xb.astype(acc)
         d2 = xa.shape[-1]
         ext = exchange(jnp.concatenate([xa, deg[:, None]], axis=1))
         gathered = ext[nbr_l]                              # [S, k, d2 + 1]
-        w = lv / (1.0 + jnp.maximum(deg[:, None], gathered[:, :, d2]))
-        w_self = 1.0 - jnp.sum(w, axis=1)
+        with device_scopes.scope("faults"):
+            w = lv / (1.0 + jnp.maximum(deg[:, None], gathered[:, :, d2]))
+            w_self = 1.0 - jnp.sum(w, axis=1)
         out = w_self[:, None] * xa + jnp.sum(
             w[:, :, None] * gathered[:, :, :d2], axis=1
         )
@@ -1737,8 +1761,9 @@ def make_halo_faulty_mixing(
 
     def _nbr_body(exchange, nbr_l, mask_f32, xb, mb):
         acc = jnp.promote_types(jnp.float32, xb.dtype)
-        m_ext = exchange(mb[:, None])[:, 0]
-        lv = (mask_f32 * mb[:, None] * m_ext[nbr_l]).astype(acc)
+        with device_scopes.scope("faults"):
+            m_ext = exchange(mb[:, None])[:, 0]
+            lv = (mask_f32 * mb[:, None] * m_ext[nbr_l]).astype(acc)
         xa = xb.astype(acc)
         ext = exchange(xa)
         out = jnp.sum(lv[:, :, None] * ext[nbr_l], axis=1)

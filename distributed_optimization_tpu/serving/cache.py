@@ -44,6 +44,7 @@ from collections import OrderedDict
 from typing import Any, Optional
 
 from distributed_optimization_tpu.config import SWEEPABLE_FIELDS
+from distributed_optimization_tpu.observability import device_scopes
 
 # Default LRU bounds: enough distinct programs for a bench/smoke session
 # without letting a long-lived daemon accumulate unbounded compiled code.
@@ -65,17 +66,9 @@ def estimate_executable_bytes(executable) -> int:
     accounting is telemetry-adjacent, never control flow worth raising for.
     """
     try:
-        ma = executable.memory_analysis()
-        size = 0
-        for attr in (
-            "generated_code_size_in_bytes",
-            "temp_size_in_bytes",
-            "argument_size_in_bytes",
-            "output_size_in_bytes",
-        ):
-            v = getattr(ma, attr, None)
-            if v:
-                size += int(v)
+        # The one memory_analysis() call an executable gets: the run
+        # builder's ``temp_bytes`` reads the same answer.
+        size = sum(device_scopes.memory(executable).values())
         if size > 0:
             return size
     except Exception:

@@ -84,7 +84,11 @@ def test_one_root_with_the_named_children_in_order(setup):
     # ``algorithm``, ``compress``, ``select``, ``wire_floats_per_edge``:
     # what ran and what one edge carries an iteration (plain D-SGD: the
     # whole model, nothing selected).
-    assert root["args"] == {
+    # ``program``, ``temp_bytes`` (ISSUE 34): a key of the executable the call
+    # ran and its temporaries by ``memory_analysis()`` (tests/test_device_scopes.py).
+    args = dict(root["args"])
+    assert isinstance(args.pop("program"), str) and args.pop("temp_bytes") >= 0
+    assert args == {
         "path": "fused", "cache": "miss",
         "carry": f"{cfg.n_workers}x{ds.n_features}",
         "algorithm": "dsgd", "compress": "none", "select": "none",
