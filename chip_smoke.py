@@ -23,7 +23,11 @@ without a TPU it exits before doing any work. Segments:
    all, while the process has put nothing else on a device, a 1.1 GB stack
    placed over the four with no chip ever holding more than its quarter
    (``parallel.mesh.place_shards``: what a deployment past one chip's memory
-   rests on).
+   rests on). And last of all (ISSUE 35) one round of the mesh's two mixing
+   forms on one ring of 2^18 rows a chip: the shift form, which a ring's
+   neighbor table takes, against the gather form to a few units of the rows'
+   scale, and no chip holding a neighbor table after the shift rounds (the
+   gather form's executable keeps 0.54 GB of it on every chip).
 
 5. The carried forward product at a size the chip notices (N = 65,536 workers
    of 53 rows, d = 81: a 1.1 GB stack): one run with the margins X·x carried
@@ -236,6 +240,73 @@ def placement_segment(device: dict, *, n_workers: int = 65_536,
         _check(np.asarray(got).tobytes() == X.tobytes(),
                "the placed stack is the host array, bit for bit")
         del got
+
+
+HALO_FORMS_ULPS = 8  # of the rows' scale: two programs of one arithmetic
+HALO_SHIFT_ROOM = 300_000_000  # bytes a chip may hold after a shift round
+
+
+def halo_forms_segment(device: dict, *, n_workers: int = 1 << 20,
+                       d: int = 81) -> None:
+    """One round of the worker mesh's two mixing forms on the same ring at
+    the four-chip cell's size, 2^18 rows a chip: the shift form (what
+    ``make_halo_mixing_op`` takes on a ring's table) against the gather
+    form, and what each leaves on a chip after a call. The gather form's
+    executable holds its per-shard neighbor table, 0.54 GB a chip in (8,
+    128) tiles; the shift form has no table, and the CPU cannot see either
+    (ISSUE 35). Bytes in use are read against the segment's own start, and
+    the segment runs last: segments 5 and 6 read chip 0's peak as theirs."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_optimization_tpu.parallel import build_topology
+    from distributed_optimization_tpu.parallel.collectives import (
+        _make_halo_gather_mixing_op,
+        make_halo_mixing_op,
+    )
+    from distributed_optimization_tpu.parallel.mesh import (
+        WORKER_AXIS,
+        make_sized_worker_mesh,
+    )
+
+    mesh = make_sized_worker_mesh(4)
+
+    def in_use():
+        return [int(dev.memory_stats()["bytes_in_use"])
+                for dev in mesh.devices.flat]
+
+    before = in_use()
+    topo = build_topology("ring", n_workers, impl="neighbor")
+    rows = NamedSharding(mesh, P(WORKER_AXIS, None))
+    x = jax.jit(lambda key: jax.random.normal(key, (n_workers, d), jnp.float32),
+                out_shardings=rows)(jax.random.key(35))
+    shift = make_halo_mixing_op(topo, mesh, dtype=jnp.float32)
+    _check(shift.impl == "halo_shift", "a ring's table is mixed by shifts")
+    got, held = {}, {}
+    for op in (shift, _make_halo_gather_mixing_op(topo, mesh, dtype=jnp.float32)):
+        for form in ("apply", "neighbor_sum"):
+            got[op.impl, form] = np.asarray(
+                jax.block_until_ready(jax.jit(getattr(op, form))(x)))
+        held[op.impl] = [b - a for a, b in zip(before, in_use())]
+        print(f"[chip_smoke] halo forms: {op.impl} bytes in use over the "
+              f"segment's start, by device: {held[op.impl]}", flush=True)
+        if op is shift:
+            _check(max(held[op.impl]) <= HALO_SHIFT_ROOM,
+                   f"after its shift rounds no chip holds {HALO_SHIFT_ROOM} "
+                   "bytes more: the rows and no neighbor table")
+    for form in ("apply", "neighbor_sum"):
+        want = got["halo_gather", form]
+        unit = float(np.finfo(np.float32).eps) * float(np.max(np.abs(want)))
+        gap = float(np.max(np.abs(got["halo_shift", form] - want))) / unit
+        print(f"[chip_smoke] halo forms: {form} shift against gather, worst "
+              f"gap {gap:.2f} units of the rows' scale", flush=True)
+        _check(gap <= HALO_FORMS_ULPS,
+               f"the two forms' {form} agree within {HALO_FORMS_ULPS} units "
+               "of the rows' scale")
+    print(f"[chip_smoke] halo forms: the gather form keeps "
+          f"{min(held['halo_gather']) - max(held['halo_shift'])} bytes more "
+          "on every chip after its calls", flush=True)
 
 
 # The benchmark's GLM limits (benchmark/configs/glm81_ring262k.json): worst
@@ -497,9 +568,9 @@ def four_chip_segment(device: dict, *, n_workers: int = 100_000,
     _check(len(roots) == 2
            and roots[0]["placement"] == "mesh4:direct"
            and roots[0]["mesh"] == f"4x{n_workers // 4}"
-           and roots[0]["mixing"] == "halo_gather"
+           and roots[0]["mixing"] == "halo_shift"
            and roots[0]["halo_rows"] == 2,
-           "worker_mesh=4 shards sent per chip, mixing by the halo gather")
+           "worker_mesh=4 shards sent per chip, a ring mixed by halo shifts")
     _check(roots[1]["placement"] == "direct" and "mesh" not in roots[1],
            "worker_mesh=0 shards placed as before")
     _check(single.result.history.mesh_devices == 1,
@@ -533,6 +604,7 @@ def main() -> int:
     reference_segment(device)
     if device["count"] >= 4:
         four_chip_segment(device)
+        halo_forms_segment(device)
     else:
         print(f"[chip_smoke] four-chip segment: NOT RUN "
               f"({device['count']} device(s) visible)", flush=True)

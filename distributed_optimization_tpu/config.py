@@ -410,7 +410,9 @@ class ExperimentConfig:
     # [N/P, d], neighbor tables [N/P, k_max] and fault-timeline columns
     # all live per-shard, and each gossip round exchanges only the
     # boundary rows a shard's neighbor table references (a ppermute halo
-    # exchange; parallel/collectives.py::make_halo_mixing_op). This is
+    # exchange; parallel/collectives.py::make_halo_mixing_op — a ring's
+    # table needs no per-shard table at all and is mixed by row shifts,
+    # read off the table with no option). This is
     # the representation that lifts matrix-free N past one device's RAM:
     # per-device memory is O(N/P·(d + k_max)), and the sharded-vs-
     # unsharded trajectories are BITWISE identical at matched N (the
@@ -443,7 +445,12 @@ class ExperimentConfig:
     # are added after the in-block partial, a different summation order,
     # so double_buffer is NOT bitwise vs off — it is a distinct
     # structural program. Plain-gossip mesh path only (no compression,
-    # faults, or robust screening).
+    # faults, or robust screening). Moot where the neighbor table is a
+    # ring's: that mixing is two row shifts whose boundary-row permutes
+    # depend on nothing local (the root span's mixing = 'halo_shift',
+    # collectives.make_halo_mixing_op), so 'off' and 'double_buffer' are
+    # ONE program there; it reorders the gather form only (chain, torus,
+    # Erdős–Rényi).
     halo_overlap: str = "off"
 
     def __post_init__(self) -> None:

@@ -38,6 +38,7 @@ from distributed_optimization_tpu.ops.mixing import MixingOp
 from distributed_optimization_tpu.parallel.mesh import WORKER_AXIS
 from distributed_optimization_tpu.parallel.topology import (
     Topology,
+    _table_is_a_ring,
     build_halo_plan,
     gather_mixing_weights,
     neighbor_tables_for,
@@ -130,6 +131,18 @@ def _grid_block_ops(axis: str, n_devices: int, rows: int, cols: int, w: float):
     return mix, nbr
 
 
+def _over_row_blocks(block_fn, mesh: Mesh):
+    """``block_fn`` under ``shard_map`` with the leading (worker) axis blocked
+    over the mesh and every parameter axis replicated: the spec follows the
+    stack's rank ([N, d] or a model-shaped [N, d, K])."""
+
+    def fn(x):
+        spec = P(WORKER_AXIS, *([None] * (x.ndim - 1)))
+        return shard_map(block_fn, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+
+    return fn
+
+
 def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
     """Build the explicit shard_map collective mixing op for a topology.
 
@@ -168,19 +181,15 @@ def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
         )
 
     def _wrap(block_fn):
-        # The worker axis (the grid's row axis) is blocked over devices and
-        # every parameter axis replicated: the spec follows the stack's
-        # rank ([N, d] or a model-shaped [N, d, K]).
-        def fn(x):
-            g = x
-            if topo.name == "grid":  # grid layout -> stencil -> back
-                rows, cols = topo.grid_shape  # type: ignore[misc]
-                g = x.reshape(rows, cols, *x.shape[1:])
-            spec = P(axis, *([None] * (g.ndim - 1)))
-            out = shard_map(
-                block_fn, mesh=mesh, in_specs=spec, out_specs=spec
-            )(g)
-            return out.reshape(x.shape)
+        # The worker axis (the grid's row axis) is blocked over devices.
+        sharded = _over_row_blocks(block_fn, mesh)
+        if topo.name != "grid":
+            return sharded
+        rows, cols = topo.grid_shape  # type: ignore[misc]
+
+        def fn(x):  # grid layout -> stencil -> back
+            g = x.reshape(rows, cols, *x.shape[1:])
+            return sharded(g).reshape(x.shape)
 
         return fn
 
@@ -203,6 +212,11 @@ def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
 # ISSUE 30); the only cross-device traffic is
 # the halo rows — O(boundary · d) per device per round, independent of N
 # for ring/torus/chain and O(E/P² · d) per rotation for Erdős–Rényi.
+# A block whose neighbor table is a ring's needs no table at all: its
+# plain mixing is two row shifts with the two boundary rows patched from
+# the ppermuted halo (``make_halo_mixing_op``'s ``halo_shift``, PR 35),
+# because the chip prices a gather by its indices. The fault, compressed
+# and robust halo layers below still address a ring through the tables.
 # Single-process multi-device (the closures capture sharded tables, which
 # multi-process jax forbids); on CPU hosts simulate the mesh via
 # XLA_FLAGS=--xla_force_host_platform_device_count=P.
@@ -318,6 +332,55 @@ def make_halo_exchange(
 def make_halo_mixing_op(
     topo: Topology, mesh: Mesh, dtype=jnp.float32, *, overlap: str = "off"
 ) -> MixingOp:
+    """The worker mesh's mixing operator: how a shard reaches its
+    neighbours' rows is read off the neighbor table, by no option and no
+    test of the topology's name (the rule PR 33 wrote for the unsharded
+    fault layer, ``topology._table_is_a_ring``). The ``dopt.run`` root's
+    ``mixing`` says which form a call took.
+
+    ``halo_shift`` — the table IS a ring's: a block's neighbours are its
+    own rows one up and one down, and its two boundary rows' neighbours
+    arrive by two one-row ``ppermute``s (``_ring_block_mix``, the body
+    ``mixing_impl='shard_map'`` runs too), ``w · (x + left + right)`` with
+    w = 1/3 as the one-chip stencil writes it, on the stack in the rank
+    the scan carries. No ``HaloExchange``, no per-shard neighbor table
+    and no weights table is built or closed over: at 262,144 rows a
+    device the gather form's ``s32[4, 262144, 2]`` table was 0.54 GB of
+    every chip's executable, and its 524,288-index row gather 5.2 of
+    25.3 ms an iteration where the shifts are 0.3 on one chip (PERF.md
+    sections 5 and 6, PR 35). The permutes depend on nothing local, so
+    ``overlap`` has nothing to reorder: 'off' and 'double_buffer' are
+    one program here.
+
+    ``halo_gather`` — every other table (chain, torus, Erdős–Rényi, a
+    ring whose slots are ordered another way): ``_make_halo_gather_mixing_op``.
+    """
+    if topo.directed:
+        raise ValueError(
+            "halo gather mixing is undirected-only (MH weights per slot); "
+            f"directed topology {topo.name!r} has no gather form"
+        )
+    if overlap not in ("off", "double_buffer"):
+        raise ValueError(f"Unknown halo overlap mode: {overlap!r}")
+    n_devices = mesh.shape[WORKER_AXIS]
+    if topo.n % n_devices:
+        raise ValueError(
+            f"n_workers={topo.n} not divisible by mesh size {n_devices}"
+        )
+    if not _table_is_a_ring(topo):
+        return _make_halo_gather_mixing_op(topo, mesh, dtype, overlap=overlap)
+    mix_block, nbr_block = _ring_block_mix(WORKER_AXIS, n_devices, 1.0 / 3.0)
+    return MixingOp(
+        topo.name,
+        "halo_shift",
+        _over_row_blocks(mix_block, mesh),
+        _over_row_blocks(nbr_block, mesh),
+    )
+
+
+def _make_halo_gather_mixing_op(
+    topo: Topology, mesh: Mesh, dtype=jnp.float32, *, overlap: str = "off"
+) -> MixingOp:
     """Sharded twin of ``ops/mixing.py`` impl='gather' over real collectives.
 
     MH weights are the identical per-slot values ``gather_mixing_weights``
@@ -326,11 +389,9 @@ def make_halo_mixing_op(
     the halo-extended buffer, so the two forms are equal to the last
     place or two (two executables: see the section comment above) — with
     boundary rows arriving over ICI as ppermute traffic instead of being
-    addressed in one device's HBM (the compiled-HLO payload test in
-    tests/test_worker_mesh.py pins ring rounds to 2·d floats per device).
-    On the chip the gather is the cost: 7.0 of 31.6 ms an iteration at
-    262,144 rows a device, against the stencil's 1.4 on one chip
-    (PERF.md sections 5 and 6, PR 30).
+    addressed in one device's HBM. On the chip the gather is the cost
+    (priced by its indices, whatever each fetches), which is why a ring's
+    table never comes here (``make_halo_mixing_op``).
 
     ``overlap='double_buffer'`` (config.halo_overlap; docs/PERF.md §17)
     restructures ``apply`` into the stencil latency-hiding form: the
@@ -343,13 +404,6 @@ def make_halo_mixing_op(
     bitwise vs off; 'off' is byte-for-byte the PR 11 body, which is the
     gate tests/test_worker_mesh.py pins.
     """
-    if topo.directed:
-        raise ValueError(
-            "halo gather mixing is undirected-only (MH weights per slot); "
-            f"directed topology {topo.name!r} has no gather form"
-        )
-    if overlap not in ("off", "double_buffer"):
-        raise ValueError(f"Unknown halo overlap mode: {overlap!r}")
     hx = make_halo_exchange(topo, mesh, overlap=overlap)
     nbr_idx, nbr_mask = neighbor_tables_for(topo)
     w_nbr_np, w_self_np = gather_mixing_weights(

@@ -150,7 +150,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_optimization_tpu.observability import device_scopes
-from distributed_optimization_tpu.parallel.topology import Topology
+from distributed_optimization_tpu.parallel.topology import (
+    Topology,
+    _table_is_a_ring,
+)
 
 # Allowed rejoin policies after a crash-recovery outage (config and CLI
 # derive from this constant): 'frozen' resumes the stale pre-crash state,
@@ -1211,26 +1214,6 @@ def make_faulty_mixing(
         rejoin_restart=rejoin_restart,
         participation_active=participation_active,
         timeline=timeline,
-    )
-
-
-def _table_is_a_ring(topo: Topology) -> bool:
-    """Whether the neighbor table IS a ring's: n >= 3, every row the two
-    neighbours (i ± 1) mod n in ascending order, every slot live. Read off
-    the table, not the topology's name: whatever graph has this table is
-    mixed by shifts (``_make_shift_faulty_mixing``), every other one by
-    gathers. Host arrays, a few ms at 2^18 workers."""
-    from distributed_optimization_tpu.parallel.topology import (
-        _ring_neighbor_tables,
-    )
-
-    n = topo.n
-    if n < 3 or topo.nbr_idx.shape != (n, 2):
-        return False
-    nbr, mask = _ring_neighbor_tables(n)
-    return bool(
-        np.array_equal(topo.nbr_mask, mask)
-        and np.array_equal(topo.nbr_idx, nbr)
     )
 
 

@@ -820,6 +820,26 @@ def neighbor_tables_for(topo: Topology) -> tuple[np.ndarray, np.ndarray]:
     return neighbor_table(topo.adjacency)
 
 
+def _table_is_a_ring(topo: Topology) -> bool:
+    """Whether the neighbor table IS a ring's: n >= 3, every row the two
+    neighbours (i ± 1) mod n in ascending order, every slot live. Read off
+    the table, not the topology's name: whatever graph has this table is
+    mixed by shifts — the unsharded fault layer
+    (``faults._make_shift_faulty_mixing``) and the worker mesh's halo
+    mixing (``collectives.make_halo_mixing_op``) ask this one rule —
+    every other one by gathers. Host arrays, a few ms at 2^18 workers."""
+    n = topo.n
+    if n < 3:
+        return False
+    nbr_idx, nbr_mask = neighbor_tables_for(topo)
+    if nbr_idx.shape != (n, 2):
+        return False
+    nbr, mask = _ring_neighbor_tables(n)
+    return bool(
+        np.array_equal(nbr_mask, mask) and np.array_equal(nbr_idx, nbr)
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class HaloStep:
     """One ppermute rotation of the halo exchange (devices p → (p+r) mod P).

@@ -77,7 +77,7 @@ def test_the_sharded_program_is_within_the_cells_limits(cell, seed):
     config, traffic = cell
     result, args, ref = run_program(config, traffic, seed)
     assert result.history.mesh_devices == 4
-    assert (args["mesh"], args["mixing"]) == ("4x16", "halo_gather")
+    assert (args["mesh"], args["mixing"]) == ("4x16", "halo_shift")
     assert args["placement"] == "mesh4:direct"
     assert args["ici_bytes_per_round"] == 2 * 81 * 4
     said = []

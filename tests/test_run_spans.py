@@ -162,8 +162,9 @@ def test_root_says_how_the_shards_were_stacked_and_placed(
 
 def test_root_names_the_worker_mesh_and_its_halo(setup):
     """ISSUE 30: under ``worker_mesh`` the root says over how many devices
-    the workers lie and how many each holds, that mixing is the halo
-    gather, and the fullest device's boundary rows and bytes over ICI a
+    the workers lie and how many each holds, which form the halo mixing
+    took (read off the neighbor table: shifts on a ring's, ISSUE 35), and
+    the fullest device's boundary rows and bytes over ICI a
     round, the numbers of ``telemetry.ici_summary`` (and so of the
     ``dopt_worker_mesh_*`` gauges); the upload's ``bytes`` stay the total,
     and no child span is added."""
@@ -176,7 +177,7 @@ def test_root_names_the_worker_mesh_and_its_halo(setup):
     ici = ici_summary(cfg, d_features=ds.n_features)
     assert args["mesh"] == f"4x{cfg.n_workers // 4}"
     assert args["placement"] == "mesh4:direct"
-    assert args["mixing"] == "halo_gather"
+    assert args["mixing"] == "halo_shift"  # a ring's table (ISSUE 35)
     # A ring block has two boundary rows whatever its length.
     assert args["halo_rows"] == max(ici["halo_rows_per_device"]) == 2
     assert args["ici_bytes_per_round"] == max(

@@ -7,10 +7,13 @@ Three layers, mirroring the tentpole's contract:
    the global gather — ``ext[local_nbr]`` must reproduce ``x[nbr_idx]``
    row for row — and the shard-local index map is checked against the
    dense realized adjacency.
-2. **Halo collectives**: ``make_halo_mixing_op`` is bitwise the
-   single-device gather operator under jit, and the compiled HLO of a
-   ring round ships exactly the boundary rows per device (2·d floats,
-   independent of N) with no all-gather of the [N, d] state.
+2. **Halo collectives**: ``make_halo_mixing_op`` is the single-device
+   gather operator under jit (to a few units of the rows' scale), by
+   shifts where the neighbor table is a ring's and through the per-shard
+   tables on every other (ISSUE 35: the two forms against each other, and
+   which table takes which), and the compiled HLO of a ring round ships
+   exactly the boundary rows per device (2·d floats, independent of N)
+   with no gather, no index table and no all-gather of the [N, d] state.
 3. **End-to-end parity**: sharded-vs-unsharded trajectories through the
    real backend at matched N — plain ring/ER, gradient tracking, churn,
    participation, Byzantine screening, checkpoint/resume — bitwise on the
@@ -22,6 +25,7 @@ is rejected with the missing piece named, and auto/explicit mesh sizing
 agrees (the ``make_worker_mesh`` grid-rows satellite).
 """
 
+import dataclasses
 import re
 
 import jax
@@ -240,6 +244,7 @@ def test_halo_ring_round_ships_boundary_rows_only():
     of [1, d] each — 2·d floats per device, independent of N — and no
     all-gather of the [N, d] state (PAPER.md's real-collective claim)."""
     from distributed_optimization_tpu.parallel.collectives import (
+        _make_halo_gather_mixing_op,
         make_halo_mixing_op,
     )
     from distributed_optimization_tpu.parallel.mesh import shard_over_workers
@@ -253,7 +258,110 @@ def test_halo_ring_round_ships_boundary_rows_only():
     payloads = _permute_payload_floats(hlo)
     assert len(payloads) == 2, f"expected 2 boundary permutes, got {payloads}"
     assert sum(payloads) == 2 * d
+    assert len(re.findall(rf"f32\[1,{d}\]\S* collective-permute", hlo)) == 2
     assert "all-gather" not in hlo
+    # A ring's table is read by shifts (ISSUE 35): no row gather, and no
+    # per-shard neighbor table baked into the executable.
+    assert op.impl == "halo_shift"
+    assert not re.search(r"\bgather\(", hlo)
+    assert not re.search(r"s32\[[\d,]*,2\]", hlo)
+    # ... both of which the gather form of the same round has.
+    table_op = _make_halo_gather_mixing_op(topo, mesh, dtype=jnp.float32)
+    table_hlo = jax.jit(table_op.apply).lower(x).compile().as_text()
+    assert re.search(r"\bgather\(", table_hlo)
+    assert re.search(r"s32\[[\d,]*,2\]", table_hlo)
+
+
+def _swapped_ring(n):
+    """A ring whose every row lists its two neighbours the other way
+    round: the same graph, not a ring's table."""
+    topo = build_topology("ring", n, impl="neighbor")
+    return dataclasses.replace(topo, nbr_idx=topo.nbr_idx[:, ::-1].copy())
+
+
+HALO_FORMS = {
+    "ring": (lambda n: build_topology("ring", n, impl="neighbor"),
+             "halo_shift"),
+    "chain": (lambda n: build_topology("chain", n, impl="neighbor"),
+              "halo_gather"),
+    "grid": (lambda n: build_topology("grid", n, impl="neighbor"),
+             "halo_gather"),
+    "erdos_renyi": (
+        lambda n: build_topology("erdos_renyi", n, seed=3, impl="neighbor"),
+        "halo_gather"),
+    "ring_slots_swapped": (_swapped_ring, "halo_gather"),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(HALO_FORMS))
+def test_halo_form_is_read_off_the_neighbor_table(rng, graph):
+    """Shifts where the table IS a ring's, the per-shard tables on every
+    other (a ring listed another way among them): by the table, by no
+    option and not by the topology's name. Either way the round is the
+    single-device gather operator's."""
+    from distributed_optimization_tpu.ops.mixing import make_mixing_op
+    from distributed_optimization_tpu.parallel.collectives import (
+        make_halo_mixing_op,
+    )
+
+    build, form = HALO_FORMS[graph]
+    topo = build(16)
+    op = make_halo_mixing_op(topo, _mesh(4), dtype=jnp.float32)
+    assert op.impl == form
+    x = jnp.asarray(rng.normal(size=(16, 7)).astype(np.float32))
+    want = jax.jit(make_mixing_op(topo, impl="gather").apply)(x)
+    assert_ulps_of_scale(jax.jit(op.apply)(x), want, 4)
+
+
+@pytest.mark.parametrize("stack", [(7,), (7, 3)], ids=["Nx7", "Nx7x3"])
+@pytest.mark.parametrize("shards", [2, 4, 8])
+@pytest.mark.parametrize("form", ["apply", "neighbor_sum"])
+def test_halo_shift_is_the_halo_gather_round(rng, form, shards, stack):
+    """One round of the two forms built on the same ring (the gather forced
+    through its private builder: no option reaches it), on the stack in
+    the rank the scan carries: two programs of one arithmetic, to a few
+    float32 units of the rows' scale. bfloat16 arithmetic is far outside."""
+    from distributed_optimization_tpu.parallel.collectives import (
+        _make_halo_gather_mixing_op,
+        make_halo_mixing_op,
+    )
+
+    n = 16
+    topo = build_topology("ring", n, impl="neighbor")
+    mesh = _mesh(shards)
+    shift = make_halo_mixing_op(topo, mesh, dtype=jnp.float32)
+    gather = _make_halo_gather_mixing_op(topo, mesh, dtype=jnp.float32)
+    assert (shift.impl, gather.impl) == ("halo_shift", "halo_gather")
+    x = jnp.asarray(rng.normal(size=(n, *stack)).astype(np.float32))
+    want = np.asarray(jax.jit(getattr(gather, form))(x))
+    got = np.asarray(jax.jit(getattr(shift, form))(x))
+    assert got.shape == want.shape == (n, *stack) and got.dtype == np.float32
+    assert_ulps_of_scale(got, want, 4)
+    rounded = jax.jit(getattr(shift, form))(x.astype(jnp.bfloat16))
+    assert rounded.dtype == jnp.bfloat16
+    with pytest.raises(AssertionError):
+        assert_ulps_of_scale(
+            np.asarray(rounded.astype(jnp.float32)), want, 4)
+
+
+def test_halo_overlap_on_a_ring_is_one_program(problem):
+    """The shift form's permutes depend on nothing local, so there is
+    nothing for ``halo_overlap`` to reorder: 'off' and 'double_buffer' on
+    a ring are one trajectory, bit for bit (the gather form's two bodies
+    sum in another order: ``_make_halo_gather_mixing_op``)."""
+    from distributed_optimization_tpu.backends import jax_backend
+
+    ds, f_opt = problem
+    off, dbl = (
+        jax_backend.run(
+            make_cfg(worker_mesh=4, halo_overlap=mode), ds, f_opt,
+            return_state=True)
+        for mode in ("off", "double_buffer"))
+    np.testing.assert_array_equal(off.final_models, dbl.final_models)
+    np.testing.assert_array_equal(
+        off.history.objective, dbl.history.objective)
+    np.testing.assert_array_equal(
+        off.history.consensus_error, dbl.history.consensus_error)
 
 
 def test_halo_mixing_rejects_directed():
@@ -329,14 +437,15 @@ def test_e2e_stragglers_bitwise(problem, graph):
 def test_e2e_byzantine_ring_bitwise(problem, rule):
     """All three robust rules screen through the halo on the ring as they
     do on one device (corrupted boundary rows arrive over ppermute like
-    benign traffic): trimmed mean and clipped gossip bitwise; the median's
-    two programs round its base mix their own way (``MODEL_ULPS``)."""
+    benign traffic). The screened mix's other branch is the base mix,
+    which on the mesh reads a ring by shifts (ISSUE 35) and on one device
+    here through the neighbor table: two programs of one arithmetic
+    (``MODEL_ULPS``), as the fault-free ring is."""
     r_u, r_s = run_pair(
         problem, attack="sign_flip", n_byzantine=1, aggregation=rule,
         robust_b=1, robust_impl="gather",
     )
-    assert_parity(
-        r_u, r_s, models_ulps=MODEL_ULPS if rule == "median" else None)
+    assert_parity(r_u, r_s, models_ulps=MODEL_ULPS)
 
 
 def test_e2e_byzantine_trimmed_mean_er_within_convention(problem):
