@@ -1,0 +1,26 @@
+"""Share of the chip's memory bandwidth that the gossip's COMPULSORY bytes
+reach while the gossip runs: the bytes a round has to move, from shapes
+(``benchmark/flops/<name>.py``, named by the configuration's ``gossip_bytes``),
+times the iterations traced, over the device seconds under ``dopt.gossip``
+(what ``scan.gossip_us_per_iter`` reads) times the peak from
+``benchmark/peaks.json``. Says how far the gather is from a pass over the
+models: the denominator is everything the program does to mix, the
+numerator only what any program must, so it cannot pass 100.
+
+Where the gossip has no device time to read (the CPU, a program without
+scopes) it reads 0.0, a number."""
+
+import importlib
+
+from benchmark import scope_reduce
+
+
+def read(trace, facts, config):
+    if trace is None or not config.get("gossip_bytes") or not facts["iterations"]:
+        return 0.0
+    us = scope_reduce.us_per_iter(trace, facts, config, "gossip")
+    if not us:  # None without a trace, 0 without a scope to bill
+        return 0.0
+    rule = importlib.import_module(f"benchmark.flops.{config['gossip_bytes']}")
+    peak = facts["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * rule.per_round_bytes(config) / (us * 1e-6 * peak)

@@ -62,6 +62,18 @@ without a TPU it exits before doing any work. Segments:
    ``memory_analysis().temp_size_in_bytes``. What the CPU cannot see: whether
    a trace event's name is the compiled text's instruction.
 
+8. One round of the gather mixing at the drawn-graph cell's size (ISSUE 36):
+   the connected Erdős–Rényi graph of 2^18 workers at mean degree 12 that
+   topology seed 7 draws, through ``make_mixing_op``'s slot-major tables
+   handed to a jitted round as ARGUMENTS, against the benchmark reference's
+   edge-list form (``benchmark/reference/dsgd_er.py``: its own draw of the
+   graph, edge for edge the table's, and two scatter-adds) within
+   ``GATHER_ROUND_ULPS`` units of the rows' scale; and the device's bytes
+   after the rounds: the rows, the result and the tables once, so no second
+   copy of a table and none among the executable's constants. What the CPU
+   cannot see: what a ``[30, 2^18]`` table takes in the device's tiles, and
+   whether the executable keeps one.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -307,6 +319,89 @@ def halo_forms_segment(device: dict, *, n_workers: int = 1 << 20,
     print(f"[chip_smoke] halo forms: the gather form keeps "
           f"{min(held['halo_gather']) - max(held['halo_shift'])} bytes more "
           "on every chip after its calls", flush=True)
+
+
+# The gather round against the edge-list form: the one sums a row's 30 slots
+# in the table's order onto w_self·x, the other adds w_e (x_j − x_i) edge by
+# edge onto x; read 1.9 units on the chip (PR 36).
+GATHER_ROUND_ULPS = 16
+# What the device may hold after the rounds beside the rows, the result and
+# the tables once: under half of one table (33.5 MB in the device's tiles).
+GATHER_ROUND_ROOM = 16_000_000
+
+
+def gather_round_segment(device: dict, *, n_workers: int = 1 << 18,
+                         d: int = 81, mean_degree: int = 12,
+                         topology_seed: int = 7) -> None:
+    """One round of the program's gather mixing on the drawn-graph cell's
+    graph against the reference's edge-list form, and what the rounds leave
+    on the device (ISSUE 36). Bytes in use are read against the segment's
+    own start."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import dsgd_er
+    from distributed_optimization_tpu.ops.mixing import make_mixing_op
+    from distributed_optimization_tpu.parallel.topology import cached_topology
+
+    dev = jax.devices()[0]
+
+    def in_use():
+        return int(dev.memory_stats()["bytes_in_use"])
+
+    before = in_use()
+    p = mean_degree / n_workers
+    topo, _ = cached_topology(
+        "erdos_renyi", n_workers, erdos_renyi_p=p, seed=topology_seed,
+        impl="neighbor", sampler="sparse")
+    op = make_mixing_op(topo, impl="gather")
+    table_bytes = sum(
+        leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(op.tables))
+    x = jax.block_until_ready(jax.random.normal(
+        jax.random.key(36), (n_workers, d), jnp.float32))
+    # As the scan is handed them: arguments, the operators bound inside.
+    mixed = {
+        form: jax.block_until_ready(jax.jit(
+            lambda x, tb, form=form: getattr(op.bind(tb), form)(x)
+        )(x, op.tables))
+        for form in ("apply", "neighbor_sum")
+    }
+    rows_bytes = x.on_device_size_in_bytes()
+    held = in_use() - before
+    print(f"[chip_smoke] gather round: k_max={topo.nbr_idx.shape[1]} "
+          f"edges={int(topo.degrees.sum()) // 2} table_bytes={table_bytes} "
+          f"rows_bytes={rows_bytes} held over the segment's start={held}",
+          flush=True)
+    _check(held <= table_bytes + 3 * rows_bytes + GATHER_ROUND_ROOM,
+           "after its rounds the device holds the rows, the two results and "
+           "the tables once: no table twice, none among the constants")
+    src, dst, tries = dsgd_er.draw_edges(n_workers, p, topology_seed)
+    live = topo.nbr_mask
+    upper = live & (topo.nbr_idx > np.arange(n_workers)[:, None])
+    _check(np.array_equal(np.nonzero(upper)[0], src)
+           and np.array_equal(topo.nbr_idx[upper], dst),
+           "the reference draws the table's graph edge for edge")
+    graph = dsgd_er.edge_blocks(
+        src, dst, dsgd_er.edge_weights(src, dst, n_workers))
+    from scipy import sparse
+
+    ends = sparse.coo_matrix(
+        (np.ones(src.size), (src, dst)), shape=(n_workers, n_workers)).tocsr()
+    want = {
+        # the reference's own round, on the chip, float32
+        "apply": np.asarray(jax.jit(dsgd_er.mix)(x, *graph)),
+        # the adjacency's product, on the host, float64
+        "neighbor_sum": (ends + ends.T) @ np.asarray(x, np.float64),
+    }
+    for form, rows in want.items():
+        unit = float(np.finfo(np.float32).eps) * float(np.max(np.abs(rows)))
+        gap = float(np.max(np.abs(np.asarray(mixed[form]) - rows))) / unit
+        print(f"[chip_smoke] gather round: {form} against the edge list "
+              f"({tries} tr{'y' if tries == 1 else 'ies'}), worst gap "
+              f"{gap:.2f} units of the rows' scale", flush=True)
+        _check(gap <= GATHER_ROUND_ULPS,
+               f"the gather's {form} is the edge list's within "
+               f"{GATHER_ROUND_ULPS} units of the rows' scale")
 
 
 # The benchmark's GLM limits (benchmark/configs/glm81_ring262k.json): worst
@@ -602,6 +697,7 @@ def main() -> int:
     glm_segment(device)
     softmax_segment(device)
     reference_segment(device)
+    gather_round_segment(device)
     if device["count"] >= 4:
         four_chip_segment(device)
         halo_forms_segment(device)

@@ -70,6 +70,7 @@ from distributed_optimization_tpu.parallel.faults import (
     make_round_robin_mixing,
 )
 from distributed_optimization_tpu.parallel import build_topology
+from distributed_optimization_tpu.parallel.topology import cached_topology
 from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     make_worker_mesh,
@@ -362,6 +363,9 @@ def _make_step_eval(p: _StepPieces, data):
         # The gather fault layer over the tables this program was handed,
         # not over constants of its own.
         faulty = faulty.bind(data["faults"])
+    if "mixing" in data:
+        # The gather mixing likewise (ISSUE 36).
+        mix_op = mix_op.bind(data["mixing"])
 
     # Full-batch fast path: sampling b >= L rows without replacement IS
     # the whole shard with 1/n_i weights (the reference's b=min(b, n_i)
@@ -699,6 +703,24 @@ def _fault_root_args(config, faulty, tables) -> dict:
     if faulty.addressing is not None:
         args["fault_mixing"] = faulty.addressing
     return args
+
+
+def _gather_root_args(topo, tables) -> dict:
+    """What the ``dopt.run`` root says of a call whose static graph mixes
+    through neighbor tables: the table's width, the graph's edges, the
+    share of the table's slots that hold one (the rest are padding, gathered
+    and weighed 0) and what the tables the scan was handed take on the
+    device."""
+    k_max = int(tables["nbr"].shape[0])
+    live = float(np.asarray(topo.degrees).sum())
+    return {
+        "k_max": k_max,
+        "edges": int(live // 2),
+        "live_slot_share": live / (topo.n * k_max),
+        "table_bytes": float(sum(
+            leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
+        )),
+    }
 
 
 def _build_faulty(config, algo, topo, T, *, drop_prob=None, keys=None,
@@ -1478,12 +1500,21 @@ def _run(
     halo_mesh = None
     compressed_mix = None
     if algo.is_decentralized:
-        topo = build_topology(
+        # ``topology``: the graph's making, or its finding in the process's
+        # cache (``cache`` = ``miss`` / ``hit``): a drawn graph at scale is
+        # seconds of host code, paid once a structural identity.
+        spans.enter("topology")
+        topo, topo_hit = cached_topology(
             config.topology, n, erdos_renyi_p=config.erdos_renyi_p,
             seed=config.resolved_topology_seed(),
             impl=config.resolved_topology_impl(),
             sampler=config.resolved_topology_sampler(),
         )
+        # The power iteration of a drawn matrix-free graph is part of its
+        # making: here, once, not in every later ``prepare``.
+        spectral_gap = topo.spectral_gap
+        spans.note(cache="hit" if topo_hit else "miss")
+        spans.enter("prepare")
         if config.worker_mesh >= 2:
             # Sharded worker mesh (ISSUE-11 tentpole, docs/PERF.md §16):
             # exactly config.worker_mesh devices, contiguous row blocks.
@@ -1576,7 +1607,6 @@ def _run(
             floats_per_iter = decentralized_floats_per_iteration(
                 topo, d_model, algo.gossip_rounds
             )
-        spectral_gap = topo.spectral_gap
         time_varying = (
             config.edge_drop_prob > 0.0
             or config.straggler_prob > 0.0
@@ -1620,6 +1650,17 @@ def _run(
                 fault_tables = replicate(mesh, faulty.tables)
             spans.note_root(**_fault_root_args(config, faulty, fault_tables))
             spans.enter("prepare")
+        mixing_tables = None
+        if halo_mesh is None:
+            # How the static graph mixes: the mechanism's engagement
+            # counter, as the mesh branch below says it for the halo forms.
+            spans.note_root(mixing=mix_op.impl)
+            if faulty is None and mix_op.tables is not None:
+                # The gather form's tables are ARGUMENTS of the scan
+                # (``data['mixing']``), never constants of it; under faults
+                # the fault layer mixes, over tables of its own.
+                mixing_tables = replicate(mesh, mix_op.tables)
+                spans.note_root(**_gather_root_args(topo, mixing_tables))
         adversary, byz_mix, robust_activity, fused_robust_step = (
             _bind_byzantine(
                 config, algo, topo, faulty, mix_op, halo_mesh=halo_mesh,
@@ -1700,6 +1741,7 @@ def _run(
         static_degree_sum = 0.0
         topo = None
         mix_op = None
+        mixing_tables = None
         faulty = None
         fault_tables = None
         edge_payload = None
@@ -1799,6 +1841,8 @@ def _run(
         # timeline) too: a closed-over [horizon, N] leaf is a constant of
         # the executable, in the device's tiles (ROADMAP A9).
         data_args["faults"] = fault_tables
+    if mixing_tables is not None:
+        data_args["mixing"] = mixing_tables
 
     track_consensus = (
         collect_metrics and algo.is_decentralized and config.record_consensus

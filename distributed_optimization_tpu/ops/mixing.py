@@ -34,14 +34,32 @@ same linear operator has three interchangeable compiled forms:
   ``jax.lax.ppermute``/``psum`` (see ``parallel/collectives.py``), for when
   manual control over the collective schedule is wanted.
 - ``gather`` (round 9): the matrix-free k_max-bounded form over padded
-  ``[N, k_max]`` neighbor tables — O(N·k_max·d), no [N, N] object
-  anywhere; the route that lifts the worker axis to N ≥ 10k. Its SHARDED
-  twin is ``parallel/collectives.make_halo_mixing_op`` (impl tag
+  neighbor tables — O(N·k_max·d), no [N, N] object anywhere; the route
+  that lifts the worker axis to N ≥ 10k, and the one every graph that is
+  not a shift takes there (Erdős–Rényi, chain). The tables are kept
+  SLOT-MAJOR, ``nbr`` / ``w_nbr`` ``[k_max, N]`` and ``w_self`` ``[N]``,
+  in ONE pytree, ``MixingOp.tables``, and the operators are built over a
+  copy of it by ``MixingOp.bind``: ``jax_backend._run`` hands the tables
+  to its scan as ARGUMENTS (``data['mixing']``) and rebinds, so they are
+  never constants of the executable (a node-major ``s32[262144, 30]``
+  constant is 134 MB in the TPU's (8, 128) tiles, slot-major 33 MB as an
+  argument; PERF.md section 6, PR 36). A round accumulates SLOT BY SLOT,
+  ``w_self·x + ((w_nbr[0]·x[nbr[0]] + w_nbr[1]·x[nbr[1]]) + …)``: one
+  row gather of ``[N, ...]`` a slot, never the ``[N, k_max, ...]`` stack
+  (at N = 2^18, k_max = 30, d = 81 that stack and its product are 8.3 GB
+  of the chip's tiles), so a round's temporaries do not grow with k_max:
+  the first slot's term, then one ``lax.scan`` over the rest whose body
+  holds one gather, for every table (``slot_sum``, which the sharded
+  twin's blocks call too, so the two keep one per-row op sequence);
+  padded slots point at the row itself and weigh 0, and ``neighbor_sum``
+  reads the mask off the weights (a live slot's is positive), so no mask
+  table exists. A caller that binds nothing gets the operators
+  over ``tables`` as they are (constants of whatever it traces). Its
+  SHARDED twin is ``parallel/collectives.make_halo_mixing_op`` (impl tag
   ``'halo_gather'``, the ``worker_mesh`` axis, docs/PERF.md §16): the
-  same per-row op sequence with the worker rows split over a device mesh
-  and boundary rows ppermute-fetched at shard edges — bitwise this
-  operator at matched N, selected by the backend (not here) because it
-  needs the device mesh.
+  same weights over per-shard tables with the worker rows split over a
+  device mesh and boundary rows ppermute-fetched at shard edges —
+  selected by the backend (not here) because it needs the device mesh.
 
 All forms agree to floating-point tolerance; property tests check stencil
 and shard_map forms against the dense matrix.
@@ -50,7 +68,7 @@ and shard_map forms against the dense matrix.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +85,33 @@ from distributed_optimization_tpu.parallel.topology import (
 MixFn = Callable[[jax.Array], jax.Array]
 
 
+def _col(v: jax.Array, x: jax.Array) -> jax.Array:
+    return v.reshape((-1,) + (1,) * (x.ndim - 1))
+
+
+def slot_sum(src: jax.Array, nbr: jax.Array, w_nbr: jax.Array,
+             weight=None) -> jax.Array:
+    """Σ_s weight(w_nbr[s])·src[nbr[s]] over slot-major ``[k_max, n]``
+    tables, slot by slot in the table's order: ONE row gather of
+    ``[n, ...]`` in flight, the ``[n, k_max, ...]`` stack of every
+    neighbour's row never made. The first slot's term, then a loop over
+    the rest, whose body XLA cannot reorder into k_max gathers held at
+    once. ``src`` may hold more rows than ``n`` (a shard's block with its
+    halo behind it); ``weight`` maps a slot's weights (``neighbor_sum``'s
+    mask), the identity where None."""
+
+    def term(idx, w):
+        return _col(w if weight is None else weight(w), src) * src[idx]
+
+    def add_slot(acc, slot):
+        return acc + term(*slot), None
+
+    total, _ = jax.lax.scan(
+        add_slot, term(nbr[0], w_nbr[0]), (nbr[1:], w_nbr[1:])
+    )
+    return total
+
+
 @dataclasses.dataclass(frozen=True)
 class MixingOp:
     """Jittable linear operators attached to one topology.
@@ -81,6 +126,14 @@ class MixingOp:
     impl: str
     apply: MixFn
     neighbor_sum: MixFn
+    # The gather form only, else None: ``tables`` is the pytree of every
+    # device array its operators read (slot-major, module docstring), and
+    # ``bind(tables_like)`` rebuilds this op over a same-structured pytree
+    # — the leaves a compiled program receives ``tables`` as.
+    # ``jax_backend._run`` hands the tables to its scan as arguments and
+    # rebinds inside it; ``FaultyMixing`` has the same pair.
+    tables: Optional[dict] = None
+    bind: Optional[Callable[[dict], "MixingOp"]] = None
 
 
 def _supports_stencil(topo: Topology) -> bool:
@@ -156,27 +209,32 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
         w_nbr_np, w_self_np = gather_mixing_weights(
             nbr_idx_np, nbr_mask_np, topo.degrees
         )
-        nbr = jnp.asarray(nbr_idx_np, dtype=jnp.int32)
-        mask = jnp.asarray(nbr_mask_np, dtype=dtype)
-        w_nbr = jnp.asarray(w_nbr_np, dtype=dtype)
-        w_self = jnp.asarray(w_self_np, dtype=dtype)
+        tables = {
+            "nbr": jnp.asarray(nbr_idx_np.T, dtype=jnp.int32),
+            "w_nbr": jnp.asarray(w_nbr_np.T, dtype=dtype),
+            "w_self": jnp.asarray(w_self_np, dtype=dtype),
+        }
+        name = topo.name
 
-        def _bshape(x: jax.Array):
-            return (x.shape[0], nbr.shape[1]) + (1,) * (x.ndim - 1)
+        def bind(tb) -> MixingOp:
+            nbr, w_nbr, w_self = tb["nbr"], tb["w_nbr"], tb["w_self"]
 
-        def apply(x: jax.Array) -> jax.Array:
-            gathered = x[nbr]  # [N, k_max, ...]
-            out = w_self.reshape((-1,) + (1,) * (x.ndim - 1)) * x + jnp.sum(
-                w_nbr.reshape(_bshape(x)) * gathered, axis=1
+            def apply(x: jax.Array) -> jax.Array:
+                out = _col(w_self, x) * x + slot_sum(x, nbr, w_nbr)
+                return out.astype(x.dtype)
+
+            def neighbor_sum(x: jax.Array) -> jax.Array:
+                # A live slot's MH weight is 1/(1 + max degree) > 0 and a
+                # padded one's is 0: the mask, without a table of its own.
+                return slot_sum(
+                    x, nbr, w_nbr, lambda w: (w > 0).astype(x.dtype)
+                ).astype(x.dtype)
+
+            return MixingOp(
+                name, "gather", apply, neighbor_sum, tables=tables, bind=bind
             )
-            return out.astype(x.dtype)
 
-        def neighbor_sum(x: jax.Array) -> jax.Array:
-            return jnp.sum(
-                mask.reshape(_bshape(x)) * x[nbr], axis=1
-            ).astype(x.dtype)
-
-        return MixingOp(topo.name, "gather", apply, neighbor_sum)
+        return bind(tables)
 
     if impl == "pallas":
         # Hand-fused VMEM kernels (ops/pallas_kernels.py). Ring and

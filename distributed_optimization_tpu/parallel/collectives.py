@@ -34,7 +34,7 @@ import dataclasses
 
 import numpy as np
 
-from distributed_optimization_tpu.ops.mixing import MixingOp
+from distributed_optimization_tpu.ops.mixing import MixingOp, slot_sum
 from distributed_optimization_tpu.parallel.mesh import WORKER_AXIS
 from distributed_optimization_tpu.parallel.topology import (
     Topology,
@@ -384,9 +384,11 @@ def _make_halo_gather_mixing_op(
     """Sharded twin of ``ops/mixing.py`` impl='gather' over real collectives.
 
     MH weights are the identical per-slot values ``gather_mixing_weights``
-    derives (sharded per block); the apply/neighbor_sum bodies run the
-    identical per-row op sequence as the single-device gather operator on
-    the halo-extended buffer, so the two forms are equal to the last
+    derives (sharded per block, slot-major: ``_slot_major_blocks``); the
+    apply/neighbor_sum bodies call the single-device gather operator's own
+    ``ops.mixing.slot_sum`` on the halo-extended buffer (slot by slot, one
+    row gather in flight, no ``[S, k_max, d]`` stack: ISSUE 36), so the
+    two forms are equal to the last
     place or two (two executables: see the section comment above) — with
     boundary rows arriving over ICI as ppermute traffic instead of being
     addressed in one device's HBM. On the chip the gather is the cost
@@ -400,92 +402,89 @@ def _make_halo_gather_mixing_op(
     concurrently with independent compute on async backends), and the
     halo contributions are added last. The summation ORDER differs from
     the gather body (in-block slots before halo slots instead of slot
-    order), so double_buffer is a distinct structural program — NOT
-    bitwise vs off; 'off' is byte-for-byte the PR 11 body, which is the
-    gate tests/test_worker_mesh.py pins.
+    order: two ``slot_sum``s over the table, the halo's slots weighing 0
+    in the first and the block's in the second), so double_buffer is a
+    distinct structural program — NOT bitwise vs off; 'off' is the
+    one-device round's op sequence, which is the gate
+    tests/test_worker_mesh.py pins.
     """
     hx = make_halo_exchange(topo, mesh, overlap=overlap)
-    nbr_idx, nbr_mask = neighbor_tables_for(topo)
-    w_nbr_np, w_self_np = gather_mixing_weights(
-        nbr_idx, nbr_mask, topo.degrees
-    )
-    # Row-major [N, k_max] / [N] tables ride ``HaloExchange.run`` as
-    # ordinary row-sharded arrays (each body sees its [S, ...] block) —
-    # no second copy of the shard_map/exchange plumbing to keep in sync.
-    w_nbr = jnp.asarray(w_nbr_np, dtype=dtype)
-    w_self = jnp.asarray(w_self_np, dtype=dtype)
-    mask_d = jnp.asarray(nbr_mask, dtype=dtype)
+    nbr_sm, w_nbr, w_self = _slot_major_blocks(hx, topo, dtype)
+    S = hx.plan.shard_rows
 
+    # The slot-major blocks ride ``HaloExchange.run`` as ordinary arrays
+    # split on their leading (shard) axis: each body sees its
+    # ``[1, k_max, S]`` block — no second copy of the shard_map/exchange
+    # plumbing to keep in sync. The plan's own node-major table and mask
+    # go unread here.
     def apply(x: jax.Array) -> jax.Array:
-        def body(exchange, nbr_l, _mask_f32, wn, ws, xb):
-            gathered = exchange(xb)[nbr_l]  # [S, k_max, d]
-            out = ws[:, None] * xb + jnp.sum(
-                wn[:, :, None] * gathered, axis=1
-            )
+        def body(exchange, _nbr_l, _mask_f32, nb, wn, ws, xb):
+            out = ws[:, None] * xb + slot_sum(exchange(xb), nb[0], wn[0])
             return out.astype(xb.dtype)
 
         x2 = x.reshape(x.shape[0], -1)
-        return hx.run(body, w_nbr, w_self, x2).reshape(x.shape)
+        return hx.run(body, nbr_sm, w_nbr, w_self, x2).reshape(x.shape)
 
     def apply_overlap(x: jax.Array) -> jax.Array:
-        S = hx.plan.shard_rows
-        h_max = hx.plan.h_max
-        n_steps = len(hx.perms)
-        perms = hx.perms
-        P_ = jax.sharding.PartitionSpec
-
-        def shard_body(nbr_lb, wn, ws, xb, *steps):
-            sends = steps[:n_steps]
-            recvs = steps[n_steps:]
-            nbr_l = nbr_lb[0]
+        def body(exchange, _nbr_l, _mask_f32, nb, wn, ws, xb):
+            nb, wn = nb[0], wn[0]
             # Issue every boundary-row send before touching the local
-            # math: the downstream partial sum has no data dependence on
+            # math: the in-block partial sum has no data dependence on
             # the permutes, so an async backend's scheduler runs the
             # collectives concurrently with it (CPU single-stream ties).
-            got = [
-                jax.lax.ppermute(xb[s[0]], WORKER_AXIS, perm)
-                for perm, s in zip(perms, sends)
-            ]
-            in_block = nbr_l < S
-            wl = jnp.where(in_block, wn, jnp.zeros((), wn.dtype))
-            local = xb[jnp.where(in_block, nbr_l, 0)]
-            partial = ws[:, None] * xb + jnp.sum(
-                wl[:, :, None] * local, axis=1
+            halo = exchange(xb)[S:]
+            in_block = nb < S
+            none = jnp.zeros((), wn.dtype)
+            partial = ws[:, None] * xb + slot_sum(
+                xb, jnp.where(in_block, nb, 0), jnp.where(in_block, wn, none)
             )
-            halo = jnp.zeros((h_max + 1, xb.shape[-1]), xb.dtype)
-            for g, r in zip(got, recvs):
-                halo = halo.at[r[0]].set(g)
-            wh = jnp.where(in_block, jnp.zeros((), wn.dtype), wn)
-            hrows = halo[jnp.where(in_block, 0, nbr_l - S)]
-            out = partial + jnp.sum(wh[:, :, None] * hrows, axis=1)
+            out = partial + slot_sum(
+                halo, jnp.where(in_block, 0, nb - S),
+                jnp.where(in_block, none, wn),
+            )
             return out.astype(xb.dtype)
 
         x2 = x.reshape(x.shape[0], -1)
-        table_spec = P_(WORKER_AXIS, None, None)
-        step_spec = P_(WORKER_AXIS, None)
-        out = shard_map(
-            shard_body,
-            mesh=mesh,
-            in_specs=(table_spec, step_spec, P_(WORKER_AXIS),
-                      step_spec)
-            + tuple(step_spec for _ in range(2 * n_steps)),
-            out_specs=P_(WORKER_AXIS, None),
-        )(hx.nbr_l, w_nbr, w_self, x2, *hx.sends, *hx.recvs)
-        return out.reshape(x.shape)
+        return hx.run(body, nbr_sm, w_nbr, w_self, x2).reshape(x.shape)
 
     def neighbor_sum(x: jax.Array) -> jax.Array:
-        def body(exchange, nbr_l, _mask_f32, mb, xb):
-            out = jnp.sum(mb[:, :, None] * exchange(xb)[nbr_l], axis=1)
-            return out.astype(xb.dtype)
+        def body(exchange, _nbr_l, _mask_f32, nb, wn, xb):
+            # A live slot's weight is positive, a padded one's 0: the mask.
+            return slot_sum(
+                exchange(xb), nb[0], wn[0],
+                lambda w: (w > 0).astype(xb.dtype),
+            ).astype(xb.dtype)
 
         x2 = x.reshape(x.shape[0], -1)
-        return hx.run(body, mask_d, x2).reshape(x.shape)
+        return hx.run(body, nbr_sm, w_nbr, x2).reshape(x.shape)
 
     return MixingOp(
         topo.name,
         "halo_gather",
         apply_overlap if overlap == "double_buffer" else apply,
         neighbor_sum,
+    )
+
+
+def _slot_major_blocks(hx: HaloExchange, topo: Topology, dtype):
+    """A halo plan's shard-local neighbor table and the MH weights of
+    ``gather_mixing_weights`` as per-shard SLOT-MAJOR blocks, ``nbr`` s32
+    and ``w_nbr`` ``[P, k_max, S]`` and ``w_self`` ``[N]``: what
+    ``ops.mixing.slot_sum`` reads on one device, so that a block's round
+    is the one-device round's per-row op sequence."""
+    nbr_idx, nbr_mask = neighbor_tables_for(topo)
+    w_nbr_np, w_self_np = gather_mixing_weights(
+        nbr_idx, nbr_mask, topo.degrees
+    )
+    P_n, S = hx.n_shards, hx.plan.shard_rows
+
+    def blocks(table):
+        return np.asarray(table).reshape(P_n, S, -1).transpose(0, 2, 1)
+
+    return (
+        jnp.asarray(blocks(hx.plan.local_nbr), dtype=jnp.int32),
+        jnp.asarray(blocks(w_nbr_np), dtype=dtype),
+        jnp.asarray(w_self_np, dtype=dtype),
     )
 
 
@@ -528,13 +527,7 @@ def make_halo_compressed_mixing_op(topo: Topology, mesh: Mesh, dtype=jnp.float32
             f"slot); directed topology {topo.name!r} has no gather form"
         )
     hx = make_halo_exchange(topo, mesh)
-    nbr_idx, nbr_mask = neighbor_tables_for(topo)
-    w_nbr_np, w_self_np = gather_mixing_weights(
-        nbr_idx, nbr_mask, topo.degrees
-    )
-    w_nbr = jnp.asarray(w_nbr_np, dtype=dtype)
-    w_self = jnp.asarray(w_self_np, dtype=dtype)
-    S = hx.plan.shard_rows
+    nbr_sm, w_nbr, w_self = _slot_major_blocks(hx, topo, dtype)
     h_max = hx.plan.h_max
     n_steps = len(hx.perms)
     perms = hx.perms
@@ -546,7 +539,6 @@ def make_halo_compressed_mixing_op(topo: Topology, mesh: Mesh, dtype=jnp.float32
         def shard_body(nbr_lb, wn, ws, qb, xb, hb, *steps):
             sends = steps[:n_steps]
             recvs = steps[n_steps:]
-            nbr_l = nbr_lb[0]
             hnew = hb
             for perm, s, r in zip(perms, sends, recvs):
                 got = jax.lax.ppermute(qb[s[0]], WORKER_AXIS, perm)
@@ -555,9 +547,7 @@ def make_halo_compressed_mixing_op(topo: Topology, mesh: Mesh, dtype=jnp.float32
             # there have no defined order — zero it so nothing leaks.
             hnew = hnew.at[h_max].set(jnp.zeros((), hnew.dtype))
             ext = jnp.concatenate([xb, hnew], axis=0)
-            out = ws[:, None] * xb + jnp.sum(
-                wn[:, :, None] * ext[nbr_l], axis=1
-            )
+            out = ws[:, None] * xb + slot_sum(ext, nbr_lb[0], wn[0])
             return out.astype(xb.dtype), hnew
 
         q2 = q.reshape(q.shape[0], -1)
@@ -568,11 +558,11 @@ def make_halo_compressed_mixing_op(topo: Topology, mesh: Mesh, dtype=jnp.float32
         mixed, halo_new = shard_map(
             shard_body,
             mesh=mesh,
-            in_specs=(table_spec, step_spec, P_(WORKER_AXIS),
+            in_specs=(table_spec, table_spec, P_(WORKER_AXIS),
                       step_spec, step_spec, step_spec)
             + tuple(step_spec for _ in range(2 * n_steps)),
             out_specs=(P_(WORKER_AXIS, None), P_(WORKER_AXIS, None)),
-        )(hx.nbr_l, w_nbr, w_self, q2, x2, h2, *hx.sends, *hx.recvs)
+        )(nbr_sm, w_nbr, w_self, q2, x2, h2, *hx.sends, *hx.recvs)
         return mixed.reshape(xhat_new.shape), halo_new.reshape(halo.shape)
 
     compressed_mix.halo_rows = halo_rows

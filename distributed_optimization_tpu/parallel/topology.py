@@ -62,15 +62,21 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 import math
+import threading
 from typing import Optional
 
 import numpy as np
 
-# Mirrors config.NEIGHBOR_TOPOLOGIES / config.MATRIX_FREE_AUTO_N (config
-# stays import-light; the single source of the AUTO policy is config.py —
-# this module only needs to know which names have a constructor).
+from distributed_optimization_tpu.config import RANDOM_TOPOLOGIES
+
+# Mirrors config.NEIGHBOR_TOPOLOGIES (the single source of the AUTO policy
+# is config.py — this module only needs to know which names have a
+# constructor). ``config`` itself imports nothing of the package, so the
+# one name taken from it above, RANDOM_TOPOLOGIES (which graphs a seed, p
+# and sampler tell apart: ``cached_topology``'s key), closes no cycle.
 MATRIX_FREE_TOPOLOGIES = ("ring", "grid", "chain", "erdos_renyi")
 
 # Power-iteration budget for the matrix-free spectral-gap estimate: the
@@ -124,9 +130,11 @@ class Topology:
     def is_matrix_free(self) -> bool:
         return self.adjacency is None
 
-    @property
+    @functools.cached_property
     def spectral_gap(self) -> float:
-        """1 - ρ where ρ is the second-largest |eigenvalue| of W.
+        """1 - ρ where ρ is the second-largest |eigenvalue| of W, worked out
+        once a ``Topology`` (the power iteration is seconds at 2^18 nodes,
+        and ``cached_topology`` hands one object to every call).
 
         Parity: reference trainer.py:133-135. Closed-form values for the
         report setup: ring(25) ≈ 0.0209, 5x5 torus ≈ 0.2764, fc = 1.0.
@@ -166,15 +174,25 @@ class Topology:
         so the normalized-iterate norm converges to the largest
         |eigenvalue| of the deflated operator — i.e. ρ — even under
         eigenvalue multiplicity, the ring's generic case)."""
+        from scipy import sparse
+
         w_nbr, w_self = gather_mixing_weights(
             self.nbr_idx, self.nbr_mask, self.degrees
+        )
+        # The live slots as one CSR matrix: a product reads 2·E weights,
+        # not N·k_max padded ones through a fancy index (at 2^18 nodes of
+        # degree 12 in a table 30 wide: 9 ms against 110, 500 times).
+        live = self.nbr_mask
+        off_diag = sparse.csr_matrix(
+            (w_nbr[live], (np.nonzero(live)[0], self.nbr_idx[live])),
+            shape=(self.n, self.n),
         )
         v = np.random.default_rng(0).standard_normal(self.n)
         v -= v.mean()
         v /= np.linalg.norm(v)
         rho = 0.0
         for _ in range(_POWER_ITERS):
-            v = w_self * v + np.sum(w_nbr * v[self.nbr_idx], axis=1)
+            v = w_self * v + off_diag @ v
             v -= v.mean()
             rho = np.linalg.norm(v)
             if rho < 1e-300:  # degenerate: W is exact averaging
@@ -1153,6 +1171,67 @@ def build_topology(
     )
     topo.validate()
     return topo
+
+
+# One graph a structural identity a process (ISSUE 36): a drawn graph at
+# 2^18 nodes is seconds of host code (the sampler, its connectivity check,
+# the packing, ``validate``, the power iteration of ``spectral_gap``), and
+# a sweep calls ``jax_backend.run`` on one graph many times. Keyed as
+# ``config.structural_dict`` keys a graph: the name, n, the resolved
+# representation and, for the drawn ones alone, p, the resolved topology
+# seed and the resolved sampler (a ring is one graph whatever they say).
+# MATRIX-FREE graphs only, the ones whose making is seconds and whose
+# tables are O(N·k_max): a dense ``Topology`` carries ``[N, N]`` matrices
+# (hundreds of MB at N near 4096), is made anew for every call as before,
+# and stays its caller's to write into. The few most recently used are
+# kept, their host arrays made read-only.
+_TOPOLOGY_CACHE: "collections.OrderedDict[tuple, Topology]" = (
+    collections.OrderedDict()
+)
+_TOPOLOGY_CACHE_MAX = 4
+_TOPOLOGY_CACHE_LOCK = threading.Lock()  # the serving plane calls from threads
+
+
+def cached_topology(
+    name: str,
+    n: int,
+    *,
+    erdos_renyi_p: float = 0.4,
+    seed: int = 0,
+    impl: str = "dense",
+    sampler: str = "dense",
+) -> tuple[Topology, bool]:
+    """``build_topology`` with the same arguments, a matrix-free graph made
+    once a process for one structural identity: ``(topology, hit)``,
+    ``hit`` False where this call made it."""
+    drawn = name in RANDOM_TOPOLOGIES
+    key = (
+        name, int(n), impl,
+        float(erdos_renyi_p) if drawn else None,
+        int(seed) if drawn else None,
+        sampler if drawn else None,
+    )
+    with _TOPOLOGY_CACHE_LOCK:
+        topo = _TOPOLOGY_CACHE.get(key)
+        if topo is not None:
+            _TOPOLOGY_CACHE.move_to_end(key)
+            return topo, True
+    topo = build_topology(
+        name, n, erdos_renyi_p=erdos_renyi_p, seed=seed, impl=impl,
+        sampler=sampler,
+    )
+    if not topo.is_matrix_free:
+        return topo, False
+    for field in dataclasses.fields(topo):
+        # Shared by every later call: a write is a fault, loudly.
+        leaf = getattr(topo, field.name)
+        if isinstance(leaf, np.ndarray):
+            leaf.setflags(write=False)
+    with _TOPOLOGY_CACHE_LOCK:
+        _TOPOLOGY_CACHE[key] = topo
+        while len(_TOPOLOGY_CACHE) > _TOPOLOGY_CACHE_MAX:
+            _TOPOLOGY_CACHE.popitem(last=False)
+    return topo, False
 
 
 def ring_spectral_gap_closed_form(n: int) -> float:

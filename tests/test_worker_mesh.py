@@ -228,6 +228,30 @@ def test_halo_mixing_bitwise_vs_gather(rng, name, n, shards):
             np.asarray(jax.jit(gather_op.apply)(x)), 4)
 
 
+@pytest.mark.parametrize("overlap,form", [
+    ("off", "apply"), ("double_buffer", "apply"), ("off", "neighbor_sum"),
+])
+def test_halo_gather_round_sums_slot_by_slot(overlap, form):
+    """A block's round is the one-device round's ``slot_sum`` on the block
+    with its halo behind it (ISSUE 36): a loop over the table's slots, one
+    row gather in its body, and the ``[S, k_max, d]`` stack of every
+    neighbour's row in no instruction, whatever the exchange's form."""
+    from distributed_optimization_tpu.parallel.collectives import (
+        make_halo_mixing_op,
+    )
+
+    n, d, shards = 64, 7, 4
+    topo = build_topology("erdos_renyi", n, erdos_renyi_p=0.2, seed=7, impl="neighbor")
+    k_max = topo.nbr_idx.shape[1]
+    assert k_max > 8
+    op = make_halo_mixing_op(topo, _mesh(shards), overlap=overlap)
+    assert op.impl == "halo_gather"
+    text = jax.jit(getattr(op, form)).lower(jnp.zeros((n, d), jnp.float32)).as_text()
+    assert "stablehlo.while" in text
+    assert f"tensor<{n // shards}x{k_max}x{d}xf32>" not in text
+    assert f"tensor<{k_max}x{n // shards}x{d}xf32>" not in text
+
+
 def _permute_payload_floats(hlo: str) -> list[int]:
     out = []
     for line in hlo.splitlines():
@@ -265,11 +289,14 @@ def test_halo_ring_round_ships_boundary_rows_only():
     assert op.impl == "halo_shift"
     assert not re.search(r"\bgather\(", hlo)
     assert not re.search(r"s32\[[\d,]*,2\]", hlo)
-    # ... both of which the gather form of the same round has.
+    # ... both of which the gather form of the same round has (its table
+    # slot-major since ISSUE 36: a block's two slots by its four rows).
+    slot_major = rf"s32\[(\d+,)?2,{n // shards}\]"
+    assert not re.search(slot_major, hlo)
     table_op = _make_halo_gather_mixing_op(topo, mesh, dtype=jnp.float32)
     table_hlo = jax.jit(table_op.apply).lower(x).compile().as_text()
     assert re.search(r"\bgather\(", table_hlo)
-    assert re.search(r"s32\[[\d,]*,2\]", table_hlo)
+    assert re.search(slot_major, table_hlo)
 
 
 def _swapped_ring(n):
