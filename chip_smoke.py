@@ -64,15 +64,16 @@ without a TPU it exits before doing any work. Segments:
 
 8. One round of the gather mixing at the drawn-graph cell's size (ISSUE 36):
    the connected Erdős–Rényi graph of 2^18 workers at mean degree 12 that
-   topology seed 7 draws, through ``make_mixing_op``'s slot-major tables
-   handed to a jitted round as ARGUMENTS, against the benchmark reference's
+   topology seed 7 draws, through ``make_mixing_op``'s tables (since PR 38
+   the live slots' chunk list: 209 chunks of 16,384 rows) handed to a
+   jitted round as ARGUMENTS, against the benchmark reference's
    edge-list form (``benchmark/reference/dsgd_er.py``: its own draw of the
    graph, edge for edge the table's, and two scatter-adds) within
    ``GATHER_ROUND_ULPS`` units of the rows' scale; and the device's bytes
    after the rounds: the rows, the result and the tables once, so no second
    copy of a table and none among the executable's constants. What the CPU
-   cannot see: what a ``[30, 2^18]`` table takes in the device's tiles, and
-   whether the executable keeps one.
+   cannot see: what a ``[209, 16384]`` table takes in the device's tiles,
+   and whether the executable keeps one.
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -321,13 +322,15 @@ def halo_forms_segment(device: dict, *, n_workers: int = 1 << 20,
           "on every chip after its calls", flush=True)
 
 
-# The gather round against the edge-list form: the one sums a row's 30 slots
-# in the table's order onto w_self·x, the other adds w_e (x_j − x_i) edge by
-# edge onto x; read 1.9 units on the chip (PR 36).
+# The gather round against the edge-list form: the one sums a row's live
+# slots in the table's order onto w_self·x, the other adds w_e (x_j − x_i)
+# edge by edge onto x; read 1.9 units on the chip (PRs 36 and 38: the live
+# list's sums are the padded table's to the bit).
 GATHER_ROUND_ULPS = 16
 # What the device may hold after the rounds beside the rows, the result and
-# the tables once: under half of one table (33.5 MB in the device's tiles).
-GATHER_ROUND_ROOM = 16_000_000
+# the tables once: under half of one table (14.2 MB in the device's tiles
+# since PR 38; under 1 MB read).
+GATHER_ROUND_ROOM = 7_000_000
 
 
 def gather_round_segment(device: dict, *, n_workers: int = 1 << 18,

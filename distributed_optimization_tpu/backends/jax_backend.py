@@ -708,15 +708,18 @@ def _fault_root_args(config, faulty, tables) -> dict:
 def _gather_root_args(topo, tables) -> dict:
     """What the ``dopt.run`` root says of a call whose static graph mixes
     through neighbor tables: the table's width, the graph's edges, the
-    share of the table's slots that hold one (the rest are padding, gathered
-    and weighed 0) and what the tables the scan was handed take on the
-    device."""
-    k_max = int(tables["nbr"].shape[0])
+    share of the table's slots that hold one (the rest are padding), the
+    rows a round gathers (the live list's chunks, whole, and the sums'
+    way back into the workers' order where the graph is not regular) and
+    what the tables the scan was handed take on the device."""
+    k_max = max(int(np.asarray(topo.degrees).max()), 1)
     live = float(np.asarray(topo.degrees).sum())
     return {
         "k_max": k_max,
         "edges": int(live // 2),
         "live_slot_share": live / (topo.n * k_max),
+        "gathered_rows": int(tables["nbr"].size)
+        + (topo.n if "inverse" in tables else 0),
         "table_bytes": float(sum(
             leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
         )),

@@ -34,27 +34,48 @@ same linear operator has three interchangeable compiled forms:
   ``jax.lax.ppermute``/``psum`` (see ``parallel/collectives.py``), for when
   manual control over the collective schedule is wanted.
 - ``gather`` (round 9): the matrix-free k_max-bounded form over padded
-  neighbor tables — O(N·k_max·d), no [N, N] object anywhere; the route
-  that lifts the worker axis to N ≥ 10k, and the one every graph that is
-  not a shift takes there (Erdős–Rényi, chain). The tables are kept
-  SLOT-MAJOR, ``nbr`` / ``w_nbr`` ``[k_max, N]`` and ``w_self`` ``[N]``,
-  in ONE pytree, ``MixingOp.tables``, and the operators are built over a
-  copy of it by ``MixingOp.bind``: ``jax_backend._run`` hands the tables
-  to its scan as ARGUMENTS (``data['mixing']``) and rebinds, so they are
-  never constants of the executable (a node-major ``s32[262144, 30]``
-  constant is 134 MB in the TPU's (8, 128) tiles, slot-major 33 MB as an
-  argument; PERF.md section 6, PR 36). A round accumulates SLOT BY SLOT,
-  ``w_self·x + ((w_nbr[0]·x[nbr[0]] + w_nbr[1]·x[nbr[1]]) + …)``: one
-  row gather of ``[N, ...]`` a slot, never the ``[N, k_max, ...]`` stack
-  (at N = 2^18, k_max = 30, d = 81 that stack and its product are 8.3 GB
-  of the chip's tiles), so a round's temporaries do not grow with k_max:
-  the first slot's term, then one ``lax.scan`` over the rest whose body
-  holds one gather, for every table (``slot_sum``, which the sharded
-  twin's blocks call too, so the two keep one per-row op sequence);
-  padded slots point at the row itself and weigh 0, and ``neighbor_sum``
-  reads the mask off the weights (a live slot's is positive), so no mask
-  table exists. A caller that binds nothing gets the operators
-  over ``tables`` as they are (constants of whatever it traces). Its
+  neighbor tables — O(E·d), no [N, N] object anywhere; the route that
+  lifts the worker axis to N ≥ 10k, and the one every graph that is not
+  a shift takes there (Erdős–Rényi, chain). A round sums over the LIVE
+  slots only (PR 38): a padded slot points at the row itself, weighs 0
+  and would be fetched and multiplied like any other (six rows in ten on
+  a drawn graph of mean degree 12 in a table 30 wide), so the host lays
+  the table's live (slot, row) pairs out once a graph
+  (``topology.live_slot_chunks``, kept with the kept graph as
+  ``Topology.gather_chunks``): rows in order of falling degree, where
+  slot s is live on a PREFIX of the rows and nowhere else, slot after
+  slot, each slot's run cut into chunks of one length C
+  (``topology.gather_chunk_rows``: derived from N, a small graph's chunk
+  the whole row axis), a run's last chunk filled with entries of weight
+  0 as padding is. The tables are ONE pytree, ``MixingOp.tables``:
+  ``nbr`` s32 and ``w_nbr`` ``[n_chunks, C]`` (the neighbours in the
+  workers' own numbering: x is gathered as it is carried), ``row0`` s32
+  ``[n_chunks]`` (each chunk's first row in the degree order, read by
+  trip number), ``w_self`` ``[N]`` in the workers' order and ``inverse``
+  s32 ``[N]`` (each worker's place in the degree order; left out where
+  that order is the workers' own, a regular graph). The operators are
+  built over a copy of the pytree by ``MixingOp.bind``:
+  ``jax_backend._run`` hands the tables to its scan as ARGUMENTS
+  (``data['mixing']``) and rebinds, so they are never constants of the
+  executable (a node-major ``s32[262144, 30]`` constant is 134 MB in the
+  TPU's (8, 128) tiles; PERF.md section 6, PR 36). A round is
+  ``w_self·x + acc[inverse]`` (``live_slot_sum``): the first chunk's
+  term starts the accumulator, then ONE ``lax.scan`` over the other
+  chunks, its body one row gather of C rows whose weighted rows are
+  added into rows ``[row0, row0 + C)`` of the accumulator in place (the
+  accumulator kept ``[N/C, C, ...]``, a chunk indexing its block: one
+  fused pass on the TPU), then one gather of N rows that puts the sums
+  back in the workers' order (skipped without ``inverse``). Never the
+  ``[N, k_max, ...]`` stack (at N = 2^18, k_max = 30, d = 81 that stack
+  and its product are 8.3 GB of the chip's tiles), one loop whatever
+  k_max and whatever the degrees, and per row the terms are added in the
+  table's slot order: the sums are those of ``slot_sum``, the loop over
+  the padded slot-major ``[k_max, n]`` tables that the sharded twin's
+  blocks run, so sharded and unsharded agree to the bit.
+  ``neighbor_sum`` reads the mask off the weights (a live slot's is
+  positive), so no mask table exists. A caller that binds nothing gets
+  the operators over ``tables`` as they are (constants of whatever it
+  traces). Its
   SHARDED twin is ``parallel/collectives.make_halo_mixing_op`` (impl tag
   ``'halo_gather'``, the ``worker_mesh`` axis, docs/PERF.md §16): the
   same weights over per-shard tables with the worker rows split over a
@@ -78,8 +99,6 @@ from distributed_optimization_tpu.config import MATRIX_FREE_AUTO_N
 from distributed_optimization_tpu.parallel.topology import (
     NEIGHBOR_TABLE_MAX_CELLS,
     Topology,
-    gather_mixing_weights,
-    neighbor_tables_for,
 )
 
 MixFn = Callable[[jax.Array], jax.Array]
@@ -92,13 +111,14 @@ def _col(v: jax.Array, x: jax.Array) -> jax.Array:
 def slot_sum(src: jax.Array, nbr: jax.Array, w_nbr: jax.Array,
              weight=None) -> jax.Array:
     """Σ_s weight(w_nbr[s])·src[nbr[s]] over slot-major ``[k_max, n]``
-    tables, slot by slot in the table's order: ONE row gather of
-    ``[n, ...]`` in flight, the ``[n, k_max, ...]`` stack of every
-    neighbour's row never made. The first slot's term, then a loop over
-    the rest, whose body XLA cannot reorder into k_max gathers held at
-    once. ``src`` may hold more rows than ``n`` (a shard's block with its
-    halo behind it); ``weight`` maps a slot's weights (``neighbor_sum``'s
-    mask), the identity where None."""
+    tables, slot by slot in the table's order, padded slots included: ONE
+    row gather of ``[n, ...]`` in flight, the ``[n, k_max, ...]`` stack of
+    every neighbour's row never made. The first slot's term, then a loop
+    over the rest, whose body XLA cannot reorder into k_max gathers held at
+    once. The halo gather's per-shard blocks run it
+    (``parallel/collectives.py``); ``src`` may hold more rows than ``n`` (a
+    shard's block with its halo behind it); ``weight`` maps a slot's
+    weights (``neighbor_sum``'s mask), the identity where None."""
 
     def term(idx, w):
         return _col(w if weight is None else weight(w), src) * src[idx]
@@ -110,6 +130,52 @@ def slot_sum(src: jax.Array, nbr: jax.Array, w_nbr: jax.Array,
         add_slot, term(nbr[0], w_nbr[0]), (nbr[1:], w_nbr[1:])
     )
     return total
+
+
+def live_slot_sum(x: jax.Array, tb: dict, weight=None) -> jax.Array:
+    """Σ_s weight(w_nbr)·x[nbr] over the LIVE slots alone, read from the
+    chunk list ``topology.live_slot_chunks`` lays out (module docstring):
+    the first chunk's term, then one loop over the rest, one row gather of
+    ``C`` rows in its body, the weighted rows added in place into the
+    accumulator's rows ``[row0, row0 + C)`` (rows in the degree order),
+    then the sums put back in the workers' order. Per row the terms are
+    added in the table's slot order: ``slot_sum``'s result. ``row0`` is read
+    by trip number, so a list cut short runs the trips it has.
+
+    The accumulator is kept ``[N/C, C, ...]`` and a chunk indexes its BLOCK
+    of rows, ``row0 // C``: an index on the leading axis is aligned to the
+    device's tiles whatever its value, so XLA:TPU fuses the sum and the
+    update into one pass over the block in place; as an offset into the
+    rows of ``[N, ...]`` it is not known to be, and the update was a copy
+    of its own after the sum (5.8 of 39.9 ms a round at the drawn-graph
+    cell's size, PERF.md section 6, PR 38)."""
+    nbr, row0 = tb["nbr"], tb["row0"]
+    w_nbr = tb["w_nbr"] if weight is None else weight(tb["w_nbr"])
+    n, chunk = x.shape[0], nbr.shape[1]
+    blocks = -(-n // chunk)
+
+    def term(idx, w):
+        return _col(w, x) * x[idx]
+
+    def add_chunk(acc, trip):
+        t, idx, w = trip
+        block = row0[t] // chunk
+        rows = jax.lax.dynamic_index_in_dim(acc, block, 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            acc, rows + term(idx, w), block, 0
+        ), None
+
+    # The list's first chunk is slot 0's first, block 0: its term starts the
+    # accumulator, as ``slot_sum``'s first slot starts its sum (where a
+    # chunk is the whole row axis the two loops are one program).
+    first = term(nbr[0], w_nbr[0])
+    acc = jnp.zeros((blocks,) + first.shape, first.dtype)
+    acc, _ = jax.lax.scan(
+        add_chunk, jax.lax.dynamic_update_index_in_dim(acc, first, 0, 0),
+        (jnp.arange(1, nbr.shape[0]), nbr[1:], w_nbr[1:]),
+    )
+    acc = acc.reshape((blocks * chunk,) + first.shape[1:])
+    return acc[tb["inverse"]] if "inverse" in tb else acc[:n]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,7 +193,8 @@ class MixingOp:
     apply: MixFn
     neighbor_sum: MixFn
     # The gather form only, else None: ``tables`` is the pytree of every
-    # device array its operators read (slot-major, module docstring), and
+    # device array its operators read (the live slots' chunk list, module
+    # docstring), and
     # ``bind(tables_like)`` rebuilds this op over a same-structured pytree
     # — the leaves a compiled program receives ``tables`` as.
     # ``jax_backend._run`` hands the tables to its scan as arguments and
@@ -205,29 +272,25 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
                 "gather mixing is undirected-only (MH weights per slot); "
                 f"directed topology {topo.name!r} has no gather form"
             )
-        nbr_idx_np, nbr_mask_np = neighbor_tables_for(topo)
-        w_nbr_np, w_self_np = gather_mixing_weights(
-            nbr_idx_np, nbr_mask_np, topo.degrees
-        )
+        # int32 indices as they are; the float64 weights in ``dtype``
         tables = {
-            "nbr": jnp.asarray(nbr_idx_np.T, dtype=jnp.int32),
-            "w_nbr": jnp.asarray(w_nbr_np.T, dtype=dtype),
-            "w_self": jnp.asarray(w_self_np, dtype=dtype),
+            key: jnp.asarray(leaf, dtype=None if leaf.dtype == np.int32 else dtype)
+            for key, leaf in topo.gather_chunks.items()
         }
         name = topo.name
 
         def bind(tb) -> MixingOp:
-            nbr, w_nbr, w_self = tb["nbr"], tb["w_nbr"], tb["w_self"]
+            w_self = tb["w_self"]
 
             def apply(x: jax.Array) -> jax.Array:
-                out = _col(w_self, x) * x + slot_sum(x, nbr, w_nbr)
+                out = _col(w_self, x) * x + live_slot_sum(x, tb)
                 return out.astype(x.dtype)
 
             def neighbor_sum(x: jax.Array) -> jax.Array:
                 # A live slot's MH weight is 1/(1 + max degree) > 0 and a
                 # padded one's is 0: the mask, without a table of its own.
-                return slot_sum(
-                    x, nbr, w_nbr, lambda w: (w > 0).astype(x.dtype)
+                return live_slot_sum(
+                    x, tb, lambda w: (w > 0).astype(x.dtype)
                 ).astype(x.dtype)
 
             return MixingOp(

@@ -200,6 +200,17 @@ class Topology:
             v /= rho
         return float(1.0 - rho)
 
+    @functools.cached_property
+    def gather_chunks(self) -> dict:
+        """``live_slot_chunks`` of this graph's neighbor table, worked out
+        once a ``Topology`` and read-only: a function of the graph alone,
+        so it is kept with the kept graph (``cached_topology``) and a later
+        call's gather mixing only casts and uploads it."""
+        chunks = live_slot_chunks(*neighbor_tables_for(self), self.degrees)
+        for leaf in chunks.values():
+            leaf.setflags(write=False)
+        return chunks
+
     @property
     def floats_per_iteration(self) -> float:
         """Analytic gossip cost in floats per iteration per model dimension.
@@ -1051,6 +1062,85 @@ def gather_mixing_weights(
     w_nbr = np.where(nbr_mask, 1.0 / (1.0 + pair), 0.0)
     w_self = 1.0 - w_nbr.sum(axis=1)
     return w_nbr, w_self
+
+
+# The most rows one chunk of the gather mixing's live list holds: one trip
+# of its loop gathers a chunk. Shorter chunks waste fewer rows on the
+# padding that ends a slot's run (half a chunk a slot on average) and make
+# more trips; settled on the chip at the drawn-graph cell's size (PERF.md
+# section 6, PR 38).
+GATHER_CHUNK_ROWS = 16384
+
+
+def gather_chunk_rows(n: int) -> int:
+    """The chunk length for a graph of ``n`` nodes: the rows split evenly
+    into the fewest chunks of at most ``GATHER_CHUNK_ROWS`` (a small graph:
+    one chunk a slot, the chunk the whole table row)."""
+    return -(-n // -(-n // GATHER_CHUNK_ROWS))
+
+
+def live_slot_chunks(
+    nbr_idx: np.ndarray, nbr_mask: np.ndarray, degrees: np.ndarray
+) -> dict:
+    """The table's LIVE (slot, row) pairs as the gather mixing walks them
+    (``ops/mixing.py``), with their Metropolis-Hastings weights.
+
+    A padded slot is fetched and multiplied like a live one, so the rows
+    are taken in order of FALLING degree (stable: a regular graph keeps its
+    order): every row lists its live slots first, hence slot s is live on
+    the first n_s rows of that order, n_s the number of rows of degree > s,
+    and on no other. Each slot's run of n_s rows is cut into chunks of
+    ``C = gather_chunk_rows(n)`` rows that start at multiples of C; the
+    last chunk of a run is filled from the table itself (padded slots,
+    self-pointing and weighing 0) and, past the table's end, with row 0 at
+    weight 0. Returns host arrays, slot after slot:
+
+    - ``nbr`` int32 ``[n_chunks, C]``: the neighbours, in the nodes' OWN
+      numbering (the models are gathered as they are carried);
+    - ``w_nbr`` float64 ``[n_chunks, C]``: ``gather_mixing_weights``' per
+      slot weights, 0 on every fill entry;
+    - ``row0`` int32 ``[n_chunks]``: the position, in the degree order, of
+      each chunk's first row;
+    - ``w_self`` float64 ``[n]``, in the nodes' own numbering;
+    - ``inverse`` int32 ``[n]``: each node's position in the degree order;
+      left out where that order is the nodes' own (a regular graph).
+    """
+    n, k_max = nbr_idx.shape
+    deg = np.asarray(degrees)
+    slots = np.arange(k_max)
+    if not np.array_equal(nbr_mask, slots < deg[:, None]):
+        raise ValueError(
+            "the gather mixing needs every row of the neighbor table to "
+            "list its live slots first, as neighbor_table packs them"
+        )
+    w_nbr, w_self = gather_mixing_weights(nbr_idx, nbr_mask, deg)
+    order = np.argsort(-deg, kind="stable")
+    chunk = gather_chunk_rows(n)
+    blocks = -(-n // chunk)
+
+    def by_chunk(table):
+        out = np.zeros((k_max, blocks * chunk), table.dtype)
+        out[:, :n] = table[order].T
+        return out.reshape(k_max, blocks, chunk)
+
+    live_rows = (deg[:, None] > slots).sum(axis=0)  # n_s
+    # (slot 0 keeps a chunk on a graph with no edge: the list is never empty)
+    per_slot = np.maximum(-(-live_rows // chunk), slots == 0)
+    slot = np.repeat(slots, per_slot)
+    block = np.arange(slot.size) - np.repeat(
+        np.cumsum(per_slot) - per_slot, per_slot
+    )
+    chunks = {
+        "nbr": by_chunk(nbr_idx)[slot, block],
+        "w_nbr": by_chunk(w_nbr)[slot, block],
+        "row0": (block * chunk).astype(np.int32),
+        "w_self": w_self,
+    }
+    if not np.array_equal(order, np.arange(n)):
+        inverse = np.empty(n, dtype=np.int32)
+        inverse[order] = np.arange(n, dtype=np.int32)
+        chunks["inverse"] = inverse
+    return chunks
 
 
 def metropolis_hastings_weights(adjacency: np.ndarray) -> np.ndarray:

@@ -1,7 +1,7 @@
 """The first graph that is not a shift (ISSUE 36) at a size a test run can
 hold: the program on a connected Erdős–Rényi graph through its normal path
-(the sparse draw, the neighbor table, gather mixing slot by slot over tables
-the scan is HANDED), against the benchmark's plain reference
+(the sparse draw, the neighbor table, gather mixing over the live slots'
+chunk list, tables the scan is HANDED), against the benchmark's plain reference
 (``benchmark/reference/dsgd_er.py``: the documented sampler restated, the
 mixing on the edge list, nothing of the package), by the limits of the
 cell's own configuration file; the gather form against the dense matrix; the
@@ -29,6 +29,7 @@ from benchmark import run as harness  # noqa: E402
 from benchmark.reference import dsgd_er  # noqa: E402
 
 from distributed_optimization_tpu.observability.spans import Tracer  # noqa: E402
+from distributed_optimization_tpu.ops import mixing  # noqa: E402
 from distributed_optimization_tpu.ops.mixing import make_mixing_op  # noqa: E402
 from distributed_optimization_tpu.parallel import topology  # noqa: E402
 
@@ -91,8 +92,12 @@ def test_the_program_is_within_the_cells_limits(cell, seed):
     assert args["mixing"] == "gather"
     assert (args["k_max"], args["edges"]) == (k_max, src.size)
     assert args["live_slot_share"] == pytest.approx(2 * src.size / (64 * k_max))
-    # nbr s32 and w_nbr f32, each [k_max, 64], and w_self f32[64]
-    assert args["table_bytes"] == (2 * k_max + 1) * 64 * 4
+    # a small graph: one chunk a slot, the chunk the 64 rows; and the sums'
+    # way back from the degree order
+    assert args["gathered_rows"] == k_max * 64 + 64
+    # nbr s32 and w_nbr f32, each [k_max, 64], row0 s32[k_max], w_self
+    # f32[64] and inverse s32[64]
+    assert args["table_bytes"] == (2 * k_max * 64 + k_max + 2 * 64) * 4
     ref = dsgd_er.run(config, traffic, X, y, pseed)
     ok, said = judged(harness.produced_of(result), ref, config)
     assert ok, said
@@ -159,13 +164,24 @@ def test_the_references_mixing_is_its_matrix(weights):
     assert np.abs(other - w).max() > 1e-3
 
 
+def padded_table(topo):
+    """The table as PR 36's round walked it, padded slots and all, with its
+    weights: node-major host arrays ``nbr``, ``mask``, ``w_nbr`` ``[n,
+    k_max]`` and ``w_self`` ``[n]``."""
+    nbr, mask = topology.neighbor_tables_for(topo)
+    return (nbr, mask, *topology.gather_mixing_weights(nbr, mask, topo.degrees))
+
+
 @pytest.mark.parametrize("rank", [2, 3])
-def test_the_gather_form_is_the_dense_matrix(rank):
-    """On a drawn graph with padded slots and a node of degree 1: slot by
-    slot over the slot-major tables is ``W @ x`` of
-    ``metropolis_hastings_weights``, ``neighbor_sum`` is ``A @ x``, and a
-    rebinding over other leaves reads those."""
+def test_the_gather_form_is_the_dense_matrix(rank, monkeypatch):
+    """On a drawn graph with padded slots and a node of degree 1, its slots'
+    runs cut into several chunks: the loop over the live list is ``W @ x``
+    of ``metropolis_hastings_weights``, ``neighbor_sum`` is ``A @ x``, both
+    are EQUAL to the loop over the padded slot-major tables (every row's
+    terms added in the table's slot order), and a rebinding over other
+    leaves reads those."""
     n, p, seed = 128, 0.035, 3
+    monkeypatch.setattr(topology, "GATHER_CHUNK_ROWS", 32)
     topo = topology.build_neighbor_topology(
         "erdos_renyi", n, erdos_renyi_p=p, seed=seed, sampler="sparse")
     assert topo.degrees.min() == 1 and not topo.nbr_mask.all()
@@ -176,14 +192,28 @@ def test_the_gather_form_is_the_dense_matrix(rank):
               topo.nbr_idx[topo.nbr_mask]] = 1.0
     W = topology.metropolis_hastings_weights(adjacency)
     op = make_mixing_op(topo, impl="gather")
-    assert set(op.tables) == {"nbr", "w_nbr", "w_self"}
-    assert op.tables["nbr"].shape == op.tables["w_nbr"].shape == (k_max, n)
+    assert set(op.tables) == {"nbr", "w_nbr", "row0", "w_self", "inverse"}
+    chunks = sum(-(-int((topo.degrees > s).sum()) // 32) for s in range(k_max))
+    assert k_max < chunks < 4 * k_max
+    assert op.tables["nbr"].shape == op.tables["w_nbr"].shape == (chunks, 32)
+    assert op.tables["row0"].shape == (chunks,)
+    assert op.tables["w_self"].shape == op.tables["inverse"].shape == (n,)
     x = np.random.default_rng(1).standard_normal((n, 7, 3)[:rank]).astype(np.float32)
     flat = x.reshape(n, -1).astype(np.float64)
-    for fn, matrix in ((op.apply, W), (op.neighbor_sum, adjacency)):
-        got = np.asarray(jax.jit(fn)(jnp.asarray(x)))
+    # slot-major, as ``ops.mixing.slot_sum`` reads them
+    nbr, _, w_nbr, w_self = padded_table(topo)
+    nbr, w_nbr, w_self = (jnp.asarray(nbr.T), jnp.asarray(w_nbr.T, jnp.float32),
+                          jnp.asarray(w_self, jnp.float32))
+    padded = {
+        "apply": lambda x: mixing._col(w_self, x) * x + mixing.slot_sum(x, nbr, w_nbr),
+        "neighbor_sum": lambda x: mixing.slot_sum(
+            x, nbr, w_nbr, lambda w: (w > 0).astype(x.dtype)),
+    }
+    for form, matrix in (("apply", W), ("neighbor_sum", adjacency)):
+        got = np.asarray(jax.jit(getattr(op, form))(jnp.asarray(x)))
         assert got.dtype == np.float32 and got.shape == x.shape
         np.testing.assert_allclose(got.reshape(n, -1), matrix @ flat, atol=3e-6)
+        np.testing.assert_array_equal(got, np.asarray(jax.jit(padded[form])(jnp.asarray(x))))
     # bound over other leaves (here: no neighbour weighs anything), as a
     # program's arguments are
     silent = dict(op.tables, w_nbr=jnp.zeros_like(op.tables["w_nbr"]),
@@ -192,18 +222,94 @@ def test_the_gather_form_is_the_dense_matrix(rank):
 
 
 def test_a_round_is_one_loop_over_the_slots_whatever_k_max():
-    """The first slot's term and a loop over the rest, one gather in its
-    body, for a chain's two slots as for a drawn graph's twenty and more:
-    one program shape, whose temporaries do not grow with k_max."""
+    """The first chunk's term, then ONE loop over the rest of the live
+    list, one gather in its body, and one gather that puts the sums back in
+    the workers' order, for a chain's two slots as for a drawn graph's
+    twenty and more: one program shape, whose temporaries do not grow with
+    k_max. A regular graph's degree order is the workers' own: no gather
+    after the loop."""
     x = jnp.zeros((64, 5), jnp.float32)
     chain = make_mixing_op(topology.build_neighbor_topology("chain", 64), impl="gather")
     drawn = make_mixing_op(topology.build_neighbor_topology(
         "erdos_renyi", 64, erdos_renyi_p=0.2, seed=7, sampler="sparse"), impl="gather")
+    torus = make_mixing_op(topology.build_neighbor_topology("grid", 64), impl="gather")
     assert chain.tables["nbr"].shape[0] == 2 and drawn.tables["nbr"].shape[0] > 20
+    assert "inverse" not in torus.tables and torus.tables["nbr"].shape == (4, 64)
     gathers = re.compile(r"= \"stablehlo\.gather\"\(")
-    for op in (chain, drawn):
+    for op, after_the_loop in ((chain, 1), (drawn, 1), (torus, 0)):
         text = jax.jit(op.apply).lower(x).as_text()
-        assert "stablehlo.while" in text and len(gathers.findall(text)) == 2
+        assert text.count("stablehlo.while") == 1
+        # the first chunk's, the loop's, and the way back
+        assert len(gathers.findall(text)) == 2 + after_the_loop
+
+
+def live_pairs(topo):
+    nbr, mask, w_nbr, _ = padded_table(topo)
+    rows, slots = np.nonzero(mask)
+    return sorted(zip(slots.tolist(), rows.tolist(), nbr[mask].tolist(), w_nbr[mask].tolist()))
+
+
+@pytest.mark.parametrize("n,p,seed,chunk", [
+    (128, 0.035, 3, 32), (500, 0.02, 2147483999, 64), (200, 0.05, 11, 16384), (301, 0.04, 5, 100)])
+def test_the_chunk_list_is_the_live_slots_once(n, p, seed, chunk, monkeypatch):
+    """Every live (slot, row) pair of the table stands in the chunk list
+    once, with its neighbour and its weight, in its own row of the degree
+    order, slot after slot; every other entry weighs 0; a slot takes the
+    chunks its live rows need and no more."""
+    monkeypatch.setattr(topology, "GATHER_CHUNK_ROWS", chunk)
+    topo = topology.build_neighbor_topology(
+        "erdos_renyi", n, erdos_renyi_p=p, seed=seed, sampler="sparse")
+    tb = topo.gather_chunks
+    C = topology.gather_chunk_rows(n)
+    assert C <= chunk and -(-n // C) == -(-n // min(n, chunk))
+    assert tb["nbr"].shape == tb["w_nbr"].shape == (tb["row0"].size, C)
+    assert tb["nbr"].dtype == tb["row0"].dtype == tb["inverse"].dtype == np.int32
+    order = np.argsort(tb["inverse"])
+    degrees = np.asarray(topo.degrees)[order]
+    assert np.all(np.diff(degrees) <= 0) and sorted(order) == list(range(n))
+    # a chunk's slot: the runs come slot after slot, each from row 0 on
+    slot = np.cumsum(tb["row0"] == 0) - 1
+    per_slot = np.bincount(slot)
+    assert per_slot.tolist() == [-(-int((topo.degrees > s).sum()) // C) for s in range(len(per_slot))]
+    assert np.all(tb["row0"] % C == 0) and np.all(tb["row0"] + C <= -(-n // C) * C)
+    place = tb["row0"][:, None] + np.arange(C)
+    live = tb["w_nbr"] > 0
+    assert place[live].max() < n and tb["nbr"].min() >= 0 and tb["nbr"].max() < n
+    got = sorted(zip(np.broadcast_to(slot[:, None], live.shape)[live].tolist(),
+                     order[place[live]].tolist(), tb["nbr"][live].tolist(),
+                     tb["w_nbr"][live].tolist()))
+    assert got == live_pairs(topo)
+    assert not tb["w_nbr"][~live].any()
+    with pytest.raises(ValueError):
+        tb["nbr"][0, 0] = 1
+
+
+def test_a_table_with_a_hole_in_a_row_is_refused():
+    """The prefix rule rests on every row listing its live slots first."""
+    topo = topology.build_neighbor_topology("chain", 8)
+    mask = topo.nbr_mask.copy()
+    mask[3] = [False, True]
+    with pytest.raises(ValueError, match="live slots first"):
+        topology.live_slot_chunks(topo.nbr_idx, mask, mask.sum(axis=1))
+
+
+def test_the_pinned_graphs_chunks():
+    """The cell's own graph, drawn as its configuration file pins it (2 s):
+    the edges and width the file states, the live list in 209 chunks of
+    16,384 rows, so a round gathers 3.42 M rows where the padded table is
+    7.86 M, and 2^18 more on the sums' way back."""
+    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as fh:
+        whole = json.load(fh)
+    graph, n = whole["graph"], whole["experiment"]["n_workers"]
+    topo = topology.build_neighbor_topology(
+        "erdos_renyi", n, erdos_renyi_p=whole["experiment"]["erdos_renyi_p"],
+        seed=graph["topology_seed"], sampler=graph["sampler"])
+    assert int(topo.degrees.sum()) == 2 * graph["edges"]
+    assert topo.nbr_idx.shape == (n, graph["k_max"])
+    tb = topo.gather_chunks
+    assert tb["nbr"].shape == (209, 16384) and tb["inverse"].shape == (n,)
+    assert int((tb["w_nbr"] > 0).sum()) == 2 * graph["edges"]
+    assert tb["nbr"].size == 3_424_256 < 0.44 * n * graph["k_max"]
 
 
 def test_the_scans_program_holds_no_table(cell, no_graphs_kept):
@@ -222,7 +328,10 @@ def test_the_scans_program_holds_no_table(cell, no_graphs_kept):
     assert args["mixing"] == "gather" and args["table_bytes"] > 2**21
     text = device_scopes._programs[args["program"]]["executable"]().as_text()
     entry = text[text.index("ENTRY"):].split("\n", 1)[0]
+    # one chunk a slot at this size, and the one gather beside the loop's
+    assert args["gathered_rows"] == (args["k_max"] + 1) * n
     assert f"s32[{args['k_max']},{n}]" in entry and f"f32[{args['k_max']},{n}]" in entry
+    assert f"s32[{args['k_max']}]" in entry and f"s32[{n}]" in entry
     constants = [
         device_scopes._shape_bytes(ins[1])
         for ins in map(device_scopes._instruction, text.splitlines())

@@ -29,10 +29,12 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def drawn_graph_round(one_chip):
-    """One gossip round of the gather form at the drawn-graph cell's width
-    (k_max 30 of the cell's 2^18 rows of 81 floats; the graph itself a small
-    one, its tables' SHAPES the cell's), tables as arguments."""
-    n, k_max, d = 1 << 18, 30, 81
+    """One gossip round of the gather form at the drawn-graph cell's size
+    (the pinned graph's live list, 209 chunks of 16,384 rows, over the
+    cell's 2^18 rows of 81 floats; the graph itself a small one, its
+    tables' SHAPES the cell's), tables as arguments."""
+    n, chunks, d = 1 << 18, 209, 81
+    rows = topology.gather_chunk_rows(n)
     small = topology.build_neighbor_topology(
         "erdos_renyi", 256, erdos_renyi_p=0.05, seed=7, sampler="sparse")
     op = make_mixing_op(small, impl="gather")
@@ -40,20 +42,22 @@ def drawn_graph_round(one_chip):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    tables = {"nbr": shape((k_max, n), jnp.int32), "w_nbr": shape((k_max, n), jnp.float32),
-              "w_self": shape((n,), jnp.float32)}
+    tables = {"nbr": shape((chunks, rows), jnp.int32), "w_nbr": shape((chunks, rows), jnp.float32),
+              "row0": shape((chunks,), jnp.int32), "w_self": shape((n,), jnp.float32),
+              "inverse": shape((n,), jnp.int32)}
     assert {k: v.dtype for k, v in tables.items()} == {
         k: v.dtype for k, v in op.tables.items()}
     return jax.jit(lambda x, tb: op.bind(tb).apply(x)).lower(
         shape((n, d), jnp.float32), tables).compile()
 
 
-def test_a_gather_round_holds_one_slot_not_thirty(drawn_graph_round):
-    """The round's temporaries are a few copies of the models, whatever
-    k_max is: 0.4 GB where the [N, 30, 81] stack and its product are 8.3
-    and thirty gathers hoisted ahead of their sum 4.2."""
+def test_a_gather_round_holds_one_chunk_not_thirty_slots(drawn_graph_round):
+    """The round's temporaries are the models row-major and the accumulator,
+    whatever k_max is: 0.27 GB (PR 36's loop over whole slots held 0.40)
+    where the [N, 30, 81] stack and its product are 8.3 and thirty gathers
+    hoisted ahead of their sum 4.2."""
     memory = drawn_graph_round.memory_analysis()
-    assert memory.temp_size_in_bytes < 600_000_000, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 450_000_000, memory.temp_size_in_bytes
 
 
 def test_a_gather_round_has_no_table_among_its_constants(drawn_graph_round):
@@ -65,4 +69,28 @@ def test_a_gather_round_has_no_table_among_its_constants(drawn_graph_round):
     ]
     assert max(constants) < 2**20
     entry = text[text.index("ENTRY"):].split("\n", 1)[0]
-    assert "s32[30,262144]" in entry and "f32[30,262144]" in entry
+    assert "s32[209,16384]" in entry and "f32[209,16384]" in entry
+    assert "s32[209]" in entry and "s32[262144]" in entry
+
+
+def test_a_gather_round_adds_a_chunk_in_place(drawn_graph_round):
+    """One loop; in its body one gather of a chunk's rows (the first chunk's
+    stands before it) and ONE fusion that sums onto the accumulator's block
+    and writes it where it lies: the ``dynamic-update-slice`` is that
+    fusion's root, not a copy of its own after the sum, and no copy of the
+    accumulator's 134 MB is made a trip; the one gather of 2^18 rows puts
+    the sums back in the workers' order."""
+    text = drawn_graph_round.as_text()
+    ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
+    assert sum(ins[2] == "while" for ins in ops) == 1
+    assert sorted(ins[1].split("{")[0] for ins in ops if ins[2] == "gather") == [
+        "f32[16384,81]", "f32[16384,81]", "f32[262144,81]"]
+    # the accumulator as 16 blocks of 16,384 rows, a chunk indexing its block
+    (update,) = [line for line in text.splitlines() if " dynamic-update-slice(" in line]
+    assert update.lstrip().startswith("ROOT ") and "f32[16,16384,81]" in update
+    fused = [ins for ins in ops if ins[2] == "fusion" and ins[1].startswith("f32[16,16384,81]")]
+    assert len(fused) == 1 and "kind=kLoop" in fused[0][4]
+    copies = [ins for ins in ops if ins[2] == "copy" and ins[1].startswith("f32[262144,81]")]
+    # x to row-major for the first chunk and for the loop, the result back
+    # to the carried layout
+    assert len(copies) <= 3, copies
