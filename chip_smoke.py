@@ -75,6 +75,19 @@ without a TPU it exits before doing any work. Segments:
    cannot see: what a ``[209, 16384]`` table takes in the device's tiles,
    and whether the executable keeps one.
 
+9. The tracker cell's deployment at its own size (ISSUE 39): gradient
+   tracking on least squares over a 128 x 128 torus, 800 rows a worker, built
+   from the benchmark's own files; 100 iterations with the state returned.
+   The root says two gossip rounds, the ``gather`` sampler and the
+   ``stencil`` mixing on a ``128x128`` grid; the tracker's mean is the last
+   gradients' mean within ``TRACKER_MEAN_UNITS`` units of the gradients'
+   scale; one round of the program's grid stencil on a random ``[16384, 81]``
+   stack against the reference's (``benchmark/reference/gt_torus.py``)
+   within ``STENCIL_ROUND_ULPS`` units; and one iteration's gathered batch is
+   the rows the reference's ``batch_weights`` weighs. What the CPU cannot
+   see: ``sampling_impl`` auto resolving to ``gather`` above 64 rows, and
+   whether the chip's ``top_k`` and the reference's pick the same rows.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -84,6 +97,7 @@ from __future__ import annotations
 
 import json
 import math
+import os
 import sys
 
 import numpy as np
@@ -407,6 +421,104 @@ def gather_round_segment(device: dict, *, n_workers: int = 1 << 18,
                f"{GATHER_ROUND_ULPS} units of the rows' scale")
 
 
+# mean_i y and mean_i g_prev after 100 iterations, in units of float32's
+# epsilon times the largest last gradient: each round adds a rounding of the
+# entries, the mean over 16,384 workers keeps a small share of it (the first
+# readings: 48.66 on one v5e, 37.59 on the sandbox's CPU; PERF.md section 6).
+TRACKER_MEAN_UNITS = 256.0
+# Five additions and a product by 1/5 a row, in one order or the other.
+STENCIL_ROUND_ULPS = 4.0
+
+
+def tracker_segment(device: dict, *, iterations: int = 100, seed: int = 39) -> None:
+    """The tracker cell's experiment at its own size, from the benchmark's
+    own files, for ``iterations`` iterations: what the root says, the
+    tracking invariant, one stencil round and one gathered batch against the
+    reference's (ISSUE 39)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import datasets, program
+    from benchmark import run as harness
+    from benchmark.reference import gt_torus
+    from benchmark.reference.dsgd_ring import batch_weights
+    from distributed_optimization_tpu.ops.mixing import make_mixing_op
+    from distributed_optimization_tpu.ops.sampling import sample_worker_batches
+    from distributed_optimization_tpu.parallel.topology import cached_topology
+
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    _, config, traffic = harness.load_cell(
+        bench, "quad81_gt_torus16k.track1k", rehearse=False)
+    exp = config["experiment"]
+    n, b = int(exp["n_workers"]), int(exp["local_batch_size"])
+    side = math.isqrt(n)
+    X, y, L = datasets.make(config, seed)
+    cfg, ds = program.build(
+        config, dict(traffic, n_iterations=iterations), X, y, L,
+        program.seed_for(seed))
+    result, root, _ = _rooted_run(
+        f"gradient tracking on a {side}x{side} torus, {L} rows a worker",
+        device, cfg, ds,
+        ("algorithm", "gossip_rounds", "state_leaves", "state_bytes",
+         "sampling", "batch_rows", "mixing", "grid_shape"),
+        return_state=True)
+    _check((root["gossip_rounds"], root["sampling"], root["mixing"],
+            root["grid_shape"]) == (2, "gather", "stencil", f"{side}x{side}"),
+           "the root says two rounds, the gather sampler, the grid's stencil")
+    hist = result.history
+    _check(bool(np.all(np.isfinite(hist.objective)))
+           and hist.objective[-1] < hist.objective[0]
+           and hist.consensus_error[0] == 0.0,
+           "the objective descends from f(0), finite; the first round is "
+           "pure gossip from zero")
+    state = result.final_state
+    mean_y, mean_g = state["y"].mean(axis=0), state["g_prev"].mean(axis=0)
+    eps = float(np.finfo(np.float32).eps)
+    gap = float(np.max(np.abs(mean_y - mean_g))) / (eps * float(np.max(np.abs(mean_g))))
+    print(f"[chip_smoke] tracker: |mean y - mean g_prev| after {iterations} "
+          f"iterations {gap:.2f} units of the mean's magnitude "
+          f"({float(np.max(np.abs(mean_g))):.4g}; the largest last gradient "
+          f"{float(np.max(np.abs(state['g_prev']))):.4g})", flush=True)
+    _check(gap <= TRACKER_MEAN_UNITS,
+           f"the tracker's mean is the gradients' within {TRACKER_MEAN_UNITS} "
+           f"units of its magnitude")
+
+    topo, _ = cached_topology("grid", n, impl=cfg.resolved_topology_impl())
+    op = make_mixing_op(topo, impl="auto")
+    rows = jax.random.normal(jax.random.key(39), (n, X.shape[1]), jnp.float32)
+    mixed = np.asarray(jax.jit(op.apply)(rows))
+    want = np.asarray(jax.jit(lambda u: gt_torus.torus_mix(u, side))(rows))
+    gap = float(np.max(np.abs(mixed - want))) / (eps * float(np.max(np.abs(want))))
+    print(f"[chip_smoke] tracker: one {op.impl} round against the reference's, "
+          f"worst gap {gap:.2f} units of the rows' scale", flush=True)
+    _check(op.impl == "stencil" and gap <= STENCIL_ROUND_ULPS,
+           f"the grid stencil is the reference's within {STENCIL_ROUND_ULPS} units")
+
+    # The row a batch entry came from rides in ``y``'s place.
+    t = jnp.asarray(7, jnp.int32)
+    row_ids = jnp.broadcast_to(jnp.arange(L, dtype=jnp.float32), (n, L))
+    Xd = jnp.asarray(X.reshape(n, L, -1))
+    Xb, ids, w = jax.jit(
+        lambda Xd, row_ids: sample_worker_batches(
+            jax.random.fold_in(jax.random.key(cfg.seed), 0), t, Xd, row_ids,
+            jnp.full((n,), L, jnp.int32), b)
+    )(Xd, row_ids)
+    ids = np.asarray(ids).astype(np.int64)
+    want = np.asarray(jax.jit(
+        lambda: batch_weights(cfg.seed, t, n, L, b))())
+    hit = np.take_along_axis(want, ids, axis=1)
+    _check(bool(np.all(hit == np.float32(1.0 / b)))
+           and bool(np.all(np.sum(want > 0, axis=1) == b))
+           and bool(np.all(np.diff(np.sort(ids, axis=1), axis=1) > 0)),
+           "the gathered batch is the b distinct rows the reference weighs")
+    some = np.arange(0, n, n // 64)
+    _check(np.array_equal(np.asarray(Xb)[some], X.reshape(n, L, -1)[some[:, None], ids[some]])
+           and bool(np.all(np.asarray(w) == np.float32(1.0 / b))),
+           "the gathered features are those rows', the weights 1/b")
+    print(f"[chip_smoke] tracker: iteration 7's batch is the reference's rows "
+          f"({n} workers x {b} of {L})", flush=True)
+
+
 # The benchmark's GLM limits (benchmark/configs/glm81_ring262k.json): worst
 # relative gap of the objective and of the consensus error over the rows.
 CARRY_LIMITS = {"objective": 1e-6, "consensus_error": 1e-5}
@@ -701,6 +813,7 @@ def main() -> int:
     softmax_segment(device)
     reference_segment(device)
     gather_round_segment(device)
+    tracker_segment(device)
     if device["count"] >= 4:
         four_chip_segment(device)
         halo_forms_segment(device)

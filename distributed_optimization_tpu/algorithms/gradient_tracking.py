@@ -18,6 +18,19 @@ t ≥ 1 by induction. This avoids needing a batch draw before the scan starts.
 Costs two gossip rounds per iteration (x and y), i.e. 2·Σdeg·d floats —
 reflected in ``gossip_rounds=2`` for the comms metric.
 
+The constant step against the graph (ISSUE 39; sandbox CPU runs and a plain
+full-batch restatement of the rule, the same readings): on target-sorted
+shards a worker's rows share one value of X·coef, so in the plane of that
+direction and the bias its Hessian is rank one, the null direction turning
+smoothly from worker to worker; a graph that mixes slowly enough no longer
+holds the tracker at a step that a small graph takes. At the ``gt-torus-64``
+preset's η = 0.01 on least squares (d = 81, b = 16) a 128 × 128 torus holds
+(consensus error peaking at 32,611 near iteration 200, then falling: 10,992
+at 1,500), a 256 × 256 torus runs away (33,430 at iteration 200, 520,646 at
+300) and a 512 × 512 one sooner (42,993 at 100, 5,727,491 at 150), whatever
+the rows a worker (53, 195 or full batch). It is the rule on this partition
+at this step, not the program: a larger torus takes a smaller step.
+
 Fault tolerance (``supports_edge_faults=True``, the default) is
 evidence-backed, not assumed: the tracking invariant is an algebraic
 identity whenever every realized W_t is doubly stochastic and a straggler's

@@ -670,6 +670,13 @@ def _flat_scan_cadence(scan_unroll: int, eval_every: int):
     return micro, eval_every // micro, max(1, scan_unroll // micro)
 
 
+def _device_bytes(tree) -> float:
+    """What a pytree's arrays take on the device, in the device's own tiles."""
+    return float(sum(
+        leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tree)
+    ))
+
+
 def _fault_root_args(config, faulty, tables) -> dict:
     """What the ``dopt.run`` root says of a call that ran faults: ``faults``
     (every active process with its rate), ``fault_form`` (``drawn``: each
@@ -697,9 +704,7 @@ def _fault_root_args(config, faulty, tables) -> dict:
         "fault_form": "drawn" if faulty.timeline is None else "timeline",
     }
     if tables is not None:
-        args["fault_bytes"] = float(sum(
-            leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
-        ))
+        args["fault_bytes"] = _device_bytes(tables)
     if faulty.addressing is not None:
         args["fault_mixing"] = faulty.addressing
     return args
@@ -720,9 +725,7 @@ def _gather_root_args(topo, tables) -> dict:
         "live_slot_share": live / (topo.n * k_max),
         "gathered_rows": int(tables["nbr"].size)
         + (topo.n if "inverse" in tables else 0),
-        "table_bytes": float(sum(
-            leaf.on_device_size_in_bytes() for leaf in jax.tree.leaves(tables)
-        )),
+        "table_bytes": _device_bytes(tables),
     }
 
 
@@ -1517,6 +1520,8 @@ def _run(
         # making: here, once, not in every later ``prepare``.
         spectral_gap = topo.spectral_gap
         spans.note(cache="hit" if topo_hit else "miss")
+        if topo.grid_shape is not None:
+            spans.note_root(grid_shape="x".join(map(str, topo.grid_shape)))
         spans.enter("prepare")
         if config.worker_mesh >= 2:
             # Sharded worker mesh (ISSUE-11 tentpole, docs/PERF.md §16):
@@ -1761,6 +1766,9 @@ def _run(
     # and how it picks what it keeps.
     spans.note_root(
         algorithm=config.algorithm,
+        # Model-sized exchanges an iteration (the rule's own count; the
+        # parameter-server pattern has no peer rounds).
+        gossip_rounds=algo.gossip_rounds if algo.is_decentralized else 0,
         compress=(
             "none" if config.compression == "none"
             else f"{config.compression}:{config.compression_k}/{d_model}"
@@ -1801,6 +1809,13 @@ def _run(
                     mesh, (compressed_mix.halo_rows, d_model),
                     device_data.X.dtype,
                 )
+    # What the scan carries from iteration to iteration, as the device
+    # holds it: the models, and whatever else the rule keeps (a tracker and
+    # the last gradients, CHOCO's copies, a halo's receiver side).
+    spans.note_root(
+        state_leaves=len(jax.tree.leaves(state0)),
+        state_bytes=_device_bytes(state0),
+    )
     key = jax.random.key(config.seed)
 
     schedule = None
@@ -1831,6 +1846,24 @@ def _run(
             "'gather' — forcing dense anyway as requested",
             stacklevel=2,
         )
+
+    # How an iteration's batch is had (the sampler's engagement counter) and
+    # the rows it holds, all workers together: ``full`` (b >= L: the shard,
+    # nothing drawn), ``dense`` (a ranking over the shard, weights on every
+    # row), ``gather`` (top_k, then the b rows fetched), ``scheduled``
+    # (injected indices, fetched).
+    batch_rows = (
+        schedule.shape[-1] if schedule is not None
+        else min(batch_size, device_data.X.shape[1])
+    )
+    spans.note_root(
+        sampling=(
+            "scheduled" if schedule is not None
+            else "full" if batch_size >= device_data.X.shape[1]
+            else sampling_impl
+        ),
+        batch_rows=n * int(batch_rows),
+    )
 
     # Sharded arrays are threaded through jit as ARGUMENTS, never captured:
     # a traced function that closes over an array spanning non-addressable

@@ -106,6 +106,14 @@ def test_one_root_with_the_named_children_in_order(setup):
         "forward": "recomputed",
         # How the static graph mixes on one device (ISSUE 36): a ring.
         "mixing": "stencil",
+        # The rule's gossip rounds, the state the scan carries (D-SGD: the
+        # models alone, as the device holds them) and the sampler that ran
+        # with the rows its batches hold (ISSUE 39; on the CPU auto draws
+        # by ``top_k`` and gathers).
+        "gossip_rounds": 1, "state_leaves": 1,
+        "state_bytes": float(cfg.n_workers * ds.n_features * 4),
+        "sampling": "gather",
+        "batch_rows": cfg.n_workers * cfg.local_batch_size,
     }
     by_name = {e["name"]: e for e in children}
     stacked = stack_shards(ds, dtype=np.float32)
