@@ -460,11 +460,14 @@ def tracker_segment(device: dict, *, iterations: int = 100, seed: int = 39) -> N
         f"gradient tracking on a {side}x{side} torus, {L} rows a worker",
         device, cfg, ds,
         ("algorithm", "gossip_rounds", "state_leaves", "state_bytes",
-         "sampling", "batch_rows", "mixing", "grid_shape"),
+         "sampling", "select", "batch_gathers", "batch_rows", "mixing",
+         "grid_shape"),
         return_state=True)
     _check((root["gossip_rounds"], root["sampling"], root["mixing"],
             root["grid_shape"]) == (2, "gather", "stencil", f"{side}x{side}"),
            "the root says two rounds, the gather sampler, the grid's stencil")
+    _check((root["select"], root["batch_gathers"]) == ("threshold:16", 1),
+           "a batch is selected by a counted threshold and fetched by one gather")
     hist = result.history
     _check(bool(np.all(np.isfinite(hist.objective)))
            and hist.objective[-1] < hist.objective[0]

@@ -45,8 +45,10 @@ from distributed_optimization_tpu.ops.compression import selection_label
 from distributed_optimization_tpu.ops.losses import paired_margins, sq_norm
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
 from distributed_optimization_tpu.ops.sampling import (
+    batch_table,
+    sample_table_batches,
     sample_worker_batch_weights,
-    sample_worker_batches,
+    targets_ride,
 )
 from distributed_optimization_tpu.ops.robust_aggregation import (
     make_gather_robust_activity,
@@ -369,7 +371,7 @@ def _make_step_eval(p: _StepPieces, data):
 
     # Full-batch fast path: sampling b >= L rows without replacement IS
     # the whole shard with 1/n_i weights (the reference's b=min(b, n_i)
-    # semantics, worker.py:21), so skip the per-iteration RNG + top_k +
+    # semantics, worker.py:21), so skip the per-iteration RNG + selection +
     # gather entirely — in the compute-bound tier the gather alone would
     # otherwise copy the full [N, L, d] every iteration, doubling HBM
     # traffic for no semantic effect.
@@ -382,6 +384,12 @@ def _make_step_eval(p: _StepPieces, data):
         full_wts = fmask / jnp.maximum(
             n_valid[:, None].astype(X.dtype), 1.0
         )
+
+    if not full_batch and schedule is None and p.sampling_impl != "dense":
+        # The gather sampler's own table, made once, before the loop: a
+        # draw is one gather of whole rows, the targets riding in them.
+        with device_scopes.scope("sampling"):
+            table = batch_table(X, y)
 
     if p.carry_forward:
         link = p.problem.link
@@ -411,7 +419,7 @@ def _make_step_eval(p: _StepPieces, data):
                 elif full_batch:
                     Xb, yb, wts = X, y, full_wts
                 elif p.sampling_impl == "dense":
-                    # Dense-weights sampling: no top_k, no gather — the
+                    # Dense-weights sampling: no indices, no gather — the
                     # weighted gradient runs over the full padded shard
                     # with 1/b weights on the sampled rows (same subsets as
                     # the gather path for the same key; see
@@ -423,8 +431,12 @@ def _make_step_eval(p: _StepPieces, data):
                     ).astype(X.dtype)
                 else:
                     slot_key = jax.random.fold_in(p.key, slot)
-                    Xb, yb, wts = sample_worker_batches(
-                        slot_key, t, X, y, n_valid, batch_size
+                    # A batch is drawn when its gradient is due: the draw
+                    # depends on nothing the scan carries, and XLA would make
+                    # a whole unrolled trip's keys at its start, [N, L] each.
+                    params, t_due = jax.lax.optimization_barrier((params, t))
+                    Xb, yb, wts = sample_table_batches(
+                        slot_key, t_due, table, n_valid, batch_size
                     )
                     wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
             with device_scopes.scope("gradient"):
@@ -1850,20 +1862,33 @@ def _run(
     # How an iteration's batch is had (the sampler's engagement counter) and
     # the rows it holds, all workers together: ``full`` (b >= L: the shard,
     # nothing drawn), ``dense`` (a ranking over the shard, weights on every
-    # row), ``gather`` (top_k, then the b rows fetched), ``scheduled``
-    # (injected indices, fetched).
+    # row), ``gather`` (the b rows picked by a counted threshold over the
+    # uniforms' bits, then fetched), ``scheduled`` (injected indices,
+    # fetched).
     batch_rows = (
         schedule.shape[-1] if schedule is not None
         else min(batch_size, device_data.X.shape[1])
     )
-    spans.note_root(
-        sampling=(
+    sampler = {
+        "sampling": (
             "scheduled" if schedule is not None
             else "full" if batch_size >= device_data.X.shape[1]
             else sampling_impl
         ),
-        batch_rows=n * int(batch_rows),
-    )
+        "batch_rows": n * int(batch_rows),
+    }
+    if sampler["sampling"] == "gather":
+        # A draw's gathers: one where the targets ride in the rows. Its
+        # selection is a ``random_k`` over a worker's L uniforms; ``select``
+        # stays the compressor's where there is one.
+        sampler["batch_gathers"] = (
+            1 if targets_ride(device_data.X.dtype, device_data.y.dtype) else 2
+        )
+        if config.compression == "none":
+            sampler["select"] = selection_label(
+                "random_k", device_data.X.dtype
+            )
+    spans.note_root(**sampler)
 
     # Sharded arrays are threaded through jit as ARGUMENTS, never captured:
     # a traced function that closes over an array spanning non-addressable

@@ -367,9 +367,11 @@ class ExperimentConfig:
     # docs/perf/sparse_mixing.json; both remain explicit opt-ins).
     mixing_impl: str = "auto"
     # 'auto' | 'gather' | 'dense'. Mini-batch realization on the jax backend:
-    # 'gather' materializes [N, b, d] batches (top_k + row gathers), 'dense'
+    # 'gather' materializes [N, b, d] batches (the b largest uniforms by a
+    # counted threshold, no sort; one gather of whole rows), 'dense'
     # computes the weighted gradient over the full padded shard with 1/b
-    # weights on the sampled rows — same sampled subsets, no top_k/gather.
+    # weights on the sampled rows — same sampled subsets, no selection of
+    # indices and no gather.
     # 'auto' picks from measurement (see resolved_sampling_impl).
     sampling_impl: str = "auto"
     # XLA scan unrolling for the jax backend's training loop. Swept on the
@@ -1400,7 +1402,11 @@ class ExperimentConfig:
 
         On the real chip (docs/perf/breakdown.json §sampling) the dense
         weighted-gradient form wins decisively when shards are small — the
-        latency-bound regime where top_k+gather dominate the iteration:
+        latency-bound regime where the gather form's selection and row
+        gathers dominate the iteration (read pre-ledger against its old
+        form, a ``top_k`` and two gathers a draw; since ISSUE 40 it selects
+        by a counted threshold and gathers once, and the rule's lower side
+        has not been read again: PERF.md section 7 row 5):
         2.5x at N=256 (L=49), 10x at N=1024 (L=13) — while the gather path
         wins for large shards (N=25, L=500: 1.8x) where the full-shard pass
         costs real FLOPs; the two tie within chip noise for L ~ 100-250.

@@ -291,10 +291,14 @@ def test_forward_is_carried_under_the_cells_faults(monkeypatch):
 # the two builders are called directly, on the same ring, keys and t.
 
 # jax 0.9's lowering of the two graphs below on the parent commit (fd89778),
-# taken there by ``lowered_scan`` on ``cfg_of(graph, n_iterations=37)``: what
-# "a graph that is not a shift runs the parent's program" is held to. Another
-# jax spells the text its own way and is held to the builder's name alone.
-PARENT_SCANS = {"jax": "0.9", "chain": "63277440065029ae", "sparse_er": "18283ae3dc7839e0"}
+# taken by ``lowered_scan`` on ``cfg_of(graph, n_iterations=37,
+# sampling_impl="dense")``: what "a graph that is not a shift runs the
+# parent's program" is held to. Another jax spells the text its own way and
+# is held to the builder's name alone. Under the dense sampler since ISSUE
+# 40, which changed the gather sampler these runs took on the CPU: the pins
+# were taken again on ISSUE 40's parent (62d21c7), where the gather form
+# still hashed to fd89778's ("63277440065029ae", "18283ae3dc7839e0").
+PARENT_SCANS = {"jax": "0.9", "chain": "67058614145c344e", "sparse_er": "77c45438f0fd285b"}
 
 
 def ring_of(n):
@@ -451,7 +455,7 @@ def test_the_form_is_read_off_the_table(graph, is_ring):
 
 @pytest.mark.parametrize("graph", ["chain", "sparse_er"])
 def test_a_graph_that_is_not_a_shift_runs_the_parents_program(graph, monkeypatch):
-    cfg = cfg_of(graph, n_iterations=37)
+    cfg = cfg_of(graph, n_iterations=37, sampling_impl="dense")
     ds = generate_synthetic_dataset(cfg)
     assert run_rooted(cfg, ds)[1]["fault_mixing"] == "gather"
     text, data = lowered_scan(cfg, ds, monkeypatch)

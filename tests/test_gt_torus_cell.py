@@ -189,6 +189,9 @@ def test_the_root_says_the_rule_its_state_and_its_sampler(cell):
     assert (args["algorithm"], args["gossip_rounds"]) == ("gradient_tracking", 2)
     assert (args["state_leaves"], args["state_bytes"]) == (3, 3 * 64 * 81 * 4)
     assert (args["sampling"], args["batch_rows"]) == ("gather", 64 * 16)
+    # drawn by a counted threshold over the uniforms' 32 bits, two a pass; one
+    # gather a draw, the targets riding in the rows
+    assert (args["select"], args["batch_gathers"]) == ("threshold:16", 1)
     assert (args["mixing"], args["grid_shape"], args["forward"]) == ("stencil", "8x8", "recomputed")
     # both x and y cross every edge every round
     assert args["wire_floats_per_edge"] == 2 * 81
@@ -205,6 +208,8 @@ def test_the_root_of_dsgd_says_one_round_and_one_leaf(sampling_impl, batch, said
     assert (args["algorithm"], args["gossip_rounds"]) == ("dsgd", 1)
     assert (args["state_leaves"], args["state_bytes"]) == (1, 64 * 81 * 4)
     assert (args["sampling"], args["batch_rows"]) == (said, 64 * min(batch, 24))
+    assert args["select"] == ("threshold:16" if said == "gather" else "none")
+    assert args.get("batch_gathers") == (1 if said == "gather" else None)
     assert "grid_shape" not in args
 
 

@@ -86,7 +86,9 @@ def test_one_root_with_the_named_children_in_order(setup):
     # ``carry``: the shape of the scan's model leaf, [N, *param_shape].
     # ``algorithm``, ``compress``, ``select``, ``wire_floats_per_edge``:
     # what ran and what one edge carries an iteration (plain D-SGD: the
-    # whole model, nothing selected).
+    # whole model; nothing is compressed, so ``select`` says how the gather
+    # sampler picks a batch's rows: a counted threshold over the uniforms'
+    # 32 bits, ISSUE 40).
     # ``program``, ``temp_bytes`` (ISSUE 34): a key of the executable the call
     # ran and its temporaries by ``memory_analysis()`` (tests/test_device_scopes.py).
     args = dict(root["args"])
@@ -94,7 +96,7 @@ def test_one_root_with_the_named_children_in_order(setup):
     assert args == {
         "path": "fused", "cache": "miss",
         "carry": f"{cfg.n_workers}x{ds.n_features}",
-        "algorithm": "dsgd", "compress": "none", "select": "none",
+        "algorithm": "dsgd", "compress": "none", "select": "threshold:16",
         "wire_floats_per_edge": float(ds.n_features),
         # How the shards were stacked and how they went up (ISSUE 29): an
         # ``argsort`` partition is gathered; so small a stack goes up as it
@@ -109,10 +111,11 @@ def test_one_root_with_the_named_children_in_order(setup):
         # The rule's gossip rounds, the state the scan carries (D-SGD: the
         # models alone, as the device holds them) and the sampler that ran
         # with the rows its batches hold (ISSUE 39; on the CPU auto draws
-        # by ``top_k`` and gathers).
+        # indices and gathers: once a draw, the targets riding in the rows,
+        # ISSUE 40).
         "gossip_rounds": 1, "state_leaves": 1,
         "state_bytes": float(cfg.n_workers * ds.n_features * 4),
-        "sampling": "gather",
+        "sampling": "gather", "batch_gathers": 1,
         "batch_rows": cfg.n_workers * cfg.local_batch_size,
     }
     by_name = {e["name"]: e for e in children}

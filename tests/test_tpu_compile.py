@@ -94,3 +94,53 @@ def test_a_gather_round_adds_a_chunk_in_place(drawn_graph_round):
     # x to row-major for the first chunk and for the loop, the result back
     # to the carried layout
     assert len(copies) <= 3, copies
+
+
+@pytest.fixture(scope="module")
+def gather_sampler_loop(one_chip):
+    """The gather sampler at the tracker cell's size (16,384 shards of 800
+    rows of 81 floats, 16 drawn a worker) as the scan runs it: its table made
+    before the loop, a draw a trip, both under ``dopt.sampling``."""
+    from distributed_optimization_tpu.ops.sampling import batch_table, sample_table_batches
+
+    n, rows, d, batch = 16_384, 800, 81, 16
+
+    def loop(X, y, n_valid):
+        key = jax.random.key(7)
+        with device_scopes.scope("sampling"):
+            table = batch_table(X, y)
+
+        def trip(acc, t):
+            with device_scopes.scope("sampling"):
+                Xb, yb, w = sample_table_batches(key, t, table, n_valid, batch)
+            return acc + jnp.einsum("nbd,nb->nd", Xb, yb * w), None
+
+        return jax.lax.scan(trip, jnp.zeros((n, d), X.dtype), jnp.arange(8, dtype=jnp.int32))[0]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return jax.jit(loop).lower(
+        shape((n, rows, d), jnp.float32), shape((n, rows), jnp.float32),
+        shape((n,), jnp.int32)).compile()
+
+
+def test_the_gather_sampler_sorts_and_scatters_nothing(gather_sampler_loop):
+    """A batch is selected by a counted threshold and fetched by ONE gather
+    of whole rows, the targets riding in them."""
+    ops = [ins for ins in map(device_scopes._instruction, gather_sampler_loop.as_text().splitlines())
+           if ins is not None]
+    assert not [ins for ins in ops if ins[2] in ("sort", "scatter")]
+    assert "TopK" not in gather_sampler_loop.as_text()
+    gathers = [ins for ins in ops if ins[2] == "gather"]
+    # 16,384 x 16 rows of 82 floats, whichever way the batch axes are merged
+    assert len(gathers) == 1 and device_scopes._shape_bytes(gathers[0][1]) == 262_144 * 82 * 4, gathers
+    assert "dopt.sampling" in gathers[0][4]
+
+
+def test_the_gather_samplers_table_is_its_one_large_temporary(gather_sampler_loop):
+    """The table, 82 numbers a row padded to 128 lanes (6.71 GB: what XLA's
+    own row-major copy of the shards took at PR 39), and no second array of
+    its size beside it while it is filled or read."""
+    memory = gather_sampler_loop.memory_analysis()
+    assert 6_710_886_400 <= memory.temp_size_in_bytes < 7_400_000_000, memory.temp_size_in_bytes
