@@ -30,13 +30,16 @@ without a TPU it exits before doing any work. Segments:
    gather form's executable keeps 0.54 GB of it on every chip).
 
 5. The carried forward product at a size the chip notices (N = 65,536 workers
-   of 53 rows, d = 81: a 1.1 GB stack): one run with the margins X·x carried
-   from the eval to the next step against the same run recomputing them, the
+   of 53 rows, d = 81: a 1.1 GB stack): the run as the chip takes it
+   (``forward`` = ``fused`` since ISSUE 41: one visit of the shards by
+   ``ops.pallas_kernels.glm_shard_visit`` leaves the objective and the next
+   gradient) and the same run with the margins X·x carried (ISSUE 31, the
+   visit's rule switched off) against the same run recomputing them, the
    objective and consensus rows within the benchmark's GLM limits, the peak
-   of device memory within 0.1 GB, and the carried run split at an eval
+   of device memory within 0.1 GB, and the fused run split at an eval
    boundary bitwise the unsplit run. What the CPU cannot see: where the
    carry's buffers live, and whether the chip's two compilations of the
-   paired pass (in the loop, in front of it) round alike.
+   visit (in the loop, in front of it) round alike.
 
 6. The same stack under the README's faults (30% of the links down, 10% of
    the workers out, every round; ISSUE 32), right after segment 5 so that the
@@ -87,6 +90,16 @@ without a TPU it exits before doing any work. Segments:
    the rows the reference's ``batch_weights`` weighs. What the CPU cannot
    see: ``sampling_impl`` auto resolving to ``gather`` above 64 rows, and
    whether the chip's ``top_k`` and the reference's pick the same rows.
+
+10. The shard visit at the GLM cells' shapes cut to 2^14 workers (ISSUE 41):
+   the kernel's gradient and loss sums against XLA's two passes within 1e-6
+   of their scale; a run whose root says ``forward`` = ``fused`` and whose
+   compiled scan holds the kernel's call and no ``copy`` or ``transpose`` of
+   the shard stack (the kernel's ``[d, L, N]`` view is a bitcast of what the
+   runtime keeps); where four chips are visible the same run under
+   ``worker_mesh=4``, ``fused`` too, within ``MESH_ATOL`` of the unsharded
+   one. What the CPU cannot see: Mosaic's compile, the runtime's layout of
+   the stack, and whether GSPMD leaves the ``shard_map`` round the call alone.
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -528,17 +541,14 @@ CARRY_LIMITS = {"objective": 1e-6, "consensus_error": 1e-5}
 CARRY_PEAK_ROOM = 100_000_000  # bytes the carried program may hold more
 
 
-def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
-                          rows: int = 53, d: int = 80,
-                          n_iterations: int = 200):
-    """Runs first on its chip, recomputed before carried, so that the peak
-    counter (which only rises) prices what the carry adds. Returns its
-    experiment and the peak it leaves, for ``faults_segment``."""
-    from distributed_optimization_tpu.backends import jax_backend
+def _glm_ring(seed: int, n_workers: int, rows: int, d: int, n_iterations: int,
+              **config):
+    """``(cfg, dataset)``: logistic D-SGD on a ring of ``n_workers`` shards of
+    ``rows`` rows, d + 1 features (eight informative, a bias column), f32."""
     from distributed_optimization_tpu.config import ExperimentConfig
     from distributed_optimization_tpu.utils.data import HostDataset
 
-    rng = np.random.default_rng(31)
+    rng = np.random.default_rng(seed)
     n = n_workers * rows
     y = np.where(rng.random(n) < 0.5, -1.0, 1.0).astype(np.float32)
     X = rng.standard_normal((n, d + 1), dtype=np.float32)
@@ -551,39 +561,55 @@ def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
     cfg = ExperimentConfig(
         problem_type="logistic", algorithm="dsgd", topology="ring",
         n_workers=n_workers, n_samples=n, n_features=d,
-        n_informative_features=8, n_iterations=n_iterations,
+        n_informative_features=8, n_iterations=n_iterations, **config,
     )
+    return cfg, ds
 
-    def run(label, **kw):
-        result, root, peak = _rooted_run(
-            f"forward {label}", device, cfg, ds, ("forward",), **kw)
-        return result, root["forward"], peak
 
-    decide = jax_backend._forward_is_carried
-    jax_backend._forward_is_carried = lambda *a, **k: False
-    try:
-        want, how, peak_recomputed = run("recomputed")
-    finally:
-        jax_backend._forward_is_carried = decide
-    _check(how == "recomputed", "the private switch gives the recomputed program")
-    got, how, peak_carried = run("carried")
-    _check(how == "carried",
-           "a GLM's D-SGD over 53-row shards carries its forward product")
-    for key, limit in CARRY_LIMITS.items():
-        a, b = getattr(got.history, key), getattr(want.history, key)
-        rel = float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)))
-        print(f"[chip_smoke] forward: {key} carried against recomputed, worst "
-              f"relative gap {rel:.3e} (limit {limit})", flush=True)
-        _check(a.shape == (n_iterations,) and rel <= limit,
-               f"{key} rows of the carried run within {limit} of the recomputed")
+def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
+                          rows: int = 53, d: int = 80,
+                          n_iterations: int = 200):
+    """Runs first on its chip, recomputed before carried, so that the peak
+    counter (which only rises) prices what the carry adds. Returns its
+    experiment and the peak it leaves, for ``faults_segment``."""
+    from distributed_optimization_tpu.backends import jax_backend
+
+    cfg, ds = _glm_ring(31, n_workers, rows, d, n_iterations)
+
+    def run(form, switched_off=None, how="", **kw):
+        """One run that must say ``forward`` = ``form``, a private rule of
+        ``jax_backend`` switched off for it."""
+        decide = switched_off and getattr(jax_backend, switched_off)
+        if switched_off:
+            setattr(jax_backend, switched_off, lambda *a, **k: False)
+        try:
+            result, root, peak = _rooted_run(
+                f"forward {form}{how}", device, cfg, ds, ("forward",), **kw)
+        finally:
+            if switched_off:
+                setattr(jax_backend, switched_off, decide)
+        _check(root["forward"] == form, f"the run's root says forward={form}")
+        return result, peak
+
+    want, peak_recomputed = run("recomputed", "_forward_is_carried")
+    peaks = {}
+    for form, rule in (("carried", "_visit_is_fused"), ("fused", None)):
+        got, peaks[form] = run(form, rule)
+        for key, limit in CARRY_LIMITS.items():
+            a, b = getattr(got.history, key), getattr(want.history, key)
+            rel = float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)))
+            print(f"[chip_smoke] forward: {key} {form} against recomputed, "
+                  f"worst relative gap {rel:.3e} (limit {limit})", flush=True)
+            _check(a.shape == (n_iterations,) and rel <= limit,
+                   f"{key} rows of the {form} run within {limit} of the recomputed")
     print(f"[chip_smoke] forward: peak_bytes recomputed={peak_recomputed} "
-          f"carried={peak_carried}", flush=True)
-    _check(peak_carried - peak_recomputed <= CARRY_PEAK_ROOM,
+          + " ".join(f"{k}={v}" for k, v in peaks.items()), flush=True)
+    _check(max(peaks.values()) - peak_recomputed <= CARRY_PEAK_ROOM,
            f"the carry costs no more than {CARRY_PEAK_ROOM} bytes of device memory")
-    split, _, _ = run("carried, in segments of 80 evals",
-                      progress_cb=lambda ev: None, progress_every=80)
+    split, _ = run("fused", how=", in segments of 80 evals",
+                   progress_cb=lambda ev: None, progress_every=80)
     _check(_same_run(split, got),
-           "the carried run split at eval boundaries is bitwise the unsplit run")
+           "the fused run split at eval boundaries is bitwise the unsplit run")
     return cfg, ds, _peak_bytes()
 
 
@@ -628,9 +654,9 @@ def faults_segment(device: dict, cfg, ds, peak_fault_free: int) -> None:
     say = ("faults", "fault_form", "fault_mixing", "fault_bytes",
            "live_edge_share", "forward")
     got, root, peak = _rooted_run("faults p=0.3 q=0.1", device, cfg, ds, say)
-    _check(root["fault_form"] == "drawn" and root["forward"] == "carried",
+    _check(root["fault_form"] == "drawn" and root["forward"] == "fused",
            "memoryless faults on the neighbor table are drawn in the step, "
-           "and the forward product stays carried")
+           "and the next gradient stays in the carry")
     _check(root["fault_mixing"] == "shift" and root["fault_bytes"] == 0.0,
            "a ring's neighbours are read by shifts, with no table handed "
            "to the scan")
@@ -750,6 +776,78 @@ def _one_round_both_ways(cfg, rounds=(0, 7)) -> None:
         _check(gap <= 1e-6, "the two forms' mixed rows agree within 1e-6")
 
 
+VISIT_RTOL = 1e-6  # of each result's scale: sums in another order, f32
+
+
+def shard_visit_segment(device: dict, *, n_workers: int = 1 << 14,
+                        rows: int = 53, d: int = 80,
+                        n_iterations: int = 100) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_optimization_tpu.backends import jax_backend
+    from distributed_optimization_tpu.observability import device_scopes
+    from distributed_optimization_tpu.observability.spans import process_tracer
+    from distributed_optimization_tpu.ops import losses, pallas_kernels
+
+    cfg, ds = _glm_ring(41, n_workers, rows, d, n_iterations,
+                        topology_impl="neighbor")
+    rng = np.random.default_rng(41)
+    # The kernel alone against XLA's two passes, ragged row counts.
+    Xs = jnp.asarray(ds.X_full.reshape(n_workers, rows, d + 1))
+    ys = jnp.asarray(ds.y_full.reshape(n_workers, rows))
+    x = jnp.asarray(0.3 * rng.standard_normal((n_workers, d + 1), dtype=np.float32))
+    n_valid = jnp.asarray(rng.integers(0, rows + 1, n_workers), jnp.int32)
+    valid = jnp.arange(rows)[None, :] < n_valid[:, None]
+    wts = valid * jnp.asarray(rng.random((n_workers, rows), dtype=np.float32))
+    xbar = jnp.mean(x, axis=0)
+    link = losses.LOGISTIC
+
+    @jax.jit
+    def two_passes():
+        z, zbar = losses.paired_margins(Xs, x, xbar)
+        g = jax.vmap(link.gradient_at, in_axes=(0, 0, 0, 0, 0, None))(
+            z, x, Xs, ys, wts, 0.0)
+        return g, jnp.sum(valid * link.loss(zbar, ys), axis=1)
+
+    got = jax.jit(lambda: pallas_kernels.glm_shard_visit(
+        link, Xs, ys, x, xbar, wts, n_valid))()
+    for name, a, b in zip(("gradient", "loss sums"), got, two_passes()):
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        print(f"[chip_smoke] visit: {name} against XLA's two passes, worst gap "
+              f"{rel:.3e} of the scale (limit {VISIT_RTOL})", flush=True)
+        _check(rel <= VISIT_RTOL, f"the visit's {name} within {VISIT_RTOL}")
+
+    one, root, _ = _rooted_run("visit", device, cfg, ds, ("forward",))
+    _check(root["forward"] == "fused",
+           "a GLM's D-SGD over 53-row shards on a TPU visits them once")
+    text = device_scopes._held[-1].as_text()  # cache off: the holder kept it
+    stack = (f"f32[{n_workers},{rows},{d + 1}]", f"f32[{d + 1},{rows},{n_workers}]")
+    moved = [
+        ins[0] for ins in map(device_scopes._instruction, text.splitlines())
+        if ins is not None and ins[2] in ("copy", "transpose")
+        and ins[1].startswith(stack)
+    ]
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    print(f"[chip_smoke] visit: compiled scan holds {calls} kernel calls, "
+          f"copies or transposes of the stack: {moved}", flush=True)
+    _check(calls >= 2 and not moved,
+           "the compiled scan calls the kernel and moves no shard stack")
+    if device["count"] < 4:
+        return
+    sharded = jax_backend.run(cfg.replace(worker_mesh=4), ds, 0.0,
+                              executable_cache=False)
+    root = [e["args"] for e in process_tracer().spans()
+            if e["name"] == "dopt.run"][-1]
+    err = float(np.max(np.abs(sharded.final_models - one.final_models)))
+    print(f"[chip_smoke] visit: worker_mesh=4 forward={root['forward']} "
+          f"mixing={root['mixing']}, max |x_mesh4 - x_mesh0| = {err:.2e} "
+          f"(tolerance {MESH_ATOL})", flush=True)
+    _check(root["forward"] == "fused" and sharded.history.mesh_devices == 4,
+           "under worker_mesh=4 every chip visits its own shards")
+    _check(err <= MESH_ATOL, "the sharded fused run agrees with the unsharded")
+
+
 def four_chip_segment(device: dict, *, n_workers: int = 100_000,
                       n_samples: int = 200_000, n_iterations: int = 100) -> None:
     from distributed_optimization_tpu.config import ExperimentConfig
@@ -817,6 +915,7 @@ def main() -> int:
     reference_segment(device)
     gather_round_segment(device)
     tracker_segment(device)
+    shard_visit_segment(device)
     if device["count"] >= 4:
         four_chip_segment(device)
         halo_forms_segment(device)

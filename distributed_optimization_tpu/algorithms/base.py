@@ -131,8 +131,10 @@ class Algorithm:
     # themselves: ``ctx.grad(state["x"], 0)``, that very array. The jax scan
     # may then hand that call the margins X·x it carried from the last eval
     # instead of reading the shards for them (jax_backend.
-    # _forward_is_carried); a gradient anywhere else (x/w, a mixed or
-    # half-stepped model, a later slot) is computed as ever.
+    # _forward_is_carried), or the gradient itself where the eval's one
+    # visit of the shards made it (_visit_is_fused); a gradient anywhere
+    # else (x/w, a mixed or half-stepped model, a later slot) is computed
+    # as ever.
     first_grad_at_x: bool = False
     # Optional override of the per-edge float payload for comms accounting:
     # (config, d) -> floats per edge per iteration. None = d · gossip_rounds

@@ -29,6 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from distributed_optimization_tpu.algorithms import get_algorithm
 from distributed_optimization_tpu.algorithms.base import StepContext
@@ -75,6 +76,7 @@ from distributed_optimization_tpu.parallel import build_topology
 from distributed_optimization_tpu.parallel.topology import cached_topology
 from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
+    WORKER_AXIS,
     make_worker_mesh,
     place_shards,
     replicate,
@@ -91,11 +93,16 @@ from distributed_optimization_tpu.utils.data import HostDataset, stack_shards
 DENSE_SAMPLING_WARN_ROWS = 256
 
 
+def _total_rows(X, n_valid):
+    """The real rows of all shards together, at least 1, in X's type."""
+    return jnp.maximum(jnp.sum(n_valid).astype(X.dtype), 1.0)
+
+
 def _full_data_weights(X, n_valid):
     """``[N, L]`` weights of the global mean over the stacked shards:
     padding rows 0, every real row 1/total."""
     mask = (jnp.arange(X.shape[1])[None, :] < n_valid[:, None]).astype(X.dtype)
-    return mask / jnp.maximum(jnp.sum(n_valid).astype(X.dtype), 1.0)
+    return mask / _total_rows(X, n_valid)
 
 
 def make_full_objective_fn(problem, reg):
@@ -321,9 +328,14 @@ class _StepPieces:
     # (collectives.make_halo_compressed_mixing_op); only set on the
     # worker-mesh path with compression != 'none'.
     compressed_mix: object = None
-    # ``_forward_is_carried``: the eval's pass over X also yields the next
-    # trip's margins X·x. Never on the replica-batched path.
-    carry_forward: bool = False
+    # What of the next trip's first gradient the eval's pass over X leaves
+    # in the scan's carry: nothing (``recomputed``), the margins X·x
+    # (``carried``: ``_forward_is_carried``) or the gradient itself
+    # (``fused``: ``_visit_is_fused``). Never carried on the replica-batched
+    # path.
+    forward: str = "recomputed"
+    # The worker mesh the scan's rows are sharded over (None: one device).
+    mesh: object = None
 
 
 def _forward_is_carried(algo, problem, config, faulty, *, rows, batch_size,
@@ -348,13 +360,37 @@ def _forward_is_carried(algo, problem, config, faulty, *, rows, batch_size,
     )
 
 
+def _visit_is_fused(carried, X):
+    """Whether the forward product a scan carries is the next gradient
+    itself, made with the objective at x̄ by ONE read of the shards
+    (``ops.pallas_kernels.glm_shard_visit``) where ``carried`` reads them
+    twice: decided by what the run is, never by an option. All of
+    ``_forward_is_carried``'s conditions and: the program is compiled for a
+    TPU (an interpreted kernel is no program to run users on, so a CPU keeps
+    ``carried``), float32 shards, and a block of 128 workers' shards fits the
+    kernel's VMEM budget twice. Anything else stays as it was."""
+    from distributed_optimization_tpu.ops.pallas_kernels import (
+        LANES,
+        shard_visit_lanes,
+    )
+
+    return bool(
+        carried and jax.default_backend() == "tpu"
+        and X.dtype == jnp.float32
+        and shard_visit_lanes(LANES, *X.shape[1:], X.dtype.itemsize)
+    )
+
+
 def _make_step_eval(p: _StepPieces, data):
     """Bind the step/eval/floats closures to the data pytree passed through
     jit (shared by the sequential and replica-batched paths — see
-    ``_StepPieces``). Under ``p.carry_forward`` ``step(state, t, z)`` takes
-    the margins of ``state["x"]``, ``eval_metrics`` returns them for the
-    state it was shown (``(rows, z)``) and ``init_forward(state)`` makes the
-    ones a scan starts from; otherwise z is None throughout."""
+    ``_StepPieces``). Where ``p.forward`` is not ``recomputed``,
+    ``step(state, t, fwd)`` takes the forward product of ``state["x"]`` (its
+    margins X·x under ``carried``; under ``fused`` its gradient at
+    iteration t's batch, less ``λx``), ``eval_metrics`` returns the one of
+    the state it was shown (``(rows, fwd)``; ``fused``: for iteration
+    ``t_last + 1``) and ``init_forward(state, t0)`` makes the one a scan
+    starts from; otherwise fwd is None throughout."""
     X, y, n_valid = data["X"], data["y"], data["n_valid"]
     schedule = data.get("schedule")
     batch_size = p.batch_size
@@ -391,22 +427,64 @@ def _make_step_eval(p: _StepPieces, data):
         with device_scopes.scope("sampling"):
             table = batch_table(X, y)
 
-    if p.carry_forward:
+    if p.forward != "recomputed":
         link = p.problem.link
+    if p.forward == "carried":
         eval_wts = _full_data_weights(X, n_valid)
+    elif p.forward == "fused":
+        total_rows = _total_rows(X, n_valid)
 
-    def full_objective(x, xbar):
-        """``(f(x̄) over the full data, z)``; carried, both from ONE pass over
-        X: ``z = X·x`` is the next trip's forward product. Else z is None."""
-        if not p.carry_forward:
+    def dense_weights(t, slot):
+        """The dense sampler's weights over the padded shard: a function of
+        (key, slot, t, worker) alone, so a trip can draw the next one's."""
+        return sample_worker_batch_weights(
+            jax.random.fold_in(p.key, slot), t, n_valid, X.shape[1],
+            batch_size,
+        ).astype(X.dtype)
+
+    def shard_visit(x, xbar, t_next):
+        """ONE read of the shards: the gradient (less ``λx``) of iteration
+        ``t_next`` at x and each worker's sum of losses at x̄ over its real
+        rows. Every quantity is a worker's own, so under a mesh each device
+        visits its rows and no collective is added."""
+        from distributed_optimization_tpu.ops.pallas_kernels import (
+            glm_shard_visit,
+        )
+
+        with device_scopes.scope("sampling"):
+            wts = full_wts if full_batch else dense_weights(t_next, 0)
+        visit = functools.partial(glm_shard_visit, link)
+        if p.mesh is not None:
+            rows, whole = P(WORKER_AXIS), P()
+            visit = jax.shard_map(
+                visit, mesh=p.mesh,
+                in_specs=(rows, rows, rows, whole, rows, rows),
+                out_specs=(rows, rows), check_vma=False,
+            )
+        with device_scopes.scope("gradient"):
+            return visit(X, y, x, xbar, wts, n_valid)
+
+    def full_objective(x, xbar, t_next):
+        """``(f(x̄) over the full data, fwd)``; both from ONE pass over X
+        where a forward product is carried: the margins ``X·x``
+        (``carried``) or the gradient of iteration ``t_next`` at x
+        (``fused``). Else fwd is None."""
+        if p.forward == "recomputed":
             return p.full_objective(xbar, X, y, n_valid), None
-        z, zbar = paired_margins(X, x, xbar)
-        data_loss = jnp.sum(jnp.sum(eval_wts * link.loss(zbar, y), axis=1))
-        return data_loss + 0.5 * p.reg * sq_norm(xbar), z
+        if p.forward == "fused":
+            fwd, per_worker = shard_visit(x, xbar, t_next)
+            data_loss = jnp.sum(per_worker) / total_rows
+        else:
+            fwd, zbar = paired_margins(X, x, xbar)
+            data_loss = jnp.sum(
+                jnp.sum(eval_wts * link.loss(zbar, y), axis=1)
+            )
+        return data_loss + 0.5 * p.reg * sq_norm(xbar), fwd
 
-    def grad_fn_factory(t, z=None, z_of=None):
-        """The iteration's ``ctx.grad``; ``z`` = X·``z_of`` as the scan
-        carried it, used where the rule asks at that very array, slot 0."""
+    def grad_fn_factory(t, fwd=None, fwd_of=None):
+        """The iteration's ``ctx.grad``; ``fwd`` is the forward product of
+        ``fwd_of`` as the scan carried it, used where the rule asks at that
+        very array, slot 0."""
         def grad(params, slot):
             with device_scopes.scope("sampling"):
                 if schedule is not None:
@@ -424,11 +502,7 @@ def _make_step_eval(p: _StepPieces, data):
                     # with 1/b weights on the sampled rows (same subsets as
                     # the gather path for the same key; see
                     # ops/sampling.py).
-                    slot_key = jax.random.fold_in(p.key, slot)
-                    Xb, yb = X, y
-                    wts = sample_worker_batch_weights(
-                        slot_key, t, n_valid, X.shape[1], batch_size
-                    ).astype(X.dtype)
+                    Xb, yb, wts = X, y, dense_weights(t, slot)
                 else:
                     slot_key = jax.random.fold_in(p.key, slot)
                     # A batch is drawn when its gradient is due: the draw
@@ -440,18 +514,20 @@ def _make_step_eval(p: _StepPieces, data):
                     )
                     wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
             with device_scopes.scope("gradient"):
-                if z is not None and params is z_of and slot == 0:
+                if fwd is not None and params is fwd_of and slot == 0:
+                    if p.forward == "fused":
+                        return fwd + p.reg * params
                     return jax.vmap(
                         link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
-                    )(z, params, Xb, yb, wts, p.reg)
+                    )(fwd, params, Xb, yb, wts, p.reg)
                 return jax.vmap(
                     p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
                 )(params, Xb, yb, wts, p.reg)
 
         return grad
 
-    def step(state, t, z=None):
-        z_of = state["x"]  # the array the carried margins are of
+    def step(state, t, fwd=None):
+        fwd_of = state["x"]  # the array the carried forward product is of
         if faulty is not None and faulty.rejoin_restart is not None:
             # neighbor_restart rejoin policy: BEFORE the step at the
             # rejoin round, a node coming back from an outage replaces
@@ -496,7 +572,7 @@ def _make_step_eval(p: _StepPieces, data):
                 )
             )
         ctx = StepContext(
-            grad=grad_fn_factory(t, z, z_of),
+            grad=grad_fn_factory(t, fwd, fwd_of),
             mix=mix_fn,
             neighbor_sum=nbr_fn,
             # Cast to the run dtype so low-precision carries (bfloat16)
@@ -595,7 +671,7 @@ def _make_step_eval(p: _StepPieces, data):
         steady overhead on the CPU container; docs/perf/telemetry.json).
         """
         out = {}
-        z_next = None
+        fwd_next = None
         if p.telemetry:
             if cadence_known:
                 out["trace"] = trace_row(state, t_last)
@@ -623,7 +699,7 @@ def _make_step_eval(p: _StepPieces, data):
                     xbar = jnp.sum(
                         x * jnp.expand_dims(hw, param_axes), axis=0
                     ) / nh
-                    f_bar, z_next = full_objective(x, xbar)
+                    f_bar, fwd_next = full_objective(x, xbar, t_last + 1)
                     out["gap"] = f_bar - p.f_opt
                     if p.track_consensus:
                         out["cons"] = jnp.sum(
@@ -633,24 +709,29 @@ def _make_step_eval(p: _StepPieces, data):
                         ) / nh
                 else:
                     xbar = jnp.mean(x, axis=0)
-                    f_bar, z_next = full_objective(x, xbar)
+                    f_bar, fwd_next = full_objective(x, xbar, t_last + 1)
                     out["gap"] = f_bar - p.f_opt
                     if p.track_consensus:
                         out["cons"] = jnp.mean(
                             jnp.sum((x - xbar[None]) ** 2, axis=param_axes)
                         )
-        return out, z_next
+        return out, fwd_next
 
     @device_scopes.scope("eval")
-    def init_forward(state):
-        """The margins a scan starts from, by the paired pass itself. The
-        barrier keeps x̄'s half alive: with it thrown away the compiler
-        makes another reduction of the half that is left, and a segment's
-        first trip is no longer bitwise the unsplit run's (at d = 81, CPU)."""
-        if not p.carry_forward:
+    def init_forward(state, t0):
+        """The forward product a scan starts from at iteration ``t0``, by
+        the eval's own pass. The barrier keeps x̄'s half alive: with it
+        thrown away the compiler makes another reduction of the half that is
+        left, and a segment's first trip is no longer bitwise the unsplit
+        run's (at d = 81, CPU)."""
+        if p.forward == "recomputed":
             return None
         x = state["x"]
-        pair = paired_margins(X, x, jnp.mean(x, axis=0))
+        xbar = jnp.mean(x, axis=0)
+        pair = (
+            shard_visit(x, xbar, t0) if p.forward == "fused"
+            else paired_margins(X, x, xbar)
+        )
         return jax.lax.optimization_barrier(pair)[0]
 
     @device_scopes.scope("faults")
@@ -1541,18 +1622,14 @@ def _run(
             # The halo-exchange gather path IS the mixing operator; state,
             # data, and timeline columns shard over the same mesh below.
             if mesh is not None:
-                from distributed_optimization_tpu.parallel.mesh import (
-                    WORKER_AXIS as _WAXIS,
-                )
-
                 if (
-                    _WAXIS not in mesh.shape
-                    or mesh.shape[_WAXIS] != config.worker_mesh
+                    WORKER_AXIS not in mesh.shape
+                    or mesh.shape[WORKER_AXIS] != config.worker_mesh
                     or mesh.size != config.worker_mesh
                 ):
                     raise ValueError(
                         f"worker_mesh={config.worker_mesh} needs a 1-D "
-                        f"mesh with a {_WAXIS!r} axis of exactly that "
+                        f"mesh with a {WORKER_AXIS!r} axis of exactly that "
                         f"size (the halo plan, timeline slices and ICI "
                         f"accounting are all built for that P); got "
                         f"axes {dict(mesh.shape)}"
@@ -1940,6 +2017,17 @@ def _run(
 
         log_kernel_mode("mixing_impl='pallas'")
 
+    # What the eval's pass over the shards leaves the next trip's first
+    # gradient (the engagement counter of both mechanisms).
+    carried = _forward_is_carried(
+        algo, problem, config, faulty, rows=device_data.X.shape[1],
+        batch_size=batch_size, sampling_impl=sampling_impl,
+        scheduled=schedule is not None, collect_metrics=collect_metrics,
+    )
+    forward = (
+        "fused" if _visit_is_fused(carried, X)
+        else "carried" if carried else "recomputed"
+    )
     pieces = _StepPieces(
         algo=algo, problem=problem, reg=reg, config=config,
         batch_size=batch_size, sampling_impl=sampling_impl, key=key,
@@ -1952,15 +2040,9 @@ def _run(
         telemetry=config.telemetry, robust_activity=robust_activity,
         static_degree_sum=static_degree_sum,
         compressed_mix=compressed_mix,
-        carry_forward=_forward_is_carried(
-            algo, problem, config, faulty, rows=device_data.X.shape[1],
-            batch_size=batch_size, sampling_impl=sampling_impl,
-            scheduled=schedule is not None, collect_metrics=collect_metrics,
-        ),
+        forward=forward, mesh=mesh,
     )
-    spans.note_root(
-        forward="carried" if pieces.carry_forward else "recomputed"
-    )
+    spans.note_root(forward=forward)
 
     n_evals = T // eval_every
     measure_timestamps = bool(measure_timestamps)
@@ -1993,26 +2075,27 @@ def _run(
                 pieces, data
             )
 
-            # The carry is (state, z): the margins X·x of the carried models
-            # where the forward product is carried, else None (no leaf: the
-            # program is the state's alone). z is the program's, not the
-            # state's contract: made here, dropped at the end.
+            # The carry is (state, fwd): the forward product of the carried
+            # models (their margins X·x, or the next gradient itself) where
+            # one is carried, else None (no leaf: the program is the state's
+            # alone). It is the program's, not the state's contract: made
+            # here, dropped at the end.
             def microchunk(carry, ts_row):
-                state, z = carry
+                state, fwd = carry
                 for j in range(micro):
-                    state, _ = step(state, ts_row[j], z if j == 0 else None)
-                out, z = eval_metrics(
+                    state, _ = step(state, ts_row[j], fwd if j == 0 else None)
+                out, fwd = eval_metrics(
                     state, ts_row[-1], cadence_known=trips_per_eval == 1
                 )
                 if faulty is not None:
                     out["floats"] = floats_for(ts_row)
-                return (state, z), out
+                return (state, fwd), out
 
             ts = (
                 t0 + jnp.arange(n_trips_seg * micro, dtype=jnp.int32)
             ).reshape(n_trips_seg, micro)
             (state, _), ys = jax.lax.scan(
-                microchunk, (state_init, init_forward(state_init)), ts,
+                microchunk, (state_init, init_forward(state_init, t0)), ts,
                 unroll=flat_unroll,
             )
             return state, ys

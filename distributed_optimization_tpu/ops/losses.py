@@ -45,7 +45,9 @@ def _softplus_neg(z: jax.Array) -> jax.Array:
 #
 # so a caller that already holds the margins (the jax scan carries X·x from
 # the eval's pass over X to the next step, ``paired_margins`` below) asks for
-# the gradient at them (``gradient_at``) and reads the shard once less.
+# the gradient at them (``gradient_at``) and reads the shard once less; on a
+# TPU ``ops.pallas_kernels.glm_shard_visit`` takes the pair's two functions
+# into one kernel that reads it once in all.
 # ---------------------------------------------------------------------------
 
 

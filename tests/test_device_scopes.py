@@ -333,3 +333,25 @@ def test_the_scans_compile_keys_the_persistent_cache_by_its_metadata():
     with pytest.raises(RuntimeError):
         device_scopes.compile_keeping_scopes(Broken())
     assert getattr(jax.config, flag) == before
+
+
+def test_the_shard_visit_is_billed_to_gradient(monkeypatch):
+    """``forward`` = ``fused`` (ISSUE 41): the visit's call is built inside
+    the eval, under ``dopt.gradient``; the innermost scope is an
+    instruction's, so in the table of the program the root names every row
+    the kernel left (here its interpreted body) says ``gradient``, and the
+    batch weights drawn for it ``sampling``: none of it falls to ``eval`` or
+    to no scope."""
+    monkeypatch.setattr(
+        jax_backend, "_visit_is_fused", lambda carried, X: bool(carried))
+    cfg = cfg_of("dsgd_ring_logistic")
+    args = run_rooted(cfg, generate_synthetic_dataset(cfg), executable_cache=False)
+    assert args["forward"] == "fused"
+    table = device_scopes.table_for(args["program"])
+    scope_by_name = {row["head"].split(" = ")[0]: row["scope"] for row in table["rows"]}
+    compiled = device_scopes._programs[args["program"]]["executable"]()
+    visited = [
+        ins[0] for ins in map(device_scopes._instruction, compiled.as_text().splitlines())
+        if ins is not None and "glm_shard_visit" in ins[4] and ins[0] in scope_by_name
+    ]
+    assert visited and {scope_by_name[name] for name in visited} == {"gradient"}

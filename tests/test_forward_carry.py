@@ -155,7 +155,8 @@ BYPASS = {
 def test_the_root_says_which(case):
     """``forward`` on the ``dopt.run`` root: ``carried`` for a GLM's D-SGD
     over the whole shard, ``recomputed`` for a matrix parameter, a
-    compressed exchange, another rule, gathered batches."""
+    compressed exchange, another rule, gathered batches; on a CPU never
+    ``fused`` (ISSUE 41: a TPU's form of ``carried``)."""
     kw, want = BYPASS[case]
     cfg = glm_cfg(n_iterations=10, **kw)
     _, root = run_rooted(cfg, generate_synthetic_dataset(cfg))
@@ -341,3 +342,173 @@ def test_the_state_holds_no_margins():
     result, root = run_rooted(cfg, ds, return_state=True)
     assert root["forward"] == "carried"
     assert sorted(result.final_state) == ["x"]
+
+
+# --- fused: the carry is the next gradient (ISSUE 41) ----------------------
+#
+# On a TPU the eval's pass is ONE visit of the shards by a kernel
+# (``ops.pallas_kernels.glm_shard_visit``) that leaves the objective at x̄ AND
+# the next trip's first gradient, at the batch weights of t + 1, in the carry.
+# A CPU never takes it; here the rule is patched on and the kernel
+# interpreted. Three programs, one trajectory: to 1e-12 of each array's scale
+# in f64 (4,096 units of 2.2e-16; the sums over d and L run in another
+# order), and bitwise wherever one program is replayed or split.
+
+FUSED_ULPS = 4096
+
+
+def fused(monkeypatch):
+    monkeypatch.setattr(
+        jax_backend, "_visit_is_fused", lambda carried, X: bool(carried)
+    )
+
+
+def assert_fused_is_carried_and_recomputed(cfg, monkeypatch, **run_kw):
+    cfg = cfg.replace(dtype="float64")
+    ds = generate_synthetic_dataset(cfg)
+    fused(monkeypatch)
+    got, root = run_rooted(cfg, ds, **run_kw)
+    assert root["forward"] == "fused"
+    monkeypatch.setattr(jax_backend, "_visit_is_fused", lambda *a: False)
+    for want_form in ("carried", "recomputed"):
+        if want_form == "recomputed":
+            recomputed(monkeypatch)
+        want, root = run_rooted(cfg, ds, **run_kw)
+        assert root["forward"] == want_form
+        assert_ulps_of_scale(
+            got.history.objective, want.history.objective, FUSED_ULPS)
+        assert_ulps_of_scale(
+            got.history.consensus_error, want.history.consensus_error,
+            FUSED_ULPS)
+        assert_ulps_of_scale(got.final_models, want.final_models, FUSED_ULPS)
+
+
+@pytest.mark.parametrize("whole", sorted(WHOLE_SHARD))
+@pytest.mark.parametrize("family", FAMILIES)
+def test_fused_run_is_the_carried_and_the_recomputed_run(
+    family, whole, monkeypatch
+):
+    assert_fused_is_carried_and_recomputed(
+        small_backend_config(problem_type=family, **WHOLE_SHARD[whole]),
+        monkeypatch,
+    )
+
+
+@pytest.mark.parametrize("kw", [
+    dict(straggler_prob=0.3),  # a frozen row's gradient is its frozen model's
+    dict(eval_every=6, scan_unroll=4),  # micro = 3, two trips an eval
+    dict(local_steps=3),  # later slots sample and read for themselves
+    dict(n_features=80, n_informative_features=40),  # the study's width
+], ids=["stragglers", "micro3_two_trips", "local_steps3", "d81"])
+def test_only_the_trips_first_gradient_is_the_carried_one(kw, monkeypatch):
+    """The visit draws iteration t + 1's batch a trip early and takes the
+    gradient at the state AFTER the freeze; within a trip of ``micro`` steps
+    only the first has the carried g."""
+    kw.setdefault("sampling_impl", "dense")
+    assert_fused_is_carried_and_recomputed(
+        small_backend_config(problem_type="logistic", **kw), monkeypatch
+    )
+
+
+def test_fused_on_one_device_is_fused_over_the_mesh(monkeypatch):
+    """Under a mesh the visit runs under ``shard_map``, each device on its
+    rows: the same kernel on the same numbers (the objective's cross-device
+    sum falls in another order)."""
+    fused(monkeypatch)
+    cfg = small_backend_config(problem_type="logistic", sampling_impl="dense")
+    ds = generate_synthetic_dataset(cfg)
+    sharded, root = run_rooted(cfg, ds)
+    one, root_one = run_rooted(cfg, ds, use_mesh=False)
+    assert root["forward"] == root_one["forward"] == "fused"
+    assert sharded.history.mesh_devices == 8 and one.history.mesh_devices == 1
+    want32 = lambda a: np.asarray(a, dtype=np.float32)  # noqa: E731
+    assert_ulps_of_scale(sharded.final_models, want32(one.final_models), ULPS)
+    assert_ulps_of_scale(
+        sharded.history.objective, want32(one.history.objective), ULPS
+    )
+
+
+@pytest.fixture(scope="module")
+def whole_fused_run():
+    # Micro-chunks of 3 steps, two trips an eval, stragglers frozen.
+    cfg = small_backend_config(
+        problem_type="logistic", sampling_impl="dense", eval_every=6,
+        scan_unroll=4, straggler_prob=0.2,
+    )
+    ds = generate_synthetic_dataset(cfg)
+    with pytest.MonkeyPatch.context() as patch:
+        fused(patch)
+        whole, root = run_rooted(cfg, ds, return_state=True)
+    assert root["forward"] == "fused" and root["path"] == "fused"
+    return cfg, ds, whole
+
+
+@pytest.mark.parametrize("form,size", [
+    ("heartbeat", 1), ("heartbeat", 3), ("checkpoint", 4), ("resumed", 3),
+])
+def test_a_split_fused_run_is_bitwise_the_unsplit_run(
+    whole_fused_run, tmp_path, monkeypatch, form, size
+):
+    """g is the program's, not the state's: every segment makes its first
+    one from the state it is handed and its own ``t0`` by the visit itself,
+    so no row shifts and a split run is the unsplit run to the bit."""
+    cfg, ds, whole = whole_fused_run
+    fused(monkeypatch)
+    if form == "heartbeat":
+        kw = {"progress_cb": lambda ev: None, "progress_every": size}
+    else:
+        kw = {"checkpoint": CheckpointOptions(
+            str(tmp_path / "ck"), every_evals=size
+        )}
+    if form == "resumed":
+        jax_backend.run(cfg.replace(n_iterations=24), ds, 0.0, **kw)
+    split, root = run_rooted(cfg, ds, **kw)
+    assert root["forward"] == "fused" and root["path"] == "segmented"
+    np.testing.assert_array_equal(split.history.objective, whole.history.objective)
+    np.testing.assert_array_equal(
+        split.history.consensus_error, whole.history.consensus_error
+    )
+    np.testing.assert_array_equal(split.final_models, whole.final_models)
+
+
+def test_the_state_holds_no_gradient(whole_fused_run):
+    assert sorted(whole_fused_run[2].final_state) == ["x"]
+
+
+def test_one_visit_of_the_shards_in_the_loop_and_one_before_it(monkeypatch):
+    """No reduction over the stack is left in the fused program: one kernel
+    call in the loop's body, one in front of it for the first gradient."""
+    fused(monkeypatch)
+    cfg = glm_cfg(problem_type="logistic", n_iterations=10)
+    ds = generate_synthetic_dataset(cfg)
+    seg_scan, args = seg_scan_of(cfg, ds, monkeypatch)
+    jaxpr = jax.make_jaxpr(seg_scan)(*args).jaxpr
+    assert reads_of(jaxpr, args[2]["X"].shape) == []
+
+    def calls(jp, in_scan):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield in_scan
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if eqn.primitive.name != "pallas_call":
+                    yield from calls(sub, in_scan or eqn.primitive.name == "scan")
+
+    assert sorted(calls(jaxpr, False)) == [False, True]
+
+
+class _Shards:
+    def __init__(self, rows, dtype=jnp.float32):
+        self.shape, self.dtype = (1 << 18, rows, 81), jnp.dtype(dtype)
+
+
+def test_the_rule_a_cpu_never_fuses_and_a_long_shard_stays_carried(monkeypatch):
+    """``_visit_is_fused``: every condition of ``carried``, a TPU, f32 shards,
+    and 128 workers' shards in the kernel's budget twice."""
+    rule = jax_backend._visit_is_fused
+    assert jax.default_backend() == "cpu"
+    assert not rule(True, _Shards(53))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rule(True, _Shards(53))
+    assert not rule(False, _Shards(53))
+    assert not rule(True, _Shards(100_000))
+    assert not rule(True, _Shards(53, jnp.bfloat16))

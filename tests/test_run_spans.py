@@ -134,6 +134,30 @@ def test_one_root_with_the_named_children_in_order(setup):
     assert tracer.phases == {}
 
 
+@pytest.mark.parametrize("visit,sampling_impl,want", [
+    (True, "dense", "fused"), (False, "dense", "carried"),
+    (True, "auto", "recomputed"), (False, "auto", "recomputed"),
+])
+def test_the_root_says_which_forward_product_the_scan_carried(
+    visit, sampling_impl, want, monkeypatch
+):
+    """``forward`` (ISSUEs 31, 41) is the engagement counter of both
+    mechanisms: ``fused`` where the kernel's visit leaves the next gradient
+    in the carry (a TPU's rule, patched on here: a CPU never takes it),
+    ``carried`` where XLA's paired pass leaves the margins, ``recomputed``
+    where neither fits (gathered batches), whatever the visit's rule says."""
+    monkeypatch.setattr(
+        jax_backend, "_visit_is_fused", lambda carried, X: visit and carried
+    )
+    cfg = small_backend_config(
+        problem_type="logistic", n_iterations=10, sampling_impl=sampling_impl
+    )
+    _, roots, _ = run_under(
+        Tracer(), cfg, generate_synthetic_dataset(cfg), executable_cache=False
+    )
+    assert roots[-1]["args"]["forward"] == want
+
+
 @pytest.mark.parametrize("layout,dtype,stack", [
     ("consecutive", "float32", "view"), ("consecutive", "float64", "cast"),
     ("argsort", "float64", "gather"),

@@ -144,3 +144,38 @@ def test_the_gather_samplers_table_is_its_one_large_temporary(gather_sampler_loo
     its size beside it while it is filled or read."""
     memory = gather_sampler_loop.memory_analysis()
     assert 6_710_886_400 <= memory.temp_size_in_bytes < 7_400_000_000, memory.temp_size_in_bytes
+
+
+@pytest.fixture(scope="module")
+def shard_visit_at_the_cell(one_chip):
+    """``glm_shard_visit`` at the GLM cells' size (2^18 shards of 53 rows of
+    81 floats, the logistic pair), compiled by Mosaic and XLA together."""
+    from distributed_optimization_tpu.ops import pallas_kernels as pk
+    from distributed_optimization_tpu.ops.losses import LOGISTIC
+
+    n, rows, d = 1 << 18, 53, 81
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return jax.jit(
+        lambda *a: pk.glm_shard_visit(LOGISTIC, *a, interpret=False)
+    ).lower(
+        shape(n, rows, d), shape(n, rows), shape(n, d), shape(d),
+        shape(n, rows), shape(n, dtype=jnp.int32)).compile()
+
+
+def test_the_shard_visit_reads_the_stack_where_it_lies(shard_visit_at_the_cell):
+    """The runtime keeps the stack with the worker axis minor, so the kernel's
+    ``[d, L, N]`` view of it, and ``[d, N]`` of the models, are bitcasts: ONE
+    custom call, no copy, transpose or fusion anywhere, no temporary."""
+    text = shard_visit_at_the_cell.as_text()
+    entry = text[text.index("ENTRY"):].split("\n", 1)[0]
+    assert "f32[262144,53,81]" in entry
+    ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
+    assert sorted({ins[2] for ins in ops} - {"parameter", "tuple", "get-tuple-element"}) == [
+        "bitcast", "custom-call"]
+    (call,) = [ins for ins in ops if ins[2] == "custom-call"]
+    assert call[0].startswith("%glm_shard_visit") and "f32[81,262144]" in call[1]
+    assert "f32[81,53,262144]{2,1,0" in text
+    assert shard_visit_at_the_cell.memory_analysis().temp_size_in_bytes == 0

@@ -156,3 +156,59 @@ def test_paired_margins_reach_the_chip_as_one_reduce_with_two_results():
     assert len(re.findall(
         rf"-> \(tensor<{n}x{L}xf32>, tensor<{n}x{L}xf32>\)", text
     )) == 1
+
+
+VISIT_SHAPES = {"two_blocks_of_the_cell": (1024, 53, 81), "ragged": (700, 13, 9)}
+
+
+def _visit_arguments(n, rows, d):
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    return (f32(n, rows, d), f32(n, rows), f32(n, d), f32(d), f32(n, rows),
+            jax.ShapeDtypeStruct((n,), jnp.int32))
+
+
+@pytest.mark.parametrize("family", ["logistic", "quadratic", "huber"])
+@pytest.mark.parametrize("shape", sorted(VISIT_SHAPES))
+def test_shard_visit_lowers_for_tpu(shape, family):
+    """``glm_shard_visit`` (ISSUE 41) through Mosaic's lowering rules at the
+    GLM cells' block ([81, 53, 512], twice) and at a ragged stack (13 rows,
+    a last block of 188 workers), for each family's pair: ONE custom call,
+    no reduction and no matmul left to XLA (whether Mosaic then compiles it
+    is for ``tests/test_tpu_compile.py`` and the chip)."""
+    from distributed_optimization_tpu.models import get_problem
+
+    link = get_problem(family).link
+    text = _lower_for_tpu(
+        lambda *a: pk.glm_shard_visit(link, *a, interpret=False),
+        *_visit_arguments(*VISIT_SHAPES[shape]),
+    ).mlir_module()
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) == 1
+    assert "stablehlo.reduce" not in text and "dot_general" not in text
+
+
+def test_the_fused_scan_reaches_the_chip_as_one_visit_a_trip(monkeypatch):
+    """The program a TPU is handed where ``forward`` = ``fused``: in the
+    loop's body ONE kernel call and no ``dot_general`` or ``reduce`` over
+    the shard stack; the same call once in front of the loop for the first
+    gradient (the CPU's scan unrolls one step a trip)."""
+    from test_forward_carry import fused, glm_cfg, seg_scan_of
+
+    from distributed_optimization_tpu.utils.data import generate_synthetic_dataset
+
+    fused(monkeypatch)
+    monkeypatch.setattr(pk, "resolve_interpret", lambda *a, **k: False)
+    cfg = glm_cfg(problem_type="logistic", n_iterations=10)
+    ds = generate_synthetic_dataset(cfg)
+    seg_scan, args = seg_scan_of(cfg, ds, monkeypatch)
+    text = _lower_for_tpu(seg_scan, *args).mlir_module()
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) == 2
+    n, rows, d = args[2]["X"].shape
+    stack = rf"tensor<(?:{n}x{rows}x{d}|{d}x{rows}x{n})xf32>"
+    over_the_stack = [
+        line for line in text.splitlines()
+        if re.search(r"stablehlo\.(dot_general|reduce)\b", line)
+        and re.search(stack, line)
+    ]
+    assert over_the_stack == []
+    body = text[text.index("stablehlo.while"):]
+    assert len(re.findall(r"@tpu_custom_call", body)) >= 1
