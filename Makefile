@@ -6,7 +6,7 @@ PY ?= python
 .PHONY: test chip-smoke smoke serve-smoke serve-restart-smoke observatory-smoke \
 	scenarios-smoke fleet-smoke perf-diff bench-byzantine bench-churn \
 	bench-robust-scale bench-sweep bench-compute bench-telemetry \
-	bench-fused bench-serving bench-serving-load bench-fleet \
+	bench-serving bench-serving-load bench-fleet \
 	bench-federated \
 	bench-async bench-async-faults bench-observatory bench-mesh \
 	bench-mesh-scale bench-scenarios bench-monitors
@@ -31,7 +31,7 @@ chip-smoke:
 smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest -q -m 'not slow' -x \
 		tests/test_faults.py tests/test_churn.py tests/test_byzantine.py \
-		tests/test_robust_gather.py tests/test_fused_robust.py \
+		tests/test_robust_gather.py \
 		tests/test_compressed_gossip.py tests/test_batch.py \
 		tests/test_telemetry.py tests/test_serving.py \
 		tests/test_federated.py tests/test_async.py \
@@ -126,13 +126,6 @@ bench-compute:
 # steady-state ceiling + bitwise off/on trajectory gate).
 bench-telemetry:
 	JAX_PLATFORMS=cpu $(PY) examples/bench_telemetry.py
-
-# Regenerate the fused-robust-kernel + compressed-gossip evidence
-# (docs/perf/fused_robust.json: fused vs gather per rule with the
-# compiled-path floor gated to accelerators + honest fused_loses flags,
-# and bytes-vs-gap envelopes for {none,top_k,qsgd} x {dsgd,gt}).
-bench-fused:
-	JAX_PLATFORMS=cpu $(PY) examples/bench_fused_robust.py
 
 # Regenerate the federated-regime evidence (docs/perf/federated.json:
 # local-steps floats-to-eps reduction >= 2x floor, participation-rate

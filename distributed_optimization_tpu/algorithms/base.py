@@ -45,9 +45,6 @@ class StepContext:
     ``eta``: learning rate for this iteration (scalar).
     ``degrees``: [N, 1] node degrees (a unit axis per parameter axis).
     ``config``: the ExperimentConfig (static hyperparameters only).
-    ``fused_mix_step``: optional backend-provided fusion of the canonical
-    gossip-SGD update, (x, g, eta) -> W x − eta g in one kernel (the pallas
-    fast path); algorithms whose update IS that form may use it when present.
     ``compressed_mix``: optional sharded wire form of the error-feedback
     exchange, (q, x̂⁺, halo) -> (W x̂⁺, halo⁺)
     (``collectives.make_halo_compressed_mixing_op``) — present only on the
@@ -63,7 +60,6 @@ class StepContext:
     t: Array
     degrees: Array
     config: Any
-    fused_mix_step: Any = None
     compressed_mix: Any = None
 
 

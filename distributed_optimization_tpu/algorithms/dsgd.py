@@ -88,11 +88,7 @@ def _step(state: State, ctx: StepContext) -> State:
         )
         return {"x": x_new, "xhat": xhat_new}
     grads = ctx.grad(x, 0)  # at the local pre-mix models (D-PSGD ordering)
-    if ctx.fused_mix_step is not None:
-        # Backend-fused W x − eta g (single pallas kernel, one HBM pass).
-        x_new = ctx.fused_mix_step(x, grads, ctx.eta)
-    else:
-        x_new = ctx.mix(x) - ctx.eta * grads
+    x_new = ctx.mix(x) - ctx.eta * grads
     # Federated local updates (config.local_steps = τ; docs/PERF.md §14):
     # the gossip-fused first descent above is local step 0 of the round;
     # τ−1 purely-local SGD descents follow, each on its own batch draw

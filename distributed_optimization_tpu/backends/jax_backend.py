@@ -304,17 +304,11 @@ class _StepPieces:
     byz_mix: object      # composed Byzantine mix or None
     adversary: object    # Adversary or None
     honest_w: object     # [N] f32 honest mask or None
-    fused_mix_step: object
     full_objective: object
     f_opt: float
     collect_metrics: bool
     track_consensus: bool
     edge_payload: object
-    # Single-kernel robust D-SGD update ``(t, x, g, eta) -> x_new``
-    # (robust_impl='fused' + dsgd; _bind_byzantine). Bound per-iteration
-    # into ``ctx.fused_mix_step`` so the algorithm's canonical
-    # mix-then-step collapses into one pallas pass.
-    fused_robust_step: object = None
     # --- flight recorder (config.telemetry; telemetry.TRACE_FIELDS) ---
     telemetry: bool = False
     # ``activity(t, x) -> scalar``: robust-aggregation screening fraction
@@ -562,15 +556,6 @@ def _make_step_eval(p: _StepPieces, data):
         mix_fn, nbr_fn = (
             device_scopes.scope("gossip")(f) for f in (mix_fn, nbr_fn)
         )
-        fused_mix_step = p.fused_mix_step
-        if p.fused_robust_step is not None:
-            # robust_impl='fused' + dsgd: the whole corrupt → screen →
-            # mix → SGD update runs as one pallas kernel for iteration t.
-            fused_mix_step = (
-                lambda xx, gg, ee, _t=t: p.fused_robust_step(  # noqa: E731
-                    _t, xx, gg, ee
-                )
-            )
         ctx = StepContext(
             grad=grad_fn_factory(t, fwd, fwd_of),
             mix=mix_fn,
@@ -581,7 +566,6 @@ def _make_step_eval(p: _StepPieces, data):
             t=t,
             degrees=p.degrees,
             config=p.config,
-            fused_mix_step=fused_mix_step,
             compressed_mix=p.compressed_mix,
         )
         with device_scopes.scope("update"):
@@ -884,30 +868,22 @@ def _build_faulty(config, algo, topo, T, *, drop_prob=None, keys=None,
 
 
 def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
-                    byz=None, noise_key=None, allow_fused=True,
-                    halo_mesh=None):
+                    byz=None, noise_key=None, halo_mesh=None):
     """Byzantine adversary + robust-aggregation wiring shared by ``_run``
     and ``run_batch`` (docs/BYZANTINE.md). Returns ``(adversary, byz_mix,
-    activity_t, fused_step_t)`` — all None when the config is benign.
+    activity_t)`` — all None when the config is benign.
     ``activity_t(t, x)`` is the flight recorder's screening-fraction probe
     (the telemetry twin of the robust rule, over the same realized graph
     and the same corrupted stack; None without a robust rule).
-    ``fused_step_t(t, x, g, eta)`` is the single-kernel robust D-SGD
-    update (gather + screen + mix + SGD in one pallas pass,
-    ``robust_impl='fused'`` + dsgd only) — when set, the step binds it as
-    ``ctx.fused_mix_step`` and the whole per-iteration update runs
-    VMEM-resident. The keyword overrides are the replica-batched hooks:
-    ``clip_tau`` a per-replica (possibly traced) radius,
-    ``byz``/``noise_key`` the per-replica Byzantine set and large-noise
-    stream; ``allow_fused=False`` rejects an explicit
-    ``robust_impl='fused'`` on the vmapped path (the pallas kernel
-    addresses unbatched VMEM blocks).
+    The keyword overrides are the replica-batched hooks: ``clip_tau`` a
+    per-replica (possibly traced) radius, ``byz``/``noise_key`` the
+    per-replica Byzantine set and large-noise stream.
     """
     byzantine_active = config.attack != "none" or (
         config.aggregation != "gossip" and config.robust_b > 0
     )
     if not byzantine_active:
-        return None, None, None, None
+        return None, None, None
     if not algo.supports_byzantine:
         raise ValueError(
             f"Byzantine injection / robust aggregation is "
@@ -926,14 +902,7 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
     )
     robust_aggregate_t = None
     activity_src = None
-    fused_update = None
     if config.aggregation != "gossip" and config.robust_b > 0:
-        from distributed_optimization_tpu.ops.pallas_kernels import (
-            log_kernel_mode,
-            make_fused_robust_aggregator,
-            make_fused_robust_dsgd_step,
-        )
-
         validate_budget(
             int(topo.degrees.min()), config.robust_b,
             config.aggregation,
@@ -944,23 +913,12 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
         # "Degree-bounded gather path"): 'gather' screens over the
         # static [N, k_max] neighbor table — O(N·k_max·d·log k_max)
         # — instead of the dense [N, N, d] node-axis sort; 'auto' routes
-        # between the two by the measured crossover. 'fused' runs the
-        # gather math as ONE pallas kernel and is only ever an EXPLICIT
-        # choice (time-varying liveness feeds the kernel per step — the
-        # parity tests force exactly that), never inside the vmapped
-        # replica batch: Mosaic does not lower it, so on the chip it
-        # surfaces the compiler's error rather than giving way to gather.
+        # between the two by the measured crossover.
         robust_impl = config.resolved_robust_impl(k_max_topo)
-        if robust_impl == "fused" and not allow_fused:
-            raise ValueError(
-                "robust_impl='fused' cannot run inside the replica-"
-                "batched program: the pallas kernel addresses unbatched "
-                "VMEM blocks — use 'auto', 'gather', or 'dense'"
-            )
         if topo.is_matrix_free and robust_impl != "gather":
             # Unreachable through config validation (neighbor topologies
             # never have k_max + 1 >= N, so 'auto' resolves to gather and
-            # explicit dense/fused are rejected up front) — guard anyway
+            # an explicit dense is rejected up front) — guard anyway
             # so a future resolver change fails loudly, not silently
             # through a None adjacency.
             raise ValueError(
@@ -975,7 +933,7 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
             # its own closed neighborhoods locally. Node-process faults
             # compose through the availability row; config already
             # rejected everything without a sharded form (edge chains,
-            # alie, dense/fused impls, the telemetry activity probe)
+            # alie, the dense impl, the telemetry activity probe)
             # with the missing piece named.
             if robust_impl != "gather":
                 raise ValueError(
@@ -990,7 +948,7 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
                 config.aggregation, config.robust_b, topo, halo_mesh,
                 ct, faulty.active if faulty is not None else None,
             )
-        elif robust_impl in ("gather", "fused"):
+        elif robust_impl == "gather":
             from distributed_optimization_tpu.parallel.topology import (
                 neighbor_tables_for,
             )
@@ -999,15 +957,9 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
             # Byzantine screening accepted on the neighbor path), derived
             # from the dense adjacency otherwise — identical layout.
             nbr_idx, nbr_mask = neighbor_tables_for(topo)
-            if robust_impl == "fused":
-                log_kernel_mode("robust_impl='fused'")
-                gather_agg = make_fused_robust_aggregator(
-                    config.aggregation, config.robust_b, nbr_idx, ct,
-                )
-            else:
-                gather_agg = make_gather_robust_aggregator(
-                    config.aggregation, config.robust_b, nbr_idx, ct,
-                )
+            gather_agg = make_gather_robust_aggregator(
+                config.aggregation, config.robust_b, nbr_idx, ct,
+            )
             if faulty is not None:
                 live_fn = faulty.make_neighbor_liveness(
                     nbr_idx, nbr_mask
@@ -1020,19 +972,6 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
             robust_aggregate_t = (
                 lambda t, v: gather_agg(live_fn(t), v)  # noqa: E731
             )
-            if robust_impl == "fused" and algo.name == "dsgd":
-                # D-SGD's whole update fuses: the −η·g lands inside
-                # the same kernel (make_fused_robust_dsgd_step);
-                # composed with the adversary below.
-                fused_update = (
-                    make_fused_robust_dsgd_step(
-                        config.aggregation, config.robust_b, nbr_idx,
-                        ct,
-                    ),
-                    live_fn,
-                )
-            # The activity probe stays the (un-fused) gather twin for
-            # both forms — observability only.
             gather_act = make_gather_robust_activity(
                 config.aggregation, config.robust_b, nbr_idx, ct,
             )
@@ -1066,26 +1005,6 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
     byz_mix = make_byzantine_mixing(
         adversary, base_mix_t, aggregate_t=robust_aggregate_t,
     )
-    fused_step_t = None
-    if fused_update is not None:
-        fused_kernel, fused_live = fused_update
-
-        def fused_step_t(t, x, g, eta):
-            # The single-kernel twin of ``byz_mix(t, x) − η·g`` for D-SGD
-            # (make_byzantine_mixing composition, SGD folded in): honest
-            # rows screen the corrupted stack in-kernel; Byzantine rows
-            # keep the benign mix of the TRUE stack (the attacker-runs-
-            # honest-dynamics threat model) — elementwise the same values
-            # as select-then-subtract, so the fused path stays bitwise.
-            xc = adversary.corrupt(t, x) if adversary is not None else x
-            out = fused_kernel(fused_live(t), xc, g, eta)
-            if adversary is not None:
-                m = jnp.asarray(
-                    adversary.byzantine, dtype=jnp.float32
-                ).reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
-                out = jnp.where(m > 0, base_mix_t(t, x) - eta * g, out)
-            return out
-
     activity_t = None
     if activity_src is not None:
         # The probe sees exactly what the screening rule sees: the stack
@@ -1096,7 +1015,7 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
             )
         else:
             activity_t = activity_src
-    return adversary, byz_mix, activity_t, fused_step_t
+    return adversary, byz_mix, activity_t
 
 
 def _on_cadence_rows(ys, n_seg_evals, trips_per_eval):
@@ -1510,18 +1429,6 @@ def run(
         )
 
 
-# Why there is no TPU-specific mixing resolver here: ``mixing_impl`` passes
-# straight through to ``make_mixing_op`` ('auto' -> stencil where the graph
-# embeds as mesh shifts, else dense). The interleaved sweep over d in
-# {81..1024} (``docs/perf/pallas_regimes.json``) has the end-to-end
-# pallas/stencil ratio bounce 0.78-1.29 with no trend across adjacent
-# dims, so there is no crossover to gate on, and the VMEM kernels are an
-# explicit opt-in (``mixing_impl='pallas'``, f32 whole-array envelope only:
-# Mosaic refuses the ring kernels' rotate in bf16 ("Rotate with non-32-bit
-# data", libtpu 0.0.34), and operands live unblocked in VMEM, so the
-# softmax tier's d*K-wide models are out of range).
-
-
 def _run(
     config,
     dataset: HostDataset,
@@ -1758,10 +1665,8 @@ def _run(
                 # the fault layer mixes, over tables of its own.
                 mixing_tables = replicate(mesh, mix_op.tables)
                 spans.note_root(**_gather_root_args(topo, mixing_tables))
-        adversary, byz_mix, robust_activity, fused_robust_step = (
-            _bind_byzantine(
-                config, algo, topo, faulty, mix_op, halo_mesh=halo_mesh,
-            )
+        adversary, byz_mix, robust_activity = _bind_byzantine(
+            config, algo, topo, faulty, mix_op, halo_mesh=halo_mesh,
         )
         # == adjacency.sum() for both orientations; degree-based so the
         # matrix-free representation needs no [N, N] array.
@@ -1834,7 +1739,6 @@ def _run(
         adversary = None
         byz_mix = None
         robust_activity = None
-        fused_robust_step = None
         static_degree_sum = 0.0
         topo = None
         mix_op = None
@@ -1992,31 +1896,6 @@ def _run(
     if adversary is not None:
         honest_w = jnp.asarray(adversary.honest.astype(np.float32))
 
-    # The pallas ring kernel fuses the whole canonical gossip-SGD update;
-    # offer it to algorithms via the context (dsgd uses it). Disabled under
-    # Byzantine injection: the fused W x − ηg bypasses the corrupt/screen
-    # composition.
-    fused_mix_step = None
-    if (
-        not byzantine_active
-        and faulty is None
-        and mix_op is not None
-        and mix_op.impl == "pallas"
-        and topo is not None
-        and topo.name == "ring"
-    ):
-        from distributed_optimization_tpu.ops.pallas_kernels import (
-            fused_ring_dsgd_step,
-        )
-
-        fused_mix_step = fused_ring_dsgd_step
-    if mix_op is not None and mix_op.impl == "pallas":
-        from distributed_optimization_tpu.ops.pallas_kernels import (
-            log_kernel_mode,
-        )
-
-        log_kernel_mode("mixing_impl='pallas'")
-
     # What the eval's pass over the shards leaves the next trip's first
     # gradient (the engagement counter of both mechanisms).
     carried = _forward_is_carried(
@@ -2033,10 +1912,9 @@ def _run(
         batch_size=batch_size, sampling_impl=sampling_impl, key=key,
         eta_fn=eta_fn, degrees=degrees, mix_op=mix_op, faulty=faulty,
         byz_mix=byz_mix, adversary=adversary, honest_w=honest_w,
-        fused_mix_step=fused_mix_step, full_objective=full_objective,
+        full_objective=full_objective,
         f_opt=f_opt, collect_metrics=collect_metrics,
         track_consensus=track_consensus, edge_payload=edge_payload,
-        fused_robust_step=fused_robust_step,
         telemetry=config.telemetry, robust_activity=robust_activity,
         static_degree_sum=static_degree_sum,
         compressed_mix=compressed_mix,
@@ -2292,18 +2170,11 @@ def batch_unsupported_reason(config) -> Optional[str]:
             "batched per-replica seed axis cannot reach — replicas would "
             "silently share compression draws"
         )
-    if config.mixing_impl in ("shard_map", "pallas"):
+    if config.mixing_impl == "shard_map":
         return (
-            f"run_batch is incompatible with mixing_impl="
-            f"{config.mixing_impl!r}: shard_map stencils pin a device "
-            "mesh and the pallas kernels address unbatched VMEM blocks — "
+            "run_batch is incompatible with mixing_impl='shard_map': "
+            "shard_map stencils pin a device mesh — "
             "use 'auto', 'dense', 'stencil', or 'sparse'"
-        )
-    if config.robust_impl == "fused":
-        return (
-            "run_batch is incompatible with robust_impl='fused': the "
-            "fused pallas kernel addresses unbatched VMEM blocks — use "
-            "'auto', 'gather', or 'dense'"
         )
     if config.compression != "none":
         return (
@@ -2381,7 +2252,7 @@ def run_batch(
 
     Structural axes (topology, n_workers, algorithm, ...) cannot batch —
     they change the traced program — and are rejected; so are the config
-    combinations whose execution cannot wrap in vmap (shard_map/pallas
+    combinations whose execution cannot wrap in vmap (shard_map
     mixing, tensor parallelism, choco's internal seed derivation) — see
     ``batch_unsupported_reason``. The batched program runs unsharded (the
     replica axis fills the chip instead of the worker mesh) and always
@@ -2883,12 +2754,11 @@ def _run_batch(
                     ),
                     timeline=tl, horizon=horizon,
                 )
-            adversary, byz_mix, robust_activity, _ = _bind_byzantine(
+            adversary, byz_mix, robust_activity = _bind_byzantine(
                 config, algo, topo, faulty, mix_op,
                 clip_tau=rp_r.get("clip_tau"),
                 byz=rp_r.get("byz"),
                 noise_key=rp_r.get("noise_key"),
-                allow_fused=False,
             )
             if adversary is not None:
                 honest_w = jnp.asarray(
@@ -2901,7 +2771,7 @@ def _run_batch(
             eta_fn=_make_eta_fn(config, eta0=rp_r.get("eta0")),
             degrees=degrees, mix_op=mix_op, faulty=faulty,
             byz_mix=byz_mix, adversary=adversary, honest_w=honest_w,
-            fused_mix_step=None, full_objective=full_objective,
+            full_objective=full_objective,
             f_opt=data["f_opt"], collect_metrics=collect_metrics,
             track_consensus=track_consensus, edge_payload=edge_payload,
             telemetry=config.telemetry, robust_activity=robust_activity,

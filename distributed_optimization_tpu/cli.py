@@ -259,7 +259,7 @@ def build_parser() -> argparse.ArgumentParser:
                      help="fixed clipping radius for clipped_gossip "
                           "(0 = adaptive per-node radius)")
     opt.add_argument("--robust-impl",
-                     choices=("auto", "dense", "gather", "fused"),
+                     choices=("auto", "dense", "gather"),
                      default=_DEFAULTS.robust_impl,
                      help="execution form of the robust rule (jax "
                           "backend): 'dense' sorts the [N,N,d] closed-"
@@ -267,13 +267,8 @@ def build_parser() -> argparse.ArgumentParser:
                           "screens over a static [N,k_max] padded "
                           "neighbor table (O(N k_max d log k_max), "
                           "~N/k_max less work on degree-bounded graphs); "
-                          "'fused' runs the gather math as one pallas "
-                          "kernel (gather+screen+mix+SGD for dsgd), the "
-                          "[N,k_max,d] stack never hitting HBM; 'auto' = "
-                          "measured rule: gather unless fully connected; "
-                          "never fused — Mosaic refuses that kernel, so "
-                          "on a TPU an explicit 'fused' stops at the "
-                          "compiler's error")
+                          "'auto' = measured rule: gather unless fully "
+                          "connected")
     opt.add_argument("--partition", choices=("sorted", "shuffled"),
                      default=_DEFAULTS.partition,
                      help="worker data split: 'sorted' = the study's "
@@ -376,7 +371,7 @@ def build_parser() -> argparse.ArgumentParser:
                             "parity)")
     execg.add_argument("--mixing-impl",
                        choices=("auto", "dense", "stencil", "shard_map",
-                                "pallas", "sparse", "gather"),
+                                "sparse", "gather"),
                        default=_DEFAULTS.mixing_impl,
                        help="'gather' = the k_max-bounded neighbor-table "
                             "mixing operator, O(N*k_max*d) per round with "

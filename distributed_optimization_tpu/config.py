@@ -334,7 +334,7 @@ class ExperimentConfig:
     aggregation: str = "gossip"
     robust_b: int = 0
     clip_tau: float = 0.0
-    # 'auto' | 'dense' | 'gather' | 'fused'. Execution form of the robust
+    # 'auto' | 'dense' | 'gather'. Execution form of the robust
     # rule on the jax backend (the numpy oracle has one per-node form):
     # 'dense' sorts the [N, N, d] closed-neighborhood tensor over the full
     # node axis — O(N²·d·log N) regardless of topology; 'gather'
@@ -342,15 +342,9 @@ class ExperimentConfig:
     # neighbor models and per-incident-edge liveness bits, and screens
     # over the k_max axis — O(N·k_max·d·log k_max), ~N/k_max-fold less
     # work on degree-bounded graphs (measured 69-75x e2e for trimmed
-    # mean/median on an N=256 ring, docs/perf/robust_scale.json); 'fused'
-    # runs the gather math as ONE pallas kernel (gather + screen + mix,
-    # plus the SGD update for dsgd) so the [N, k_max, d] neighbor stack
-    # never materializes in HBM (ops/pallas_kernels.py; count rules need
-    # the closed neighborhood to fit the in-kernel sort network,
-    # k_max+1 <= FUSED_MAX_SORT_WIDTH). 'auto' picks dense or gather from
-    # the measured crossover (resolved_robust_impl) and never selects
-    # 'fused': Mosaic refuses the kernel's in-kernel gather, so it is an
-    # explicit opt-in that runs only in interpreter mode on CPU.
+    # mean/median on an N=256 ring, docs/perf/robust_scale.json). 'auto'
+    # picks dense or gather from the measured crossover
+    # (resolved_robust_impl).
     robust_impl: str = "auto"
     # Gossip schedule: 'synchronous' averages with all (surviving) neighbors
     # per iteration; 'one_peer' is Boyd-style randomized gossip — each node
@@ -359,12 +353,11 @@ class ExperimentConfig:
     # cycles deterministic matchings that cover the edge set every P
     # iterations (ring/chain/even-sided grid).
     gossip_schedule: str = "synchronous"
-    # 'auto' | 'dense' | 'stencil' | 'shard_map' | 'pallas' | 'sparse'.
+    # 'auto' | 'dense' | 'stencil' | 'shard_map' | 'sparse' | 'gather'.
     # 'auto' picks the measured winner: stencil where the graph embeds as
-    # mesh shifts, else dense (round 5: the 7-dim pallas sweep found no
-    # reproducible win — docs/perf/pallas_regimes.json — and the CSR sparse
-    # form measured slower than dense at every cell —
-    # docs/perf/sparse_mixing.json; both remain explicit opt-ins).
+    # mesh shifts, else dense (the CSR sparse form measured slower than
+    # dense at every cell — docs/perf/sparse_mixing.json — and remains an
+    # explicit opt-in).
     mixing_impl: str = "auto"
     # 'auto' | 'gather' | 'dense'. Mini-batch realization on the jax backend:
     # 'gather' materializes [N, b, d] batches (the b largest uniforms by a
@@ -465,7 +458,7 @@ class ExperimentConfig:
         if self.backend not in BACKENDS:
             raise ValueError(f"Unknown backend: {self.backend}")
         if self.mixing_impl not in ("auto", "dense", "stencil", "shard_map",
-                                    "pallas", "sparse", "gather"):
+                                    "sparse", "gather"):
             raise ValueError(f"Unknown mixing impl: {self.mixing_impl}")
         if self.sampling_impl not in ("auto", "gather", "dense"):
             raise ValueError(f"Unknown sampling impl: {self.sampling_impl}")
@@ -560,7 +553,7 @@ class ExperimentConfig:
                 "aggregation rule; plain 'gossip' has no screening step and "
                 "would silently ignore it"
             )
-        if self.robust_impl not in ("auto", "dense", "gather", "fused"):
+        if self.robust_impl not in ("auto", "dense", "gather"):
             raise ValueError(f"Unknown robust impl: {self.robust_impl}")
         if self.robust_impl != "auto" and not (
             self.aggregation != "gossip" and self.robust_b > 0
@@ -774,7 +767,7 @@ class ExperimentConfig:
                 raise ValueError(
                     f"topology_impl='neighbor' runs robust aggregation in "
                     f"gather form over the [N, k_max] table; robust_impl="
-                    f"{self.robust_impl!r} materializes dense/VMEM objects "
+                    f"{self.robust_impl!r} materializes [N, N] objects "
                     "the matrix-free path never builds — use 'auto' or "
                     "'gather'"
                 )
@@ -875,7 +868,7 @@ class ExperimentConfig:
                 raise ValueError(
                     f"worker_mesh screens Byzantine messages in halo-"
                     f"gather form over the sharded tables; robust_impl="
-                    f"{self.robust_impl!r} materializes dense/VMEM "
+                    f"{self.robust_impl!r} materializes [N, N] "
                     "objects the sharded path never builds — use 'auto' "
                     "or 'gather'"
                 )
@@ -1139,13 +1132,12 @@ class ExperimentConfig:
                     "trajectory at a time — use backend='jax' or loop "
                     "single runs"
                 )
-            if self.mixing_impl in ("shard_map", "pallas"):
+            if self.mixing_impl == "shard_map":
                 raise ValueError(
                     f"replicas={self.replicas} is incompatible with "
-                    f"mixing_impl={self.mixing_impl!r}: the replica axis "
+                    "mixing_impl='shard_map': the replica axis "
                     "vmaps the whole compiled program, but shard_map "
-                    "stencils pin a fixed device mesh and the pallas "
-                    "kernels address unbatched VMEM blocks — use 'auto', "
+                    "stencils pin a fixed device mesh — use 'auto', "
                     "'dense', 'stencil', 'sparse', or 'gather' (the "
                     "sharded-gather worker_mesh route instead dispatches "
                     "replicas as sequential mesh runs — see "
@@ -1167,14 +1159,6 @@ class ExperimentConfig:
                     "per-replica seed axis cannot reach — replicas would "
                     "silently share compression draws; run seeds "
                     "sequentially instead"
-                )
-            if self.robust_impl == "fused":
-                raise ValueError(
-                    "replicas > 1 is incompatible with "
-                    "robust_impl='fused': the replica axis vmaps the "
-                    "whole compiled program, but the fused pallas kernel "
-                    "addresses unbatched VMEM blocks — use 'auto', "
-                    "'gather', or 'dense'"
                 )
         if self.tp_degree < 1:
             raise ValueError(
@@ -1266,7 +1250,7 @@ class ExperimentConfig:
         loudly. Below the threshold (or off the jax backend, or with a
         dense-only feature in play) 'auto' keeps the dense form: at small
         N the [N, N] matrices are cheap and every measured fast path
-        (stencil mixing, the fused robust kernels, dense fault machinery)
+        (stencil mixing, dense fault machinery)
         assumes them.
         """
         if self.topology_impl != "auto":
@@ -1285,7 +1269,7 @@ class ExperimentConfig:
             # Byzantine screening DOES run matrix-free now (gather form,
             # ISSUE-9 satellite) but stays an explicit opt-in: auto keeps
             # defense studies on the dense path where every execution
-            # form (dense/gather/fused) is comparable. Edge-fault
+            # form (dense/gather) is comparable. Edge-fault
             # processes are no longer dense-only — the [horizon, E]
             # chains index through the (node, slot)→edge-id table.
             or self.attack != "none"
@@ -1431,12 +1415,8 @@ class ExperimentConfig:
         connected), where it sorts the same closed axis as dense plus the
         gather and the two measure a tie. Rule: gather iff k_max+1 < N
         (dense keeps the fully-connected case: nothing to gain, and the
-        [N, k_max+1, d] gather buffer matches dense's memory anyway).
-
-        'auto' never resolves to 'fused': the pallas kernel does not
-        lower for the TPU (Mosaic's gather rule rejects its in-kernel
-        ``jnp.take``), and the default path must be the same program on
-        the chip and on CPU. An explicit robust_impl is never overridden.
+        [N, k_max+1, d] gather buffer matches dense's memory anyway). An
+        explicit robust_impl is never overridden.
         """
         if self.robust_impl != "auto":
             return self.robust_impl

@@ -254,7 +254,7 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
             "shard_map mixing ops need a Mesh; build them via "
             "distributed_optimization_tpu.parallel.collectives instead"
         )
-    if impl not in ("dense", "stencil", "pallas", "sparse", "gather"):
+    if impl not in ("dense", "stencil", "sparse", "gather"):
         raise ValueError(f"Unknown mixing impl: {impl!r}")
     if impl == "stencil" and not _supports_stencil(topo):
         raise ValueError(f"stencil mixing unsupported for {topo.name} (n={topo.n})")
@@ -298,25 +298,6 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
             )
 
         return bind(tables)
-
-    if impl == "pallas":
-        # Hand-fused VMEM kernels (ops/pallas_kernels.py). Ring and
-        # fully-connected only — the graphs whose uniform-MH stencils reduce
-        # to rolls/means of the whole [N, d] block.
-        from distributed_optimization_tpu.ops import pallas_kernels as pk
-
-        if topo.name == "ring" and topo.n >= 3:
-            return MixingOp(
-                topo.name, "pallas", pk.ring_mix, pk.ring_neighbor_sum
-            )
-        if topo.name == "fully_connected":
-            return MixingOp(
-                topo.name, "pallas", pk.fc_mix, pk.fc_neighbor_sum
-            )
-        raise ValueError(
-            f"pallas mixing supports ring (n>=3) and fully_connected, "
-            f"not {topo.name} (n={topo.n})"
-        )
 
     if impl == "sparse":
         # CSR edge-list contraction: works for ANY graph, directed included

@@ -38,7 +38,7 @@ directly from the rule definitions (numpy-oracle convention, see
 ``backends/numpy_backend.py``): equivalence between the vectorized jax
 forms and this oracle is pinned in tests/test_byzantine.py.
 
-Three jax implementations of every rule (``robust_impl`` knob):
+Two jax implementations of every rule (``robust_impl`` knob):
 
 - **dense** (``make_robust_aggregator``): materializes the [N, N, d]
   closed-neighborhood tensor and sorts over the full node axis —
@@ -51,13 +51,7 @@ Three jax implementations of every rule (``robust_impl`` knob):
   sorts/trims/medians/clips over the k_max axis — O(N·k_max·d·log k_max)
   work and O(N·k_max·d) memory, an ~N/k_max-fold reduction on
   degree-bounded graphs (measured 69-75× e2e for trimmed mean/median on
-  an N=256 ring, docs/perf/robust_scale.json);
-- **fused** (``ops/pallas_kernels.py::make_fused_robust_aggregator`` —
-  lives with the other pallas kernels, not here): the gather math
-  term-for-term as ONE VMEM-resident pallas kernel (plus the D-SGD
-  update for dsgd), so the [N, k_max, d] stack never round-trips HBM
-  between ops; bitwise the gather form for the count rules, ≤ 1e-12
-  for clipping (tests/test_fused_robust.py, docs/perf/fused_robust.json).
+  an N=256 ring, docs/perf/robust_scale.json).
 
 The two are algebraically identical: the gather sort sees the same finite
 values (+inf padding beyond the realized neighborhood, same convention),

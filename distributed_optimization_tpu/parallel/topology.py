@@ -407,9 +407,7 @@ def neighbor_table(adjacency: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     (an always-in-bounds gather target) with mask False. ``k_max`` is the
     maximum degree — the whole point of the gather path is that sorts and
     reductions then run over k_max+1 values instead of N
-    (``ops/robust_aggregation.py::make_gather_robust_aggregator``, and
-    the single-kernel fused twin that consumes the same table entirely
-    in VMEM, ``ops/pallas_kernels.py::make_fused_robust_aggregator``).
+    (``ops/robust_aggregation.py::make_gather_robust_aggregator``).
 
     Host-side like everything in this module: built once per run, outside
     ``jit``. Directed graphs are rejected — the degree-bounded screening

@@ -145,8 +145,7 @@ RULES: tuple[Rule, ...] = (
     _domain("topology", "topology", TOPOLOGIES),
     _domain("backend", "execution", BACKENDS),
     _domain("mixing_impl", "topology",
-            ("auto", "dense", "stencil", "shard_map", "pallas", "sparse",
-             "gather")),
+            ("auto", "dense", "stencil", "shard_map", "sparse", "gather")),
     _domain("sampling_impl", "execution", ("auto", "gather", "dense")),
     _domain("lr_schedule", "algorithm", ("auto", "sqrt_decay", "constant")),
     _domain("compression", "compression", COMPRESSIONS),
@@ -231,7 +230,7 @@ RULES: tuple[Rule, ...] = (
            f"robust_b={f['robust_b']} only takes effect with a robust "
            "aggregation rule"
        )),
-    _domain("robust_impl", "byzantine", ("auto", "dense", "gather", "fused")),
+    _domain("robust_impl", "byzantine", ("auto", "dense", "gather")),
     _r("byzantine:impl_without_rule", ("byzantine",),
        lambda f: f["robust_impl"] != "auto" and not _robust_rule_on(f),
        lambda f: (
@@ -428,7 +427,7 @@ RULES: tuple[Rule, ...] = (
        lambda f: (
            "topology_impl='neighbor' runs robust aggregation in gather "
            f"form; robust_impl={f['robust_impl']!r} materializes "
-           "dense/VMEM objects the matrix-free path never builds"
+           "[N, N] objects the matrix-free path never builds"
        )),
     _r("neighbor×schedule", ("topology",),
        lambda f: f["topology_impl"] == "neighbor"
@@ -530,7 +529,7 @@ RULES: tuple[Rule, ...] = (
        and f["robust_impl"] not in ("auto", "gather"),
        lambda f: (
            f"worker_mesh screens in halo-gather form; robust_impl="
-           f"{f['robust_impl']!r} materializes dense/VMEM objects"
+           f"{f['robust_impl']!r} materializes [N, N] objects"
        )),
     _r("mesh×robust_telemetry", ("worker_mesh", "byzantine"),
        lambda f: _mesh_base_ok(f) and f["telemetry"]
@@ -724,11 +723,11 @@ RULES: tuple[Rule, ...] = (
        )),
     _r("replicas×mixing_impl", ("replicas", "topology"),
        lambda f: f["replicas"] > 1 and f["backend"] == "jax"
-       and f["mixing_impl"] in ("shard_map", "pallas"),
+       and f["mixing_impl"] == "shard_map",
        lambda f: (
-           f"replicas={f['replicas']} is incompatible with mixing_impl="
-           f"{f['mixing_impl']!r}: mesh-pinned / unbatched-VMEM forms "
-           "cannot ride the replica vmap axis"
+           f"replicas={f['replicas']} is incompatible with "
+           "mixing_impl='shard_map': a mesh-pinned form cannot ride the "
+           "replica vmap axis"
        )),
     _r("replicas×choco", ("replicas", "algorithm"),
        lambda f: f["replicas"] > 1 and f["backend"] == "jax"
@@ -743,12 +742,6 @@ RULES: tuple[Rule, ...] = (
        lambda f: (
            "replicas > 1 is unsupported with compressed gossip: the "
            "compressor stream derives from config.seed internally"
-       )),
-    _r("replicas×fused", ("replicas", "byzantine"),
-       lambda f: f["replicas"] > 1 and f["backend"] == "jax"
-       and f["robust_impl"] == "fused",
-       lambda f: (
-           "replicas > 1 is incompatible with robust_impl='fused'"
        )),
     # --------------------------------------------------- tensor parallel
     _r("domain:tp_degree", ("worker_mesh",),

@@ -19,8 +19,8 @@ the ``run_batch`` call for them:
   the cached executable — identical across cohorts of the same class and
   size, whether or not this particular cohort's values differ.
 - **fallback**: configs ``jax_backend.batch_unsupported_reason`` rejects
-  (choco, compressed gossip, shard_map/pallas mixing, fused robust kernel,
-  tensor parallelism, non-jax backends) become singleton sequential plans
+  (choco, compressed gossip, shard_map mixing, tensor parallelism,
+  non-jax backends) become singleton sequential plans
   executed via ``run_algorithm`` — same rejection logic, no duplicated
   condition list.
 

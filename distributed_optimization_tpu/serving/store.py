@@ -30,6 +30,11 @@ Contract decisions, and why:
   on: serialized XLA executables are not portable across jax versions or
   device kinds, and a redeploy that upgrades jax must degrade to a cold
   compile, not a corrupt-program crash.
+  An artifact also records the ids of the devices its program runs over
+  (a mesh of 4 workers takes 4 of 8 visible devices) and is loaded onto
+  exactly those: left to jax, a load binds every visible device and the
+  first call fails on the shard count. Where the process lacks one of
+  them the artifact is skipped the same way.
 - **Corruption degrades to a cold compile.** A truncated, unreadable or
   wrong-schema artifact logs a single warning per file and reads as a
   miss — mirroring the ISSUE-3 checkpoint-fallback contract
@@ -212,6 +217,12 @@ class PersistentExecutableStore:
                 "schema": STORE_SCHEMA_VERSION,
                 "provenance": self._prov(),
                 "key_repr": repr(key),
+                # The devices the program runs over (a worker mesh may
+                # take fewer than the process sees): loading binds them.
+                "device_ids": [
+                    d.id for d in
+                    entry.executable.runtime_executable().local_devices()
+                ],
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
@@ -299,6 +310,12 @@ class PersistentExecutableStore:
             for k in ("jax_version", "device_kind", "x64")
             if stored_prov.get(k) != here.get(k)
         }
+        import jax
+
+        devices = {d.id: d for d in jax.devices()}
+        device_ids = record.get("device_ids")
+        if device_ids is None or not set(device_ids) <= set(devices):
+            mismatched["device_ids"] = (device_ids, sorted(devices))
         if mismatched:
             with self._lock:
                 self._stats.skipped_provenance += 1
@@ -317,7 +334,8 @@ class PersistentExecutableStore:
             from jax.experimental import serialize_executable
 
             executable = serialize_executable.deserialize_and_load(
-                record["payload"], record["in_tree"], record["out_tree"]
+                record["payload"], record["in_tree"], record["out_tree"],
+                execution_devices=[devices[i] for i in device_ids],
             )
         except Exception as e:
             with self._lock:

@@ -72,8 +72,7 @@ def main() -> None:
     )
     # This artifact documents the GATHER robust path's breakdown table
     # (PR 3); pin it explicitly so the config string says what a regen
-    # measures whatever 'auto' resolves to (between PR 6 and PR 21 it
-    # resolved to the fused pallas kernel on these static-ring cells).
+    # measures whatever 'auto' resolves to.
     ROBUST_IMPL = "gather"
     # Attackers, per-neighborhood budget (ring min degree 2 => b <= 1),
     # sign-flip scale. f=6 under seed 203 places <= 1 attacker in every

@@ -10,7 +10,6 @@ Measures, for the north-star N=256 ring-logistic configuration (reference
    ``mixing_impl``, identical workload, best of ``--repeats`` runs.
 
 Implementations compared: ``stencil`` (jnp.roll stencil, XLA-fused),
-``pallas`` (hand-written VMEM kernels incl. the fused W x − ηg step),
 ``dense`` ([N,N] matmul — the reference's own formulation, on the MXU),
 ``shard_map`` (explicit ppermute collectives; degenerate on a single chip —
 included for completeness, flagged in the output).
@@ -89,7 +88,6 @@ def main() -> None:
     mesh = make_worker_mesh(n)
     impls = {
         "stencil": make_mixing_op(topo, impl="stencil").apply,
-        "pallas": make_mixing_op(topo, impl="pallas").apply,
         "dense": make_mixing_op(topo, impl="dense").apply,
         "shard_map": make_shard_map_mixing_op(topo, mesh).apply,
     }
@@ -119,7 +117,7 @@ def main() -> None:
     best: dict[str, float] = {}
     gaps: dict[str, float] = {}
     for _ in range(args.repeats):
-        for impl in ("stencil", "pallas", "dense", "shard_map"):
+        for impl in ("stencil", "dense", "shard_map"):
             if impl in e2e:  # already failed; don't retry every cycle
                 continue
             cfg = cfg0.replace(mixing_impl=impl)

@@ -166,7 +166,7 @@ def agreement_axes():
                  "clip_tau": 0.5},
                 {"attack": "alie", "n_byzantine": 2,
                  "aggregation": "median", "robust_b": 2},
-                {"robust_impl": "fused"},
+                {"robust_impl": "dense"},
                 {"aggregation": "trimmed_mean"}, {"n_byzantine": 3},
             ]
         ),

@@ -12,7 +12,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 python examples/bench_mixing.py            # -> docs/perf/mixing_bench.json
-python examples/bench_pallas_regimes.py    # -> docs/perf/pallas_regimes.json
 python examples/bench_breakdown.py         # -> docs/perf/breakdown.json
 python examples/bench_scaling.py           # -> docs/perf/scaling.json + figure
 python examples/bench_presets.py           # -> docs/perf/presets.json
@@ -24,7 +23,6 @@ python examples/bench_sparse_mixing.py     # -> docs/perf/sparse_mixing.json
 python examples/bench_compute_bound.py     # -> docs/perf/compute_bound.json (MFU-floor gated)
 python examples/bench_sweep.py             # -> docs/perf/sweep.json (replica-batch floor gated)
 python examples/bench_telemetry.py         # -> docs/perf/telemetry.json (overhead-ceiling gated)
-python examples/bench_fused_robust.py      # -> docs/perf/fused_robust.json (CPU only: Mosaic refuses the fused kernel)
 python examples/bench_serving.py           # -> docs/perf/serving.json (latency/throughput floors gated)
 python examples/bench_serving_load.py      # -> docs/perf/serving_load.json (sustained-load warm-p99/saturation/fairness floors + restart-warm + shed gates; multi-worker daemon + persistent store)
 python examples/bench_fleet.py            # -> docs/perf/fleet.json (self-healing soak: every injected incident remediated + zero stuck + autoscale cycle gated; fleet reflex layer over the multi-worker daemon)

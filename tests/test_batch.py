@@ -207,9 +207,9 @@ def test_rejects_choco_and_unbatchable_mixing():
             _cfg(algorithm="choco", lr_schedule="constant"), ds, f_opt,
             seeds=[1, 2],
         )
-    with pytest.raises(ValueError, match="pallas"):
+    with pytest.raises(ValueError, match="shard_map"):
         jax_backend.run_batch(
-            _cfg(mixing_impl="pallas"), ds, f_opt, seeds=[1, 2]
+            _cfg(mixing_impl="shard_map"), ds, f_opt, seeds=[1, 2]
         )
 
 

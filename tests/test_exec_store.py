@@ -180,6 +180,24 @@ def test_wrong_device_kind_and_x64_skipped(tmp_path):
     assert store.stats()["skipped_provenance"] == 2
 
 
+def test_artifact_over_devices_the_process_lacks_is_skipped(tmp_path, capsys):
+    """An artifact made over more devices than this process sees (or by a
+    store that recorded none) is a provenance miss before the deserializer,
+    never a program bound to the wrong devices."""
+    import jax
+
+    store = PersistentExecutableStore(tmp_path)
+    key = ("seq", "wide-mesh")
+    _fake_artifact(store, key, device_ids=[0, len(jax.devices())])
+    capsys.readouterr()
+    assert store.load(key) is None
+    _fake_artifact(store, key)
+    assert store.load(key) is None
+    st = store.stats()
+    assert (st["skipped_provenance"], st["corrupt"]) == (2, 0)
+    assert len(_store_warnings(capsys, "device_ids")) == 1
+
+
 def test_key_repr_mismatch_reads_as_corrupt(tmp_path):
     """A digest collision / key-format drift is caught by the stored
     key repr and reads as a miss, never as the wrong program."""

@@ -149,14 +149,34 @@ def test_sparse_is_opt_in_only():
     ).impl == "sparse"
 
 
-def test_auto_never_picks_pallas_after_round5_sweep():
-    """Round 5's interleaved 7-dim sweep (docs/perf/pallas_regimes.json)
-    found NO reproducible pallas win at any d in [81, 1024] (e2e ratios
-    0.78-1.29, no trend; the round-3 d=1024 win did not replicate), so
-    'auto' never resolves to the VMEM kernels — stencil/dense only — at
-    any dimension, and pallas is explicit opt-in."""
-    for n in (8, 256):
-        assert make_mixing_op(build_topology("ring", n)).impl == "stencil"
-    assert make_mixing_op(
-        build_topology("ring", 8), impl="pallas"
-    ).impl == "pallas"
+# The two option values whose kernels no ledger line ever chose (ROADMAP C3,
+# PR 42) are gone: a config, the operator builder and the CLI refuse them as
+# they refuse any other unknown value.
+REMOVED_VALUES = pytest.mark.parametrize("field,value,rule", [
+    ("mixing_impl", "pallas", {}),
+    ("robust_impl", "fused", dict(aggregation="trimmed_mean", robust_b=1)),
+], ids=["mixing_impl-pallas", "robust_impl-fused"])
+
+
+@REMOVED_VALUES
+def test_removed_impl_values_are_unknown(field, value, rule):
+    from distributed_optimization_tpu.config import ExperimentConfig
+
+    with pytest.raises(ValueError, match=f"Unknown .* impl: {value}"):
+        ExperimentConfig(**{field: value}, **rule)
+    if field == "mixing_impl":
+        with pytest.raises(ValueError, match=f"Unknown mixing impl: '{value}'"):
+            make_mixing_op(build_topology("ring", 8), impl=value)
+
+
+@REMOVED_VALUES
+def test_removed_impl_values_are_no_cli_choice(field, value, rule, capsys):
+    from distributed_optimization_tpu.cli import build_parser
+
+    argv = [
+        arg for key, val in {**rule, field: value}.items()
+        for arg in ("--" + key.replace("_", "-"), str(val))
+    ]
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(argv)
+    assert f"invalid choice: '{value}'" in capsys.readouterr().err

@@ -1,8 +1,6 @@
 """Pallas kernel tests (interpreter mode on CPU — same code path Mosaic
-compiles on real TPU).
-
-Equivalence oracle: the dense mixing matrix (the reference's own W,
-reference ``trainer.py:91-136``).
+compiles on real TPU): the shard visit against XLA's two passes, and how a
+call decides between the interpreter and Mosaic.
 """
 
 import functools
@@ -13,88 +11,8 @@ import numpy as np
 import pytest
 from conftest import assert_ulps_of_scale
 
-from distributed_optimization_tpu.backends import jax_backend
-from distributed_optimization_tpu.config import ExperimentConfig
 from distributed_optimization_tpu.ops import losses
 from distributed_optimization_tpu.ops import pallas_kernels as pk
-from distributed_optimization_tpu.ops.mixing import make_mixing_op
-from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.utils.data import generate_synthetic_dataset
-from distributed_optimization_tpu.utils.oracle import compute_reference_optimum
-
-
-@pytest.fixture
-def x(rng):
-    return jnp.asarray(rng.standard_normal((8, 12)), dtype=jnp.float32)
-
-
-def test_ring_mix_matches_dense_W(x):
-    topo = build_topology("ring", 8)
-    want = topo.mixing_matrix @ np.asarray(x, dtype=np.float64)
-    got = np.asarray(pk.ring_mix(x))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-
-
-def test_fc_mix_matches_dense_W(x):
-    topo = build_topology("fully_connected", 8)
-    want = topo.mixing_matrix @ np.asarray(x, dtype=np.float64)
-    got = np.asarray(pk.fc_mix(x))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-
-
-def test_fused_step_equals_mix_then_step(x, rng):
-    g = jnp.asarray(rng.standard_normal(x.shape), dtype=jnp.float32)
-    eta = 0.07
-    got = np.asarray(pk.fused_ring_dsgd_step(x, g, eta))
-    want = np.asarray(pk.ring_mix(x)) - eta * np.asarray(g)
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
-
-
-def test_mixing_op_pallas_ring_and_fc(x):
-    for name in ("ring", "fully_connected"):
-        topo = build_topology(name, 8)
-        op = make_mixing_op(topo, impl="pallas")
-        assert op.impl == "pallas"
-        np.testing.assert_allclose(
-            np.asarray(op.apply(x)),
-            topo.mixing_matrix @ np.asarray(x, dtype=np.float64),
-            rtol=1e-5, atol=1e-6,
-        )
-        # Direct roll/sum kernels — exact to fp32 accumulation.
-        np.testing.assert_allclose(
-            np.asarray(op.neighbor_sum(x)),
-            topo.adjacency @ np.asarray(x, dtype=np.float64),
-            rtol=1e-5, atol=1e-6,
-        )
-
-
-def test_pallas_rejects_unsupported_topology():
-    with pytest.raises(ValueError, match="pallas mixing supports"):
-        make_mixing_op(build_topology("grid", 9), impl="pallas")
-
-
-def test_end_to_end_run_with_pallas_mixing():
-    cfg = ExperimentConfig(
-        n_workers=8, n_samples=320, n_features=8, n_informative_features=4,
-        n_iterations=200, local_batch_size=8, problem_type="quadratic",
-        algorithm="dsgd", topology="ring", mixing_impl="pallas",
-        eval_every=20,
-    )
-    ds = generate_synthetic_dataset(cfg)
-    _, f_opt = compute_reference_optimum(ds, cfg.reg_param)
-    pallas_run = jax_backend.run(cfg, ds, f_opt, use_mesh=False)
-    stencil_run = jax_backend.run(
-        cfg.replace(mixing_impl="stencil"), ds, f_opt, use_mesh=False
-    )
-    # Identical batches (same counter-keyed RNG) => identical trajectories.
-    np.testing.assert_allclose(
-        pallas_run.history.objective, stencil_run.history.objective,
-        rtol=1e-4, atol=1e-6,
-    )
-    np.testing.assert_allclose(
-        pallas_run.final_models, stencil_run.final_models,
-        rtol=1e-4, atol=1e-6,
-    )
 
 
 # --- the shard visit (ISSUE 41): one read of a GLM's shards ----------------
@@ -176,3 +94,39 @@ def test_shard_visit_block_is_sized_by_the_budget():
             LINKS["logistic"], X, X[:, :, 0], X[:, 0], X[0, 0], X[:, :, 0],
             jnp.zeros(4, jnp.int32),
         )
+
+
+# --- interpreter or Mosaic: what a call reads ------------------------------
+
+def test_resolve_interpret_explicit_override_wins():
+    x = jnp.zeros((4, 4))
+    assert pk.resolve_interpret(x, interpret=True) is True
+    assert pk.resolve_interpret(x, interpret=False) is False
+
+
+def test_resolve_interpret_uses_committed_platform():
+    """On this CPU-only container every committed array lives on cpu, and
+    the resolver must read THAT (not the global devices list) — including
+    under an explicit jax.default_device scope, in BOTH forms jax
+    accepts (a Device object and a platform string — the latter leaves a
+    plain str in jax.config.jax_default_device)."""
+    x = jax.device_put(jnp.zeros((4, 4)), jax.devices("cpu")[0])
+    assert pk.resolve_interpret(x) is True
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert pk.resolve_interpret(None) is True
+    with jax.default_device("cpu"):
+        assert pk.resolve_interpret(None) is True
+
+
+def test_resolve_interpret_handles_tracers():
+    """Inside jit the operand is a tracer with no committed device; the
+    resolver must fall back to the ambient platform instead of raising."""
+    seen = {}
+
+    @jax.jit
+    def probe(x):
+        seen["interp"] = pk.resolve_interpret(x)
+        return x
+
+    probe(jnp.zeros((2, 2)))
+    assert seen["interp"] is True  # cpu container

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax import enable_x64
 import numpy as np
 import pytest
+from conftest import assert_ulps_of_scale
 
 from distributed_optimization_tpu.backends import jax_backend, numpy_backend
 from distributed_optimization_tpu.config import ExperimentConfig
@@ -79,14 +80,9 @@ def test_incident_edge_slots_are_symmetric():
 
 # ----------------------------------------------- unit parity (f64 <= 1e-12)
 
-@pytest.mark.parametrize("rule", RULES)
-@pytest.mark.parametrize(
-    "topo_name,n", [("ring", 16), ("erdos_renyi", 14), ("grid", 16)]
-)
-def test_gather_matches_dense_and_oracle_f64(rule, topo_name, n):
-    """The acceptance parity: gather vs dense vs the per-node numpy oracle
-    at ≤ 1e-12 in float64, over an irregular fault-realized graph with
-    wild (attack-like) rows."""
+def _faulted_instance(topo_name, n):
+    """An irregular fault-realized graph with wild (attack-like) rows: the
+    realized adjacency, the stack, the static table and its liveness."""
     topo = build_topology(topo_name, n, erdos_renyi_p=0.5, seed=3)
     rng = np.random.default_rng(11)
     A = np.array(topo.adjacency, copy=True)
@@ -96,7 +92,19 @@ def test_gather_matches_dense_and_oracle_f64(rule, topo_name, n):
     x = rng.standard_normal((n, 7))
     x[[1, 5]] *= 1e4  # wild rows the screening must contain
     nbr_idx, nbr_mask = neighbor_table(topo.adjacency)
-    live = _gather_live(A, nbr_idx, nbr_mask)
+    return A, x, nbr_idx, _gather_live(A, nbr_idx, nbr_mask)
+
+
+
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize(
+    "topo_name,n", [("ring", 16), ("erdos_renyi", 14), ("grid", 16)]
+)
+def test_gather_matches_dense_and_oracle_f64(rule, topo_name, n):
+    """The acceptance parity: gather vs dense vs the per-node numpy oracle
+    at ≤ 1e-12 in float64, over an irregular fault-realized graph with
+    wild (attack-like) rows."""
+    A, x, nbr_idx, live = _faulted_instance(topo_name, n)
     with enable_x64():
         dense = make_robust_aggregator(rule, budget=1)
         gather = make_gather_robust_aggregator(rule, 1, nbr_idx)
@@ -113,6 +121,27 @@ def test_gather_matches_dense_and_oracle_f64(rule, topo_name, n):
     # would demand better-than-ulp agreement).
     np.testing.assert_allclose(g_out, d_out, rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(g_out, o_out, rtol=1e-12, atol=1e-12)
+
+
+@pytest.mark.parametrize("rule", ("trimmed_mean", "median"))
+def test_gather_f32_matches_dense_f32(rule):
+    """Both forms keep float32 inputs in float32 and pick the same sorted
+    values, so the count rules agree to float32's own units of the stack's
+    scale (the kept values are summed over axes of different length);
+    bfloat16 arithmetic is far outside."""
+    A, x, nbr_idx, live = _faulted_instance("erdos_renyi", 14)
+    dense = make_robust_aggregator(rule, budget=1)
+    gather = make_gather_robust_aggregator(rule, 1, nbr_idx)
+    xv = jnp.asarray(x, jnp.float32)
+    want = dense(jnp.asarray(A, jnp.float32), xv)
+    got = gather(jnp.asarray(live, jnp.float32), xv)
+    assert got.dtype == want.dtype == jnp.float32
+    assert_ulps_of_scale(got, want, 4)
+    rounded = gather(
+        jnp.asarray(live, jnp.bfloat16), xv.astype(jnp.bfloat16)
+    ).astype(jnp.float32)
+    with pytest.raises(AssertionError):
+        assert_ulps_of_scale(rounded, want, 4)
 
 
 def test_gather_fixed_clip_tau_matches_dense():
@@ -282,6 +311,45 @@ def test_e2e_auto_routes_like_explicit_on_sparse_graph(e2e_data):
     ra = jax_backend.run(cfg, ds, f_opt)
     rg = jax_backend.run(cfg.replace(robust_impl="gather"), ds, f_opt)
     np.testing.assert_array_equal(ra.final_models, rg.final_models)
+
+
+def test_auto_stays_gather_under_faults_and_telemetry(e2e_data):
+    """Time-varying graphs and an active telemetry activity probe run the
+    measured gather routing, like every other 'auto' configuration."""
+    ds, f_opt = e2e_data
+    faulty = E2E_CFG.replace(edge_drop_prob=0.2)
+    ra = jax_backend.run(faulty, ds, f_opt, use_mesh=False)
+    rg = jax_backend.run(
+        faulty.replace(robust_impl="gather"), ds, f_opt, use_mesh=False
+    )
+    np.testing.assert_array_equal(ra.final_models, rg.final_models)
+    tele = E2E_CFG.replace(telemetry=True)
+    rt = jax_backend.run(tele, ds, f_opt, use_mesh=False)
+    rtg = jax_backend.run(
+        tele.replace(robust_impl="gather"), ds, f_opt, use_mesh=False
+    )
+    np.testing.assert_array_equal(rt.final_models, rtg.final_models)
+
+
+def test_e2e_gradient_tracking_gather_matches_dense(e2e_data):
+    """A second step rule through the screened mix (the tracker's two
+    gossip rounds an iteration both screen), with the flight recorder's
+    activity probe on: gather and dense are one f64 trajectory and one
+    screened fraction."""
+    ds, f_opt = e2e_data
+    cfg = E2E_CFG.replace(algorithm="gradient_tracking", telemetry=True)
+    rd, rg = (
+        jax_backend.run(cfg.replace(robust_impl=impl), ds, f_opt, use_mesh=False)
+        for impl in ("dense", "gather")
+    )
+    np.testing.assert_allclose(
+        rg.final_models, rd.final_models, rtol=1e-12, atol=1e-12
+    )
+    np.testing.assert_allclose(
+        rg.history.trace["clip_frac"], rd.history.trace["clip_frac"],
+        rtol=1e-12, atol=1e-12,
+    )
+    assert np.max(rg.history.trace["clip_frac"]) > 0.0
 
 
 def test_gather_resume_exactness(e2e_data, tmp_path):

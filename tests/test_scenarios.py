@@ -58,7 +58,7 @@ WIDE_AXES = {
         {"aggregation": "clipped_gossip", "robust_b": 1, "clip_tau": 0.5},
         {"attack": "alie", "n_byzantine": 2, "aggregation": "median",
          "robust_b": 2},
-        {"robust_impl": "fused"}, {"aggregation": "trimmed_mean"},
+        {"robust_impl": "dense"}, {"aggregation": "trimmed_mean"},
         {"attack": "large_noise"}, {"n_byzantine": 3},
     ],
     "compression": [

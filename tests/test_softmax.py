@@ -223,7 +223,7 @@ def test_scan_step_carries_the_matrix_and_never_flattens_it():
         key=jax.random.key(cfg.seed), eta_fn=jax_backend._make_eta_fn(cfg),
         degrees=jnp.asarray(topo.degrees, jnp.float32).reshape(n, 1, 1),
         mix_op=make_mixing_op(topo), faulty=None, byz_mix=None,
-        adversary=None, honest_w=None, fused_mix_step=None,
+        adversary=None, honest_w=None,
         full_objective=jax_backend.make_full_objective_fn(
             problem, cfg.reg_param
         ),
@@ -301,7 +301,6 @@ _OPERATOR_PATHS = {
     ),
     "push_sum": dict(algorithm="push_sum", topology="directed_ring"),
     "admm": dict(algorithm="admm", topology="erdos_renyi"),
-    "pallas_fused_ring": dict(mixing_impl="pallas"),
 }
 
 

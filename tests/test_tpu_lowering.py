@@ -1,11 +1,12 @@
 """What tier-1 (CPU) can pin about the program the chip compiles.
 
 The sandbox has no accelerator, but two things about the on-chip program
-are checkable here: (1) whether each Pallas kernel LOWERS for the TPU
-(``jax.export`` runs the Mosaic lowering rules without a device; whether
-Mosaic then compiles the result is for a chip run — CHANGES.md PR 21 lists
-it kernel by kernel), and (2) that the program form ``auto`` picks on an
-accelerator — dense sampling, scan unroll 8 — computes the same run as the
+are checkable here: (1) whether the forms a run takes LOWER for the TPU —
+the gossip round of every static graph, the fault layer's round, the robust
+gather round, and the shard visit's Pallas kernel (``jax.export`` runs the
+StableHLO and Mosaic lowering rules without a device; whether the chip's
+compiler then takes the result is for tests/test_tpu_compile.py and a chip
+run), and (2) that the program form ``auto`` picks on an accelerator — dense sampling, scan unroll 8 — computes the same run as the
 CPU default (gather sampling, unroll 1).
 """
 
@@ -33,40 +34,104 @@ def _lower_for_tpu(fn, *args):
     return export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
-@pytest.mark.parametrize("kernel", [
-    pk.ring_mix, pk.fc_mix, pk.ring_neighbor_sum, pk.fc_neighbor_sum,
-])
-def test_mixing_kernels_lower_for_tpu(kernel):
-    """mixing_impl='pallas' (explicit opt-in) reaches these four."""
-    exported = _lower_for_tpu(lambda x: kernel(x, interpret=False), X)
+def _stablehlo_ops(text):
+    return set(re.findall(r"\b(?:stablehlo|chlo)\.\w+", text))
+
+
+# The forms ``auto`` or the neighbor table choose for a static graph, each as
+# (topology, its arguments, the form). No option reaches any of them.
+MIXING_FORMS = {
+    "ring_stencil": ("ring", {}, "stencil"),
+    "grid_stencil": ("grid", {}, "stencil"),
+    "fully_connected_mean": ("fully_connected", {}, "stencil"),
+    "drawn_graph_live_slot_gather": (
+        "erdos_renyi", dict(impl="neighbor", erdos_renyi_p=0.05, seed=7),
+        "gather",
+    ),
+    "chain_gather": ("chain", dict(impl="neighbor"), "gather"),
+}
+
+
+@pytest.mark.parametrize("stack", [(D,), (33, 16)], ids=["Nd", "NdK"])
+@pytest.mark.parametrize("form", sorted(MIXING_FORMS))
+def test_mixing_forms_lower_for_tpu(form, stack):
+    """One round of each form a run's gossip takes, on the stack in the rank
+    the scan carries (``[N, d]``, and ``[N, d, K]`` as it is: none flattens
+    it), the gather's tables arguments of the program as the scan hands them:
+    the chip is given a program with no sort and no scatter."""
+    from distributed_optimization_tpu.ops.mixing import make_mixing_op
+
+    name, kwargs, impl = MIXING_FORMS[form]
+    op = make_mixing_op(build_topology(name, N, **kwargs))
+    assert op.impl == impl
+    x = jax.ShapeDtypeStruct((N, *stack), jnp.float32)
+
+    def one_round(v, tables):
+        bound = op if tables is None else op.bind(tables)
+        return bound.apply(v), bound.neighbor_sum(v)
+
+    exported = _lower_for_tpu(one_round, x, op.tables)
     assert exported.platforms == ("tpu",)
+    assert all(out.shape == x.shape for out in exported.out_avals)
+    text = exported.mlir_module()
+    ops = _stablehlo_ops(text)
+    assert not ops & {"stablehlo.sort", "stablehlo.scatter", "chlo.top_k"}, ops
+    assert len(stack) == 1 or f"tensor<{N}x{int(np.prod(stack))}x" not in text
 
 
-def test_fused_ring_dsgd_step_lowers_for_tpu():
+@pytest.mark.parametrize("topology,addressing", [
+    ("ring", "shift"), ("chain", "gather"),
+])
+def test_faulty_ring_round_lowers_for_tpu(topology, addressing):
+    """A round of the fault layer (memoryless drops and stragglers, the bits
+    drawn in the step from (seed, t)) in its two forms: neighbours by shifts
+    where the table is a ring's, by the table's gather anywhere else."""
+    from distributed_optimization_tpu.parallel.faults import make_faulty_mixing
+
+    topo = build_topology(topology, N, impl="neighbor")
+    faulty = make_faulty_mixing(topo, 0.3, seed=5, straggler_prob=0.1)
+    assert faulty.addressing == addressing and faulty.timeline is None
+
+    def one_round(t, v, tables):
+        bound = faulty if tables is None else faulty.bind(tables)
+        return bound.mix(t, v), bound.active(t)
+
     exported = _lower_for_tpu(
-        lambda x, g: pk.fused_ring_dsgd_step(x, g, 0.05, interpret=False), X, X
+        one_round, jax.ShapeDtypeStruct((), jnp.int32), X, faulty.tables
     )
     assert exported.platforms == ("tpu",)
+    assert "stablehlo.scatter" not in _stablehlo_ops(exported.mlir_module())
 
 
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="Mosaic's _gather_lowering_rule rejects the in-kernel "
-           "jnp.take(xa, nbr, axis=0): 'Shape mismatch in input, indices "
-           "and output'. robust_impl='auto' therefore never selects "
-           "'fused'; when this starts passing, ROADMAP A4/C3 can re-open.",
-)
-@pytest.mark.parametrize("with_sgd", [False, True])
+@pytest.mark.parametrize("liveness", ["all_live", "faulted"])
 @pytest.mark.parametrize("rule", ["trimmed_mean", "median", "clipped_gossip"])
-def test_fused_robust_kernel_lowers_for_tpu(rule, with_sgd):
-    nbr_idx, nbr_mask = neighbor_table(build_topology("ring", N).adjacency)
-    live = jax.ShapeDtypeStruct(nbr_mask.shape, jnp.float32)
-    if with_sgd:
-        step = pk.make_fused_robust_dsgd_step(rule, 1, nbr_idx, interpret=False)
-        _lower_for_tpu(lambda lv, x, g: step(lv, x, g, 0.05), live, X, X)
+def test_robust_gather_round_lowers_for_tpu(rule, liveness):
+    """The screening round every Byzantine run takes (``robust_impl`` auto is
+    the gather form on any graph but the complete one): over the static
+    table's mask, and over the liveness bits the fault layer draws for the
+    same table at t. These hold a sort (the count rules' and the adaptive
+    radius's): only that they lower is pinned."""
+    from distributed_optimization_tpu.ops.robust_aggregation import (
+        make_gather_robust_aggregator,
+    )
+    from distributed_optimization_tpu.parallel.faults import make_faulty_mixing
+
+    topo = build_topology("ring", N)
+    nbr_idx, nbr_mask = neighbor_table(topo.adjacency)
+    aggregate = make_gather_robust_aggregator(rule, 1, nbr_idx)
+    if liveness == "all_live":
+        live = jnp.asarray(nbr_mask, dtype=jnp.float32)
+        exported = _lower_for_tpu(lambda v: aggregate(live, v), X)
     else:
-        agg = pk.make_fused_robust_aggregator(rule, 1, nbr_idx, interpret=False)
-        _lower_for_tpu(agg, live, X)
+        live_fn = make_faulty_mixing(
+            topo, 0.3, seed=5, straggler_prob=0.1
+        ).make_neighbor_liveness(nbr_idx, nbr_mask)
+        exported = _lower_for_tpu(
+            lambda t, v: aggregate(live_fn(t), v),
+            jax.ShapeDtypeStruct((), jnp.int32), X,
+        )
+    assert exported.platforms == ("tpu",)
+    assert exported.out_avals[0].shape == (N, D)
 
 
 def test_accelerator_program_form_matches_cpu_default():
@@ -123,7 +188,7 @@ def test_top_k_exchange_lowers_without_sort_or_scatter(shape):
         return ef.exchange(None, v, memory, lambda m: jnp.roll(m, 1, axis=0))
 
     text = _lower_for_tpu(exchange, x, x).mlir_module()
-    ops = set(re.findall(r"\b(?:stablehlo|chlo)\.\w+", text))
+    ops = _stablehlo_ops(text)
     assert {"stablehlo.while", "stablehlo.reduce", "stablehlo.compare"} <= ops
     assert not ops & {
         "stablehlo.sort", "chlo.top_k", "stablehlo.scatter",

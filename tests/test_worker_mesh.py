@@ -546,7 +546,7 @@ def test_worker_mesh_one_rejected():
      "sign_flip or large_noise"),
     (dict(mttf=20.0, mttr=3.0, rejoin="neighbor_restart"),
      "halo-averaged warm restart"),
-    (dict(robust_impl="fused", attack="sign_flip", n_byzantine=1,
+    (dict(robust_impl="dense", attack="sign_flip", n_byzantine=1,
           aggregation="median", robust_b=1), "halo-gather"),
     (dict(algorithm="centralized"), "no peer graph"),
 ])
