@@ -101,6 +101,19 @@ without a TPU it exits before doing any work. Segments:
    one. What the CPU cannot see: Mosaic's compile, the runtime's layout of
    the stack, and whether GSPMD leaves the ``shard_map`` round the call alone.
 
+11. The Byzantine cell's deployment cut to 2^16 workers (ISSUE 43): the
+   README's sign-flipping ring (6 attackers in 64, placed within the
+   per-neighbourhood budget, screened by the trimmed mean at b = 1), built
+   from the benchmark's own files; 100 iterations against the benchmark's
+   plain reference (``benchmark/reference/dsgd_ring_byzantine.py``) by the
+   cell's own limits. The root says ``forward`` = ``fused`` (the shard visit
+   with the HONEST mean as the eval's x-bar), ``robust_impl`` = ``gather``,
+   ``budget_max`` = 1; the device's peak over the bytes in use at the
+   segment's start; and the compiled scan's instructions under
+   ``dopt.robust``, the largest with their bytes. What the CPU cannot see:
+   the visit carrying an adversary, the sort's layout and what the unrolled
+   trip keeps alive of it.
+
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
 line of stdout is one JSON object naming the device as JAX reports it.
@@ -535,6 +548,67 @@ def tracker_segment(device: dict, *, iterations: int = 100, seed: int = 39) -> N
           f"({n} workers x {b} of {L})", flush=True)
 
 
+def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
+                      iterations: int = 100, seed: int = 43) -> None:
+    """The Byzantine cell's experiment cut to ``n_workers``, from the
+    benchmark's own files, against its reference over ``iterations``
+    iterations: what the root says, the rows by the cell's limits, the
+    peak, and what the compiled scan holds under ``dopt.robust`` (ISSUE 43)."""
+    import jax
+
+    from benchmark import compare, datasets, program
+    from benchmark import run as harness
+    from benchmark.reference import dsgd_ring_byzantine
+    from distributed_optimization_tpu.observability import device_scopes
+
+    cell = "glm81_ring262k_signflip_tm1.screen1k"
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    _, config, traffic = harness.load_cell(bench, cell, rehearse=False)
+    exp = dict(config["experiment"])
+    share = exp["n_byzantine"] / exp["n_workers"]
+    exp.update(n_workers=n_workers, n_byzantine=int(share * n_workers))
+    config = dict(config, experiment=exp)
+    traffic = dict(traffic, n_iterations=iterations, check_iterations=iterations)
+    X, y, L = datasets.make(config, seed)
+    cfg, ds = program.build(config, traffic, X, y, L, program.seed_for(seed))
+    start = int(jax.devices()[0].memory_stats()["bytes_in_use"])
+    result, root, peak = _rooted_run(
+        f"sign-flip on a ring of {n_workers}, trimmed mean", device, cfg, ds,
+        ("attack", "byzantine_placement", "budget_max", "aggregation",
+         "robust_impl", "screened_rows", "robust_bytes", "forward", "mixing",
+         "temp_bytes"))
+    _check((root["forward"], root["robust_impl"], root["budget_max"])
+           == ("fused", "gather", 1),
+           "the root says the shard visit, the gather form, one attacker at "
+           "most beside an honest worker")
+    _check(root["attack"] == f"sign_flip:{exp['n_byzantine']}/{n_workers}"
+           and root["byzantine_placement"] == "within_budget"
+           and root["aggregation"] == "trimmed_mean:b=1"
+           and root["screened_rows"] == 3 * n_workers,
+           "the root says who lied and how it was screened")
+    ref = dsgd_ring_byzantine.run(config, traffic, X, y, program.seed_for(seed))
+    nums = compare.numbers(harness.produced_of(result), ref)
+    ok = compare.judge(nums, config["limits"]["screen1k"],
+                       lambda line: print("[chip_smoke] byzantine:", line, flush=True))
+    _check(ok, f"the program's {iterations} rows are the reference's by the cell's limits")
+    hist = result.history
+    _check(bool(np.all(np.isfinite(result.final_models)))
+           and hist.objective[-1] < hist.objective[0],
+           "every model finite, the objective at the honest mean descending")
+    print(f"[chip_smoke] byzantine: peak {peak - start} B over the "
+          f"{start} B in use at the segment's start (shards "
+          f"{X.nbytes} B, the scan's temporaries {int(root['temp_bytes'])} B)",
+          flush=True)
+    rows = [r for r in device_scopes.table_for(root["program"])["rows"]
+            if r["scope"] == "robust"]
+    _check(bool(rows), "the compiled scan carries dopt.robust")
+    sized = sorted(((device_scopes._shape_bytes(r["head"]), r["head"]) for r in rows),
+                   reverse=True)
+    print(f"[chip_smoke] byzantine: {len(rows)} instructions under dopt.robust; "
+          "the largest: " + "; ".join(f"{h[:64]} {b} B" for b, h in sized[:6]),
+          flush=True)
+
+
 # The benchmark's GLM limits (benchmark/configs/glm81_ring262k.json): worst
 # relative gap of the objective and of the consensus error over the rows.
 CARRY_LIMITS = {"objective": 1e-6, "consensus_error": 1e-5}
@@ -916,6 +990,7 @@ def main() -> int:
     gather_round_segment(device)
     tracker_segment(device)
     shard_visit_segment(device)
+    byzantine_segment(device)
     if device["count"] >= 4:
         four_chip_segment(device)
         halo_forms_segment(device)

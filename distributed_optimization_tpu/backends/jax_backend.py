@@ -65,6 +65,8 @@ from distributed_optimization_tpu.serving.cache import (
     sequential_cache_key,
 )
 from distributed_optimization_tpu.parallel.adversary import (
+    attackers_per_honest_neighbourhood,
+    byzantine_set,
     make_adversary,
     make_byzantine_mixing,
 )
@@ -73,7 +75,10 @@ from distributed_optimization_tpu.parallel.faults import (
     make_round_robin_mixing,
 )
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel.topology import cached_topology
+from distributed_optimization_tpu.parallel.topology import (
+    cached_topology,
+    neighbor_tables_for,
+)
 from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     WORKER_AXIS,
@@ -806,6 +811,42 @@ def _gather_root_args(topo, tables) -> dict:
     }
 
 
+def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
+    """What the ``dopt.run`` root says of a call that was attacked,
+    screened or both (none of it on a benign call): ``attack`` (payload,
+    attackers of workers) with ``byzantine_placement`` and ``budget_max``
+    (the most attackers any honest worker counts among its neighbours: the
+    screening rules' guarantee as a counter); ``aggregation`` (rule and
+    budget) with ``robust_impl`` (``gather`` / ``dense`` / ``halo_gather``:
+    the form that ran, the engagement counter), ``screened_rows`` (the
+    rows of numbers a round orders: every closed neighbourhood's) and
+    ``robust_bytes`` (what the round's tables take on the device as
+    arguments of the scan: 0 while they are constants of it)."""
+    args = {}
+    if adversary is not None:
+        args.update(
+            attack=f"{config.attack}:{config.n_byzantine}/{config.n_workers}",
+            byzantine_placement=config.byzantine_placement,
+            budget_max=attackers_per_honest_neighbourhood(
+                np.asarray(adversary.byzantine), *neighbor_tables_for(topo)
+            ),
+            aggregation="gossip",
+        )
+    if config.aggregation != "gossip" and config.robust_b > 0:
+        k_max = int(topo.degrees.max())
+        impl = (
+            "halo_gather" if halo_mesh is not None
+            else config.resolved_robust_impl(k_max)
+        )
+        args.update(
+            aggregation=f"{config.aggregation}:b={config.robust_b}",
+            robust_impl=impl,
+            screened_rows=topo.n * (topo.n if impl == "dense" else k_max + 1),
+            robust_bytes=0.0,
+        )
+    return args
+
+
 def _build_faulty(config, algo, topo, T, *, drop_prob=None, keys=None,
                   timeline=None, horizon=None, halo_mesh=None):
     """Time-varying gossip wiring shared by ``_run`` and ``run_batch``.
@@ -896,6 +937,8 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
             "column-stochastic mass conservation screening breaks) "
             "— use 'dsgd' or 'gradient_tracking'"
         )
+    if byz is None and config.attack != "none":
+        byz = byzantine_set(config, topo)
     adversary = make_adversary(
         config.n_workers, config.attack, config.n_byzantine,
         config.attack_scale, config.seed, byz=byz, noise_key=noise_key,
@@ -949,10 +992,6 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
                 ct, faulty.active if faulty is not None else None,
             )
         elif robust_impl == "gather":
-            from distributed_optimization_tpu.parallel.topology import (
-                neighbor_tables_for,
-            )
-
             # Native tables for matrix-free topologies (the satellite:
             # Byzantine screening accepted on the neighbor path), derived
             # from the dense adjacency otherwise — identical layout.
@@ -1665,9 +1704,18 @@ def _run(
                 # the fault layer mixes, over tables of its own.
                 mixing_tables = replicate(mesh, mix_op.tables)
                 spans.note_root(**_gather_root_args(topo, mixing_tables))
+        if byzantine_active:
+            # ``adversary``: placing the attackers on the graph and binding
+            # the screening rule (docs/OBSERVABILITY.md).
+            spans.enter("adversary")
         adversary, byz_mix, robust_activity = _bind_byzantine(
             config, algo, topo, faulty, mix_op, halo_mesh=halo_mesh,
         )
+        if byzantine_active:
+            spans.note_root(
+                **_byzantine_root_args(config, topo, adversary, halo_mesh)
+            )
+            spans.enter("prepare")
         # == adjacency.sum() for both orientations; degree-based so the
         # matrix-free representation needs no [N, N] array.
         static_degree_sum = float(np.asarray(topo.degrees).sum())
@@ -2436,7 +2484,6 @@ def _run_batch(
     from distributed_optimization_tpu.config import SWEEPABLE_FIELDS
     from distributed_optimization_tpu.parallel.adversary import (
         _BYZ_NOISE_TAG,
-        byzantine_mask,
     )
     from distributed_optimization_tpu.parallel.faults import (
         FaultTimeline,
@@ -2636,7 +2683,7 @@ def _run_batch(
     byz_hosts = None
     if byzantine_active and config.attack != "none":
         byz_hosts = np.stack([
-            byzantine_mask(n, config.n_byzantine, s) for s in seeds
+            byzantine_set(config, topo, seed=s) for s in seeds
         ])
         rp["byz"] = jnp.asarray(byz_hosts)
         rp["noise_key"] = jnp.stack([

@@ -53,7 +53,7 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     validate_budget,
 )
 from distributed_optimization_tpu.parallel import build_topology
-from distributed_optimization_tpu.parallel.adversary import byzantine_mask
+from distributed_optimization_tpu.parallel.adversary import byzantine_set
 from distributed_optimization_tpu.utils.data import HostDataset
 
 _SUPPORTED = (
@@ -673,7 +673,7 @@ def run(
     # the wire — same convention as parallel/adversary.py).
     byz = None
     if byz_active:
-        byz = byzantine_mask(n, config.n_byzantine, config.seed)
+        byz = byzantine_set(config, topo)
         robust_name = (
             config.aggregation
             if config.aggregation != "gossip" and config.robust_b > 0

@@ -31,6 +31,7 @@ from distributed_optimization_tpu.config import (
     AGGREGATIONS,
     ALGORITHMS,
     ATTACKS,
+    BYZANTINE_PLACEMENTS,
     BACKENDS,
     COMPRESSIONS,
     EXECUTIONS,
@@ -246,6 +247,14 @@ def build_parser() -> argparse.ArgumentParser:
                      help="payload magnitude: sign-flip multiplier, "
                           "large-noise sigma, or ALIE's z (honest std "
                           "devs of shift)")
+    opt.add_argument("--byzantine-placement", choices=BYZANTINE_PLACEMENTS,
+                     default=_DEFAULTS.byzantine_placement,
+                     help="where the attackers sit: 'uniform' draws them "
+                          "without looking at the graph; 'within_budget' "
+                          "draws them so every honest worker keeps at most "
+                          "robust-b attacking neighbours (the screening "
+                          "rules' assumption; at scale a uniform draw "
+                          "breaks it: docs/BYZANTINE.md 'Placement')")
     opt.add_argument("--aggregation", choices=AGGREGATIONS,
                      default=_DEFAULTS.aggregation,
                      help="robust neighbor aggregation rule honest workers "
@@ -567,6 +576,7 @@ def config_from_args(args: argparse.Namespace) -> ExperimentConfig:
         attack=args.attack,
         n_byzantine=args.n_byzantine,
         attack_scale=args.attack_scale,
+        byzantine_placement=args.byzantine_placement,
         aggregation=args.aggregation,
         robust_b=args.robust_b,
         clip_tau=args.clip_tau,

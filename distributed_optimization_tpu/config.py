@@ -58,6 +58,13 @@ COMPRESSED_ALGORITHMS = ("choco", "dsgd", "gradient_tracking")
 # honest_mean − scale·honest_std (Baruch et al. 2019), hiding inside the
 # honest spread to evade norm/outlier filters.
 ATTACKS = ("none", "sign_flip", "large_noise", "alie")
+# How the static Byzantine set is placed (parallel/adversary.py,
+# docs/BYZANTINE.md "Placement"): 'uniform' draws it without looking at the
+# graph; 'within_budget' draws it on the graph's neighbor table so that
+# every honest worker keeps at most robust_b attacking neighbours — the
+# screening rules' own assumption, which a uniform draw breaks at f²/N
+# honest workers of a ring in expectation.
+BYZANTINE_PLACEMENTS = ("uniform", "within_budget")
 
 # Rejoin policies after a crash-recovery outage (parallel/faults.py
 # REJOIN_POLICIES mirrors this constant; config stays jax-free).
@@ -273,6 +280,10 @@ class ExperimentConfig:
     attack: str = "none"
     n_byzantine: int = 0
     attack_scale: float = 1.0
+    # Where the attackers sit (BYZANTINE_PLACEMENTS): 'within_budget' needs
+    # an attack, a robust rule and robust_b >= 1 (the budget it places
+    # within) and raises where the graph cannot hold n_byzantine of them.
+    byzantine_placement: str = "uniform"
     # --- federated execution regime (docs/PERF.md §14) ---
     # τ local SGD steps per gossip round (Koloskova et al. '20 local
     # updates): each scan iteration is one ROUND — the algorithm's normal
@@ -552,6 +563,23 @@ class ExperimentConfig:
                 f"robust_b={self.robust_b} only takes effect with a robust "
                 "aggregation rule; plain 'gossip' has no screening step and "
                 "would silently ignore it"
+            )
+        if self.byzantine_placement not in BYZANTINE_PLACEMENTS:
+            raise ValueError(
+                f"Unknown byzantine placement: {self.byzantine_placement}"
+            )
+        if self.byzantine_placement == "within_budget" and not (
+            self.attack != "none"
+            and self.aggregation != "gossip" and self.robust_b > 0
+        ):
+            raise ValueError(
+                "byzantine_placement='within_budget' places the attackers "
+                "so that every honest worker keeps at most robust_b "
+                "attacking neighbours: it needs attackers to place (an "
+                "attack) and a budget to place them within (a robust "
+                "aggregation rule with robust_b >= 1); got attack="
+                f"{self.attack!r}, aggregation={self.aggregation!r}, "
+                f"robust_b={self.robust_b}"
             )
         if self.robust_impl not in ("auto", "dense", "gather"):
             raise ValueError(f"Unknown robust impl: {self.robust_impl}")

@@ -6,7 +6,8 @@ compiled program. Three parts, one mechanism:
 1. **Scopes.** ``scope(name)`` is ``jax.named_scope("dopt.<name>")`` for a
    name of ``SCOPES`` and nothing else: metadata on the operations traced
    under it, no operation of its own. ``jax_backend._make_step_eval``,
-   ``parallel/faults.py`` and ``ops/compression.py`` open them where the
+   ``parallel/faults.py``, ``parallel/adversary.py`` and
+   ``ops/compression.py`` open them where the
    work of a phase is built. Scopes nest; an instruction belongs to its
    INNERMOST ``dopt.*`` component.
 2. **The compiled program's own account.** A scope reaches the compiled
@@ -44,11 +45,11 @@ from typing import Optional
 
 # The whole vocabulary: what a scan iteration is made of, plus the flight
 # recorder's rows. docs/OBSERVABILITY.md ("Device scopes") says what each
-# covers; the benchmark reads ``scan.<scope>_us_per_iter`` for the first
-# seven.
+# covers; the benchmark reads ``scan.<scope>_us_per_iter`` for all but
+# the recorder's.
 SCOPES = (
-    "sampling", "gradient", "gossip", "compress", "faults", "update", "eval",
-    "recorder",
+    "sampling", "gradient", "gossip", "compress", "faults", "robust",
+    "update", "eval", "recorder",
 )
 SCOPE_PREFIX = "dopt."
 

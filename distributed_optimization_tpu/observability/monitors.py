@@ -683,12 +683,10 @@ def fault_context(config, onset: int, *, window: Optional[int] = None,
 
     if config.attack != "none":
         from distributed_optimization_tpu.parallel.adversary import (
-            byzantine_mask,
+            byzantine_set,
         )
 
-        mask = byzantine_mask(
-            config.n_workers, config.n_byzantine, config.seed
-        )
+        mask = byzantine_set(config)
         block = {
             "attack": config.attack,
             "attack_scale": float(config.attack_scale),

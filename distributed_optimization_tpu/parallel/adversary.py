@@ -28,10 +28,17 @@ without per-call counters. All adversarial math runs in at-least-float32
 (the faults convention); only the corrupted stack is cast back to the run
 dtype.
 
-The Byzantine SET is sampled host-side from the config seed
-(``byzantine_mask``) and shared verbatim by the jax backend, the numpy
-oracle backend, and the honest-only metrics — all three must agree on who
-is lying.
+The Byzantine SET is sampled host-side from the config seed and shared
+verbatim by the jax backend, the numpy oracle backend, the incident
+forensics and the honest-only metrics — all must agree on who is lying, so
+all ask ONE resolver, ``byzantine_set(config, topo)``. Two placements
+(``config.byzantine_placement``, docs/BYZANTINE.md "Placement"):
+``uniform`` (``byzantine_mask``: a draw that does not know the graph) and
+``within_budget`` (``place_within_budget``: a seeded greedy on the graph's
+neighbor table that leaves every honest worker at most ``robust_b``
+attacking neighbours — the screening rules' per-neighbourhood assumption,
+which a uniform draw of f attackers on a ring of N breaks at f²/N honest
+workers in expectation).
 """
 
 from __future__ import annotations
@@ -44,6 +51,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_optimization_tpu.config import ATTACKS
+from distributed_optimization_tpu.observability import device_scopes
+from distributed_optimization_tpu.parallel.topology import (
+    cached_topology,
+    neighbor_tables_for,
+)
 
 # Stream tags folded into the seed key, disjoint from the fault layer's
 # (0x0FA17 edges, 0x57A66 stragglers, 0x3A7C4 matchings).
@@ -68,6 +80,106 @@ def byzantine_mask(n_workers: int, n_byzantine: int, seed: int) -> np.ndarray:
         rng = np.random.default_rng([seed, _BYZ_SET_TAG])
         mask[rng.choice(n_workers, size=n_byzantine, replace=False)] = True
     return mask
+
+
+def place_within_budget(
+    nbr_idx: np.ndarray, nbr_mask: np.ndarray, n_byzantine: int,
+    budget: int, seed: int,
+) -> np.ndarray:
+    """Byzantine set drawn WITHIN the screening budget, as a host [N] bool
+    mask: the workers in a seeded random order (``permutation(N)`` of the
+    stream ``byzantine_mask`` draws from), a candidate admitted iff every
+    CURRENTLY HONEST neighbour of it would still count at most ``budget``
+    attackers among its neighbours; stops at ``n_byzantine``.
+
+    On return every honest worker has at most ``budget`` attacking
+    neighbours: an admission checks exactly the honest workers whose count
+    it raises, and a worker that later turns attacker only drops a
+    constraint. Reads the ``[N, k_max]`` neighbor table (padded slots
+    masked out), never an [N, N] matrix. A function of (seed, graph,
+    n_byzantine, budget) alone. Host work of a Python loop over the
+    candidates tried (about f / (1 − share·k) of them; 30 ms at f = 24,576
+    on a ring of 2^18), the table's rows fetched a block of candidates at a
+    time so the loop never walks what it does not try.
+    """
+    n = nbr_idx.shape[0]
+    if not 0 <= n_byzantine < n:
+        raise ValueError(
+            f"n_byzantine must be in [0, n_workers), got {n_byzantine} "
+            f"of {n}"
+        )
+    if budget < 1:
+        raise ValueError(
+            f"a placement within the budget needs robust_b >= 1, got {budget}"
+        )
+    order = np.random.default_rng([seed, _BYZ_SET_TAG]).permutation(n)
+    padded = not bool(np.all(nbr_mask))
+    byz = bytearray(n)  # 1 = attacker
+    hits = [0] * n      # attackers among a worker's neighbours
+    placed = 0
+    block = max(4096, 2 * n_byzantine)
+    for lo in range(0, n, block):
+        if placed == n_byzantine:
+            break
+        cand = order[lo:lo + block]
+        rows = nbr_idx[cand].tolist()
+        live = nbr_mask[cand].tolist() if padded else None
+        for k, c in enumerate(cand.tolist()):
+            nbrs = rows[k]
+            if padded:
+                nbrs = [j for j, m in zip(nbrs, live[k]) if m]
+            if all(byz[j] or hits[j] < budget for j in nbrs):
+                byz[c] = 1
+                for j in nbrs:
+                    hits[j] += 1
+                placed += 1
+                if placed == n_byzantine:
+                    break
+    if placed < n_byzantine:
+        raise ValueError(
+            f"byzantine_placement='within_budget' placed {placed} of the "
+            f"{n_byzantine} attackers asked for: on this graph ({n} "
+            f"workers, max degree {nbr_idx.shape[1]}) with robust_b="
+            f"{budget} and seed {seed} the order ran out — every worker "
+            f"left would give an honest neighbour more than {budget} "
+            f"attacking neighbours. The graph's limit under this order is "
+            f"{placed}: lower n_byzantine or raise robust_b"
+        )
+    return np.frombuffer(byz, dtype=bool).copy()
+
+
+def attackers_per_honest_neighbourhood(
+    byz: np.ndarray, nbr_idx: np.ndarray, nbr_mask: np.ndarray
+) -> int:
+    """The most attackers any HONEST worker counts among its neighbours:
+    the screening rules' guarantee as a number (``budget_max`` on the
+    ``dopt.run`` root; at most ``robust_b`` under ``within_budget``)."""
+    counts = np.sum(byz[nbr_idx] & nbr_mask, axis=1)
+    honest = ~byz
+    return int(counts[honest].max()) if honest.any() else 0
+
+
+def byzantine_set(config, topo=None, *, seed: Optional[int] = None) -> np.ndarray:
+    """WHO lies in a run of ``config``: the one resolver every layer asks
+    (``make_adversary``'s callers in the jax backend, ``run_batch``'s
+    per-seed sets, the numpy oracle, ``monitors.fault_context``).
+    ``seed`` overrides the config's (a replica's); ``topo`` is the run's
+    graph where the caller holds it — ``within_budget`` reads its neighbor
+    table and builds it from the config otherwise."""
+    seed = config.seed if seed is None else seed
+    if config.byzantine_placement == "uniform":
+        return byzantine_mask(config.n_workers, config.n_byzantine, seed)
+    if topo is None:
+        topo, _ = cached_topology(
+            config.topology, config.n_workers,
+            erdos_renyi_p=config.erdos_renyi_p,
+            seed=config.resolved_topology_seed(),
+            impl=config.resolved_topology_impl(),
+            sampler=config.resolved_topology_sampler(),
+        )
+    return place_within_budget(
+        *neighbor_tables_for(topo), config.n_byzantine, config.robust_b, seed
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,15 +285,21 @@ def make_byzantine_mixing(
     corrupted echo instead makes their state diverge exponentially under
     self-centered rules, overflowing to inf and poisoning the honest rows
     through NaN payloads — a simulation artifact, not an attack.
+
+    The corruption and the screening rule are the device scope ``robust``
+    (``dopt.robust``, nested in the caller's ``dopt.gossip``: the innermost
+    scope bills); the benign mix — the attackers' rows, and the vulnerable
+    baseline's — stays ``gossip``.
     """
     corrupt = (
         adversary.corrupt if adversary is not None else (lambda t, x: x)
     )
 
     def honest_view(t, x):
-        xa = corrupt(t, x)
-        if aggregate_t is not None:
-            return aggregate_t(t, xa)
+        with device_scopes.scope("robust"):
+            xa = corrupt(t, x)
+            if aggregate_t is not None:
+                return aggregate_t(t, xa)
         return base_mix(t, xa)
 
     if adversary is None:

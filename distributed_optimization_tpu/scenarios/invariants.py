@@ -182,7 +182,10 @@ def _check_gt_tracking(ctx: CellContext) -> InvariantResult:
 
 def _check_robust_envelope(ctx: CellContext) -> InvariantResult:
     cfg = ctx.config
-    twin = cfg.replace(attack="none", n_byzantine=0, attack_scale=1.0)
+    twin = cfg.replace(
+        attack="none", n_byzantine=0, attack_scale=1.0,
+        byzantine_placement="uniform",
+    )
     clean = ctx.run_served(twin)
     envelope = ctx.envelope("robust_envelope", 5.0)
     gap, gap_clean = _gap(ctx.result), _gap(clean)
@@ -277,6 +280,7 @@ def _check_reduction_churn(ctx: CellContext) -> InvariantResult:
 def _check_reduction_zero_budget(ctx: CellContext) -> InvariantResult:
     base = ctx.config.replace(
         robust_b=0, clip_tau=0.0, robust_impl="auto",
+        byzantine_placement="uniform",
     )
     robust_off = ctx.run_direct(base)
     gossip = ctx.run_direct(base.replace(aggregation="gossip"))

@@ -38,6 +38,7 @@ from distributed_optimization_tpu.config import (
     ALGORITHMS,
     ATTACKS,
     BACKENDS,
+    BYZANTINE_PLACEMENTS,
     COMPRESSED_ALGORITHMS,
     COMPRESSIONS,
     DIRECTED_TOPOLOGIES,
@@ -229,6 +230,15 @@ RULES: tuple[Rule, ...] = (
        lambda f: (
            f"robust_b={f['robust_b']} only takes effect with a robust "
            "aggregation rule"
+       )),
+    _domain("byzantine_placement", "byzantine", BYZANTINE_PLACEMENTS),
+    _r("byzantine:placement_without_budget", ("byzantine",),
+       lambda f: f["byzantine_placement"] == "within_budget"
+       and not (f["attack"] != "none" and _robust_rule_on(f)),
+       lambda f: (
+           "byzantine_placement='within_budget' needs attackers to place "
+           "(an attack) and a budget to place them within (a robust "
+           "aggregation rule with robust_b >= 1)"
        )),
     _domain("robust_impl", "byzantine", ("auto", "dense", "gather")),
     _r("byzantine:impl_without_rule", ("byzantine",),
