@@ -108,11 +108,12 @@ without a TPU it exits before doing any work. Segments:
    plain reference (``benchmark/reference/dsgd_ring_byzantine.py``) by the
    cell's own limits. The root says ``forward`` = ``fused`` (the shard visit
    with the HONEST mean as the eval's x-bar), ``robust_impl`` = ``gather``,
-   ``budget_max`` = 1; the device's peak over the bytes in use at the
-   segment's start; and the compiled scan's instructions under
-   ``dopt.robust``, the largest with their bytes. What the CPU cannot see:
-   the visit carrying an adversary, the sort's layout and what the unrolled
-   trip keeps alive of it.
+   ``screen_order`` = ``network:3`` (ISSUE 44: three slot planes ordered by
+   compare-and-select, no sort in the compiled scan), ``budget_max`` = 1;
+   the device's peak over the bytes in use at the segment's start; and the
+   compiled scan's instructions under ``dopt.robust``, the largest with
+   their bytes. What the CPU cannot see: the visit carrying an adversary,
+   the gathered planes' layout and what the unrolled trip keeps alive.
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -575,12 +576,13 @@ def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
     result, root, peak = _rooted_run(
         f"sign-flip on a ring of {n_workers}, trimmed mean", device, cfg, ds,
         ("attack", "byzantine_placement", "budget_max", "aggregation",
-         "robust_impl", "screened_rows", "robust_bytes", "forward", "mixing",
-         "temp_bytes"))
-    _check((root["forward"], root["robust_impl"], root["budget_max"])
-           == ("fused", "gather", 1),
-           "the root says the shard visit, the gather form, one attacker at "
-           "most beside an honest worker")
+         "robust_impl", "screen_order", "screened_rows", "robust_bytes",
+         "forward", "mixing", "temp_bytes"))
+    _check((root["forward"], root["robust_impl"], root["screen_order"],
+            root["budget_max"]) == ("fused", "gather", "network:3", 1),
+           "the root says the shard visit, the gather form, three slots "
+           "ordered by the network, one attacker at most beside an honest "
+           "worker")
     _check(root["attack"] == f"sign_flip:{exp['n_byzantine']}/{n_workers}"
            and root["byzantine_placement"] == "within_budget"
            and root["aggregation"] == "trimmed_mean:b=1"
@@ -599,8 +601,10 @@ def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
           f"{start} B in use at the segment's start (shards "
           f"{X.nbytes} B, the scan's temporaries {int(root['temp_bytes'])} B)",
           flush=True)
-    rows = [r for r in device_scopes.table_for(root["program"])["rows"]
-            if r["scope"] == "robust"]
+    table = device_scopes.table_for(root["program"])["rows"]
+    _check(not [r for r in table if r["head"].startswith("%sort")],
+           "the compiled scan holds no sort")
+    rows = [r for r in table if r["scope"] == "robust"]
     _check(bool(rows), "the compiled scan carries dopt.robust")
     sized = sorted(((device_scopes._shape_bytes(r["head"]), r["head"]) for r in rows),
                    reverse=True)

@@ -56,6 +56,7 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     make_gather_robust_aggregator,
     make_robust_activity,
     make_robust_aggregator,
+    screen_order,
     validate_budget,
 )
 from distributed_optimization_tpu.telemetry import cost_from_lowered
@@ -818,8 +819,10 @@ def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
     (the most attackers any honest worker counts among its neighbours: the
     screening rules' guarantee as a counter); ``aggregation`` (rule and
     budget) with ``robust_impl`` (``gather`` / ``dense`` / ``halo_gather``:
-    the form that ran, the engagement counter), ``screened_rows`` (the
-    rows of numbers a round orders: every closed neighbourhood's) and
+    the form that ran, the engagement counter), ``screen_order`` (how a
+    closed neighbourhood was put in order and over how many slots:
+    ``network:3`` / ``sort:31``, read off the table's width), ``screened_rows``
+    (the rows of numbers a round orders: every closed neighbourhood's) and
     ``robust_bytes`` (what the round's tables take on the device as
     arguments of the scan: 0 while they are constants of it)."""
     args = {}
@@ -841,6 +844,9 @@ def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
         args.update(
             aggregation=f"{config.aggregation}:b={config.robust_b}",
             robust_impl=impl,
+            screen_order=screen_order(
+                config.aggregation, impl, topo.n, k_max
+            ),
             screened_rows=topo.n * (topo.n if impl == "dense" else k_max + 1),
             robust_bytes=0.0,
         )

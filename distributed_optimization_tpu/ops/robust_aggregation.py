@@ -46,18 +46,37 @@ Two jax implementations of every rule (``robust_impl`` knob):
   topology is;
 - **gather** (``make_gather_robust_aggregator``): precomputes a static
   padded neighbor-index table [N, k_max] from the topology
-  (``parallel/topology.py::neighbor_table``), gathers neighbor models to
-  [N, k_max, d] and per-incident-edge liveness bits to [N, k_max], and
-  sorts/trims/medians/clips over the k_max axis — O(N·k_max·d·log k_max)
-  work and O(N·k_max·d) memory, an ~N/k_max-fold reduction on
-  degree-bounded graphs (measured 69-75× e2e for trimmed mean/median on
-  an N=256 ring, docs/perf/robust_scale.json).
+  (``parallel/topology.py::neighbor_table``) and reads neighbor models and
+  per-incident-edge liveness bits [N, k_max] through it — O(N·k_max·d)
+  memory, an ~N/k_max-fold reduction on degree-bounded graphs (measured
+  69-75× e2e for trimmed mean/median on an N=256 ring,
+  docs/perf/robust_scale.json). Clipping reduces over the k_max axis of
+  the gathered [N, k_max, d] differences. The two count rules order the
+  closed neighborhood (``closed_neighbourhood_rule``, the ONE definition
+  the unsharded and the halo form both call), and HOW is read off the
+  table's static width, never off an option:
 
-The two are algebraically identical: the gather sort sees the same finite
-values (+inf padding beyond the realized neighborhood, same convention),
+  - up to ``NETWORK_MAX_SLOTS`` = 17 slots (k_max + 1: a ring's 3, a
+    torus's 5, an 8-regular graph's 9) the neighborhood is k_max + 1
+    PLANES of [N, d] — the worker's own rows and, from ONE gather through
+    the table read slot-major, each slot's rows, +inf where the slot is
+    dead — put in order by a compare-exchange network (a compare and two
+    selects a comparator, adjacent planes only, so it is stable), and the
+    rules read planes: no [N, k_max + 1, d] stack, no sort. Each plane
+    holds bit for bit what ``jnp.sort`` puts in that position: +inf after
+    every finite value, a NaN last, tied values (±0 among them) in slot
+    order;
+  - above it (a drawn graph's k_max of 30) the stack, ``jnp.sort`` along
+    the slot axis and a masked sum, as ever.
+
+The forms are algebraically identical: each orders the same finite values
+(+inf padding beyond the realized neighborhood, same convention),
 neighbor slots are ordered ascending by index (the order a dense axis-1
 reduction visits them), and f64 parity ≤ 1e-12 across dense / gather /
-the numpy oracle is asserted in tests/test_robust_gather.py.
+the numpy oracle is asserted in tests/test_robust_gather.py. Where the
+kept planes are several (a torus at b = 1 keeps three) the network path
+adds them in rising position, the sort path by ``jnp.sum``: the last bits
+of a float32 sum may differ between the two, never the values summed.
 """
 
 from __future__ import annotations
@@ -127,6 +146,150 @@ def _adaptive_clip_tau(mask, norms, budget: int, k_cap: int):
     k = jnp.clip(deg - budget - 1, 0, k_cap - 1)
     kth = jnp.take_along_axis(ranked, k[:, None], axis=1)[:, 0]
     return jnp.where(deg - budget >= 1, kth, 0.0)
+
+
+# The widest closed neighborhood (k_max + 1 slots) the compare-exchange
+# network orders; a wider table takes ``jnp.sort``. Derived, no option:
+# the round alone on one v5e at N = 2^18, d = 81 (PERF.md §6, PR 44), ms a
+# round, network | sort: 3 slots 6.6 | 66.1, 5: 12.1 | 22.9, 7: 17.6 | 89.9,
+# 9: 23.5 | 50.5, 17: 57.2 | 107.2. The network won at every width read, so
+# this is the widest READ, not a crossover: at a drawn graph's 31 slots the
+# sort's side does not fit the chip beside the network's to be compared.
+NETWORK_MAX_SLOTS = 17
+
+
+def screen_order(name: str, impl: str, n: int, k_max: int) -> str:
+    """How a screened call orders a closed neighborhood, and over how many
+    slots, as its ``dopt.run`` root says it: ``network:3`` / ``sort:31``
+    for the count rules over a neighbor table (``gather``, ``halo_gather``),
+    ``sort:<N>`` for their dense form; clipping orders no models: ``none``."""
+    if name == "clipped_gossip":
+        return "none"
+    if impl == "dense":
+        return f"sort:{n}"
+    slots = k_max + 1
+    return f"{'network' if slots <= NETWORK_MAX_SLOTS else 'sort'}:{slots}"
+
+
+def _compare_exchange(a, b):
+    """(lower, upper) elementwise by a compare and two selects, so each
+    result is one of the two VALUES to the bit. A NaN counts as above
+    everything, +inf included, and a tie (±0, two NaNs) keeps its order:
+    ``jnp.sort``'s own order. ``minimum`` / ``maximum`` would spread a NaN
+    into both results, and so let one payload through the trimming."""
+    swap = (a > b) | (jnp.isnan(a) & ~jnp.isnan(b))
+    return jnp.where(swap, b, a), jnp.where(swap, a, b)
+
+
+def _ordered_planes(planes):
+    """The planes ascending per row and coordinate, by an odd-even
+    transposition network: len(planes) rounds of comparators between
+    ADJACENT planes (three comparators for three planes). Adjacent
+    comparators that leave a tie alone make the network stable, so plane j
+    is bit for bit position j of the stable ``jnp.sort`` over the slot axis
+    whatever the planes hold; what no rule reads of the last rounds the
+    compiler drops."""
+    planes = list(planes)
+    for rnd in range(len(planes)):
+        for i in range(rnd % 2, len(planes) - 1, 2):
+            planes[i], planes[i + 1] = _compare_exchange(
+                planes[i], planes[i + 1]
+            )
+    return planes
+
+
+def _received_planes(source, nbr, live):
+    """What each slot delivered, a plane [N, d] a slot, +inf where the slot
+    is dead: ONE row gather through the table read slot-major, so that
+    slot s is the rows [s·N, (s + 1)·N) of what it returns: whole tiles
+    whatever k_max, where [N, k_max, d] keeps the slots on the sublanes."""
+    n, k_max = nbr.shape
+    rows = source[nbr.T.reshape(-1)]
+    return [
+        jnp.where(live[:, s, None] > 0, rows[s * n:(s + 1) * n], jnp.inf)
+        for s in range(k_max)
+    ]
+
+
+def _pick_plane(planes, idx):
+    """``planes[idx[i]]`` row by row, as a chain of selects."""
+    out = planes[0]
+    for j in range(1, len(planes)):
+        out = jnp.where((idx == j)[:, None], planes[j], out)
+    return out
+
+
+def closed_neighbourhood_rule(name: str, budget: int):
+    """The two count rules over a neighbor table: ``rule(own, source, nbr,
+    live) -> aggregate``, all in the accumulation dtype.
+
+    ``own``: the workers' own rows [N, d]; ``source``: the rows the table
+    addresses (``own`` itself unsharded, the halo-extended block under a
+    worker mesh); ``nbr``: the [N, k_max] table into ``source`` (a host
+    array or a traced one); ``live``: this round's 0/1 bits [N, k_max].
+    ONE definition for ``make_gather_robust_aggregator`` and the halo form
+    (``parallel/collectives.py``), which are held BITWISE equal.
+
+    The table's static width decides how the closed neighborhood is put in
+    order (module docstring): planes and a compare-exchange network up to
+    ``NETWORK_MAX_SLOTS`` slots, the [N, k_max + 1, d] stack and
+    ``jnp.sort`` above. Position j holds the same value either way; valid
+    values occupy positions [0, c_i), the +inf padding is never selected.
+    """
+    if name not in ("trimmed_mean", "median"):
+        raise ValueError(f"{name!r} is no count rule")
+
+    def rule(own, source, nbr, live):
+        k_max = nbr.shape[1]
+        counts = jnp.sum(live, axis=1) + 1.0
+        as_planes = k_max + 1 <= NETWORK_MAX_SLOTS
+        if as_planes:
+            ordered = _ordered_planes(
+                [own] + _received_planes(source, nbr, live)
+            )
+        else:
+            vals = jnp.where(live[:, :, None] > 0, source[nbr], jnp.inf)
+            closed = jnp.concatenate([own[:, None, :], vals], axis=1)
+            ordered = jnp.sort(closed, axis=1)
+
+        if name == "trimmed_mean":
+            # Keep the positions [b, c_i − b).
+            kept = jnp.maximum(counts - 2 * budget, 0.0)
+            if as_planes:
+                # Only the planes b … k_max − b can be kept at any count;
+                # added in rising position. Where ONE plane is kept the
+                # total is that plane to the bit.
+                total = None
+                for j in range(budget, k_max + 1 - budget):
+                    term = jnp.where(
+                        (counts - budget > j)[:, None], ordered[j], 0.0
+                    )
+                    total = term if total is None else total + term
+            else:
+                pos = jnp.arange(k_max + 1, dtype=own.dtype)
+                keep = (pos[None, :] >= budget) & (
+                    pos[None, :] < (counts - budget)[:, None]
+                )
+                total = jnp.sum(
+                    jnp.where(keep[:, :, None], ordered, 0.0), axis=1
+                )
+            mean = total / jnp.maximum(kept, 1.0)[:, None]
+            # Faulted-down neighborhoods (c_i ≤ 2b): identity row.
+            return jnp.where((kept >= 1.0)[:, None], mean, own)
+
+        c = counts.astype(jnp.int32)
+        lo = jnp.maximum((c - 1) // 2, 0)
+        hi = jnp.maximum(c // 2, 0)
+        if as_planes:
+            # hi ≤ (k_max + 1) // 2: the planes above are never the middle.
+            middle = ordered[: (k_max + 1) // 2 + 1]
+            return 0.5 * (_pick_plane(middle, lo) + _pick_plane(middle, hi))
+        return 0.5 * (
+            jnp.take_along_axis(ordered, lo[:, None, None], axis=1)
+            + jnp.take_along_axis(ordered, hi[:, None, None], axis=1)
+        )[:, 0, :]
+
+    return rule
 
 
 def make_robust_aggregator(
@@ -246,11 +409,14 @@ def make_gather_robust_aggregator(
     degree is recoverable by gathering row sums. ``x``: the [N, d] stack
     AS TRANSMITTED, like the dense form.
 
-    Each rule mirrors its dense twin term for term over the k_max axis —
+    Each rule mirrors its dense twin term for term over the k_max slots —
     same +inf padding, same accumulation dtype floor, same identity-row
     degradation for faulted-down neighborhoods (realized closed
-    neighborhood ≤ 2b, or deg ≤ b for adaptive clipping) — but the sort,
-    rank selection, and neighbor reduction are O(k_max), not O(N).
+    neighborhood ≤ 2b, or deg ≤ b for adaptive clipping) — but the
+    ordering, rank selection, and neighbor reduction are O(k_max), not
+    O(N). The two count rules are ``closed_neighbourhood_rule``, which
+    reads off the table's width whether the slots are planes put in order
+    by a compare-exchange network or a stack given to ``jnp.sort``.
     """
     if name not in AGGREGATIONS or name == "gossip":
         raise ValueError(
@@ -261,54 +427,25 @@ def make_gather_robust_aggregator(
         raise ValueError(
             f"{name} needs a positive attack budget, got {budget}"
         )
-    nbr = jnp.asarray(nbr_idx, dtype=jnp.int32)  # [N, k_max]
-    k_max = nbr.shape[1]
+    k_max = nbr_idx.shape[1]
 
-    def _closed_sorted(live, x):
-        """Ascending per-coordinate sort of the realized closed
-        neighborhood over the slot axis: [N, k_max+1, d] (self in slot 0
-        pre-sort; +inf beyond each row's realized count) + counts [N]."""
-        vals = jnp.where(live[:, :, None] > 0, x[nbr], jnp.inf)
-        closed = jnp.concatenate([x[:, None, :], vals], axis=1)
-        return jnp.sort(closed, axis=1), jnp.sum(live, axis=1) + 1.0
-
-    if name == "trimmed_mean":
+    if name in ("trimmed_mean", "median"):
+        # The table stays on the host: the program's constant is the flat
+        # s32[k_max·N] the gather reads ([N, k_max] s32 on the device lies
+        # in tiles of 128 lanes).
+        table = np.asarray(nbr_idx, dtype=np.int32)
+        rule = closed_neighbourhood_rule(name, budget)
 
         def aggregate(live, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)
             xa = x.astype(acc)
-            s, counts = _closed_sorted(live.astype(acc), xa)
-            pos = jnp.arange(k_max + 1, dtype=acc)
-            keep = (pos[None, :] >= budget) & (
-                pos[None, :] < (counts - budget)[:, None]
-            )
-            kept = jnp.maximum(counts - 2 * budget, 0.0)
-            total = jnp.sum(jnp.where(keep[:, :, None], s, 0.0), axis=1)
-            mean = total / jnp.maximum(kept, 1.0)[:, None]
-            # Faulted-down neighborhoods (c_i ≤ 2b): identity row.
-            return jnp.where(
-                (kept >= 1.0)[:, None], mean, xa
-            ).astype(x.dtype)
-
-    elif name == "median":
-
-        def aggregate(live, x):
-            acc = jnp.promote_types(jnp.float32, x.dtype)
-            xa = x.astype(acc)
-            s, counts = _closed_sorted(live.astype(acc), xa)
-            c = counts.astype(jnp.int32)
-            lo = jnp.maximum((c - 1) // 2, 0)[:, None, None]
-            hi = jnp.maximum(c // 2, 0)[:, None, None]
-            med = 0.5 * (
-                jnp.take_along_axis(s, lo, axis=1)
-                + jnp.take_along_axis(s, hi, axis=1)
-            )
-            return med[:, 0, :].astype(x.dtype)
+            return rule(xa, xa, table, live.astype(acc)).astype(x.dtype)
 
     else:  # clipped_gossip
         # Same host decision as the dense twin: traced clip_tau (a swept
         # replica axis) is the fixed form; concrete 0.0 is adaptive.
         adaptive_tau = isinstance(clip_tau, (int, float)) and clip_tau <= 0.0
+        nbr = jnp.asarray(nbr_idx, dtype=jnp.int32)  # [N, k_max]
 
         def aggregate(live, x):
             acc = jnp.promote_types(jnp.float32, x.dtype)

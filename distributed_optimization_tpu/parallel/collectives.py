@@ -583,10 +583,11 @@ def make_halo_robust_aggregator_t(
     (coordinate-wise trimmed mean / median, self-centered clipping) run
     shard-locally on the halo-extended buffer: corrupted boundary rows
     arrive over ppermute exactly like benign gossip traffic, each shard
-    screens its own [S, k_max+1, d] closed neighborhoods, and the per-row
-    op sequence mirrors the unsharded gather twin term for term (same
-    +inf padding, same accumulation floor, same identity-row
-    degeneration) — sharded-vs-unsharded screening is BITWISE identical.
+    screens its own closed neighborhoods of k_max + 1 slots, and the two
+    count rules ARE the unsharded gather form's
+    (``closed_neighbourhood_rule``, called on the block and its halo;
+    clipping mirrors its twin term for term) — sharded-vs-unsharded
+    screening is BITWISE identical.
     ``active_fn(t) -> [N] float32`` composes node-process faults
     (stragglers/churn/participation) into the realized liveness through a
     1-float-per-row halo exchange; None = the static graph. The caller
@@ -594,6 +595,10 @@ def make_halo_robust_aggregator_t(
     BEFORE this aggregate, like every other robust binding.
     """
     from distributed_optimization_tpu.config import AGGREGATIONS
+    from distributed_optimization_tpu.ops.robust_aggregation import (
+        _adaptive_clip_tau,
+        closed_neighbourhood_rule,
+    )
 
     if name not in AGGREGATIONS or name == "gossip":
         raise ValueError(
@@ -612,61 +617,21 @@ def make_halo_robust_aggregator_t(
         m_ext = exchange(mb[:, None])[:, 0]
         return mask_f32 * mb[:, None] * m_ext[nbr_l]  # [S, k_max] f32
 
-    def _closed_sorted(exchange, nbr_l, mask_f32, xb, mb):
-        """Shard-local twin of the gather rules' closed-neighborhood sort
-        (ops/robust_aggregation.py): same +inf padding on dead slots,
-        same self-row prepend, same sort axis — the exact terms the
-        BITWISE sharded-vs-unsharded parity contract depends on, kept in
-        one place for both count rules below."""
-        acc = jnp.promote_types(jnp.float32, xb.dtype)
-        xa = xb.astype(acc)
-        lv = _live(exchange, nbr_l, mask_f32, mb).astype(acc)
-        ext = exchange(xa)
-        vals = jnp.where(lv[:, :, None] > 0, ext[nbr_l], jnp.inf)
-        closed = jnp.concatenate([xa[:, None, :], vals], axis=1)
-        s = jnp.sort(closed, axis=1)
-        counts = jnp.sum(lv, axis=1) + 1.0
-        return acc, xa, s, counts
-
-    if name == "trimmed_mean":
+    if name in ("trimmed_mean", "median"):
+        # The unsharded gather form's own definition, on the block and its
+        # halo: the terms the BITWISE sharded-vs-unsharded contract rests
+        # on live in one place.
+        rule = closed_neighbourhood_rule(name, budget)
 
         def body(exchange, nbr_l, mask_f32, xb, mb):
-            acc, xa, s, counts = _closed_sorted(
-                exchange, nbr_l, mask_f32, xb, mb
-            )
-            pos = jnp.arange(k_max + 1, dtype=acc)
-            keep = (pos[None, :] >= budget) & (
-                pos[None, :] < (counts - budget)[:, None]
-            )
-            kept = jnp.maximum(counts - 2 * budget, 0.0)
-            total = jnp.sum(jnp.where(keep[:, :, None], s, 0.0), axis=1)
-            mean = total / jnp.maximum(kept, 1.0)[:, None]
-            return jnp.where(
-                (kept >= 1.0)[:, None], mean, xa
-            ).astype(xb.dtype)
-
-    elif name == "median":
-
-        def body(exchange, nbr_l, mask_f32, xb, mb):
-            _, _, s, counts = _closed_sorted(
-                exchange, nbr_l, mask_f32, xb, mb
-            )
-            c = counts.astype(jnp.int32)
-            lo = jnp.maximum((c - 1) // 2, 0)[:, None, None]
-            hi = jnp.maximum(c // 2, 0)[:, None, None]
-            med = 0.5 * (
-                jnp.take_along_axis(s, lo, axis=1)
-                + jnp.take_along_axis(s, hi, axis=1)
-            )
-            return med[:, 0, :].astype(xb.dtype)
+            acc = jnp.promote_types(jnp.float32, xb.dtype)
+            xa = xb.astype(acc)
+            lv = _live(exchange, nbr_l, mask_f32, mb).astype(acc)
+            return rule(xa, exchange(xa), nbr_l, lv).astype(xb.dtype)
 
     else:  # clipped_gossip
 
         def body(exchange, nbr_l, mask_f32, xb, mb):
-            from distributed_optimization_tpu.ops.robust_aggregation import (
-                _adaptive_clip_tau,
-            )
-
             acc = jnp.promote_types(jnp.float32, xb.dtype)
             xa = xb.astype(acc)
             lv = _live(exchange, nbr_l, mask_f32, mb).astype(acc)

@@ -141,6 +141,7 @@ def test_the_root_says_who_lied_and_how_it_was_screened(cell):
     assert args["attack"] == "sign_flip:6/64"
     assert args["byzantine_placement"] == "within_budget" and args["budget_max"] == 1
     assert args["aggregation"] == "trimmed_mean:b=1" and args["robust_impl"] == "gather"
+    assert args["screen_order"] == "network:3"  # three slots: no sort (PR 44)
     assert args["screened_rows"] == 64 * 3 and args["robust_bytes"] == 0.0
     assert args["mixing"] == "stencil"  # the attackers' benign rows
     assert children.count("dopt.run.adversary") == 1
@@ -148,7 +149,7 @@ def test_the_root_says_who_lied_and_how_it_was_screened(cell):
     # a benign call says none of it and opens no such span
     _, args, children, _ = run_program(benign(config), traffic, SEEDS[0])
     assert not {"attack", "byzantine_placement", "budget_max", "aggregation", "robust_impl",
-                "screened_rows", "robust_bytes"} & set(args)
+                "screen_order", "screened_rows", "robust_bytes"} & set(args)
     assert "dopt.run.adversary" not in children
 
 
@@ -161,8 +162,11 @@ def test_the_compiled_scan_bills_the_round_to_its_own_scope(cell):
     config, traffic = cell
     _, args, _, _ = run_program(config, traffic, SEEDS[0])
     rows = device_scopes.table_for(args["program"])["rows"]
-    robust = [r for r in rows if r["scope"] == "robust"]
-    assert any(r["head"].startswith("%sort") for r in robust), [r["head"][:60] for r in robust]
+    # three slots are ordered by the compare-exchange network (PR 44): no
+    # sort anywhere, and the rule's selects ride in a fusion that says so
+    assert not [r for r in rows if r["head"].startswith("%sort")]
+    robust = [r for r in rows if r["scope"] == "robust" or "robust" in r["also"]]
+    assert any("fusion" in r["head"].split(" = ")[0] for r in robust), [r["head"][:60] for r in robust]
     assert any(r["scope"] == "gossip" for r in rows)
     _, args, _, _ = run_program(benign(config), traffic, SEEDS[0])
     rows = device_scopes.table_for(args["program"])["rows"]

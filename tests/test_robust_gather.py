@@ -245,6 +245,165 @@ def test_faulted_down_neighborhood_degrades_to_identity_row(rule):
     np.testing.assert_allclose(g_out, o_out, rtol=0, atol=1e-12)
 
 
+# ------------------------ slot planes and the compare-exchange network (PR 44)
+
+from distributed_optimization_tpu.ops import robust_aggregation as ra  # noqa: E402
+
+THRESHOLD = ra.NETWORK_MAX_SLOTS
+FLOATS = {"f32": jnp.float32, "f64": jnp.float64}
+
+
+def _hostile_instance(slots, dtype, n=48, d=7, seed=5):
+    """A table of ``slots - 1`` neighbours a worker (a circulant graph's, an
+    odd width's last column the antipode), 30% of the slots dead, three rows
+    with every slot dead, and a stack that holds what an attack and a fault
+    put there: payloads at -5·x, ONE row of NaN, zeros of both signs, ties."""
+    k = slots - 1
+    rng = np.random.default_rng([seed, slots])
+    i = np.arange(n)[:, None]
+    cols = [(i + o) % n for o in range(1, k // 2 + 1)]
+    cols += [(i - o) % n for o in range(1, k // 2 + 1)]
+    if k % 2:
+        cols.append((i + n // 2) % n)
+    nbr = np.sort(np.concatenate(cols, axis=1), axis=1).astype(np.int32)
+    live = (rng.random((n, k)) >= 0.3).astype(np.float64)
+    live[[2, 11, 30]] = 0.0                       # count 1: identity rows
+    live[7] = 1.0                                 # the NaN row screens in full
+    x = rng.standard_normal((n, d))
+    x[[4, 19, 33]] *= -5.0                        # sign-flip payloads
+    x[7] = np.nan                                 # one NaN payload
+    x[:, 0] = np.where(rng.random(n) < 0.5, 0.0, -0.0)  # tied zeros, both signs
+    x[:, 1] = np.round(x[:, 1])                   # ties among small integers
+    return nbr, jnp.asarray(live, dtype), jnp.asarray(x, dtype)
+
+
+def _sorted_reference(rule, budget, nbr, live, x):
+    """The count rules as they were written before PR 44: the closed
+    neighbourhood stacked [N, k_max + 1, d], ``jnp.sort`` along the slot
+    axis, a masked ``jnp.sum`` / ``take_along_axis``."""
+    vals = jnp.where(live[:, :, None] > 0, x[nbr], jnp.inf)
+    s = jnp.sort(jnp.concatenate([x[:, None, :], vals], axis=1), axis=1)
+    counts = jnp.sum(live, axis=1) + 1.0
+    if rule == "median":
+        c = counts.astype(jnp.int32)
+        lo = jnp.maximum((c - 1) // 2, 0)[:, None, None]
+        hi = jnp.maximum(c // 2, 0)[:, None, None]
+        return 0.5 * (jnp.take_along_axis(s, lo, axis=1)
+                      + jnp.take_along_axis(s, hi, axis=1))[:, 0, :], counts
+    pos = jnp.arange(nbr.shape[1] + 1, dtype=x.dtype)
+    keep = (pos[None, :] >= budget) & (pos[None, :] < (counts - budget)[:, None])
+    kept = jnp.maximum(counts - 2 * budget, 0.0)
+    total = jnp.sum(jnp.where(keep[:, :, None], s, 0.0), axis=1)
+    mean = total / jnp.maximum(kept, 1.0)[:, None]
+    return jnp.where((kept >= 1.0)[:, None], mean, x), counts
+
+
+# every (width, rule, budget) the table can support: 2b <= k_max
+HOSTILE_CASES = [
+    (slots, rule, budget)
+    for slots in (3, 5, THRESHOLD, THRESHOLD + 1)
+    for rule, budget in (("trimmed_mean", 1), ("trimmed_mean", 2), ("median", 1))
+    if 2 * budget <= slots - 1
+]
+
+
+@pytest.mark.parametrize("floats", sorted(FLOATS))
+@pytest.mark.parametrize("slots,rule,budget", HOSTILE_CASES)
+def test_the_network_is_the_sort_on_hostile_stacks(slots, rule, budget, floats, monkeypatch):
+    """Planes and a compare-exchange network against ``jnp.sort`` and a
+    masked sum, on dead slots, faulted-down rows, a NaN payload, ±0 ties and
+    payloads at -5·x: the VALUES kept are the sort's, so the aggregate is
+    the sort's wherever one plane is kept (a row of the trimmed mean with
+    c - 2b = 1; the median everywhere: 0.5·(a + b) is one arithmetic) and
+    within the rounding of another order of additions elsewhere: 1e-12 of
+    the stack's scale in float64, 2·slots units in the last place of it in
+    float32. Both orderings are run at every width (the constant patched),
+    and the module's own choice is one of them."""
+    with enable_x64(floats == "f64"):
+        nbr, live, x = _hostile_instance(slots, FLOATS[floats])
+        want, counts = _sorted_reference(rule, budget, nbr, live, x)
+        want, counts = np.asarray(want), np.asarray(counts)
+        got = {}
+        for path, widest in (("network", 10**6), ("sort", 0), ("own", THRESHOLD)):
+            monkeypatch.setattr(ra, "NETWORK_MAX_SLOTS", widest)
+            out = ra.make_gather_robust_aggregator(rule, budget, nbr)(live, x)
+            assert out.dtype == FLOATS[floats]
+            got[path] = np.asarray(out)
+    np.testing.assert_array_equal(got["sort"], want)
+    np.testing.assert_array_equal(
+        got["own"], got["network" if slots <= THRESHOLD else "sort"])
+    one_kept = (
+        np.ones(len(counts), bool) if rule == "median"
+        else counts - 2 * budget <= 1  # one plane, or the identity row
+    )
+    assert one_kept.any()
+    np.testing.assert_array_equal(got["network"][one_kept], want[one_kept])
+    scale = float(np.nanmax(np.abs(np.where(np.isfinite(want), want, np.nan))))
+    atol = (1e-12 if floats == "f64" else 2 * slots * float(np.finfo(np.float32).eps)) * scale
+    np.testing.assert_allclose(got["network"], want, rtol=0, atol=atol)
+    # the NaN payload is trimmed exactly as the sort trims it: under the
+    # trimmed mean its row's neighbours hold numbers (under the median a
+    # neighbour with a dead slot beside it reads +inf, as the sort's does);
+    # an identity row keeps its own
+    if rule == "trimmed_mean":
+        assert np.isfinite(got["network"][[6, 8]]).all()
+    np.testing.assert_array_equal(got["network"][[2, 11, 30]], np.asarray(x)[[2, 11, 30]])
+
+
+@pytest.mark.parametrize("floats", sorted(FLOATS))
+@pytest.mark.parametrize("slots", [2, 3, 5, THRESHOLD])
+def test_the_network_orders_planes_as_the_stable_sort_does(slots, floats):
+    """Plane j is BIT FOR BIT position j of ``jnp.sort`` along the slot axis:
+    +inf after every number, a NaN (of either sign) after +inf, and tied
+    values, -0 and +0 among them, in slot order, because the comparators sit
+    between adjacent planes and leave a tie alone."""
+    rng = np.random.default_rng([9, slots])
+    with enable_x64(floats == "f64"):
+        stack = rng.standard_normal((slots, 64, 5))
+        stack[:, :, 0] = np.round(stack[:, :, 0])  # ties
+        special = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, -np.nan, 1.0, -5.0])
+        stack[:, :, 1:3] = rng.choice(special, size=(slots, 64, 2))
+        stack = jnp.asarray(stack, FLOATS[floats])
+        planes = ra._ordered_planes([stack[j] for j in range(slots)])
+        got = np.stack([np.asarray(p) for p in planes])
+        want = np.asarray(jnp.sort(stack, axis=0))
+    bits = np.uint64 if floats == "f64" else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+    assert np.isnan(want).any() and np.signbit(want[want == 0]).any()
+
+
+def test_one_kept_plane_keeps_its_own_zero():
+    """Where ONE plane is kept the aggregate is that plane to the bit, the
+    sign of a zero included: the median of (-0, -1, 1) is -0. (The masked
+    ``jnp.sum`` of the sort path adds it to a +0 and returns +0: equal as
+    numbers, the one bit the two orderings may differ in.)"""
+    nbr = np.array([[1, 2], [0, 2], [0, 1]], dtype=np.int32)
+    x = jnp.asarray([[-0.0], [-1.0], [1.0]], jnp.float32)
+    live = jnp.ones((3, 2), jnp.float32)
+    for rule in ("trimmed_mean", "median"):
+        out = np.asarray(ra.make_gather_robust_aggregator(rule, 1, nbr)(live, x))
+        np.testing.assert_array_equal(out, np.zeros((3, 1), np.float32))
+        assert np.signbit(out).all(), out
+
+
+@pytest.mark.parametrize("slots,order", [(THRESHOLD, "network"), (THRESHOLD + 1, "sort")])
+def test_the_tables_width_alone_decides_the_ordering(slots, order):
+    """No option, no name: ``k_max + 1`` up to ``NETWORK_MAX_SLOTS`` traces
+    no sort, one slot more traces one; the root's ``screen_order`` is the
+    same rule read aloud."""
+    import jax
+
+    nbr, live, x = _hostile_instance(slots, jnp.float32)
+    for rule in ("trimmed_mean", "median"):
+        traced = str(jax.make_jaxpr(ra.make_gather_robust_aggregator(rule, 1, nbr))(live, x))
+        assert ("sort[" in traced) == (order == "sort"), traced[:400]
+        assert ("concatenate" in traced) == (order == "sort")
+        assert ra.screen_order(rule, "gather", 48, slots - 1) == f"{order}:{slots}"
+        assert ra.screen_order(rule, "halo_gather", 48, slots - 1) == f"{order}:{slots}"
+        assert ra.screen_order(rule, "dense", 48, slots - 1) == "sort:48"
+    assert ra.screen_order("clipped_gossip", "gather", 48, slots - 1) == "none"
+
+
 # --------------------------------------------- end-to-end impl equivalence
 
 E2E_CFG = ExperimentConfig(

@@ -7,6 +7,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -186,8 +187,9 @@ def byzantine_round(one_chip):
     """One screened gossip round at the Byzantine cell's size (ISSUE 43: a
     ring of 2^18 workers, 24,576 sign-flippers placed within the budget, the
     trimmed mean at b = 1 over the neighbor table, k_max = 2), as
-    ``_bind_byzantine`` composes it: the payload, the gather form's rule,
-    the attackers' stencil."""
+    ``_bind_byzantine`` composes it: the payload, the gather form's rule
+    (three slot planes and a compare-exchange network since ISSUE 44), the
+    attackers' stencil."""
     from distributed_optimization_tpu.ops.robust_aggregation import (
         make_gather_robust_aggregator,
     )
@@ -213,18 +215,21 @@ def byzantine_round(one_chip):
 
 
 def test_the_byzantine_round_orders_three_slots_without_a_lane_wide_stack(byzantine_round):
-    """The closed neighbourhood's sort stays ``[N, 3, 81]`` with the
-    coordinates on the lanes and the three slots on the sublanes (0.27 GB an
-    operand in tiles): no operand is the stack's size times 128, which the
-    slot axis moved to the lanes would make (10.9 GB), and the round's
-    temporaries are a fraction of the chip."""
+    """Three slots are three planes ``[N, 81]`` ordered by compare-and-select
+    (ISSUE 44): NO ``sort``, no ``iota s32[262144,3,81]`` beside it, no
+    operand ``[262144,3,81]`` at all (the concatenate and the masked sum went
+    with it); the network, the attackers' stencil and the outer select are
+    ONE elementwise fusion over the models. No operand is the stack's size
+    times 128, and the round's temporaries are the gathered planes, the
+    payload and the row-major copies: 0.67 GB where the sort's round held
+    1.21."""
     text = byzantine_round.as_text()
     ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
     stack = (1 << 18) * 81 * 4
     assert max(device_scopes._shape_bytes(ins[1]) for ins in ops) < 16 * stack
-    (sort,) = [ins for ins in ops if ins[2] == "sort"]
-    assert "f32[262144,3,81]{2,1,0" in sort[1], sort[1]
-    assert byzantine_round.memory_analysis().temp_size_in_bytes < 1_600_000_000
+    assert not [ins for ins in ops if ins[2] in ("sort", "iota")], "a sort came back"
+    assert "[262144,3,81]" not in text and "[262144,2,81]" not in text
+    assert byzantine_round.memory_analysis().temp_size_in_bytes < 900_000_000
 
 
 def test_the_byzantine_rounds_tables_fold_into_small_constants(byzantine_round):
@@ -241,6 +246,28 @@ def test_the_byzantine_rounds_tables_fold_into_small_constants(byzantine_round):
     assert max(device_scopes._shape_bytes(ins[1]) for ins in constants) <= 524_288 * 4
     assert not [ins for ins in constants if "[262144,2]" in ins[1]]
     assert byzantine_round.memory_analysis().generated_code_size_in_bytes < 16 * 2**20
+    # ONE gather fetches both slots' planes, through the table read slot-major
     (gather,) = [ins for ins in map(device_scopes._instruction, text.splitlines())
                  if ins is not None and ins[2] == "gather"]
     assert "dopt.robust" in gather[4]
+
+
+def test_one_slot_over_the_networks_width_still_sorts(one_chip):
+    """The table's width decides and nothing else: the same rule over a table
+    of ``NETWORK_MAX_SLOTS`` neighbours a worker (one slot more than the
+    network takes) at the cell's N and d compiles the stack and its sort."""
+    from distributed_optimization_tpu.ops import robust_aggregation as ra
+
+    n, d, k = 1 << 18, 81, ra.NETWORK_MAX_SLOTS
+    i = np.arange(n)[:, None]
+    nbr_idx = np.sort(np.concatenate(
+        [(i + o) % n for o in range(1, k // 2 + 1)]
+        + [(i - o) % n for o in range(1, k - k // 2 + 1)], axis=1), axis=1)
+    assert nbr_idx.shape == (n, k)
+    rule = ra.make_gather_robust_aggregator("trimmed_mean", 1, nbr_idx)
+    live = jnp.ones((n, k), jnp.float32)
+    text = jax.jit(lambda x: rule(live, x)).lower(
+        jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)).compile().as_text()
+    (sort,) = [ins for ins in map(device_scopes._instruction, text.splitlines())
+               if ins is not None and ins[2] == "sort"]
+    assert f"f32[262144,{k + 1},81]" in sort[1], sort[1]

@@ -109,8 +109,9 @@ def test_robust_gather_round_lowers_for_tpu(rule, liveness):
     """The screening round every Byzantine run takes (``robust_impl`` auto is
     the gather form on any graph but the complete one): over the static
     table's mask, and over the liveness bits the fault layer draws for the
-    same table at t. These hold a sort (the count rules' and the adaptive
-    radius's): only that they lower is pinned."""
+    same table at t. Clipping's adaptive radius holds a sort (the count
+    rules order a ring's three slots by compare-and-select since PR 44):
+    only that they lower is pinned."""
     from distributed_optimization_tpu.ops.robust_aggregation import (
         make_gather_robust_aggregator,
     )
