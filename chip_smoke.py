@@ -109,11 +109,13 @@ without a TPU it exits before doing any work. Segments:
    cell's own limits. The root says ``forward`` = ``fused`` (the shard visit
    with the HONEST mean as the eval's x-bar), ``robust_impl`` = ``gather``,
    ``screen_order`` = ``network:3`` (ISSUE 44: three slot planes ordered by
-   compare-and-select, no sort in the compiled scan), ``budget_max`` = 1;
+   compare-and-select, no sort in the compiled scan), ``screen_fetch`` =
+   ``shift`` (ISSUE 45: the two received planes are two shifts of the
+   transmitted stack, no gather under ``dopt.robust``), ``budget_max`` = 1;
    the device's peak over the bytes in use at the segment's start; and the
    compiled scan's instructions under ``dopt.robust``, the largest with
    their bytes. What the CPU cannot see: the visit carrying an adversary,
-   the gathered planes' layout and what the unrolled trip keeps alive.
+   the shifted planes' layout and what the unrolled trip keeps alive.
 
 Every ``*_impl`` selector and ``scan_unroll`` stay at their defaults, so
 the choices ``auto`` makes on the chip are the ones exercised. The last
@@ -576,13 +578,14 @@ def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
     result, root, peak = _rooted_run(
         f"sign-flip on a ring of {n_workers}, trimmed mean", device, cfg, ds,
         ("attack", "byzantine_placement", "budget_max", "aggregation",
-         "robust_impl", "screen_order", "screened_rows", "robust_bytes",
-         "forward", "mixing", "temp_bytes"))
+         "robust_impl", "screen_order", "screen_fetch", "screened_rows",
+         "robust_bytes", "forward", "mixing", "temp_bytes"))
     _check((root["forward"], root["robust_impl"], root["screen_order"],
-            root["budget_max"]) == ("fused", "gather", "network:3", 1),
+            root["screen_fetch"], root["budget_max"])
+           == ("fused", "gather", "network:3", "shift", 1),
            "the root says the shard visit, the gather form, three slots "
-           "ordered by the network, one attacker at most beside an honest "
-           "worker")
+           "ordered by the network, the received rows by shifts, one "
+           "attacker at most beside an honest worker")
     _check(root["attack"] == f"sign_flip:{exp['n_byzantine']}/{n_workers}"
            and root["byzantine_placement"] == "within_budget"
            and root["aggregation"] == "trimmed_mean:b=1"
@@ -606,6 +609,9 @@ def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
            "the compiled scan holds no sort")
     rows = [r for r in table if r["scope"] == "robust"]
     _check(bool(rows), "the compiled scan carries dopt.robust")
+    _check(not [r for r in table if "robust" in (r["scope"], *r["also"])
+                and "gather" in r["head"].split("(")[0]],
+           "no gather under dopt.robust: a ring's rows come by shifts")
     sized = sorted(((device_scopes._shape_bytes(r["head"]), r["head"]) for r in rows),
                    reverse=True)
     print(f"[chip_smoke] byzantine: {len(rows)} instructions under dopt.robust; "

@@ -56,6 +56,7 @@ from distributed_optimization_tpu.ops.robust_aggregation import (
     make_gather_robust_aggregator,
     make_robust_activity,
     make_robust_aggregator,
+    screen_fetch,
     screen_order,
     validate_budget,
 )
@@ -821,7 +822,10 @@ def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
     budget) with ``robust_impl`` (``gather`` / ``dense`` / ``halo_gather``:
     the form that ran, the engagement counter), ``screen_order`` (how a
     closed neighbourhood was put in order and over how many slots:
-    ``network:3`` / ``sort:31``, read off the table's width), ``screened_rows``
+    ``network:3`` / ``sort:31``, read off the table's width), ``screen_fetch``
+    (how the received rows were fetched: ``shift`` on a ring's table,
+    ``gather`` through any other, from the predicate the rule asks),
+    ``screened_rows``
     (the rows of numbers a round orders: every closed neighbourhood's) and
     ``robust_bytes`` (what the round's tables take on the device as
     arguments of the scan: 0 while they are constants of it)."""
@@ -846,6 +850,10 @@ def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
             robust_impl=impl,
             screen_order=screen_order(
                 config.aggregation, impl, topo.n, k_max
+            ),
+            screen_fetch=screen_fetch(
+                config.aggregation, impl,
+                None if impl == "dense" else neighbor_tables_for(topo)[0],
             ),
             screened_rows=topo.n * (topo.n if impl == "dense" else k_max + 1),
             robust_bytes=0.0,

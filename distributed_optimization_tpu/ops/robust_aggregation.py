@@ -58,14 +58,22 @@ Two jax implementations of every rule (``robust_impl`` knob):
 
   - up to ``NETWORK_MAX_SLOTS`` = 17 slots (k_max + 1: a ring's 3, a
     torus's 5, an 8-regular graph's 9) the neighborhood is k_max + 1
-    PLANES of [N, d] — the worker's own rows and, from ONE gather through
-    the table read slot-major, each slot's rows, +inf where the slot is
-    dead — put in order by a compare-exchange network (a compare and two
-    selects a comparator, adjacent planes only, so it is stable), and the
-    rules read planes: no [N, k_max + 1, d] stack, no sort. Each plane
-    holds bit for bit what ``jnp.sort`` puts in that position: +inf after
-    every finite value, a NaN last, tied values (±0 among them) in slot
-    order;
+    PLANES of [N, d] — the worker's own rows and each slot's rows, +inf
+    where the slot is dead — put in order by a compare-exchange network (a
+    compare and two selects a comparator, adjacent planes only, so it is
+    stable), and the rules read planes: no [N, k_max + 1, d] stack, no
+    sort. Each plane holds bit for bit what ``jnp.sort`` puts in that
+    position: +inf after every finite value, a NaN last, tied values (±0
+    among them) in slot order. HOW a slot's rows are fetched is read off
+    the table too (``_received_planes``): where it is a host array and IS
+    a ring's (``parallel.topology.table_is_a_ring``, the ONE rule the
+    fault layer and the halo mixing ask too) the two planes are two shifts
+    of the transmitted stack, ``roll(x, 1)`` and ``roll(x, -1)``, swapped
+    by a select on rows 0 and N − 1, whose table lists i + 1 first — no
+    table, no gather, nothing row-major in the program; on every other
+    table (a ring listed another way, chain, torus, a drawn graph, the halo
+    form's traced table) ONE gather through the table read slot-major.
+    The same bits either way (``tests/test_robust_gather.py``);
   - above it (a drawn graph's k_max of 30) the stack, ``jnp.sort`` along
     the slot axis and a masked sum, as ever.
 
@@ -91,6 +99,7 @@ from distributed_optimization_tpu.config import AGGREGATIONS
 from distributed_optimization_tpu.parallel.faults import (
     metropolis_hastings_weights,
 )
+from distributed_optimization_tpu.parallel.topology import table_is_a_ring
 
 RobustAggregator = Callable[[jax.Array, jax.Array], jax.Array]
 
@@ -198,15 +207,56 @@ def _ordered_planes(planes):
     return planes
 
 
+def _rows_come_by_shifts(nbr) -> bool:
+    """Whether the count rules read a table's neighbours by two shifts of
+    the transmitted stack: the table is a HOST array (the unsharded form's;
+    the halo form's is the shard's traced table over the halo-extended
+    block) and IS a ring's (``topology.table_is_a_ring``, the rule the fault
+    layer and the halo mixing ask). Read off the table, by no option."""
+    return isinstance(nbr, np.ndarray) and table_is_a_ring(nbr)
+
+
+def screen_fetch(name: str, impl: str, nbr_idx) -> str:
+    """How a screened call fetches the rows a worker received, as its
+    ``dopt.run`` root says it, from the predicate the rule itself asks:
+    ``shift`` = a ring's table under the unsharded count rules (its three
+    slots are always planes), two shifts of the transmitted stack;
+    ``gather`` = through the neighbor table (every other table, clipping,
+    ``halo_gather``); ``none`` for the dense form, which reads no table
+    (``nbr_idx`` is not looked at)."""
+    if impl == "dense":
+        return "none"
+    by_shifts = (
+        name != "clipped_gossip"
+        and impl == "gather"
+        and _rows_come_by_shifts(nbr_idx)
+    )
+    return "shift" if by_shifts else "gather"
+
+
 def _received_planes(source, nbr, live):
     """What each slot delivered, a plane [N, d] a slot, +inf where the slot
-    is dead: ONE row gather through the table read slot-major, so that
-    slot s is the rows [s·N, (s + 1)·N) of what it returns: whole tiles
-    whatever k_max, where [N, k_max, d] keeps the slots on the sublanes."""
+    is dead. On a ring's table (``_rows_come_by_shifts``) two shifts of
+    ``source``: the table lists neighbours ASCENDING, so slot 0 is row
+    i − 1 and slot 1 row i + 1 on every row but 0 and N − 1 ([1, N − 1],
+    [0, N − 2]), where a select on the row index swaps the two, and plane s
+    holds what slot s delivered on EVERY row, as ``live[:, s]`` and the
+    network's order of ties want it. On every other table ONE row gather
+    through the table read slot-major, so that slot s is the rows
+    [s·N, (s + 1)·N) of what it returns: whole tiles whatever k_max, where
+    [N, k_max, d] keeps the slots on the sublanes. The same bits either
+    way."""
     n, k_max = nbr.shape
-    rows = source[nbr.T.reshape(-1)]
+    if _rows_come_by_shifts(nbr):
+        below, above = jnp.roll(source, 1, axis=0), jnp.roll(source, -1, axis=0)
+        row = jnp.arange(n)[:, None]
+        end = (row == 0) | (row == n - 1)
+        slots = [jnp.where(end, above, below), jnp.where(end, below, above)]
+    else:
+        rows = source[nbr.T.reshape(-1)]
+        slots = [rows[s * n:(s + 1) * n] for s in range(k_max)]
     return [
-        jnp.where(live[:, s, None] > 0, rows[s * n:(s + 1) * n], jnp.inf)
+        jnp.where(live[:, s:s + 1] > 0, slots[s], jnp.inf)
         for s in range(k_max)
     ]
 
@@ -235,6 +285,8 @@ def closed_neighbourhood_rule(name: str, budget: int):
     ``NETWORK_MAX_SLOTS`` slots, the [N, k_max + 1, d] stack and
     ``jnp.sort`` above. Position j holds the same value either way; valid
     values occupy positions [0, c_i), the +inf padding is never selected.
+    The planes of a ring's host table are two shifts of ``source``, of any
+    other table one gather (``_received_planes``): the same bits.
     """
     if name not in ("trimmed_mean", "median"):
         raise ValueError(f"{name!r} is no count rule")
@@ -416,7 +468,8 @@ def make_gather_robust_aggregator(
     ordering, rank selection, and neighbor reduction are O(k_max), not
     O(N). The two count rules are ``closed_neighbourhood_rule``, which
     reads off the table's width whether the slots are planes put in order
-    by a compare-exchange network or a stack given to ``jnp.sort``.
+    by a compare-exchange network or a stack given to ``jnp.sort``, and
+    off the table itself whether a ring's planes come by two shifts.
     """
     if name not in AGGREGATIONS or name == "gossip":
         raise ValueError(
@@ -430,9 +483,10 @@ def make_gather_robust_aggregator(
     k_max = nbr_idx.shape[1]
 
     if name in ("trimmed_mean", "median"):
-        # The table stays on the host: the program's constant is the flat
-        # s32[k_max·N] the gather reads ([N, k_max] s32 on the device lies
-        # in tiles of 128 lanes).
+        # The table stays on the host, where the rule can read whether it
+        # is a ring's (then no table reaches the program); otherwise the
+        # program's constant is the flat s32[k_max·N] the gather reads
+        # ([N, k_max] s32 on the device lies in tiles of 128 lanes).
         table = np.asarray(nbr_idx, dtype=np.int32)
         rule = closed_neighbourhood_rule(name, budget)
 

@@ -847,24 +847,31 @@ def neighbor_tables_for(topo: Topology) -> tuple[np.ndarray, np.ndarray]:
     return neighbor_table(topo.adjacency)
 
 
-def _table_is_a_ring(topo: Topology) -> bool:
-    """Whether the neighbor table IS a ring's: n >= 3, every row the two
-    neighbours (i ± 1) mod n in ascending order, every slot live. Read off
-    the table, not the topology's name: whatever graph has this table is
-    mixed by shifts — the unsharded fault layer
-    (``faults._make_shift_faulty_mixing``) and the worker mesh's halo
-    mixing (``collectives.make_halo_mixing_op``) ask this one rule —
+def table_is_a_ring(nbr_idx: np.ndarray, nbr_mask=None) -> bool:
+    """Whether a neighbor table IS a ring's: n >= 3 rows, every row the two
+    neighbours (i ± 1) mod n in ascending order and, where the static mask
+    is given, every slot live in it. Read off the table, not a topology's
+    name: whatever graph has this table has its neighbours read by shifts —
+    the unsharded fault layer (``faults._make_shift_faulty_mixing``), the
+    worker mesh's halo mixing (``collectives.make_halo_mixing_op``) and the
+    screened round's count rules
+    (``ops.robust_aggregation.closed_neighbourhood_rule``, which is handed
+    each round's liveness and so asks without the mask) ask this ONE rule —
     every other one by gathers. Host arrays, a few ms at 2^18 workers."""
-    n = topo.n
-    if n < 3:
-        return False
-    nbr_idx, nbr_mask = neighbor_tables_for(topo)
-    if nbr_idx.shape != (n, 2):
+    n = nbr_idx.shape[0]
+    if n < 3 or nbr_idx.shape != (n, 2):
         return False
     nbr, mask = _ring_neighbor_tables(n)
     return bool(
-        np.array_equal(nbr_mask, mask) and np.array_equal(nbr_idx, nbr)
+        (nbr_mask is None or np.array_equal(nbr_mask, mask))
+        and np.array_equal(nbr_idx, nbr)
     )
+
+
+def _table_is_a_ring(topo: Topology) -> bool:
+    """``table_is_a_ring`` of a topology's own tables, the static mask
+    included: what the fault layer and the halo mixing ask."""
+    return table_is_a_ring(*neighbor_tables_for(topo))
 
 
 @dataclasses.dataclass(frozen=True)

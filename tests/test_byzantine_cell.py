@@ -142,6 +142,7 @@ def test_the_root_says_who_lied_and_how_it_was_screened(cell):
     assert args["byzantine_placement"] == "within_budget" and args["budget_max"] == 1
     assert args["aggregation"] == "trimmed_mean:b=1" and args["robust_impl"] == "gather"
     assert args["screen_order"] == "network:3"  # three slots: no sort (PR 44)
+    assert args["screen_fetch"] == "shift"  # a ring's table: no gather (PR 45)
     assert args["screened_rows"] == 64 * 3 and args["robust_bytes"] == 0.0
     assert args["mixing"] == "stencil"  # the attackers' benign rows
     assert children.count("dopt.run.adversary") == 1
@@ -149,8 +150,27 @@ def test_the_root_says_who_lied_and_how_it_was_screened(cell):
     # a benign call says none of it and opens no such span
     _, args, children, _ = run_program(benign(config), traffic, SEEDS[0])
     assert not {"attack", "byzantine_placement", "budget_max", "aggregation", "robust_impl",
-                "screen_order", "screened_rows", "robust_bytes"} & set(args)
+                "screen_order", "screen_fetch", "screened_rows", "robust_bytes"} & set(args)
     assert "dopt.run.adversary" not in children
+
+
+@pytest.mark.parametrize("graph,fetch,order", [("ring", "shift", "network:3"), ("grid", "gather", "network:5")])
+def test_the_root_says_how_the_received_rows_were_fetched(cell, graph, fetch, order):
+    """``screen_fetch`` is the predicate the rule asked, read off the neighbor
+    table (ISSUE 45): ``shift`` on the cell's ring, whose compiled scan holds
+    no gather under ``dopt.robust``; ``gather`` on an 8 x 8 torus of the same
+    workers (what its round compiles to is ``tests/test_tpu_compile.py``'s)."""
+    from distributed_optimization_tpu.observability import device_scopes
+
+    config, traffic = cell
+    result, args, _, _ = run_program(config, traffic, SEEDS[0], topology=graph)
+    assert (args["screen_fetch"], args["screen_order"]) == (fetch, order)
+    assert args["robust_impl"] == "gather" and args["budget_max"] == 1
+    assert not harness.gate_failures(result, traffic)
+    if fetch == "shift":
+        rows = device_scopes.table_for(args["program"])["rows"]
+        robust = [r["head"] for r in rows if r["scope"] == "robust" or "robust" in r["also"]]
+        assert robust and not [h for h in robust if "gather" in h.split("(")[0]], robust
 
 
 def test_the_compiled_scan_bills_the_round_to_its_own_scope(cell):

@@ -404,6 +404,106 @@ def test_the_tables_width_alone_decides_the_ordering(slots, order):
     assert ra.screen_order("clipped_gossip", "gather", 48, slots - 1) == "none"
 
 
+# ------------------------------------------- a ring's rows come by shifts
+
+def _end_row_stack(n, shape, seed):
+    """Numbers with ties of -0 and +0, ±inf and NaN payloads of either sign
+    strewn over two columns, and on BOTH slots of the end rows (rows 0 and
+    N - 1 receive rows 1, N - 1 and 0, N - 2) one of each, so that a plane
+    holding the wrong slot's row there shows in the bits."""
+    rng = np.random.default_rng([45, n, seed])
+    x = rng.standard_normal((n,) + shape)
+    flat = x.reshape(n, -1)  # a view
+    flat[:, 0] = np.round(flat[:, 0])
+    special = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, -np.nan, 1.0, -5.0])
+    flat[:, 1:3] = rng.choice(special, size=(n, 2))
+    # row 0 receives rows 1 (slot 0) and N - 1 (slot 1), row N - 1 rows 0
+    # (slot 0) and N - 2 (slot 1): a tie of -0 then +0, and of NaN then
+    # -NaN, above the row's own value leaves the network in slot order, so
+    # the middle plane is the FIRST slot's, and another's under a swap
+    for first, second, own, col in ((1, n - 1, 0, 3), (0, n - 2, n - 1, 6)):
+        flat[[first, second, own], col] = [-0.0, 0.0, -1.0]
+        flat[[first, second, own], col + 1] = [np.nan, -np.nan, 0.0]
+        flat[[first, second], col + 2] = [np.inf, -np.inf]
+    return jnp.asarray(x, jnp.float32)
+
+
+def _liveness(n, drawn, seed):
+    if not drawn:
+        return jnp.ones((n, 2), jnp.float32)
+    live = (np.random.default_rng([46, n, seed]).random((n, 2)) > 0.3).astype(np.float32)
+    live[0], live[n - 1] = (0.0, 1.0), (1.0, 0.0)  # a dead slot on each end row, not the same one
+    return jnp.asarray(live)
+
+
+def _through_a_traced_table(rule, budget, nbr):
+    """The gather form: the same ``closed_neighbourhood_rule`` handed the
+    table as a traced array, which no predicate can read."""
+    import jax
+
+    count_rule = ra.closed_neighbourhood_rule(rule, budget)
+
+    @jax.jit
+    def aggregate(table, live, x):
+        rows = x.reshape(x.shape[0], -1)
+        return count_rule(rows, rows, table, live).reshape(x.shape)
+
+    return lambda live, x: aggregate(jnp.asarray(nbr), live, x)
+
+
+SHIFT_CASES = [
+    pytest.param(rule, n, drawn, shape, id=f"{rule}-ring{n}-{'drawn' if drawn else 'live'}-{'NdK' if len(shape) == 2 else 'Nd'}")
+    for rule in ("trimmed_mean", "median") for n in (3, 4, 64)
+    for drawn in (False, True) for shape in ((9,), (5, 3))
+] + [
+    pytest.param(rule, table, True, (9,), id=f"{rule}-{table}")
+    for rule, table in (("trimmed_mean", "ring_descending"), ("median", "chain"), ("trimmed_mean", "torus"))
+]
+
+
+@pytest.mark.parametrize("rule,table,drawn,shape", SHIFT_CASES)
+def test_a_rings_rows_come_by_shifts_bitwise_the_gather(rule, table, drawn, shape):
+    """ISSUE 45: handed a ring's table as a HOST array the count rules read
+    the two received planes by two shifts of the transmitted stack (no
+    gather traced), and the aggregate is BIT FOR BIT the gather form's: the
+    slot order is the table's on the end rows too (rows 0 and N - 1 list
+    i + 1 first), so a slot's liveness bit masks that slot's row and ties
+    leave the network in slot order. A ring listed descending, a chain and a
+    torus keep the gather and their values."""
+    import jax
+
+    by_shifts = isinstance(table, int)
+    if by_shifts:
+        nbr, mask = neighbor_table(build_topology("ring", table).adjacency)
+    elif table == "ring_descending":
+        nbr, mask = neighbor_table(build_topology("ring", 16).adjacency)
+        nbr = nbr[:, ::-1].copy()
+    else:
+        nbr, mask = neighbor_table(build_topology({"torus": "grid"}.get(table, table), 16).adjacency)
+    n, k_max = nbr.shape
+    x = _end_row_stack(n, shape, seed=len(rule))
+    if k_max == 2:
+        live = _liveness(n, drawn, seed=len(rule))
+    else:
+        live = jnp.asarray(np.random.default_rng(47).random((n, k_max)) > 0.3, jnp.float32)
+    live = live * jnp.asarray(mask, jnp.float32)  # a chain's end rows hold one neighbour
+    own = ra.make_gather_robust_aggregator(rule, 1, nbr)
+    got = np.asarray(own(live, x))
+    want = np.asarray(_through_a_traced_table(rule, 1, nbr)(live, x))
+    assert got.shape == x.shape and got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    if by_shifts and not drawn:
+        # the end rows' ties came out in slot order: the first slot's -0 and NaN
+        ends = want.reshape(n, -1)[[0, n - 1], [3, 6]], want.reshape(n, -1)[[0, n - 1], [4, 7]]
+        assert (ends[0] == 0).all() and np.signbit(ends[0]).all(), ends
+        assert np.isnan(ends[1]).all() and not np.signbit(ends[1]).any(), ends
+    traced = str(jax.make_jaxpr(own)(live, x))
+    assert ("gather" in traced) == (not by_shifts), traced[:300]
+    assert ra.screen_fetch(rule, "gather", nbr) == ("shift" if by_shifts else "gather")
+    assert ra.screen_fetch(rule, "halo_gather", nbr) == "gather"
+    assert ra.screen_fetch("clipped_gossip", "gather", nbr) == "gather"
+
+
 # --------------------------------------------- end-to-end impl equivalence
 
 E2E_CFG = ExperimentConfig(

@@ -188,8 +188,9 @@ def byzantine_round(one_chip):
     ring of 2^18 workers, 24,576 sign-flippers placed within the budget, the
     trimmed mean at b = 1 over the neighbor table, k_max = 2), as
     ``_bind_byzantine`` composes it: the payload, the gather form's rule
-    (three slot planes and a compare-exchange network since ISSUE 44), the
-    attackers' stencil."""
+    (three slot planes and a compare-exchange network since ISSUE 44, the
+    two received planes two shifts of the transmitted stack since ISSUE 45:
+    the table is a ring's), the attackers' stencil."""
     from distributed_optimization_tpu.ops.robust_aggregation import (
         make_gather_robust_aggregator,
     )
@@ -220,36 +221,91 @@ def test_the_byzantine_round_orders_three_slots_without_a_lane_wide_stack(byzant
     operand ``[262144,3,81]`` at all (the concatenate and the masked sum went
     with it); the network, the attackers' stencil and the outer select are
     ONE elementwise fusion over the models. No operand is the stack's size
-    times 128, and the round's temporaries are the gathered planes, the
-    payload and the row-major copies: 0.67 GB where the sort's round held
-    1.21."""
+    times 128, and the round's temporaries are the payload and the shifted
+    planes' row slices: 0.37 GB (ISSUE 45) where the gathered planes and the
+    row-major copies held 0.67 and the sort's round 1.21."""
     text = byzantine_round.as_text()
     ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
     stack = (1 << 18) * 81 * 4
     assert max(device_scopes._shape_bytes(ins[1]) for ins in ops) < 16 * stack
-    assert not [ins for ins in ops if ins[2] in ("sort", "iota")], "a sort came back"
+    # (the one iota is the row index ``s32[262144]`` of the end rows' select)
+    assert not [ins for ins in ops if ins[2] == "sort" or (ins[2] == "iota" and "81]" in ins[1])], "a sort came back"
     assert "[262144,3,81]" not in text and "[262144,2,81]" not in text
-    assert byzantine_round.memory_analysis().temp_size_in_bytes < 900_000_000
+    assert byzantine_round.memory_analysis().temp_size_in_bytes < 500_000_000
 
 
-def test_the_byzantine_rounds_tables_fold_into_small_constants(byzantine_round):
-    """Closed into the executable, the ring's neighbor table is ONE flat
-    ``s32[524288]`` (2 MB), the all-ones liveness is folded away and the
-    attackers' mask is a ``pred[262144]``: no ``[262144, 2]`` constant, which
-    lies in 134 MB of tiles, survives. Why the tables stay constants
-    (ROADMAP W2 d)."""
+def _holds_no_table_and_nothing_row_major(text):
+    """A ring's round by shifts (ISSUE 45): no gather, no gathered
+    ``f32[524288,81]``, no index constant ``s32[524288]`` and no array
+    ``f32[262144,81]`` laid row-major (the carried models lie ``{0,1}``, the
+    worker axis minor; the gather wanted them ``{1,0}`` and the aggregate
+    copied back)."""
+    ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
+    assert not [ins for ins in ops if ins[2] == "gather"], "a gather came back"
+    assert "[524288,81]" not in text and "s32[524288]" not in text
+    assert not [ins for ins in ops if "f32[262144,81]{1,0" in ins[1]], "a row-major copy came back"
+    return ops
+
+
+def test_the_byzantine_round_reads_its_neighbours_by_shifts(byzantine_round):
+    """Closed into the executable, the all-ones liveness is folded away and
+    the attackers' mask is a ``pred[262144]``; the ring's neighbor table is
+    not in the program at all (ISSUE 45; one flat ``s32[524288]`` before):
+    no ``[262144, 2]`` constant, which lies in 134 MB of tiles, survives.
+    Why the masks stay constants (ROADMAP W2 d). The transmitted stack's row
+    slices are ``dopt.robust``'s, the stencil's ``dopt.gossip``'s."""
     text = byzantine_round.as_text()
-    constants = [
-        ins for ins in map(device_scopes._instruction, text.splitlines())
-        if ins is not None and ins[2] == "constant"
-    ]
-    assert max(device_scopes._shape_bytes(ins[1]) for ins in constants) <= 524_288 * 4
+    ops = _holds_no_table_and_nothing_row_major(text)
+    constants = [ins for ins in ops if ins[2] == "constant"]
+    assert max(device_scopes._shape_bytes(ins[1]) for ins in constants) <= 262_144
     assert not [ins for ins in constants if "[262144,2]" in ins[1]]
-    assert byzantine_round.memory_analysis().generated_code_size_in_bytes < 16 * 2**20
-    # ONE gather fetches both slots' planes, through the table read slot-major
+    assert byzantine_round.memory_analysis().generated_code_size_in_bytes < 4 * 2**20
+    slices = [ins for ins in ops if ins[2] == "fusion" and "f32[262143,81]" in ins[1]]
+    assert len(slices) == 2 and sum("dopt.robust" in ins[4] for ins in slices) == 1
+
+
+def test_the_faulted_byzantine_round_reads_its_neighbours_by_shifts(one_chip):
+    """The same rule over the liveness bits the ring's fault layer draws at
+    t (a bit a slot, 30% link loss, 10% stragglers) at the cell's size:
+    shifts too, and the fault layer's own reads are shifts since ISSUE 33,
+    so the whole round holds no gather and no table."""
+    from distributed_optimization_tpu.ops.robust_aggregation import (
+        make_gather_robust_aggregator,
+    )
+    from distributed_optimization_tpu.parallel.faults import make_faulty_mixing
+
+    n, d = 1 << 18, 81
+    ring = topology.build_topology("ring", n, impl="neighbor")
+    nbr_idx, nbr_mask = topology.neighbor_tables_for(ring)
+    rule = make_gather_robust_aggregator("trimmed_mean", 1, nbr_idx)
+    live_fn = make_faulty_mixing(
+        ring, 0.3, seed=5, straggler_prob=0.1).make_neighbor_liveness(nbr_idx, nbr_mask)
+    text = jax.jit(lambda t, x: rule(live_fn(t), x)).lower(
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)).compile().as_text()
+    _holds_no_table_and_nothing_row_major(text)
+
+
+def test_a_toruss_round_still_holds_its_one_gather(one_chip):
+    """Any table that is not a ring's keeps the gather as it was: a 512 x 512
+    torus at the cell's N and d, its four slots' planes from ONE gather of
+    ``[4·N, d]`` under ``dopt.robust``."""
+    from distributed_optimization_tpu.ops.robust_aggregation import (
+        make_gather_robust_aggregator,
+    )
+
+    n, d = 1 << 18, 81
+    torus = topology.build_topology("grid", n, impl="neighbor")
+    nbr_idx, nbr_mask = topology.neighbor_tables_for(torus)
+    assert nbr_idx.shape == (n, 4)
+    rule = make_gather_robust_aggregator("trimmed_mean", 1, nbr_idx)
+    live = jnp.asarray(nbr_mask, dtype=jnp.float32)
+    text = jax.jit(lambda x: rule(live, x)).lower(
+        jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)).compile().as_text()
     (gather,) = [ins for ins in map(device_scopes._instruction, text.splitlines())
                  if ins is not None and ins[2] == "gather"]
-    assert "dopt.robust" in gather[4]
+    assert "dopt.robust" not in gather[4]  # the rule alone: the scope is the mixing's
+    assert "f32[1048576,81]" in text and "s32[1048576]" in text
 
 
 def test_one_slot_over_the_networks_width_still_sorts(one_chip):

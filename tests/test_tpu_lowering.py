@@ -38,6 +38,12 @@ def _stablehlo_ops(text):
     return set(re.findall(r"\b(?:stablehlo|chlo)\.\w+", text))
 
 
+def _gathers(text):
+    """The ``stablehlo.gather`` operations of a module (its attribute
+    ``#stablehlo.gather<...>`` names the op a second time)."""
+    return len(re.findall(r'stablehlo\.gather"?\(', text))
+
+
 # The forms ``auto`` or the neighbor table choose for a static graph, each as
 # (topology, its arguments, the form). No option reaches any of them.
 MIXING_FORMS = {
@@ -105,24 +111,29 @@ def test_faulty_ring_round_lowers_for_tpu(topology, addressing):
 
 @pytest.mark.parametrize("liveness", ["all_live", "faulted"])
 @pytest.mark.parametrize("rule", ["trimmed_mean", "median", "clipped_gossip"])
-def test_robust_gather_round_lowers_for_tpu(rule, liveness):
+@pytest.mark.parametrize("graph", ["ring", "grid"])
+def test_robust_gather_round_lowers_for_tpu(graph, rule, liveness):
     """The screening round every Byzantine run takes (``robust_impl`` auto is
     the gather form on any graph but the complete one): over the static
     table's mask, and over the liveness bits the fault layer draws for the
     same table at t. Clipping's adaptive radius holds a sort (the count
-    rules order a ring's three slots by compare-and-select since PR 44):
-    only that they lower is pinned."""
+    rules order a ring's three slots and a torus's five by compare-and-select
+    since PR 44). On a RING the count rules read their two neighbours by
+    shifts of the transmitted stack (ISSUE 45): no ``stablehlo.gather`` in
+    the round, all-live or faulted; a torus's holds its ONE row gather, and
+    clipping keeps its own on either."""
     from distributed_optimization_tpu.ops.robust_aggregation import (
         make_gather_robust_aggregator,
     )
     from distributed_optimization_tpu.parallel.faults import make_faulty_mixing
 
-    topo = build_topology("ring", N)
+    topo = build_topology(graph, N)
     nbr_idx, nbr_mask = neighbor_table(topo.adjacency)
     aggregate = make_gather_robust_aggregator(rule, 1, nbr_idx)
     if liveness == "all_live":
         live = jnp.asarray(nbr_mask, dtype=jnp.float32)
         exported = _lower_for_tpu(lambda v: aggregate(live, v), X)
+        before = 0
     else:
         live_fn = make_faulty_mixing(
             topo, 0.3, seed=5, straggler_prob=0.1
@@ -131,8 +142,17 @@ def test_robust_gather_round_lowers_for_tpu(rule, liveness):
             lambda t, v: aggregate(live_fn(t), v),
             jax.ShapeDtypeStruct((), jnp.int32), X,
         )
+        # the fault layer's own reads of its bits through the table
+        before = _gathers(_lower_for_tpu(
+            live_fn, jax.ShapeDtypeStruct((), jnp.int32)
+        ).mlir_module())
     assert exported.platforms == ("tpu",)
     assert exported.out_avals[0].shape == (N, D)
+    gathers = _gathers(exported.mlir_module()) - before
+    if rule == "clipped_gossip":
+        assert gathers >= 2  # the rows and the neighbours' degrees
+    else:
+        assert gathers == (0 if graph == "ring" else 1)
 
 
 def test_accelerator_program_form_matches_cpu_default():
