@@ -75,6 +75,7 @@ from distributed_optimization_tpu.parallel.adversary import (
 from distributed_optimization_tpu.parallel.faults import (
     make_faulty_mixing,
     make_round_robin_mixing,
+    timeline_counters,
 )
 from distributed_optimization_tpu.parallel import build_topology
 from distributed_optimization_tpu.parallel.topology import (
@@ -769,7 +770,14 @@ def _fault_root_args(config, faulty, tables) -> dict:
     arrays are arguments of the program (``FaultyMixing.tables``),
     ``fault_bytes``: what they take on the device, timeline leaves
     included (0 where the form needs none), and ``fault_mixing``
-    (``shift`` / ``gather``: how that layer addresses a neighbour)."""
+    (``shift`` / ``gather``: how that layer addresses a neighbour). Of a
+    call whose faults have memory, and of no other: ``fault_chains`` (each
+    two-state chain with its rates: ``burst:<p>x<B>``,
+    ``churn:<mttf>/<mttr>``), under churn ``rejoin`` (the policy) and
+    ``down_share``, under ``neighbor_restart`` ``rejoin_rows`` (the rows it
+    is asked to restart over the horizon: the restart's engagement
+    counter), and ``timeline_placement`` (``device`` / ``host``: where the
+    leaves the scan reads were made; ``faults.timeline_counters``)."""
     parts = [
         f"{name}:{value:g}" for name, value, on in (
             ("edge_drop", config.edge_drop_prob, config.edge_drop_prob > 0.0),
@@ -791,6 +799,23 @@ def _fault_root_args(config, faulty, tables) -> dict:
         args["fault_bytes"] = _device_bytes(tables)
     if faulty.addressing is not None:
         args["fault_mixing"] = faulty.addressing
+    chains = [
+        text for text, on in (
+            (f"burst:{config.edge_drop_prob:g}x{config.burst_len:g}",
+             config.edge_drop_prob > 0.0 and config.burst_len >= 1.0),
+            (f"churn:{config.mttf:g}/{config.mttr:g}", config.mttf > 0.0),
+        ) if on
+    ]
+    if chains and faulty.timeline is not None:
+        counted = timeline_counters(faulty.timeline)
+        args.update(
+            fault_chains=",".join(chains),
+            timeline_placement=counted["timeline_placement"],
+        )
+        if faulty.churn_active:
+            args.update(rejoin=faulty.rejoin, down_share=counted["down_share"])
+        if faulty.rejoin_restart is not None:
+            args["rejoin_rows"] = counted["rejoin_rows"]
     return args
 
 
@@ -1704,7 +1729,11 @@ def _run(
                 config, algo, topo, T, halo_mesh=halo_mesh
             )
             if faulty.tables is not None:
-                fault_tables = replicate(mesh, faulty.tables)
+                # Where the leaves stayed on the device the chains' scans
+                # are still running: the span holds them.
+                fault_tables = jax.block_until_ready(
+                    replicate(mesh, faulty.tables)
+                )
             spans.note_root(**_fault_root_args(config, faulty, fault_tables))
             spans.enter("prepare")
         mixing_tables = None

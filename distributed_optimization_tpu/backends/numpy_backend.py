@@ -600,19 +600,11 @@ def run(
     realized_degree_total = 0.0
     if faults_active:
         from distributed_optimization_tpu.parallel.faults import (
-            build_fault_timeline,
+            timeline_for_config,
         )
 
-        timeline = build_fault_timeline(
-            topo, T, config.seed,
-            edge_drop_prob=config.edge_drop_prob,
-            burst_len=config.burst_len if config.burst_len >= 1.0 else 1.0,
-            straggler_prob=(
-                0.0 if config.mttf > 0.0 else config.straggler_prob
-            ),
-            mttf=config.mttf, mttr=config.mttr,
-            participation_rate=config.participation_rate,
-        )
+        # The realization the jax backend reads, its leaves on the host.
+        timeline = timeline_for_config(config, topo, T)
 
         def _up_row(t: int) -> Optional[np.ndarray]:
             """Composed [N] bool availability at round t: churn/straggler-up

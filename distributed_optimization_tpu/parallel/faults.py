@@ -48,8 +48,9 @@ Persistent processes are realized as PRECOMPUTED ``[horizon]``-indexed
 fault timelines (``build_fault_timeline``): the per-(iteration, edge/node)
 uniform draws still come purely from (seed, t) via counter-based keys —
 identical to the on-the-fly samplers — but the chain state is unrolled once
-at setup into host arrays, so per-iteration access is a jit-gatherable
-``timeline[t]`` with NO carried RNG or chain state.  Checkpoint/resume
+at setup into ``[horizon, ·]`` arrays (over a neighbor table they stay on
+the device that unrolled them; ``FaultTimeline``), so per-iteration access
+is a jit-gatherable ``timeline[t]`` with NO carried RNG or chain state.  Checkpoint/resume
 therefore stays exact (a resumed run rebuilds the identical timeline from
 the config), and the numpy oracle backend consumes the SAME timeline while
 implementing all mask/weight math independently.  Why correlated faults
@@ -143,6 +144,7 @@ the property the timeline precompute and the numpy-oracle parity rely on.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -217,8 +219,9 @@ class FaultyMixing:
     # into the node-availability row, and the backend must freeze
     # sampled-out nodes' state exactly like stragglers.
     participation_active: bool = False
-    # The host-side precomputed timeline backing this mixing (None on the
-    # memoryless on-the-fly path) — exposed for diagnostics
+    # The precomputed timeline backing this mixing (None on the memoryless
+    # on-the-fly path; its leaves on the device or the host as
+    # ``FaultTimeline`` says) — exposed for diagnostics
     # (``node_downtime``, ``windowed_connectivity``) and tests.
     timeline: Optional["FaultTimeline"] = None
     # Unsharded matrix-free forms only, else None: ``tables`` is the pytree
@@ -233,6 +236,7 @@ class FaultyMixing:
     # of whatever program traces them, which at 2^18 workers is hundreds
     # of megabytes of executable (ROADMAP A9).
     tables: Optional[dict] = None
+    # None too on a mixing that ``bind`` itself returned (``_rebindable``).
     bind: Optional[Callable[[dict], "FaultyMixing"]] = None
     # How those operators reach a neighbour's value and an incident edge's
     # bit: ``'shift'`` where the neighbor table is a ring's (rolls and
@@ -246,7 +250,7 @@ class FaultyMixing:
 
 @dataclasses.dataclass(frozen=True)
 class FaultTimeline:
-    """Precomputed ``[horizon]``-indexed fault realizations (host arrays).
+    """Precomputed ``[horizon]``-indexed fault realizations.
 
     Pure function of (topology, horizon, seed, fault params): the uniform
     draw at (t, edge/node) is the same counter-based float32 draw the
@@ -257,11 +261,19 @@ class FaultTimeline:
     receiver/sender pairs for directed ones).  ``node_up[t, i]`` is node
     availability; ``rejoin[t, i]`` marks the first up-round after an
     outage.  Entries are None for fault modes that are not active.
+
+    Where the ``[horizon, ·]`` leaves live: built over a matrix-free
+    topology they are the DEVICE arrays the chains' scans returned (the
+    program that reads them by ``[t]`` runs there: nothing is fetched and
+    placed again); built over a dense one, injected, stacked or rebuilt by a
+    host consumer (``timeline_for_config``) they are host arrays. A host
+    consumer of a timeline it did not build fetches when it reads:
+    ``host()``.
     """
 
     horizon: int
     directed: bool
-    edge_index: Optional[np.ndarray] = None  # [E, 2] int32
+    edge_index: Optional[np.ndarray] = None  # [E, 2] int32, always host
     edge_up: Optional[np.ndarray] = None     # [horizon, E] bool
     node_up: Optional[np.ndarray] = None     # [horizon, N] bool
     rejoin: Optional[np.ndarray] = None      # [horizon, N] bool
@@ -272,6 +284,23 @@ class FaultTimeline:
     # an outage — no rejoin events — so ``rejoin`` stays a pure
     # crash-recovery record.
     part_up: Optional[np.ndarray] = None     # [horizon, N] bool
+
+    def host(self) -> "FaultTimeline":
+        """This timeline with every leaf a host array (itself where they
+        already are)."""
+        held = {
+            name: getattr(self, name) for name in TIMELINE_LEAVES
+            if isinstance(getattr(self, name), jax.Array)
+        }
+        if not held:
+            return self
+        return dataclasses.replace(
+            self, **{name: np.asarray(leaf) for name, leaf in held.items()}
+        )
+
+
+# The ``[horizon, ·]`` leaves of a ``FaultTimeline``.
+TIMELINE_LEAVES = ("edge_up", "node_up", "rejoin", "part_up")
 
 
 def sample_surviving_adjacency(key, adjacency: jax.Array, drop_prob: float):
@@ -458,7 +487,8 @@ def timeline_for_config(config, topo: Topology, horizon: int,
     realization the backend executed, so the burst clamp and the
     straggler-vs-churn exclusivity rule live in exactly one place.
     ``seed`` overrides ``config.seed`` (the replica-batched path passes
-    per-replica seeds).
+    per-replica seeds). Every caller reads the leaves on the host, so they
+    are fetched here, once.
     """
     return build_fault_timeline(
         topo, horizon, config.seed if seed is None else seed,
@@ -469,7 +499,7 @@ def timeline_for_config(config, topo: Topology, horizon: int,
         ),
         mttf=config.mttf, mttr=config.mttr,
         participation_rate=config.participation_rate,
-    )
+    ).host()
 
 
 def build_fault_timeline(
@@ -484,7 +514,9 @@ def build_fault_timeline(
     mttr: float = 0.0,
     participation_rate: float = 1.0,
 ) -> FaultTimeline:
-    """Unroll the per-edge / per-node fault chains into host arrays.
+    """Unroll the per-edge / per-node fault chains into ``[horizon, ·]``
+    bool arrays: over a neighbor table the device arrays the scans return,
+    over a dense adjacency host arrays (``FaultTimeline``).
 
     The uniform draw at iteration t is the SAME counter-based float32 draw
     the on-the-fly samplers consume (same key derivation, same shape), so
@@ -525,7 +557,9 @@ def build_fault_timeline(
     n = topo.n
     fault_key = jax.random.fold_in(jax.random.key(seed), 0x0FA17)
     node_key = jax.random.fold_in(jax.random.key(seed), 0x57A66)
-    ts = jnp.arange(horizon, dtype=jnp.int32)
+    # Over a neighbor table the leaves stay the device arrays the scans
+    # return; over a dense adjacency they are fetched, as ever.
+    keep = (lambda leaf: leaf) if topo.is_matrix_free else np.asarray
 
     edge_index = None
     edge_up = None
@@ -541,6 +575,7 @@ def build_fault_timeline(
             t_enter = np.float32(p / burst_len)            # P(down | up)
             t_stay = np.float32(1.0 - (1.0 - p) / burst_len)  # P(down|down)
             t_init = np.float32(p)                          # stationary
+        n_edges = edge_index.shape[0]
 
         if topo.is_matrix_free:
             # Matrix-free edge chains (ISSUE-9 satellite): draw ONE
@@ -550,13 +585,10 @@ def build_fault_timeline(
             # is a different (equally seed-pure) realization of the same
             # chain; dense-vs-matrix-free parity tests inject one shared
             # timeline rather than relying on shared draws.
-            n_edges = edge_index.shape[0]
-
-            def edge_draw(t):
-                return jax.random.uniform(
-                    jax.random.fold_in(fault_key, t), (n_edges,),
-                    dtype=jnp.float32,
-                )
+            ups = _unroll_chain(
+                fault_key, t_init, t_enter, t_stay,
+                size=n_edges, horizon=horizon,
+            )
         else:
             ei = jnp.asarray(edge_index[:, 0])
             ej = jnp.asarray(edge_index[:, 1])
@@ -570,18 +602,10 @@ def build_fault_timeline(
                     dtype=jnp.float32,
                 )[ei, ej]
 
-        def edge_step(up_prev, t):
-            u = edge_draw(t)
-            thresh = jnp.where(
-                t == 0, t_init, jnp.where(up_prev, t_enter, t_stay)
+            ups = _chain_scan(
+                edge_draw, t_init, t_enter, t_stay, n_edges, horizon
             )
-            up = u >= thresh
-            return up, up
-
-        _, ups = jax.lax.scan(
-            edge_step, jnp.ones(edge_index.shape[0], dtype=bool), ts
-        )
-        edge_up = np.asarray(ups)
+        edge_up = keep(ups)
 
     node_up = None
     rejoin = None
@@ -592,23 +616,11 @@ def build_fault_timeline(
             n_init = np.float32(mttr / (mttf + mttr))  # stationary downtime
         else:
             n_crash = n_stay = n_init = np.float32(straggler_prob)
-
-        def node_step(up_prev, t):
-            u = jax.random.uniform(
-                jax.random.fold_in(node_key, t), (n,), dtype=jnp.float32
-            )
-            thresh = jnp.where(
-                t == 0, n_init, jnp.where(up_prev, n_crash, n_stay)
-            )
-            up = u >= thresh
-            return up, up
-
-        _, nups = jax.lax.scan(node_step, jnp.ones(n, dtype=bool), ts)
-        node_up = np.asarray(nups)
-        prev_up = np.concatenate(
-            [np.ones((1, n), dtype=bool), node_up[:-1]], axis=0
+        node_up = _unroll_chain(
+            node_key, n_init, n_crash, n_stay, size=n, horizon=horizon
         )
-        rejoin = node_up & ~prev_up
+        rejoin = keep(_rejoin_rounds(node_up))
+        node_up = keep(node_up)
 
     part_up = None
     if participation_rate < 1.0:
@@ -616,18 +628,13 @@ def build_fault_timeline(
         # configured rate, from its OWN counter-based stream — distinct
         # from the churn/straggler chain, so participation composes with
         # (never perturbs) every other fault realization. Survival
-        # convention matches the node chain: in iff u >= 1 − rate.
+        # convention matches the node chain: in iff u >= 1 − rate, whatever
+        # the round before (a chain whose three thresholds are one).
         part_key = jax.random.fold_in(jax.random.key(seed), 0x9AC70)
         p_out = np.float32(1.0 - participation_rate)
-
-        def part_step(_, t):
-            u = jax.random.uniform(
-                jax.random.fold_in(part_key, t), (n,), dtype=jnp.float32
-            )
-            return None, u >= p_out
-
-        _, pups = jax.lax.scan(part_step, None, ts)
-        part_up = np.asarray(pups)
+        part_up = keep(_unroll_chain(
+            part_key, p_out, p_out, p_out, size=n, horizon=horizon
+        ))
 
     return FaultTimeline(
         horizon=horizon,
@@ -640,6 +647,85 @@ def build_fault_timeline(
     )
 
 
+def _chain_scan(draw, t_init, t_enter, t_stay, size: int, horizon: int):
+    """``[horizon, size]`` bool: a two-state chain a column, unrolled.
+    Column c is up in round t iff ``draw(t)[c] >= threshold``, the
+    threshold ``t_init`` in round 0 and after it ``t_enter`` where the
+    column was up the round before, ``t_stay`` where it was down."""
+
+    def step(up_prev, t):
+        thresh = jnp.where(
+            t == 0, t_init, jnp.where(up_prev, t_enter, t_stay)
+        )
+        up = draw(t) >= thresh
+        return up, up
+
+    _, ups = jax.lax.scan(
+        step, jnp.ones(size, dtype=bool),
+        jnp.arange(horizon, dtype=jnp.int32),
+    )
+    return ups
+
+
+@functools.partial(jax.jit, static_argnames=("size", "horizon"))
+def _unroll_chain(key, t_init, t_enter, t_stay, *, size: int, horizon: int):
+    """``_chain_scan`` over one float32 uniform a column a round from
+    ``fold_in(key, t)``: every node process, and a neighbor table's edges.
+    One executable a (size, horizon) for the process's life: the key and
+    the thresholds are arguments, so a later call traces and compiles
+    nothing."""
+    return _chain_scan(
+        lambda t: jax.random.uniform(
+            jax.random.fold_in(key, t), (size,), dtype=jnp.float32
+        ),
+        t_init, t_enter, t_stay, size, horizon,
+    )
+
+
+@jax.jit
+def _rejoin_rounds(node_up):
+    """``[horizon, N]`` bool: up in round t and down in t − 1, round 0
+    held against every node up."""
+    prev_up = jnp.concatenate(
+        [jnp.ones_like(node_up[:1]), node_up[:-1]], axis=0
+    )
+    return node_up & ~prev_up
+
+
+def timeline_counters(timeline: FaultTimeline) -> dict:
+    """What a call's ``dopt.run`` root says of the timeline it read:
+    ``timeline_placement`` (``device`` where the leaves are the arrays the
+    chains' scans returned, ``host`` where they were fetched, injected or
+    stacked) and, under a node process, ``down_share`` (the mean of
+    ``1 − node_up`` over the horizon) and ``rejoin_rows`` (the set bits of
+    ``rejoin``: the rows a ``neighbor_restart`` run is asked to restart).
+    Counted where the leaves live; what is fetched is a count a node."""
+    leaves = [
+        getattr(timeline, name) for name in TIMELINE_LEAVES
+        if getattr(timeline, name) is not None
+    ]
+    out = {
+        "timeline_placement": (
+            "device" if leaves and all(
+                isinstance(leaf, jax.Array) for leaf in leaves
+            ) else "host"
+        ),
+    }
+    if timeline.node_up is not None:
+        up = _set_bits(timeline.node_up)
+        out["down_share"] = 1.0 - up / timeline.node_up.size
+        out["rejoin_rows"] = _set_bits(timeline.rejoin)
+    return out
+
+
+def _set_bits(leaf) -> int:
+    """The set bits of a ``[horizon, N]`` bool leaf: summed over the rounds
+    where the leaf lives (an int32 a node holds any horizon), over the
+    nodes on the host."""
+    xp = jnp if isinstance(leaf, jax.Array) else np
+    return int(np.asarray(xp.sum(leaf, axis=0, dtype=np.int32)).sum())
+
+
 # --- availability / staleness diagnostics (host-side, over a timeline) ----
 
 
@@ -647,7 +733,7 @@ def node_downtime(timeline: FaultTimeline) -> np.ndarray:
     """Per-node fraction of rounds spent down over the timeline horizon."""
     if timeline.node_up is None:
         raise ValueError("timeline has no node fault process")
-    return 1.0 - timeline.node_up.mean(axis=0)
+    return 1.0 - np.asarray(timeline.node_up).mean(axis=0)
 
 
 def outage_stats(timeline: FaultTimeline) -> dict:
@@ -656,9 +742,10 @@ def outage_stats(timeline: FaultTimeline) -> dict:
     if timeline.node_up is None:
         raise ValueError("timeline has no node fault process")
     lengths: list[int] = []
-    for i in range(timeline.node_up.shape[1]):
+    node_up = np.asarray(timeline.node_up)
+    for i in range(node_up.shape[1]):
         run = 0
-        for up in timeline.node_up[:, i]:
+        for up in node_up[:, i]:
             if not up:
                 run += 1
             elif run:
@@ -678,6 +765,7 @@ def _realized_edge_alive(
 ) -> tuple[np.ndarray, np.ndarray]:
     """([T, E] bool alive-mask, [E, 2] edge list) of per-round realized
     edges: an edge is alive iff its link is up AND both endpoints are up."""
+    timeline = timeline.host()
     edges = (
         timeline.edge_index
         if timeline.edge_index is not None
@@ -797,7 +885,9 @@ def stack_fault_timelines(timelines: list[FaultTimeline]) -> FaultTimeline:
 
     def _stack(field):
         vals = [getattr(t, field) for t in timelines]
-        return np.stack(vals) if vals[0] is not None else None
+        if vals[0] is None:
+            return None
+        return np.stack([np.asarray(v) for v in vals])
 
     return FaultTimeline(
         horizon=t0.horizon,
@@ -1234,8 +1324,9 @@ def _matrix_free_bits(
     Decided by ``timeline``: given one (persistent processes, the
     replica-batched stacker, an injected realization) round t reads its
     rows, and ``leaves`` holds them (``edge_up``, ``node_up``, ``part_up``,
-    ``rejoin``: host arrays or traced slices, to be handed to the program
-    with the form's own tables); given none (memoryless faults) round t
+    ``rejoin``: the timeline's own arrays, on the device where
+    ``build_fault_timeline`` left them there, else host arrays or traced
+    slices; handed to the program with the form's own tables); given none (memoryless faults) round t
     DRAWS them from ``fold_in(fault_key, t)`` / ``fold_in(node_key, t)`` —
     ``build_fault_timeline``'s own keys, shapes and float32 comparison, so
     the two realize one graph bit for bit and ``leaves`` is empty.
@@ -1305,6 +1396,15 @@ def _matrix_free_bits(
         return edge_up, active
 
     return leaves, edge_index, bits
+
+
+def _rebindable(bind, tables) -> FaultyMixing:
+    """``bind(tables)`` with ``bind`` as its ``bind`` field. The field is
+    set from outside the closure: a ``bind`` that named itself would be a
+    reference cycle through its own cell, and the ``[horizon, ·]`` leaves it
+    closes over (0.79 GB on the device at the churn cell's size) would
+    outlive their call until the garbage collector's next full pass."""
+    return dataclasses.replace(bind(tables), bind=bind)
 
 
 def _make_gather_faulty_mixing(
@@ -1474,11 +1574,10 @@ def _make_gather_faulty_mixing(
             participation_active=participation_active,
             timeline=timeline,
             tables=tables,
-            bind=bind,
             addressing="gather",
         )
 
-    return bind(tables)
+    return _rebindable(bind, tables)
 
 
 def _make_shift_faulty_mixing(
@@ -1633,11 +1732,10 @@ def _make_shift_faulty_mixing(
             participation_active=participation_active,
             timeline=timeline,
             tables=tables,
-            bind=bind,
             addressing="shift",
         )
 
-    return bind(tables)
+    return _rebindable(bind, tables)
 
 
 def make_halo_faulty_mixing(

@@ -327,3 +327,25 @@ def test_one_slot_over_the_networks_width_still_sorts(one_chip):
     (sort,) = [ins for ins in map(device_scopes._instruction, text.splitlines())
                if ins is not None and ins[2] == "sort"]
     assert f"f32[262144,{k + 1},81]" in sort[1], sort[1]
+
+
+def test_a_timelines_chain_is_one_scan_and_its_leaf_a_byte_a_bit(one_chip):
+    """The churn cell's chains at its size (ISSUE 46): one scan of 1,000
+    rounds over 2^18 columns leaves ``pred[1000, 262144]``, 262,144,000 B in
+    the chip's tiles (a byte a bit, no padding: what ``faults.state_bytes``
+    counts three of), with no second ``[T, N]`` array among its temporaries;
+    and ``rejoin`` is one pass over ``node_up`` with no temporary at all."""
+    from distributed_optimization_tpu.parallel import faults
+
+    n, horizon = 1 << 18, 1000
+    key = jax.eval_shape(lambda: jax.random.fold_in(jax.random.key(0), 1))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    chain = faults._unroll_chain.lower(key, f32, f32, f32, size=n, horizon=horizon).compile()
+    memory = chain.memory_analysis()
+    assert memory.output_size_in_bytes == horizon * n
+    assert memory.temp_size_in_bytes < 4 << 20
+    assert "pred[1000,262144]" in chain.as_text()
+    up = jax.ShapeDtypeStruct((horizon, n), jnp.bool_, sharding=one_chip)
+    back = faults._rejoin_rounds.lower(up).compile().memory_analysis()
+    assert (back.output_size_in_bytes, back.temp_size_in_bytes) == (horizon * n, 0)
