@@ -24,7 +24,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -340,7 +340,20 @@ class _StepPieces:
     mesh: object = None
 
 
-def _forward_is_carried(algo, problem, config, faulty, *, rows, batch_size,
+class _Forward(NamedTuple):
+    """What a trip's eval leaves the next trip's first gradient, in the
+    program's own carry: ``product``, the margins X·x (``carried``) or the
+    gradient itself (``fused``), and ``of``, the models it was taken at
+    where they are not the state's own: under ``neighbor_restart`` the
+    stepped models with the rows that rejoin NEXT round already restarted,
+    made once, by the eval, and taken over by the step. None (no leaf)
+    everywhere else: the product is of ``state["x"]``."""
+
+    product: jax.Array
+    of: Optional[jax.Array] = None
+
+
+def _forward_is_carried(algo, problem, config, *, rows, batch_size,
                         sampling_impl, scheduled, collect_metrics):
     """Whether the scan carries the margins ``z = X·x`` from a trip's eval to
     the next trip's first gradient (two reads of the shard stack an
@@ -351,14 +364,14 @@ def _forward_is_carried(algo, problem, config, faulty, *, rows, batch_size,
     injected batches); metrics collected (the eval makes the paired pass); a
     rule whose first gradient is at the carried models
     (``Algorithm.first_grad_at_x``; its compressed branch is left as it
-    was); no restart of x at a rejoin. Anything else recomputes, its program
-    unchanged."""
+    was). Anything else recomputes, its program unchanged. A restart of x at
+    a rejoin decides nothing here: the product is then of the restarted
+    models (``_Forward.of``)."""
     return bool(
         problem.link is not None
         and algo.first_grad_at_x and config.compression == "none"
         and collect_metrics and not scheduled
         and (batch_size >= rows or sampling_impl == "dense")
-        and (faulty is None or faulty.rejoin_restart is None)
     )
 
 
@@ -387,12 +400,14 @@ def _make_step_eval(p: _StepPieces, data):
     """Bind the step/eval/floats closures to the data pytree passed through
     jit (shared by the sequential and replica-batched paths — see
     ``_StepPieces``). Where ``p.forward`` is not ``recomputed``,
-    ``step(state, t, fwd)`` takes the forward product of ``state["x"]`` (its
-    margins X·x under ``carried``; under ``fused`` its gradient at
-    iteration t's batch, less ``λx``), ``eval_metrics`` returns the one of
-    the state it was shown (``(rows, fwd)``; ``fused``: for iteration
-    ``t_last + 1``) and ``init_forward(state, t0)`` makes the one a scan
-    starts from; otherwise fwd is None throughout."""
+    ``step(state, t, fwd)`` takes the forward product (a ``_Forward``) of
+    the models iteration t differentiates at: ``state["x"]`` (its margins
+    X·x under ``carried``; under ``fused`` its gradient at iteration t's
+    batch, less ``λx``) or, where rejoining rows are restarted before the
+    step, ``fwd.of``; ``eval_metrics`` returns the one of the state it was
+    shown (``(rows, fwd)``, for iteration ``t_last + 1``) and
+    ``init_forward(state, t0)`` makes the one a scan starts from; otherwise
+    fwd is None throughout."""
     X, y, n_valid = data["X"], data["y"], data["n_valid"]
     schedule = data.get("schedule")
     batch_size = p.batch_size
@@ -406,6 +421,7 @@ def _make_step_eval(p: _StepPieces, data):
     if "mixing" in data:
         # The gather mixing likewise (ISSUE 36).
         mix_op = mix_op.bind(data["mixing"])
+    restarts = faulty is not None and faulty.rejoin_restart is not None
 
     # Full-batch fast path: sampling b >= L rows without replacement IS
     # the whole shard with 1/n_i weights (the reference's b=min(b, n_i)
@@ -444,6 +460,16 @@ def _make_step_eval(p: _StepPieces, data):
             batch_size,
         ).astype(X.dtype)
 
+    @device_scopes.scope("faults")
+    def restarted(t, x):
+        """neighbor_restart rejoin policy: the models as iteration t's step
+        takes them. A node coming back from an outage at t replaces its
+        stale model row with the realized-neighborhood average (auxiliary
+        leaves stay frozen-stale — only the model is warm-restarted); the
+        restarted value is what it differentiates at and gossips that
+        round. A function of x and of row t of the timeline's leaves."""
+        return faulty.rejoin_restart(t, x)
+
     def shard_visit(x, xbar, t_next):
         """ONE read of the shards: the gradient (less ``λx``) of iteration
         ``t_next`` at x and each worker's sum of losses at x̄ over its real
@@ -466,6 +492,23 @@ def _make_step_eval(p: _StepPieces, data):
         with device_scopes.scope("gradient"):
             return visit(X, y, x, xbar, wts, n_valid)
 
+    def forward_pass(x, xbar, t_next):
+        """ONE pass over X for both of its readers: ``(fwd, at_xbar)``, fwd
+        the product iteration ``t_next``'s first gradient takes, at the
+        models it will really be taken at (x, or x with the rows that
+        rejoin at ``t_next`` restarted a trip early: ``fwd.of``), and x̄'s
+        half (each worker's sum of losses under ``fused``, the margins X·x̄
+        under ``carried``), which knows of no restart. The horizon's last
+        trip asks the restart for row T of leaves that have T rows: a traced
+        ``[t]`` clamps to the last row, and that product is dropped at the
+        scan's end, read by no row of the history."""
+        x_at = restarted(t_next, x) if restarts else x
+        product, at_xbar = (
+            shard_visit(x_at, xbar, t_next) if p.forward == "fused"
+            else paired_margins(X, x_at, xbar)
+        )
+        return _Forward(product, x_at if restarts else None), at_xbar
+
     def full_objective(x, xbar, t_next):
         """``(f(x̄) over the full data, fwd)``; both from ONE pass over X
         where a forward product is carried: the margins ``X·x``
@@ -473,20 +516,19 @@ def _make_step_eval(p: _StepPieces, data):
         (``fused``). Else fwd is None."""
         if p.forward == "recomputed":
             return p.full_objective(xbar, X, y, n_valid), None
+        fwd, at_xbar = forward_pass(x, xbar, t_next)
         if p.forward == "fused":
-            fwd, per_worker = shard_visit(x, xbar, t_next)
-            data_loss = jnp.sum(per_worker) / total_rows
+            data_loss = jnp.sum(at_xbar) / total_rows
         else:
-            fwd, zbar = paired_margins(X, x, xbar)
             data_loss = jnp.sum(
-                jnp.sum(eval_wts * link.loss(zbar, y), axis=1)
+                jnp.sum(eval_wts * link.loss(at_xbar, y), axis=1)
             )
         return data_loss + 0.5 * p.reg * sq_norm(xbar), fwd
 
     def grad_fn_factory(t, fwd=None, fwd_of=None):
         """The iteration's ``ctx.grad``; ``fwd`` is the forward product of
-        ``fwd_of`` as the scan carried it, used where the rule asks at that
-        very array, slot 0."""
+        ``fwd_of`` as the scan carried it (a ``_Forward``), used where the
+        rule asks at that very array, slot 0."""
         def grad(params, slot):
             with device_scopes.scope("sampling"):
                 if schedule is not None:
@@ -518,10 +560,10 @@ def _make_step_eval(p: _StepPieces, data):
             with device_scopes.scope("gradient"):
                 if fwd is not None and params is fwd_of and slot == 0:
                     if p.forward == "fused":
-                        return fwd + p.reg * params
+                        return fwd.product + p.reg * params
                     return jax.vmap(
                         link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
-                    )(fwd, params, Xb, yb, wts, p.reg)
+                    )(fwd.product, params, Xb, yb, wts, p.reg)
                 return jax.vmap(
                     p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
                 )(params, Xb, yb, wts, p.reg)
@@ -529,18 +571,14 @@ def _make_step_eval(p: _StepPieces, data):
         return grad
 
     def step(state, t, fwd=None):
+        if restarts:
+            # BEFORE the step at the rejoin round; where the last trip's
+            # eval made the restarted models for its product, those.
+            state = {
+                **state,
+                "x": fwd.of if fwd is not None else restarted(t, state["x"]),
+            }
         fwd_of = state["x"]  # the array the carried forward product is of
-        if faulty is not None and faulty.rejoin_restart is not None:
-            # neighbor_restart rejoin policy: BEFORE the step at the
-            # rejoin round, a node coming back from an outage replaces
-            # its stale model row with the realized-neighborhood
-            # average (auxiliary leaves stay frozen-stale — only the
-            # model is warm-restarted). The restarted value is what it
-            # gossips this round.
-            with device_scopes.scope("faults"):
-                state = {
-                    **state, "x": faulty.rejoin_restart(t, state["x"])
-                }
         if faulty is not None:
             mix_fn = lambda v: faulty.mix(t, v)  # noqa: E731
             nbr_fn = lambda v: faulty.neighbor_sum(t, v)  # noqa: E731
@@ -719,12 +757,10 @@ def _make_step_eval(p: _StepPieces, data):
         if p.forward == "recomputed":
             return None
         x = state["x"]
-        xbar = jnp.mean(x, axis=0)
-        pair = (
-            shard_visit(x, xbar, t0) if p.forward == "fused"
-            else paired_margins(X, x, xbar)
-        )
-        return jax.lax.optimization_barrier(pair)[0]
+        fwd, at_xbar = forward_pass(x, jnp.mean(x, axis=0), t0)
+        return fwd._replace(product=jax.lax.optimization_barrier(
+            (fwd.product, at_xbar)
+        )[0])
 
     @device_scopes.scope("faults")
     def floats_for(ts):
@@ -1990,7 +2026,7 @@ def _run(
     # What the eval's pass over the shards leaves the next trip's first
     # gradient (the engagement counter of both mechanisms).
     carried = _forward_is_carried(
-        algo, problem, config, faulty, rows=device_data.X.shape[1],
+        algo, problem, config, rows=device_data.X.shape[1],
         batch_size=batch_size, sampling_impl=sampling_impl,
         scheduled=schedule is not None, collect_metrics=collect_metrics,
     )
@@ -2012,6 +2048,13 @@ def _run(
         forward=forward, mesh=mesh,
     )
     spans.note_root(forward=forward)
+    if (
+        forward != "recomputed" and faulty is not None
+        and faulty.rejoin_restart is not None
+    ):
+        # The product is of the models as the NEXT round's restart leaves
+        # them (``_Forward.of``); absent on every call without a restart.
+        spans.note_root(forward_of="restarted")
 
     n_evals = T // eval_every
     measure_timestamps = bool(measure_timestamps)
@@ -2045,10 +2088,11 @@ def _run(
             )
 
             # The carry is (state, fwd): the forward product of the carried
-            # models (their margins X·x, or the next gradient itself) where
-            # one is carried, else None (no leaf: the program is the state's
-            # alone). It is the program's, not the state's contract: made
-            # here, dropped at the end.
+            # models (their margins X·x, or the next gradient itself; a
+            # ``_Forward``, which under ``neighbor_restart`` also holds the
+            # restarted models it is of) where one is carried, else None (no
+            # leaf: the program is the state's alone). It is the program's,
+            # not the state's contract: made here, dropped at the end.
             def microchunk(carry, ts_row):
                 state, fwd = carry
                 for j in range(micro):

@@ -30,6 +30,7 @@ from benchmark import compare, datasets, program  # noqa: E402
 from benchmark import run as harness  # noqa: E402
 from benchmark.reference import dsgd_ring_churn  # noqa: E402
 
+from distributed_optimization_tpu.backends import jax_backend  # noqa: E402
 from distributed_optimization_tpu.observability.spans import Tracer  # noqa: E402
 from distributed_optimization_tpu.parallel import build_topology, faults  # noqa: E402
 from distributed_optimization_tpu.parallel.mesh import replicate  # noqa: E402
@@ -60,9 +61,10 @@ def at(config, churn):
     return dict(config, experiment=dict(config["experiment"], **CHURN[churn]))
 
 
-def run_program(config, traffic, seed):
+def run_program(config, traffic, seed, **replace):
     X, y, L = datasets.make(config, seed)
     cfg, dataset = program.build(config, traffic, X, y, L, program.seed_for(seed))
+    cfg = cfg.replace(**replace)
     tracer = Tracer()
     with tracer.activate():
         result = program.run_experiment(cfg, dataset)
@@ -101,16 +103,30 @@ def host_rounds(cfg):
     }
 
 
-@pytest.mark.parametrize("seed", SEEDS)
-@pytest.mark.parametrize("churn", sorted(CHURN))
-def test_the_program_is_within_the_cells_limits(cell, churn, seed):
+# ``forward``: the dense sampler the chip takes (since ISSUE 47 the margins
+# carried from the eval, taken at the models the NEXT round's restart leaves),
+# what the CPU's auto takes (the gather sampler: recomputed), and the chip's
+# own form, the shard visit, interpreted.
+@pytest.mark.parametrize("churn,seed,forward", [
+    (churn, seed, "carried") for churn in sorted(CHURN) for seed in SEEDS
+] + [("cell", SEEDS[0], "recomputed"), ("fast", SEEDS[1], "recomputed"),
+     ("cell", SEEDS[1], "fused"), ("fast", SEEDS[0], "fused")])
+def test_the_program_is_within_the_cells_limits(cell, churn, seed, forward, monkeypatch):
     config, traffic = at(cell[0], churn), cell[1]
-    result, args, children, cfg, (X, y, pseed) = run_program(config, traffic, seed)
+    replace = {}
+    if forward != "recomputed":
+        replace["sampling_impl"] = "dense"
+    if forward == "fused":
+        monkeypatch.setattr(jax_backend, "_visit_is_fused", lambda carried, X: carried)
+        monkeypatch.setenv("DOPT_EXEC_CACHE", "0")
+    result, args, children, cfg, (X, y, pseed) = run_program(
+        config, traffic, seed, **replace)
     want = CHURN[churn]
     assert args["faults"] == f"edge_drop:0.3,burst:4,mttf:{want['mttf']:g},mttr:{want['mttr']:g}"
     assert args["fault_chains"] == f"burst:0.3x4,churn:{want['mttf']:g}/{want['mttr']:g}"
     assert (args["fault_form"], args["fault_mixing"]) == ("timeline", "shift")
-    assert (args["rejoin"], args["forward"]) == ("neighbor_restart", "recomputed")
+    assert (args["rejoin"], args["forward"]) == ("neighbor_restart", forward)
+    assert args.get("forward_of") == (None if forward == "recomputed" else "restarted")
     assert args["timeline_placement"] == "device"
     assert args["fault_bytes"] == 3 * T * N  # edge_up, node_up, rejoin: a byte a bit
     assert "dopt.run.faults" in children

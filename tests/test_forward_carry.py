@@ -65,13 +65,16 @@ def recomputed(monkeypatch):
     )
 
 
-def assert_carried_is_recomputed(cfg, monkeypatch, ulps=ULPS):
+def assert_carried_is_recomputed(cfg, monkeypatch, ulps=ULPS, forward_of=None):
+    """``forward_of``: what the root says the carried product is of, where
+    that is not the state's own models (``restarted``); nothing else."""
     ds = generate_synthetic_dataset(cfg)
     got, root = run_rooted(cfg, ds)
     assert root["forward"] == "carried"
+    assert root.get("forward_of") == forward_of
     recomputed(monkeypatch)
     want, root = run_rooted(cfg, ds)
-    assert root["forward"] == "recomputed"
+    assert root["forward"] == "recomputed" and "forward_of" not in root
     want32 = lambda a: np.asarray(a, dtype=np.float32)  # noqa: E731
     assert_ulps_of_scale(got.history.objective, want32(want.history.objective), ulps)
     assert_ulps_of_scale(
@@ -118,12 +121,54 @@ def test_only_the_trips_first_gradient_is_carried(kw, monkeypatch):
     )
 
 
-def test_restart_at_rejoin_recomputes():
-    """neighbor_restart rewrites x before the step: nothing is carried."""
-    cfg = glm_cfg(problem_type="logistic", mttf=12.0, mttr=4.0,
-                  rejoin="neighbor_restart")
+# --- neighbor_restart: the product is of the RESTARTED models (ISSUE 47) ----
+#
+# The step of round t + 1 replaces the rejoining rows before it differentiates,
+# so the eval of trip t restarts them a trip early (row t + 1 of the
+# timeline's leaves, which the program holds), takes the product at those
+# models and the objective at the unrestarted mean, and hands both on. The
+# fault layer's three forms give the one ``rejoin_restart(t, x)``: the ring's
+# shifts, any other neighbor table's gather, a dense adjacency.
+
+SHIFT = dict(topology_impl="neighbor")  # the 8-ring's table IS a ring's
+RESTARTS = {
+    # (config, the root's fault_mixing)
+    "shift_mttf12": (dict(mttf=12.0, mttr=4.0, **SHIFT), "shift"),
+    "shift_mttf6": (dict(mttf=6.0, mttr=3.0, **SHIFT), "shift"),
+    "shift_bursts": (
+        dict(mttf=12.0, mttr=4.0, edge_drop_prob=0.3, burst_len=4.0, **SHIFT),
+        "shift"),
+    "gather_torus": (
+        dict(mttf=12.0, mttr=4.0, topology="grid", n_workers=16,
+             n_samples=800, **SHIFT), "gather"),
+    "gather_chain_bursts": (
+        dict(mttf=6.0, mttr=3.0, edge_drop_prob=0.3, burst_len=4.0,
+             topology="chain", **SHIFT), "gather"),
+    "dense_adjacency": (dict(mttf=12.0, mttr=4.0), None),
+    # later steps of a trip restart and read the shards for themselves
+    "micro3_two_trips": (
+        dict(mttf=6.0, mttr=3.0, eval_every=6, scan_unroll=4, **SHIFT),
+        "shift"),
+    "local_steps3": (dict(mttf=6.0, mttr=3.0, local_steps=3, **SHIFT), "shift"),
+    "full_batch_quadratic": (
+        dict(mttf=6.0, mttr=3.0, problem_type="quadratic",
+             sampling_impl="auto", local_batch_size=64, **SHIFT), "shift"),
+}
+
+
+def restart_cfg(**kw):
+    kw.setdefault("problem_type", "logistic")
+    return glm_cfg(rejoin="neighbor_restart", **kw)
+
+
+@pytest.mark.parametrize("case", sorted(RESTARTS))
+def test_under_restart_the_carried_run_is_the_recomputed_run(case, monkeypatch):
+    kw, addressing = RESTARTS[case]
+    cfg = restart_cfg(**kw)
     _, root = run_rooted(cfg, generate_synthetic_dataset(cfg))
-    assert root["forward"] == "recomputed"
+    assert root["rejoin"] == "neighbor_restart" and root["rejoin_rows"] > 0
+    assert root.get("fault_mixing") == addressing
+    assert_carried_is_recomputed(cfg, monkeypatch, forward_of="restarted")
 
 
 BYPASS = {
@@ -280,14 +325,20 @@ def test_gradient_at_the_margins_is_the_gradient(family):
 # --- one program, replayed or split: bitwise -------------------------------
 
 
-@pytest.fixture(scope="module")
-def whole_run():
-    # Micro-chunks of 3 steps, two trips an eval, stragglers frozen.
-    cfg = glm_cfg(problem_type="logistic", eval_every=6, scan_unroll=4,
-                  straggler_prob=0.2)
+@pytest.fixture(scope="module", params=["stragglers", "restarted"])
+def whole_run(request):
+    # Micro-chunks of 3 steps, two trips an eval; stragglers frozen, or a
+    # rejoin most rounds under neighbor_restart (ISSUE 47).
+    kw = dict(problem_type="logistic", eval_every=6, scan_unroll=4)
+    if request.param == "stragglers":
+        cfg, forward_of = glm_cfg(straggler_prob=0.2, **kw), None
+    else:
+        cfg = restart_cfg(mttf=6.0, mttr=3.0, **kw, **SHIFT)
+        forward_of = "restarted"
     ds = generate_synthetic_dataset(cfg)
-    whole, root = run_rooted(cfg, ds)
+    whole, root = run_rooted(cfg, ds, return_state=True)
     assert root["forward"] == "carried" and root["path"] == "fused"
+    assert root.get("forward_of") == forward_of
     return cfg, ds, whole
 
 
@@ -301,7 +352,10 @@ def test_a_split_carried_run_is_bitwise_the_unsplit_run(
     """z is the program's, not the state's: every segment makes its z_0 from
     the state it is handed (a checkpoint's, after a resume) with the paired
     pass itself, so a run split at eval boundaries is the unsplit run to the
-    bit, as ``tests/test_segments.py`` holds the recomputed program to."""
+    bit, as ``tests/test_segments.py`` holds the recomputed program to. So
+    are the restarted models z is taken at: a segment hands on the
+    UNrestarted state and the next restarts at its own ``t0`` before its
+    first pass."""
     cfg, ds, whole = whole_run
     if form == "heartbeat":
         kw = {"progress_cb": lambda ev: None, "progress_every": size}
@@ -317,6 +371,8 @@ def test_a_split_carried_run_is_bitwise_the_unsplit_run(
     split, root = run_rooted(cfg, ds, **kw)
     assert root["forward"] == "carried"
     assert root["path"] == ("chunked" if form == "timed" else "segmented")
+    assert root.get("forward_of") == (
+        "restarted" if cfg.rejoin == "neighbor_restart" else None)
     np.testing.assert_array_equal(split.history.objective, whole.history.objective)
     np.testing.assert_array_equal(
         split.history.consensus_error, whole.history.consensus_error
@@ -336,12 +392,28 @@ def test_telemetry_leaves_the_carried_trajectory_alone():
     np.testing.assert_array_equal(on.history.objective, off.history.objective)
 
 
-def test_the_state_holds_no_margins():
-    cfg = glm_cfg(problem_type="logistic", n_iterations=10)
+def test_the_state_holds_no_margins(whole_run):
+    """Nor the restarted models they were taken at."""
+    assert sorted(whole_run[2].final_state) == ["x"]
+
+
+def test_the_horizons_last_trip_restarts_at_a_clamped_row():
+    """The last trip's eval asks the restart for row T of leaves that have T
+    rows: the index clamps and the product is dropped, so a run of T
+    iterations is the first T of a run of 2T over the same chains (a chain's
+    round t is a function of (key, t) and the round before), where row T is
+    really read."""
+    cfg = restart_cfg(mttf=6.0, mttr=3.0, **SHIFT)
     ds = generate_synthetic_dataset(cfg)
-    result, root = run_rooted(cfg, ds, return_state=True)
-    assert root["forward"] == "carried"
-    assert sorted(result.final_state) == ["x"]
+    short, root = run_rooted(cfg, ds)
+    twice, _ = run_rooted(cfg.replace(n_iterations=2 * cfg.n_iterations), ds)
+    assert root["forward_of"] == "restarted"
+    T = cfg.n_iterations
+    assert np.isfinite(short.history.objective).all()
+    np.testing.assert_array_equal(
+        short.history.objective, twice.history.objective[:T])
+    np.testing.assert_array_equal(
+        short.history.consensus_error, twice.history.consensus_error[:T])
 
 
 # --- fused: the carry is the next gradient (ISSUE 41) ----------------------
@@ -363,12 +435,14 @@ def fused(monkeypatch):
     )
 
 
-def assert_fused_is_carried_and_recomputed(cfg, monkeypatch, **run_kw):
+def assert_fused_is_carried_and_recomputed(
+    cfg, monkeypatch, forward_of=None, **run_kw
+):
     cfg = cfg.replace(dtype="float64")
     ds = generate_synthetic_dataset(cfg)
     fused(monkeypatch)
     got, root = run_rooted(cfg, ds, **run_kw)
-    assert root["forward"] == "fused"
+    assert root["forward"] == "fused" and root.get("forward_of") == forward_of
     monkeypatch.setattr(jax_backend, "_visit_is_fused", lambda *a: False)
     for want_form in ("carried", "recomputed"):
         if want_form == "recomputed":
@@ -407,6 +481,25 @@ def test_only_the_trips_first_gradient_is_the_carried_one(kw, monkeypatch):
     kw.setdefault("sampling_impl", "dense")
     assert_fused_is_carried_and_recomputed(
         small_backend_config(problem_type="logistic", **kw), monkeypatch
+    )
+
+
+@pytest.mark.parametrize("kw", [
+    dict(mttf=6.0, mttr=3.0, **SHIFT),
+    dict(mttf=12.0, mttr=4.0, edge_drop_prob=0.3, burst_len=4.0,
+         eval_every=6, scan_unroll=4, **SHIFT),
+    dict(mttf=6.0, mttr=3.0, topology="chain", **SHIFT),
+], ids=["shift", "shift_bursts_micro3", "gather_chain"])
+def test_under_restart_the_visit_takes_the_gradient_at_the_restarted_models(
+    kw, monkeypatch
+):
+    """The kernel's two model operands come apart: the gradient at the
+    restarted stack, the losses at the unrestarted mean."""
+    kw.setdefault("sampling_impl", "dense")
+    assert_fused_is_carried_and_recomputed(
+        small_backend_config(
+            problem_type="logistic", rejoin="neighbor_restart", **kw),
+        monkeypatch, forward_of="restarted",
     )
 
 

@@ -158,6 +158,29 @@ def test_the_root_says_which_forward_product_the_scan_carried(
     assert roots[-1]["args"]["forward"] == want
 
 
+@pytest.mark.parametrize("churn,sampling_impl,want", [
+    (dict(rejoin="neighbor_restart"), "dense", ("carried", "restarted")),
+    (dict(rejoin="frozen"), "dense", ("carried", None)),
+    (dict(rejoin="neighbor_restart"), "auto", ("recomputed", None)),
+    (dict(mttf=0.0, mttr=0.0), "dense", ("carried", None)),
+], ids=["restart", "frozen", "restart_recomputed", "no_churn"])
+def test_the_root_says_what_the_forward_product_is_of(churn, sampling_impl, want):
+    """``forward_of`` = ``restarted`` (ISSUE 47) beside ``forward`` on a call
+    whose carried product is taken at the models the NEXT round's
+    ``neighbor_restart`` leaves; on every other call, a recomputed one under
+    the same policy among them, the root does not hold the argument."""
+    cfg = small_backend_config(
+        problem_type="logistic", n_iterations=10, sampling_impl=sampling_impl,
+        **{"mttf": 6.0, "mttr": 3.0, **churn},
+    )
+    _, roots, _ = run_under(
+        Tracer(), cfg, generate_synthetic_dataset(cfg), executable_cache=False
+    )
+    args = roots[-1]["args"]
+    assert (args["forward"], args.get("forward_of")) == want
+    assert ("rejoin_rows" in args) == (churn.get("rejoin") == "neighbor_restart")
+
+
 @pytest.mark.parametrize("layout,dtype,stack", [
     ("consecutive", "float32", "view"), ("consecutive", "float64", "cast"),
     ("argsort", "float64", "gather"),

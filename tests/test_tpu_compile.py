@@ -3,7 +3,9 @@ compiler is installed here; nothing runs, so no time and no result comes out
 of this file). The one file that describes a topology: only the worker that
 is given it loads the TPU's library, inside a fixture, never at import."""
 
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -349,3 +351,107 @@ def test_a_timelines_chain_is_one_scan_and_its_leaf_a_byte_a_bit(one_chip):
     up = jax.ShapeDtypeStruct((horizon, n), jnp.bool_, sharding=one_chip)
     back = faults._rejoin_rounds.lower(up).compile().memory_analysis()
     assert (back.output_size_in_bytes, back.temp_size_in_bytes) == (horizon * n, 0)
+
+
+@pytest.fixture(scope="module")
+def churn_cell_scan(one_chip):
+    """The churn cell's whole scan as ``_run`` builds it (ISSUE 47: bursts,
+    churn and ``neighbor_restart`` on the ring's shift form, the dense
+    sampler, the visit fused as on the chip) at N = 2^18, L = 53, d = 81,
+    unroll 8, compiled for 1,000 trips with the three ``pred[1000, N]``
+    leaves as arguments. No shard, timeline or model of that size is made:
+    the call is cut where it hands its program to the driver, the program
+    lowered from shapes."""
+    from distributed_optimization_tpu.backends import jax_backend
+    from distributed_optimization_tpu.config import ExperimentConfig
+    from distributed_optimization_tpu.ops import pallas_kernels as pk
+    from distributed_optimization_tpu.utils.data import DeviceDataset, HostDataset
+
+    n, rows, d, horizon = 1 << 18, 53, 81, 1000
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "glm81_ring262k_burst4_churn400.json")) as fh:
+        experiment = json.load(fh)["experiment"]
+    assert (experiment["n_workers"], experiment["n_features"]) == (n, d - 1)
+    # what ``auto`` takes on the chip (a CPU's is the gather sampler, unroll 1)
+    cfg = ExperimentConfig(
+        **experiment, n_samples=n * rows, sampling_impl="dense", scan_unroll=8,
+        n_iterations=2, eval_every=1)
+
+    class Handed(Exception):
+        pass
+
+    def hand_over(make_seg_scan, trips_per_eval, state0, data_args, *a, **kw):
+        raise Handed(make_seg_scan, state0, data_args)
+
+    def zeros(*dims, dtype=np.float32):  # no memory behind them
+        return np.broadcast_to(np.zeros((), dtype), dims)
+
+    def shaped(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax_backend, "_drive_segments", hand_over)
+        patch.setattr(jax_backend, "stack_shards", lambda ds, dtype: DeviceDataset(
+            X=zeros(n, rows, d), y=zeros(n, rows), n_valid=np.full(n, rows, np.int32),
+            stacked_by="view"))
+        patch.setattr(jax_backend, "place_shards", lambda mesh, X: (shaped(X), "direct"))
+        patch.setattr(jax_backend, "shard_over_workers", lambda mesh, a: shaped(a))
+        patch.setattr(jax_backend, "_visit_is_fused", lambda carried, X: bool(carried))
+        patch.setattr(pk, "resolve_interpret", lambda *a, **kw: False)
+        nothing = HostDataset(X_full=zeros(1, d - 1), y_full=zeros(1), shard_indices=[],
+                              problem_type="logistic")
+        with pytest.raises(Handed) as handed:
+            jax_backend.run(cfg, nothing, 0.0, use_mesh=False, measure_compile=False)
+        make_seg_scan, state0, data = handed.value.args
+        data = jax.tree.map(shaped, data)
+        data["faults"] = {
+            k: jax.ShapeDtypeStruct((horizon, n), v.dtype, sharding=one_chip)
+            for k, v in data["faults"].items()}
+        return jax.jit(make_seg_scan(horizon)).lower(
+            jax.tree.map(shaped, state0),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), data).compile()
+
+
+def test_the_churn_cells_trip_visits_the_shards_once(churn_cell_scan):
+    """Under ``neighbor_restart`` the trip is the fused one (ISSUE 47): ONE
+    ``glm_shard_visit`` a trip (eight in the unrolled body, one in front of
+    the loop), no fusion, reduce or copy that reads or writes the
+    ``f32[262144,53,81]`` stack besides, and the restart's two row slices
+    made ONCE a trip (the restarted models ride in the carry: the step does
+    not make them again)."""
+    text = churn_cell_scan.as_text()
+    ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
+    visits = [ins for ins in ops if ins[2] == "custom-call" and ins[0].startswith("%glm_shard_visit")]
+    assert len(visits) == 9 and all("f32[81,262144]" in ins[1] for ins in visits)
+    # Whatever holds the stack (a parameter, the loop's element, a bitcast
+    # of either) is read by the visit and by nothing else: no reduce, fusion
+    # or copy of it. Names are a computation's own, so one at a time.
+    stack = re.compile(r"^f32\[(262144,53,81|81,53,262144)\]")
+    comps = device_scopes._computations(text)[0]
+    for lines in comps.values():
+        body = [ins for ins in map(device_scopes._instruction, lines) if ins is not None]
+        held = {ins[0] for ins in body if stack.match(ins[1])}
+        readers = {ins[2] for ins in body if held & set(ins[3])}
+        assert readers <= {"bitcast", "custom-call", "tuple", "while"}, readers
+    assert not [ins for ins in ops if stack.match(ins[1])
+                and ins[2] not in ("parameter", "get-tuple-element", "bitcast")]
+    slices = [ins for ins in ops if ins[2] == "fusion" and ins[1].startswith("(f32[262143,81]")]
+    restarts = [ins for ins in slices if "dopt.faults" in ins[4]]
+    assert len(restarts) == 9, len(restarts)  # a trip's ONE, and the first trip's in front of the loop
+    assert churn_cell_scan.memory_analysis().temp_size_in_bytes < 760_000_000
+
+
+def test_the_churn_cells_leaves_stay_arguments(churn_cell_scan):
+    """The three ``pred[1000, 262144]`` timeline leaves are parameters of the
+    entry computation; no constant of their size (262 MB each) is closed
+    into the executable."""
+    text = churn_cell_scan.as_text()
+    entry = text[text.index("ENTRY"):].split("\n", 1)[0]
+    assert entry.count("pred[1000,262144]") == 3
+    constants = [
+        device_scopes._shape_bytes(ins[1])
+        for ins in map(device_scopes._instruction, text.splitlines())
+        if ins is not None and ins[2] == "constant"]
+    assert max(constants) < 2**21, max(constants)
+    assert churn_cell_scan.memory_analysis().generated_code_size_in_bytes < 32 * 2**20
