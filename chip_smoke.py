@@ -277,7 +277,7 @@ def placement_segment(device: dict, *, n_workers: int = 65_536,
         (dict(min_tiled_bytes=0, block_bytes=32 << 20),
          "mesh4:flat:68688x1024/8", 1.5),
     ):
-        got, how = place_shards(mesh, X, **kw)
+        got, how, _ = place_shards(mesh, X, **kw)
         jax.block_until_ready(got)
         stats = [dev.memory_stats() for dev in mesh.devices.flat]
         peaks = [int(st["peak_bytes_in_use"]) for st in stats]

@@ -24,7 +24,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import NamedTuple, Optional
+from typing import Iterator, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -153,12 +153,12 @@ def _flat_rows(tree):
     """Host leaves as ``[rows, D]``: the flat contract of every boundary
     (``final_models``, ``final_state``, checkpoints), whatever parameter
     shape the scan carried (``Problem.param_shape``). A view where the leaf
-    is C-contiguous (every rank-2 leaf), else a copy (``_host_f64``)."""
+    is C-contiguous (every rank-2 leaf), else a copy (``_cast_f64``)."""
     return jax.tree.map(lambda a: a.reshape(a.shape[0], -1), tree)
 
 
-def _host_f64(x):
-    """A device leaf as the float64 ``[rows, D]`` array the results hold.
+def _cast_f64(a):
+    """A fetched leaf as the float64 ``[rows, D]`` array the results hold.
 
     The cast writes C order BEFORE the flatten: the TPU runtime hands a
     rank-3 array to the host in the device's own dimension order (the
@@ -166,7 +166,7 @@ def _host_f64(x):
     ``[4097, 96, 512]`` buffer — my chip run, PR 25), so flattening the
     fetched array first would copy the whole leaf once more, where the
     cast, which touches every element anyway, reorders for nothing."""
-    return _flat_rows(_fetch_to_host(x).astype(np.float64, order="C"))
+    return _flat_rows(a.astype(np.float64, order="C"))
 
 
 def _restored_state(state_np, like):
@@ -1134,22 +1134,32 @@ def _bind_byzantine(config, algo, topo, faulty, mix_op, *, clip_tau=None,
 
 def _on_cadence_rows(ys, n_seg_evals, trips_per_eval):
     """On-cadence rows of one segment's stacked outputs, on the host:
-    ``({"gap", "cons", "floats"} as recorded, trace buffers)``. The scan
+    ``({"gap", "cons", "floats"} as recorded, trace buffers, the bytes that
+    came down for them)``. The scan
     evaluates every trip, so only every ``trips_per_eval``-th row sits on
     an eval boundary: the others hold real evals the requested cadence
     discards, and the trace selects the same rows. Faults' realized floats
     are summed per eval."""
     sel = slice(trips_per_eval - 1, None, trips_per_eval)
+    down = 0
+
+    def host(a):
+        nonlocal down
+        a = np.asarray(a)
+        down += a.nbytes
+        return a
+
     rows = {
-        k: np.asarray(ys[k][sel], dtype=np.float64)
+        k: host(ys[k][sel]).astype(np.float64, copy=False)
         for k in ("gap", "cons") if k in ys
     }
     if "floats" in ys:
         rows["floats"] = (
-            np.asarray(ys["floats"], dtype=np.float64)
+            host(ys["floats"]).astype(np.float64, copy=False)
             .reshape(n_seg_evals, trips_per_eval).sum(axis=1)
         )
-    return rows, {k: np.asarray(v)[sel] for k, v in ys.get("trace", {}).items()}
+    trace = {k: host(v)[sel] for k, v in ys.get("trace", {}).items()}
+    return rows, trace, down
 
 
 def _drive_segments(
@@ -1295,15 +1305,27 @@ def _drive_segments(
         ))
 
     pending = []  # (ys, size) of the segments whose rows are on the device
+    # What ``fetch_rows`` brought down; once ``harvest`` has begun, the
+    # arguments of its ``rows`` part.
+    counted = {"bytes": 0}
+    rows_part = contextlib.ExitStack()
 
     def fetch_rows():
         for ys, size in pending:
-            rows, trace_seg = _on_cadence_rows(ys, size, trips_per_eval)
+            rows, trace_seg, down = _on_cadence_rows(ys, size, trips_per_eval)
+            counted["bytes"] += down
             for k, v in rows.items():
                 hist[k].extend(v.tolist())
             for k, v in trace_seg.items():
                 trace_lists.setdefault(k, []).append(v)
         pending.clear()
+
+    def enter_harvest():
+        """``harvest`` and its ``rows`` part, open until the driver
+        returns: the last boundary's rows, heartbeat and save."""
+        spans.enter("harvest")
+        counted["bytes"] = 0  # what came down inside the scan was the scan's
+        rows_part.enter_context(spans.part("rows"))["args"] = counted
 
     # The shards' copy drains here, after all host preparation and outside
     # the scan's clock: the program could not start before its inputs were
@@ -1316,64 +1338,65 @@ def _drive_segments(
     run_seconds = 0.0
     done = start_chunk
     last = False
-    for size, t0 in segments:
-        state, ys = compiled_by_size[size](state, t0, data_args)
-        state = jax.block_until_ready(state)
-        pending.append((ys, size))
-        done += size
-        last = done == n_evals
-        prev = time_offset + run_seconds
-        if last:
-            spans.enter("harvest")
-            run_seconds = scan["duration"] - save_seconds
-        else:
-            run_seconds = (
-                time.perf_counter() - scan["start"] - save_seconds
+    with rows_part:  # closes the ``rows`` part, on an exception too
+        for size, t0 in segments:
+            state, ys = compiled_by_size[size](state, t0, data_args)
+            state = jax.block_until_ready(state)
+            pending.append((ys, size))
+            done += size
+            last = done == n_evals
+            prev = time_offset + run_seconds
+            if last:
+                enter_harvest()
+                run_seconds = scan["duration"] - save_seconds
+            else:
+                run_seconds = (
+                    time.perf_counter() - scan["start"] - save_seconds
+                )
+            stamp = time_offset + run_seconds
+            stamps = np.linspace(prev + (stamp - prev) / size, stamp, size)
+            stamps[-1] = stamp
+            hist["time"].extend(stamps.tolist())
+            evals_here = done - start_chunk
+            beat = progress_hook is not None and (
+                last or evals_here % progress_every == 0
             )
-        stamp = time_offset + run_seconds
-        stamps = np.linspace(prev + (stamp - prev) / size, stamp, size)
-        stamps[-1] = stamp
-        hist["time"].extend(stamps.tolist())
-        evals_here = done - start_chunk
-        beat = progress_hook is not None and (
-            last or evals_here % progress_every == 0
-        )
-        save = ckptr is not None and (
-            last or evals_here % checkpoint.every_evals == 0
-        )
-        if beat or save:
-            fetch_rows()
-        if beat:
-            progress_hook(done, hist["gap"], hist["cons"], stamp)
-        if save:
-            t_save = time.perf_counter()
-            ckptr.save(
-                done, _flat_rows(_fetch_to_host(state)), hist["gap"],
-                hist["cons"], hist["floats"], hist["time"],
+            save = ckptr is not None and (
+                last or evals_here % checkpoint.every_evals == 0
             )
-            save_seconds += time.perf_counter() - t_save
-        if halt_check is not None and halt_check():
-            # Early-halt policy (ISSUE-13): a fatal anomaly fired on this
-            # boundary's heartbeat. The executed prefix is the full run's
-            # prefix; the remaining segments never execute.
-            break
-    if not last:
-        spans.enter("harvest")
-    fetch_rows()
-
-    return (
-        state,
-        np.asarray(hist["gap"], dtype=np.float64) if hist["gap"] else None,
-        np.asarray(hist["cons"], dtype=np.float64) if hist["cons"] else None,
-        np.asarray(hist["time"], dtype=np.float64),
-        float(np.sum(hist["floats"])) if hist["floats"] else None,
-        (done - start_chunk) * eval_every,
-        compile_seconds,
-        run_seconds,
-        {k: np.concatenate(v, axis=0) for k, v in trace_lists.items()}
-        or None,
-        cost,
-    )
+            if beat or save:
+                fetch_rows()
+            if beat:
+                progress_hook(done, hist["gap"], hist["cons"], stamp)
+            if save:
+                t_save = time.perf_counter()
+                ckptr.save(
+                    done, _flat_rows(_fetch_to_host(state)), hist["gap"],
+                    hist["cons"], hist["floats"], hist["time"],
+                )
+                save_seconds += time.perf_counter() - t_save
+            if halt_check is not None and halt_check():
+                # Early-halt policy (ISSUE-13): a fatal anomaly fired on this
+                # boundary's heartbeat. The executed prefix is the full run's
+                # prefix; the remaining segments never execute.
+                break
+        if not last:
+            enter_harvest()
+        fetch_rows()
+        return (
+            state,
+            np.asarray(hist["gap"], dtype=np.float64) if hist["gap"] else None,
+            np.asarray(hist["cons"], dtype=np.float64)
+            if hist["cons"] else None,
+            np.asarray(hist["time"], dtype=np.float64),
+            float(np.sum(hist["floats"])) if hist["floats"] else None,
+            (done - start_chunk) * eval_every,
+            compile_seconds,
+            run_seconds,
+            {k: np.concatenate(v, axis=0) for k, v in trace_lists.items()}
+            or None,
+            cost,
+        )
 
 
 class _RunSpans:
@@ -1405,6 +1428,19 @@ class _RunSpans:
         if self._open is not None:
             span, self._open = self._open, None
             span.__exit__(None, None, None)
+
+    @contextlib.contextmanager
+    def part(self, name: str, **args) -> Iterator[dict]:
+        """A named part of the open child: ``dopt.run.<child>.<name>``
+        under it (the tracer's thread stack makes the child its parent).
+        Parts of one child are disjoint and in order, and what they leave
+        is the child's own time; yields the part's event, whose ``args``
+        take counts until it closes."""
+        with self._tracer.span(
+            f"{self._event['name']}.{name}", aggregate=False, **args
+        ) as event:
+            event.setdefault("args", {})
+            yield event
 
     def note(self, **args) -> None:
         """Add arguments to the open child: counts known only after the
@@ -1905,7 +1941,8 @@ def _run(
         bytes=device_data.X.nbytes + device_data.y.nbytes
         + device_data.n_valid.nbytes,
     )
-    X, placement = place_shards(mesh, device_data.X)
+    X, placement, waits = place_shards(mesh, device_data.X)
+    spans.note(**waits)  # ``blocks``, ``wait_s``, ...: a flat placement's
     spans.note_root(stack=device_data.stacked_by, placement=placement)
     # Host arrays, so under a mesh each device's rows go to that device
     # and nothing is staged whole on the first (ISSUE 30).
@@ -2198,13 +2235,38 @@ def _run(
         sum(v.nbytes for v in jax.tree.leaves(final_state))
         if return_state else 0
     ))
-    final_models = _host_f64(x_final)
+
+    def host_f64(leaf):
+        """A device leaf as the results' float64 ``[rows, D]`` array, in
+        ``harvest``'s two parts: ``fetch`` (the copy to the host alone) and
+        ``cast`` (the float64 C-order copy and the flatten)."""
+        with spans.part("fetch", bytes=leaf.nbytes, leaves=1) as part:
+            host = _fetch_to_host(leaf)
+            # 1 where the runtime handed the leaf over in its own
+            # dimension order (``_cast_f64``).
+            part["args"]["strided"] = int(not host.flags.c_contiguous)
+        with spans.part("cast") as part:
+            out = _cast_f64(host)
+            part["args"]["bytes"] = out.nbytes
+        return out
+
+    final_models = host_f64(x_final)
     # The reported model under attack is the HONEST average — Byzantine
     # rows are adversary-controlled state, not part of the solution.
-    final_avg = (
-        final_models[adversary.honest].mean(axis=0)
-        if adversary is not None
-        else final_models.mean(axis=0)
+    with spans.part("average") as part:
+        averaged = (
+            final_models if adversary is None
+            else final_models[adversary.honest]  # an indexed copy
+        )
+        final_avg = averaged.mean(axis=0)
+        part["args"].update(
+            rows=averaged.shape[0],
+            copied_bytes=0 if adversary is None else averaged.nbytes,
+        )
+        del averaged
+    host_state = (
+        {k: host_f64(v) for k, v in final_state.items()}
+        if return_state else None
     )
 
     history = RunHistory(
@@ -2235,11 +2297,7 @@ def _run(
         history=history,
         final_models=final_models,
         final_avg_model=final_avg,
-        final_state=(
-            {k: _host_f64(v) for k, v in final_state.items()}
-            if return_state
-            else None
-        ),
+        final_state=host_state,
     )
 
 

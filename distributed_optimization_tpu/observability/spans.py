@@ -207,6 +207,74 @@ class Tracer:
         lines.append(f"{'total':<24}{total:>10.3f}")
         return "\n".join(lines)
 
+    def calls_table(self, name: str = "dopt.run", format: str = "text"):
+        """One row a span called ``name`` (a call of the run builder),
+        oldest first: which call was slow, and in which child — a sweep
+        read without a profiler. A row holds the span's ``id``, ``start``
+        (seconds since the first row's) and ``duration``; ``seconds``, by
+        name less the ``<name>.`` prefix, of its children (``harvest``) and
+        of their parts (``harvest.cast``); and ``counts``, the numeric
+        arguments of both (``upload.bytes``, ``upload.wait_s``,
+        ``harvest.fetch.strided``). Both are summed where a call opened a
+        name twice. ``format="json"`` returns the rows, ``"text"`` one
+        aligned table of them, a column for every key any row holds."""
+        if format not in ("text", "json"):
+            raise ValueError(
+                f"calls_table: format {format!r} is not 'text' or 'json'"
+            )
+        with self._lock:
+            events = sorted(self._events, key=lambda e: e["id"])
+        calls = {}  # id of a call -> its row
+        owner = {}  # id of a call's child -> the call's id
+        for e in events:  # ids go up at entry: a parent before its children
+            if e["name"] == name:
+                calls[e["id"]] = {
+                    "id": e["id"], "start": e["start"],
+                    "duration": e["duration"], "seconds": {}, "counts": {},
+                }
+                continue
+            if e["parent"] in calls:
+                call = owner[e["id"]] = e["parent"]
+            elif e["parent"] in owner:  # a part of a child; nothing deeper
+                call = owner[e["parent"]]
+            else:
+                continue
+            row = calls[call]
+            key = e["name"].removeprefix(name + ".")
+            row["seconds"][key] = row["seconds"].get(key, 0.0) + e["duration"]
+            for arg, value in (e.get("args") or {}).items():
+                if isinstance(value, bool) or not isinstance(value, (int, float)):
+                    continue
+                count = f"{key}.{arg}"
+                row["counts"][count] = row["counts"].get(count, 0) + value
+        rows = sorted(calls.values(), key=lambda r: r["start"])
+        first = rows[0]["start"] if rows else 0.0
+        for row in rows:
+            row["start"] -= first
+        if format == "json":
+            return rows
+        flat = [
+            {"start": row["start"], "duration": row["duration"],
+             **row["seconds"], **row["counts"]}
+            for row in rows
+        ]
+        columns = list(dict.fromkeys(
+            ["start", "duration", *(k for row in flat for k in row)]
+        ))
+        table = [["#", *columns]] + [
+            [str(n)] + [
+                f"{row[c]:.6f}" if isinstance(row.get(c), float)
+                else str(row.get(c, "-"))
+                for c in columns
+            ]
+            for n, row in enumerate(flat)
+        ]
+        widths = [max(map(len, column)) for column in zip(*table)]
+        return "\n".join(
+            "  ".join(cell.rjust(w) for cell, w in zip(line, widths))
+            for line in table
+        )
+
     # ------------------------------------------------------ chrome export
     def chrome_events(self) -> list[dict]:
         """Complete ("ph": "X") trace events, µs timestamps, one tid per

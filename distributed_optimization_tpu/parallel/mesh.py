@@ -14,6 +14,7 @@ from __future__ import annotations
 import collections
 import functools
 import math
+import time
 from typing import Optional, Sequence
 
 import jax
@@ -166,9 +167,12 @@ def _place_flat(blocks, devices, block_workers: int, columns: int):
     """Each of ``blocks`` (equal ``[n, L, d]`` host arrays) on its device
     (``None``: the default device, uncommitted), sent as pieces of
     ``block_workers`` workers, each a 2-D ``[rows, columns]`` view of its
-    bytes (and a tail); and the rows sent to one device in all. The pieces
-    go round the devices in turn, so the copies to different devices are in
-    flight together."""
+    bytes (and a tail); the rows sent to one device in all; and what the
+    host waited: ``blocks`` (pieces sent, all devices), ``wait_s`` (seconds
+    in the ``block_until_ready`` calls below), ``slowest_block_s`` and
+    ``slowest_block`` (the longest of them, and which piece in the order
+    sent it waited for). The pieces go round the devices in turn, so the
+    copies to different devices are in flight together."""
     n = blocks[0].shape[0]
     per_worker = blocks[0].shape[1] * blocks[0].shape[2]
     flats = [X.reshape(-1) for X in blocks]  # views: each is C-contiguous
@@ -182,6 +186,8 @@ def _place_flat(blocks, devices, block_workers: int, columns: int):
     ]
     written = [collections.deque() for _ in blocks]
     rows = 0
+    waits = {"blocks": 0, "wait_s": 0.0, "slowest_block_s": 0.0,
+             "slowest_block": 0}
     for w0 in range(0, n, block_workers):
         for p, (flat, dev) in enumerate(zip(flats, devices)):
             chunk = flat[w0 * per_worker: (w0 + block_workers) * per_worker]
@@ -196,10 +202,17 @@ def _place_flat(blocks, devices, block_workers: int, columns: int):
             )
             # At most two pieces on a device beside its ``out``: the copy
             # of this one runs under the write of the one before.
-            written[p].append(done)
+            written[p].append((waits["blocks"], done))
+            waits["blocks"] += 1
             if len(written[p]) > 1:
-                written[p].popleft().block_until_ready()
-    return outs, rows
+                sent, done = written[p].popleft()
+                t = time.perf_counter()
+                done.block_until_ready()
+                waited = time.perf_counter() - t
+                waits["wait_s"] += waited
+                if waited > waits["slowest_block_s"]:
+                    waits.update(slowest_block_s=waited, slowest_block=sent)
+    return outs, rows, waits
 
 
 def place_shards(
@@ -209,11 +222,14 @@ def place_shards(
     min_tiled_bytes: int = FLAT_MIN_TILED_BYTES,
     block_bytes: int = FLAT_BLOCK_BYTES,
     columns: int = FLAT_COLUMNS,
-) -> tuple[jax.Array, str]:
+) -> tuple[jax.Array, str, dict]:
     """The stacked shards ``X [N, L, d]`` (host) on the device, with the
     shape, dtype and default layout ``jnp.asarray`` gives, and how they got
     there: ``direct``, or ``flat:<rows>x<C>/<blocks>``; under a mesh of P
-    devices ``mesh<P>:`` and then how each device's block got to it.
+    devices ``mesh<P>:`` and then how each device's block got to it. The
+    third result is what the host waited inside a flat placement
+    (``_place_flat``: ``blocks``, ``wait_s``, ``slowest_block_s``,
+    ``slowest_block``), empty under ``direct``, which waits for nothing.
 
     Under a mesh every device's block of workers (a view of ``X``) goes
     from the host to that device and to no other, and the blocks are joined
@@ -241,9 +257,10 @@ def place_shards(
         or not X.flags.c_contiguous
     ):
         if mesh is None:
-            return jnp.asarray(X), "direct"
+            return jnp.asarray(X), "direct", {}
         outs = [jax.device_put(b, dev) for b, dev in zip(blocks, devices)]
         label += "direct"
+        waits = {}
     else:
         n = block.shape[0]
         per_worker = X.shape[1] * X.shape[2]
@@ -257,11 +274,14 @@ def place_shards(
         whole = columns // math.gcd(per_worker, columns)
         if whole <= block_workers:
             block_workers = math.ceil(block_workers / whole) * whole
-        outs, rows = _place_flat(blocks, devices, block_workers, columns)
+        outs, rows, waits = _place_flat(
+            blocks, devices, block_workers, columns
+        )
         label += f"flat:{rows}x{columns}/{math.ceil(n / block_workers)}"
     if mesh is None:
-        return outs[0], label
+        return outs[0], label, waits
     return (
         jax.make_array_from_single_device_arrays(X.shape, sharding, outs),
         label,
+        waits,
     )

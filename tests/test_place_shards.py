@@ -72,9 +72,14 @@ def test_flat_placement_is_the_host_array(case):
     shape, dtype, kw, label = FLAT_CASES[case]
     X = stack(shape, np.dtype(dtype))
     with jax.enable_x64(dtype == "float64"):
-        got, how = place_shards(None, X, min_tiled_bytes=0, **kw)
+        got, how, waits = place_shards(None, X, min_tiled_bytes=0, **kw)
         want = jnp.asarray(X)
     assert how == label
+    # What the host waited (ISSUE 48): every piece but a device's last.
+    blocks = int(label.rsplit("/", 1)[1])
+    assert waits["blocks"] == blocks
+    assert 0 <= waits["slowest_block_s"] <= waits["wait_s"]
+    assert 0 <= waits["slowest_block"] < max(blocks - 1, 1)
     assert got.shape == X.shape and got.dtype == X.dtype
     assert got.sharding == want.sharding
     assert np.asarray(got).tobytes() == X.tobytes()
@@ -91,8 +96,9 @@ def test_flat_placement_is_the_host_array(case):
 def test_direct_placement_where_flat_gains_nothing(why, X, mesh_size, kw):
     mesh = make_worker_mesh(X.shape[0], jax.devices()[:mesh_size]) if (
         mesh_size) else None
-    got, how = place_shards(mesh, X, **kw)
+    got, how, waits = place_shards(mesh, X, **kw)
     assert how == ("direct" if mesh is None else f"mesh{mesh_size}:direct")
+    assert waits == {}  # one copy a device, nothing waited for (ISSUE 48)
     assert got.shape == X.shape and got.dtype == X.dtype
     np.testing.assert_array_equal(np.asarray(got), X)
     if mesh is not None:
@@ -166,8 +172,12 @@ def test_under_a_mesh_each_block_goes_to_its_own_device(case, monkeypatch):
             patch.setattr(jax, "device_put", device_put)
             patch.setattr(jnp, "zeros", zeros)
             patch.setattr(jnp, "asarray", asarray)
-            got, how = place_shards(mesh, X, **kw)
+            got, how, waits = place_shards(mesh, X, **kw)
     assert how == label
+    # Pieces sent over all devices, where the label counts one device's.
+    assert waits.get("blocks", 0) == (
+        n_dev * int(label.rsplit("/", 1)[1]) if "flat" in label else 0
+    )
     assert got.shape == X.shape and got.dtype == want.dtype == X.dtype
     assert got.sharding == want.sharding and got.committed
     assert np.asarray(got).tobytes() == X.tobytes()

@@ -40,6 +40,8 @@ CHILDREN_COLD = [
     "cache_lookup", "compile", "upload_wait", "scan", "harvest",
 ]
 CHILDREN_WARM = [c for c in CHILDREN_COLD if c != "compile"]
+# ``harvest``'s parts (ISSUE 48): grandchildren of the root, in order.
+HARVEST_PARTS = ["rows", "fetch", "cast", "average"]
 
 
 @pytest.fixture(scope="module")
@@ -318,9 +320,14 @@ def test_second_identical_call_hits_the_cache_and_does_not_compile(setup):
     assert names(children) == [c for c in CHILDREN_COLD if c != "cache_lookup"]
 
 
+@pytest.mark.parametrize("problem", ["quadratic", "softmax", "logistic"])
 @pytest.mark.parametrize("path", ["segmented", "chunked"])
-def test_outputs_bitwise_under_another_tracer_and_path(setup, path):
+def test_outputs_bitwise_under_another_tracer_and_path(setup, path, problem):
     cfg, ds = setup
+    if problem != "quadratic":  # a rank-3 carry, and a GLM (ISSUE 48)
+        cfg = small_backend_config(
+            n_iterations=40, eval_every=10, problem_type=problem, n_classes=3)
+        ds = generate_synthetic_dataset(cfg)
     base = jax_backend.run(cfg, ds, 0.0)  # the process tracer
     kw = (
         {"progress_cb": lambda ev: None} if path == "segmented"
@@ -339,6 +346,203 @@ def test_outputs_bitwise_under_another_tracer_and_path(setup, path):
     assert got[:7] == CHILDREN_COLD[:7]
     assert got[-3:] == ["upload_wait", "scan", "harvest"]
     assert set(got) <= set(CHILDREN_COLD)
+
+
+def parts_of(tracer, child):
+    """The parts of ``child`` (an event), in order of their start."""
+    return sorted(
+        (e for e in tracer.spans() if e["parent"] == child["id"]),
+        key=lambda e: e["start"],
+    )
+
+
+def test_harvest_holds_its_four_parts_and_they_are_grandchildren(setup):
+    """ISSUE 48: ``rows``, ``fetch``, ``cast``, ``average`` under
+    ``dopt.run.harvest``, disjoint and in order, inside it; the root's own
+    children are what they were (the tests above), and no other child has a
+    part."""
+    cfg, ds = setup
+    tracer = Tracer()
+    result, roots, children = run_under(tracer, cfg, ds)
+    harvest = children[-1]
+    parts = parts_of(tracer, harvest)
+    assert [e["name"] for e in parts] == [
+        "dopt.run.harvest." + p for p in HARVEST_PARTS
+    ]
+    end = harvest["start"]
+    for e in parts:
+        assert (e["parent"], e["root"]) == (harvest["id"], roots[-1]["id"])
+        assert e["start"] >= end
+        end = e["start"] + e["duration"]
+    assert end <= harvest["start"] + harvest["duration"]
+    assert sum(e["duration"] for e in parts) <= harvest["duration"]
+    ids = {e["id"] for e in children} | {roots[-1]["id"]}
+    assert {e["parent"] for e in tracer.spans()} <= ids | {None}
+    assert all(e["parent"] == harvest["id"] for e in tracer.spans()
+               if e["id"] not in ids)
+    rows, fetch, cast, average = (e["args"] for e in parts)
+    n, d = result.final_models.shape
+    # Four rows of objective and consensus error, float32 on the device.
+    assert rows == {"bytes": 2 * 4 * 4}
+    assert fetch == {"bytes": harvest["args"]["bytes"], "leaves": 1,
+                     "strided": 0}
+    assert fetch["bytes"] == n * d * 4
+    assert cast == {"bytes": 8 * n * d}
+    assert average == {"rows": n, "copied_bytes": 0}
+    assert tracer.phases == {}
+
+
+@pytest.mark.parametrize("problem,classes", [("quadratic", 2), ("softmax", 3)])
+def test_fetch_counts_every_leaf_under_return_state(problem, classes):
+    """CHOCO under ``return_state``: the models come down, then x again and
+    xhat; ``fetch`` and ``cast`` open once a leaf, and their ``bytes`` by
+    name are what ``harvest`` says came down, and eight bytes a number."""
+    cfg = small_backend_config(
+        n_iterations=20, eval_every=10, algorithm="choco", compression="top_k",
+        compression_k=4, choco_gamma=0.2, problem_type=problem,
+        n_classes=classes,
+    )
+    tracer = Tracer()
+    result, _, children = run_under(
+        tracer, cfg, generate_synthetic_dataset(cfg), return_state=True)
+    parts = parts_of(tracer, children[-1])
+    assert [e["name"].rsplit(".", 1)[1] for e in parts] == HARVEST_PARTS + [
+        "fetch", "cast", "fetch", "cast"]
+    fetched = sum(e["args"]["bytes"] for e in parts if e["name"].endswith("fetch"))
+    written = sum(e["args"]["bytes"] for e in parts if e["name"].endswith("cast"))
+    assert fetched == children[-1]["args"]["bytes"] == 3 * result.final_models.size * 4
+    assert written == 8 * 3 * result.final_models.size
+    assert sum(e["args"].get("leaves", 0) for e in parts) == 3
+    np.testing.assert_array_equal(result.final_state["x"], result.final_models)
+    np.testing.assert_array_equal(
+        result.final_avg_model, result.final_models.mean(axis=0))
+
+
+def test_average_says_the_honest_rows_and_what_it_copied():
+    """An attacked call averages the honest rows, an indexed COPY of them;
+    a benign call averages in place."""
+    cfg = small_backend_config(
+        n_workers=16, n_iterations=10, eval_every=10, attack="sign_flip",
+        n_byzantine=2, aggregation="trimmed_mean", robust_b=1,
+    )
+    for cfg, honest in ((cfg, 14), (cfg.replace(
+            attack="none", n_byzantine=0, aggregation="gossip", robust_b=0), 16)):
+        tracer = Tracer()
+        result, _, children = run_under(
+            tracer, cfg, generate_synthetic_dataset(cfg))
+        average = parts_of(tracer, children[-1])[-1]
+        assert average["name"] == "dopt.run.harvest.average"
+        assert average["args"] == {
+            "rows": honest,
+            "copied_bytes": (
+                honest * result.final_models.shape[1] * 8 if honest < 16 else 0),
+        }
+
+
+@pytest.mark.parametrize("shape,order", [
+    ((6, 5), "C"), ((6, 4, 3), "C"), ((6, 4, 3), "runtime")])
+def test_fetch_then_cast_is_the_parents_one_expression(shape, order):
+    """``_host_f64`` split in two (ISSUE 48): the same float64 ``[rows, D]``
+    array to the bit, also for a leaf the runtime hands over in its own
+    dimension order (strided on the host, PR 25)."""
+    a = np.arange(np.prod(shape), dtype=np.float32).reshape(shape) / 7
+    fetched = jax_backend._fetch_to_host(jax.numpy.asarray(a))
+    if order == "runtime":  # the strides of a [4, 6, 3] buffer
+        fetched = np.ascontiguousarray(fetched.transpose(1, 0, 2)).transpose(1, 0, 2)
+        assert not fetched.flags.c_contiguous
+    want = fetched.astype(np.float64, order="C").reshape(shape[0], -1)
+    got = jax_backend._cast_f64(fetched)
+    assert got.dtype == np.float64 and got.flags.c_contiguous
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, a.reshape(shape[0], -1).astype(np.float64))
+
+
+def test_upload_counts_its_waiting_under_a_flat_placement(setup, monkeypatch):
+    """ISSUE 48: ``blocks``, ``wait_s``, ``slowest_block_s`` and
+    ``slowest_block`` on ``dopt.run.upload`` where the shards went up in
+    pieces, three numbers and an index a call and no span a piece; none of
+    them under ``direct``."""
+    cfg, ds = setup
+    tracer = Tracer()
+    _, _, children = run_under(tracer, cfg, ds, use_mesh=False)
+    upload = next(e for e in children if e["name"] == "dopt.run.upload")
+    assert set(upload["args"]) == {"bytes"}
+    n, L, d = stack_shards(ds, dtype=np.float32).X.shape
+    monkeypatch.setattr(
+        jax_backend, "place_shards",
+        functools.partial(
+            place_shards, min_tiled_bytes=0, columns=128,
+            block_bytes=n // 4 * L * d * 4),
+    )
+    _, roots, children = run_under(tracer, cfg, ds, use_mesh=False)
+    assert roots[-1]["args"]["placement"].endswith("/4")
+    upload = next(e for e in children if e["name"] == "dopt.run.upload")
+    args = upload["args"]
+    assert set(args) == {
+        "bytes", "blocks", "wait_s", "slowest_block_s", "slowest_block"}
+    assert args["blocks"] == 4
+    assert 0 <= args["slowest_block_s"] <= args["wait_s"] <= upload["duration"]
+    assert args["slowest_block"] in range(3)  # the last is not waited for
+    assert parts_of(tracer, upload) == []
+
+
+def test_calls_table_is_one_row_a_call():
+    """``Tracer.calls_table``: the children's and the parts' seconds and
+    their numeric arguments by call, as rows and as text."""
+    tracer = Tracer()
+    for k in range(2):
+        with tracer.span("dopt.run", aggregate=False, path="fused"):
+            tracer.add_span("dopt.run.prepare", 0.25)
+            with tracer.span("dopt.run.upload", bytes=100, how="flat") as up:
+                up["args"].update(wait_s=0.5, blocks=2 + k)
+            tracer.add_span("dopt.run.prepare", 0.5)
+            with tracer.span("dopt.run.harvest", bytes=40):
+                for _ in range(1 + k):
+                    tracer.add_span(
+                        "dopt.run.harvest.fetch", 0.125, bytes=20, strided=1)
+                with tracer.span("dopt.run.harvest.cast"):
+                    tracer.add_span("deeper", 1.0)
+    with tracer.span("another.root"):
+        tracer.add_span("dopt.run.prepare", 9.0)
+    rows = tracer.calls_table(format="json")
+    assert [r["start"] for r in rows][0] == 0.0 < rows[1]["start"]
+    assert [r["id"] for r in rows] == sorted(r["id"] for r in rows)
+    for k, row in enumerate(rows):
+        assert list(row["seconds"]) == [
+            "prepare", "upload", "harvest", "harvest.fetch", "harvest.cast"]
+        assert row["seconds"]["prepare"] == 0.75
+        assert row["seconds"]["harvest.fetch"] == 0.125 * (1 + k)
+        assert row["seconds"]["harvest"] <= row["duration"]
+        assert row["counts"] == {
+            "upload.bytes": 100, "upload.wait_s": 0.5, "upload.blocks": 2 + k,
+            "harvest.bytes": 40, "harvest.fetch.bytes": 20 * (1 + k),
+            "harvest.fetch.strided": 1 + k,
+        }
+    text = tracer.calls_table().splitlines()
+    assert len(text) == 3 and len({len(line) for line in text}) == 1
+    assert text[0].split() == [
+        "#", "start", "duration", *rows[0]["seconds"], *rows[0]["counts"]]
+    assert text[2].split()[0] == "1" and text[2].split()[-1] == "2"
+    assert text[1].split()[3] == "0.750000"
+    assert tracer.calls_table("no.such.span") == "#  start  duration"
+    assert tracer.calls_table("no.such.span", format="json") == []
+    with pytest.raises(ValueError, match="format"):
+        tracer.calls_table(format="csv")
+
+
+def test_calls_table_of_real_calls(setup):
+    cfg, ds = setup
+    tracer = Tracer()
+    for _ in range(2):
+        run_under(tracer, cfg, ds)
+    first, second = tracer.calls_table(format="json")
+    assert set(CHILDREN_WARM) | {"harvest." + p for p in HARVEST_PARTS} <= set(
+        second["seconds"])
+    assert sum(
+        second["seconds"]["harvest." + p] for p in HARVEST_PARTS
+    ) <= second["seconds"]["harvest"] <= second["duration"]
+    assert second["counts"]["harvest.fetch.bytes"] == second["counts"]["harvest.bytes"]
+    assert second["counts"]["harvest.average.rows"] == cfg.n_workers
 
 
 def test_iters_per_second_is_the_scan_spans_clock(setup):
@@ -360,7 +564,9 @@ def test_process_tracer_keeps_the_last_roots_only(setup):
     assert [e["id"] for e in roots] == sorted(e["id"] for e in roots)
     # Every event that is left belongs to a root that is left.
     assert {e["root"] for e in events} == {e["id"] for e in roots}
-    assert len(events) <= PROCESS_TRACER_ROOTS * (len(CHILDREN_COLD) + 1)
+    assert len(events) <= PROCESS_TRACER_ROOTS * (
+        len(CHILDREN_COLD) + 1 + len(HARVEST_PARTS)
+    )
 
 
 def test_bounded_tracer_drops_a_root_with_its_children():
@@ -472,6 +678,7 @@ def test_spans_module_imports_without_jax():
         "t = m.Tracer()\n"
         "with t.span('a'):\n    pass\n"
         "assert [e['name'] for e in t.spans()] == ['a']\n"
+        "assert len(t.calls_table('a').splitlines()) == 2\n"
         "assert 'jax.profiler' not in sys.modules\n"
     )
     proc = subprocess.run(

@@ -395,7 +395,7 @@ def churn_cell_scan(one_chip):
         patch.setattr(jax_backend, "stack_shards", lambda ds, dtype: DeviceDataset(
             X=zeros(n, rows, d), y=zeros(n, rows), n_valid=np.full(n, rows, np.int32),
             stacked_by="view"))
-        patch.setattr(jax_backend, "place_shards", lambda mesh, X: (shaped(X), "direct"))
+        patch.setattr(jax_backend, "place_shards", lambda mesh, X: (shaped(X), "direct", {}))
         patch.setattr(jax_backend, "shard_over_workers", lambda mesh, a: shaped(a))
         patch.setattr(jax_backend, "_visit_is_fused", lambda carried, X: bool(carried))
         patch.setattr(pk, "resolve_interpret", lambda *a, **kw: False)
