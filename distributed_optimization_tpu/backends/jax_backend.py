@@ -23,7 +23,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import sys
+import threading
 import time
+import weakref
 from typing import Iterator, NamedTuple, Optional
 
 import jax
@@ -157,16 +160,104 @@ def _flat_rows(tree):
     return jax.tree.map(lambda a: a.reshape(a.shape[0], -1), tree)
 
 
-def _cast_f64(a):
-    """A fetched leaf as the float64 ``[rows, D]`` array the results hold.
+def _cast_f64(a, dst):
+    """A fetched leaf as the float64 ``[rows, D]`` array the results hold,
+    written into ``dst`` (float64, C order, ``a``'s shape) in one pass.
 
-    The cast writes C order BEFORE the flatten: the TPU runtime hands a
+    The copy writes C order BEFORE the flatten: the TPU runtime hands a
     rank-3 array to the host in the device's own dimension order (the
     softmax models ``[96, 4097, 512]`` arrive with the strides of a
     ``[4097, 96, 512]`` buffer — my chip run, PR 25), so flattening the
     fetched array first would copy the whole leaf once more, where the
     cast, which touches every element anyway, reorders for nothing."""
-    return _flat_rows(a.astype(np.float64, order="C"))
+    np.copyto(dst, a, casting="unsafe")  # ``astype``'s own casting rule
+    return _flat_rows(dst)
+
+
+class _ResultBuffers:
+    """The float64 host buffers the last two harvests wrote, handed to a
+    later harvest once nothing holds them (ISSUE 49).
+
+    Why: glibc maps an allocation of a result's size anew and unmaps it on
+    free, so a cast into a new array faults in every page it writes (1.5 s
+    of a 1.6 s cast at the softmax cells' 1.61 GB, PERF.md §6, PR 48); the
+    same cast into memory the process has touched is the arithmetic alone.
+
+    Who owns a result's memory: whoever holds the result. A result is a
+    view (``_flat_rows``) of the buffer it was cast into, and numpy gives
+    every view, every slice of a view and every buffer export the OWNING
+    array as its base, so the owner's reference count says whether a live
+    object can still reach the memory. ``take`` hands a kept buffer out
+    only at the idle count (this store's list and the question's own
+    argument) and with no weak reference to it; a held result, a held
+    slice, a ``memoryview``, an array another library aliased all read as
+    held, and the harvest allocates as if there were no store. The question
+    and the hand-out happen under one lock: the serving plane harvests from
+    threads, and two never receive one buffer. The count is CPython's, with
+    its lock: on any other interpreter nothing is kept and every harvest
+    allocates.
+
+    Why two harvests: a sweep written ``for ...: r = run(...)`` (every
+    sweep under ``examples/``) still holds call n's result while call n + 1
+    harvests, and lets it go when ``r`` is bound again. So ``keep`` holds
+    on, for ONE more call, to the last harvest's buffers that are held as
+    it ends: call n + 2 finds them free, and the loop reuses from its third
+    call, two buffers taking turns (what the loop has alive at its peak
+    without the store). A caller who drops the result before the next call
+    reuses from the second. A buffer held through two later harvests is
+    forgotten, its holders' alone; a free one no harvest took (another
+    shape) goes. So the store reaches at most the last two harvests'
+    buffers, and of bytes no caller holds at most those two harvests'
+    until the next harvest ends, then at most its own."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._kept: list[np.ndarray] = []  # the last harvest's
+        self._older: list[np.ndarray] = []  # the one before's, held as it ended
+        # The idle count as THIS interpreter counts it, read the way
+        # ``_is_free`` reads it: one owner in a list, asked by subscript.
+        probe = [np.empty(0)]
+        self._idle_refs = sys.getrefcount(probe[0])
+        self._counts_say = sys.implementation.name == "cpython" and getattr(
+            sys, "_is_gil_enabled", lambda: True)()
+
+    def _is_free(self, pool, i: int) -> bool:
+        return (
+            sys.getrefcount(pool[i]) == self._idle_refs
+            and weakref.getweakrefcount(pool[i]) == 0
+        )
+
+    def take(self, shape) -> tuple[np.ndarray, bool]:
+        """A C-order float64 array of ``shape`` to write a result into, and
+        whether it is a kept buffer (its pages touched) or a new one."""
+        with self._lock:
+            for pool in (self._kept, self._older):
+                for i in range(len(pool)):
+                    if pool[i].shape == shape and self._is_free(pool, i):
+                        return pool.pop(i), True
+        return np.empty(shape, np.float64), False
+
+    def keep(self, buffers) -> None:
+        """The buffers of the harvest that just ended become the last
+        harvest's; the last harvest's that are still held, the one
+        before's; whatever else was kept is let go."""
+        if not self._counts_say:
+            return
+        with self._lock:
+            kept = self._kept
+            self._older = [
+                kept[i] for i in range(len(kept)) if not self._is_free(kept, i)
+            ]
+            self._kept = list(buffers)
+
+    def clear(self) -> None:
+        """Let go of everything kept: a long-lived process hands back what
+        its dropped results left here (at most two harvests' bytes)."""
+        with self._lock:
+            self._kept, self._older = [], []
+
+
+_RESULT_BUFFERS = _ResultBuffers()
 
 
 def _restored_state(state_np, like):
@@ -2236,38 +2327,55 @@ def _run(
         if return_state else 0
     ))
 
+    harvested = []  # this call's float64 buffers: the store's when it ends
+    was_reused = set()  # over the leaves: {True}, {False} or both
+
     def host_f64(leaf):
         """A device leaf as the results' float64 ``[rows, D]`` array, in
         ``harvest``'s two parts: ``fetch`` (the copy to the host alone) and
-        ``cast`` (the float64 C-order copy and the flatten)."""
+        ``cast`` (the float64 C-order copy, into a buffer an earlier
+        harvest wrote where one is free: ``_ResultBuffers``; the flatten)."""
         with spans.part("fetch", bytes=leaf.nbytes, leaves=1) as part:
             host = _fetch_to_host(leaf)
             # 1 where the runtime handed the leaf over in its own
             # dimension order (``_cast_f64``).
             part["args"]["strided"] = int(not host.flags.c_contiguous)
         with spans.part("cast") as part:
-            out = _cast_f64(host)
-            part["args"]["bytes"] = out.nbytes
+            dst, reused = _RESULT_BUFFERS.take(host.shape)
+            out = _cast_f64(host, dst)
+            harvested.append(dst)
+            was_reused.add(reused)
+            part["args"].update(
+                bytes=out.nbytes, reused_bytes=out.nbytes if reused else 0)
         return out
 
     final_models = host_f64(x_final)
     # The reported model under attack is the HONEST average — Byzantine
     # rows are adversary-controlled state, not part of the solution.
     with spans.part("average") as part:
-        averaged = (
-            final_models if adversary is None
-            else final_models[adversary.honest]  # an indexed copy
-        )
-        final_avg = averaged.mean(axis=0)
-        part["args"].update(
-            rows=averaged.shape[0],
-            copied_bytes=0 if adversary is None else averaged.nbytes,
-        )
-        del averaged
+        if adversary is None:
+            n_averaged = final_models.shape[0]
+            final_avg = final_models.mean(axis=0)
+        else:
+            # One masked reduction over the buffer the cast just wrote: the
+            # additions of the mean over the honest rows' indexed copy, in
+            # its order (bitwise, tests/test_result_buffers.py), and no
+            # model-sized copy into pages never touched.
+            honest = adversary.honest
+            n_averaged = int(honest.sum())
+            final_avg = np.add.reduce(
+                final_models, axis=0, where=honest[:, None]
+            ) / n_averaged
+        part["args"]["rows"] = n_averaged
     host_state = (
         {k: host_f64(v) for k, v in final_state.items()}
         if return_state else None
     )
+    _RESULT_BUFFERS.keep(harvested)
+    spans.note_root(result_buffers=(
+        "mixed" if len(was_reused) > 1
+        else "reused" if True in was_reused else "fresh"
+    ))
 
     history = RunHistory(
         objective=gap_hist,

@@ -95,6 +95,9 @@ def test_one_root_with_the_named_children_in_order(setup):
     # ran and its temporaries by ``memory_analysis()`` (tests/test_device_scopes.py).
     args = dict(root["args"])
     assert isinstance(args.pop("program"), str) and args.pop("temp_bytes") >= 0
+    # Whether the harvest wrote its float64 results into buffers an earlier
+    # call of this process left free (ISSUE 49; tests/test_result_buffers.py).
+    assert args.pop("result_buffers") in ("fresh", "reused")
     assert args == {
         "path": "fused", "cache": "miss",
         "carry": f"{cfg.n_workers}x{ds.n_features}",
@@ -387,8 +390,12 @@ def test_harvest_holds_its_four_parts_and_they_are_grandchildren(setup):
     assert fetch == {"bytes": harvest["args"]["bytes"], "leaves": 1,
                      "strided": 0}
     assert fetch["bytes"] == n * d * 4
-    assert cast == {"bytes": 8 * n * d}
-    assert average == {"rows": n, "copied_bytes": 0}
+    # ISSUE 49: into a kept buffer or a new one, as the store stood.
+    assert set(cast) == {"bytes", "reused_bytes"}
+    assert cast["bytes"] == 8 * n * d and cast["reused_bytes"] in (0, 8 * n * d)
+    assert roots[-1]["args"]["result_buffers"] == (
+        "reused" if cast["reused_bytes"] else "fresh")
+    assert average == {"rows": n}
     assert tracer.phases == {}
 
 
@@ -418,9 +425,9 @@ def test_fetch_counts_every_leaf_under_return_state(problem, classes):
         result.final_avg_model, result.final_models.mean(axis=0))
 
 
-def test_average_says_the_honest_rows_and_what_it_copied():
-    """An attacked call averages the honest rows, an indexed COPY of them;
-    a benign call averages in place."""
+def test_average_says_the_honest_rows_and_copies_nothing():
+    """An attacked call averages the honest rows, a benign call all of them,
+    both in place (ISSUE 49: no indexed copy of the honest rows)."""
     cfg = small_backend_config(
         n_workers=16, n_iterations=10, eval_every=10, attack="sign_flip",
         n_byzantine=2, aggregation="trimmed_mean", robust_b=1,
@@ -432,11 +439,7 @@ def test_average_says_the_honest_rows_and_what_it_copied():
             tracer, cfg, generate_synthetic_dataset(cfg))
         average = parts_of(tracer, children[-1])[-1]
         assert average["name"] == "dopt.run.harvest.average"
-        assert average["args"] == {
-            "rows": honest,
-            "copied_bytes": (
-                honest * result.final_models.shape[1] * 8 if honest < 16 else 0),
-        }
+        assert average["args"] == {"rows": honest}
 
 
 @pytest.mark.parametrize("shape,order", [
@@ -444,14 +447,17 @@ def test_average_says_the_honest_rows_and_what_it_copied():
 def test_fetch_then_cast_is_the_parents_one_expression(shape, order):
     """``_host_f64`` split in two (ISSUE 48): the same float64 ``[rows, D]``
     array to the bit, also for a leaf the runtime hands over in its own
-    dimension order (strided on the host, PR 25)."""
+    dimension order (strided on the host, PR 25); written into the buffer
+    it is handed (ISSUE 49), whatever that held."""
     a = np.arange(np.prod(shape), dtype=np.float32).reshape(shape) / 7
     fetched = jax_backend._fetch_to_host(jax.numpy.asarray(a))
     if order == "runtime":  # the strides of a [4, 6, 3] buffer
         fetched = np.ascontiguousarray(fetched.transpose(1, 0, 2)).transpose(1, 0, 2)
         assert not fetched.flags.c_contiguous
     want = fetched.astype(np.float64, order="C").reshape(shape[0], -1)
-    got = jax_backend._cast_f64(fetched)
+    dst = np.full(shape, np.nan)
+    got = jax_backend._cast_f64(fetched, dst)
+    assert got.base is dst
     assert got.dtype == np.float64 and got.flags.c_contiguous
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(got, a.reshape(shape[0], -1).astype(np.float64))
