@@ -648,7 +648,11 @@ def _make_step_eval(p: _StepPieces, data):
                         slot_key, t_due, table, n_valid, batch_size
                     )
                     wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
-            with device_scopes.scope("gradient"):
+            # A round's first gradient is ``gradient``; those of its τ − 1
+            # later descents (a static slot above 0, or the traced slot of
+            # the ``fori_loop`` form) are ``local``. Metadata only.
+            first = isinstance(slot, int) and slot == 0
+            with device_scopes.scope("gradient" if first else "local"):
                 if fwd is not None and params is fwd_of and slot == 0:
                     if p.forward == "fused":
                         return fwd.product + p.reg * params
@@ -904,7 +908,10 @@ def _fault_root_args(config, faulty, tables) -> dict:
     ``down_share``, under ``neighbor_restart`` ``rejoin_rows`` (the rows it
     is asked to restart over the horizon: the restart's engagement
     counter), and ``timeline_placement`` (``device`` / ``host``: where the
-    leaves the scan reads were made; ``faults.timeline_counters``)."""
+    leaves the scan reads were made; ``faults.timeline_counters``). Of a
+    call with participation sampling, and of no other:
+    ``sampled_out_share`` (the share of the (round, worker) pairs the
+    sampling froze) and, again, ``timeline_placement``."""
     parts = [
         f"{name}:{value:g}" for name, value, on in (
             ("edge_drop", config.edge_drop_prob, config.edge_drop_prob > 0.0),
@@ -933,17 +940,42 @@ def _fault_root_args(config, faulty, tables) -> dict:
             (f"churn:{config.mttf:g}/{config.mttr:g}", config.mttf > 0.0),
         ) if on
     ]
-    if chains and faulty.timeline is not None:
+    sampled = faulty.participation_active
+    if (chains or sampled) and faulty.timeline is not None:
         counted = timeline_counters(faulty.timeline)
-        args.update(
-            fault_chains=",".join(chains),
-            timeline_placement=counted["timeline_placement"],
-        )
+        args["timeline_placement"] = counted["timeline_placement"]
+        if chains:
+            args["fault_chains"] = ",".join(chains)
         if faulty.churn_active:
             args.update(rejoin=faulty.rejoin, down_share=counted["down_share"])
         if faulty.rejoin_restart is not None:
             args["rejoin_rows"] = counted["rejoin_rows"]
+        if sampled:
+            args["sampled_out_share"] = counted["sampled_out_share"]
     return args
+
+
+# Reads of the shard stack that a round's FIRST gradient and the eval's
+# objective take together, by what the eval leaves that gradient.
+_FIRST_READS = {"fused": 1, "carried": 2, "recomputed": 3}
+
+
+def _local_root_args(local_steps: int, forward: str) -> dict:
+    """What the ``dopt.run`` root says of a call whose gossip round holds
+    more than one gradient step (``local_steps`` = τ > 1), and of no other:
+    ``local_steps``; ``local_forward``, how the τ − 1 later gradients are
+    made (``recomputed``: ``grad_fn_factory`` takes the carried product at
+    slot 0 alone, so each later one is ``gradient_weighted`` over the whole
+    padded shard: the engagement counter of any path that serves them
+    otherwise); and ``shard_reads``, the reads of the shard stack one round
+    was BUILT with, from ``forward`` and τ and nothing measured: the first
+    gradient and the objective one, two or three, each later gradient two
+    (X·x, then Xᵀ·c). A plan, as ``ici_bytes_per_round`` is."""
+    return {
+        "local_steps": local_steps,
+        "local_forward": "recomputed",
+        "shard_reads": _FIRST_READS[forward] + 2 * (local_steps - 1),
+    }
 
 
 def _gather_root_args(topo, tables) -> dict:
@@ -2176,6 +2208,8 @@ def _run(
         forward=forward, mesh=mesh,
     )
     spans.note_root(forward=forward)
+    if config.local_steps > 1:
+        spans.note_root(**_local_root_args(config.local_steps, forward))
     if (
         forward != "recomputed" and faulty is not None
         and faulty.rejoin_restart is not None

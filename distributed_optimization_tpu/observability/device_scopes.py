@@ -48,7 +48,7 @@ from typing import Optional
 # covers; the benchmark reads ``scan.<scope>_us_per_iter`` for all but
 # the recorder's.
 SCOPES = (
-    "sampling", "gradient", "gossip", "compress", "faults", "robust",
+    "sampling", "gradient", "local", "gossip", "compress", "faults", "robust",
     "update", "eval", "recorder",
 )
 SCOPE_PREFIX = "dopt."

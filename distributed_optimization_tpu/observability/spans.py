@@ -54,6 +54,13 @@ from typing import Iterator, Optional
 # Root spans whose events the process-wide tracer keeps (older ones are
 # dropped whole, children included).
 PROCESS_TRACER_ROOTS = 64
+# A call's own arguments that ``Tracer.calls_table`` repeats in its row:
+# what a gossip round of the call was made of, where it holds more than
+# one gradient step or samples its workers (docs/OBSERVABILITY.md).
+CALL_ARGS = (
+    "local_steps", "local_forward", "shard_reads", "sampled_out_share",
+    "timeline_placement",
+)
 
 
 def _trace_annotation(name: str):
@@ -216,7 +223,9 @@ class Tracer:
         of their parts (``harvest.cast``); and ``counts``, the numeric
         arguments of both (``upload.bytes``, ``upload.wait_s``,
         ``harvest.fetch.strided``). Both are summed where a call opened a
-        name twice. ``format="json"`` returns the rows, ``"text"`` one
+        name twice. ``said`` repeats those of the span's OWN arguments that
+        ``CALL_ARGS`` names (what a round of the call was made of), where it
+        has them. ``format="json"`` returns the rows, ``"text"`` one
         aligned table of them, a column for every key any row holds."""
         if format not in ("text", "json"):
             raise ValueError(
@@ -228,9 +237,11 @@ class Tracer:
         owner = {}  # id of a call's child -> the call's id
         for e in events:  # ids go up at entry: a parent before its children
             if e["name"] == name:
+                said = e.get("args") or {}
                 calls[e["id"]] = {
                     "id": e["id"], "start": e["start"],
                     "duration": e["duration"], "seconds": {}, "counts": {},
+                    "said": {a: said[a] for a in CALL_ARGS if a in said},
                 }
                 continue
             if e["parent"] in calls:
@@ -255,7 +266,7 @@ class Tracer:
             return rows
         flat = [
             {"start": row["start"], "duration": row["duration"],
-             **row["seconds"], **row["counts"]}
+             **row["seconds"], **row["counts"], **row["said"]}
             for row in rows
         ]
         columns = list(dict.fromkeys(

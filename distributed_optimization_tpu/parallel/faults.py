@@ -698,7 +698,9 @@ def timeline_counters(timeline: FaultTimeline) -> dict:
     chains' scans returned, ``host`` where they were fetched, injected or
     stacked) and, under a node process, ``down_share`` (the mean of
     ``1 − node_up`` over the horizon) and ``rejoin_rows`` (the set bits of
-    ``rejoin``: the rows a ``neighbor_restart`` run is asked to restart).
+    ``rejoin``: the rows a ``neighbor_restart`` run is asked to restart);
+    under participation sampling ``sampled_out_share`` (the mean of
+    ``1 − part_up`` over the horizon).
     Counted where the leaves live; what is fetched is a count a node."""
     leaves = [
         getattr(timeline, name) for name in TIMELINE_LEAVES
@@ -715,6 +717,9 @@ def timeline_counters(timeline: FaultTimeline) -> dict:
         up = _set_bits(timeline.node_up)
         out["down_share"] = 1.0 - up / timeline.node_up.size
         out["rejoin_rows"] = _set_bits(timeline.rejoin)
+    if timeline.part_up is not None:
+        taking_part = _set_bits(timeline.part_up)
+        out["sampled_out_share"] = 1.0 - taking_part / timeline.part_up.size
     return out
 
 

@@ -3,6 +3,7 @@ compiler is installed here; nothing runs, so no time and no result comes out
 of this file). The one file that describes a topology: only the worker that
 is given it loads the TPU's library, inside a fixture, never at import."""
 
+import collections
 import json
 import os
 import re
@@ -353,12 +354,10 @@ def test_a_timelines_chain_is_one_scan_and_its_leaf_a_byte_a_bit(one_chip):
     assert (back.output_size_in_bytes, back.temp_size_in_bytes) == (horizon * n, 0)
 
 
-@pytest.fixture(scope="module")
-def churn_cell_scan(one_chip):
-    """The churn cell's whole scan as ``_run`` builds it (ISSUE 47: bursts,
-    churn and ``neighbor_restart`` on the ring's shift form, the dense
-    sampler, the visit fused as on the chip) at N = 2^18, L = 53, d = 81,
-    unroll 8, compiled for 1,000 trips with the three ``pred[1000, N]``
+def _cell_scan(one_chip, config_name, horizon):
+    """A GLM ring cell's whole scan as ``_run`` builds it (the dense sampler,
+    the visit fused as on the chip) at N = 2^18, L = 53, d = 81, unroll 8,
+    compiled for ``horizon`` trips with the timeline's ``pred[horizon, N]``
     leaves as arguments. No shard, timeline or model of that size is made:
     the call is cut where it hands its program to the driver, the program
     lowered from shapes."""
@@ -367,10 +366,9 @@ def churn_cell_scan(one_chip):
     from distributed_optimization_tpu.ops import pallas_kernels as pk
     from distributed_optimization_tpu.utils.data import DeviceDataset, HostDataset
 
-    n, rows, d, horizon = 1 << 18, 53, 81, 1000
+    n, rows, d = 1 << 18, 53, 81
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            root, "benchmark", "configs", "glm81_ring262k_burst4_churn400.json")) as fh:
+    with open(os.path.join(root, "benchmark", "configs", config_name + ".json")) as fh:
         experiment = json.load(fh)["experiment"]
     assert (experiment["n_workers"], experiment["n_features"]) == (n, d - 1)
     # what ``auto`` takes on the chip (a CPU's is the gather sampler, unroll 1)
@@ -411,6 +409,14 @@ def churn_cell_scan(one_chip):
         return jax.jit(make_seg_scan(horizon)).lower(
             jax.tree.map(shaped, state0),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), data).compile()
+
+
+@pytest.fixture(scope="module")
+def churn_cell_scan(one_chip):
+    """The churn cell's whole scan (ISSUE 47: bursts, churn and
+    ``neighbor_restart`` on the ring's shift form), 1,000 trips, the three
+    ``pred[1000, N]`` leaves as arguments."""
+    return _cell_scan(one_chip, "glm81_ring262k_burst4_churn400", 1000)
 
 
 def test_the_churn_cells_trip_visits_the_shards_once(churn_cell_scan):
@@ -455,3 +461,74 @@ def test_the_churn_cells_leaves_stay_arguments(churn_cell_scan):
         if ins is not None and ins[2] == "constant"]
     assert max(constants) < 2**21, max(constants)
     assert churn_cell_scan.memory_analysis().generated_code_size_in_bytes < 32 * 2**20
+
+
+@pytest.fixture(scope="module")
+def federated_cell_scan(one_chip):
+    """The federated cell's whole scan (ISSUE 50: four gradient steps a
+    round, half the workers sampled out), 250 trips, the one
+    ``pred[250, N]`` leaf as an argument."""
+    return _cell_scan(one_chip, "glm81_ring262k_local4_part50", 250)
+
+
+def _stack_readers(text):
+    """(scope, opcode) of every instruction that reads the
+    ``f32[262144,53,81]`` stack, through whatever holds it in its
+    computation (a parameter, the loop's element, a bitcast of either);
+    bitcasts, tuples and loops themselves left out, and the insides of a
+    fusion (the fusion is the reader)."""
+    stack = re.compile(r"^f32\[(262144,53,81|81,53,262144)\]")
+    parsed = {}  # computation -> [(instruction, its scope)]
+    for name, lines in device_scopes._computations(text)[0].items():
+        pairs = ((device_scopes._instruction(line), device_scopes._scope_of(line))
+                 for line in lines)
+        parsed[name] = [(ins, scope) for ins, scope in pairs if ins is not None]
+    fused = {called for body in parsed.values() for ins, _ in body if ins[2] == "fusion"
+             for called in device_scopes._CALLED_RE.findall(ins[4])}
+    readers = []
+    for name, body in parsed.items():
+        if name in fused:
+            continue
+        held = {ins[0] for ins, _ in body if stack.match(ins[1])}
+        readers += [(scope, ins[2]) for ins, scope in body
+                    if held & set(ins[3]) and ins[2] not in ("bitcast", "tuple", "while")]
+    return readers
+
+
+def test_the_federated_cells_round_reads_the_shards_seven_times(federated_cell_scan):
+    """A round of tau = 4 as the root's ``shard_reads`` plans it: ONE
+    ``glm_shard_visit`` (the first gradient and the objective, ``gradient``)
+    and, for each of the three later descents, two fusions that read the
+    stack (X.x, then X^T.c), every one of them under ``local`` and none under
+    any other scope: 7 reads a trip. 250 trips are 31 bodies of eight and a
+    remainder of two, so ten trips are written out: ten visits and one in
+    front of the loops, sixty fusions."""
+    readers = collections.Counter(_stack_readers(federated_cell_scan.as_text()))
+    assert set(readers) == {("gradient", "custom-call"), ("local", "fusion")}, readers
+    assert readers["gradient", "custom-call"] == 10 + 1
+    assert readers["local", "fusion"] == 10 * 3 * 2
+
+
+def test_the_federated_cells_large_rows_carry_local(federated_cell_scan):
+    """The two kinds of row a trace shows largest, ``multiply_reduce_fusion
+    f32[262144,53]`` (the margins) and ``f32[262144,81]`` (the transpose
+    product), hold the later descents' gradients alone: every instruction of
+    either kind says ``local``, so the benchmark's reduction bills both rows
+    whole."""
+    table = device_scopes.scope_table(federated_cell_scan)
+    for shape in ("f32[262144,53]", "f32[262144,81]"):
+        scopes = [row["scope"] for row in table["rows"]
+                  if row["head"].startswith("%multiply_reduce_fusion")
+                  and row["head"].split(" = ")[1].startswith(shape)]
+        assert len(scopes) >= 8 * 3 and set(scopes) == {"local"}, (shape, scopes)
+
+
+def test_the_federated_cells_leaf_stays_an_argument(federated_cell_scan):
+    text = federated_cell_scan.as_text()
+    entry = text[text.index("ENTRY"):].split("\n", 1)[0]
+    assert entry.count("pred[250,262144]") == 1
+    memory = federated_cell_scan.memory_analysis()
+    # the temporaries of three unrolled plain gradients beside the kernel:
+    # 2.2 GB where the one-gradient cells hold 0.6-0.8 (PERF.md section 5)
+    assert memory.temp_size_in_bytes < 2_600_000_000, memory.temp_size_in_bytes
+    assert memory.generated_code_size_in_bytes < 32 * 2**20
