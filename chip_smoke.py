@@ -93,10 +93,15 @@ without a TPU it exits before doing any work. Segments:
 
 10. The shard visit at the GLM cells' shapes cut to 2^14 workers (ISSUE 41):
    the kernel's gradient and loss sums against XLA's two passes within 1e-6
-   of their scale; a run whose root says ``forward`` = ``fused`` and whose
+   of their scale, and the kernel without its objective half
+   (``glm_shard_gradient``, ISSUE 51) against the visit's gradient; a run
+   whose root says ``forward`` = ``fused`` and whose
    compiled scan holds the kernel's call and no ``copy`` or ``transpose`` of
    the shard stack (the kernel's ``[d, L, N]`` view is a bitcast of what the
-   runtime keeps); where four chips are visible the same run under
+   runtime keeps); the same run with four gradient steps a round, whose root
+   says ``local_forward`` = ``visited`` and ``shard_reads`` 4, against that
+   round with the visit's rule switched off (``carried``, ``recomputed``, 8)
+   within the federated cell's limits; where four chips are visible the run under
    ``worker_mesh=4``, ``fused`` too, within ``MESH_ATOL`` of the unsharded
    one. What the CPU cannot see: Mosaic's compile, the runtime's layout of
    the stack, and whether GSPMD leaves the ``shard_map`` round the call alone.
@@ -124,6 +129,7 @@ line of stdout is one JSON object naming the device as JAX reports it.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
@@ -623,6 +629,9 @@ def byzantine_segment(device: dict, *, n_workers: int = 1 << 16,
 # relative gap of the objective and of the consensus error over the rows.
 CARRY_LIMITS = {"objective": 1e-6, "consensus_error": 1e-5}
 CARRY_PEAK_ROOM = 100_000_000  # bytes the carried program may hold more
+# The federated cell's (benchmark/configs/glm81_ring262k_local4_part50.json):
+# four gradients a round round four times as often.
+LOCAL_LIMITS = {"objective": 2e-6, "consensus_error": 1e-5}
 
 
 def _glm_ring(seed: int, n_workers: int, rows: int, d: int, n_iterations: int,
@@ -650,28 +659,49 @@ def _glm_ring(seed: int, n_workers: int, rows: int, d: int, n_iterations: int,
     return cfg, ds
 
 
+@contextlib.contextmanager
+def _rule_off(name):
+    """A private rule of ``jax_backend`` (``_forward_is_carried``,
+    ``_visit_is_fused``) answering no inside the block; None: nothing."""
+    from distributed_optimization_tpu.backends import jax_backend
+
+    if name is None:
+        yield
+        return
+    decide = getattr(jax_backend, name)
+    setattr(jax_backend, name, lambda *a, **k: False)
+    try:
+        yield
+    finally:
+        setattr(jax_backend, name, decide)
+
+
+def _check_rows(segment: str, what: str, got, want, limits: dict) -> None:
+    """The objective and consensus rows of ``got`` within ``limits`` (worst
+    relative gap) of ``want``'s."""
+    for key, limit in limits.items():
+        a, b = getattr(got.history, key), getattr(want.history, key)
+        rel = float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)))
+        print(f"[chip_smoke] {segment}: {key} {what}, worst relative gap "
+              f"{rel:.3e} (limit {limit})", flush=True)
+        _check(a.shape == b.shape and rel <= limit,
+               f"{key} rows {what} within {limit}")
+
+
 def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
                           rows: int = 53, d: int = 80,
                           n_iterations: int = 200):
     """Runs first on its chip, recomputed before carried, so that the peak
     counter (which only rises) prices what the carry adds. Returns its
     experiment and the peak it leaves, for ``faults_segment``."""
-    from distributed_optimization_tpu.backends import jax_backend
-
     cfg, ds = _glm_ring(31, n_workers, rows, d, n_iterations)
 
     def run(form, switched_off=None, how="", **kw):
         """One run that must say ``forward`` = ``form``, a private rule of
         ``jax_backend`` switched off for it."""
-        decide = switched_off and getattr(jax_backend, switched_off)
-        if switched_off:
-            setattr(jax_backend, switched_off, lambda *a, **k: False)
-        try:
+        with _rule_off(switched_off):
             result, root, peak = _rooted_run(
                 f"forward {form}{how}", device, cfg, ds, ("forward",), **kw)
-        finally:
-            if switched_off:
-                setattr(jax_backend, switched_off, decide)
         _check(root["forward"] == form, f"the run's root says forward={form}")
         return result, peak
 
@@ -679,13 +709,10 @@ def forward_carry_segment(device: dict, *, n_workers: int = 65_536,
     peaks = {}
     for form, rule in (("carried", "_visit_is_fused"), ("fused", None)):
         got, peaks[form] = run(form, rule)
-        for key, limit in CARRY_LIMITS.items():
-            a, b = getattr(got.history, key), getattr(want.history, key)
-            rel = float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)))
-            print(f"[chip_smoke] forward: {key} {form} against recomputed, "
-                  f"worst relative gap {rel:.3e} (limit {limit})", flush=True)
-            _check(a.shape == (n_iterations,) and rel <= limit,
-                   f"{key} rows of the {form} run within {limit} of the recomputed")
+        _check(got.history.objective.shape == (n_iterations,),
+               f"the {form} run holds a row an iteration")
+        _check_rows("forward", f"{form} against recomputed", got, want,
+                    CARRY_LIMITS)
     print(f"[chip_smoke] forward: peak_bytes recomputed={peak_recomputed} "
           + " ".join(f"{k}={v}" for k, v in peaks.items()), flush=True)
     _check(max(peaks.values()) - peak_recomputed <= CARRY_PEAK_ROOM,
@@ -901,6 +928,14 @@ def shard_visit_segment(device: dict, *, n_workers: int = 1 << 14,
         print(f"[chip_smoke] visit: {name} against XLA's two passes, worst gap "
               f"{rel:.3e} of the scale (limit {VISIT_RTOL})", flush=True)
         _check(rel <= VISIT_RTOL, f"the visit's {name} within {VISIT_RTOL}")
+    # The kernel without its objective half (ISSUE 51): another compilation
+    # of the same sums in the same order.
+    alone = jax.jit(lambda: pallas_kernels.glm_shard_gradient(
+        link, Xs, ys, x, wts))()
+    rel = float(jnp.max(jnp.abs(alone - got[0])) / jnp.max(jnp.abs(got[0])))
+    print(f"[chip_smoke] visit: the gradient alone against the visit's, worst "
+          f"gap {rel:.3e} of the scale (limit {VISIT_RTOL})", flush=True)
+    _check(rel <= VISIT_RTOL, f"the gradient alone within {VISIT_RTOL}")
 
     one, root, _ = _rooted_run("visit", device, cfg, ds, ("forward",))
     _check(root["forward"] == "fused",
@@ -917,6 +952,23 @@ def shard_visit_segment(device: dict, *, n_workers: int = 1 << 14,
           f"copies or transposes of the stack: {moved}", flush=True)
     _check(calls >= 2 and not moved,
            "the compiled scan calls the kernel and moves no shard stack")
+    # Four gradient steps a round: the three later descents visit the shards
+    # once each, against the same round with the visit's rule switched off
+    # (the margins carried, the later gradients two plain passes).
+    local = cfg.replace(local_steps=4)
+    said = ("forward", "local_forward", "shard_reads")
+    got, root, _ = _rooted_run("visit, local_steps=4", device, local, ds, said)
+    _check((root["forward"], root["local_forward"], root["shard_reads"])
+           == ("fused", "visited", 4),
+           "a round of four gradients reads the shards four times")
+    with _rule_off("_visit_is_fused"):
+        want, root, _ = _rooted_run(
+            "carried, local_steps=4", device, local, ds, said)
+    _check((root["forward"], root["local_forward"], root["shard_reads"])
+           == ("carried", "recomputed", 8),
+           "the same round without the kernel reads them eight times")
+    _check_rows("visit", "visited round against recomputed", got, want,
+                LOCAL_LIMITS)
     if device["count"] < 4:
         return
     sharded = jax_backend.run(cfg.replace(worker_mesh=4), ds, 0.0,

@@ -561,6 +561,17 @@ def _make_step_eval(p: _StepPieces, data):
         round. A function of x and of row t of the timeline's leaves."""
         return faulty.rejoin_restart(t, x)
 
+    def on_own_rows(kernel, in_specs, out_specs):
+        """A kernel call whose every quantity is a worker's own: under a
+        mesh each device runs it on its rows (GSPMD cannot partition a
+        custom call) and no collective is added."""
+        if p.mesh is None:
+            return kernel
+        return jax.shard_map(
+            kernel, mesh=p.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        )
+
     def shard_visit(x, xbar, t_next):
         """ONE read of the shards: the gradient (less ``λx``) of iteration
         ``t_next`` at x and each worker's sum of losses at x̄ over its real
@@ -572,16 +583,27 @@ def _make_step_eval(p: _StepPieces, data):
 
         with device_scopes.scope("sampling"):
             wts = full_wts if full_batch else dense_weights(t_next, 0)
-        visit = functools.partial(glm_shard_visit, link)
-        if p.mesh is not None:
-            rows, whole = P(WORKER_AXIS), P()
-            visit = jax.shard_map(
-                visit, mesh=p.mesh,
-                in_specs=(rows, rows, rows, whole, rows, rows),
-                out_specs=(rows, rows), check_vma=False,
-            )
+        rows, whole = P(WORKER_AXIS), P()
+        visit = on_own_rows(
+            functools.partial(glm_shard_visit, link),
+            (rows, rows, rows, whole, rows, rows), (rows, rows),
+        )
         with device_scopes.scope("gradient"):
             return visit(X, y, x, xbar, wts, n_valid)
+
+    def shard_gradient(x, wts):
+        """ONE read of the shards for a gradient no eval rides with (less
+        ``λx``): the visit's kernel without its objective half, for a
+        round's later descents under ``fused``. A worker's own, as the
+        visit's; the caller's scope is the call's."""
+        from distributed_optimization_tpu.ops.pallas_kernels import (
+            glm_shard_gradient,
+        )
+
+        rows = P(WORKER_AXIS)
+        return on_own_rows(
+            functools.partial(glm_shard_gradient, link), (rows,) * 4, rows
+        )(X, y, x, wts)
 
     def forward_pass(x, xbar, t_next):
         """ONE pass over X for both of its readers: ``(fwd, at_xbar)``, fwd
@@ -619,8 +641,16 @@ def _make_step_eval(p: _StepPieces, data):
     def grad_fn_factory(t, fwd=None, fwd_of=None):
         """The iteration's ``ctx.grad``; ``fwd`` is the forward product of
         ``fwd_of`` as the scan carried it (a ``_Forward``), used where the
-        rule asks at that very array, slot 0."""
+        rule asks at that very array, slot 0. Under ``fused`` a round's
+        LATER descents (``local_steps`` > 1: a slot above 0) visit the
+        shards once each (``shard_gradient``); any other call is
+        ``gradient_weighted``, two reads."""
         def grad(params, slot):
+            # A round's first gradient, or one of its τ − 1 later descents
+            # (a static slot above 0, or the traced slot of the
+            # ``fori_loop`` form), which under ``fused`` visit the shards.
+            first = isinstance(slot, int) and slot == 0
+            visited = p.forward == "fused" and not first
             with device_scopes.scope("sampling"):
                 if schedule is not None:
                     idx = schedule[t]  # [N, b] injected batch indices
@@ -637,7 +667,14 @@ def _make_step_eval(p: _StepPieces, data):
                     # with 1/b weights on the sampled rows (same subsets as
                     # the gather path for the same key; see
                     # ops/sampling.py).
-                    Xb, yb, wts = X, y, dense_weights(t, slot)
+                    t_due = t
+                    if visited:
+                        # as the gather sampler below: a later descent's
+                        # draw is made when its gradient is due
+                        params, t_due = jax.lax.optimization_barrier(
+                            (params, t)
+                        )
+                    Xb, yb, wts = X, y, dense_weights(t_due, slot)
                 else:
                     slot_key = jax.random.fold_in(p.key, slot)
                     # A batch is drawn when its gradient is due: the draw
@@ -648,10 +685,8 @@ def _make_step_eval(p: _StepPieces, data):
                         slot_key, t_due, table, n_valid, batch_size
                     )
                     wts = wts.astype(X.dtype)  # keep bf16 carries unpromoted
-            # A round's first gradient is ``gradient``; those of its τ − 1
-            # later descents (a static slot above 0, or the traced slot of
-            # the ``fori_loop`` form) are ``local``. Metadata only.
-            first = isinstance(slot, int) and slot == 0
+            # The first is ``gradient``, the later ones ``local``. Metadata
+            # only.
             with device_scopes.scope("gradient" if first else "local"):
                 if fwd is not None and params is fwd_of and slot == 0:
                     if p.forward == "fused":
@@ -659,6 +694,8 @@ def _make_step_eval(p: _StepPieces, data):
                     return jax.vmap(
                         link.gradient_at, in_axes=(0, 0, 0, 0, 0, None)
                     )(fwd.product, params, Xb, yb, wts, p.reg)
+                if visited:
+                    return shard_gradient(params, wts) + p.reg * params
                 return jax.vmap(
                     p.problem.gradient_weighted, in_axes=(0, 0, 0, 0, None)
                 )(params, Xb, yb, wts, p.reg)
@@ -963,18 +1000,22 @@ _FIRST_READS = {"fused": 1, "carried": 2, "recomputed": 3}
 def _local_root_args(local_steps: int, forward: str) -> dict:
     """What the ``dopt.run`` root says of a call whose gossip round holds
     more than one gradient step (``local_steps`` = τ > 1), and of no other:
-    ``local_steps``; ``local_forward``, how the τ − 1 later gradients are
-    made (``recomputed``: ``grad_fn_factory`` takes the carried product at
-    slot 0 alone, so each later one is ``gradient_weighted`` over the whole
-    padded shard: the engagement counter of any path that serves them
-    otherwise); and ``shard_reads``, the reads of the shard stack one round
-    was BUILT with, from ``forward`` and τ and nothing measured: the first
-    gradient and the objective one, two or three, each later gradient two
-    (X·x, then Xᵀ·c). A plan, as ``ici_bytes_per_round`` is."""
+    ``local_steps``; ``local_forward``, how the τ − 1 later gradients were
+    built (``visited`` under ``forward`` = ``fused``: each ONE read of the
+    shards by ``glm_shard_gradient``, the visit's kernel without its
+    objective half; ``recomputed`` everywhere else: ``gradient_weighted``
+    over the whole padded shard, X·x and then Xᵀ·c: the engagement counter
+    of the path that serves them); and ``shard_reads``, the reads of the
+    shard stack one round was BUILT with, from ``forward`` and τ and nothing
+    measured: the first gradient and the objective one, two or three, each
+    later gradient one where visited and two where recomputed. A plan, as
+    ``ici_bytes_per_round`` is."""
+    visited = forward == "fused"
     return {
         "local_steps": local_steps,
-        "local_forward": "recomputed",
-        "shard_reads": _FIRST_READS[forward] + 2 * (local_steps - 1),
+        "local_forward": "visited" if visited else "recomputed",
+        "shard_reads": _FIRST_READS[forward]
+        + (1 if visited else 2) * (local_steps - 1),
     }
 
 

@@ -1,12 +1,17 @@
-"""The Pallas TPU kernel of the scan: ``glm_shard_visit``.
+"""The Pallas TPU kernel of the scan, under its two entries.
 
-ONE read of a GLM's shard stack ``[N, L, d]`` for everything an iteration of
-the fused scan asks of it: the margins X·x and X·x̄, the weighted gradient
-Xᵀ·coeff and the objective's partial sums at x̄, over blocks of workers with
-the worker axis on the lanes. It is taken by what a run is
-(``jax_backend._visit_is_fused``), never by an option, and it is here because
-the ledger says it wins (PERF.md §6, PR 41); a kernel without such a line has
-no place in this module.
+``glm_shard_visit``: ONE read of a GLM's shard stack ``[N, L, d]`` for
+everything an iteration of the fused scan asks of it: the margins X·x and
+X·x̄, the weighted gradient Xᵀ·coeff and the objective's partial sums at x̄,
+over blocks of workers with the worker axis on the lanes.
+``glm_shard_gradient`` (ISSUE 51): the same kernel built without its
+objective half (z alone, g alone), for a gradient no eval rides with: the
+τ − 1 later descents of a round of ``local_steps`` = τ > 1. One maker, one
+set of block specs, one VMEM rule; two names, because a trace row is billed
+to a device scope by its kind and the two serve different scopes. Both are
+taken by what a run is (``jax_backend._visit_is_fused``), never by an
+option, and are here because the ledger says the kernel wins (PERF.md §6,
+PR 41 and PR 51); a kernel without such a line has no place in this module.
 
 The kernel compiles via Mosaic on a TPU and runs in interpreter mode on the
 CPU (tests). Which of the two follows the INPUT's committed platform — not
@@ -118,28 +123,41 @@ def shard_visit_lanes(n: int, rows: int, d: int, itemsize: int = 4):
     return n if n <= lanes else lanes
 
 
-def _make_shard_visit_kernel(link, d: int, lanes: int):
+def _make_shard_visit_kernel(link, d: int, lanes: int, objective: bool):
+    """The visit's body; ``objective`` says whether the objective at x̄ is
+    asked for beside the gradient. Without it a strip makes z alone and
+    writes g: no ``xbar`` (SMEM), no ``n_valid``, no ``f``."""
     full, tail = divmod(lanes, LANES)
 
     def strip(refs, cols):
         """One strip of at most 128 workers: both sweeps of its slabs."""
-        xbar_ref, X_ref, x_ref, y_ref, w_ref, nv_ref, g_ref, f_ref = refs
-        z = zbar = None
+        if objective:
+            xbar_ref, X_ref, x_ref, y_ref, w_ref, nv_ref, g_ref, f_ref = refs
+        else:
+            X_ref, x_ref, y_ref, w_ref, g_ref = refs
+        sums = None  # z, and z̄ beside it where the objective is asked for
         for k in range(d):
             slab = X_ref[k, :, cols]
-            own, mean = slab * x_ref[k:k + 1, cols], slab * xbar_ref[k]
-            z, zbar = (own, mean) if z is None else (z + own, zbar + mean)
+            terms = [slab * x_ref[k:k + 1, cols]]
+            if objective:
+                terms.append(slab * xbar_ref[k])
+            sums = terms if sums is None else [
+                s + t for s, t in zip(sums, terms)
+            ]
+        z = sums[0]
         y = y_ref[:, cols]
         c = w_ref[:, cols] * link.coeff(z, y)
         for k in range(d):
             g_ref[k:k + 1, cols] = jnp.sum(
                 X_ref[k, :, cols] * c, axis=0, keepdims=True
             )
-        row = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
-        f_ref[:, cols] = jnp.sum(
-            jnp.where(row < nv_ref[:, cols], link.loss(zbar, y), 0.0),
-            axis=0, keepdims=True,
-        )
+        if objective:
+            zbar = sums[1]
+            row = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
+            f_ref[:, cols] = jnp.sum(
+                jnp.where(row < nv_ref[:, cols], link.loss(zbar, y), 0.0),
+                axis=0, keepdims=True,
+            )
 
     def kernel(*refs):
         if full:
@@ -151,6 +169,54 @@ def _make_shard_visit_kernel(link, d: int, lanes: int):
             strip(refs, slice(full * LANES, lanes))
 
     return kernel
+
+
+def _shard_visit_call(link, name, X, y, x, wts, objective=None,
+                      interpret: Optional[bool] = None):
+    """Both entries' one ``pallas_call``: a grid over blocks of
+    ``shard_visit_lanes`` workers, the block specs and the VMEM rule the
+    same whether ``objective`` = ``(xbar, n_valid)`` is asked for or not.
+    Returns the kernel's own results, ``[g [d, N]]`` or ``[g, f [1, N]]``."""
+    n, rows, d = X.shape
+    lanes = shard_visit_lanes(n, rows, d, X.dtype.itemsize)
+    if lanes is None:
+        raise ValueError(
+            f"a block of {LANES} shards [{rows}, {d}] does not fit the "
+            f"visit's VMEM budget twice ({SHARD_VISIT_VMEM_BYTES} B)"
+        )
+    by_lanes = lambda i: (0, i)  # noqa: E731
+    rows_spec = pl.BlockSpec((rows, lanes), by_lanes)
+    model_spec = pl.BlockSpec((d, lanes), by_lanes)
+    worker_spec = pl.BlockSpec((1, lanes), by_lanes)
+    in_specs = [
+        pl.BlockSpec((d, rows, lanes), lambda i: (0, 0, i)),
+        model_spec, rows_spec, rows_spec,
+    ]
+    operands = [X.transpose(2, 1, 0), x.T, y.T, wts.T]
+    out_specs = [model_spec]
+    out_shape = [jax.ShapeDtypeStruct((d, n), X.dtype)]
+    if objective is not None:
+        xbar, n_valid = objective
+        in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), *in_specs,
+                    worker_spec]
+        operands = [xbar, *operands, n_valid.astype(jnp.int32)[None, :]]
+        out_specs.append(worker_spec)
+        out_shape.append(jax.ShapeDtypeStruct((1, n), X.dtype))
+    return pl.pallas_call(
+        _make_shard_visit_kernel(link, d, lanes, objective is not None),
+        grid=(pl.cdiv(n, lanes),),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=2 * _visit_block_bytes(
+                d, rows, lanes, X.dtype.itemsize
+            ) + SHARD_VISIT_VMEM_SPARE,
+        ),
+        interpret=resolve_interpret(X, interpret),
+        name=name,
+    )(*operands)
 
 
 def glm_shard_visit(link, X, y, x, xbar, wts, n_valid,
@@ -169,38 +235,21 @@ def glm_shard_visit(link, X, y, x, xbar, wts, n_valid,
     kernel reads the transposed views ``[d, L, N]``, ``[d, N]``, ``[L, N]``:
     bitcasts where the worker axis is the minor one in memory, as the TPU
     runtime keeps these shapes (tests/test_tpu_compile.py)."""
-    n, rows, d = X.shape
-    lanes = shard_visit_lanes(n, rows, d, X.dtype.itemsize)
-    if lanes is None:
-        raise ValueError(
-            f"a block of {LANES} shards [{rows}, {d}] does not fit the "
-            f"visit's VMEM budget twice ({SHARD_VISIT_VMEM_BYTES} B)"
-        )
-    by_lanes = lambda i: (0, i)  # noqa: E731
-    rows_spec = pl.BlockSpec((rows, lanes), by_lanes)
-    model_spec = pl.BlockSpec((d, lanes), by_lanes)
-    worker_spec = pl.BlockSpec((1, lanes), by_lanes)
-    g, f = pl.pallas_call(
-        _make_shard_visit_kernel(link, d, lanes),
-        grid=(pl.cdiv(n, lanes),),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((d, rows, lanes), lambda i: (0, 0, i)),
-            model_spec, rows_spec, rows_spec, worker_spec,
-        ],
-        out_specs=[model_spec, worker_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((d, n), X.dtype),
-            jax.ShapeDtypeStruct((1, n), X.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=2 * _visit_block_bytes(
-                d, rows, lanes, X.dtype.itemsize
-            ) + SHARD_VISIT_VMEM_SPARE,
-        ),
-        interpret=resolve_interpret(X, interpret),
-        name="glm_shard_visit",
-    )(xbar, X.transpose(2, 1, 0), x.T, y.T, wts.T,
-      n_valid.astype(jnp.int32)[None, :])
+    g, f = _shard_visit_call(
+        link, "glm_shard_visit", X, y, x, wts, (xbar, n_valid), interpret
+    )
     return g.T, f[0]
+
+
+def glm_shard_gradient(link, X, y, x, wts, interpret: Optional[bool] = None):
+    """The visit without its objective half: ``g [N, d]`` alone, the same
+    sums in the same order as ``glm_shard_visit``'s g (to the bit), from one
+    read of the shards, for a gradient that no eval rides with (a round's
+    later descents). A kernel of its own NAME, so that a trace shows it as a
+    row of its own and a program's scope table bills that row to the scope
+    its callers carry (``benchmark/scope_reduce.py`` bills a row only where
+    every instruction of its kind shares one scope)."""
+    (g,) = _shard_visit_call(
+        link, "glm_shard_gradient", X, y, x, wts, None, interpret
+    )
+    return g.T

@@ -471,7 +471,9 @@ def test_fused_run_is_the_carried_and_the_recomputed_run(
 @pytest.mark.parametrize("kw", [
     dict(straggler_prob=0.3),  # a frozen row's gradient is its frozen model's
     dict(eval_every=6, scan_unroll=4),  # micro = 3, two trips an eval
-    dict(local_steps=3),  # later slots sample and read for themselves
+    # later slots draw for themselves and visit the shards once each (ISSUE
+    # 51: ``glm_shard_gradient``), two plain passes under the other forms
+    dict(local_steps=3),
     dict(n_features=80, n_informative_features=40),  # the study's width
 ], ids=["stragglers", "micro3_two_trips", "local_steps3", "d81"])
 def test_only_the_trips_first_gradient_is_the_carried_one(kw, monkeypatch):
@@ -503,12 +505,15 @@ def test_under_restart_the_visit_takes_the_gradient_at_the_restarted_models(
     )
 
 
-def test_fused_on_one_device_is_fused_over_the_mesh(monkeypatch):
+@pytest.mark.parametrize("local_steps", [1, 3])
+def test_fused_on_one_device_is_fused_over_the_mesh(local_steps, monkeypatch):
     """Under a mesh the visit runs under ``shard_map``, each device on its
     rows: the same kernel on the same numbers (the objective's cross-device
-    sum falls in another order)."""
+    sum falls in another order); and so does the visit without its objective
+    half, a round's later descents (ISSUE 51)."""
     fused(monkeypatch)
-    cfg = small_backend_config(problem_type="logistic", sampling_impl="dense")
+    cfg = small_backend_config(
+        problem_type="logistic", sampling_impl="dense", local_steps=local_steps)
     ds = generate_synthetic_dataset(cfg)
     sharded, root = run_rooted(cfg, ds)
     one, root_one = run_rooted(cfg, ds, use_mesh=False)

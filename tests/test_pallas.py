@@ -1,6 +1,7 @@
 """Pallas kernel tests (interpreter mode on CPU — same code path Mosaic
-compiles on real TPU): the shard visit against XLA's two passes, and how a
-call decides between the interpreter and Mosaic.
+compiles on real TPU): the shard visit against XLA's two passes, the visit
+without its objective half against the visit, and how a call decides between
+the interpreter and Mosaic.
 """
 
 import functools
@@ -77,6 +78,30 @@ def test_shard_visit_is_the_two_passes(family, dtype, visit, rng):
         assert_ulps_of_scale(g, want_g, VISIT_ULPS)
         assert_ulps_of_scale(f, want_f, VISIT_ULPS)
         assert float(jnp.max(jnp.abs(g[0]))) == 0.0 == float(f[0])  # empty shard
+
+
+# A full strip and a lane tail of two at a width an interpreted compile is
+# quick at (the visit's own cases hold the study's shard), and the ragged
+# last block.
+GRADIENTS = {"strip_and_tail": (130, 13, 9),
+             "ragged_last_block": VISITS["ragged_last_block"]}
+
+
+@pytest.mark.parametrize("visit", sorted(GRADIENTS))
+@pytest.mark.parametrize("family", ["logistic", "quadratic"])
+def test_shard_gradient_is_the_visits_g_to_the_bit(family, visit, rng):
+    """The kernel without its objective half (ISSUE 51) makes the visit's g
+    itself: the same products and sums in the same order, so equal to the
+    bit, under a ragged ``n_valid`` (which it never reads: the weights are 0
+    on the padding), with a lane tail and with a ragged last block."""
+    X, y, x, xbar, wts, n_valid = visit_case(rng, *GRADIENTS[visit], "float32")
+    link = LINKS[family]
+    g, _ = jax.jit(functools.partial(pk.glm_shard_visit, link))(
+        X, y, x, xbar, wts, n_valid)
+    alone = jax.jit(functools.partial(pk.glm_shard_gradient, link))(X, y, x, wts)
+    assert alone.shape == g.shape and alone.dtype == g.dtype
+    np.testing.assert_array_equal(np.asarray(alone), np.asarray(g))
+    assert float(jnp.max(jnp.abs(alone[0]))) == 0.0  # the empty shard
 
 
 def test_shard_visit_block_is_sized_by_the_budget():

@@ -7,6 +7,7 @@ import collections
 import json
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +186,31 @@ def test_the_shard_visit_reads_the_stack_where_it_lies(shard_visit_at_the_cell):
     assert shard_visit_at_the_cell.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_the_shard_gradient_reads_the_stack_where_it_lies(one_chip):
+    """The visit without its objective half (ISSUE 51) at the same size: the
+    same bitcast views, ONE custom call under its own name with ONE result,
+    no temporary."""
+    from distributed_optimization_tpu.ops import pallas_kernels as pk
+    from distributed_optimization_tpu.ops.losses import LOGISTIC
+
+    n, rows, d = 1 << 18, 53, 81
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: pk.glm_shard_gradient(LOGISTIC, *a, interpret=False)
+    ).lower(shape(n, rows, d), shape(n, rows), shape(n, d), shape(n, rows)).compile()
+    text = compiled.as_text()
+    ops = [ins for ins in map(device_scopes._instruction, text.splitlines()) if ins is not None]
+    assert sorted({ins[2] for ins in ops} - {"parameter", "tuple", "get-tuple-element"}) == [
+        "bitcast", "custom-call"]
+    (call,) = [ins for ins in ops if ins[2] == "custom-call"]
+    assert call[0].startswith("%glm_shard_gradient") and call[1].startswith("f32[81,262144]")
+    assert "f32[81,53,262144]{2,1,0" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.fixture(scope="module")
 def byzantine_round(one_chip):
     """One screened gossip round at the Byzantine cell's size (ISSUE 43: a
@@ -354,11 +380,13 @@ def test_a_timelines_chain_is_one_scan_and_its_leaf_a_byte_a_bit(one_chip):
     assert (back.output_size_in_bytes, back.temp_size_in_bytes) == (horizon * n, 0)
 
 
-def _cell_scan(one_chip, config_name, horizon):
+def _cell_scan(one_chip, config_name, horizon, **replace):
     """A GLM ring cell's whole scan as ``_run`` builds it (the dense sampler,
     the visit fused as on the chip) at N = 2^18, L = 53, d = 81, unroll 8,
     compiled for ``horizon`` trips with the timeline's ``pred[horizon, N]``
-    leaves as arguments. No shard, timeline or model of that size is made:
+    leaves (where the cell has a fault layer) as arguments; ``replace``
+    overrides fields of the file's experiment. No shard, timeline or model
+    of that size is made:
     the call is cut where it hands its program to the driver, the program
     lowered from shapes."""
     from distributed_optimization_tpu.backends import jax_backend
@@ -373,8 +401,8 @@ def _cell_scan(one_chip, config_name, horizon):
     assert (experiment["n_workers"], experiment["n_features"]) == (n, d - 1)
     # what ``auto`` takes on the chip (a CPU's is the gather sampler, unroll 1)
     cfg = ExperimentConfig(
-        **experiment, n_samples=n * rows, sampling_impl="dense", scan_unroll=8,
-        n_iterations=2, eval_every=1)
+        **{**experiment, **replace}, n_samples=n * rows, sampling_impl="dense",
+        scan_unroll=8, n_iterations=2, eval_every=1)
 
     class Handed(Exception):
         pass
@@ -403,9 +431,10 @@ def _cell_scan(one_chip, config_name, horizon):
             jax_backend.run(cfg, nothing, 0.0, use_mesh=False, measure_compile=False)
         make_seg_scan, state0, data = handed.value.args
         data = jax.tree.map(shaped, data)
-        data["faults"] = {
-            k: jax.ShapeDtypeStruct((horizon, n), v.dtype, sharding=one_chip)
-            for k, v in data["faults"].items()}
+        if "faults" in data:  # the fault-free control has no timeline
+            data["faults"] = {
+                k: jax.ShapeDtypeStruct((horizon, n), v.dtype, sharding=one_chip)
+                for k, v in data["faults"].items()}
         return jax.jit(make_seg_scan(horizon)).lower(
             jax.tree.map(shaped, state0),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), data).compile()
@@ -472,7 +501,7 @@ def federated_cell_scan(one_chip):
 
 
 def _stack_readers(text):
-    """(scope, opcode) of every instruction that reads the
+    """(scope, opcode, ``name = shape``) of every instruction that reads the
     ``f32[262144,53,81]`` stack, through whatever holds it in its
     computation (a parameter, the loop's element, a bitcast of either);
     bitcasts, tuples and loops themselves left out, and the insides of a
@@ -490,37 +519,48 @@ def _stack_readers(text):
         if name in fused:
             continue
         held = {ins[0] for ins, _ in body if stack.match(ins[1])}
-        readers += [(scope, ins[2]) for ins, scope in body
+        readers += [(scope, ins[2], f"{ins[0]} = {ins[1]}") for ins, scope in body
                     if held & set(ins[3]) and ins[2] not in ("bitcast", "tuple", "while")]
     return readers
 
 
-def test_the_federated_cells_round_reads_the_shards_seven_times(federated_cell_scan):
-    """A round of tau = 4 as the root's ``shard_reads`` plans it: ONE
-    ``glm_shard_visit`` (the first gradient and the objective, ``gradient``)
-    and, for each of the three later descents, two fusions that read the
-    stack (X.x, then X^T.c), every one of them under ``local`` and none under
-    any other scope: 7 reads a trip. 250 trips are 31 bodies of eight and a
+def test_the_federated_cells_round_reads_the_shards_four_times(federated_cell_scan):
+    """A round of tau = 4 as the root's ``shard_reads`` plans it (ISSUE 51):
+    ONE ``glm_shard_visit`` (the first gradient and the objective,
+    ``gradient``) and, for each of the three later descents, ONE
+    ``glm_shard_gradient`` (the visit without its objective half, ``local``);
+    no fusion reads the stack: 4 reads a trip, where the later descents' X.x
+    and X^T.c fusions made it 7. 250 trips are 31 bodies of eight and a
     remainder of two, so ten trips are written out: ten visits and one in
-    front of the loops, sixty fusions."""
-    readers = collections.Counter(_stack_readers(federated_cell_scan.as_text()))
-    assert set(readers) == {("gradient", "custom-call"), ("local", "fusion")}, readers
-    assert readers["gradient", "custom-call"] == 10 + 1
-    assert readers["local", "fusion"] == 10 * 3 * 2
+    front of the loops, thirty gradients."""
+    readers = collections.Counter(
+        (scope, opcode, name.split(".")[0])
+        for scope, opcode, name in _stack_readers(federated_cell_scan.as_text()))
+    assert readers == {("gradient", "custom-call", "%glm_shard_visit"): 10 + 1,
+                       ("local", "custom-call", "%glm_shard_gradient"): 10 * 3}, readers
 
 
-def test_the_federated_cells_large_rows_carry_local(federated_cell_scan):
-    """The two kinds of row a trace shows largest, ``multiply_reduce_fusion
-    f32[262144,53]`` (the margins) and ``f32[262144,81]`` (the transpose
-    product), hold the later descents' gradients alone: every instruction of
-    either kind says ``local``, so the benchmark's reduction bills both rows
-    whole."""
-    table = device_scopes.scope_table(federated_cell_scan)
-    for shape in ("f32[262144,53]", "f32[262144,81]"):
-        scopes = [row["scope"] for row in table["rows"]
-                  if row["head"].startswith("%multiply_reduce_fusion")
-                  and row["head"].split(" = ")[1].startswith(shape)]
-        assert len(scopes) >= 8 * 3 and set(scopes) == {"local"}, (shape, scopes)
+def test_the_federated_cells_kernel_rows_carry_one_scope_each(federated_cell_scan):
+    """Every instruction of the later descents' kernel says ``local`` and
+    every ``glm_shard_visit`` ``gradient``: two kinds of row by the
+    benchmark's own rule (``trace_reduce.op_kind``: another stem, another
+    output type), and no kind of row that reads the stack holds two scopes,
+    so the reduction bills each row whole and neither reads 0."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import scope_reduce, trace_reduce
+
+    kinds = scope_reduce.kinds_of([device_scopes.scope_table(federated_cell_scan)])
+    assert {k: v["scopes"] for k, v in kinds.items() if k.startswith("glm_shard_")} == {
+        "glm_shard_gradient f32[81,262144]": {"local"},
+        "glm_shard_visit (f32[81,262144]": {"gradient"}}
+    reading = {trace_reduce.op_kind(name)
+               for _, _, name in _stack_readers(federated_cell_scan.as_text())}
+    assert len(reading) == 2 and all(len(kinds[k]["scopes"]) == 1 for k in reading), reading
+    # the plain later gradients' two large rows went with them
+    assert not [k for k, v in kinds.items()
+                if k.startswith("multiply_reduce_fusion") and "local" in v["scopes"]]
 
 
 def test_the_federated_cells_leaf_stays_an_argument(federated_cell_scan):
@@ -528,7 +568,31 @@ def test_the_federated_cells_leaf_stays_an_argument(federated_cell_scan):
     entry = text[text.index("ENTRY"):].split("\n", 1)[0]
     assert entry.count("pred[250,262144]") == 1
     memory = federated_cell_scan.memory_analysis()
-    # the temporaries of three unrolled plain gradients beside the kernel:
-    # 2.2 GB where the one-gradient cells hold 0.6-0.8 (PERF.md section 5)
-    assert memory.temp_size_in_bytes < 2_600_000_000, memory.temp_size_in_bytes
+    # the later gradients' [N, 53] margins went with their fusions: 2.2 GB
+    # at PR 50 (PERF.md section 5)
+    assert memory.temp_size_in_bytes < 1_300_000_000, memory.temp_size_in_bytes
     assert memory.generated_code_size_in_bytes < 32 * 2**20
+
+
+def test_a_round_of_one_gradient_holds_no_shard_gradient(one_chip):
+    """The tau = 1 control (``glm81_ring262k``, everyone taking part): its
+    compiled scan visits the shards once a trip and holds no instruction of
+    the later descents' kernel, nor anything under ``local``."""
+    text = _cell_scan(one_chip, "glm81_ring262k", 250).as_text()
+    assert "glm_shard_gradient" not in text and "dopt.local" not in text
+    readers = collections.Counter(
+        (scope, opcode, name.split(".")[0]) for scope, opcode, name in _stack_readers(text))
+    assert readers == {("gradient", "custom-call", "%glm_shard_visit"): 10 + 1}, readers
+
+
+def test_the_loop_form_of_the_local_descents_visits_once_a_descent(one_chip):
+    """tau = 10 on the federated cell (nine descents, over ``LOCAL_UNROLL_MAX``:
+    a ``fori_loop`` whose slot is traced): Mosaic and XLA compile the kernel
+    inside the loop's body, ONE ``glm_shard_gradient`` a written-out trip
+    under ``local`` at weights drawn from the traced slot, and nothing else
+    reads the stack."""
+    text = _cell_scan(one_chip, "glm81_ring262k_local4_part50", 250, local_steps=10).as_text()
+    readers = collections.Counter(
+        (scope, opcode, name.split(".")[0]) for scope, opcode, name in _stack_readers(text))
+    assert readers == {("gradient", "custom-call", "%glm_shard_visit"): 10 + 1,
+                       ("local", "custom-call", "%glm_shard_gradient"): 10}, readers

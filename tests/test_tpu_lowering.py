@@ -272,6 +272,24 @@ def test_shard_visit_lowers_for_tpu(shape, family):
     assert "stablehlo.reduce" not in text and "dot_general" not in text
 
 
+@pytest.mark.parametrize("family", ["logistic", "quadratic", "huber"])
+@pytest.mark.parametrize("shape", sorted(VISIT_SHAPES))
+def test_shard_gradient_lowers_for_tpu(shape, family):
+    """``glm_shard_gradient`` (ISSUE 51: the visit without its objective
+    half) through the same rules at the same shapes: ONE custom call of four
+    operands (no x̄ in SMEM, no row counts) and one result."""
+    from distributed_optimization_tpu.models import get_problem
+
+    link = get_problem(family).link
+    X, y, x, _, wts, _ = _visit_arguments(*VISIT_SHAPES[shape])
+    text = _lower_for_tpu(
+        lambda *a: pk.glm_shard_gradient(link, *a, interpret=False), X, y, x, wts,
+    ).mlir_module()
+    (call,) = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\)", text)
+    assert len(call.split(",")) == 4
+    assert "stablehlo.reduce" not in text and "dot_general" not in text
+
+
 def test_the_fused_scan_reaches_the_chip_as_one_visit_a_trip(monkeypatch):
     """The program a TPU is handed where ``forward`` = ``fused``: in the
     loop's body ONE kernel call and no ``dot_general`` or ``reduce`` over
