@@ -1038,6 +1038,26 @@ def _gather_root_args(topo, tables) -> dict:
     }
 
 
+def _halo_gather_root_args(mix_op) -> dict:
+    """What the ``dopt.run`` root says of a ``worker_mesh`` call whose static
+    graph mixes by the halo gather, for the fullest shard: what
+    ``_gather_root_args`` says of the one-chip gather (the table's width,
+    the rows a round gathers from the halo-extended block: every slot of
+    every row, padded ones too), the rotations the plan holds, and what the
+    per-shard tables and the exchange's index lists take on a device and
+    how the program holds them (``constant``: closed into the executable;
+    ``argument`` where an op has a ``bind``)."""
+    tables = mix_op.tables
+    _, k_max, shard_rows = tables["nbr"].shape
+    return {
+        "k_max": int(k_max),
+        "gathered_rows": int(shard_rows * k_max),
+        "halo_steps": len(tables["send"]),
+        "halo_table_bytes": _device_bytes(tables),
+        "halo_tables": "constant" if mix_op.bind is None else "argument",
+    }
+
+
 def _byzantine_root_args(config, topo, adversary, halo_mesh) -> dict:
     """What the ``dopt.run`` root says of a call that was attacked,
     screened or both (none of it on a benign call): ``attack`` (payload,
@@ -1867,6 +1887,12 @@ def _run(
                 make_halo_mixing_op,
             )
 
+            # ``halo_plan``: the static plan of the exchange (which rows
+            # each shard sends on which rotation) and the per-shard tables
+            # over the halo-extended block, made on the host and put on a
+            # device; a ring's two shifts plan nothing
+            # (docs/OBSERVABILITY.md).
+            spans.enter("halo_plan")
             mix_op = make_halo_mixing_op(
                 topo, mesh, dtype=device_data.X.dtype,
                 overlap=config.halo_overlap,
@@ -1880,6 +1906,8 @@ def _run(
                 compressed_mix = make_halo_compressed_mixing_op(
                     topo, mesh, dtype=device_data.X.dtype
                 )
+            spans.note(form=mix_op.impl)
+            spans.enter("prepare")
         elif (
             mesh is None and use_mesh and len(jax.devices()) > 1
             and not topo.is_matrix_free
@@ -2047,6 +2075,11 @@ def _run(
                     max(_ici["bytes_per_device_per_round"])
                 ),
             )
+            if faulty is None and mix_op.tables is not None:
+                # The halo gather says what the one-chip gather says
+                # (ISSUE 52); under faults the fault layer mixes, over
+                # tables of its own.
+                spans.note_root(**_halo_gather_root_args(mix_op))
     else:
         if (
             config.edge_drop_prob > 0.0

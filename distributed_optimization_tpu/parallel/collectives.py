@@ -393,7 +393,17 @@ def _make_halo_gather_mixing_op(
     boundary rows arriving over ICI as ppermute traffic instead of being
     addressed in one device's HBM. On the chip the gather is the cost
     (priced by its indices, whatever each fetches), which is why a ring's
-    table never comes here (``make_halo_mixing_op``).
+    table never comes here (``make_halo_mixing_op``). What a round lowers
+    to at k_max = 4, a 1024 x 1024 torus over four v5e (PERF.md section 5,
+    PR 52's traced run): the block copied row-major (the scan carries it
+    worker-minor) and back at the end, two 1,024-row gathers for the
+    sends, two ``collective-permute``s of ``f32[1024,81]``, two scatters
+    into the ``[2049, 81]`` halo, the concatenate as a pad-and-maximum
+    ``f32[264193,81]``, then PER SLOT an index clamp, one row gather
+    ``fusion f32[262144,81]`` (2.54 ms each: 10.2 of the round's 12.4 ms)
+    and one ``multiply_add_fusion`` (0.44 ms), the first slot's written
+    out and the other three inside ``slot_sum``'s ``while``; the permutes
+    themselves lie under the ten rows a trace reader is handed.
 
     ``overlap='double_buffer'`` (config.halo_overlap; docs/PERF.md §17)
     restructures ``apply`` into the stencil latency-hiding form: the
@@ -463,6 +473,14 @@ def _make_halo_gather_mixing_op(
         "halo_gather",
         apply_overlap if overlap == "double_buffer" else apply,
         neighbor_sum,
+        # Every device array the operators read, whole (all P shards'):
+        # with no ``bind`` they are constants of whatever program closes
+        # over this op, and the ``dopt.run`` root says what they take
+        # (``halo_table_bytes``, ``halo_tables`` = ``constant``).
+        tables={
+            "nbr": nbr_sm, "w_nbr": w_nbr, "w_self": w_self,
+            "send": hx.sends, "recv": hx.recvs,
+        },
     )
 
 

@@ -235,7 +235,8 @@ def test_root_names_the_worker_mesh_and_its_halo(setup):
     the fullest device's boundary rows and bytes over ICI a
     round, the numbers of ``telemetry.ici_summary`` (and so of the
     ``dopt_worker_mesh_*`` gauges); the upload's ``bytes`` stay the total,
-    and no child span is added."""
+    and ONE child span is added, ``halo_plan`` round the making of the halo
+    mixing (ISSUE 52), which says the form too."""
     from distributed_optimization_tpu.telemetry import ici_summary
 
     cfg, ds = setup
@@ -250,9 +251,13 @@ def test_root_names_the_worker_mesh_and_its_halo(setup):
     assert args["halo_rows"] == max(ici["halo_rows_per_device"]) == 2
     assert args["ici_bytes_per_round"] == max(
         ici["bytes_per_device_per_round"]) == 2 * ds.n_features * 4
-    assert names(children) in (CHILDREN_COLD, CHILDREN_WARM)
+    at = CHILDREN_COLD.index("topology") + 2  # after the prepare that follows it
+    under_a_mesh = CHILDREN_COLD[:at] + ["halo_plan", "prepare"] + CHILDREN_COLD[at:]
+    assert names(children) in (
+        under_a_mesh, [c for c in under_a_mesh if c != "compile"])
     stacked = stack_shards(ds, dtype=np.float32)
     by_name = {e["name"]: e for e in children}
+    assert by_name["dopt.run.halo_plan"]["args"] == {"form": "halo_shift"}
     assert by_name["dopt.run.upload"]["args"]["bytes"] == (
         stacked.X.nbytes + stacked.y.nbytes + stacked.n_valid.nbytes
     )
