@@ -204,8 +204,7 @@ bench-mesh:
 # Regenerate the million-worker mesh evidence (docs/perf/mesh_scale.json:
 # N=1M ring/torus sharded completions over 16 forced host devices, flat
 # per-device memory at matched rows/device, the O(N·k_max) sparse ER
-# build at 1M, the <=50% compressed-halo wire cut inside the 2.5x gap
-# envelope, and the measured overlap ratio — the script forces the
-# 16-device host platform itself).
+# build at 1M and the <=50% compressed-halo wire cut inside the 2.5x gap
+# envelope — the script forces the 16-device host platform itself).
 bench-mesh-scale:
 	$(PY) examples/bench_mesh_scale.py

@@ -9,7 +9,8 @@ pytree state whose leaves are ``[N, d]``-stacked arrays, so the same rule
 - runs step-at-a-time under numpy on the fidelity path, and
 - is agnostic to how its collectives are realized (the ``StepContext``
   carries ``mix``/``neighbor_sum`` closures that may be a dense matmul, a
-  GSPMD stencil, or explicit shard_map ppermute/psum collectives).
+  GSPMD stencil, a table-driven gather, or the worker mesh's explicit
+  ppermute halo forms).
 
 ``[N, d]`` stands for ``[N, *param_shape]``: the jax scan carries the
 problem's own parameter shape (``[N, d, K]`` for softmax — models/base.py),

@@ -85,7 +85,6 @@ from distributed_optimization_tpu.parallel.topology import (
     cached_topology,
     neighbor_tables_for,
 )
-from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     WORKER_AXIS,
     make_worker_mesh,
@@ -1893,10 +1892,7 @@ def _run(
             # device; a ring's two shifts plan nothing
             # (docs/OBSERVABILITY.md).
             spans.enter("halo_plan")
-            mix_op = make_halo_mixing_op(
-                topo, mesh, dtype=device_data.X.dtype,
-                overlap=config.halo_overlap,
-            )
+            mix_op = make_halo_mixing_op(topo, mesh, dtype=device_data.X.dtype)
             if config.compression != "none":
                 # Compressed halo exchange (ISSUE-18): the error-feedback
                 # algorithms route their wire rounds through this instead
@@ -1908,37 +1904,26 @@ def _run(
                 )
             spans.note(form=mix_op.impl)
             spans.enter("prepare")
-        elif (
-            mesh is None and use_mesh and len(jax.devices()) > 1
-            and not topo.is_matrix_free
-        ):
-            # The shard_map grid stencil — and the GSPMD grid stencil the
-            # auto path resolves to — block grid ROWS over devices, so the
-            # mesh size must divide the row count, not just N (the
-            # ISSUE-11 satellite: auto and explicit shard_map now apply
-            # the SAME row-divisibility rule, so both resolve to the same
-            # mesh instead of auto landing on a device count the row
-            # reshape cannot split). The matrix-free path runs unsharded
-            # unless worker_mesh asks for the halo route above: gather
-            # indices under plain GSPMD would all-gather.
-            if topo.grid_shape is not None and config.mixing_impl in (
-                "shard_map", "stencil", "auto"
-            ):
-                mesh = make_worker_mesh(topo.grid_shape[0])
-            else:
-                mesh = make_worker_mesh(n)
-        # No platform-specific resolution (see the mixing-impl history note
-        # above the run() helpers): make_mixing_op resolves 'auto'.
-        mixing_impl = config.mixing_impl
-        if halo_mesh is not None:
-            pass  # the halo gather op above IS the resolved mixing form
-        elif mixing_impl == "shard_map":
-            if mesh is None:
-                raise ValueError("shard_map mixing requires a device mesh")
-            mix_op = make_shard_map_mixing_op(topo, mesh)
         else:
+            if (
+                mesh is None and use_mesh and len(jax.devices()) > 1
+                and not topo.is_matrix_free
+            ):
+                # The GSPMD grid stencil blocks grid ROWS over devices, so
+                # the mesh size must divide the row count, not just N (else
+                # auto lands on a device count the row reshape cannot
+                # split). The matrix-free path runs unsharded unless
+                # worker_mesh asks for the halo route above: gather indices
+                # under plain GSPMD would all-gather.
+                if topo.grid_shape is not None and config.mixing_impl in (
+                    "stencil", "auto"
+                ):
+                    mesh = make_worker_mesh(topo.grid_shape[0])
+                else:
+                    mesh = make_worker_mesh(n)
+            # No platform-specific resolution: make_mixing_op resolves 'auto'.
             mix_op = make_mixing_op(
-                topo, impl=mixing_impl, dtype=device_data.X.dtype
+                topo, impl=config.mixing_impl, dtype=device_data.X.dtype
             )
         degrees = jnp.asarray(
             topo.degrees, dtype=device_data.X.dtype
@@ -1963,19 +1948,6 @@ def _run(
         byzantine_active = config.attack != "none" or (
             config.aggregation != "gossip" and config.robust_b > 0
         )
-        if config.mixing_impl == "shard_map":
-            if time_varying:
-                raise ValueError(
-                    "fault injection / matching-based gossip requires dense "
-                    "or stencil mixing: the shard_map stencils assume the "
-                    "static uniform-weight topology"
-                )
-            if byzantine_active:
-                raise ValueError(
-                    "Byzantine injection / robust aggregation requires "
-                    "dense or stencil mixing: the shard_map stencils "
-                    "assume the static uniform-weight benign topology"
-                )
         # Time-varying gossip and the Byzantine adversary + robust
         # aggregation composition (docs/BYZANTINE.md) — wiring shared with
         # the replica-batched path (``_build_faulty``/``_bind_byzantine``).
@@ -2579,12 +2551,6 @@ def batch_unsupported_reason(config) -> Optional[str]:
             "batched per-replica seed axis cannot reach — replicas would "
             "silently share compression draws"
         )
-    if config.mixing_impl == "shard_map":
-        return (
-            "run_batch is incompatible with mixing_impl='shard_map': "
-            "shard_map stencils pin a device mesh — "
-            "use 'auto', 'dense', 'stencil', or 'sparse'"
-        )
     if config.compression != "none":
         return (
             "run_batch does not support compressed gossip: the "
@@ -2661,8 +2627,8 @@ def run_batch(
 
     Structural axes (topology, n_workers, algorithm, ...) cannot batch —
     they change the traced program — and are rejected; so are the config
-    combinations whose execution cannot wrap in vmap (shard_map
-    mixing, tensor parallelism, choco's internal seed derivation) — see
+    combinations whose execution cannot wrap in vmap (tensor
+    parallelism, choco's internal seed derivation) — see
     ``batch_unsupported_reason``. The batched program runs unsharded (the
     replica axis fills the chip instead of the worker mesh) and always
     uses the fused flat scan.

@@ -366,21 +366,11 @@ def build_parser() -> argparse.ArgumentParser:
                             "law (structural identity). 'auto' = dense "
                             "below N=65,536 on the matrix-free ER path, "
                             "sparse above")
-    execg.add_argument("--halo-overlap", choices=("off", "double_buffer"),
-                       default=_DEFAULTS.halo_overlap,
-                       help="worker-mesh halo-exchange overlap (docs/"
-                            "PERF.md §17): 'double_buffer' issues the "
-                            "boundary ppermutes first and computes the "
-                            "in-block partial sum while they are in "
-                            "flight (plain gossip mesh path only; "
-                            "reordered summation — not bitwise vs 'off'). "
-                            "'off' = PR 11's exchange, bitwise-pinned")
     execg.add_argument("--eval-every", type=int, default=_DEFAULTS.eval_every,
                        help="full-data objective eval cadence (1 = reference "
                             "parity)")
     execg.add_argument("--mixing-impl",
-                       choices=("auto", "dense", "stencil", "shard_map",
-                                "sparse", "gather"),
+                       choices=("auto", "dense", "stencil", "gather"),
                        default=_DEFAULTS.mixing_impl,
                        help="'gather' = the k_max-bounded neighbor-table "
                             "mixing operator, O(N*k_max*d) per round with "
@@ -564,7 +554,6 @@ def config_from_args(args: argparse.Namespace) -> ExperimentConfig:
         tp_degree=args.tp,
         worker_mesh=args.worker_mesh,
         topology_sampler=args.topology_sampler,
-        halo_overlap=args.halo_overlap,
         eval_every=args.eval_every,
         erdos_renyi_p=args.erdos_renyi_p,
         edge_drop_prob=args.edge_drop_prob,

@@ -115,7 +115,7 @@ NEIGHBOR_TOPOLOGIES = ("ring", "grid", "chain", "erdos_renyi")
 # N at which ``topology_impl='auto'`` switches to the matrix-free neighbor
 # path (and mixing_impl='auto' to the k_max-bounded gather operator on
 # matrix-backed irregular graphs): the dense-mixing measurements stop at
-# N = 4096 — the axis cap docs/perf/sparse_mixing.json records — and the
+# N = 4096 (docs/PERF.md, "Pre-ledger history") and the
 # federated-scale bench (docs/perf/federated.json) measures the gather
 # route winning on CPU well below it while being the only route that
 # completes at N >= 10k.
@@ -364,11 +364,12 @@ class ExperimentConfig:
     # cycles deterministic matchings that cover the edge set every P
     # iterations (ring/chain/even-sided grid).
     gossip_schedule: str = "synchronous"
-    # 'auto' | 'dense' | 'stencil' | 'shard_map' | 'sparse' | 'gather'.
-    # 'auto' picks the measured winner: stencil where the graph embeds as
-    # mesh shifts, else dense (the CSR sparse form measured slower than
-    # dense at every cell — docs/perf/sparse_mixing.json — and remains an
-    # explicit opt-in).
+    # 'auto' | 'dense' | 'stencil' | 'gather'. 'auto' picks the measured
+    # winner: stencil where the graph embeds as mesh shifts, gather for a
+    # matrix-free or large degree-bounded graph that does not, else dense
+    # (ops/mixing.make_mixing_op). Under worker_mesh the mesh's halo forms
+    # run instead, read off the neighbor table
+    # (collectives.make_halo_mixing_op).
     mixing_impl: str = "auto"
     # 'auto' | 'gather' | 'dense'. Mini-batch realization on the jax backend:
     # 'gather' materializes [N, b, d] batches (the b largest uniforms by a
@@ -442,24 +443,6 @@ class ExperimentConfig:
     # path, 'dense' otherwise. Only meaningful for topology='erdos_renyi'
     # (rejected elsewhere rather than silently ignored).
     topology_sampler: str = "auto"
-    # 'off' | 'double_buffer'. Halo-exchange overlap on the worker mesh
-    # (docs/PERF.md §17): 'off' runs PR 11's exchange unchanged
-    # (bitwise-pinned); 'double_buffer' issues the boundary-row ppermutes
-    # FIRST and computes the self + in-block partial sums while they are
-    # in flight (the standard stencil latency-hiding idiom — XLA's
-    # scheduler overlaps collectives with independent compute on
-    # accelerators; CPU single-stream may tie). The halo contributions
-    # are added after the in-block partial, a different summation order,
-    # so double_buffer is NOT bitwise vs off — it is a distinct
-    # structural program. Plain-gossip mesh path only (no compression,
-    # faults, or robust screening). Moot where the neighbor table is a
-    # ring's or a torus's cut by whole grid rows: that mixing is row
-    # shifts whose boundary-row permutes depend on nothing local (the
-    # root span's mixing = 'halo_shift',
-    # collectives.make_halo_mixing_op), so 'off' and 'double_buffer' are
-    # ONE program there; it reorders the gather form only (chain,
-    # Erdős–Rényi, a torus the mesh cuts through a grid row).
-    halo_overlap: str = "off"
 
     def __post_init__(self) -> None:
         if self.problem_type not in PROBLEM_TYPES:
@@ -470,8 +453,7 @@ class ExperimentConfig:
             raise ValueError(f"Unknown topology: {self.topology}")
         if self.backend not in BACKENDS:
             raise ValueError(f"Unknown backend: {self.backend}")
-        if self.mixing_impl not in ("auto", "dense", "stencil", "shard_map",
-                                    "sparse", "gather"):
+        if self.mixing_impl not in ("auto", "dense", "stencil", "gather"):
             raise ValueError(f"Unknown mixing impl: {self.mixing_impl}")
         if self.sampling_impl not in ("auto", "gather", "dense"):
             raise ValueError(f"Unknown sampling impl: {self.sampling_impl}")
@@ -783,8 +765,7 @@ class ExperimentConfig:
                     "real collectives, shard the worker axis instead: "
                     "worker_mesh >= 2 lowers gather mixing to a ppermute "
                     "halo exchange (the sharded-gather path; "
-                    "docs/PERF.md §16) — mixing_impl='shard_map' is the "
-                    "dense-representation stencil form only"
+                    "docs/PERF.md §16)"
                 )
             if (
                 self.attack != "none"
@@ -941,41 +922,6 @@ class ExperimentConfig:
                 "stream as its own sampler — use topology_impl='auto' or "
                 "'neighbor'"
             )
-        if self.halo_overlap not in ("off", "double_buffer"):
-            raise ValueError(
-                f"Unknown halo overlap mode: {self.halo_overlap!r} "
-                "(expected 'off' or 'double_buffer')"
-            )
-        if self.halo_overlap == "double_buffer":
-            if self.worker_mesh < 2:
-                raise ValueError(
-                    "halo_overlap='double_buffer' overlaps the worker-mesh "
-                    "halo exchange with local gather math; without "
-                    "worker_mesh >= 2 there is no exchange to overlap — "
-                    "leave halo_overlap='off'"
-                )
-            if self.compression != "none":
-                raise ValueError(
-                    "halo_overlap='double_buffer' does not compose with "
-                    "compressed gossip: the compressed exchange ships "
-                    "error-feedback estimate rows whose halo copies must "
-                    "land before the mix reads them — run overlap studies "
-                    "with compression='none'"
-                )
-            if (
-                self.straggler_prob > 0.0
-                or self.mttf > 0.0
-                or self.participation_rate < 1.0
-                or self.attack != "none"
-                or (self.aggregation != "gossip" and self.robust_b > 0)
-            ):
-                raise ValueError(
-                    "halo_overlap='double_buffer' restructures the PLAIN "
-                    "gossip mixing body only; the fault/robust mesh paths "
-                    "run their own liveness + model exchanges and would "
-                    "silently ignore it — run overlap studies on the "
-                    "plain path"
-                )
         if self.execution not in EXECUTIONS:
             raise ValueError(f"Unknown execution mode: {self.execution}")
         if self.latency_model not in LATENCY_MODELS:
@@ -1161,17 +1107,6 @@ class ExperimentConfig:
                     "backend compiles; the numpy/cpp backends run one "
                     "trajectory at a time — use backend='jax' or loop "
                     "single runs"
-                )
-            if self.mixing_impl == "shard_map":
-                raise ValueError(
-                    f"replicas={self.replicas} is incompatible with "
-                    "mixing_impl='shard_map': the replica axis "
-                    "vmaps the whole compiled program, but shard_map "
-                    "stencils pin a fixed device mesh — use 'auto', "
-                    "'dense', 'stencil', 'sparse', or 'gather' (the "
-                    "sharded-gather worker_mesh route instead dispatches "
-                    "replicas as sequential mesh runs — see "
-                    "jax_backend.run_batch)"
                 )
             if self.algorithm == "choco":
                 raise ValueError(

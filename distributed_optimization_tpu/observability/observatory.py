@@ -620,8 +620,6 @@ PERF_TOLERANCES: dict[str, tuple[Check, ...]] = {
         Check("gates.n100k_ici_bytes_per_device_per_round", equal=True),
     ),
     "mesh_scale.json": (
-        # overlap_loses is measured, not asserted (CPU may tie either
-        # way between sessions) — every other gate boolean is pinned.
         Check("gates.n1m_*", equal=True),
         Check("gates.per_device_flat_at_matched_rows", equal=True),
         Check("gates.ring_ici_bytes_per_device_flat_in_n", equal=True),

@@ -2,27 +2,12 @@
 
 The reference realizes gossip as a dense ``W @ models`` matmul in numpy
 (reference ``trainer.py:173``) — a *simulation* of communication. Here the
-same linear operator has three interchangeable compiled forms:
+same linear operator has three interchangeable compiled forms on one
+device or an auto (GSPMD) mesh:
 
 - ``dense``: an on-device matmul with the [N, N] mixing matrix. Works for any
   graph (Erdős–Rényi et al.). Under GSPMD sharding this becomes an
   all-gather + local contraction — fine for irregular graphs.
-- ``sparse`` (round 5): a CSR-style edge-list contraction for irregular
-  graphs — gather rows by edge source, scale by per-edge weight, and
-  ``jax.ops.segment_sum`` into edge destinations (edges pre-sorted by
-  destination host-side, so the segments are sorted). O(E·d) work instead
-  of the dense form's O(N²·d) — and MEASURED SLOWER than dense at every
-  cell tried (17 on-chip cells: N ∈ {256, 1024, 4096} × chain/star/ER/
-  directed-ER at densities 0.05%–40%, ``docs/perf/sparse_mixing.json``;
-  CPU spot-checks agree). On TPU the [N, N] matmul rides the MXU at a
-  ~40–90 µs latency floor through N=4096 while gather+scatter pays
-  per-row DMA that scales with E and catastrophically with density (200×
-  slower at 40%) — asymptotic sparsity arguments lose to the systolic
-  array at any scale a single chip holds. ``auto`` therefore keeps DENSE
-  for irregular graphs; ``sparse`` stays as an explicit opt-in (exact for
-  all graphs, directed included) for regimes beyond the measured envelope
-  (N >> 4096 multi-chip, where the [N, N] weight replication itself
-  becomes the bottleneck).
 - ``stencil``: for ring / torus / fully-connected graphs, where MH weights are
   uniform by symmetry, W x is a weighted sum of circular shifts of x along the
   worker axis (ring: ±1; torus: ±1 along each grid axis; fc: the global mean).
@@ -30,9 +15,6 @@ same linear operator has three interchangeable compiled forms:
   axis into ``CollectivePermute`` over ICI and the fc mean into an
   ``AllReduce`` — the communication graph maps onto the pod topology, which is
   the north-star design (SURVEY.md §5.8).
-- ``shard_map``: explicit-collective form of the same stencils using
-  ``jax.lax.ppermute``/``psum`` (see ``parallel/collectives.py``), for when
-  manual control over the collective schedule is wanted.
 - ``gather`` (round 9): the matrix-free k_max-bounded form over padded
   neighbor tables — O(E·d), no [N, N] object anywhere; the route that
   lifts the worker axis to N ≥ 10k, and the one every graph that is not
@@ -84,8 +66,8 @@ same linear operator has three interchangeable compiled forms:
   (a ring's table, and a torus's cut by whole grid rows, takes that
   builder's ``'halo_shift'`` instead: shifts, no table).
 
-All forms agree to floating-point tolerance; property tests check stencil
-and shard_map forms against the dense matrix.
+All forms agree to floating-point tolerance; property tests check the
+stencil, gather and halo forms against the dense matrix.
 """
 
 from __future__ import annotations
@@ -219,11 +201,11 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
     """Build the compiled mixing operator for a topology.
 
     ``impl``: 'auto' picks 'stencil' where the graph embeds into the mesh as
-    shifts (ring/grid/fc), else 'dense' — the measured winner for irregular
-    graphs at every cell tried, BOTH platforms (round 5,
-    ``docs/perf/sparse_mixing.json``; see the module docstring for the
-    mechanism). 'sparse' is opt-in only. 'shard_map' variants are built in
-    ``parallel/collectives.py`` because they need a Mesh.
+    shifts (ring/grid/fc), 'gather' for a matrix-free graph that is not one
+    and for a large degree-bounded irregular one, else 'dense' (for a small
+    irregular graph the [N, N] matmul beat an edge-list contraction at every
+    size one chip holds: docs/PERF.md, "Pre-ledger history"). The worker
+    mesh's forms are built in ``parallel/collectives.py``: they need a Mesh.
     """
     if impl == "auto":
         if _supports_stencil(topo):
@@ -237,7 +219,7 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
             # for matrix-backed irregular graphs above the measured
             # threshold — the [N, N] contraction's O(N²·d) work and the
             # matrix itself stop fitting where docs/perf/federated.json's
-            # scale cells take over from sparse_mixing.json's. Gate on
+            # scale cells take over (docs/PERF.md §14). Gate on
             # the SAME degree bound build_neighbor_topology enforces:
             # gather's [N, k_max, d] transient beats dense only while
             # k_max ≪ N, so high-degree graphs (star, dense ER) keep the
@@ -251,12 +233,7 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
             impl = "gather" if degree_bounded else "dense"
         else:
             impl = "dense"
-    if impl == "shard_map":
-        raise ValueError(
-            "shard_map mixing ops need a Mesh; build them via "
-            "distributed_optimization_tpu.parallel.collectives instead"
-        )
-    if impl not in ("dense", "stencil", "sparse", "gather"):
+    if impl not in ("dense", "stencil", "gather"):
         raise ValueError(f"Unknown mixing impl: {impl!r}")
     if impl == "stencil" and not _supports_stencil(topo):
         raise ValueError(f"stencil mixing unsupported for {topo.name} (n={topo.n})")
@@ -300,44 +277,6 @@ def make_mixing_op(topo: Topology, impl: str = "auto", dtype=jnp.float32) -> Mix
             )
 
         return bind(tables)
-
-    if impl == "sparse":
-        # CSR edge-list contraction: works for ANY graph, directed included
-        # (the convention adjacency[i, j] = 1 iff j sends to i makes dst the
-        # receiving row for both orientations). np.nonzero walks row-major,
-        # so edges come out sorted by destination — segment_sum runs in its
-        # sorted fast path. Weights/edge lists are built host-side once; the
-        # device never materializes the [N, N] matrix.
-        dst_np, src_np = np.nonzero(topo.adjacency)
-        if dst_np.size == 0:
-            raise ValueError(
-                f"sparse mixing needs at least one edge ({topo.name}, "
-                f"n={topo.n})"
-            )
-        dst = jnp.asarray(dst_np, dtype=jnp.int32)
-        src = jnp.asarray(src_np, dtype=jnp.int32)
-        w_edge = jnp.asarray(
-            topo.mixing_matrix[dst_np, src_np], dtype=dtype
-        )
-        w_diag = jnp.asarray(np.diag(topo.mixing_matrix), dtype=dtype)
-        n = topo.n
-
-        def _bcast(v: jax.Array, x: jax.Array) -> jax.Array:
-            return v.reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
-
-        def apply(x: jax.Array) -> jax.Array:
-            gathered = _bcast(w_edge, x) * x[src]
-            agg = jax.ops.segment_sum(
-                gathered, dst, num_segments=n, indices_are_sorted=True
-            )
-            return (_bcast(w_diag, x) * x + agg).astype(x.dtype)
-
-        def neighbor_sum(x: jax.Array) -> jax.Array:
-            return jax.ops.segment_sum(
-                x[src], dst, num_segments=n, indices_are_sorted=True
-            ).astype(x.dtype)
-
-        return MixingOp(topo.name, "sparse", apply, neighbor_sum)
 
     if impl == "dense":
         W = jnp.asarray(topo.mixing_matrix, dtype=dtype)

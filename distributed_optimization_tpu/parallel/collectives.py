@@ -1,26 +1,24 @@
-"""Explicit-collective mixing operators: shard_map + ppermute/psum over ICI.
+"""The worker mesh's gossip: row-sharded mixing over explicit collectives.
 
-This is the north-star communication backend (SURVEY.md §5.8, C12): each
-device holds a contiguous block of workers, and one gossip round exchanges
-only the block-boundary rows with the neighboring devices via
-``jax.lax.ppermute`` (ring/torus) or reduces with ``jax.lax.psum`` (fully
-connected / centralized). This replaces the reference's simulated dense
-``W @ models`` matmul (reference ``trainer.py:173``) with the real collective
-traffic pattern: a ring of N workers on D devices moves exactly 2·d floats
-per device per round over ICI, independent of N — enforced against the
-compiled HLO (instruction kinds and payload element counts) by
-``tests/test_collectives.py::test_ring_lowers_to_boundary_permutes_with_2d_floats``
-and companions, for both this module's explicit ops and the GSPMD stencils.
+Under ``worker_mesh`` each device holds a contiguous block of N/P worker
+rows (worker i at block row i % (N/P) on device i // (N/P), the layout
+``mesh.shard_over_workers`` produces), and one gossip round moves only the
+rows a block's neighbours hold on another device, by ``jax.lax.ppermute``
+over ICI. This replaces the reference's simulated dense ``W @ models``
+matmul (reference ``trainer.py:173``) with the real traffic pattern: a ring
+of N workers on P devices moves exactly 2*d floats a device a round,
+independent of N (``tests/test_collectives.py`` holds the compiled HLO to
+that, instruction kinds and payload element counts, for these forms and for
+the GSPMD stencils of ``ops/mixing.py``, which lower to the same permutes on
+a mesh that is not ``worker_mesh``'s).
 
-The GSPMD stencils in ``ops/mixing.py`` compile to the same collectives
-automatically; this module is the manually scheduled form — used when
-``mixing_impl='shard_map'`` — and doubles as executable documentation of the
-communication pattern. Property tests check both against the dense matrix.
-
-Intra-block neighbor averaging is pure local compute; only the first/last
-rows of each block cross device boundaries. Worker blocks are contiguous
-(worker i lives at block row i % (N/D) on device i // (N/D)), matching the
-``NamedSharding`` layout that ``mesh.shard_over_workers`` produces.
+``make_halo_mixing_op`` is the one entry for plain gossip and reads the form
+off the neighbor table and the mesh: ``halo_shift`` (``_ring_block_mix`` on
+a ring, ``_grid_block_ops`` on a torus in whole grid rows: shifts with the
+boundary rows ppermuted, no table) or ``halo_gather`` (every other table:
+the planned ``HaloExchange`` and the one-device gather's ``slot_sum`` on the
+halo-extended block). The compressed and the robust halo layers below ride
+the same ``HaloExchange``.
 """
 
 from __future__ import annotations
@@ -68,40 +66,6 @@ def _ring_block_mix(axis: str, n_devices: int, w: float):
     def nbr(block):
         left, right = exchange(block)
         return (left + right).astype(block.dtype)
-
-    return mix, nbr
-
-
-def _directed_ring_block_mix(axis: str, n_devices: int):
-    """Per-block directed-ring stencil: ONE forward ppermute per round.
-
-    The directed ring receives only from the predecessor, so each device
-    ships exactly its last worker row forward — d floats per device per
-    round, HALF the undirected ring's boundary traffic (asserted against
-    compiled HLO by tests/test_push_sum.py)."""
-    fwd = [(i, (i + 1) % n_devices) for i in range(n_devices)]
-
-    def exchange(block):  # block: [per, d] on each device
-        from_prev = jax.lax.ppermute(block[-1:], axis, fwd)
-        return jnp.concatenate([from_prev, block[:-1]], axis=0)  # x_{i-1}
-
-    def mix(block):
-        return (0.5 * (block + exchange(block))).astype(block.dtype)
-
-    def nbr(block):
-        return exchange(block).astype(block.dtype)
-
-    return mix, nbr
-
-
-def _fc_block_ops(axis: str, n_total: int):
-    def mix(block):
-        total = jax.lax.psum(jnp.sum(block, axis=0, keepdims=True), axis)
-        return jnp.broadcast_to(total / n_total, block.shape).astype(block.dtype)
-
-    def nbr(block):
-        total = jax.lax.psum(jnp.sum(block, axis=0, keepdims=True), axis)
-        return (total - block).astype(block.dtype)
 
     return mix, nbr
 
@@ -176,52 +140,6 @@ def _over_row_blocks(block_fn, mesh: Mesh):
         return shard_map(block_fn, mesh=mesh, in_specs=spec, out_specs=spec)(x)
 
     return fn
-
-
-def make_shard_map_mixing_op(topo: Topology, mesh: Mesh) -> MixingOp:
-    """Build the explicit shard_map collective mixing op for a topology.
-
-    Supports the mesh-embeddable graphs (ring, torus grid, fully connected).
-    Irregular graphs (Erdős–Rényi, chain, star) use the dense form instead
-    (SURVEY.md §7 hard part (c)).
-    """
-    axis = WORKER_AXIS
-    n_devices = mesh.shape[axis]
-    n = topo.n
-    if n % n_devices != 0:
-        raise ValueError(f"n_workers={n} not divisible by mesh size {n_devices}")
-
-    if topo.name == "ring":
-        if n < 3:
-            raise ValueError("shard_map ring mixing needs n >= 3")
-        mix_block, nbr_block = _ring_block_mix(axis, n_devices, 1.0 / 3.0)
-    elif topo.name == "directed_ring":
-        if n < 3:
-            raise ValueError("shard_map directed_ring mixing needs n >= 3")
-        mix_block, nbr_block = _directed_ring_block_mix(axis, n_devices)
-    elif topo.name == "fully_connected":
-        mix_block, nbr_block = _fc_block_ops(axis, n)
-    elif topo.name == "grid":
-        rows, cols = topo.grid_shape  # type: ignore[misc]
-        if min(rows, cols) < 3:
-            raise ValueError("shard_map grid mixing needs a >=3x3 torus")
-        if rows % n_devices != 0:
-            raise ValueError(
-                f"grid rows={rows} not divisible by mesh size {n_devices}"
-            )
-        mix_block, nbr_block = _grid_block_ops(axis, n_devices, cols, 1.0 / 5.0)
-    else:
-        raise ValueError(
-            f"No shard_map stencil for topology {topo.name!r}; use dense mixing"
-        )
-
-    # The worker axis (a torus's grid rows, whole) is blocked over devices.
-    return MixingOp(
-        topo.name,
-        "shard_map",
-        _over_row_blocks(mix_block, mesh),
-        _over_row_blocks(nbr_block, mesh),
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -318,24 +236,15 @@ class HaloExchange:
         )(self.nbr_l, self.mask, *self.sends, *self.recvs, *arrays)
 
 
-def make_halo_exchange(
-    topo: Topology, mesh: Mesh, *, overlap: str = "off"
-) -> HaloExchange:
-    """Build the device-ready halo plan for a topology over a 1-D mesh.
-
-    ``overlap`` names the exchange form the plan serves (it is part of
-    the plan's memoization identity — see ``build_halo_plan``); the
-    device arrays are identical across modes today.
-    """
+def make_halo_exchange(topo: Topology, mesh: Mesh) -> HaloExchange:
+    """Build the device-ready halo plan for a topology over a 1-D mesh."""
     n_devices = mesh.shape[WORKER_AXIS]
     nbr_idx, nbr_mask = neighbor_tables_for(topo)
     if topo.n % n_devices:
         raise ValueError(
             f"n_workers={topo.n} not divisible by mesh size {n_devices}"
         )
-    plan = build_halo_plan(
-        nbr_idx, nbr_mask, n_devices, sampler=topo.sampler, overlap=overlap
-    )
+    plan = build_halo_plan(nbr_idx, nbr_mask, n_devices, sampler=topo.sampler)
     S, k_max = plan.shard_rows, nbr_idx.shape[1]
     return HaloExchange(
         mesh=mesh,
@@ -361,7 +270,7 @@ def make_halo_exchange(
 
 
 def make_halo_mixing_op(
-    topo: Topology, mesh: Mesh, dtype=jnp.float32, *, overlap: str = "off"
+    topo: Topology, mesh: Mesh, dtype=jnp.float32
 ) -> MixingOp:
     """The worker mesh's mixing operator: how a shard reaches its
     neighbours' rows is read off the neighbor table and the mesh's size, by
@@ -370,33 +279,28 @@ def make_halo_mixing_op(
     of the table, the ring's first, so a ring pays nothing for the second.
     The ``dopt.run`` root's ``mixing`` says which form a call took.
 
-    ``halo_shift`` on a ring — the table IS a ring's: a block's neighbours
-    are its own rows one up and one down, and its two boundary rows'
-    neighbours arrive by two one-row ``ppermute``s (``_ring_block_mix``,
-    the body ``mixing_impl='shard_map'`` runs too), ``w · (x + left +
-    right)`` with w = 1/3 as the one-chip stencil writes it, on the stack
-    in the rank the scan carries. No ``HaloExchange``, no per-shard
-    neighbor table and no weights table is built or closed over: at 262,144
-    rows a device the gather form's ``s32[4, 262144, 2]`` table was 0.54 GB
-    of every chip's executable, and its 524,288-index row gather 5.2 of
-    25.3 ms an iteration where the shifts are 0.3 on one chip (PERF.md
-    sections 5 and 6, PR 35). The permutes depend on nothing local, so
-    ``overlap`` has nothing to reorder: 'off' and 'double_buffer' are
-    one program here.
+    ``halo_shift`` on a ring — the table IS a ring's: a block's neighbours are
+    its own rows one up and one down, and its two boundary rows' neighbours
+    arrive by two one-row ``ppermute``s (``_ring_block_mix``), ``w · (x + left
+    + right)`` with w = 1/3 as the one-chip stencil writes it, on the stack in
+    the rank the scan carries. No ``HaloExchange``, no per-shard neighbor table
+    and no weights table is built or closed over: at 262,144 rows a device the
+    gather form's ``s32[4, 262144, 2]`` table was 0.54 GB of every chip's
+    executable, and its 524,288-index row gather 5.2 of 25.3 ms an iteration
+    where the shifts are 0.3 on one chip (PERF.md sections 5 and 6, PR 35).
 
-    ``halo_shift`` on a torus — the table IS a square torus's and the mesh
-    cuts it by whole grid rows (``topology.table_is_a_torus_in_row_blocks``,
-    PR 53): every neighbour sits at a fixed offset, ±1 inside a grid row
-    with the column wrap and ±cols for the grid row above and below, and
-    only a block's first and last grid row have neighbours on another
-    device. ``_grid_block_ops`` (the body ``mixing_impl='shard_map'`` runs
-    too): two ``ppermute``s of one grid row each, the rotations of
-    ``cols`` rows the gather's plan shipped, then ``w · (x + (up + down +
+    ``halo_shift`` on a torus — the table IS a square torus's and the mesh cuts
+    it by whole grid rows (``topology.table_is_a_torus_in_row_blocks``, PR 53):
+    every neighbour sits at a fixed offset, ±1 inside a grid row with the
+    column wrap and ±cols for the grid row above and below, and only a block's
+    first and last grid row have neighbours on another device.
+    ``_grid_block_ops``: two ``ppermute``s of one grid row each, the rotations
+    of ``cols`` rows the gather's plan shipped, then ``w · (x + (up + down +
     left + right))`` with w = 1/5, the torus's exact Metropolis–Hastings
-    weights, on the stack in the rank the scan carries, with nothing built
-    or closed over and ``overlap`` as moot as on the ring. At 2^18 workers
-    a device the gather form priced its four 262,144-index row gathers at
-    10.2 of an iteration's 23.4 ms (PERF.md sections 5 and 6, PR 52).
+    weights, on the stack in the rank the scan carries, with nothing built or
+    closed over. At 2^18 workers a device the gather form priced its four
+    262,144-index row gathers at 10.2 of an iteration's 23.4 ms (PERF.md
+    sections 5 and 6, PR 52).
 
     ``halo_gather`` — every other table (chain, Erdős–Rényi, a torus that
     the mesh cuts through a grid row, a ring or a torus whose slots are
@@ -407,8 +311,6 @@ def make_halo_mixing_op(
             "halo gather mixing is undirected-only (MH weights per slot); "
             f"directed topology {topo.name!r} has no gather form"
         )
-    if overlap not in ("off", "double_buffer"):
-        raise ValueError(f"Unknown halo overlap mode: {overlap!r}")
     n_devices = mesh.shape[WORKER_AXIS]
     if topo.n % n_devices:
         raise ValueError(
@@ -424,7 +326,7 @@ def make_halo_mixing_op(
             WORKER_AXIS, n_devices, math.isqrt(topo.n), 1.0 / 5.0
         )
     else:
-        return _make_halo_gather_mixing_op(topo, mesh, dtype, overlap=overlap)
+        return _make_halo_gather_mixing_op(topo, mesh, dtype)
     return MixingOp(
         topo.name,
         "halo_shift",
@@ -434,7 +336,7 @@ def make_halo_mixing_op(
 
 
 def _make_halo_gather_mixing_op(
-    topo: Topology, mesh: Mesh, dtype=jnp.float32, *, overlap: str = "off"
+    topo: Topology, mesh: Mesh, dtype=jnp.float32
 ) -> MixingOp:
     """Sharded twin of ``ops/mixing.py`` impl='gather' over real collectives.
 
@@ -459,23 +361,9 @@ def _make_halo_gather_mixing_op(
     ``fusion f32[262144,81]`` (2.54 ms each: 10.2 of the round's 12.4 ms)
     and one ``multiply_add_fusion`` (0.44 ms), the first slot's written
     out and the others inside ``slot_sum``'s ``while``.
-
-    ``overlap='double_buffer'`` (config.halo_overlap; docs/PERF.md §17)
-    restructures ``apply`` into the stencil latency-hiding form: the
-    boundary-row ppermutes are issued FIRST, the self + in-block partial
-    sum computes while they are in flight (XLA schedules collectives
-    concurrently with independent compute on async backends), and the
-    halo contributions are added last. The summation ORDER differs from
-    the gather body (in-block slots before halo slots instead of slot
-    order: two ``slot_sum``s over the table, the halo's slots weighing 0
-    in the first and the block's in the second), so double_buffer is a
-    distinct structural program — NOT bitwise vs off; 'off' is the
-    one-device round's op sequence, which is the gate
-    tests/test_worker_mesh.py pins.
     """
-    hx = make_halo_exchange(topo, mesh, overlap=overlap)
+    hx = make_halo_exchange(topo, mesh)
     nbr_sm, w_nbr, w_self = _slot_major_blocks(hx, topo, dtype)
-    S = hx.plan.shard_rows
 
     # The slot-major blocks ride ``HaloExchange.run`` as ordinary arrays
     # split on their leading (shard) axis: each body sees its
@@ -485,28 +373,6 @@ def _make_halo_gather_mixing_op(
     def apply(x: jax.Array) -> jax.Array:
         def body(exchange, _nbr_l, _mask_f32, nb, wn, ws, xb):
             out = ws[:, None] * xb + slot_sum(exchange(xb), nb[0], wn[0])
-            return out.astype(xb.dtype)
-
-        x2 = x.reshape(x.shape[0], -1)
-        return hx.run(body, nbr_sm, w_nbr, w_self, x2).reshape(x.shape)
-
-    def apply_overlap(x: jax.Array) -> jax.Array:
-        def body(exchange, _nbr_l, _mask_f32, nb, wn, ws, xb):
-            nb, wn = nb[0], wn[0]
-            # Issue every boundary-row send before touching the local
-            # math: the in-block partial sum has no data dependence on
-            # the permutes, so an async backend's scheduler runs the
-            # collectives concurrently with it (CPU single-stream ties).
-            halo = exchange(xb)[S:]
-            in_block = nb < S
-            none = jnp.zeros((), wn.dtype)
-            partial = ws[:, None] * xb + slot_sum(
-                xb, jnp.where(in_block, nb, 0), jnp.where(in_block, wn, none)
-            )
-            out = partial + slot_sum(
-                halo, jnp.where(in_block, 0, nb - S),
-                jnp.where(in_block, none, wn),
-            )
             return out.astype(xb.dtype)
 
         x2 = x.reshape(x.shape[0], -1)
@@ -526,7 +392,7 @@ def _make_halo_gather_mixing_op(
     return MixingOp(
         topo.name,
         "halo_gather",
-        apply_overlap if overlap == "double_buffer" else apply,
+        apply,
         neighbor_sum,
         # Every device array the operators read, whole (all P shards'):
         # with no ``bind`` they are constants of whatever program closes

@@ -33,7 +33,8 @@ impl='neighbor')``): ring/torus/chain/Erdős–Rényi built directly as a
 static padded ``[N, k_max]`` neighbor table — the dense ``[N, N]``
 adjacency and mixing matrix are never materialized (``adjacency`` /
 ``mixing_matrix`` are None; at N = 10k the dense float64 pair alone is
-~1.6 GB, the cap docs/perf/sparse_mixing.json ran into around N≈4k).
+~1.6 GB; the pre-ledger dense-mixing measurements stop around N≈4k,
+docs/PERF.md "Pre-ledger history").
 Everything downstream that needs the graph reads the table: gather-form
 MH mixing (``gather_mixing_weights`` + ``ops/mixing.py`` impl='gather',
 O(N·k_max·d) per round), node-process fault composition
@@ -974,7 +975,6 @@ def build_halo_plan(
     n_shards: int,
     *,
     sampler: str = "dense",
-    overlap: str = "off",
 ) -> HaloPlan:
     """Shard a padded neighbor table into P contiguous row blocks + halo maps.
 
@@ -989,11 +989,10 @@ def build_halo_plan(
     construction (asserted against the realized adjacency in
     tests/test_worker_mesh.py).
 
-    ``sampler`` and ``overlap`` name the exchange form the plan serves
-    (the topology's sampler identity and the ``halo_overlap`` mode).
-    Today's plan layout is identical across both, but they are part of
-    the memoization key so a cache hit can never serve a plan built for
-    the other exchange form if the layouts ever diverge.
+    ``sampler`` names the topology's sampler identity. Today's plan layout
+    is identical across samplers, but it is part of the memoization key so
+    a cache hit can never serve a plan built for the other if the layouts
+    ever diverge.
     """
     n, k_max = nbr_idx.shape
     if n_shards < 2:
@@ -1006,8 +1005,7 @@ def build_halo_plan(
     digest.update(np.ascontiguousarray(nbr_idx).tobytes())
     digest.update(np.ascontiguousarray(nbr_mask).tobytes())
     cache_key = (
-        digest.hexdigest(), nbr_idx.shape, int(n_shards),
-        str(sampler), str(overlap),
+        digest.hexdigest(), nbr_idx.shape, int(n_shards), str(sampler)
     )
     cached = _HALO_PLAN_CACHE.get(cache_key)
     if cached is not None:

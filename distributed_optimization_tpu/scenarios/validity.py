@@ -146,7 +146,7 @@ RULES: tuple[Rule, ...] = (
     _domain("topology", "topology", TOPOLOGIES),
     _domain("backend", "execution", BACKENDS),
     _domain("mixing_impl", "topology",
-            ("auto", "dense", "stencil", "shard_map", "sparse", "gather")),
+            ("auto", "dense", "stencil", "gather")),
     _domain("sampling_impl", "execution", ("auto", "gather", "dense")),
     _domain("lr_schedule", "algorithm", ("auto", "sqrt_decay", "constant")),
     _domain("compression", "compression", COMPRESSIONS),
@@ -730,14 +730,6 @@ RULES: tuple[Rule, ...] = (
        lambda f: (
            f"replicas={f['replicas']} batches seed replicates through "
            "one vmapped XLA program, which only the jax backend compiles"
-       )),
-    _r("replicas×mixing_impl", ("replicas", "topology"),
-       lambda f: f["replicas"] > 1 and f["backend"] == "jax"
-       and f["mixing_impl"] == "shard_map",
-       lambda f: (
-           f"replicas={f['replicas']} is incompatible with "
-           "mixing_impl='shard_map': a mesh-pinned form cannot ride the "
-           "replica vmap axis"
        )),
     _r("replicas×choco", ("replicas", "algorithm"),
        lambda f: f["replicas"] > 1 and f["backend"] == "jax"

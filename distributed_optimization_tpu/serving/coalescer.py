@@ -19,7 +19,7 @@ the ``run_batch`` call for them:
   the cached executable — identical across cohorts of the same class and
   size, whether or not this particular cohort's values differ.
 - **fallback**: configs ``jax_backend.batch_unsupported_reason`` rejects
-  (choco, compressed gossip, shard_map mixing, tensor parallelism,
+  (choco, compressed gossip, tensor parallelism,
   non-jax backends) become singleton sequential plans
   executed via ``run_algorithm`` — same rejection logic, no duplicated
   condition list.

@@ -665,7 +665,7 @@ def ici_summary(
     """Bytes-over-ICI block for sharded worker-mesh runs (ISSUE-11).
 
     Rebuilds the static halo-exchange plan host-side — the identical plan
-    the backend's shard_map mixing executes — and prices the per-device
+    the backend's halo gather executes — and prices the per-device
     ppermute traffic exactly: each device ships the rotation-padded WIRE
     rows per gossip round (every rotation pads to its max per-device
     count so the collective is shape-uniform; on regular rings wire ==
@@ -707,8 +707,7 @@ def ici_summary(
         topo = _config_topology(config)
     nbr_idx, nbr_mask = neighbor_tables_for(topo)
     plan = build_halo_plan(
-        nbr_idx, nbr_mask, config.worker_mesh,
-        sampler=topo.sampler, overlap=config.halo_overlap,
+        nbr_idx, nbr_mask, config.worker_mesh, sampler=topo.sampler
     )
     problem = get_problem(
         config.problem_type, huber_delta=config.huber_delta,

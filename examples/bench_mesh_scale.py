@@ -2,7 +2,7 @@
 
 A CPU COUNT BENCH: it runs under a FORCED 16-device host platform
 (JAX_PLATFORMS=cpu and XLA_FLAGS, both pinned below before jax
-initializes). Four measured claims, each gated:
+initializes). Three measured claims, each gated:
 
 1. **1M completion** — N = 1,000,000 ring AND torus runs COMPLETE
    sharded over 16 devices (10× the N=100k headroom worker_mesh.json
@@ -23,15 +23,10 @@ initializes). Four measured claims, each gated:
    the same static plan that drives the collectives), and the compressed
    run's final gap stays within the 2.5× envelope of the uncompressed
    run at equal iterations (the fused_robust.json convention).
-4. **Overlap** — halo_overlap='double_buffer' is measured against 'off'
-   at matched config. On this single-stream CPU host the ppermute/
-   compute overlap has no hardware to exploit, so the ratio is reported
-   with an honest ``overlap_loses`` flag rather than asserted >= 1; the
-   load-bearing gate is bitwise-off parity (tests/test_mesh_scale.py).
 
 CPU-container numbers: absolute iters/sec is not chip evidence; the
-load-bearing content is the completions, the flat footprint, the wire
-accounting, and the honest flags.
+load-bearing content is the completions, the flat footprint and the wire
+accounting.
 """
 
 from __future__ import annotations
@@ -74,9 +69,6 @@ ER_MEAN_DEGREE = 20.0  # above the ln(N) ≈ 13.8 connectivity threshold
 
 COMPRESS_N = 4096
 COMPRESS_T = 400
-
-OVERLAP_N = 100_000
-OVERLAP_T = 30
 
 
 def _mesh_cfg(topology, n, mesh_p, **extra):
@@ -275,50 +267,6 @@ def bench_compression():
     }
 
 
-def bench_overlap():
-    import time
-
-    from distributed_optimization_tpu.backends import jax_backend
-    from distributed_optimization_tpu.utils.data import (
-        generate_synthetic_dataset,
-    )
-
-    cfg_off = _mesh_cfg("ring", OVERLAP_N, 4).replace(
-        n_iterations=OVERLAP_T, eval_every=OVERLAP_T
-    )
-    cfg_db = cfg_off.replace(halo_overlap="double_buffer")
-    ds = generate_synthetic_dataset(cfg_off)
-    cells = {}
-    for label, cfg in (("off", cfg_off), ("double_buffer", cfg_db)):
-        t0 = time.perf_counter()
-        r = jax_backend.run(cfg, ds, 0.0)
-        cells[label] = {
-            "iters_per_second": float(r.history.iters_per_second),
-            "compile_seconds": float(r.history.compile_seconds),
-            "wall_seconds": time.perf_counter() - t0,
-            "final_gap": float(r.history.objective[-1]),
-        }
-        print(f"[overlap] {label}: "
-              f"{cells[label]['iters_per_second']:.1f} iters/s")
-    ratio = (cells["double_buffer"]["iters_per_second"]
-             / cells["off"]["iters_per_second"])
-    return {
-        "n_workers": OVERLAP_N,
-        "n_iterations": OVERLAP_T,
-        "worker_mesh": 4,
-        "cells": cells,
-        "double_buffer_speedup": ratio,
-        "overlap_loses": bool(ratio < 1.0),
-        "note": (
-            "single-stream CPU host: ppermute and the in-block partial "
-            "sum serialize, so the restructured body can only tie or "
-            "lose here — the flag is reported honestly, not asserted; "
-            "the accelerator rationale is the issued-first ppermute the "
-            "double_buffer body hands XLA's latency-hiding scheduler"
-        ),
-    }
-
-
 def main() -> None:
     import multiprocessing as mp
     from concurrent import futures
@@ -352,8 +300,6 @@ def main() -> None:
               f"plan {er_plan['plan_seconds']:.1f}s")
     with timer.phase("compression"):
         compression = bench_compression()
-    with timer.phase("overlap"):
-        overlap = bench_overlap()
 
     by_label = {c["label"]: c for c in cells}
     big = by_label["ring_1m_p16"]
@@ -396,10 +342,6 @@ def main() -> None:
                 "fused_robust.json convention; sharded-vs-unsharded "
                 "bitwise parity asserted on the compressed cell"
             ),
-            "overlap": (
-                f"ring N={OVERLAP_N}, P=4, halo_overlap off vs "
-                "double_buffer at matched config, measured iters/sec"
-            ),
         },
         "scale": {
             "n_iterations": SCALE_T,
@@ -412,7 +354,6 @@ def main() -> None:
         },
         "er_plan": er_plan,
         "compression": compression,
-        "overlap": overlap,
         "gates": {
             "n1m_ring_completed_sharded": True,
             "n1m_torus_completed_sharded": True,
@@ -430,15 +371,13 @@ def main() -> None:
             ),
             "compressed_models_match_unsharded": compression[
                 "models_match_unsharded"],
-            "overlap_measured": True,
-            "overlap_loses": overlap["overlap_loses"],
         },
         "note": (
             "CPU-container numbers: absolute iters/sec is not chip "
             "evidence; the load-bearing content is the 1M sharded "
             "completions, the flat per-device footprint at matched "
-            "rows/device, the <= 50% compressed wire bytes inside the "
-            "2.5x gap envelope, and the honest overlap_loses flag. "
+            "rows/device and the <= 50% compressed wire bytes inside the "
+            "2.5x gap envelope. "
             "Bitwise guarantees live in tests/test_mesh_scale.py."
         ),
     }

@@ -11,7 +11,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-python examples/bench_mixing.py            # -> docs/perf/mixing_bench.json
 python examples/bench_breakdown.py         # -> docs/perf/breakdown.json
 python examples/bench_scaling.py           # -> docs/perf/scaling.json + figure
 python examples/bench_presets.py           # -> docs/perf/presets.json
@@ -19,7 +18,6 @@ python examples/bench_faults.py            # -> docs/perf/faults.json
 python examples/bench_churn.py             # -> docs/perf/churn.json
 python examples/bench_byzantine.py         # -> docs/perf/byzantine.json
 python examples/bench_robust_scale.py      # -> docs/perf/robust_scale.json
-python examples/bench_sparse_mixing.py     # -> docs/perf/sparse_mixing.json
 python examples/bench_compute_bound.py     # -> docs/perf/compute_bound.json (MFU-floor gated)
 python examples/bench_sweep.py             # -> docs/perf/sweep.json (replica-batch floor gated)
 python examples/bench_telemetry.py         # -> docs/perf/telemetry.json (overhead-ceiling gated)
@@ -32,7 +30,7 @@ python examples/bench_federated.py         # -> docs/perf/federated.json (floats
 python examples/bench_async.py             # -> docs/perf/async.json (wall-clock-to-eps floors + degenerate sync gate)
 python examples/bench_async_faults.py      # -> docs/perf/async_faults.json (crash-free bitwise gate + tracking-invariant bound + matched-availability envelope + under-faults barrier floor)
 python examples/bench_worker_mesh.py       # -> docs/perf/worker_mesh.json (sharded parity bitwise + N=100k completion incl. sparse-sampled ER + flat per-device memory gated; forces 4 host devices itself)
-python examples/bench_mesh_scale.py        # -> docs/perf/mesh_scale.json (N=1M ring/torus sharded completions + flat per-device memory + sparse-ER 1M build + compressed-halo wire cut + overlap ratio gated; forces 16 host devices itself)
+python examples/bench_mesh_scale.py        # -> docs/perf/mesh_scale.json (N=1M ring/torus sharded completions + flat per-device memory + sparse-ER 1M build + compressed-halo wire cut gated; forces 16 host devices itself)
 python examples/bench_scenarios.py         # -> docs/perf/scenarios.json (validity-agreement + per-cell invariant + warm-replay + chaos gates; forces 4 host devices itself)
 python examples/reproduce_report.py --json docs/perf/report_reproduction.json
 python examples/northstar_consensus.py --ring-full  # -> docs/perf/northstar_consensus.json

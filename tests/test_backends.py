@@ -166,20 +166,19 @@ def test_gradient_tracking_on_torus():
     assert r.total_floats_transmitted == pytest.approx(2 * 4 * 16 * 11 * 500)
 
 
-def test_shard_map_backend_path(quad_setup):
-    """End-to-end run with explicit shard_map collectives on the 8-dev mesh."""
+def test_worker_mesh_backend_path(quad_setup):
+    """End-to-end run with explicit ppermute collectives on the 8-dev mesh
+    (the worker mesh's shifts on the ring's neighbor table) against the
+    dense matmul on one device."""
     cfg, ds, f_opt = quad_setup
-    from distributed_optimization_tpu.parallel.mesh import make_worker_mesh
-
-    mesh = make_worker_mesh(cfg.n_workers)
-    r_sm = run_algorithm(
-        cfg.replace(mixing_impl="shard_map", n_iterations=50), ds, f_opt, mesh=mesh
+    r_halo = run_algorithm(
+        cfg.replace(worker_mesh=8, topology_impl="neighbor", n_iterations=50), ds, f_opt
     )
     r_dense = run_algorithm(
         cfg.replace(mixing_impl="dense", n_iterations=50), ds, f_opt, use_mesh=False
     )
     np.testing.assert_allclose(
-        r_sm.final_models, r_dense.final_models, rtol=5e-4, atol=5e-4
+        r_halo.final_models, r_dense.final_models, rtol=5e-4, atol=5e-4
     )
 
 

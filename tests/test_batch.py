@@ -200,16 +200,12 @@ def test_rejects_sweep_length_mismatch():
         )
 
 
-def test_rejects_choco_and_unbatchable_mixing():
+def test_rejects_choco():
     ds, f_opt = _setup(_cfg())
     with pytest.raises(ValueError, match="choco"):
         jax_backend.run_batch(
             _cfg(algorithm="choco", lr_schedule="constant"), ds, f_opt,
             seeds=[1, 2],
-        )
-    with pytest.raises(ValueError, match="shard_map"):
-        jax_backend.run_batch(
-            _cfg(mixing_impl="shard_map"), ds, f_opt, seeds=[1, 2]
         )
 
 
@@ -257,8 +253,6 @@ def test_config_rejects_unbatchable_combinations():
         _cfg(replicas=2, backend="numpy")
     with pytest.raises(ValueError, match="choco"):
         _cfg(replicas=2, algorithm="choco", lr_schedule="constant")
-    with pytest.raises(ValueError, match="shard_map"):
-        _cfg(replicas=2, mixing_impl="shard_map")
     with pytest.raises(ValueError, match=">= 1"):
         _cfg(replicas=0)
     with pytest.raises(ValueError, match="mutually exclusive"):

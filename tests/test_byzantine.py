@@ -327,12 +327,6 @@ def test_cpp_backend_rejects_byzantine(data):
         )
 
 
-def test_shard_map_mixing_rejected_under_attack(data):
-    ds, _ = data
-    with pytest.raises(ValueError, match="dense or stencil"):
-        jax_backend.run(ATTACKED.replace(mixing_impl="shard_map"), ds, 0.0)
-
-
 # ------------------------------------------------------- jax vs numpy oracle
 
 ORACLE_CFG = ExperimentConfig(

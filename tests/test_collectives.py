@@ -1,4 +1,5 @@
-"""Explicit shard_map/ppermute collective tests on the 8-device CPU mesh."""
+"""Sharded mixing on the 8-device CPU mesh: the worker mesh's halo forms and
+the GSPMD stencils against the dense matrix, and what each lowers to."""
 
 import jax
 import jax.numpy as jnp
@@ -7,7 +8,7 @@ import numpy as np
 import pytest
 
 from distributed_optimization_tpu.ops.mixing import make_mixing_op
-from distributed_optimization_tpu.parallel.collectives import make_shard_map_mixing_op
+from distributed_optimization_tpu.parallel.collectives import make_halo_mixing_op
 from distributed_optimization_tpu.parallel.mesh import (
     make_worker_mesh,
     shard_over_workers,
@@ -21,34 +22,57 @@ def _mesh(n_workers):
     return make_worker_mesh(n_workers)
 
 
-@pytest.mark.parametrize(
-    "name,n",
-    [("ring", 8), ("ring", 16), ("ring", 24), ("fully_connected", 8), ("fully_connected", 16), ("grid", 64)],
-)
-def test_shard_map_mix_equals_dense(rng, name, n):
-    """ppermute/psum stencils reproduce W @ x exactly (up to f32)."""
-    topo = build_topology(name, n)
-    mesh = _mesh(n)
-    op = make_shard_map_mixing_op(topo, mesh)
-    assert op.impl == "shard_map"
-    x_host = rng.normal(size=(n, 7)).astype(np.float32)
-    x = shard_over_workers(mesh, jnp.asarray(x_host))
-    expected = topo.mixing_matrix @ x_host
-    np.testing.assert_allclose(np.asarray(op.apply(x)), expected, rtol=1e-5, atol=1e-6)
+def _row_mesh(topo):
+    """The auto mesh ``_run`` sizes: whole grid rows a block on a torus."""
+    return make_worker_mesh(topo.grid_shape[0] if topo.grid_shape else topo.n)
+
+
+def _assert_is_the_dense_round(op, x, x_host, dense):
     np.testing.assert_allclose(
-        np.asarray(op.neighbor_sum(x)), topo.adjacency @ x_host, rtol=1e-5, atol=1e-5
+        np.asarray(op.apply(x)), dense.mixing_matrix @ x_host, rtol=1e-5, atol=1e-6
+    )
+    np.testing.assert_allclose(
+        np.asarray(op.neighbor_sum(x)), dense.adjacency @ x_host, rtol=1e-5, atol=1e-5
     )
 
 
-def test_shard_map_mix_under_jit_preserves_sharding(rng):
-    n = 16
-    topo = build_topology("ring", n)
+# grid 64 over 8 devices: ONE grid row a block, ``up`` and ``down`` wholly halo
+@pytest.mark.parametrize("name,n", [("ring", 8), ("ring", 16), ("ring", 24), ("grid", 64)])
+def test_halo_shift_equals_dense(rng, name, n):
+    """The worker mesh's ppermute shifts, built from the neighbor table,
+    reproduce W @ x exactly (up to f32)."""
+    topo = build_topology(name, n, impl="neighbor")
+    mesh = _row_mesh(topo)
+    op = make_halo_mixing_op(topo, mesh)
+    assert op.impl == "halo_shift"
+    x_host = rng.normal(size=(n, 7)).astype(np.float32)
+    x = shard_over_workers(mesh, jnp.asarray(x_host))
+    _assert_is_the_dense_round(op, x, x_host, build_topology(name, n))
+
+
+@pytest.mark.parametrize("n", [8, 16])
+def test_gspmd_fc_stencil_on_sharded_input_equals_dense(rng, n):
+    """A complete graph has no neighbor table and no halo form: sharded, it
+    runs the GSPMD stencil (the global mean, an AllReduce)."""
+    topo = build_topology("fully_connected", n)
     mesh = _mesh(n)
-    op = make_shard_map_mixing_op(topo, mesh)
+    op = make_mixing_op(topo, impl="stencil")
+    x_host = rng.normal(size=(n, 7)).astype(np.float32)
+    x = shard_over_workers(mesh, jnp.asarray(x_host))
+    _assert_is_the_dense_round(op, x, x_host, topo)
+
+
+def test_halo_shift_under_jit_preserves_sharding(rng):
+    n = 16
+    topo = build_topology("ring", n, impl="neighbor")
+    mesh = _mesh(n)
+    op = make_halo_mixing_op(topo, mesh)
+    assert op.impl == "halo_shift"
     x = shard_over_workers(mesh, jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32)))
     out = jax.jit(op.apply)(x)
     np.testing.assert_allclose(
-        np.asarray(out), topo.mixing_matrix @ np.asarray(x), rtol=1e-5, atol=1e-6
+        np.asarray(out), build_topology("ring", n).mixing_matrix @ np.asarray(x),
+        rtol=1e-5, atol=1e-6,
     )
     assert out.sharding.is_equivalent_to(worker_sharding(mesh, 2), 2)
 
@@ -96,12 +120,6 @@ def test_usable_device_count():
     assert usable_device_count(11, 8) == 1
 
 
-def test_shard_map_rejects_irregular_topology():
-    topo = build_topology("erdos_renyi", 8, seed=0)
-    with pytest.raises(ValueError):
-        make_shard_map_mixing_op(topo, _mesh(8))
-
-
 def test_mesh_uses_multiple_devices():
     """The conftest 8-device CPU platform must actually be in effect."""
     assert len(jax.devices()) == 8
@@ -110,14 +128,15 @@ def test_mesh_uses_multiple_devices():
 
 # --------------------------------------------------------- compiled lowering
 #
-# The module docstrings make two hardware claims that nothing above checks:
-# parallel/collectives.py:8-10 — the sharded mixing ops lower to real
+# parallel/collectives.py's module docstring makes two hardware claims that
+# nothing above checks: the sharded mixing ops lower to real
 # CollectivePermute/AllReduce instructions (not all-gathers of the full
 # state), and a ring round moves exactly 2·d floats per device, independent
 # of N. These tests enforce both against the compiled HLO on the 8-device
-# mesh, for the explicit shard_map ops AND the GSPMD stencils (where XLA,
-# not we, chooses the collective — the roll-stencil only embeds as boundary
-# permutes if the compiler recognizes it).
+# mesh, for the GSPMD stencils (where XLA, not we, chooses the collective —
+# the roll-stencil only embeds as boundary permutes if the compiler
+# recognizes it) and, on a torus, for the worker mesh's shifts beside them
+# (a ring's: tests/test_worker_mesh.py).
 
 import re
 
@@ -138,7 +157,7 @@ def _permute_payload_floats(hlo: str) -> list[int]:
     return out
 
 
-@pytest.mark.parametrize("impl", ["shard_map", "stencil"])
+@pytest.mark.parametrize("impl", ["stencil"])
 @pytest.mark.parametrize("n", [16, 24])
 def test_ring_lowers_to_boundary_permutes_with_2d_floats(impl, n):
     """Ring mixing on D devices compiles to exactly two boundary
@@ -147,10 +166,7 @@ def test_ring_lowers_to_boundary_permutes_with_2d_floats(impl, n):
     d = 7
     topo = build_topology("ring", n)
     mesh = _mesh(n)
-    if impl == "shard_map":
-        op = make_shard_map_mixing_op(topo, mesh)
-    else:
-        op = make_mixing_op(topo, impl="stencil")
+    op = make_mixing_op(topo, impl=impl)
     x = shard_over_workers(mesh, jnp.zeros((n, d), jnp.float32))
     hlo = _compiled_hlo(op.apply, x)
     payloads = _permute_payload_floats(hlo)
@@ -160,17 +176,14 @@ def test_ring_lowers_to_boundary_permutes_with_2d_floats(impl, n):
     assert "all-reduce" not in hlo
 
 
-@pytest.mark.parametrize("impl", ["shard_map", "stencil"])
+@pytest.mark.parametrize("impl", ["stencil"])
 def test_fc_lowers_to_all_reduce(impl):
     """Fully-connected mixing is the global mean: one AllReduce spanning all
     devices, no permutes, no gather of the full state."""
     n, d = 16, 7
     topo = build_topology("fully_connected", n)
     mesh = _mesh(n)
-    if impl == "shard_map":
-        op = make_shard_map_mixing_op(topo, mesh)
-    else:
-        op = make_mixing_op(topo, impl="stencil")
+    op = make_mixing_op(topo, impl=impl)
     x = shard_over_workers(mesh, jnp.zeros((n, d), jnp.float32))
     hlo = _compiled_hlo(op.apply, x)
     assert re.search(r"all-reduce(-start)?\(", hlo)
@@ -178,11 +191,10 @@ def test_fc_lowers_to_all_reduce(impl):
     assert "all-gather" not in hlo
 
 
-@pytest.mark.parametrize("route", ["shard_map", "worker_mesh"])
-def test_grid_shard_map_lowers_to_row_permutes(route):
-    """Torus stencil with whole grid rows blocked over devices, the ONE flat
-    block body both routes run (``mixing_impl='shard_map'`` and, since
-    ISSUE 53, ``worker_mesh``'s halo mixing on a torus cut by grid rows):
+@pytest.mark.parametrize("route", ["stencil", "worker_mesh"])
+def test_grid_lowers_to_row_permutes(route):
+    """A torus with whole grid rows blocked over devices, by the GSPMD
+    stencil on an auto mesh and by ``worker_mesh``'s halo shifts (ISSUE 53):
     two boundary grid-row exchanges of [cols, d] each, 2·cols·d floats per
     device per round, and no gather and no neighbor table in the compiled
     text."""
@@ -190,13 +202,9 @@ def test_grid_shard_map_lowers_to_row_permutes(route):
     topo = build_topology("grid", n)
     rows, cols = topo.grid_shape
     mesh = make_worker_mesh(rows)
-    if route == "shard_map":
-        op = make_shard_map_mixing_op(topo, mesh)
+    if route == "stencil":
+        op = make_mixing_op(topo, impl="stencil")
     else:
-        from distributed_optimization_tpu.parallel.collectives import (
-            make_halo_mixing_op,
-        )
-
         op = make_halo_mixing_op(topo, mesh)
         assert op.impl == "halo_shift" and op.tables is None
     x = shard_over_workers(mesh, jnp.zeros((n, d), jnp.float32))
@@ -212,8 +220,8 @@ def test_grid_shard_map_lowers_to_row_permutes(route):
 def test_dense_mixing_on_sharded_input_gathers():
     """Contrast case: the dense [N, N] contraction cannot ride boundary
     permutes — under GSPMD it materializes the full state (all-gather or
-    equivalent full-state movement), which is exactly why the stencil/
-    shard_map forms exist for mesh-embeddable graphs."""
+    equivalent full-state movement), which is exactly why the stencil and
+    halo forms exist for mesh-embeddable graphs."""
     n, d = 16, 7
     topo = build_topology("ring", n)
     mesh = _mesh(n)

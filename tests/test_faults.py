@@ -110,14 +110,6 @@ def test_numpy_backend_runs_synchronous_faults():
         numpy_backend.run(CFG.replace(gossip_schedule="one_peer"), ds, 0.0)
 
 
-def test_shard_map_mixing_rejects_faults():
-    ds = generate_synthetic_dataset(CFG)
-    with pytest.raises(ValueError, match="dense or stencil"):
-        jax_backend.run(
-            CFG.replace(edge_drop_prob=0.1, mixing_impl="shard_map"), ds, 0.0
-        )
-
-
 def test_straggler_adjacency_and_mean_preservation():
     topo = build_topology("fully_connected", 10)
     fm = make_faulty_mixing(topo, 0.0, seed=4, straggler_prob=0.4)

@@ -1,6 +1,6 @@
 """Million-worker mesh round (ISSUE 18, docs/PERF.md §17).
 
-Four layers:
+Three layers:
 
 1. **Sparse sampler**: the O(N·k_max) Erdős–Rényi constructor is
    seed-pure, realizes the same G(n, p) law as the dense-stream
@@ -13,11 +13,7 @@ Four layers:
    to ~1e-12 for qsgd (stochastic-rounding thresholds sit on a reduction
    XLA may fuse differently across the two programs), while
    compression='none' stays bitwise-identical to the PR 11 exchange.
-3. **Double-buffered overlap**: `halo_overlap='off'` is bitwise the
-   PR 11 trajectory; 'double_buffer' runs the restructured body
-   (different summation order — documented non-bitwise) to the same
-   optimum.
-4. **Scale** (slow-marked): N=1,000,000 ring/torus tables + halo plans
+3. **Scale** (slow-marked): N=1,000,000 ring/torus tables + halo plans
    build dense-free under a memory ceiling.
 
 Plus the sequential-mesh replica dispatch satellite (run_batch).
@@ -183,21 +179,16 @@ def test_sampler_rejections():
         make_cfg(topology_sampler="sparse")
 
 
-def test_halo_plan_cache_key_includes_sampler_and_overlap():
-    er = dict(topology="erdos_renyi", erdos_renyi_p=0.5, topology_seed=7)
+def test_halo_plan_cache_key_includes_sampler():
     t_dense = build_neighbor_topology("erdos_renyi", N, erdos_renyi_p=0.5,
                                       seed=7, sampler="dense")
     t_sparse = build_neighbor_topology("erdos_renyi", N, erdos_renyi_p=0.5,
                                        seed=7, sampler="sparse")
-    del er
     p1 = build_halo_plan(*neighbor_tables_for(t_dense), 4, sampler="dense")
     p2 = build_halo_plan(*neighbor_tables_for(t_dense), 4, sampler="dense")
     assert p1 is p2  # cache hit
     p3 = build_halo_plan(*neighbor_tables_for(t_sparse), 4, sampler="sparse")
     assert p3 is not p1
-    p4 = build_halo_plan(*neighbor_tables_for(t_dense), 4, sampler="dense",
-                         overlap="double_buffer")
-    assert p4 is not p1
 
 
 # ------------------------------------------- compressed halo exchange
@@ -263,46 +254,19 @@ def test_ici_summary_prices_compressed_wire_rows():
     assert comp["payload_floats_per_row"] == pytest.approx(2 * 2)
 
 
-# --------------------------------------------------- overlap double-buffer
-
-
-def test_overlap_off_bitwise_and_double_buffer_close(problem):
+def test_two_mesh_runs_of_one_config_are_bitwise(problem):
     from distributed_optimization_tpu.backends import jax_backend
 
     ds, f_opt = problem
     r_u = jax_backend.run(make_cfg(), ds, f_opt, use_mesh=False)
-    r_off = jax_backend.run(make_cfg(worker_mesh=4, halo_overlap="off"),
-                            ds, f_opt)
-    r_db = jax_backend.run(
-        make_cfg(worker_mesh=4, halo_overlap="double_buffer"), ds, f_opt
-    )
-    # 'off' is the PR 11 body; against the unsharded program it is another
-    # executable (MODEL_ULPS), and so is each replay of itself bitwise:
-    assert_ulps_of_scale(r_off.final_models, r_u.final_models, MODEL_ULPS)
-    r_again = jax_backend.run(make_cfg(worker_mesh=4, halo_overlap="off"),
-                              ds, f_opt)
+    r_mesh = jax_backend.run(make_cfg(worker_mesh=4), ds, f_opt)
+    # Against the unsharded program the mesh run is another executable
+    # (MODEL_ULPS); a replay of itself is bitwise:
+    assert_ulps_of_scale(r_mesh.final_models, r_u.final_models, MODEL_ULPS)
+    r_again = jax_backend.run(make_cfg(worker_mesh=4), ds, f_opt)
     np.testing.assert_array_equal(
-        np.asarray(r_off.final_models), np.asarray(r_again.final_models)
+        np.asarray(r_mesh.final_models), np.asarray(r_again.final_models)
     )
-    # double-buffer reorders the neighbor sum (in-block partial first,
-    # halo contributions last) — same fixed point, not bitwise.
-    np.testing.assert_allclose(
-        np.asarray(r_off.final_models), np.asarray(r_db.final_models),
-        rtol=0, atol=1e-8,
-    )
-
-
-@pytest.mark.parametrize("kw,needle", [
-    (dict(worker_mesh=0), "no exchange to overlap"),
-    (dict(worker_mesh=4, compression="top_k", compression_k=4,
-          choco_gamma=0.5), "compressed gossip"),
-    (dict(worker_mesh=4, straggler_prob=0.2), "PLAIN"),
-    (dict(worker_mesh=4, halo_overlap="ring"), "Unknown halo overlap"),
-])
-def test_overlap_composition_rejected(kw, needle):
-    kw = {"halo_overlap": kw.pop("halo_overlap", "double_buffer"), **kw}
-    with pytest.raises(ValueError, match=needle):
-        make_cfg(**kw)
 
 
 # ------------------------------------------------- sequential-mesh batch

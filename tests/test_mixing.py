@@ -57,67 +57,51 @@ def test_mixing_preserves_mean(rng, name, n):
     )
 
 
-SPARSE_CASES = [("erdos_renyi", 12), ("chain", 9), ("star", 9),
-                ("directed_erdos_renyi", 12), ("ring", 8)]
+# Undirected graphs with no stencil (and a ring, which has one): the gather
+# over the live slots against the dense matrix. The drawn graph as the
+# matrix-free tables the drawn-graph cell runs, against the dense build of
+# the same seed.
+GATHER_CASES = [("erdos_renyi", 12, "neighbor"), ("chain", 9, "dense"),
+                ("star", 9, "dense"), ("ring", 8, "dense")]
 
 
-@pytest.mark.parametrize("name,n", SPARSE_CASES)
-def test_sparse_equals_dense(rng, name, n):
-    """The CSR segment-sum contraction is the same linear operator as the
-    dense matmul, for undirected AND directed (column-stochastic) graphs."""
-    topo = build_topology(name, n, seed=2, erdos_renyi_p=0.35)
+@pytest.mark.parametrize("name,n,representation", GATHER_CASES)
+def test_gather_equals_dense(rng, name, n, representation):
+    """The table-driven gather is the same linear operator as the dense
+    matmul, from a dense build's derived tables and from a matrix-free
+    build's own."""
+    kw = dict(seed=2, erdos_renyi_p=0.35)
+    dense_topo = build_topology(name, n, **kw)
     x = rng.normal(size=(n, 5)).astype(np.float32)
-    dense = make_mixing_op(topo, impl="dense")
-    sparse = make_mixing_op(topo, impl="sparse")
-    assert sparse.impl == "sparse"
+    dense = make_mixing_op(dense_topo, impl="dense")
+    gather = make_mixing_op(
+        build_topology(name, n, impl=representation, **kw), impl="gather"
+    )
+    assert gather.impl == "gather"
     np.testing.assert_allclose(
-        np.asarray(sparse.apply(jnp.asarray(x))),
+        np.asarray(gather.apply(jnp.asarray(x))),
         np.asarray(dense.apply(jnp.asarray(x))),
         rtol=1e-5, atol=1e-6,
     )
     np.testing.assert_allclose(
-        np.asarray(sparse.neighbor_sum(jnp.asarray(x))),
+        np.asarray(gather.neighbor_sum(jnp.asarray(x))),
         np.asarray(dense.neighbor_sum(jnp.asarray(x))),
         rtol=1e-5, atol=1e-5,
     )
 
 
-def test_sparse_handles_trailing_dims_and_jit(rng):
-    """[N]-trailing-shape variants (push-sum's [N, 1] mass) and jit both
-    work through the segment-sum path."""
+def test_gather_handles_trailing_dims_and_jit(rng):
+    """[N]-trailing-shape variants (an [N, 1] mass column) and jit both
+    work through the live-slot loop."""
     import jax
 
     topo = build_topology("erdos_renyi", 10, seed=4)
-    sparse = make_mixing_op(topo, impl="sparse")
+    gather = make_mixing_op(topo, impl="gather")
     w = rng.normal(size=(10, 1)).astype(np.float32)
     expected = topo.mixing_matrix.astype(np.float32) @ w
     np.testing.assert_allclose(
-        np.asarray(jax.jit(sparse.apply)(jnp.asarray(w))), expected,
+        np.asarray(jax.jit(gather.apply)(jnp.asarray(w))), expected,
         rtol=1e-5, atol=1e-6,
-    )
-
-
-def test_sparse_through_backend_matches_dense_run(rng):
-    """End-to-end: a backend run with mixing_impl='sparse' reproduces the
-    dense run's trajectory exactly (same linear operator, same batches)."""
-    from conftest import small_backend_config
-    from distributed_optimization_tpu.backends import jax_backend
-    from distributed_optimization_tpu.utils.data import (
-        generate_synthetic_dataset,
-    )
-    from distributed_optimization_tpu.utils.oracle import (
-        compute_reference_optimum,
-    )
-
-    cfg = small_backend_config(topology="erdos_renyi", n_iterations=40,
-                               dtype="float64")
-    ds = generate_synthetic_dataset(cfg)
-    _, f_opt = compute_reference_optimum(ds, cfg.reg_param)
-    rd = jax_backend.run(cfg.replace(mixing_impl="dense"), ds, f_opt)
-    rs = jax_backend.run(cfg.replace(mixing_impl="sparse"), ds, f_opt)
-    np.testing.assert_allclose(rs.final_models, rd.final_models, rtol=1e-10)
-    np.testing.assert_allclose(
-        rs.history.objective, rd.history.objective, rtol=1e-9
     )
 
 
@@ -132,30 +116,69 @@ def test_auto_picks_stencil_for_regular_graphs():
     assert make_mixing_op(build_topology("erdos_renyi", 8, seed=0)).impl == "dense"
 
 
-def test_sparse_is_opt_in_only():
-    """docs/perf/sparse_mixing.json measured DENSE faster than the CSR
-    form at every cell (N up to 4096, densities 0.05%-40%, both
-    platforms), so auto keeps dense for irregular graphs at any scale and
-    sparse is explicit opt-in."""
-    assert make_mixing_op(build_topology("chain", 128)).impl == "dense"
-    assert make_mixing_op(build_topology("chain", 16)).impl == "dense"
-    assert make_mixing_op(
-        build_topology("erdos_renyi", 128, seed=0, erdos_renyi_p=0.05)
-    ).impl == "dense"
-    # Regular graphs keep their stencils at any N.
-    assert make_mixing_op(build_topology("ring", 256)).impl == "stencil"
-    assert make_mixing_op(
-        build_topology("chain", 128), impl="sparse"
-    ).impl == "sparse"
+# (topology, representation, N, devices of a worker mesh or None) -> the form
+# the code takes, by no option: ``make_mixing_op``'s ``auto`` on one device
+# or an auto mesh, ``make_halo_mixing_op`` under ``worker_mesh``. Rows that no
+# other test holds (a ring and a small drawn graph: above; a matrix-free
+# drawn graph and a large chain: tests/test_federated.py; the mesh's forms
+# on matrix-free tables over four blocks: tests/test_worker_mesh.py's
+# HALO_FORMS).
+DECISION = [
+    ("ring", "dense", 256, None, "stencil"),
+    ("grid", "dense", 16, None, "stencil"),
+    ("grid", "neighbor", 16, None, "stencil"),
+    ("fully_connected", "dense", 8, None, "stencil"),
+    ("directed_ring", "dense", 8, None, "stencil"),
+    # a small irregular graph keeps its matrix, at any size one matmul holds
+    ("chain", "dense", 16, None, "dense"),
+    ("chain", "dense", 128, None, "dense"),
+    ("erdos_renyi", "dense", 128, None, "dense"),
+    ("directed_erdos_renyi", "dense", 12, None, "dense"),
+    ("chain", "neighbor", 16, None, "gather"),
+    # under a mesh the table is asked, whichever representation made it:
+    # a dense build's derived tables are a ring's and a torus's too
+    ("ring", "dense", 16, 8, "halo_shift"),
+    ("grid", "dense", 64, 4, "halo_shift"),
+    # 4 x 4 over eight devices: two workers a block, inside a grid row
+    ("grid", "neighbor", 16, 8, "halo_gather"),
+    ("erdos_renyi", "dense", 16, 4, "halo_gather"),
+]
 
 
-# The two option values whose kernels no ledger line ever chose (ROADMAP C3,
-# PR 42) are gone: a config, the operator builder and the CLI refuse them as
-# they refuse any other unknown value.
+@pytest.mark.parametrize(
+    "name,representation,n,devices,form", DECISION,
+    ids=[f"{t}-{r}-{n}-" + (f"mesh{p}" if p else "one") for t, r, n, p, _ in DECISION],
+)
+def test_the_form_is_the_codes_choice(name, representation, n, devices, form):
+    import jax
+    from jax.sharding import Mesh
+
+    from distributed_optimization_tpu.parallel.collectives import (
+        make_halo_mixing_op,
+    )
+
+    topo = build_topology(
+        name, n, seed=0, erdos_renyi_p=0.05 if n == 128 else 0.4,
+        impl=representation,
+    )
+    if devices is None:
+        assert make_mixing_op(topo).impl == form
+    else:
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("workers",))
+        assert make_halo_mixing_op(topo, mesh).impl == form
+
+
+# The option values that no ledger line ever chose (ROADMAP C3; the kernels'
+# two in PR 42, the explicit-collective stencils and the edge-list
+# contraction in PR 54) are gone: a config, the operator builder and the CLI
+# refuse them as they refuse any other unknown value.
 REMOVED_VALUES = pytest.mark.parametrize("field,value,rule", [
     ("mixing_impl", "pallas", {}),
     ("robust_impl", "fused", dict(aggregation="trimmed_mean", robust_b=1)),
-], ids=["mixing_impl-pallas", "robust_impl-fused"])
+    ("mixing_impl", "shard_map", {}),
+    ("mixing_impl", "sparse", {}),
+], ids=["mixing_impl-pallas", "robust_impl-fused", "mixing_impl-shard_map",
+        "mixing_impl-sparse"])
 
 
 @REMOVED_VALUES
@@ -180,3 +203,32 @@ def test_removed_impl_values_are_no_cli_choice(field, value, rule, capsys):
     with pytest.raises(SystemExit):
         build_parser().parse_args(argv)
     assert f"invalid choice: '{value}'" in capsys.readouterr().err
+
+
+# ``halo_overlap`` (PR 54) is no field at all: the halo gather has one body.
+
+
+def test_removed_field_is_no_config_argument():
+    from distributed_optimization_tpu.config import ExperimentConfig
+
+    with pytest.raises(TypeError, match="halo_overlap"):
+        ExperimentConfig(halo_overlap="off")
+
+
+def test_removed_field_is_no_cli_argument(capsys):
+    from distributed_optimization_tpu.cli import build_parser
+
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(["--halo-overlap", "off"])
+    assert "unrecognized arguments: --halo-overlap" in capsys.readouterr().err
+
+
+def test_a_manifest_that_carries_the_removed_field_still_loads():
+    """``from_dict`` drops keys it does not know: a manifest or a checkpoint
+    written before PR 54 (docs/perf/*.manifest.json hold ``halo_overlap``)
+    gives the config it described."""
+    from distributed_optimization_tpu.config import ExperimentConfig
+
+    cfg = ExperimentConfig(n_workers=16, topology="ring")
+    old = {**cfg.to_dict(), "halo_overlap": "off"}
+    assert ExperimentConfig.from_dict(old) == cfg

@@ -184,26 +184,25 @@ def test_directed_fault_realized_matrix_invariants(n, drop, t, seed):
 
 @settings(**SETTINGS)
 @given(
-    topology=st.sampled_from(["chain", "star", "erdos_renyi",
-                              "directed_erdos_renyi", "ring"]),
+    topology=st.sampled_from(["chain", "star", "erdos_renyi", "ring"]),
     n=st.integers(min_value=3, max_value=32),
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
-def test_sparse_mixing_equals_dense_property(topology, n, seed):
-    """Round 5: the CSR segment-sum contraction is the same linear
-    operator as the dense matmul for arbitrary graphs, both orientations,
-    apply and neighbor_sum."""
+def test_gather_mixing_equals_dense_property(topology, n, seed):
+    """The table-driven gather over the live slots is the same linear
+    operator as the dense matmul for arbitrary undirected graphs, apply
+    and neighbor_sum."""
     from distributed_optimization_tpu.ops.mixing import make_mixing_op
 
     topo = build_topology(topology, n, erdos_renyi_p=0.5, seed=seed)
     rng = np.random.default_rng(seed % 2**16)
     x = jnp.asarray(rng.standard_normal((n, 3)), dtype=jnp.float32)
     dense = make_mixing_op(topo, impl="dense")
-    sparse = make_mixing_op(topo, impl="sparse")
-    np.testing.assert_allclose(np.asarray(sparse.apply(x)),
+    gather = make_mixing_op(topo, impl="gather")
+    np.testing.assert_allclose(np.asarray(gather.apply(x)),
                                np.asarray(dense.apply(x)),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(sparse.neighbor_sum(x)),
+    np.testing.assert_allclose(np.asarray(gather.neighbor_sum(x)),
                                np.asarray(dense.neighbor_sum(x)),
                                rtol=1e-5, atol=1e-5)
 

@@ -366,9 +366,6 @@ PERF_ARTIFACT_KEYS = {
     "observatory.json": {
         "device", "platform", "protocol", "note", "heartbeat", "async",
         "scrape", "gates"},
-    "mixing_bench.json": {
-        "d", "device", "end_to_end", "iters", "n_workers", "note",
-        "op_chain", "op_us_per_apply", "platform", "winner"},
     "northstar_consensus.json": {
         "consensus_definition", "device", "metric", "runs",
         "total_wall_seconds"},
@@ -389,8 +386,6 @@ PERF_ARTIFACT_KEYS = {
     "serving_load.json": {
         "device", "platform", "protocol", "note", "traffic", "latency",
         "saturation", "shed", "fairness", "restart", "parity", "gates"},
-    "sparse_mixing.json": {
-        "device", "end_to_end", "note", "op_level", "protocol"},
     "sweep.json": {
         "cells", "device", "eta_sweep_demo", "floors", "note", "platform",
         "protocol"},
@@ -403,7 +398,7 @@ PERF_ARTIFACT_KEYS = {
         "gates"},
     "mesh_scale.json": {
         "device", "platform", "protocol", "note", "scale", "er_plan",
-        "compression", "overlap", "gates"},
+        "compression", "gates"},
 }
 
 

@@ -229,14 +229,12 @@ def test_halo_mixing_bitwise_vs_gather(rng, name, n, shards):
             np.asarray(jax.jit(gather_op.apply)(x)), 4)
 
 
-@pytest.mark.parametrize("overlap,form", [
-    ("off", "apply"), ("double_buffer", "apply"), ("off", "neighbor_sum"),
-])
-def test_halo_gather_round_sums_slot_by_slot(overlap, form):
+@pytest.mark.parametrize("form", ["apply", "neighbor_sum"])
+def test_halo_gather_round_sums_slot_by_slot(form):
     """A block's round is the one-device round's ``slot_sum`` on the block
     with its halo behind it (ISSUE 36): a loop over the table's slots, one
     row gather in its body, and the ``[S, k_max, d]`` stack of every
-    neighbour's row in no instruction, whatever the exchange's form."""
+    neighbour's row in no instruction."""
     from distributed_optimization_tpu.parallel.collectives import (
         make_halo_mixing_op,
     )
@@ -245,7 +243,7 @@ def test_halo_gather_round_sums_slot_by_slot(overlap, form):
     topo = build_topology("erdos_renyi", n, erdos_renyi_p=0.2, seed=7, impl="neighbor")
     k_max = topo.nbr_idx.shape[1]
     assert k_max > 8
-    op = make_halo_mixing_op(topo, _mesh(shards), overlap=overlap)
+    op = make_halo_mixing_op(topo, _mesh(shards))
     assert op.impl == "halo_gather"
     text = jax.jit(getattr(op, form)).lower(jnp.zeros((n, d), jnp.float32)).as_text()
     assert "stablehlo.while" in text
@@ -445,26 +443,6 @@ def test_halo_shift_is_the_halo_gather_round(rng, graph, n, form, shards, stack)
             np.asarray(rounded.astype(jnp.float32)), want, 4)
 
 
-def test_halo_overlap_on_a_ring_is_one_program(problem):
-    """The shift form's permutes depend on nothing local, so there is
-    nothing for ``halo_overlap`` to reorder: 'off' and 'double_buffer' on
-    a ring are one trajectory, bit for bit (the gather form's two bodies
-    sum in another order: ``_make_halo_gather_mixing_op``)."""
-    from distributed_optimization_tpu.backends import jax_backend
-
-    ds, f_opt = problem
-    off, dbl = (
-        jax_backend.run(
-            make_cfg(worker_mesh=4, halo_overlap=mode), ds, f_opt,
-            return_state=True)
-        for mode in ("off", "double_buffer"))
-    np.testing.assert_array_equal(off.final_models, dbl.final_models)
-    np.testing.assert_array_equal(
-        off.history.objective, dbl.history.objective)
-    np.testing.assert_array_equal(
-        off.history.consensus_error, dbl.history.consensus_error)
-
-
 def test_halo_mixing_rejects_directed():
     from distributed_optimization_tpu.parallel.collectives import (
         make_halo_mixing_op,
@@ -613,7 +591,7 @@ def test_worker_mesh_one_rejected():
     (dict(backend="numpy"), "backend='jax'"),
     (dict(topology="fully_connected"), "matrix-free"),
     (dict(topology_impl="dense"), "neighbor"),
-    (dict(mixing_impl="shard_map"), "halo"),
+    (dict(mixing_impl="dense"), "no sharded form"),
     (dict(execution="async", latency_model="exponential"), "async"),
     (dict(edge_drop_prob=0.1), "per-shard slicing"),
     (dict(attack="alie", n_byzantine=2, aggregation="median", robust_b=2),
@@ -639,18 +617,7 @@ def test_neighbor_mixing_rejection_names_sharded_gather_path():
     """Satellite: the topology_impl='neighbor' × mixing_impl rejection now
     points at worker_mesh for the real-collectives route, not at dense."""
     with pytest.raises(ValueError, match="worker_mesh >= 2"):
-        make_cfg(mixing_impl="shard_map", worker_mesh=0)
-
-
-def test_replica_rejection_names_sharded_gather_path():
-    """Satellite: the replicas × mixing_impl message names the worker_mesh
-    path as likewise mesh-pinned."""
-    with pytest.raises(ValueError, match="worker_mesh"):
-        ExperimentConfig(**{
-            **{k: v for k, v in BASE.items()
-               if k not in ("topology_impl", "mixing_impl")},
-            "replicas": 2, "mixing_impl": "shard_map",
-        })
+        make_cfg(mixing_impl="dense", worker_mesh=0)
 
 
 def test_batch_unsupported_reason_names_mesh():
@@ -689,7 +656,7 @@ def test_cli_worker_mesh_flag():
 
 def test_auto_and_explicit_grid_mesh_agree(problem, monkeypatch):
     """Satellite: the auto mixing path applies the same grid-row
-    divisibility rule as explicit shard_map, so both size the mesh off
+    divisibility rule as the explicit stencil, so both size the mesh off
     grid ROWS (6 for a 6×6 torus on 8 devices), not off N=36 (which
     would land on 4 — a count the row reshape cannot split)."""
     from distributed_optimization_tpu.backends import jax_backend
@@ -717,7 +684,7 @@ def test_auto_and_explicit_grid_mesh_agree(problem, monkeypatch):
     })
     ds = generate_synthetic_dataset(cfg)
     _, f_opt = compute_reference_optimum(ds, cfg.reg_param)
-    for impl in ("auto", "shard_map"):
+    for impl in ("auto", "stencil"):
         jax_backend.run(cfg.replace(mixing_impl=impl), ds, f_opt)
     assert sizes["calls"] == [6, 6], sizes
 
